@@ -443,7 +443,7 @@ class ModelRunner:
             ).start()
         self._np_rng = np.random.default_rng(config.seed ^ 0x5EED)
 
-        if config.parallel.enable_dbo and not ops._on_tpu():
+        if config.parallel.enable_dbo and not ops._on_tpu(mesh_ctx.mesh):
             # Never a silent regression: see ParallelConfig.enable_dbo
             # for the full substrate condition.
             log.warning(
@@ -454,7 +454,7 @@ class ModelRunner:
                 "enable only on a real multi-chip slice and trust the "
                 "bench delta (docs/architecture/dbo.md)"
             )
-        if self.moe_overlap > 1 and not ops._on_tpu():
+        if self.moe_overlap > 1 and not ops._on_tpu(mesh_ctx.mesh):
             # Same substrate condition as DBO: see ParallelConfig.
             # moe_overlap and the bench moe_ep part's on/off delta.
             log.warning(
@@ -488,7 +488,10 @@ class ModelRunner:
         self.prefill_buckets = sched.prefill_token_buckets or _buckets(
             sched.max_num_batched_tokens, start=16
         )
+        # op -> the kernel plans its traces took (ops.record_plans).
+        self.kernel_plans: dict[str, set[str]] = {}
         self._build_programs()
+        self._check_page_table_fits_smem()
         # Padding-efficiency accounting (EngineStats padded/live tokens):
         # every dispatch path adds its live token count and the padded
         # compute width the traced shape actually paid for.
@@ -573,6 +576,30 @@ class ModelRunner:
             self.flat_t_buckets = tuple(range(16, limit + 1, 16))
             self.flat_rows = self.unified_row_buckets[-1]
             self._flat = self._build_flat()
+
+    def _check_page_table_fits_smem(self) -> None:
+        """The attention kernels scalar-prefetch the whole page table
+        into SMEM; refuse a geometry whose table cannot fit there now,
+        with the flags that size it, instead of letting the chip's
+        compiler refuse the first request's program."""
+        fit = ops.page_table_smem(
+            self.cfg, self.page, self.max_pages, self.ctx.world,
+            self.ctx.mesh, decode_rows=self.batch_buckets[-1],
+            flat_rows=self.flat_rows,
+            flat_tokens=self.flat_t_buckets[-1] if self.flat_rows else 0,
+        )
+        if fit is None or fit[0] <= fit[1]:
+            return
+        sched = self.config.scheduler
+        raise ValueError(
+            f"the page table ({self.max_pages} pages a row) needs {fit[0]} B "
+            f"of the {fit[1]} B of SMEM the attention kernels prefetch it "
+            f"into: lower --max-model-len ({self.cfg.max_model_len}; pages "
+            f"= max-model-len / block-size {self.page}) or --max-num-seqs "
+            f"({sched.max_num_seqs}; rows grow with it and with "
+            f"--max-num-batched-tokens / {self.unified_row_cap}), or "
+            "LLMD_PALLAS=off for the XLA attention"
+        )
 
     # ------------------------------------------------------------------ #
     # Wide-EP MoE control plane (census drain, adaptive capacity, EPLB)
@@ -878,15 +905,18 @@ class ModelRunner:
             kw["kv_swa"] = kv_swa
         if census is not None:
             kw["moe_census"] = census
-        out = llama.forward_hidden(
-            params, kv_cache, inp, cfg, self.ctx.world,
-            mesh=self.ctx.mesh, moe_backend=moe_backend,
-            ep_capacity_factor=self.ep_capacity, kv_rep=self.kv_rep,
-            dbo=dbo, moe_overlap=self.moe_overlap,
-            moe_placement=params.get("moe_placement"),
-            cp_prefill=cp,
-            **kw,
-        )
+        # Runs at trace time only: every program this runner compiles
+        # notes here which kernel plan each of its ops took.
+        with ops.record_plans(self.kernel_plans):
+            out = llama.forward_hidden(
+                params, kv_cache, inp, cfg, self.ctx.world,
+                mesh=self.ctx.mesh, moe_backend=moe_backend,
+                ep_capacity_factor=self.ep_capacity, kv_rep=self.kv_rep,
+                dbo=dbo, moe_overlap=self.moe_overlap,
+                moe_placement=params.get("moe_placement"),
+                cp_prefill=cp,
+                **kw,
+            )
         if census is not None:
             census = out[-1]
             out = out[:-1]

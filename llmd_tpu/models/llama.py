@@ -288,7 +288,7 @@ def forward_hidden(
             if moe_backend == "grouped" and world_size == 1:
                 from llmd_tpu.models.moe import moe_block_grouped
 
-                return moe_block_grouped(h2, lp, cfg), None
+                return moe_block_grouped(h2, lp, cfg, mesh=mesh), None
             # Sharded jit without the EP backend: the dense combine is
             # the only path GSPMD can partition (expert weights are
             # EP-sharded; the grouped kernel has no partitioning rule

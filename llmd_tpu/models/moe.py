@@ -124,7 +124,9 @@ def expert_glu(gate: jax.Array, up: jax.Array, cfg: ModelConfig) -> jax.Array:
     return jax.nn.silu(gate) * up
 
 
-def moe_block_grouped(h: jax.Array, lp: dict, cfg: ModelConfig) -> jax.Array:
+def moe_block_grouped(
+    h: jax.Array, lp: dict, cfg: ModelConfig, mesh=None
+) -> jax.Array:
     """MoE FFN via grouped GEMM (DeepGEMM role): tokens sorted by expert,
     each expert multiplies only its routed rows. Numerically equivalent to
     the dense combine (same f32 weighted sum) at top_k/E of the FLOPs."""
@@ -139,6 +141,7 @@ def moe_block_grouped(h: jax.Array, lp: dict, cfg: ModelConfig) -> jax.Array:
     out = moe_apply_grouped(
         ht, weights, ids, lp["we_gate"], lp["we_up"], lp["we_down"],
         scales=_expert_scales(lp), biases=_expert_biases(lp), cfg=cfg,
+        mesh=mesh,
     ).astype(h.dtype)
     if cfg.shared_expert_intermediate_size:
         out = out + shared_expert_ffn(ht, lp)

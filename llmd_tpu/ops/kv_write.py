@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llmd_tpu.compat import pallas_tpu_compiler_params
-
 
 def _write_kernel(
     # scalar prefetch
@@ -91,6 +89,13 @@ def _write_kernel(
         store.wait()
 
 
+def _slab_align(dtype) -> int:
+    """Row alignment a manual DMA may start at on the sublane-tiled token
+    axis of the HBM slab: one (8, 128) tile of 32-bit words, which packs
+    8 rows of a 4-byte, 16 of a 2-byte and 32 of a 1-byte dtype."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
 def _flat_write_kernel(
     # scalar prefetch
     layer_ref,  # [1] i32 layer index (full-cache variant; [0] otherwise)
@@ -105,7 +110,7 @@ def _flat_write_kernel(
     out_ref,     # same buffer as kv_hbm_ref
     # scratch
     page_buf,   # [2, K, page, 2D] VMEM double buffer (the target pages)
-    slab_buf,   # [2, K, page, 2D] VMEM double buffer (the token slabs)
+    slab_buf,   # [2, K, page + align, 2D] VMEM double buffer (token windows)
     sem_page,   # [2] DMA
     sem_slab,   # [2] DMA
     sem_out,    # scalar DMA
@@ -117,25 +122,33 @@ def _flat_write_kernel(
     covers every token the page receives) — which is what keeps the
     cross-step software pipeline's prefetch safe where the per-token
     decode kernel's same-page read-modify-writes would race it. The
-    token slab arrives page-padded and pre-shifted ([K, T + 2*page,
-    2D], run slab start = page + t0 - off), so the fixed-size slab DMA
-    lands token t0+j exactly at page row off+j with no in-kernel
-    gather."""
+    token slab arrives page-padded and pre-shifted ([K, Tp, 2D], run
+    slab start = page + t0 - off), so slab row src+j is what page row j
+    receives. The token axis is sublane-tiled in HBM and Mosaic refuses
+    a DMA that starts off a tile boundary, so each run fetches the
+    ALIGNED window holding its slab (start rounded down to the tiling,
+    one tile longer than a page) and rotates it into place in VMEM —
+    as 32-bit values, the only width the sublane rotate takes at an
+    arbitrary shift; widening and narrowing back is exact for every
+    pool dtype."""
     r = pl.program_id(0)
     R = pl.num_programs(0)
     page = page_buf.shape[2]
+    win = slab_buf.shape[2]
+    align = win - page
     is_full = len(kv_hbm_ref.shape) == 5
     src = kv_hbm_ref.at[layer_ref[0]] if is_full else kv_hbm_ref
     dst = out_ref.at[layer_ref[0]] if is_full else out_ref
 
     def load(i):
         slot_i = jax.lax.rem(i, 2)
+        start = pl.multiple_of((src_ref[i] // align) * align, align)
         return (
             pltpu.make_async_copy(
                 src.at[phys_ref[i]], page_buf.at[slot_i], sem_page.at[slot_i]
             ),
             pltpu.make_async_copy(
-                kv_new_ref.at[:, pl.ds(src_ref[i], page), :],
+                kv_new_ref.at[:, pl.ds(start, win), :],
                 slab_buf.at[slot_i],
                 sem_slab.at[slot_i],
             ),
@@ -158,9 +171,18 @@ def _flat_write_kernel(
         for c in load(r):
             c.wait()
         buf = page_buf.at[slot]
+        wide = (
+            jnp.float32 if jnp.issubdtype(buf.dtype, jnp.floating)
+            else jnp.int32
+        )
+        # Window row shift+j -> page row j: rotate up by the run's offset
+        # into its window (roll takes a non-negative amount).
+        shift = jax.lax.rem(src_ref[r], align)
+        slab = pltpu.roll(slab_buf[slot].astype(wide), win - shift, 1)
+        slab = slab[:, :page, :].astype(buf.dtype)
         rows = jax.lax.broadcasted_iota(jnp.int32, buf.shape, 1)
         hit = (rows >= off_ref[r]) & (rows < off_ref[r] + cnt_ref[r])
-        buf[:] = jnp.where(hit, slab_buf[slot], buf[:])
+        buf[:] = jnp.where(hit, slab, buf[:])
         store = pltpu.make_async_copy(buf, dst.at[phys_ref[r]], sem_out)
         store.start()
         store.wait()
@@ -170,17 +192,18 @@ def _flat_write_call(kv_cache, kv_new_t, layer, src, phys, offset, cnt, interpre
     K = kv_new_t.shape[0]
     page, D2 = kv_cache.shape[-2], kv_cache.shape[-1]
     R = src.shape[0]
+    win = page + _slab_align(kv_cache.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(R,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM((2, K, page, D2), kv_cache.dtype),
-            pltpu.VMEM((2, K, page, D2), kv_cache.dtype),
+            pltpu.VMEM((2, K, win, D2), kv_cache.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA,
@@ -193,7 +216,7 @@ def _flat_write_call(kv_cache, kv_new_t, layer, src, phys, offset, cnt, interpre
         # operand index counts scalar-prefetch args first: 5 scalars,
         # kv_new_t, then kv_cache at index 6 -> aliased to output 0.
         input_output_aliases={6: 0},
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -226,11 +249,15 @@ def write_kv_pages_flat_full(
     T, K, D2 = kv_new.shape
     L, num_pages, Kc, page, D2c = kv_cache.shape
     assert (K, D2) == (Kc, D2c), (kv_new.shape, kv_cache.shape)
-    # Head-major slab, padded one page on both ends so every pre-shifted
-    # run slice (src in [1, page + T]) stays in range.
+    # Head-major slab, padded one page in front so every pre-shifted run
+    # start (src in [1, page + T)) is in range, and behind so the aligned
+    # window of the last run is too (it ends before src + page + align),
+    # rounded up to whole tiles.
+    align = _slab_align(kv_cache.dtype)
+    tail = page + align + (-(T + 2 * page + align)) % align
     kv_new_t = jnp.pad(
         kv_new.transpose(1, 0, 2).astype(kv_cache.dtype),
-        ((0, 0), (page, page), (0, 0)),
+        ((0, 0), (page, tail), (0, 0)),
     )
     return _flat_write_call(
         kv_cache, kv_new_t, layer, src, phys, offset, cnt, interpret
@@ -245,9 +272,9 @@ def _write_call(kv_cache, kv_new4, layer, phys, offset, valid, interpret):
         grid=(T,),
         in_specs=[
             pl.BlockSpec((1, K, 1, D2), lambda t, l, p, o, v: (t, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM((2, K, page, D2), kv_cache.dtype),
             pltpu.SemaphoreType.DMA((2,)),
@@ -261,7 +288,7 @@ def _write_call(kv_cache, kv_new4, layer, phys, offset, valid, interpret):
         # operand index counts scalar-prefetch args first: 4 scalars,
         # kv_new, then kv_cache at index 5 -> aliased to output 0.
         input_output_aliases={5: 0},
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

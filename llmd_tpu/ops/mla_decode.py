@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llmd_tpu.compat import pallas_tpu_compiler_params
-
 NEG_INF = -2.0**30
 
 
@@ -166,7 +164,7 @@ def mla_decode_paged_attention_full(
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, H, Dl), lambda b, l, pt, kl: (b, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, H, rank), lambda b, l, pt, kl: (b, 0, 0)),
         scratch_shapes=[
@@ -185,7 +183,7 @@ def mla_decode_paged_attention_full(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, rank), q_eff.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

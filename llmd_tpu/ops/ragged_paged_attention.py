@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llmd_tpu.compat import pallas_tpu_compiler_params
-
 NEG_INF = -2.0**30
 
 
@@ -51,7 +49,7 @@ def _decode_kernel(
     #                   the causal mask derived from cu_q_lens)
     #   win_starts_ref  [B] i32 first attended position (sliding; 0=full)
     # blocks: q_ref, sinks_ref, kv_hbm_full_ref, [ks_ref, vs_ref when
-    # quant: [1, K, S_max] f16 per-row scales, gathered into lane-aligned
+    # quant: [1, K, S_max] f32 per-row scales, gathered into lane-aligned
     # form by XLA in _decode_call — Mosaic manual DMA requires a
     # 128-aligned minor dim, which a page's [K, page, 2] scale slab (2
     # lanes) can never satisfy, so the scales cannot ride per-page DMAs
@@ -137,12 +135,11 @@ def _decode_kernel(
             q = q_ref[0]  # [K, G, D]
             ks = vs = None
             if quant:
-                # Scales ride as f16 (they live on the f16 grid — see
-                # pool_scales_to_wire) and upcast here: HALF the
-                # per-block scale-plane bytes of the old f32 relayout,
-                # bit-identical math (f16 -> f32 widening is exact).
-                ks = ks_ref[0, :, pl.ds(i * S, S)].astype(jnp.float32)
-                vs = vs_ref[0, :, pl.ds(i * S, S)].astype(jnp.float32)
+                # Scales ride as f32, the pool's own dtype: Mosaic has
+                # no f16 vector type on TPU, so an f16 plane (half the
+                # bytes) is refused by the chip's compiler.
+                ks = ks_ref[0, :, pl.ds(i * S, S)]
+                vs = vs_ref[0, :, pl.ds(i * S, S)]
                 k = k.astype(q.dtype)  # i8 -> exact in bf16/f32
             # Unfetched positions (tail past kv_len, or pages before the
             # window) hold uninitialized VMEM; zero them so a stray NaN
@@ -178,8 +175,8 @@ def _decode_kernel(
             m_ref[:, :, :1] = m_new
             # Dead-column vs values are DEFINED (the scale operand is a
             # fully-copied XLA gather, not a manual DMA) but may be a
-            # pathological f16-overflow inf — 0-prob x inf = NaN, so
-            # re-mask after the multiply.
+            # pathological inf — 0-prob x inf = NaN, so re-mask after
+            # the multiply.
             pv_probs = (
                 probs if not quant
                 else jnp.where(live, probs * vs[:, None, :], 0.0)
@@ -253,7 +250,7 @@ def _decode_call(
     in_specs = [
         pl.BlockSpec((1, K, G, D), lambda b, l, pt, kl, ws: (b, 0, 0, 0)),
         pl.BlockSpec((K, G), lambda b, l, pt, kl, ws: (0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),  # stays in HBM; manual DMA
+        pl.BlockSpec(memory_space=pl.ANY),  # stays in HBM; manual DMA
     ]
     operands = [qk, sinks2d, kv_cache]
     if scales is not None:
@@ -270,12 +267,9 @@ def _decode_call(
             if scales.ndim == 5 else scales
         )  # [P, K, page, 2]
         mp = page_table.shape[1]
-        # Cast BEFORE the gather: pool scales are f32 values ON the f16
-        # grid (quant_kv layout contract), so the f16 gather+relayout
-        # moves half the bytes of the old f32 form losslessly — this
-        # plane scales with max_pages, not the live context, which made
-        # it the widest int8-only HBM stream in the decode step.
-        g = sl.astype(jnp.float16)[page_table]  # [B, mp, K, page, 2]
+        # This plane scales with max_pages, not the live context: the
+        # widest int8-only HBM stream in the decode step.
+        g = sl[page_table]  # [B, mp, K, page, 2]
         ksvs = g.transpose(0, 2, 4, 1, 3).reshape(B, K, 2, mp * page)
         sspec = pl.BlockSpec(
             (1, K, mp * page), lambda b, l, pt, kl, ws: (b, 0, 0)
@@ -307,7 +301,7 @@ def _decode_call(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -393,13 +387,12 @@ def flat_paged_attention_full(
     in_specs = [
         pl.BlockSpec((1, K, G, D), lambda b, l, r, pt, kl, ws: (b, 0, 0, 0)),
         pl.BlockSpec((K, G), lambda b, l, r, pt, kl, ws: (0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),  # stays in HBM; manual DMA
+        pl.BlockSpec(memory_space=pl.ANY),  # stays in HBM; manual DMA
     ]
     operands = [qk, sinks2d, kv_cache]
     if scales is not None:
         # Per-ROW scale plane (scales cannot ride the page DMAs — see
-        # _decode_call): gathered ONCE per row ([R, K, mp*page], f16 on
-        # the wire, upcast in-kernel — lossless, half the bytes) and
+        # _decode_call): gathered ONCE per row ([R, K, mp*page] f32) and
         # indexed through the scalar-prefetched row map in the
         # BlockSpec, so a prefill chunk's tokens share one plane
         # instead of duplicating it chunk-length times into a
@@ -411,7 +404,7 @@ def flat_paged_attention_full(
         )
         mp = page_table.shape[1]
         R = page_table.shape[0]
-        g = sl.astype(jnp.float16)[page_table]  # [R, mp, K, page, 2]
+        g = sl[page_table]  # [R, mp, K, page, 2]
         ksvs = g.transpose(0, 2, 4, 1, 3).reshape(R, K, 2, mp * page)
         sspec = pl.BlockSpec(
             (1, K, mp * page), lambda b, l, r, pt, kl, ws: (r[b], 0, 0)
@@ -444,7 +437,7 @@ def flat_paged_attention_full(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, K, G, D), q.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -36,9 +36,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from llmd_tpu.compat import shard_map
 from llmd_tpu.ops.paged_attention import _dequant_gathered, _window_mask
 
 _NEG_INF = -1e30

@@ -52,9 +52,9 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from llmd_tpu.compat import shard_map
 from llmd_tpu.config import ModelConfig
 from llmd_tpu.models.moe import router_topk
 
@@ -138,7 +138,7 @@ def moe_block_ep(
 
     local = functools.partial(
         _moe_ep_local, cfg=cfg, W=W, C=C, axes=axes, n_mb=n_mb,
-        E_phys=E_phys, emit_census=emit_census,
+        E_phys=E_phys, emit_census=emit_census, mesh=mesh,
     )
     # Per-param specs: experts (and their int8 channel scales) sharded over
     # the flattened EP axes; router + shared expert replicated. Passing a
@@ -184,7 +184,7 @@ def moe_block_ep(
 
 
 def _dispatch_compute_combine(
-    xc, wc, destc, e_localc, validc, p, *, cfg, W, C, axes, E_loc
+    xc, wc, destc, e_localc, validc, p, *, cfg, W, C, axes, E_loc, mesh
 ):
     """One microbatch chain: dispatch a2a → grouped experts → combine a2a.
 
@@ -247,7 +247,7 @@ def _dispatch_compute_combine(
         biases = (p["we_gate_b"], p["we_up_b"], p["we_down_b"])
     ys = expert_mlp_grouped(
         xr[order], group_sizes, p["we_gate"], p["we_up"], p["we_down"],
-        scales=scales, biases=biases, cfg=cfg,
+        scales=scales, biases=biases, cfg=cfg, mesh=mesh,
     )
     yr = (
         jnp.zeros_like(xr).at[order].set(ys)
@@ -269,7 +269,7 @@ def _dispatch_compute_combine(
 def _moe_ep_local(
     ht, valid, p: dict, place: dict, *,
     cfg: ModelConfig, W: int, C: int, axes, n_mb: int, E_phys: int,
-    emit_census: bool,
+    emit_census: bool, mesh,
 ):
     """Per-shard body: route → [n_mb x (dispatch a2a → local experts →
     combine a2a)] → shared expert.
@@ -310,7 +310,7 @@ def _moe_ep_local(
         ts, ks = slice(i * t_mb, (i + 1) * t_mb), slice(i * km, (i + 1) * km)
         y_i, d_i, dem_i = _dispatch_compute_combine(
             ht[ts], weights[ts], dest[ks], e_local[ks], valid_slot[ks], p,
-            cfg=cfg, W=W, C=C, axes=axes, E_loc=E_loc,
+            cfg=cfg, W=W, C=C, axes=axes, E_loc=E_loc, mesh=mesh,
         )
         ys.append(y_i)
         drops.append(d_i)

@@ -11,6 +11,8 @@ from __future__ import annotations
 import argparse
 import json
 import logging
+import os
+import time
 
 
 def parse_lora_adapters(spec: str | None) -> dict[str, tuple[int, str | None]]:
@@ -324,7 +326,9 @@ def build_parser() -> argparse.ArgumentParser:
     )
     p.add_argument(
         "--platform", default=None,
-        help="force a JAX platform (e.g. cpu for the sim backend)",
+        help="JAX platform to serve from. Default: the TPU, and the server "
+        "refuses to start when JAX finds none; cpu asks for the CPU on "
+        "purpose (tests, the sim backend)",
     )
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--kv-transfer-config", default=None, help="JSON, vLLM-style")
@@ -406,12 +410,11 @@ def main(argv=None) -> None:
     logging.basicConfig(level=logging.INFO)
     args = build_parser().parse_args(argv)
 
-    if args.platform:
-        # Must run before any jax import; env alone is overridden by site
-        # customization on some hosts, so set the config too.
-        import jax
+    from llmd_tpu import jaxrt
 
-        jax.config.update("jax_platforms", args.platform)
+    jaxrt.pin_platform(args.platform)
+    cache_dir = jaxrt.enable_compile_cache()
+    compiles = jaxrt.CompileCounters().install()
 
     from aiohttp import web
 
@@ -425,6 +428,11 @@ def main(argv=None) -> None:
         coordinator=args.distributed_coordinator,
         num_processes=args.distributed_num_processes,
         process_id=args.distributed_process_id,
+    )
+    device = jaxrt.serving_device(args.platform)
+    logging.info(
+        "serving from platform=%s device_kind=%s count=%d; compile cache %s",
+        device["platform"], device["kind"], device["count"], cache_dir,
     )
 
     adapter_specs = parse_lora_adapters(args.lora_adapters) or None
@@ -459,7 +467,9 @@ def main(argv=None) -> None:
             trace_file=args.trace_file,
             sample_ratio=args.trace_sample_ratio,
         )
+    t0 = time.monotonic()
     engine = LLMEngine(config, event_sink=event_sink)
+    startup = {"model_load_s": round(time.monotonic() - t0, 3)}
     if multihost and not dist.is_leader():
         # Worker rank of a multi-host deployment: no HTTP frontend — mirror
         # the leader's device dispatches until it broadcasts shutdown (the
@@ -495,8 +505,31 @@ def main(argv=None) -> None:
                 logging.info("loaded LoRA adapter %r from %s into slot %d",
                              name, path, slot)
     if not args.skip_warmup:
+        t0 = time.monotonic()
         n = engine.runner.warmup()
-        logging.info("warmup compiled %d programs", n)
+        startup["warmup_programs"] = n
+        startup["warmup_s"] = round(time.monotonic() - t0, 3)
+        logging.info(
+            "warmup compiled %d programs in %.1f s", n, startup["warmup_s"]
+        )
+    startup["compile"] = compiles.snapshot()
+
+    def runtime_report() -> dict:
+        return {
+            "device": device,
+            "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+            "device_files": jaxrt.held_device_files(),
+            "pallas_mode": os.environ.get("LLMD_PALLAS", "auto"),
+            "kernel_plans": {
+                op: sorted(plans)
+                for op, plans in list(engine.runner.kernel_plans.items())
+            },
+            "startup": startup,
+            "compile": compiles.snapshot(),
+            "compile_cache_dir": cache_dir,
+            "peak_bytes_in_use": jaxrt.peak_bytes_in_use(),
+        }
+
     tokenizer = load_tokenizer(config.tokenizer_path)
     app = build_app(
         AsyncEngine(engine),
@@ -504,6 +537,7 @@ def main(argv=None) -> None:
         args.served_model_name or args.model,
         config.model.max_model_len,
         lora_adapters=lora_adapters,
+        runtime_report=runtime_report,
     )
 
     async def _close_engine(app):

@@ -54,6 +54,9 @@ MAXLEN_KEY = web.AppKey("llmd_max_model_len", int)
 MM_SESSION_KEY = web.AppKey("llmd_mm_session", object)
 # adapter name -> slot id (1-based; the base model is slot 0)
 LORA_KEY = web.AppKey("llmd_lora_adapters", dict)
+# () -> dict of what the process runs on (device, kernel plans, compile
+# counters); the entry point supplies it, /admin/status reports it.
+RUNTIME_KEY = web.AppKey("llmd_runtime_report", object)
 
 _EC_HOST_RE = re.compile(r"[A-Za-z0-9_.\-]{1,253}:\d{1,5}")
 _EC_DIGEST_RE = re.compile(r"[0-9a-f]{16,64}")
@@ -1319,11 +1322,13 @@ async def handle_admin_status(request: web.Request) -> web.Response:
         return denied
     engine = request.app[ENGINE_KEY]
     stats = engine.stats
+    runtime = request.app.get(RUNTIME_KEY)
     return web.json_response(
         {
             "paused": engine.paused,
             "running": stats.num_running,
             "waiting": stats.num_waiting,
+            **(runtime() if runtime is not None else {}),
         }
     )
 
@@ -1442,9 +1447,12 @@ def build_app(
     max_model_len: int,
     extra_routes: list | None = None,
     lora_adapters: dict[str, int] | None = None,
+    runtime_report=None,
 ) -> web.Application:
     app = web.Application()
     app[ENGINE_KEY] = engine
+    if runtime_report is not None:
+        app[RUNTIME_KEY] = runtime_report
     app[TOK_KEY] = tokenizer
     app[MODEL_KEY] = model_name
     app[MAXLEN_KEY] = max_model_len

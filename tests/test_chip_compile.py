@@ -1,0 +1,213 @@
+"""Lower the main-path Pallas kernels with the TPU's own compiler, for a v5e
+that is described and not attached (on-chip-measurement guide, section 2.3).
+
+Interpret mode cannot see what Mosaic refuses — a DMA off the sublane
+tiling, a vector type the chip lacks, a page table larger than SMEM — so
+every kernel the serving step calls is compiled here at the real widths of
+the models it serves. Nothing runs: a pass says the chip's compiler accepts
+the kernel, never that its result is right (chip_smoke.py checks that on the
+chip). Run this file alone; the cases take a second or two each.
+"""
+
+import functools
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from llmd_tpu.ops.grouped_gemm import grouped_matmul
+from llmd_tpu.ops.kv_write import (
+    write_kv_pages_decode_full,
+    write_kv_pages_flat_full,
+)
+from llmd_tpu.ops.mla_decode import mla_decode_paged_attention_full
+from llmd_tpu.ops.ragged_paged_attention import (
+    decode_paged_attention_full,
+    flat_paged_attention_full,
+)
+
+BF16, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
+PAGE, PAGES = 16, 2048
+# (layers, q heads, kv heads, head dim): llama-3.2-3b and qwen3-30b-a3b.
+LLAMA = (28, 24, 8, 128)
+QWEN3 = (48, 32, 4, 128)
+# Serving defaults: 64 sequences, a 2048-token step, 8192-token contexts:
+# 96 flat rows x 512 pages, and up to 2064 tokens a step.
+ROWS, SEQS, MAX_PAGES = 96, 64, 512
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    # llmd: allow(broad-except) -- whatever keeps the TPU compiler from describing a chip here (no libtpu, no plugin) means these cases cannot run: skip, with the reason
+    except Exception as e:
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    return topo.devices[0]
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep it off around these."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _pool(model, dtype):
+    L, _, K, D = model
+    return ((L, PAGES, K, PAGE, 2 * D), dtype)
+
+
+def _scales(model):
+    L, _, K, _ = model
+    return ((L, PAGES, K, PAGE, 2), F32)
+
+
+def _flat_attention(model, dtype, T):
+    _, H, _, D = model
+    args = [
+        ((T, 1, H, D), BF16), _pool(model, dtype), ((), I32), ((T,), I32),
+        ((ROWS, MAX_PAGES), I32), ((T,), I32),
+    ]
+    if dtype == I8:
+        return (
+            lambda q, kv, l, r, pt, kl, sc: flat_paged_attention_full(
+                q, kv, l, r, pt, kl, scales=sc
+            ),
+            args + [_scales(model)],
+        )
+    return flat_paged_attention_full, args
+
+
+def _decode_attention(model, dtype):
+    _, H, _, D = model
+    args = [
+        ((SEQS, 1, H, D), BF16), _pool(model, dtype), ((), I32),
+        ((SEQS, MAX_PAGES), I32), ((SEQS,), I32),
+    ]
+    if dtype == I8:
+        return (
+            lambda q, kv, l, pt, kl, sc: decode_paged_attention_full(
+                q, kv, l, pt, kl, scales=sc
+            ),
+            args + [_scales(model)],
+        )
+    return decode_paged_attention_full, args
+
+
+def _flat_write(model, dtype, T):
+    _, _, K, D = model
+    runs = 2 * ROWS + -(-T // PAGE)  # the runner's bound on runs a step
+    return write_kv_pages_flat_full, [
+        _pool(model, dtype), ((T, K, 2 * D), dtype), ((), I32),
+        *[((runs,), I32)] * 4,
+    ]
+
+
+def _decode_write(model, dtype):
+    _, _, K, D = model
+    return write_kv_pages_decode_full, [
+        _pool(model, dtype), ((SEQS, K, 2 * D), dtype), ((), I32),
+        *[((SEQS,), I32)] * 3,
+    ]
+
+
+def _mla_decode():
+    # deepseek-v2-lite: 16 heads over a 512 + 64 latent padded to 640 lanes.
+    H, Dl, rank = 16, 640, 512
+    return (
+        functools.partial(
+            mla_decode_paged_attention_full, rank=rank, sm_scale=0.1
+        ),
+        [
+            ((SEQS, 1, H, Dl), BF16), ((27, PAGES, 1, PAGE, Dl), BF16),
+            ((), I32), ((SEQS, MAX_PAGES), I32), ((SEQS,), I32),
+        ],
+    )
+
+
+def _gmm(hidden, ffn, experts, device):
+    # The grouped GEMM picks megablox from the devices of the mesh it is
+    # given: hand it the described chip (the default backend is the CPU).
+    mesh = Mesh(np.asarray([device]).reshape(1, 1), ("dp", "tp"))
+    return (
+        lambda x, w, g: grouped_matmul(x, w, g, mesh),
+        [((2048, hidden), BF16), ((experts, hidden, ffn), BF16),
+         ((experts,), I32)],
+    )
+
+
+CASES = {
+    "flat_attention-bf16": lambda d: _flat_attention(LLAMA, BF16, 2064),
+    "flat_attention-int8": lambda d: _flat_attention(LLAMA, I8, 256),
+    "flat_attention-qwen3-30b-a3b": lambda d: _flat_attention(QWEN3, BF16, 256),
+    "flat_write-bf16": lambda d: _flat_write(LLAMA, BF16, 2064),
+    "flat_write-int8": lambda d: _flat_write(LLAMA, I8, 256),
+    "flat_write-qwen3-30b-a3b": lambda d: _flat_write(QWEN3, BF16, 256),
+    "decode_attention-bf16": lambda d: _decode_attention(LLAMA, BF16),
+    "decode_attention-int8": lambda d: _decode_attention(LLAMA, I8),
+    "decode_write-bf16": lambda d: _decode_write(LLAMA, BF16),
+    "decode_write-int8": lambda d: _decode_write(LLAMA, I8),
+    "mla_decode-deepseek-v2-lite": lambda d: _mla_decode(),
+    "gmm-deepseek-v2-lite": lambda d: _gmm(2048, 1408, 64, d),
+    "gmm-qwen3-30b-a3b": lambda d: _gmm(2048, 768, 128, d),
+}
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_kernel_compiles_for_v5e(v5e, case):
+    fn, shapes = CASES[case](v5e)
+    on_chip = SingleDeviceSharding(v5e)
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+        for shape, dtype in shapes
+    ]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_page_table_at_the_smem_bound_compiles(v5e):
+    """``ops.page_table_smem`` refuses at start-up what this compiler would
+    refuse at the first request: it knows the described chip by its
+    ``device_kind``, and the largest table it lets through compiles."""
+    from llmd_tpu import ops
+    from llmd_tpu.config import ModelConfig
+
+    L, H, K, D = LLAMA
+    cfg = ModelConfig(num_heads=H, num_kv_heads=K, head_dim=D)
+    mesh = Mesh(np.asarray([v5e]).reshape(1, 1), ("dp", "tp"))
+    rows, tokens = 120, 2064
+
+    def smem(rows):
+        return ops.page_table_smem(
+            cfg, PAGE, 2048, 1, mesh, decode_rows=SEQS, flat_rows=rows,
+            flat_tokens=tokens,
+        )
+
+    need, have = smem(rows)
+    assert need <= have < smem(rows + 8)[0]
+    on_chip = SingleDeviceSharding(v5e)
+    shapes = [
+        ((tokens, 1, H, D), BF16), _pool(LLAMA, BF16), ((), I32),
+        ((tokens,), I32), ((rows, 2048), I32), ((tokens,), I32),
+    ]
+    jax.jit(flat_paged_attention_full).lower(*[
+        jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+        for shape, dtype in shapes
+    ]).compile()

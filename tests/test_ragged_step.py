@@ -392,7 +392,11 @@ def _flat_layout(rng, page=8, rows=((3, 5), (9, 1), (0, 11))):
     return T, tok_rows, positions, live, runs
 
 
-def test_flat_write_kernel_matches_xla_scatter():
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
+def test_flat_write_kernel_matches_xla_scatter(dtype):
+    """Byte-identical to the XLA scatter for every pool dtype: the kernel
+    fetches each run's tile-aligned window (8, 16 or 32 rows by dtype) and
+    rotates it into place as 32-bit values, which must lose nothing."""
     import jax.numpy as jnp
 
     from llmd_tpu.ops.kv_write import write_kv_pages_flat_full
@@ -400,9 +404,13 @@ def test_flat_write_kernel_matches_xla_scatter():
 
     rng = np.random.default_rng(0)
     L, P, K, page, D = 2, 24, 2, 8, 128
-    cache = jnp.asarray(
-        rng.normal(size=(L, P, K, page, 2 * D)).astype(np.float32)
-    )
+
+    def values(shape):
+        if dtype == "int8":
+            return jnp.asarray(rng.integers(-127, 128, shape, dtype=np.int8))
+        return jnp.asarray(rng.normal(size=shape).astype(np.float32), dtype)
+
+    cache = values((L, P, K, page, 2 * D))
     # row 1 straddles pages (pos0=3, qlen=11 crosses two page boundaries)
     T, tok_rows, positions, live, runs = _flat_layout(
         rng, page=page, rows=((3, 11), (17, 1), (0, 5))
@@ -414,22 +422,28 @@ def test_flat_write_kernel_matches_xla_scatter():
     )
     off = np.asarray(runs[2] + [0], np.int32)
     cnt = np.asarray(runs[3] + [0], np.int32)  # trailing pad run
-    kv_new = rng.normal(size=(T, K, 2 * D)).astype(np.float32)
+    kv_new = values((T, K, 2 * D))
     out = write_kv_pages_flat_full(
-        cache, jnp.asarray(kv_new), jnp.int32(1), jnp.asarray(src),
+        cache, kv_new, jnp.int32(1), jnp.asarray(src),
         jnp.asarray(phys), jnp.asarray(off), jnp.asarray(cnt),
         interpret=True,
     )
     oracle = write_kv_pages(
         cache[1],
-        jnp.asarray(kv_new[:, None, :, :D]),
-        jnp.asarray(kv_new[:, None, :, D:]),
+        kv_new[:, None, :, :D],
+        kv_new[:, None, :, D:],
         jnp.asarray(pt[tok_rows]),
         jnp.asarray(positions[:, None]),
         jnp.asarray(live[:, None]),
     )
-    np.testing.assert_allclose(np.asarray(out[1]), np.asarray(oracle))
-    np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(cache[0]))
+
+    def bits(a):
+        a = np.asarray(a)
+        return a.view(f"u{a.dtype.itemsize}")
+
+    np.testing.assert_array_equal(bits(out[1]), bits(oracle))
+    np.testing.assert_array_equal(bits(out[0]), bits(cache[0]))
+    assert not np.array_equal(bits(oracle), bits(cache[1]))
 
 
 def test_flat_attention_kernel_matches_xla():
@@ -524,10 +538,61 @@ def test_flat_forward_dispatches_kernels(monkeypatch):
     eng = make_engine(True, page=8, head_dim=128)
     out = eng.generate([PROMPTS[0]], GREEDY)
     assert calls["attn"] > 0 and calls["write"] > 0
-    ref = make_engine(False, page=8, head_dim=128).generate(
-        [PROMPTS[0]], GREEDY
-    )
+    # ... and the runner recorded the plan its programs took (what the
+    # server reports on /admin/status and chip_smoke.py checks).
+    assert eng.runner.kernel_plans == {
+        "flat_attention": {"pallas"}, "flat_kv_write": {"pallas"},
+    }
+    ref_eng = make_engine(False, page=8, head_dim=128)
+    ref = ref_eng.generate([PROMPTS[0]], GREEDY)
     assert _toks(out) == _toks(ref)
+    # The bucketed engine's prefill rows are wider than the kernels'
+    # Q == 1: the record says why those programs took XLA.
+    for op in ("attention", "kv_write"):
+        assert "xla:geometry" in ref_eng.runner.kernel_plans[op]
+
+
+def test_page_table_larger_than_smem_is_refused_at_startup(monkeypatch):
+    """The attention kernels scalar-prefetch the whole page table into
+    SMEM. Where the kernels are active on a device whose SMEM is known, a
+    geometry whose table cannot fit is refused when the runner is built,
+    naming the flags that size it — not by the chip's compiler at the
+    first request (v5e, libtpu 0.0.34: "RESOURCE_EXHAUSTED ... Used 1.04M
+    of 1.00M smem"). The bound is the v5e's and only the v5e's."""
+    import jax
+
+    from llmd_tpu import ops
+
+    monkeypatch.setenv("LLMD_PALLAS", "interpret")
+    kw = dict(page=16, head_dim=128, max_seqs=64, max_batched=2048)
+    # 96 flat rows x 8192 pages x 4 B = 3 MiB: the HF default context.
+    # Interpreted on this host there is no SMEM to overflow: it starts.
+    cfg = make_engine(True, max_model_len=131072, **kw).runner.cfg
+    # Lend this host's device kind the v5e's megabyte.
+    v5e = ops._SMEM_BYTES["TPU v5 lite"]
+    monkeypatch.setitem(ops._SMEM_BYTES, jax.devices()[0].device_kind, v5e)
+    with pytest.raises(ValueError, match=r"--max-model-len.*--max-num-seqs"):
+        make_engine(True, max_model_len=131072, **kw)
+
+    def smem(max_pages, mesh=None, **rows):
+        world = 1 if mesh is None else mesh.devices.size
+        return ops.page_table_smem(cfg, 16, max_pages, world, mesh, **rows)
+
+    flat = dict(decode_rows=64, flat_rows=96, flat_tokens=2064)
+    need, have = smem(8192, **flat)
+    assert have == v5e and need > 3 << 20
+    need, have = smem(2048, **flat)  # --max-model-len 32768 fits
+    assert 96 * 2048 * 4 < need <= have
+    # A decode table splits over dp with the batch, so a device holds its
+    # shard's rows; the flat step's compact table stays whole.
+    mesh = jax.make_mesh((2, 2), ("dp", "tp"), devices=jax.devices()[:4])
+    whole, _ = smem(2048, decode_rows=64)
+    shard, _ = smem(2048, mesh, decode_rows=64)
+    assert 64 * 2048 * 4 < whole and 32 * 2048 * 4 < shard < 64 * 2048 * 4
+    assert 96 * 2048 * 4 < smem(2048, mesh, **flat)[0] <= need
+    # With the kernels off nothing is prefetched, so nothing bounds it.
+    monkeypatch.setenv("LLMD_PALLAS", "off")
+    assert smem(8192, **flat) is None
 
 
 # --------------------------------------------------------------------- #

@@ -580,3 +580,59 @@ def test_deliver_is_atomic_against_same_id_reregistration():
     finally:
         inst._fetch_pool.shutdown(wait=False, cancel_futures=True)
         loop.close()
+
+
+# --------------------------------------------------------------------- #
+# what the process runs on (llmd_tpu/jaxrt.py; serve/__main__.py wires it)
+
+
+def test_serving_device_refuses_anything_but_a_tpu_unless_asked():
+    """No silent CPU fallback: without --platform the server serves from a
+    TPU or exits; --platform cpu is the explicit request (this suite's)."""
+    from llmd_tpu import jaxrt
+
+    info = jaxrt.serving_device("cpu")
+    assert info["platform"] == "cpu" and info["count"] >= 1
+    assert set(info) == {"platform", "kind", "count"}
+    with pytest.raises(SystemExit, match="not a TPU.*--platform cpu"):
+        jaxrt.serving_device(None)
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: used as is, no directory set in code.
+    Unset: one fixed path inside the checkout."""
+    import jax
+
+    from llmd_tpu import jaxrt
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(jaxrt.COMPILE_CACHE_ENV, "/somewhere/else")
+        assert jaxrt.enable_compile_cache() == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv(jaxrt.COMPILE_CACHE_ENV)
+        fixed = jaxrt.enable_compile_cache()
+        assert fixed == str(jaxrt.DEFAULT_COMPILE_CACHE_DIR)
+        assert fixed == jaxrt.enable_compile_cache()  # no pid, no timestamp
+        assert jax.config.jax_compilation_cache_dir == fixed
+    finally:  # tests stay without a cache
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+async def test_admin_status_reports_the_runtime():
+    """/admin/status carries what the entry point says the process runs on
+    (device, kernel plans, compile counters) beside the engine's state."""
+    engine = make_engine()
+    report = {
+        "device": {"platform": "cpu", "kind": "cpu", "count": 1},
+        "kernel_plans": {"flat_attention": ["xla:geometry"]},
+    }
+    app = build_app(
+        AsyncEngine(engine), ByteTokenizer(), "tiny", 128,
+        runtime_report=lambda: report,
+    )
+    async with TestClient(TestServer(app)) as c:
+        status = await (await c.get("/admin/status")).json()
+    assert status["device"] == report["device"]
+    assert status["kernel_plans"] == report["kernel_plans"]
+    assert status["paused"] is False and status["running"] == 0

@@ -475,6 +475,31 @@ def test_dp_start_rank_validation():
     assert [r.port for r in sup.ranks] == [9300, 9301]
 
 
+def test_dp_ranks_each_get_their_own_chip():
+    """One process per chip: each of several local ranks is told of one
+    chip no other rank is, a libtpu runtime port of its own, and a
+    one-chip slice — otherwise each opens every chip and the first wins."""
+    from llmd_tpu.serve.dp_supervisor import _chip_env
+
+    envs = [_chip_env(i, 4, {}) for i in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+    for e in envs:
+        assert e["TPU_PROCESS_BOUNDS"] == e["TPU_HOST_BOUNDS"] == "1,1,1"
+        assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["TPU_PROCESS_ADDRESSES"].endswith(":" + e["TPU_PROCESS_PORT"])
+    # The chips an operator placed are the ones handed out ...
+    placed = {"TPU_VISIBLE_CHIPS": "2,3"}
+    assert [
+        _chip_env(i, 2, placed)["TPU_VISIBLE_CHIPS"] for i in range(2)
+    ] == ["2", "3"]
+    with pytest.raises(ValueError, match="2 chips for 4 local ranks"):
+        _chip_env(0, 4, placed)
+    # ... and a single rank is left its environment: nobody to keep apart,
+    # and its --tensor-parallel-size may want every chip there is.
+    assert _chip_env(0, 1, placed) == {} == _chip_env(0, 1, {})
+
+
 @pytest.mark.anyio
 async def test_dp_supervisor_spawns_and_restarts():
     """Two trivially-fast rank processes; kill one; supervisor restarts it."""
