@@ -54,7 +54,7 @@ SERVING_ONLY = frozenset({
     "skip_warmup", "advertised_address", "data_parallel_rank",
     "distributed_coordinator", "distributed_num_processes",
     "distributed_process_id", "otlp_traces_endpoint", "trace_file",
-    "trace_sample_ratio",
+    "trace_sample_ratio", "profile_dir",
 })
 
 

@@ -41,6 +41,7 @@ from llmd_tpu.engine.runner import (
     StepResult,
 )
 from llmd_tpu.engine.scheduler import EngineScheduler, ScheduledBatch
+from llmd_tpu.obs import profiling
 from llmd_tpu.parallel.mesh import MeshContext, build_mesh
 
 
@@ -247,6 +248,37 @@ class EngineStats:
     engine_steps_total: int = 0
     step_host_gap_ms: float = 0.0
     step_host_gap_ms_total: float = 0.0
+    # Where a step's time goes, by phase (running sums over the steps
+    # counted in engine_steps_total; unrounded, rounded where exported).
+    # The phases are the spans of obs/profiling.py by the same names:
+    # admit (parked KV streams, cold adapter loads, the pager's pump),
+    # schedule, launch (host arrays built and the program handed to the
+    # device), wait (the device and the one readback), finish (collect,
+    # scheduler update, outputs, offload flush). In sync mode schedule +
+    # launch + finish IS step_host_gap_ms_total; in async mode schedule
+    # and most of launch overlap the device and the gap stays the
+    # post-readback part. step_ms_total is the whole of step().
+    step_admit_ms_total: float = 0.0
+    step_schedule_ms_total: float = 0.0
+    step_launch_ms_total: float = 0.0
+    step_wait_ms_total: float = 0.0
+    step_finish_ms_total: float = 0.0
+    step_ms_total: float = 0.0
+    # Steps by what they carried (prefill chunks only, decode rows only,
+    # both) and their whole-step time: a mean step time hides that a
+    # step with a long prefill chunk is another thing than a decode step.
+    steps_prefill_total: int = 0
+    steps_decode_total: int = 0
+    steps_mixed_total: int = 0
+    step_ms_decode_total: float = 0.0
+    step_ms_prefill_total: float = 0.0  # prefill or mixed
+    # Queue wait: at a request's FIRST scheduling, now - arrival_time
+    # (a re-admission after preemption is not counted again).
+    queue_wait_ms_total: float = 0.0
+    queue_admitted_total: int = 0
+    # Step programs traced (runner.traced_programs names them): a trace
+    # after warm-up is a shape nobody warmed, seconds inside a step.
+    programs_traced_total: int = 0
     # Speculative rows invalidated by a late finish/abort at reconcile
     # (EOS / stop token / max-tokens landed after the next batch was
     # staged against the optimistic one-token-per-decode assumption).
@@ -363,7 +395,6 @@ class _InflightStep:
     batch: ScheduledBatch
     pending_prefill: PendingPrefill | None
     pending_decode: PendingDecode | None
-    dispatch_time: float
     pending_unified: PendingUnified | None = None
 
 
@@ -642,6 +673,10 @@ class LLMEngine:
             )
             self._async = False
         self._inflight: _InflightStep | None = None
+        # (kind, rows, tokens) of the batch this call of step() finished
+        # (async, first call of a pipeline: dispatched), for the llmd.step
+        # span and the by-kind sums.
+        self._step_carried: tuple[str, int, int] = ("empty", 0, 0)
         # Aborts that arrived while their request was in flight: freeing
         # the pages immediately would hand them to another sequence while
         # the device still writes them — applied at the reconcile point.
@@ -1375,24 +1410,52 @@ class LLMEngine:
         # notice, 503 /health and terminate in-flight streams. Unarmed
         # this is one module-global None check.
         faults.delay("engine.step.stall")
-        if self._kv_parked:
-            self._admit_kv_streams()
-        if self._lora_parked:
-            self._admit_cold_loads()
-        if self.pager is not None:
-            # Restore parked attention windows before scheduling — a
-            # still-pending fetch leaves the request fetch-pending (a
-            # wait state the scheduler skips, not a fault).
-            self.pager.pump(self.scheduler.waiting)
-        outputs = self._step_async() if self._async else self._step_sync()
+        t_in = time.monotonic()
+        counted = self.stats.engine_steps_total
+        self._step_carried = ("empty", 0, 0)
+        with profiling.span("llmd.step") as step_span:
+            with profiling.span("llmd.step.admit"):
+                if self._kv_parked:
+                    self._admit_kv_streams()
+                if self._lora_parked:
+                    self._admit_cold_loads()
+                if self.pager is not None:
+                    # Restore parked attention windows before scheduling —
+                    # a still-pending fetch leaves the request fetch-pending
+                    # (a wait state the scheduler skips, not a fault).
+                    self.pager.pump(self.scheduler.waiting)
+            t_admitted = time.monotonic()
+            outputs = self._step_async() if self._async else self._step_sync()
+            kind, rows, tokens = self._step_carried
+            if rows:
+                step_span.set_metadata(
+                    kind=kind, rows=rows, tokens=tokens,
+                    program=self.runner.last_program,
+                )
+            else:
+                step_span.set_metadata(kind=kind)
+            if self.stats.engine_steps_total != counted:
+                self._count_step(kind, t_in, t_admitted)
         if self._lora_failed_outputs:
             outputs = [*self._lora_failed_outputs, *outputs]
             self._lora_failed_outputs = []
         return outputs
 
+    def _count_step(self, kind: str, t_in: float, t_admitted: float) -> None:
+        """The whole-step sums of a step that ran a batch (the phase sums
+        were added by ``_finish_step``)."""
+        st = self.stats
+        step_ms = (time.monotonic() - t_in) * 1e3
+        st.step_admit_ms_total += (t_admitted - t_in) * 1e3
+        st.step_ms_total += step_ms
+        if kind == "decode":
+            st.step_ms_decode_total += step_ms
+        else:
+            st.step_ms_prefill_total += step_ms
+
     def _step_sync(self) -> list[RequestOutput]:
         t0 = time.monotonic()
-        batch: ScheduledBatch = self.scheduler.schedule()
+        batch = self._schedule_spanned()
         if batch.is_empty:
             return []
         now = time.monotonic()
@@ -1414,17 +1477,20 @@ class LLMEngine:
             )
         )
         pend_p = pend_d = pend_u = None
-        if not eager_ack and self._unified_eligible(batch):
-            pend_u = self._dispatch_unified(batch, None)
-        else:
-            if batch.prefills:
-                pend_p = self.runner.dispatch_prefill(batch.prefills)
-                self.stats.step_dispatches_total += len(pend_p.entries)
-                for seq in batch.prefills:
-                    self.stats.prompt_tokens += seq.num_tokens
-            if batch.decodes:
-                pend_d = self._dispatch_decodes(batch.decodes, batch.spec_window)
-        self.scheduler.note_dispatch(batch)
+        with profiling.span("llmd.runner.launch"):
+            if not eager_ack and self._unified_eligible(batch):
+                pend_u = self._dispatch_unified(batch, None)
+            else:
+                if batch.prefills:
+                    pend_p = self.runner.dispatch_prefill(batch.prefills)
+                    self.stats.step_dispatches_total += len(pend_p.entries)
+                    for seq in batch.prefills:
+                        self.stats.prompt_tokens += seq.num_tokens
+                if batch.decodes:
+                    pend_d = self._dispatch_decodes(
+                        batch.decodes, batch.spec_window
+                    )
+            self.scheduler.note_dispatch(batch)
         t_dispatched = time.monotonic()
         # One coalesced readback for the whole step (prefill bucket
         # groups + the decode window — or the one unified program —
@@ -1433,16 +1499,25 @@ class LLMEngine:
             None if eager_ack else pend_p, pend_d, pend_u
         )
         t_read = time.monotonic()
-        sampled, logprobs = self._collect(batch, pres, dres)
-        accepted = self.scheduler.update_after_step(batch, sampled)
-        outputs = self._assemble_outputs(batch, accepted, logprobs, now)
-        if self.offloader is not None:
-            # One bucketed HBM->host gather for the step's committed pages.
-            self.offloader.flush()
-        if self.pager is not None:
-            # Spill pages that fell below the window + prefetch horizon.
-            self.pager.tick(self.scheduler.running)
-        self._finish_step((t_dispatched - t0) + (time.monotonic() - t_read))
+        with profiling.span("llmd.step.finish") as finish_span:
+            sampled, logprobs = self._collect(batch, pres, dres)
+            accepted = self.scheduler.update_after_step(batch, sampled)
+            outputs = self._assemble_outputs(batch, accepted, logprobs)
+            if self.offloader is not None:
+                # One bucketed HBM->host gather for the step's committed
+                # pages.
+                self.offloader.flush()
+            if self.pager is not None:
+                # Spill pages that fell below the window + prefetch
+                # horizon.
+                self.pager.tick(self.scheduler.running)
+            finish_span.set_metadata(outputs=len(outputs))
+        finish_s = time.monotonic() - t_read
+        self._finish_step(
+            batch, (t_dispatched - t0) + finish_s,
+            schedule_s=now - t0, launch_s=t_dispatched - now,
+            wait_s=t_read - t_dispatched, finish_s=finish_s,
+        )
         return outputs
 
     def _step_async(self) -> list[RequestOutput]:
@@ -1457,13 +1532,16 @@ class LLMEngine:
         (docs/architecture/async-scheduling.md)."""
         inflight = self._inflight
         if inflight is None:
-            batch = self.scheduler.schedule()
+            batch = self._schedule_spanned()
             if batch.is_empty:
                 return []
             self._dispatch_async(batch)
+            self._step_carried = self._carried(batch)
             return []  # pipeline is one step deep: tokens land next call
         # ---- overlapped host region: the device is executing N ----
-        staged = self.scheduler.schedule()  # speculative: pending counts
+        t0 = time.monotonic()
+        staged = self._schedule_spanned()  # speculative: pending counts
+        t_sched = time.monotonic()
         staged_dec: (
             StagedDecode | StagedVerify | StagedVerifyWindow
             | StagedUnified | None
@@ -1493,6 +1571,7 @@ class LLMEngine:
                     staged.decodes, k_steps=staged.decodes[0].num_tokens
                 )
         # ---- block on step N's single coalesced readback ----
+        t_staged = time.monotonic()
         pres, dres = self.runner.wait_step(
             inflight.pending_prefill, inflight.pending_decode,
             inflight.pending_unified,
@@ -1549,24 +1628,46 @@ class LLMEngine:
         if staged.is_empty and rolled and self.scheduler.has_work():
             # The whole slot was invalidated; the freed pages/budget may
             # admit different work now that nothing is pending.
-            staged = self.scheduler.schedule()
+            staged = self._schedule_spanned()
             staged_dec = None
+        t_reconciled = time.monotonic()
         if not staged.is_empty:
             self._dispatch_async(staged, staged_dec)
         # Device idle ends at the re-dispatch above; output assembly and
         # gauge refresh below overlap step N+1's execution.
-        host_gap = time.monotonic() - t_read
-        outputs = self._assemble_outputs(
-            inflight.batch, accepted, logprobs, inflight.dispatch_time
+        t_redispatched = time.monotonic()
+        host_gap = t_redispatched - t_read
+        with profiling.span("llmd.step.finish") as finish_span:
+            outputs = self._assemble_outputs(
+                inflight.batch, accepted, logprobs
+            )
+            if self.offloader is not None:
+                self.offloader.flush()
+            if self.pager is not None:
+                # Protected (in-flight) rows are skipped inside the tick,
+                # so the staged batch's page tables stay valid.
+                self.pager.tick(self.scheduler.running)
+            finish_span.set_metadata(outputs=len(outputs))
+        # The phases of THIS call, by the sync step's names: the staged
+        # schedule and prestaging ran while the device executed step N;
+        # collect/update/reconcile count as finish with the assembly.
+        self._finish_step(
+            inflight.batch, host_gap,
+            schedule_s=t_sched - t0,
+            launch_s=(t_staged - t_sched) + (t_redispatched - t_reconciled),
+            wait_s=t_read - t_staged,
+            finish_s=(t_reconciled - t_read)
+            + (time.monotonic() - t_redispatched),
         )
-        if self.offloader is not None:
-            self.offloader.flush()
-        if self.pager is not None:
-            # Protected (in-flight) rows are skipped inside the tick, so
-            # the staged batch's page tables stay valid.
-            self.pager.tick(self.scheduler.running)
-        self._finish_step(host_gap)
         return outputs
+
+    def _schedule_spanned(self) -> ScheduledBatch:
+        with profiling.span("llmd.sched.schedule") as sched_span:
+            batch = self.scheduler.schedule()
+            sched_span.set_metadata(
+                prefills=len(batch.prefills), decodes=len(batch.decodes)
+            )
+        return batch
 
     def _dispatch_async(
         self,
@@ -1576,27 +1677,28 @@ class LLMEngine:
             | StagedUnified | None
         ) = None,
     ) -> None:
-        now = time.monotonic()
         pend_p = pend_d = pend_u = None
-        if self._unified_eligible(batch):
-            pend_u = self._dispatch_unified(
-                batch,
-                staged_dec if isinstance(staged_dec, StagedUnified) else None,
-            )
-        else:
-            if batch.prefills:
-                pend_p = self.runner.dispatch_prefill(batch.prefills)
-                self.stats.step_dispatches_total += len(pend_p.entries)
-                for seq in batch.prefills:
-                    self.stats.prompt_tokens += seq.num_tokens
-            if batch.decodes:
-                pend_d = self._dispatch_decodes(
-                    batch.decodes, batch.spec_window,
-                    None if isinstance(staged_dec, StagedUnified)
-                    else staged_dec,
+        with profiling.span("llmd.runner.launch"):
+            if self._unified_eligible(batch):
+                pend_u = self._dispatch_unified(
+                    batch,
+                    staged_dec if isinstance(staged_dec, StagedUnified)
+                    else None,
                 )
-        self.scheduler.note_dispatch(batch)
-        self._inflight = _InflightStep(batch, pend_p, pend_d, now, pend_u)
+            else:
+                if batch.prefills:
+                    pend_p = self.runner.dispatch_prefill(batch.prefills)
+                    self.stats.step_dispatches_total += len(pend_p.entries)
+                    for seq in batch.prefills:
+                        self.stats.prompt_tokens += seq.num_tokens
+                if batch.decodes:
+                    pend_d = self._dispatch_decodes(
+                        batch.decodes, batch.spec_window,
+                        None if isinstance(staged_dec, StagedUnified)
+                        else staged_dec,
+                    )
+            self.scheduler.note_dispatch(batch)
+        self._inflight = _InflightStep(batch, pend_p, pend_d, pend_u)
 
     def _unified_eligible(self, batch: ScheduledBatch) -> bool:
         """Does this batch ride the unified single-dispatch program?
@@ -1856,10 +1958,10 @@ class LLMEngine:
         batch: ScheduledBatch,
         accepted: dict[str, list[int]],
         logprobs: dict[str, list[float]],
-        now: float,
     ) -> list[RequestOutput]:
         outputs: list[RequestOutput] = []
         finished = 0
+        now = time.monotonic()  # the step's tokens are on the host
         for seq in batch.seqs:
             req = seq.request
             new_tokens = accepted.get(req.request_id)
@@ -1883,18 +1985,45 @@ class LLMEngine:
                     num_output_tokens=req.total_output_tokens,
                     num_cached_tokens=req.num_cached_tokens,
                     kv_transfer_params=req.export_params,
+                    queue_wait_ms=req.queue_wait_ms,
+                    ttft_ms=(req.first_token_time - req.arrival_time) * 1e3,
                 )
             )
         self.stats.requests_finished += finished
         return outputs
 
-    def _finish_step(self, host_gap_s: float) -> None:
+    @staticmethod
+    def _carried(batch: ScheduledBatch) -> tuple[str, int, int]:
+        """(kind, rows, tokens) of a batch that is not empty."""
+        if batch.prefills and batch.decodes:
+            kind = "mixed"
+        else:
+            kind = "prefill" if batch.prefills else "decode"
+        return kind, len(batch.seqs), batch.total_tokens
+
+    def _finish_step(
+        self,
+        batch: ScheduledBatch,
+        host_gap_s: float,
+        schedule_s: float,
+        launch_s: float,
+        wait_s: float,
+        finish_s: float,
+    ) -> None:
+        """Count one step that ran ``batch``: the host gap, the phase
+        sums and the step kind (``step()`` adds the whole-step sums)."""
+        st = self.stats
         gap_ms = host_gap_s * 1e3
-        self.stats.engine_steps_total += 1
-        self.stats.step_host_gap_ms = round(gap_ms, 3)
-        self.stats.step_host_gap_ms_total = round(
-            self.stats.step_host_gap_ms_total + gap_ms, 3
-        )
+        st.engine_steps_total += 1
+        st.step_host_gap_ms = round(gap_ms, 3)
+        st.step_host_gap_ms_total += gap_ms
+        st.step_schedule_ms_total += schedule_s * 1e3
+        st.step_launch_ms_total += launch_s * 1e3
+        st.step_wait_ms_total += wait_s * 1e3
+        st.step_finish_ms_total += finish_s * 1e3
+        self._step_carried = self._carried(batch)
+        by_kind = f"steps_{self._step_carried[0]}_total"
+        setattr(st, by_kind, getattr(st, by_kind) + 1)
         self._moe_tick()
         self._refresh_gauges()
 
@@ -1958,6 +2087,9 @@ class LLMEngine:
                 self.stats.swa_section_captures = s["captures"]
         self.stats.prefix_hit_ratio = self.allocator.hit_ratio()
         self.stats.preemptions = self.scheduler.num_preemptions
+        self.stats.queue_wait_ms_total = self.scheduler.queue_wait_ms
+        self.stats.queue_admitted_total = self.scheduler.queue_admitted
+        self.stats.programs_traced_total = self.runner.programs_traced
         self.stats.batch_backlog_jobs = sum(
             1 for r in self.scheduler.waiting if r.is_batch
         )

@@ -143,6 +143,10 @@ class Request:
     # prompt without changing the token sequence).
     spec_gram_state: Any = None
     finish_reason: FinishReason | None = None
+    # ms between arrival and the FIRST admission by the scheduler; kept
+    # across preemption (EngineStats.queue_wait_ms_total, and the
+    # llm_d.queue_wait_ms attribute of the request's OTLP span).
+    queue_wait_ms: float | None = None
     first_token_time: float | None = None
     finish_time: float | None = None
     # Per-step sampled logprob of each output token (if requested).
@@ -212,3 +216,7 @@ class RequestOutput:
     num_output_tokens: int
     num_cached_tokens: int = 0
     kv_transfer_params: dict[str, Any] | None = None
+    # From the request's own timestamps, once known: arrival to first
+    # admission, and arrival to the step that produced the first token.
+    queue_wait_ms: float | None = None
+    ttft_ms: float | None = None

@@ -31,6 +31,7 @@ axis, donated through every step so XLA updates it in place.
 
 from __future__ import annotations
 
+import collections
 import concurrent.futures
 import functools
 import logging
@@ -54,6 +55,7 @@ from llmd_tpu.engine.sampler import (
 from llmd_tpu.engine.scheduler import ScheduledSeq
 from llmd_tpu.models import llama
 from llmd_tpu.models.common import StepInput
+from llmd_tpu.obs import profiling
 from llmd_tpu.parallel import distributed as dist
 from llmd_tpu.parallel.mesh import MeshContext, kv_cache_spec, shard_params
 
@@ -490,6 +492,15 @@ class ModelRunner:
         )
         # op -> the kernel plans its traces took (ops.record_plans).
         self.kernel_plans: dict[str, set[str]] = {}
+        # Which step programs were traced, and when: (unix time, family,
+        # [B, Q] — flat: [T, 1]; the windows: [B, tokens a row]). A trace
+        # after warm-up is a shape nobody warmed: seconds inside a step.
+        self.traced_programs: collections.deque = collections.deque(maxlen=256)
+        self.programs_traced = 0
+        self._tracing = ""  # the program being traced (_note_traced)
+        # "family:shape" of the step program dispatched last (the
+        # llmd.step span's ``program``).
+        self.last_program = ""
         self._build_programs()
         self._check_page_table_fits_smem()
         # Padding-efficiency accounting (EngineStats padded/live tokens):
@@ -905,9 +916,12 @@ class ModelRunner:
             kw["kv_swa"] = kv_swa
         if census is not None:
             kw["moe_census"] = census
-        # Runs at trace time only: every program this runner compiles
-        # notes here which kernel plan each of its ops took.
-        with ops.record_plans(self.kernel_plans):
+        # Runs at trace time only: the model's forward being traced, as
+        # a span of the program ``_note_traced`` named, and every op
+        # noting which kernel plan it took.
+        with profiling.span(
+            "llmd.runner.trace", program=self._tracing
+        ), ops.record_plans(self.kernel_plans):
             out = llama.forward_hidden(
                 params, kv_cache, inp, cfg, self.ctx.world,
                 mesh=self.ctx.mesh, moe_backend=moe_backend,
@@ -925,6 +939,15 @@ class ModelRunner:
             kv_swa = out[2]
         return hidden, kv_cache, kv_swa, census
 
+    def _note_traced(self, family: str, shape) -> None:
+        """First line of every jitted step program's body, so it runs
+        once per trace of the program (a new shape, or a rebuilt family):
+        counts it and logs (unix time, family, shape)."""
+        shape = tuple(int(d) for d in shape)
+        self.traced_programs.append((time.time(), family, shape))
+        self.programs_traced += 1
+        self._tracing = f"{family}:{shape}"
+
     def _build_forward(self, cp: int = 0):
         """The prefill/one-shot-step program. ``cp`` > 1 builds the
         context-parallel ring variant (ops/ring_attention.py): same call
@@ -940,8 +963,12 @@ class ModelRunner:
             donate_argnums=(1, 2) if ring else (1,),
             static_argnames=("all_greedy",),
         )
-        def fwd(params, kv_cache, kv_swa, inp: StepInput, s: SamplingInputs,
-                census=None, all_greedy=False):
+        def llmd_prefill_step(params, kv_cache, kv_swa, inp: StepInput,
+                              s: SamplingInputs, census=None,
+                              all_greedy=False):
+            self._note_traced(
+                "prefill_cp" if cp else "prefill", inp.token_ids.shape
+            )
             hidden, kv_cache, kv_swa, census = self._fwd_hidden(
                 params, kv_cache, kv_swa, inp, census, dbo=dbo, cp=cp
             )
@@ -956,7 +983,7 @@ class ModelRunner:
             )
             return kv_cache, kv_swa, replicate(packed), census
 
-        return fwd
+        return llmd_prefill_step
 
     def _build_verify(self):
         """Speculative verify: the prefill forward over [B, 1+k] rows
@@ -978,8 +1005,10 @@ class ModelRunner:
             donate_argnums=(1, 2) if ring else (1,),
             static_argnames=("all_greedy",),
         )
-        def verify(params, kv_cache, kv_swa, inp: StepInput, s: SamplingInputs,
-                   census=None, all_greedy=False):
+        def llmd_verify_step(params, kv_cache, kv_swa, inp: StepInput,
+                             s: SamplingInputs, census=None,
+                             all_greedy=False):
+            self._note_traced("verify", inp.token_ids.shape)
             hidden, kv_cache, kv_swa, census = self._fwd_hidden(
                 params, kv_cache, kv_swa, inp, census, dbo=dbo
             )
@@ -1003,7 +1032,7 @@ class ModelRunner:
             )
             return kv_cache, kv_swa, replicate(packed), census
 
-        return verify
+        return llmd_verify_step
 
     def _build_unified(self):
         """Unified single-dispatch step: ONE ragged program for an entire
@@ -1036,7 +1065,7 @@ class ModelRunner:
             donate_argnums=(1, 2) if ring else (1,),
             static_argnames=("Q", "all_greedy"),
         )
-        def unified(
+        def llmd_unified_step(
             params,
             kv_cache,
             kv_swa,  # ring pool (None unless swa_ring)
@@ -1058,6 +1087,7 @@ class ModelRunner:
             all_greedy: bool = False,
         ):
             B = row_start.shape[0]
+            self._note_traced("unified", (B, Q))
             cols = jnp.arange(Q)
             gidx = jnp.clip(
                 row_start[:, None] + cols[None, :], 0, stream.shape[0] - 1
@@ -1112,7 +1142,7 @@ class ModelRunner:
             )  # [B, 2S]
             return kv_cache, kv_swa, replicate(packed), census
 
-        return unified
+        return llmd_unified_step
 
     def _build_flat(self):
         """Genuinely ragged flattened-token step (`cu_q_lens`): the SAME
@@ -1142,7 +1172,7 @@ class ModelRunner:
             donate_argnums=(1, 2) if ring else (1,),
             static_argnames=("all_greedy",),
         )
-        def flat(
+        def llmd_flat_step(
             params,
             kv_cache,
             kv_swa,  # ring pool (None unless swa_ring)
@@ -1168,6 +1198,7 @@ class ModelRunner:
         ):
             T = stream.shape[0]
             B = row_start.shape[0]
+            self._note_traced("flat", (T, 1))
             t = jnp.arange(T)
             ends = row_start + qlens  # non-decreasing (pad rows = total)
             row_of = jnp.clip(
@@ -1222,7 +1253,7 @@ class ModelRunner:
             )  # [B, 2S]
             return kv_cache, kv_swa, replicate(packed), census
 
-        return flat
+        return llmd_flat_step
 
     def _build_verify_window(self):
         """Fused verify window: ``window`` verify iterations in ONE jit
@@ -1256,7 +1287,7 @@ class ModelRunner:
             donate_argnums=(1, 2) if ring else (1,),
             static_argnames=("window", "all_greedy"),
         )
-        def verify_window(
+        def llmd_verify_window(
             params,
             kv_cache,
             kv_swa,  # ring pool (None unless swa_ring)
@@ -1281,6 +1312,7 @@ class ModelRunner:
             all_greedy: bool = False,
         ):
             B = first_token.shape[0]
+            self._note_traced("verify_window", (B, window * Q))
             Wmax = window * Q
             qcols = jnp.arange(Q)
             dcols = jnp.arange(k)
@@ -1405,7 +1437,7 @@ class ModelRunner:
             )  # [B, 4 + 2*Wmax]
             return kv_cache, kv_swa, replicate(packed), census
 
-        return verify_window
+        return llmd_verify_window
 
     def _build_multi(self):
         cfg = self.cfg
@@ -1418,7 +1450,7 @@ class ModelRunner:
             donate_argnums=(1, 2) if ring else (1,),
             static_argnames=("k_steps", "all_greedy"),
         )
-        def multi(
+        def llmd_decode_window(
             params,
             kv_cache,
             kv_swa,  # ring pool (None unless swa_ring)
@@ -1437,6 +1469,7 @@ class ModelRunner:
             all_greedy: bool = False,
         ):
             B = first_token.shape[0]
+            self._note_traced("decode_window", (B, k_steps))
 
             def body(i, carry):
                 kv_cache, kv_swa, census, tok, out_t, out_l = carry
@@ -1478,7 +1511,7 @@ class ModelRunner:
             )  # [B, 2K]
             return kv_cache, kv_swa, replicate(packed), census
 
-        return multi
+        return llmd_decode_window
 
     # ------------------------------------------------------------------ #
     # multi-host KV staging programs (lockstep-dispatched on all procs)
@@ -2899,6 +2932,15 @@ class ModelRunner:
         res, _ = self.wait_step(pending, None)
         return res
 
+    def _dispatching(self, family: str, **shape):
+        """The ``llmd.runner.dispatch`` span of one step program's
+        hand-over to the device (lockstep broadcast + the jitted call
+        returning); notes the program as ``last_program``."""
+        self.last_program = family + ":" + ",".join(
+            f"{k}={v}" for k, v in shape.items()
+        )
+        return profiling.span("llmd.runner.dispatch", program=self.last_program)
+
     def dispatch_prefill(self, seqs: list[ScheduledSeq]) -> PendingPrefill:
         """Enqueue all scheduled prompt chunks, batched by Q bucket; no
         host readback (that is ``wait_step``'s single coalesced fetch).
@@ -2951,7 +2993,7 @@ class ModelRunner:
         self.live_tokens_total += live
         self.padded_tokens_total += B * Q - live
         all_greedy = all(s.request.sampling.greedy for s in seqs)
-        with self._dispatch_lock:
+        with self._dispatch_lock, self._dispatching("prefill", B=B, Q=Q):
             arrays = self._sync_locked(_OP_PREFILL, B, Q, all_greedy, arrays)
             return self._exec_prefill(arrays, all_greedy)
 
@@ -2967,6 +3009,7 @@ class ModelRunner:
         """Stage + enqueue the decode program; no host readback."""
         return self.dispatch_staged_decode(self.stage_decode(seqs, k_steps))
 
+    @profiling.spanned("llmd.runner.build")
     def stage_decode(
         self, seqs: list[ScheduledSeq], k_steps: int = 1
     ) -> StagedDecode:
@@ -3024,7 +3067,9 @@ class ModelRunner:
         n = len(staged.seqs)
         self.live_tokens_total += n * staged.k
         self.padded_tokens_total += (staged.B - n) * staged.k
-        with self._dispatch_lock:
+        with self._dispatch_lock, self._dispatching(
+            "decode_window", B=staged.B, K=staged.k
+        ):
             arrays = self._sync_locked(
                 _OP_DECODE, staged.B, staged.k, staged.all_greedy,
                 staged.arrays,
@@ -3034,6 +3079,7 @@ class ModelRunner:
             [(packed, list(range(n)), staged.k, 0)], n, staged.k
         )
 
+    @profiling.spanned("llmd.runner.build")
     def stage_spec_verify(self, seqs: list[ScheduledSeq]) -> StagedVerify:
         """Build the verify dispatch's host arrays AHEAD of the previous
         step's readback (async stepping). The page/ring tables are final
@@ -3100,7 +3146,9 @@ class ModelRunner:
         live = int(qlens.sum())
         self.live_tokens_total += live
         self.padded_tokens_total += staged.B * staged.q - live
-        with self._dispatch_lock:
+        with self._dispatch_lock, self._dispatching(
+            "verify", B=staged.B, Q=staged.q
+        ):
             arrays = self._sync_locked(
                 _OP_VERIFY, staged.B, staged.q, staged.all_greedy,
                 staged.arrays,
@@ -3237,6 +3285,7 @@ class ModelRunner:
             entries.append((pd.entries[0][0], plain, 1, 0))
         return PendingDecode(entries, len(seqs), self.spec_q)
 
+    @profiling.spanned("llmd.runner.build")
     def stage_unified(
         self, prefills: list[ScheduledSeq], decodes: list[ScheduledSeq]
     ) -> StagedUnified:
@@ -3328,6 +3377,36 @@ class ModelRunner:
         so hot sampling is reproducible within a mode, not across the
         unified/split switch — the same contract as spec on/off.)"""
         a = staged.arrays
+        self._fill_unified(staged)
+        if staged.flat:
+            with self._dispatch_lock, self._dispatching("flat", T=staged.T):
+                arrays = self._sync_locked(
+                    _OP_FLAT, staged.B, staged.T, staged.all_greedy, a
+                )
+                packed = self._exec_flat(arrays, staged.all_greedy)
+        else:
+            with self._dispatch_lock, self._dispatching(
+                "unified", B=staged.B, Q=staged.Q, T=staged.T
+            ):
+                arrays = self._sync_locked(
+                    _OP_UNIFIED, staged.B, (staged.Q << 20) | staged.T,
+                    staged.all_greedy, a,
+                )
+                packed = self._exec_unified(
+                    arrays, staged.Q, staged.all_greedy
+                )
+        return PendingUnified(
+            packed, staged.S, list(staged.prefill_rows),
+            list(staged.decode_rows), len(staged.prefills),
+            len(staged.decodes),
+        )
+
+    @profiling.spanned("llmd.runner.build")
+    def _fill_unified(self, staged: StagedUnified) -> None:
+        """The host half of ``dispatch_staged_unified``: the packed
+        stream, the per-row metadata, the seeds and (flat) the KV-write
+        runs, into ``staged.arrays``."""
+        a = staged.arrays
         stream, row_start = a["stream"], a["row_start"]
         pos0, qlens, kvlens = a["pos0"], a["qlens"], a["kvlens"]
         kind = a["kind"]
@@ -3372,26 +3451,8 @@ class ModelRunner:
             row_start[len(staged.row_seqs):] = t
             self._fill_flat_runs(staged, a)
             self.padded_tokens_total += staged.T - t
-            with self._dispatch_lock:
-                arrays = self._sync_locked(
-                    _OP_FLAT, staged.B, staged.T, staged.all_greedy, a
-                )
-                packed = self._exec_flat(arrays, staged.all_greedy)
         else:
             self.padded_tokens_total += staged.B * staged.Q - t
-            with self._dispatch_lock:
-                arrays = self._sync_locked(
-                    _OP_UNIFIED, staged.B, (staged.Q << 20) | staged.T,
-                    staged.all_greedy, a,
-                )
-                packed = self._exec_unified(
-                    arrays, staged.Q, staged.all_greedy
-                )
-        return PendingUnified(
-            packed, staged.S, list(staged.prefill_rows),
-            list(staged.decode_rows), len(staged.prefills),
-            len(staged.decodes),
-        )
 
     def _fill_flat_runs(self, staged: StagedUnified, a: dict) -> None:
         """Host half of the flat KV-write plan: walk each row's token
@@ -3506,6 +3567,7 @@ class ModelRunner:
             pad_to_bucket(s.num_tokens, self.prefill_buckets) for s in seqs
         })
 
+    @profiling.spanned("llmd.runner.build")
     def stage_spec_verify_window(
         self, seqs: list[ScheduledSeq], window: int
     ) -> StagedVerifyWindow:
@@ -3585,7 +3647,9 @@ class ModelRunner:
         self.padded_tokens_total += (
             (staged.B - n) * staged.window * staged.q
         )
-        with self._dispatch_lock:
+        with self._dispatch_lock, self._dispatching(
+            "verify_window", B=staged.B, W=staged.window, Q=staged.q
+        ):
             arrays = self._sync_locked(
                 _OP_VERIFY_WINDOW, staged.B, staged.window,
                 staged.all_greedy, arrays,
@@ -3596,6 +3660,7 @@ class ModelRunner:
         wmax = staged.window * staged.q
         return PendingDecode([(packed, list(range(n)), wmax, 4)], n, wmax)
 
+    @profiling.spanned("llmd.runner.wait")
     def wait_step(
         self,
         prefill: PendingPrefill | None,

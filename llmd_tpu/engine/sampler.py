@@ -29,6 +29,7 @@ class SamplingInputs:
     seeds: jax.Array  # [B] u32
 
 
+@jax.named_scope("llmd.sampler")
 def sample_tokens(
     logits: jax.Array, s: SamplingInputs, all_greedy: bool = False
 ) -> tuple[jax.Array, jax.Array]:

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import time
 
 from llmd_tpu.config import CacheConfig, SchedulerConfig
 from llmd_tpu.engine.kv_cache import (
@@ -110,6 +111,11 @@ class EngineScheduler:
         self.waiting: list[Request] = []
         self.running: list[Request] = []
         self.num_preemptions = 0
+        # Queue wait, taken at a request's FIRST admission (one that
+        # comes back from preemption is not counted again): the sums
+        # behind EngineStats.queue_wait_ms_total / queue_admitted_total.
+        self.queue_wait_ms = 0.0
+        self.queue_admitted = 0
         # request_id -> committed page hash chain tail + count
         self._chain: dict[str, tuple[bytes, int]] = {}
         # Called with the finished Request before its pages are released
@@ -433,7 +439,7 @@ class EngineScheduler:
                     req.swa_table_row = None
                 break  # out of pages; retry next step
             self.waiting.pop(0)
-            req.status = RequestStatus.RUNNING
+            self._note_admitted(req)
             self.running.append(req)
             prefills.append(ScheduledSeq(req, chunk))
             scheduled.add(req.request_id)
@@ -455,6 +461,13 @@ class EngineScheduler:
         return ScheduledBatch(
             prefills=prefills, decodes=decodes, spec_window=spec_w
         )
+
+    def _note_admitted(self, req: Request) -> None:
+        req.status = RequestStatus.RUNNING
+        if req.queue_wait_ms is None:
+            req.queue_wait_ms = (time.monotonic() - req.arrival_time) * 1e3
+            self.queue_wait_ms += req.queue_wait_ms
+            self.queue_admitted += 1
 
     @property
     def _batch_band(self) -> bool:
@@ -574,7 +587,7 @@ class EngineScheduler:
                     req.swa_table_row = None
                 break  # out of pages; retry next step
             self.waiting.pop(0)
-            req.status = RequestStatus.RUNNING
+            self._note_admitted(req)
             self.running.append(req)
             prefills.append(ScheduledSeq(req, chunk))
             scheduled.add(req.request_id)

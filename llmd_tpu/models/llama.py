@@ -591,6 +591,7 @@ def forward_hidden(
     return out
 
 
+@jax.named_scope("llmd.lm_head")
 def compute_logits(params: dict, hidden: jax.Array, cfg: ModelConfig) -> jax.Array:
     """Project hidden states [N, H] -> logits [N, V] (f32 for sampling)."""
     if cfg.tie_word_embeddings:

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from llmd_tpu.config import ModelConfig
 
 
+@jax.named_scope("llmd.moe.router")
 def router_topk(
     h: jax.Array,
     w_router: jax.Array,
@@ -86,6 +87,7 @@ def router_topk(
     return weights * cfg.routed_scaling_factor, ids
 
 
+@jax.named_scope("llmd.moe.shared")
 def shared_expert_ffn(ht: jax.Array, lp: dict) -> jax.Array:
     """DeepSeek/Qwen2-MoE always-on shared expert (one place, three
     backends: dense / grouped / EP)."""

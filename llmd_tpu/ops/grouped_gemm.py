@@ -35,6 +35,7 @@ def _use_megablox(H: int, F: int, mesh=None) -> bool:
     ) != "xla"
 
 
+@jax.named_scope("llmd.moe.gmm")
 def grouped_matmul(
     x: jax.Array,            # [T, K_dim] tokens sorted by group
     w: jax.Array,            # [G, K_dim, N]

@@ -393,6 +393,12 @@ def build_parser() -> argparse.ArgumentParser:
     )
     p.add_argument("--trace-file", default=None, help="JSONL span log path")
     p.add_argument("--trace-sample-ratio", type=float, default=0.1)
+    p.add_argument(
+        "--profile-dir", default=None,
+        help="directory POST /start_profile writes JAX profiler traces "
+        "under (the device's operations and the engine's phase spans on "
+        "one clock); unset, the two profile endpoints answer 409",
+    )
     # Multi-host: join a jax.distributed world (reference LWS leader/worker
     # shape, --data-parallel-address $LWS_LEADER_ADDRESS; here the env
     # contract LLMD_COORDINATOR/LWS_LEADER_ADDRESS + LWS_GROUP_SIZE +
@@ -524,6 +530,10 @@ def main(argv=None) -> None:
                 op: sorted(plans)
                 for op, plans in list(engine.runner.kernel_plans.items())
             },
+            "traced_programs": [
+                {"time": t, "family": family, "shape": list(shape)}
+                for t, family, shape in list(engine.runner.traced_programs)
+            ],
             "startup": startup,
             "compile": compiles.snapshot(),
             "compile_cache_dir": cache_dir,
@@ -538,6 +548,7 @@ def main(argv=None) -> None:
         config.model.max_model_len,
         lora_adapters=lora_adapters,
         runtime_report=runtime_report,
+        profile_dir=args.profile_dir,
     )
 
     async def _close_engine(app):

@@ -35,6 +35,7 @@ from llmd_tpu.epp.types import (
     HDR_RESUME,
     HDR_STREAM_TOKENS,
 )
+from llmd_tpu.obs import profiling
 from llmd_tpu.obs.tracing import get_tracer
 from llmd_tpu.serve import protocol as P
 from llmd_tpu.serve.async_engine import (
@@ -57,6 +58,8 @@ LORA_KEY = web.AppKey("llmd_lora_adapters", dict)
 # () -> dict of what the process runs on (device, kernel plans, compile
 # counters); the entry point supplies it, /admin/status reports it.
 RUNTIME_KEY = web.AppKey("llmd_runtime_report", object)
+# --profile-dir: where POST /start_profile writes; None = profiling off.
+PROFILE_DIR_KEY = web.AppKey("llmd_profile_dir", object)
 
 _EC_HOST_RE = re.compile(r"[A-Za-z0-9_.\-]{1,253}:\d{1,5}")
 _EC_DIGEST_RE = re.compile(r"[0-9a-f]{16,64}")
@@ -527,6 +530,7 @@ async def _stream_response(
     finish = None
     n_out = resume_output_tokens
     cached = 0
+    out = None
     try:
         async for out in engine.generate(rid, prompt_ids, sampling, priority,
                                          kv_transfer_params, lora_id, lora_name,
@@ -579,6 +583,7 @@ async def _stream_response(
     if span is not None:
         span.set("gen_ai.usage.completion_tokens", n_out)
         span.set("llm_d.cache.hit_tokens", cached)
+        _set_engine_timing(span, out)
     final = (
         P.chat_chunk(rid, model, {}, finish)
         if chat
@@ -591,6 +596,14 @@ async def _stream_response(
     await resp.write(b"data: [DONE]\n\n")
     await resp.write_eof()
     return resp
+
+
+def _set_engine_timing(span, out: RequestOutput | None) -> None:
+    """The request's own timestamps, as the engine reports them on its
+    outputs: arrival to first admission, arrival to first token."""
+    if out is not None and out.queue_wait_ms is not None:
+        span.set("llm_d.queue_wait_ms", round(out.queue_wait_ms, 3))
+        span.set("llm_d.ttft_ms", round(out.ttft_ms, 3))
 
 
 async def _stream_response_multi(
@@ -1019,6 +1032,7 @@ async def _handle_generate(request: web.Request, chat: bool) -> web.StreamRespon
     completion_tokens = sum(f.num_output_tokens for _, _, f in choices if f)
     span.set("gen_ai.usage.completion_tokens", completion_tokens)
     span.set("llm_d.cache.hit_tokens", final.num_cached_tokens if final else 0)
+    _set_engine_timing(span, final)
     span.end()
     usage = P.usage_dict(
         len(prompt_ids),
@@ -1334,6 +1348,37 @@ async def handle_admin_status(request: web.Request) -> web.Response:
 
 
 # --------------------------------------------------------------------- #
+# Profiler control (vLLM's endpoint names): the same obs.profiling calls
+# the benchmark makes, in the process that holds the chip. Admin surface:
+# a trace slows the host and writes to the server's disk.
+
+
+async def handle_start_profile(request: web.Request) -> web.Response:
+    denied = _admin_denied(request)
+    if denied is not None:
+        return denied
+    trace_dir = request.app.get(PROFILE_DIR_KEY)
+    if not trace_dir:
+        return _error(409, "profiling is off: start the server with --profile-dir")
+    try:
+        await asyncio.to_thread(profiling.start, trace_dir)
+    except profiling.ProfilerBusy as e:
+        return _error(409, str(e))
+    return web.json_response({"profiling": True, "trace_dir": trace_dir})
+
+
+async def handle_stop_profile(request: web.Request) -> web.Response:
+    denied = _admin_denied(request)
+    if denied is not None:
+        return denied
+    try:
+        trace_dir = await asyncio.to_thread(profiling.stop)
+    except profiling.ProfilerBusy as e:
+        return _error(409, str(e))
+    return web.json_response({"profiling": False, "trace_dir": trace_dir})
+
+
+# --------------------------------------------------------------------- #
 # Runtime adapter load/unload (the vLLM dynamic-LoRA contract;
 # docs/architecture/multi-tenant-lora.md). Registration is unbounded —
 # the paged pool bounds HBM residency, not the servable set. Loads are
@@ -1448,9 +1493,11 @@ def build_app(
     extra_routes: list | None = None,
     lora_adapters: dict[str, int] | None = None,
     runtime_report=None,
+    profile_dir: str | None = None,
 ) -> web.Application:
     app = web.Application()
     app[ENGINE_KEY] = engine
+    app[PROFILE_DIR_KEY] = profile_dir
     if runtime_report is not None:
         app[RUNTIME_KEY] = runtime_report
     app[TOK_KEY] = tokenizer
@@ -1478,6 +1525,8 @@ def build_app(
             web.post("/v1/load_lora_adapter", handle_load_lora_adapter),
             web.post("/v1/unload_lora_adapter", handle_unload_lora_adapter),
             *_responses_routes(),
+            web.post("/start_profile", handle_start_profile),
+            web.post("/stop_profile", handle_stop_profile),
             web.post("/admin/pause", handle_admin_pause),
             web.post("/admin/resume", handle_admin_resume),
             web.post("/admin/drain", handle_admin_drain),

@@ -20,6 +20,7 @@ from typing import Any, AsyncIterator
 
 from llmd_tpu.engine.engine import LLMEngine
 from llmd_tpu.engine.request import RequestOutput, SamplingParams
+from llmd_tpu.obs import profiling
 
 log = logging.getLogger(__name__)
 
@@ -516,25 +517,27 @@ class AsyncEngine:
                     return
                 pending, self._inbox = self._inbox, []
                 aborts, self._aborts = self._aborts, []
-            for rid in aborts:
-                self.engine.abort_request(rid)
-            for p in pending:
-                try:
-                    self.engine.add_request(
-                        p.prompt_token_ids,
-                        p.sampling,
-                        request_id=p.request_id,
-                        priority=p.priority,
-                        kv_transfer_params=p.kv_transfer_params,
-                        lora_id=p.lora_id,
-                        lora_name=p.lora_name,
-                        resume_output_tokens=p.resume_output_tokens,
-                    )
-                # llmd: allow(broad-except) -- surfaced: the caller receives it as a RequestFailed terminal item
-                except Exception as e:  # validation errors -> caller
-                    _release_pulled(self.engine, p.kv_transfer_params)
-                    self._deliver(p.request_id, RequestFailed(str(e)))
-            if not self.engine.has_work():
+            with profiling.span("llmd.serve.intake", added=len(pending)):
+                for rid in aborts:
+                    self.engine.abort_request(rid)
+                for p in pending:
+                    try:
+                        self.engine.add_request(
+                            p.prompt_token_ids,
+                            p.sampling,
+                            request_id=p.request_id,
+                            priority=p.priority,
+                            kv_transfer_params=p.kv_transfer_params,
+                            lora_id=p.lora_id,
+                            lora_name=p.lora_name,
+                            resume_output_tokens=p.resume_output_tokens,
+                        )
+                    # llmd: allow(broad-except) -- surfaced: the caller receives it as a RequestFailed terminal item
+                    except Exception as e:  # validation errors -> caller
+                        _release_pulled(self.engine, p.kv_transfer_params)
+                        self._deliver(p.request_id, RequestFailed(str(e)))
+                idle = not self.engine.has_work()
+            if idle:
                 continue
             try:
                 # Watchdog heartbeat brackets the one blocking call.
@@ -553,5 +556,6 @@ class AsyncEngine:
                 self.last_step_done = time.monotonic()
                 self._steps_done += 1
                 self._stall_flagged = False
-            for out in outputs:
-                self._deliver(out.request_id, out)
+            with profiling.span("llmd.serve.deliver", outputs=len(outputs)):
+                for out in outputs:
+                    self._deliver(out.request_id, out)

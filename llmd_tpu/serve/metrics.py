@@ -118,6 +118,24 @@ def render_metrics(
         # Async stepping (speculate/rollback contract)
         "engine_steps_total": stats.engine_steps_total,
         "step_host_gap_ms_total": round(stats.step_host_gap_ms_total, 3),
+        # Where a step's time goes (EngineStats says what each phase
+        # holds; obs/profiling.py's spans carry the same names): running
+        # sums in ms over engine_steps_total, the steps by what they
+        # carried, queue wait at first admission, step programs traced.
+        "step_admit_ms_total": round(stats.step_admit_ms_total, 3),
+        "step_schedule_ms_total": round(stats.step_schedule_ms_total, 3),
+        "step_launch_ms_total": round(stats.step_launch_ms_total, 3),
+        "step_wait_ms_total": round(stats.step_wait_ms_total, 3),
+        "step_finish_ms_total": round(stats.step_finish_ms_total, 3),
+        "step_ms_total": round(stats.step_ms_total, 3),
+        "steps_prefill_total": stats.steps_prefill_total,
+        "steps_decode_total": stats.steps_decode_total,
+        "steps_mixed_total": stats.steps_mixed_total,
+        "step_ms_decode_total": round(stats.step_ms_decode_total, 3),
+        "step_ms_prefill_total": round(stats.step_ms_prefill_total, 3),
+        "queue_wait_ms_total": round(stats.queue_wait_ms_total, 3),
+        "queue_admitted_total": stats.queue_admitted_total,
+        "programs_traced_total": stats.programs_traced_total,
         "async_rollbacks_total": stats.async_rollbacks_total,
         "decode_dispatches_total": stats.decode_dispatches_total,
         # Unified single-dispatch steps (the family split of

@@ -278,6 +278,47 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                    "scheduling shrinks it to the reconcile sliver; a "
                    "regression here re-serializes the pipeline "
                    "(docs/architecture/async-scheduling.md)."),
+        panel("Step time by phase",
+              [f"rate(llmd:step_{ph}_ms_total{M}[5m]) / "
+               f"rate(llmd:engine_steps_total{M}[5m])"
+               for ph in ("admit", "schedule", "launch", "wait", "finish")]
+              + [f"rate(llmd:step_ms_total{M}[5m]) / "
+                 f"rate(llmd:engine_steps_total{M}[5m])"],
+              legends=["admit", "schedule", "launch", "wait (device + "
+                       "readback)", "finish", "whole step"], unit="ms",
+              desc="Mean ms a step spends in each phase (the spans of "
+                   "llmd_tpu/obs/profiling.py by the same names). In sync "
+                   "mode schedule + launch + finish is the host gap; "
+                   "whatever of the whole step the five do not cover is "
+                   "the engine's own bookkeeping after the step."),
+        panel("Step time by kind",
+              [f"rate(llmd:step_ms_decode_total{M}[5m]) / "
+               f"rate(llmd:steps_decode_total{M}[5m])",
+               f"rate(llmd:step_ms_prefill_total{M}[5m]) / "
+               f"(rate(llmd:steps_prefill_total{M}[5m]) + "
+               f"rate(llmd:steps_mixed_total{M}[5m]))",
+               f"(rate(llmd:steps_prefill_total{M}[5m]) + "
+               f"rate(llmd:steps_mixed_total{M}[5m])) / "
+               f"rate(llmd:engine_steps_total{M}[5m])"],
+              legends=["decode-only step (ms)", "step with prefill (ms)",
+                       "share of steps with prefill"],
+              desc="A decode row's gap between tokens is the decode step "
+                   "where no prompt is in the batch and the prefill step "
+                   "where one is: the share says how often."),
+        panel("Queue wait before first scheduling",
+              [f"rate(llmd:queue_wait_ms_total{M}[5m]) / "
+               f"rate(llmd:queue_admitted_total{M}[5m])"],
+              unit="ms",
+              desc="Mean ms between a request's arrival at the engine and "
+                   "its first admission by the scheduler: the part of the "
+                   "time to first token that is waiting, not computing."),
+        panel("Step programs traced /s",
+              [f"rate(llmd:programs_traced_total{M}[5m])"],
+              thresholds=[(None, "green"), (0.01, "red")],
+              desc="A step program traced after warm-up is a shape nobody "
+                   "warmed: seconds of trace, lowering and (on a cache "
+                   "miss) compile inside a serving step. /admin/status "
+                   "names the shapes."),
         panel("Engine steps /s", [f"rate(llmd:engine_steps_total{M}[5m])"],
               desc="Step cadence; flat at 0 while requests run = the "
                    "step loop is wedged."),

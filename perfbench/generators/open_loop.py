@@ -9,6 +9,11 @@ the tokens completed by 14 % and the 90th percentile of the time to first
 token by 40 %; so the order is part of the cell, not of the seed. A request
 is timed from when it was DUE.
 
+``run(system, rec, tail)``: with a ``tail`` (``--trace 2``) the generator,
+once the window is closed and its numbers are complete, calls ``await
+tail(offer)``, where ``offer(seconds)`` sends that much more of the same mix
+in yet another order (unmeasured records), and cuts everything when it returns.
+
 Mix parameters: ``prompt``, ``output`` (distributions, perfbench/stats.py),
 ``arrival_cv`` (1 = Poisson), ``warm_seconds``, ``drain_seconds``.
 Cell parameters: ``rate`` (requests a second); ``above_knee`` (true for a cell
@@ -57,23 +62,35 @@ class Generator:
         self.main = schedule(mix, cell["rate"], seconds, seed, vocab)
         self.warm_seconds, self.seconds = warm, seconds
         self.above_knee = bool(cell.get("above_knee", False))
+        self.rate, self.seed, self.vocab = cell["rate"], seed, vocab
 
-    async def run(self, system, rec) -> None:
-        tasks = []
+    async def run(self, system, rec, tail=None) -> None:
+        tasks: list = []
+
+        async def send(plan) -> None:
+            for due, prompt, out_tokens, measured in plan:
+                delay = due - time.monotonic()
+                if delay > 0:
+                    await asyncio.sleep(delay)
+                r = rec.new(due, len(prompt), out_tokens, measured)
+                tasks.append(asyncio.create_task(drive(system, rec, r, prompt, out_tokens)))
+
+        async def offer(seconds: float) -> None:
+            more = schedule(self.mix, self.rate, seconds, self.seed ^ 0x7A117A11, self.vocab, order=2)
+            start = time.monotonic()
+            await send([(start + d, p, o, False) for d, p, o in more])
+
         start = time.monotonic()
         rec.t0 = start + self.warm_seconds
         rec.t1 = rec.t0 + self.seconds
         plan = [(start + d, p, o, False) for d, p, o in self.warm]
         plan += [(rec.t0 + d, p, o, True) for d, p, o in self.main]
-        for due, prompt, out_tokens, measured in plan:
-            delay = due - time.monotonic()
-            if delay > 0:
-                await asyncio.sleep(delay)
-            r = rec.new(due, len(prompt), out_tokens, measured)
-            tasks.append(asyncio.create_task(drive(system, rec, r, prompt, out_tokens)))
+        await send(plan)
         delay = rec.t1 - time.monotonic()
         if delay > 0:
             await asyncio.sleep(delay)
         if not self.above_knee:
             await wait_first_tokens(rec, float(self.mix.get("drain_seconds", 10)))
+        if tail is not None:
+            await tail(offer)
         await cancel_all(tasks)

@@ -41,7 +41,7 @@ class Generator:
             if r.error:
                 return
 
-    async def run(self, system, rec) -> None:
+    async def run(self, system, rec, tail=None) -> None:
         tasks, records = [], []
         for p in self.prompts:
             first: list = []
@@ -55,4 +55,6 @@ class Generator:
         rec.t0 = time.monotonic()
         rec.t1 = rec.t0 + self.seconds
         await asyncio.sleep(self.seconds)
+        if tail is not None:  # --trace 2: the sequences simply keep decoding
+            await tail(None)
         await cancel_all(tasks)

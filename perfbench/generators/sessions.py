@@ -78,10 +78,12 @@ class Generator:
                     break
                 context += answer
 
-    async def run(self, system, rec) -> None:
+    async def run(self, system, rec, tail=None) -> None:
         start = time.monotonic()
         rec.t0 = start + self.warm_seconds
         rec.t1 = rec.t0 + self.seconds
         tasks = [asyncio.create_task(self._client(system, rec)) for _ in range(self.clients)]
         await asyncio.sleep(max(0.0, rec.t1 - time.monotonic()))
+        if tail is not None:  # --trace 2: the clients simply keep going
+            await tail(None)
         await cancel_all(tasks)

@@ -1,6 +1,6 @@
 """One process, one cell, one run:
 
-    python3 perfbench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+    python3 perfbench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1|2>
 
 Finds everything by the names in ``BENCHMARK.json``: the cell's parameters in
 ``perfbench/cells/<workload>.json``, its configuration's file, its traffic mix
@@ -12,8 +12,16 @@ each metric's definition in ``perfbench/end_to_end/`` or
 Prints one JSON object as the last line of its output. ``--trace 0`` reports
 the cell's end-to-end metrics with the profiler off; ``--trace 1`` reports its
 per-layer metrics, with host steps recorded and a profiler trace over the
-last seconds of the window. Off the chip it fails, unless ``--rehearse`` (the
-builder's CPU rehearsal at a tiny size: exit code 3, no device metric).
+last seconds of the window. ``--trace 2`` is a ``--trace 0`` run followed by a
+short traced tail in the same process: up to the moment the window is closed
+it does what ``--trace 0`` does and takes the end-to-end numbers from there;
+then the same traffic goes on, the profiler is started and stopped once for
+nothing (its first start costs more), and TRACE_SECONDS are traced through the
+program's own control (``llmd_tpu/obs/profiling.py``). Its line holds both
+kinds of metric: counters are deltas over the MEASURED window, trace metrics,
+the step time and the breakdown come from the tail. Off the chip a run fails,
+unless ``--rehearse`` (the builder's CPU rehearsal at a tiny size: exit code
+3, no device metric).
 """
 
 from __future__ import annotations
@@ -35,6 +43,13 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 OUT_DIR = ROOT / "chiprun_out" / "perfbench"
 TRACE_SECONDS = 2.5
+# --trace 2: the traced slice starts this long after the window was closed,
+# under the same traffic (an open-loop cell has just waited for its last first
+# tokens with no new arrivals; the profiler's first start and stop, 0.3-1.4 s,
+# fall at the head of it), and this much more open-loop traffic is drawn (the
+# tail ends when the trace is written, well before it runs out).
+TAIL_SETTLE_SECONDS = 3.5
+TAIL_OFFER_SECONDS = 40.0
 
 
 def load(workload: str, root: pathlib.Path = ROOT) -> types.SimpleNamespace:
@@ -69,12 +84,51 @@ def published(conf: dict) -> dict:
     return {k: v for k, v in conf.items() if k not in own}
 
 
-async def _measure(system, gen, rec, traced: bool, trace_dir: pathlib.Path) -> dict:
+def window_numbers(rec) -> dict:
+    """What a run reports of the closed window, from the recorder."""
+    attempted = rec.attempted()
+    return {
+        "series": rec.series(), "attempted": len(attempted),
+        "failed": sum(1 for r in attempted if rec.failed(r)),
+    }
+
+
+async def _measure(system, gen, rec, mode: int, trace_dir: pathlib.Path) -> dict:
     """Serve, run the generator, and mark the window: counters at its start
-    and end, the profiler over its last TRACE_SECONDS in a traced run."""
+    and end; the profiler over its last TRACE_SECONDS (``mode`` 1) or over
+    TRACE_SECONDS of a tail that follows the closed window (``mode`` 2)."""
     import jax
 
     marks: dict = {}
+    traced = mode == 1
+
+    async def tail(offer) -> None:
+        """Called by the generator when the window is closed and complete."""
+        marks["closed"] = window_numbers(rec)
+        t_closed = time.monotonic()
+        system.time_steps()
+        more = asyncio.create_task(offer(TAIL_OFFER_SECONDS)) if offer else None
+        discard = trace_dir.with_name(trace_dir.name + ".discard")
+        try:
+            # The first start costs more, and not the same in every run: it
+            # is paid at once and thrown away, and the traced slice starts at
+            # a fixed offset into the tail's traffic whatever it cost.
+            await asyncio.to_thread(system.trace_start, str(discard))
+            await asyncio.to_thread(system.trace_stop)
+            marks["first_start_stop_s"] = time.monotonic() - t_closed
+            await asyncio.sleep(max(0.0, t_closed + TAIL_SETTLE_SECONDS - time.monotonic()))
+            await asyncio.to_thread(system.trace_start, str(trace_dir))
+            marks["trace_on"] = time.monotonic()
+            await asyncio.sleep(TRACE_SECONDS)
+            marks["trace_off"] = time.monotonic()
+            await asyncio.to_thread(system.trace_stop)
+            marks["trace_stop_s"] = time.monotonic() - marks["trace_off"]
+        finally:
+            shutil.rmtree(discard, ignore_errors=True)
+            if more is not None:
+                marks["tail_offer_ran_out"] = more.done()
+                more.cancel()
+                await asyncio.gather(more, return_exceptions=True)
 
     async def watch() -> None:
         while rec.t0 is None or time.monotonic() < rec.t0:
@@ -95,7 +149,7 @@ async def _measure(system, gen, rec, traced: bool, trace_dir: pathlib.Path) -> d
     system.start(record_steps=traced)
     try:
         watcher = asyncio.create_task(watch())
-        await gen.run(system, rec)
+        await gen.run(system, rec, tail if mode == 2 else None)
         await watcher
     finally:
         system.pause()
@@ -131,13 +185,13 @@ def set_up(system, spec, args) -> dict:
     return check
 
 
-def run_window(system, spec, mix, cell, seed, seconds, traced, trace_dir):
+def run_window(system, spec, mix, cell, seed, seconds, mode, trace_dir):
     from perfbench import recorder
 
     generator = importlib.import_module(f"perfbench.generators.{mix['generator']}")
     rec = recorder.Recorder(system.vocab_size)
     gen = generator.Generator(mix, cell, seed, seconds, system)
-    marks = asyncio.run(_measure(system, gen, rec, traced, trace_dir))
+    marks = asyncio.run(_measure(system, gen, rec, mode, trace_dir))
     return rec, marks
 
 
@@ -146,7 +200,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, default=10.0)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--root", default=str(ROOT), help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
@@ -160,13 +214,15 @@ def main(argv=None) -> int:
         trace_dir = OUT_DIR / "trace" / args.workload
         if traced:
             shutil.rmtree(trace_dir, ignore_errors=True)
-        rec, marks = run_window(system, spec, mix, spec.cell, args.seed, args.seconds, traced, trace_dir)
+        rec, marks = run_window(system, spec, mix, spec.cell, args.seed, args.seconds, args.trace, trace_dir)
         peak = system.peak_bytes()
         plans = system.kernel_plans()
+        programs = system.traced_programs() if args.trace == 2 else None
     finally:
         system.stop()
 
-    series = rec.series()
+    closed = marks.get("closed") or window_numbers(rec)
+    series = closed["series"]
     series["setup_s"] = [rec.t0 - T_PROCESS]
     delta = {
         k: marks["c1"][k] - marks["c0"][k] for k in marks["c0"]
@@ -183,10 +239,19 @@ def main(argv=None) -> int:
             device["busy_s"], device["window_s"] = trace["busy_s"], trace["window_s"]
             if trace["window_s"] > 0:
                 device["idle_share"] = 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
-        steps = system.steps[: marks["steps_at_t1"]]
-        series["step_ms"] = [
-            (e - s) * 1e3 for s, e, _n in steps if s >= rec.t0 and e <= marks.get("trace_on", rec.t1)
-        ]
+        if args.trace == 2:  # the steps of the traced tail; the trace is reduced, nothing else reads it
+            shutil.rmtree(trace_dir, ignore_errors=True)
+            series["step_ms"] = [
+                (e - s) * 1e3 for s, e, _n in system.steps
+                if s >= marks.get("trace_on", 0.0) and e <= marks.get("trace_off", 0.0)
+            ]
+            if series["step_ms"]:  # against the window's step_ms_total / engine_steps_total: what tracing costs
+                marks["traced_step_ms_mean"] = sum(series["step_ms"]) / len(series["step_ms"])
+        if args.trace == 1:
+            steps = system.steps[: marks["steps_at_t1"]]
+            series["step_ms"] = [
+                (e - s) * 1e3 for s, e, _n in steps if s >= rec.t0 and e <= marks.get("trace_on", rec.t1)
+            ]
     if peak is not None:
         device["memory_peak_bytes"] = peak
         device["peak_hbm_gb"] = peak / 1e9
@@ -195,18 +260,18 @@ def main(argv=None) -> int:
         "series": series, "counter_delta": delta, "trace": trace, "device": device,
         "config": spec.config, "cell": spec.cell, "bench_dir": str(spec.bench_dir),
     }
-    section, names = ("per_layer", spec.per_layer) if traced else ("end_to_end", spec.end_to_end)
+    sections = {0: ("end_to_end",), 1: ("per_layer",), 2: ("end_to_end", "per_layer")}[args.trace]
     metrics = {}
-    for name in names:
-        v = reducers.reduce(section, name, rctx)
-        if v is not None:
-            metrics[name] = {"value": v, "unit": spec.units[name]}
+    for section in sections:
+        for name in getattr(spec, section):
+            v = reducers.reduce(section, name, rctx)
+            if v is not None:
+                metrics[name] = {"value": v, "unit": spec.units[name]}
 
-    attempted = rec.attempted()
-    failed = sum(1 for r in attempted if rec.failed(r))
+    attempted, failed = closed["attempted"], closed["failed"]
     result = {
-        "correct": bool(check["ok"] and failed == 0 and len(attempted) > 0),
-        "attempted": len(attempted), "failed": failed, "metrics": metrics,
+        "correct": bool(check["ok"] and failed == 0 and attempted > 0),
+        "attempted": attempted, "failed": failed, "metrics": metrics,
         "device": {k: device[k] for k in
                    ("platform", "kind", "count", "memory_peak_bytes", "busy_s", "window_s")
                    if k in device},
@@ -218,6 +283,9 @@ def main(argv=None) -> int:
         }
     detail = {
         "workload": args.workload, "seed": args.seed, "seconds": args.seconds, "traced": traced,
+        "tail": {k: marks[k] for k in
+                 ("first_start_stop_s", "trace_stop_s", "tail_offer_ran_out", "traced_step_ms_mean") if k in marks},
+        "traced_programs": programs,
         "setup_log": system.setup_log, "reference_check": check, "kernel_plans": plans,
         "counter_delta": delta, "compile": {k: marks["c1"][k] for k in marks["c1"] if k.startswith("compile_")},
         "samples": {k: len(v) for k, v in series.items()},

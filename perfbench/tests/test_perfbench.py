@@ -6,6 +6,7 @@ repo's tier-1 command (``pytest tests/``); run with
 
 from __future__ import annotations
 
+import asyncio
 import importlib
 import json
 import pathlib
@@ -18,7 +19,7 @@ import pytest
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from perfbench import reducers, run, stats, trace_reduce  # noqa: E402
+from perfbench import idle_split, recorder, reducers, run, stats, trace_reduce  # noqa: E402
 from perfbench.generators import open_loop, sessions  # noqa: E402
 
 MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -154,6 +155,126 @@ def test_trace_reduction_on_the_recorded_fixture():
     assert r["window_s"] == pytest.approx(want["window_s"], rel=1e-9)
     assert 0 < r["busy_s"] <= r["window_s"]
     assert trace_reduce.top_ops(r["op_seconds"], 3)[0][0] == want["top_op"]
+
+
+IDLE_PHASES = ("schedule", "launch", "finish", "unattributed")
+
+
+def test_idle_split_names_the_phase_of_each_gap():
+    """Device busy 0-100, 300-400, 700-800, 950-1000 ns; the first gap lies
+    in the program's schedule span, the second mostly in launch (its build
+    covers less of it), the third in nothing. The whole-step span is not
+    among the candidates: it would take every gap inside a step."""
+    assert not "llmd.step".startswith(trace_reduce.SPAN_PREFIX)
+    assert all(n.startswith(trace_reduce.SPAN_PREFIX) for n in
+               ("pb.step", "llmd.step.finish", "llmd.step.admit", "llmd.sched.schedule", "llmd.runner.wait",
+                "llmd.serve.intake"))
+    loaded = {
+        "devices": {"/device:TPU:0": [("a", 0, 100), ("a", 300, 400), ("a", 700, 800), ("a", 950, 1000)]},
+        "spans": [("llmd.sched.schedule", 100, 290), ("llmd.runner.wait", 295, 410),
+                  ("llmd.runner.launch", 440, 700), ("llmd.runner.build", 450, 690),
+                  ("llmd.runner.wait", 700, 810)],
+    }
+    r = trace_reduce.reduce(loaded)
+    # a whole gap goes to the one span that covers most of it
+    assert r["idle_by_host_s"] == {
+        "llmd.runner.launch": pytest.approx(300e-9), "llmd.sched.schedule": pytest.approx(200e-9),
+        "outside any step": pytest.approx(150e-9)}
+    shares = {p: idle_split.share(r, p) for p in IDLE_PHASES}
+    assert shares == {"schedule": pytest.approx(20.0), "launch": pytest.approx(30.0), "finish": 0.0,
+                      "unattributed": pytest.approx(15.0)}
+    assert sum(shares.values()) == pytest.approx(100.0 * (1 - r["busy_s"] / r["window_s"]))
+    # a span inside launch counts as launch; the --trace 1 wrappers as unattributed
+    inner = dict(r, idle_by_host_s={"llmd.runner.dispatch": 100e-9, "llmd.runner.build": 50e-9, "pb.step": 25e-9})
+    assert idle_split.share(inner, "launch") == pytest.approx(15.0)
+    assert idle_split.share(inner, "unattributed") == pytest.approx(2.5)
+    assert idle_split.share(None, "launch") is None  # no trace: nothing to read
+
+
+def test_idle_split_on_the_recorded_fixture_with_the_programs_spans():
+    """The second recorded trace (tests/make_fixture_llmd.py, a v5e): steps
+    under the program's own ``llmd.*`` spans with a long schedule in one step
+    and a long finish in another, read through the four metric files; each
+    phase gets its gaps and they add up to ``device.idle_share``."""
+    fixture = BENCH / "tests" / "fixture_llmd_v5e.xplane.pb"
+    want = json.loads((BENCH / "tests" / "fixture_llmd_v5e.expected.json").read_text())
+    loaded = trace_reduce.load(str(fixture))
+    assert {n for n, _, _ in loaded["spans"]} == {
+        "llmd.serve.intake", "llmd.step.admit", "llmd.sched.schedule", "llmd.runner.launch", "llmd.runner.build",
+        "llmd.runner.dispatch", "llmd.runner.wait", "llmd.step.finish", "llmd.serve.deliver"}
+    r = trace_reduce.reduce(loaded)
+    assert r["busy_s"] == pytest.approx(want["busy_s"], rel=1e-9)
+    assert r["window_s"] == pytest.approx(want["window_s"], rel=1e-9)
+    assert r["idle_by_host_s"] == {k: pytest.approx(v, rel=1e-9) for k, v in want["idle_by_host_s"].items()}
+    idle = 100.0 * (1.0 - r["busy_s"] / r["window_s"])
+    ctx = {"series": {}, "counter_delta": {}, "trace": r, "device": {"idle_share": idle}, "bench_dir": str(BENCH)}
+    shares = {p: reducers.reduce("per_layer", f"device.idle_{p}_share", ctx) for p in IDLE_PHASES}
+    assert shares == {p: pytest.approx(v, rel=1e-9) for p, v in want["shares"].items()}
+    assert all(shares[p] > 5.0 for p in ("schedule", "launch", "finish"))  # each phase has its gap
+    assert sum(shares.values()) == pytest.approx(reducers.reduce("per_layer", "device.idle_share", ctx), rel=1e-9)
+    # the recorder slept 3 ms in one schedule, 2 ms in every build, 4 ms in one finish
+    assert all(name.startswith(("llmd.sched.", "llmd.runner.", "llmd.step.", "outside")) for name, _ in r["idle_gaps"])
+
+
+class _FakeSystem:
+    """Answers every request with its tokens at once: enough for a generator."""
+
+    vocab_size, max_model_len = 1000, 4096
+
+    async def stream(self, prompt, max_tokens):
+        for i in range(max_tokens):
+            await asyncio.sleep(0)
+            yield type("Out", (), {"new_token_ids": [1], "finished": i == max_tokens - 1, "num_cached_tokens": 0})
+
+
+def test_the_window_of_a_trace2_run_is_the_window_of_a_trace0_run():
+    """Open loop: with a tail the generator sends the window's schedule
+    element for element as without one, and only then more of the mix, in
+    another order, as unmeasured records."""
+    mix = dict(json.loads((BENCH / "traffic" / "chat.json").read_text()), warm_seconds=0.2, drain_seconds=2)
+    mix["output"] = {"type": "lognormal", "mean": 8, "sd": 4, "min": 2, "max": 16}
+    system = _FakeSystem()
+
+    def once(with_tail: bool):
+        rec = recorder.Recorder(system.vocab_size)
+        gen = open_loop.Generator(mix, {"rate": 40.0}, 2**31 + 9, 1.0, system)
+        seen = {}
+
+        async def tail(offer):
+            seen["closed"] = run.window_numbers(rec)
+            await offer(0.5)
+
+        asyncio.run(gen.run(system, rec, tail) if with_tail else gen.run(system, rec))
+        return gen, rec, seen
+
+    g0, r0, _ = once(False)
+    g2, r2, seen = once(True)
+    assert g0.main == g2.main and g0.warm == g2.warm
+
+    def sent(rec, measured):
+        return [(round(r.due - rec.t0, 6), r.n_prompt, r.want_tokens) for r in rec.records if r.measured == measured]
+
+    assert sent(r0, True) == sent(r2, True) == [(round(d, 6), len(p), o) for d, p, o in g0.main]
+    extra = [r for r in r2.records[len(r0.records):]]
+    assert len(extra) == 20 and all(not r.measured and r.due >= r2.t1 for r in extra)
+    assert sorted(r.n_prompt for r in extra) != sorted(r.n_prompt for r in r2.records if r.measured)[:20]
+    # the numbers taken when the window closed are the numbers of the whole run's window
+    assert seen["closed"]["attempted"] == len(r2.attempted()) == len(r0.attempted()) == 40
+    assert seen["closed"]["failed"] == 0 and seen["closed"]["series"]["output_tokens"] == r2.series()["output_tokens"]
+
+
+@pytest.mark.parametrize("cell", ["qwen3-30b-a3b.prefix-sessions", "deepseek-v2-lite.chat-overload"])
+def test_trace2_rehearsal_reports_both_sections(cell, capsys):
+    rc = run.main(["--workload", cell, "--seed", str(2**31 + 11), "--seconds", "2", "--trace", "2", "--rehearse"])
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rc == 3 and line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
+    spec = run.load(cell)
+    reported = set(line["metrics_reported"])
+    assert set(spec.end_to_end) <= reported  # what --trace 0 reports
+    counters = {n for n in spec.per_layer if reducers.definition("per_layer", n)["source"] != "device_trace"}
+    assert counters - {"device.peak_hbm_gb"} <= reported  # and what a CPU can of --trace 1's
+    assert {"runner.step_ms_p50", "runner.launch_ms", "runner.retraces_in_window"} <= reported
+    assert not (run.OUT_DIR / "trace" / cell).exists()  # the trace is deleted once reduced
 
 
 @pytest.mark.parametrize("cell", ["qwen3-30b-a3b.chat", "deepseek-v2-lite.long-decode"])

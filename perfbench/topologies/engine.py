@@ -258,16 +258,7 @@ class System:
         if record_steps and self.steps is None:
             import jax
 
-            self.steps = []
-            inner, log = self.engine.step, self.steps
             span = jax.profiler.TraceAnnotation
-
-            def step():
-                t0 = time.monotonic()
-                with span("pb.step"):
-                    outs = inner()
-                log.append((t0, time.monotonic(), len(outs)))
-                return outs
 
             def spanned(fn, name):
                 def call(*a, **kw):
@@ -277,9 +268,10 @@ class System:
 
             # Host spans on the profiler's clock, so that an idle gap of the
             # device can be put down to what the host was doing in it.
-            self.engine.step = step
+            self.engine.step = spanned(self.engine.step, "pb.step")
             self.engine.scheduler.schedule = spanned(self.engine.scheduler.schedule, "pb.schedule")
             self.engine.runner.wait_step = spanned(self.engine.runner.wait_step, "pb.wait_step")
+            self.time_steps()
         self._async = AsyncEngine(self.engine, watchdog_s=0)
         self._async.start(asyncio.get_running_loop())
 
@@ -302,6 +294,41 @@ class System:
         out.update(compile_programs=c["programs"], compile_seconds=c["seconds"],
                    compile_cache_hits=c["cache_hits"])
         return out
+
+    def time_steps(self) -> None:
+        """From now on, the host clock around every ``engine.step`` (into
+        ``self.steps``): ``--trace 1`` from the start, around its ``pb.step``
+        span; ``--trace 2`` once the window is closed (the serving thread
+        looks ``engine.step`` up at every call)."""
+        if self.steps is not None:
+            return
+        self.steps = []
+        inner, log = self.engine.step, self.steps
+
+        def step():
+            t0 = time.monotonic()
+            outs = inner()
+            log.append((t0, time.monotonic(), len(outs)))
+            return outs
+
+        self.engine.step = step
+
+    def trace_start(self, trace_dir) -> None:
+        """Open a profiler session through the program's own control, in
+        this process, which holds the chip (``--trace 2``)."""
+        from llmd_tpu.obs import profiling
+
+        profiling.start(trace_dir)
+
+    def trace_stop(self) -> None:
+        from llmd_tpu.obs import profiling
+
+        profiling.stop()
+
+    def traced_programs(self) -> list:
+        """The step programs the runner traced, newest last: (unix time,
+        family, shape)."""
+        return [list(p) for p in self.engine.runner.traced_programs]
 
     def kernel_plans(self) -> dict:
         return {op: sorted(p) for op, p in self.engine.runner.kernel_plans.items()}

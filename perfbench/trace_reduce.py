@@ -8,7 +8,8 @@ event per executed HLO operation (fusions, custom calls — the Pallas kernels
 among them — copies, while-loop bodies' contents) and whose line ``XLA
 Modules`` holds one event per executed program; one plane ``/host:CPU`` with
 a line per thread, which is where ``jax.profiler.TraceAnnotation`` spans of
-the benchmark (``pb.*``) appear, on the same clock.
+the benchmark's wrappers (``pb.*``, ``--trace 1``) and of the program itself
+(``llmd.*``, llmd_tpu/obs/profiling.py) appear, on the same clock.
 
 Busy time is the UNION of the op intervals of a chip (ops can nest or
 overlap), averaged over the chips that ran anything.
@@ -33,7 +34,11 @@ def short_name(name: str) -> str:
 
 OPS_LINE = "XLA Ops"
 HOST_PLANE = "/host:CPU"
-SPAN_PREFIX = "pb."
+# The benchmark's wrappers, and the program's PHASE spans. The program's
+# whole-step span (the bare "llmd.step") is left out on purpose: a gap goes to
+# the span that covers most of it, a whole step covers at least what any phase
+# inside it covers, and "inside step()" is what the phases are there to split.
+SPAN_PREFIX = ("pb.", "llmd.sched.", "llmd.runner.", "llmd.step.", "llmd.serve.")
 
 
 def find_xplane(trace_dir: str) -> str | None:
