@@ -58,7 +58,11 @@ PROFILES = {
         kernels=dict(L=2, pages=2048, H=24, K=8, D=128, rows=96,
                      max_pages=512, batch=64, ctx=1000,
                      mla=dict(L=2, H=16, Dl=640, rank=512),
-                     gmm=dict(tokens=1536, hidden=2048, ffn=1408, experts=64)),
+                     gmm=dict(tokens=1536, hidden=2048, ffn=1408, experts=64),
+                     # learned sparse attention at Keye-VL-2.0-30B-A3B's widths:
+                     # a 16 x 64 indexer, top-2,048 of 8k-24k paged tokens
+                     sparse=dict(J=16, Di=64, topk=2048, H=32, K=4, pages=3200,
+                                 max_pages=1536, contexts=(8192, 24000, 16000), chunk=40)),
         tp_blocks=512,
     ),
     "rehearse": dict(
@@ -70,7 +74,9 @@ PROFILES = {
         kernels=dict(L=2, pages=64, H=4, K=2, D=128, rows=8, max_pages=16,
                      batch=4, ctx=100,
                      mla=dict(L=2, H=4, Dl=256, rank=128),
-                     gmm=dict(tokens=64, hidden=128, ffn=128, experts=4)),
+                     gmm=dict(tokens=64, hidden=128, ffn=128, experts=4),
+                     sparse=dict(J=2, Di=64, topk=64, H=4, K=2, pages=64,
+                                 max_pages=32, contexts=(100, 300, 200), chunk=20)),
         tp_blocks=64,
     ),
 }
@@ -522,11 +528,14 @@ def phase_kernels(profile: dict, seed: int, rehearse: bool) -> None:
     import numpy as np
 
     from llmd_tpu import jaxrt, ops
+    from llmd_tpu.ops import sparse_attention
     from llmd_tpu.ops.grouped_gemm import grouped_matmul
     from llmd_tpu.ops.kv_write import write_kv_pages_decode_full, write_kv_pages_flat_full
     from llmd_tpu.ops.mla_attention import mla_paged_attention_xla
     from llmd_tpu.ops.mla_decode import mla_decode_paged_attention_full
-    from llmd_tpu.ops.paged_attention import paged_attention_xla, write_kv_pages
+    from llmd_tpu.ops.paged_attention import (
+        paged_attention_xla, paged_attention_xla_blocked, write_kv_pages,
+    )
     from llmd_tpu.ops.ragged_paged_attention import (
         decode_paged_attention_full, flat_paged_attention_full,
     )
@@ -667,6 +676,58 @@ def phase_kernels(profile: dict, seed: int, rehearse: bool) -> None:
         qe, lat[1], jnp.asarray(dec_table[:, :ctx_pages]), mlens,
         jnp.asarray(dec_pos[:, None]), rank=m["rank"], sm_scale=sm_scale)
     close("mla_decode", mgot, mref, np.ones(B, bool), ATTN_ATOL["bfloat16"], ATTN_RTOL)
+
+    # Learned sparse attention (ops/sparse_attention.py): the selection
+    # itself against plain jax.numpy, then the Pallas flat attention under the
+    # mask against the XLA attention under the reference's sets.
+    sp = k["sparse"]
+    topk, c0, c1, c2 = sp["topk"], *sp["contexts"]
+    srows = [(c0 - 1, 1), (c1 - 1, 1), (c2 - sp["chunk"], sp["chunk"])]  # two decode rows, one chunk
+    splan = _flat_plan(iter(rng.permutation(sp["pages"]).tolist()), srows, page, sp["max_pages"], 8)
+    sT, S = splan["T"], sp["max_pages"] * page
+    spool = normal((L, sp["pages"], sp["K"], page, 2 * D), jnp.bfloat16)
+    plane = normal((L, sp["pages"], page, sp["Di"]), jnp.bfloat16)
+    iq, iw = normal((sT, sp["J"], sp["Di"]), jnp.bfloat16), normal((sT, sp["J"]), jnp.bfloat16)
+    srow_of, stable = jnp.asarray(splan["rows"]), jnp.asarray(splan["table"])
+    slens = jnp.asarray(np.where(splan["live"], splan["positions"] + 1, 0).astype(np.int32))
+    sel = jax.jit(lambda *a: sparse_attention.select_topk(sparse_attention.index_scores(*a), topk))(
+        iq, iw, plane[L - 1], stable, srow_of, slens)
+
+    @jax.jit
+    def plain_scores(iq, iw, plane, table, lens):
+        keys = plane[table].reshape(table.shape[0], S, -1).astype(jnp.float32)  # [T, S, Di]
+        with jax.default_matmul_precision("highest"):
+            s = jnp.einsum("tjd,tsd->tjs", iq.astype(jnp.float32), keys)
+        s = jnp.sum(jax.nn.relu(s) * iw.astype(jnp.float32)[:, :, None], axis=1)
+        return jnp.where(jnp.arange(S)[None, :] < lens[:, None], s, -jnp.inf)
+
+    scores = np.asarray(plain_scores(iq, iw, plane[L - 1], stable[srow_of], slens))
+    causal = np.arange(S)[None, :] < np.asarray(slens)[:, None]
+    order = np.argsort(-scores, axis=1, kind="stable")[:, :topk]
+    want = np.zeros_like(causal)
+    np.put_along_axis(want, order, True, axis=1)
+    want &= causal
+    got_sel = np.asarray(sel) & causal
+    overlap = float((got_sel & want).sum() / want.sum())
+    # What differs sits at the threshold: within a rounding of the topk-th score.
+    kth = np.take_along_axis(scores, order[:, -1:], axis=1)
+    near = np.abs(np.where(causal, scores, 0.0) - kth) <= 1e-3 * np.abs(kth) + 1e-4
+    off = (got_sel ^ want) & ~near
+    ok = bool(overlap >= 0.999 and not off.any()
+              and (got_sel.sum(1) == np.minimum(np.asarray(slens), topk)).all())
+    results.append(("sparse_select", ok))
+    emit(phase="kernels", kernel="sparse_select", check="selected sets", ok=ok, overlap=overlap,
+         differing=int((got_sel ^ want).sum()), beyond_a_rounding=int(off.sum()),
+         tokens=int(splan["live"].sum()), topk=topk, contexts=list(sp["contexts"]))
+    sq = normal((sT, 1, sp["H"], D), jnp.bfloat16, 2.0)
+    with jax.named_scope("llmd.sparse_attention"):
+        sgot = jax.jit(flat_paged_attention_full, static_argnames="interpret")(
+            sq, spool, layer, srow_of, stable, slens, interpret=interpret, sel=sel)
+    sref = jax.jit(paged_attention_xla_blocked)(
+        sq, spool[L - 1], stable[srow_of], slens, jnp.asarray(splan["positions"][:, None]),
+        sel=jnp.asarray(want)[:, None, :])
+    close("sparse_attention", sgot, sref, splan["live"], ATTN_ATOL["bfloat16"], ATTN_RTOL)
+    del spool, plane, sgot, sref
 
     g = k["gmm"]
     xs = normal((g["tokens"], g["hidden"]), act)

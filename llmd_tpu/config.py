@@ -122,6 +122,17 @@ class ModelConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    # --- learned sparse attention (HF ``sa_config``; DeepSeek-Sparse-
+    # Attention-style indexer over GQA, docs/architecture/sparse-attention.md) ---
+    # indexer_topk > 0 turns it on: every layer carries an indexer of
+    # ``indexer_num_heads`` x ``indexer_head_dim`` over ONE shared key per
+    # token (cached in a plane beside K and V, under the same page ids),
+    # and a query token attends only the ``indexer_topk`` earlier tokens
+    # its index scores rank highest (all of them while that many or fewer
+    # are cached). Nothing turns the path off for such a model.
+    indexer_topk: int = 0
+    indexer_num_heads: int = 0
+    indexer_head_dim: int = 0
 
     def __post_init__(self) -> None:
         if self.quantization not in (None, "int8"):
@@ -162,6 +173,24 @@ class ModelConfig:
                 "attention path would silently serve base-model outputs for "
                 "adapter requests"
             )
+        if self.indexer_topk > 0:
+            if self.indexer_num_heads <= 0 or self.indexer_head_dim <= 0:
+                raise ValueError(
+                    "indexer_topk > 0 needs indexer_num_heads and "
+                    "indexer_head_dim (HF sa_config)"
+                )
+            for what, on in (
+                ("MLA", self.kv_lora_rank > 0),
+                ("sliding_window", self.sliding_window > 0),
+                ("attention_sinks", self.attention_sinks),
+                ("LoRA adapters", self.num_lora_adapters > 0),
+            ):
+                if on:
+                    raise ValueError(
+                        f"learned sparse attention (indexer_topk > 0) is not "
+                        f"supported with {what}: that attention path would "
+                        "silently attend past the indexer's selection"
+                    )
 
     def window_for_layer(self, i: int) -> int:
         """Attention window for layer ``i`` (0 = full attention)."""
@@ -188,6 +217,12 @@ class ModelConfig:
     @property
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def sparse_attention(self) -> bool:
+        """Learned sparse attention: an indexer picks the cached tokens a
+        query token may read (``indexer_topk`` > 0)."""
+        return self.indexer_topk > 0
 
     @property
     def mla_latent_dim(self) -> int:
@@ -750,6 +785,37 @@ class EngineConfig:
 
     def to_dict(self) -> dict[str, Any]:
         return dataclasses.asdict(self)
+
+    def check_sparse_attention(self) -> None:
+        """Refuse, at start, what would drop or misread the indexer's key
+        plane or attend past its selection (no silent fallback). The
+        sparse path exists on the flat step of one device; everything
+        that moves KV pages by another road knows K and V only."""
+        if not self.model.sparse_attention:
+            return
+        s, c, p = self.scheduler, self.cache, self.parallel
+        refused = {
+            "speculative decoding (speculative_ngram)": s.speculative_ngram,
+            "fused decode windows (decode_window > 1)": s.decode_window > 1,
+            "the bucketed or split step (unified_step / ragged_qlens off)":
+                not (s.unified_step and s.ragged_qlens),
+            "an int8 KV cache": c.quantized,
+            "the sliding-window ring (swa_ring)": c.swa_ring,
+            "tiered KV offload": self.offload is not None and self.offload.enabled,
+            "P/D KV transfer (kv_role)": bool(self.kv_role),
+            "a sharded mesh (tp/dp/ep > 1)":
+                p.world_size > 1 or p.expert_parallel_size > 1,
+            "ring prefill or dual-batch overlap": p.cp_prefill > 1 or p.enable_dbo,
+            "int8 weights": self.model.quantization is not None,
+        }
+        on = [what for what, is_on in refused.items() if is_on]
+        if on:
+            raise ValueError(
+                f"{self.model.name}: learned sparse attention (indexer top-"
+                f"{self.model.indexer_topk}) does not run with " + "; ".join(on)
+                + ": that path would drop the indexer's key plane or attend "
+                "past its selection"
+            )
 
 
 def tiny_model_config(**overrides: Any) -> ModelConfig:

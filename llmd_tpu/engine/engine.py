@@ -325,6 +325,17 @@ class EngineStats:
     # T granule. padded / live is the padding-waste gauge.
     live_tokens_total: int = 0
     padded_tokens_total: int = 0
+    # Learned sparse attention (models with an indexer; 0 elsewhere),
+    # counted on the host from each flat step's positions: computed query
+    # tokens that had more than indexer_topk cached tokens (the selection
+    # binds) and those that had not; the cached keys the indexer scored
+    # for the former (sum of their context lengths; every layer scores
+    # them again); indexer keys written (one a computed token and layer's
+    # plane, counted once a token).
+    sparse_bound_tokens_total: int = 0
+    sparse_unbound_tokens_total: int = 0
+    indexer_keys_scored_total: int = 0
+    indexer_keys_written_total: int = 0
     # Per-row verify depth histogram (speculative engines): index d
     # counts decode rows dispatched with a 1 + draft width of exactly d
     # tokens (backed-off rows: 1; hot-draft rows: up to 1 + spec_k,
@@ -2113,6 +2124,11 @@ class LLMEngine:
             self.stats.spec_row_depth_hist = tuple(self._spec_row_depth)
         self.stats.live_tokens_total = self.runner.live_tokens_total
         self.stats.padded_tokens_total = self.runner.padded_tokens_total
+        r = self.runner
+        self.stats.sparse_bound_tokens_total = r.sparse_bound_tokens_total
+        self.stats.sparse_unbound_tokens_total = r.sparse_unbound_tokens_total
+        self.stats.indexer_keys_scored_total = r.indexer_keys_scored_total
+        self.stats.indexer_keys_written_total = r.indexer_keys_written_total
         self.stats.dispatches_per_emitted_token = round(
             self.stats.decode_dispatches_total
             / max(1, self.stats.generation_tokens),

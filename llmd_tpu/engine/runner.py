@@ -347,6 +347,7 @@ class ModelRunner:
     ) -> None:
         self.config = config
         self.cfg = config.model
+        config.check_sparse_attention()
         self.ctx = mesh_ctx
         self.max_pages = config.cache.max_pages_per_seq(self.cfg.max_model_len)
         self.page = config.cache.page_size
@@ -508,6 +509,15 @@ class ModelRunner:
         # compute width the traced shape actually paid for.
         self.live_tokens_total = 0
         self.padded_tokens_total = 0
+        # Learned sparse attention (EngineStats fields of the same names),
+        # from the positions each flat step already holds: computed query
+        # tokens with more / no more than indexer_topk cached tokens, the
+        # cached keys the indexer scored for the former, and the indexer
+        # keys written (one a computed token; every layer writes its own).
+        self.sparse_bound_tokens_total = 0
+        self.sparse_unbound_tokens_total = 0
+        self.indexer_keys_scored_total = 0
+        self.indexer_keys_written_total = 0
 
     def _build_programs(self) -> None:
         """(Re)build every jitted forward program. Called at init and
@@ -753,7 +763,15 @@ class ModelRunner:
             len(self.swa.full_layers) if self.swa is not None
             else self.cfg.num_layers
         )
-        return self._alloc_pool(layers, c.num_blocks)
+        kv = self._alloc_pool(layers, c.num_blocks)
+        if not self.cfg.sparse_attention:
+            return kv
+        # Learned sparse attention: the indexer's one key per token, a
+        # second plane under the SAME page ids (ops/sparse_attention.py).
+        plane = (layers, c.num_blocks, c.page_size, self.cfg.indexer_head_dim)
+        return ops.IndexedPool(kv=kv, index=jnp.zeros(
+            plane, jnp.dtype(c.dtype), device=self.ctx.replicated
+        ))
 
     def _alloc_swa(self):
         """The sliding-window ring pool (None unless swa_ring resolves)."""
@@ -819,6 +837,8 @@ class ModelRunner:
 
     @property
     def _kv_data(self) -> jax.Array:
+        if isinstance(self.kv_cache, ops.IndexedPool):
+            return self.kv_cache.kv
         return self.kv_cache[0] if self.kv_quantized else self.kv_cache
 
     @property
@@ -828,7 +848,7 @@ class ModelRunner:
         int8 pools, the pool dtype otherwise."""
         if self.kv_quantized:
             return np.dtype(jnp.dtype(self.cfg.dtype))
-        return np.dtype(self.kv_cache.dtype)
+        return np.dtype(self._kv_data.dtype)
 
     @property
     def staging_dtype_name(self) -> str:
@@ -1707,6 +1727,11 @@ class ModelRunner:
         """Select the staging target: the main pool or the SWA ring pool.
         The staging programs themselves are pool-agnostic (the pool is an
         argument), so both pools share them."""
+        if not swa and isinstance(self.kv_cache, ops.IndexedPool):
+            raise RuntimeError(
+                "page staging moves K and V only; it would drop the indexer "
+                "keys of a sparse-attention pool"
+            )
         return self.kv_swa if swa else self.kv_cache
 
     def _pool_data(self, swa: bool) -> jax.Array:
@@ -3416,6 +3441,7 @@ class ModelRunner:
         n_pre_rows = (
             staged.prefill_rows[-1] + 1 if staged.prefill_rows else 0
         )
+        sparse_topk = self.cfg.indexer_topk
         t = 0
         for r, (seq, off, _plan) in enumerate(
             zip(staged.row_seqs, staged.row_off, staged.row_plan)
@@ -3443,6 +3469,17 @@ class ModelRunner:
             qlens[r] = w
             kvlens[r] = start + w
             t += w
+            if sparse_topk:
+                # Tokens at positions [lo, start + w) see more than top-k
+                # cached tokens (position + 1 of them each).
+                lo = max(start, sparse_topk)
+                bound = max(0, start + w - lo)
+                self.sparse_bound_tokens_total += bound
+                self.sparse_unbound_tokens_total += w - bound
+                self.indexer_keys_scored_total += (
+                    bound * (lo + start + w + 1) // 2
+                )
+                self.indexer_keys_written_total += w
         self._overwrite_seeded_rows(a["seeds"], staged.row_seqs, staged.S)
         self.live_tokens_total += t
         if staged.flat:

@@ -66,10 +66,24 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     return (xf * jax.lax.rsqrt(var + eps)).astype(dtype) * weight
 
 
+def layer_norm(
+    x: jax.Array, weight: jax.Array, bias: jax.Array, eps: float
+) -> jax.Array:
+    dtype = x.dtype
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(var + eps)).astype(dtype) * weight + bias
+
+
 SUPPORTED_ROPE_TYPES = ("default", "linear", "llama3", "yarn")
 
 
 def rope_type(scaling: dict | None) -> str:
+    """The scaling's kind. ``mrope_section`` beside ``default`` (Qwen-VL
+    style: each rotary frequency reads one of three position rows) is the
+    ordinary rope for text, whose three rows are equal; the program
+    serves 1-D positions only, so the sections change nothing here."""
     if not scaling:
         return "default"
     return scaling.get("rope_type") or scaling.get("type") or "default"

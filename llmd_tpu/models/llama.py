@@ -19,12 +19,15 @@ import jax.numpy as jnp
 
 from llmd_tpu.config import ModelConfig
 from llmd_tpu.models.common import (
-    StepInput, apply_rope, param_dtype, pdot, rms_norm, rope_tables,
+    StepInput, apply_rope, layer_norm, param_dtype, pdot, rms_norm,
+    rope_tables,
 )
 from llmd_tpu.models.moe import moe_block
 from llmd_tpu.ops import (
     paged_attention_full,
     paged_attention_full_flat,
+    sparse_attention_full_flat,
+    write_index_keys_full_flat,
     write_kv_pages_full,
     write_kv_pages_full_flat,
 )
@@ -89,6 +92,15 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
         if cfg.qk_norm:
             layers["attn_q_norm"] = jnp.ones((n, D), dt)
             layers["attn_k_norm"] = jnp.ones((n, D), dt)
+        if cfg.sparse_attention:
+            # The indexer: J query heads and per-head score weights from
+            # the layer's normed input, ONE shared key per token.
+            J, Di = cfg.indexer_num_heads, cfg.indexer_head_dim
+            layers["wi_q"] = mkp("wi_q", (n, H, J * Di))
+            layers["wi_k"] = mkp("wi_k", (n, H, Di))
+            layers["wi_w"] = mkp("wi_w", (n, H, J))
+            layers["wi_k_norm"] = jnp.ones((n, Di), dt)
+            layers["wi_k_norm_b"] = jnp.zeros((n, Di), dt)
         if cfg.num_lora_adapters and not cfg.is_mla:
             # Adapter slot 0 = base model (zeros); slots 1..A are live
             # adapters on the q and v projections (the classic target set).
@@ -255,6 +267,17 @@ def forward_hidden(
     # entry points below. DBO keeps the bucketed layout only (its
     # half-batch table slicing assumes per-row tables).
     flat = inp.token_rows is not None
+    if cfg.sparse_attention and not flat:
+        raise NotImplementedError(
+            f"{cfg.name}: learned sparse attention runs on the flat step "
+            "only; this program would attend past the indexer's selection"
+        )
+    if cfg.sparse_attention:
+        J, Di = cfg.indexer_num_heads, cfg.indexer_head_dim
+        # The indexer rotates all Di dimensions of its heads and its key.
+        icos, isin = rope_tables(
+            inp.positions, Di, cfg.rope_theta, cfg.rope_scaling
+        )
     use_dbo = (
         bool(dbo) and not flat and B >= 2 and B % 2 == 0
         and (B // 2) % _dp == 0
@@ -381,6 +404,25 @@ def forward_hidden(
             if kv_rep > 1:
                 k = jnp.repeat(k, kv_rep, axis=2)
                 v = jnp.repeat(v, kv_rep, axis=2)
+            if cfg.sparse_attention:
+                # Indexer: per-token query heads, head weights and the one
+                # shared key (LayerNorm, then rope), in one matmul.
+                iqkw = h @ jnp.concatenate(
+                    [lp["wi_q"], lp["wi_k"], lp["wi_w"]], axis=-1
+                )
+                iq = apply_rope(
+                    iqkw[..., : J * Di].reshape(B, Q, J, Di), icos, isin
+                )
+                ik = layer_norm(
+                    iqkw[..., J * Di : (J + 1) * Di], lp["wi_k_norm"],
+                    lp["wi_k_norm_b"], cfg.rms_norm_eps,
+                )
+                ik = apply_rope(ik[:, :, None, :], icos, isin)[:, :, 0]
+                iw = iqkw[..., (J + 1) * Di :]
+                cache = write_index_keys_full_flat(
+                    cache, layer_idx, ik[:, 0], table, inp.token_rows,
+                    inp.positions[:, 0], valid[:, 0],
+                )
             if flat:
                 cache = write_kv_pages_full_flat(
                     cache, layer_idx, k, v, table, inp.token_rows,
@@ -417,7 +459,14 @@ def forward_hidden(
                     )
                 x2, cd = _tails_dbo(outs)
                 return x2, cache, cd
-            if flat:
+            if cfg.sparse_attention:
+                attn = sparse_attention_full_flat(
+                    q, iq[:, 0], iw[:, 0], cache, layer_idx,
+                    inp.token_rows, table, inp.kv_lens, inp.positions,
+                    cfg.indexer_topk, sm_scale,
+                    world_size=world_size, mesh=mesh,
+                )
+            elif flat:
                 attn = paged_attention_full_flat(
                     q, cache, layer_idx, inp.token_rows, table,
                     inp.kv_lens, inp.positions, sm_scale,

@@ -144,6 +144,38 @@ def _qwen3_30b_a3b() -> ModelConfig:
     )
 
 
+@register_model("keye-vl-2.0-30b-a3b")
+def _keye_vl2_30b_a3b() -> ModelConfig:
+    """Keye-VL-2.0-30B-A3B's language model (HF Kwai-Keye/Keye-VL-2.0-
+    30B-A3B, text only): Qwen3-MoE widths plus ``sa_config`` — an indexer
+    of 16 heads x 64 over one shared key per token that picks the 2,048
+    cached tokens a query token attends (docs/architecture/
+    sparse-attention.md). The vision tower is not served."""
+    return ModelConfig(
+        name="keye-vl-2.0-30b-a3b", vocab_size=151936, hidden_size=2048,
+        intermediate_size=6144, num_layers=48, num_heads=32, num_kv_heads=4,
+        head_dim=128, rope_theta=10000000.0, max_model_len=262144,
+        rope_scaling={
+            "mrope_section": [16, 24, 24], "rope_type": "default",
+            "type": "default",
+        },
+        rms_norm_eps=1e-6, qk_norm=True,
+        num_experts=128, num_experts_per_tok=8, moe_intermediate_size=768,
+        indexer_topk=2048, indexer_num_heads=16, indexer_head_dim=64,
+    )
+
+
+@register_model("tiny-dsa")
+def _tiny_dsa() -> ModelConfig:
+    """The sparse-attention architecture in miniature (CPU tests and the
+    benchmark's rehearsal): 2 indexer heads x 8, top-32."""
+    return tiny_model_config(
+        name="tiny-dsa", qk_norm=True, num_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=64, max_model_len=512,
+        indexer_topk=32, indexer_num_heads=2, indexer_head_dim=8,
+    )
+
+
 @register_model("mixtral-8x7b")
 def _mixtral_8x7b() -> ModelConfig:
     return ModelConfig(

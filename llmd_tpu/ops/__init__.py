@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import dataclasses
 import os
 
 import jax
@@ -49,6 +50,11 @@ from llmd_tpu.ops.ragged_paged_attention import (
     decode_paged_attention,
     decode_paged_attention_full,
     flat_paged_attention_full,
+)
+from llmd_tpu.ops.sparse_attention import (  # noqa: F401  (entry points of learned sparse attention)
+    IndexedPool,
+    sparse_attention_full_flat,
+    write_index_keys_full_flat,
 )
 
 
@@ -267,7 +273,7 @@ def _split_cache(kv_cache):
 
 
 def _attention_xla(q, kv_slice, page_table, kv_lens, positions, sm_scale,
-                   window=None, sinks=None, scales=None):
+                   window=None, sinks=None, scales=None, sel=None):
     S = page_table.shape[1] * kv_slice.shape[-2]
     if S > _DENSE_XLA_MAX_S:
         # The blocked online-softmax path handles Q==1 too — long-context
@@ -275,11 +281,11 @@ def _attention_xla(q, kv_slice, page_table, kv_lens, positions, sm_scale,
         # gather the whole padded context per step.
         return paged_attention_xla_blocked(
             q, kv_slice, page_table, kv_lens, positions, sm_scale,
-            window=window, sinks=sinks, scales=scales,
+            window=window, sinks=sinks, scales=scales, sel=sel,
         )
     return paged_attention_xla(
         q, kv_slice, page_table, kv_lens, positions, sm_scale, window=window,
-        sinks=sinks, scales=scales,
+        sinks=sinks, scales=scales, sel=sel,
     )
 
 
@@ -425,7 +431,14 @@ def write_kv_pages_full_flat(
     same-page-safe where the per-token decode kernel's pipeline is not).
     XLA fallback: gather the per-token table rows, then the plain
     scatter (distinct (page, slot) targets per live token).
+    A sparse-attention pool (``IndexedPool``) has its K/V written here and
+    its indexer keys by ``write_index_keys_full_flat``.
     """
+    if isinstance(kv_cache_full, IndexedPool):
+        return dataclasses.replace(kv_cache_full, kv=write_kv_pages_full_flat(
+            kv_cache_full.kv, layer, k, v, page_table, rows, positions,
+            valid, runs, world_size=world_size, mesh=mesh,
+        ))
     kv_cache_full, kv_scales = _split_cache(kv_cache_full)
     if kv_scales is not None:
         from llmd_tpu.ops.quant_kv import quantize_kv_rows

@@ -169,6 +169,7 @@ def paged_attention_xla_blocked(
     window=None,  # i32 scalar (0/None = full attention)
     sinks=None,   # [H] per-q-head virtual-key logits (gpt-oss)
     scales=None,  # [num_pages, K, page, 2] f32: int8-pool row scales
+    sel=None,     # [B, Q, S] bool: keys each query may read (sparse attention)
 ) -> jax.Array:
     """Flash-style blocked paged attention in plain XLA.
 
@@ -190,6 +191,8 @@ def paged_attention_xla_blocked(
             [page_table, jnp.repeat(page_table[:, -1:], pad, axis=1)], axis=1
         )
         max_pages += pad
+        if sel is not None:
+            sel = jnp.pad(sel, ((0, 0), (0, 0), (0, pad * page)))
     n_blocks = max_pages // block_pages
     Sb = block_pages * page
     G = H // K
@@ -216,9 +219,10 @@ def paged_attention_xla_blocked(
         key_pos = blk * Sb + jnp.arange(Sb)[None, None, :]
         causal = key_pos <= positions[:, :, None]
         in_ctx = key_pos < kv_lens[:, None, None]
-        mask = (causal & in_ctx & _window_mask(key_pos, positions, window))[
-            :, :, None, None, :
-        ]
+        mask = causal & in_ctx & _window_mask(key_pos, positions, window)
+        if sel is not None:
+            mask &= jax.lax.dynamic_slice_in_dim(sel, blk * Sb, Sb, axis=2)
+        mask = mask[:, :, None, None, :]
         s = jnp.where(mask, s, -1e30)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))  # [B, Q, K, G]
         alpha = jnp.exp(m - m_new)
@@ -262,6 +266,7 @@ def paged_attention_xla(
     window=None,  # i32 scalar (0/None = full attention)
     sinks=None,   # [H] per-q-head virtual-key logits (gpt-oss)
     scales=None,  # [num_pages, K, page, 2] f32: int8-pool row scales
+    sel=None,     # [B, Q, S] bool: keys each query may read (sparse attention)
 ) -> jax.Array:
     """Reference paged attention: gather the whole context, masked softmax."""
     B, Q, H, D = q.shape
@@ -290,9 +295,10 @@ def paged_attention_xla(
     key_pos = jnp.arange(S)[None, None, :]  # [1,1,S]
     causal = key_pos <= positions[:, :, None]  # [B,Q,S]
     in_ctx = key_pos < kv_lens[:, None, None]  # [B,1,S]
-    mask = (causal & in_ctx & _window_mask(key_pos, positions, window))[
-        :, :, None, None, :
-    ]  # [B,Q,1,1,S]
+    mask = causal & in_ctx & _window_mask(key_pos, positions, window)
+    if sel is not None:
+        mask &= sel
+    mask = mask[:, :, None, None, :]  # [B,Q,1,1,S]
     scores = jnp.where(mask, scores, -1e30)
     if sinks is not None:
         # gpt-oss attention sinks: append the per-head sink logit as an

@@ -42,6 +42,7 @@ def _decode_kernel(
     has_sinks: bool,
     quant: bool,
     row_lookup: bool = False,
+    select: bool = False,
 ):
     # remaining scalar prefetch:
     #   page_table_ref  [B|R, max_pages] i32
@@ -57,11 +58,15 @@ def _decode_kernel(
     if row_lookup:
         rows_ref, *refs = refs
     page_table_ref, kv_lens_ref, win_starts_ref, *refs = refs
+    q_ref, sinks_ref, kv_hbm_full_ref, *refs = refs
     if quant:
-        (q_ref, sinks_ref, kv_hbm_full_ref, ks_ref, vs_ref, out_ref,
-         m_ref, l_ref, acc_ref) = refs
-    else:
-        q_ref, sinks_ref, kv_hbm_full_ref, out_ref, m_ref, l_ref, acc_ref = refs
+        ks_ref, vs_ref, *refs = refs
+    if select:
+        # sel_ref [1, 1, S_max] f32: 1.0 where this token may read the key
+        # (learned sparse attention's selection), lane-aligned like the
+        # scale planes above.
+        sel_ref, *refs = refs
+    out_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
     # Row-lookup prologue: program b handles TOKEN b; its pages live in
     # the compact table's row rows_ref[b]. kv_lens/win_starts stay
@@ -162,6 +167,9 @@ def _decode_kernel(
                 s = s * ks[:, None, :]
             pos = i * S + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
             live = jnp.logical_and(pos < kv_len, pos >= win_start)
+            if select:
+                chosen = sel_ref[0, :, pl.ds(i * S, S)] > 0.5  # [1, S]
+                live = jnp.logical_and(live, chosen[:, None, :])
             s = jnp.where(live, s, NEG_INF)
 
             m_prev = m_ref[:, :, :1]  # [K, G, 1]
@@ -348,6 +356,7 @@ def flat_paged_attention_full(
     window: jax.Array | None = None,
     sinks: jax.Array | None = None,
     scales: jax.Array | None = None,  # [L, num_pages, K, page, 2]
+    sel: jax.Array | None = None,  # [T, S] bool: keys each token may read
 ) -> jax.Array:
     """Flattened-token (``cu_q_lens``) attention: the grid iterates the
     packed TOKEN stream — program t streams exactly the pages token t's
@@ -357,7 +366,10 @@ def flat_paged_attention_full(
     per-token table is ever materialized for the data DMAs. Pure decode
     rows cost ONE program; prefill-chunk tokens each stream their live
     prefix (write-before-read per layer makes same-step earlier tokens'
-    fresh KV visible)."""
+    fresh KV visible). ``sel`` (learned sparse attention) masks every
+    key a token's indexer did not select, on top of the causal mask: the
+    pass stays dense over the live pages, the result reads selected
+    tokens only."""
     T, Q, H, D = q.shape
     assert Q == 1, "flat attention takes the packed [T, 1, H, D] stream"
     K, page, D2 = kv_cache.shape[-3], kv_cache.shape[-2], kv_cache.shape[-1]
@@ -411,6 +423,15 @@ def flat_paged_attention_full(
         )
         in_specs.extend([sspec, sspec])
         operands.extend([ksvs[:, :, 0], ksvs[:, :, 1]])
+    if sel is not None:
+        S_max = page_table.shape[1] * page
+        selp = jnp.pad(
+            sel.astype(jnp.float32), ((0, 0), (0, S_max - sel.shape[1]))
+        )
+        in_specs.append(pl.BlockSpec(
+            (1, 1, S_max), lambda b, l, r, pt, kl, ws: (b, 0, 0)
+        ))
+        operands.append(selp[:, None, :])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(T,),
@@ -434,6 +455,7 @@ def flat_paged_attention_full(
             has_sinks=sinks is not None,
             quant=scales is not None,
             row_lookup=True,
+            select=sel is not None,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, K, G, D), q.dtype),

@@ -106,6 +106,12 @@ PARAM_SPECS: dict[str, P] = {
     "sinks": P(None, TP_AXIS),    # [L, Nq] per-q-head sink logits
     "attn_q_norm": P(None, None),  # [L, D] per-head norm, replicated
     "attn_k_norm": P(None, None),
+    # Learned sparse attention's indexer (one device only; replicated).
+    "wi_q": P(None, None, None),    # [L, H, J*Di]
+    "wi_k": P(None, None, None),    # [L, H, Di] one shared key per token
+    "wi_w": P(None, None, None),    # [L, H, J] per-head score weights
+    "wi_k_norm": P(None, None),     # [L, Di] LayerNorm weight on the key
+    "wi_k_norm_b": P(None, None),   # [L, Di] and its bias
     # LoRA: down-projections replicated (rank is tiny), up-projections
     # head-sharded like their base weights.
     "la_q": P(None, None, None, None),       # [L, A+1, H, r]

@@ -164,6 +164,12 @@ def render_metrics(
         "resume_replayed_tokens_total": stats.resume_replayed_tokens_total,
         "stream_resume_failures_total": stats.stream_resume_failures_total,
     }
+    if stats.indexer_keys_written_total:
+        # Learned sparse attention (models with an indexer only).
+        counters["sparse_bound_tokens_total"] = stats.sparse_bound_tokens_total
+        counters["sparse_unbound_tokens_total"] = stats.sparse_unbound_tokens_total
+        counters["indexer_keys_scored_total"] = stats.indexer_keys_scored_total
+        counters["indexer_keys_written_total"] = stats.indexer_keys_written_total
     if stats.swa_ring_pages:
         # Hybrid-APC section retention activity
         counters["swa_section_hits_total"] = stats.swa_section_hits

@@ -362,6 +362,22 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                    "16-token T-granule; a high ratio with ragged on "
                    "means steps are too small for their granule, with "
                    "ragged off it is the bucketed sub-row padding."),
+        panel("Sparse attention (learned indexer)",
+              [f"rate(llmd:sparse_bound_tokens_total{M}[5m]) / "
+               f"(rate(llmd:sparse_bound_tokens_total{M}[5m]) + "
+               f"rate(llmd:sparse_unbound_tokens_total{M}[5m]))",
+               f"rate(llmd:indexer_keys_scored_total{M}[5m]) / "
+               f"rate(llmd:sparse_bound_tokens_total{M}[5m])",
+               f"rate(llmd:indexer_keys_written_total{M}[5m])"],
+              legends=["bound token share", "indexer keys scored/bound token",
+                       "indexer keys written/s"],
+              desc="Models with learned sparse attention only "
+                   "(docs/architecture/sparse-attention.md). Bound token "
+                   "share: computed query tokens that had more cached "
+                   "tokens than the indexer's top-k, so the selection "
+                   "binds. Keys scored per bound token is their context "
+                   "length: the indexer's scores and the dense pass "
+                   "under the mask grow with it."),
         row("Speculative decoding"),
         panel("Draft acceptance", [f"llmd:spec_acceptance_rate{M}"],
               unit="percentunit", max1=True,

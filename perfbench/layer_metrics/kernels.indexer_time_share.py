@@ -1,0 +1,14 @@
+"""Device time of the indexer (its key plane's upkeep, the gather of each
+token's key pages and their scores) over device busy time, in %: matched by
+the shapes in each event's HLO text (perfbench/sparse_trace.py says why)."""
+
+import pathlib
+import sys
+
+sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[2]))
+
+from perfbench import sparse_trace  # noqa: E402
+
+
+def read(ctx, definition):
+    return sparse_trace.share(ctx, "indexer")

@@ -95,6 +95,24 @@ def _flat_attention(model, dtype, T):
     return flat_paged_attention_full, args
 
 
+def _sparse_attention(T):
+    """The flat kernel under an indexer's mask, at the geometry of
+    keye-vl-2.0-30b-a3b.1chip: 6 layers of 24,576 pages, 34 flat rows x 2,048
+    pages (32,768-token contexts), the mask a [T, 32768] plane."""
+    L, H, K, D = 6, 32, 4, 128
+    rows, max_pages = 34, 2048
+    return (
+        lambda q, kv, l, r, pt, kl, sel: flat_paged_attention_full(
+            q, kv, l, r, pt, kl, sel=sel
+        ),
+        [
+            ((T, 1, H, D), BF16), ((L, 24576, K, PAGE, 2 * D), BF16), ((), I32),
+            ((T,), I32), ((rows, max_pages), I32), ((T,), I32),
+            ((T, max_pages * PAGE), jnp.bool_),
+        ],
+    )
+
+
 def _decode_attention(model, dtype):
     _, H, _, D = model
     args = [
@@ -157,6 +175,7 @@ CASES = {
     "flat_attention-bf16": lambda d: _flat_attention(LLAMA, BF16, 2064),
     "flat_attention-int8": lambda d: _flat_attention(LLAMA, I8, 256),
     "flat_attention-qwen3-30b-a3b": lambda d: _flat_attention(QWEN3, BF16, 256),
+    "sparse_attention-keye-vl-2.0-30b-a3b": lambda d: _sparse_attention(144),
     "flat_write-bf16": lambda d: _flat_write(LLAMA, BF16, 2064),
     "flat_write-int8": lambda d: _flat_write(LLAMA, I8, 256),
     "flat_write-qwen3-30b-a3b": lambda d: _flat_write(QWEN3, BF16, 256),
