@@ -8,9 +8,10 @@ K cached heads (shapes from the event's HLO text: output ``bf16[T,K,G,D]``,
 the pool ``bf16[L,P,K,page,2D]``):
   tokens = T x live share  (the flat stream pads to a multiple of 16 and a
            pad token attends nothing: the share of LIVE tokens among the
-           computed ones comes from the window's own counters,
-           ``live_tokens_total`` / (live + ``padded_tokens_total``), 92-93 %
-           in the cell this metric lists; nothing caps the result, so a
+           computed ones comes from the program's counters over the
+           TRACED slice (``counter_delta_traced``, never the window's: a
+           tail does not look like its window), ``live_tokens_total`` /
+           (live + ``padded_tokens_total``); nothing caps the result, so a
            count that is too high shows as a share over 100 %)
   FLOPs  = tokens x topk x H x D x 4        (q.k and p.v)
   bytes  = tokens x topk x K x 2D x width   (the selected rows of K and V)
@@ -54,7 +55,7 @@ def call_cost(name: str, topk: int, live_share: float = 1.0):
 def read(ctx, definition):
     trace = ctx.get("trace")
     topk = (ctx["config"].get("sa_config") or {}).get("topk")
-    counters = ctx.get("counter_delta") or {}
+    counters = ctx.get("counter_delta_traced") or {}
     live, padded = counters.get("live_tokens_total", 0), counters.get("padded_tokens_total", 0)
     if not trace or not trace.get("op_seconds") or not topk or live <= 0:
         return None

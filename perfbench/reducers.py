@@ -8,7 +8,9 @@ kinds:
   value          {"series"}                the one value of a series
   ratio_of_sums  {"num", "den", "scale"}   sum(series num) / sum(series den) x scale
   counter_delta  {"counter"}               a program counter, end minus start of the window
-  counter_ratio  {"num": [..], "den": [..], "scale"}  sums of counter deltas
+  counter_ratio  {"num": [..], "den": [..], "scale"}  sums of counter deltas; with "over": "traced" the
+                 deltas over the slice the profiler was on (None without one), and with "den_config": [keys]
+                 the denominator times the configuration's first such key (a count per expert, say)
   trace_share    {"patterns": [..]}        device time of matching ops / device busy time, in %
   device         {"field"}                 a field of the device summary
   reader         {}                        perfbench/layer_metrics/<name>.py::read(ctx)
@@ -62,8 +64,10 @@ def reduce(section: str, name: str, ctx: dict):
         v = ctx["counter_delta"].get(d["counter"])
         return None if v is None else float(v)
     if kind == "counter_ratio":
-        num = _sum_counters(ctx["counter_delta"], d["num"])
-        den = _sum_counters(ctx["counter_delta"], d["den"])
+        delta = (ctx.get("counter_delta_traced") or {}) if d.get("over") == "traced" else ctx["counter_delta"]
+        num, den = _sum_counters(delta, d["num"]), _sum_counters(delta, d["den"])
+        if den and "den_config" in d:
+            den *= next((ctx["config"][k] for k in d["den_config"] if ctx["config"].get(k)), 0)
         return None if num is None or not den else d.get("scale", 1.0) * num / den
     if kind == "trace_share":
         tr = ctx.get("trace")

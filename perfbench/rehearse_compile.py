@@ -66,18 +66,25 @@ def rehearse(name: str, conf: dict, device) -> None:
     r.prefill_buckets = sched.prefill_token_buckets or _buckets(sched.max_num_batched_tokens, start=16)
     r.kernel_plans = {}
     r.kv_swa = None
+    r.traced_programs, r.programs_traced, r._tracing = [], 0, None  # what _note_traced writes
     params = jax.eval_shape(lambda k: llama.init_params(cfg, k), jax.random.key(0))
     fused = jax.eval_shape(runner_mod._fuse_projection_tree, params) if not cfg.is_mla else params
     r.params = on_chip(fused)
     pool = (cfg.num_layers, config.cache.num_blocks, cfg.kv_cache_heads,
             config.cache.page_size, cfg.kv_cache_entry_dim)
     r.kv_cache = jax.ShapeDtypeStruct(pool, jax.numpy.dtype(config.cache.dtype), sharding=here)
+    if cfg.sparse_attention:  # the indexer's key plane under the same page ids (runner._alloc_kv)
+        from llmd_tpu import ops
+
+        plane = (cfg.num_layers, config.cache.num_blocks, config.cache.page_size, cfg.indexer_head_dim)
+        r.kv_cache = ops.IndexedPool(
+            kv=r.kv_cache, index=jax.ShapeDtypeStruct(plane, r.kv_cache.dtype, sharding=here))
     r._build_programs()
     r._check_page_table_fits_smem()
 
     gib = 2.0 ** 30
     weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(r.params))
-    pool_b = r.kv_cache.size * r.kv_cache.dtype.itemsize
+    pool_b = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(r.kv_cache))
     print(f"== {name}: {cfg.num_layers} layers, weights {weights / gib:.2f} GiB, "
           f"KV pool {pool_b / gib:.2f} GiB ({config.cache.num_blocks} pages x {r.page} tokens)")
 

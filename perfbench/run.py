@@ -19,7 +19,9 @@ then the same traffic goes on, the profiler is started and stopped once for
 nothing (its first start costs more), and TRACE_SECONDS are traced through the
 program's own control (``llmd_tpu/obs/profiling.py``). Its line holds both
 kinds of metric: counters are deltas over the MEASURED window, trace metrics,
-the step time and the breakdown come from the tail. Off the chip a run fails,
+the step time and the breakdown come from the tail (a reader that lays a
+counter beside the trace takes ``counter_delta_traced``, the counters over
+the traced slice). Off the chip a run fails,
 unless ``--rehearse`` (the builder's CPU rehearsal at a tiny size: exit code
 3, no device metric).
 """
@@ -118,9 +120,9 @@ async def _measure(system, gen, rec, mode: int, trace_dir: pathlib.Path) -> dict
             marks["first_start_stop_s"] = time.monotonic() - t_closed
             await asyncio.sleep(max(0.0, t_closed + TAIL_SETTLE_SECONDS - time.monotonic()))
             await asyncio.to_thread(system.trace_start, str(trace_dir))
-            marks["trace_on"] = time.monotonic()
+            marks["trace_on"], marks["ct0"] = time.monotonic(), system.counters()
             await asyncio.sleep(TRACE_SECONDS)
-            marks["trace_off"] = time.monotonic()
+            marks["trace_off"], marks["ct1"] = time.monotonic(), system.counters()
             await asyncio.to_thread(system.trace_stop)
             marks["trace_stop_s"] = time.monotonic() - marks["trace_off"]
         finally:
@@ -139,11 +141,12 @@ async def _measure(system, gen, rec, mode: int, trace_dir: pathlib.Path) -> dict
             if lead > 0:
                 await asyncio.sleep(lead)
             jax.profiler.start_trace(str(trace_dir))
-            marks["trace_on"] = time.monotonic()
+            marks["trace_on"], marks["ct0"] = time.monotonic(), system.counters()
         await asyncio.sleep(max(0.0, rec.t1 - time.monotonic()))
         marks["c1"] = system.counters()
         marks["steps_at_t1"] = len(system.steps) if system.steps is not None else 0
         if traced:
+            marks["ct1"] = marks["c1"]
             await asyncio.to_thread(jax.profiler.stop_trace)
 
     system.start(record_steps=traced)
@@ -224,10 +227,15 @@ def main(argv=None) -> int:
     closed = marks.get("closed") or window_numbers(rec)
     series = closed["series"]
     series["setup_s"] = [rec.t0 - T_PROCESS]
-    delta = {
-        k: marks["c1"][k] - marks["c0"][k] for k in marks["c0"]
-        if isinstance(marks["c0"][k], (int, float))
-    }
+
+    def counter_delta(a: str, b: str) -> dict:
+        return {k: marks[b][k] - v for k, v in marks[a].items() if isinstance(v, (int, float))}
+
+    delta = counter_delta("c0", "c1")
+    # What the program counted while the profiler was on: a number that is laid
+    # beside the trace comes from the steps that were traced, and a --trace 2
+    # tail does not look like its window (an open-loop cell's starts drained).
+    delta_traced = counter_delta("ct0", "ct1") if "ct1" in marks else None
     device = dict(system.device)
     trace = None
     if traced:
@@ -257,8 +265,8 @@ def main(argv=None) -> int:
         device["peak_hbm_gb"] = peak / 1e9
 
     rctx = {
-        "series": series, "counter_delta": delta, "trace": trace, "device": device,
-        "config": spec.config, "cell": spec.cell, "bench_dir": str(spec.bench_dir),
+        "series": series, "counter_delta": delta, "counter_delta_traced": delta_traced,
+        "trace": trace, "device": device, "config": spec.config, "cell": spec.cell, "bench_dir": str(spec.bench_dir),
     }
     sections = {0: ("end_to_end",), 1: ("per_layer",), 2: ("end_to_end", "per_layer")}[args.trace]
     metrics = {}
@@ -287,7 +295,8 @@ def main(argv=None) -> int:
                  ("first_start_stop_s", "trace_stop_s", "tail_offer_ran_out", "traced_step_ms_mean") if k in marks},
         "traced_programs": programs,
         "setup_log": system.setup_log, "reference_check": check, "kernel_plans": plans,
-        "counter_delta": delta, "compile": {k: marks["c1"][k] for k in marks["c1"] if k.startswith("compile_")},
+        "counter_delta": delta, "counter_delta_traced": delta_traced,
+        "compile": {k: marks["c1"][k] for k in marks["c1"] if k.startswith("compile_")},
         "samples": {k: len(v) for k, v in series.items()},
         "idle_by_host_s": trace and trace["idle_by_host_s"],
         "trace_lines": trace and trace["lines"],

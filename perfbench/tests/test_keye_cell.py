@@ -91,18 +91,20 @@ def test_the_sparse_attention_roofline_counts_the_selected_rows_of_live_tokens()
             "s32[40,2048]{1,0} %c, s32[48]{0} %d, s32[48]{0} %e, bf16[48,4,8,128]{3,2,1,0} %q, f32[4,8]{1,0} %s, "
             "bf16[6,24576,4,16,256]{4,3,2,1,0} %pool, f32[48,1,32768]{2,1,0} %sel), custom_call_target=\"tpu_custom_call\"")
     flops, nbytes = mod.call_cost(name, 2048, 0.75)
-    tokens = 48 * 0.75  # the window's counters say which share of the computed tokens was live
+    tokens = 48 * 0.75  # the traced slice's counters say which share of the computed tokens was live
     assert flops == 4.0 * tokens * 2048 * 32 * 128
     assert nbytes == tokens * 2048 * 4 * 256 * 2 + 2 * tokens * 32 * 128 * 2
     ctx = {"trace": {"op_seconds": {name: 1e-3}, "op_calls": {name: 1}}, "bench_dir": str(BENCH),
            "device": {"kind": "TPU v5 lite"}, "config": {"sa_config": {"topk": 2048}},
-           "counter_delta": {"live_tokens_total": 360, "padded_tokens_total": 120}}
+           "counter_delta": {"live_tokens_total": 1000, "padded_tokens_total": 0},  # the window's: never read
+           "counter_delta_traced": {"live_tokens_total": 360, "padded_tokens_total": 120}}
     d = reducers.definition("per_layer", "kernels.sparse_attention_roofline")
     share = mod.read(ctx, d)
     assert 0 < share < 100 and abs(share - 100 * (nbytes / 819e9) / 1e-3) < 1e-9
     # Nothing caps it: a call timed faster than its selected rows can move reads over 100 %.
     assert mod.read(dict(ctx, trace={"op_seconds": {name: 1e-5}, "op_calls": {name: 1}}), d) > 100
-    assert mod.read(dict(ctx, counter_delta={}), d) is None  # a program without the counters
+    assert mod.read(dict(ctx, counter_delta_traced={}), d) is None  # a program without the counters
+    assert mod.read(dict(ctx, counter_delta_traced=None), d) is None  # a trace and no counters over it
     # A program without the kernel (the parent), or a configuration without
     # an indexer: nothing to read, nothing raised.
     assert mod.read(dict(ctx, trace={"op_seconds": {"%gmm.1 = f32[8,8]": 1.0}, "op_calls": {}}), d) is None

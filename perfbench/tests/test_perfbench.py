@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import asyncio
 import importlib
+import importlib.util
 import json
 import pathlib
 import re
@@ -157,6 +158,137 @@ def test_trace_reduction_on_the_recorded_fixture():
     assert trace_reduce.top_ops(r["op_seconds"], 3)[0][0] == want["top_op"]
 
 
+def _reader(name):
+    spec = importlib.util.spec_from_file_location("r", BENCH / "layer_metrics" / f"{name}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+# %gmm instruction texts: the first as a v5e trace gives it (my chip run, PR 29), the others as the
+# kernels that ROADMAP S9/S10 ask for would name theirs; (text, M, K, N, E, weight width).
+GMM_TEXTS = {
+    "one_layer": ("%gmm.13 = f32[256,768]{1,0:T(8,128)} custom-call(s32[130]{0} %a, s32[130]{0} %b, s32[130]{0} %c, "
+                  "s32[1]{0} %d, bf16[256,2048]{1,0:T(8,128)(2,1)} %x, bf16[128,2048,768]{2,1,0:T(8,128)(2,1)} %w), "
+                  "custom_call_target=\"tpu_custom_call\", operand_layout_constraints={s32[130]{0}, s32[130]{0}, "
+                  "s32[130]{0}, s32[1]{0}, bf16[256,2048]{1,0}, bf16[128,2048,768]{2,1,0}}", 256, 2048, 768, 128, 2),
+    "gate_up_fused": ("%gmm.2 = f32[256,1536]{1,0} custom-call(s32[130]{0} %a, bf16[256,2048]{1,0} %x, "
+                      "bf16[128,2048,1536]{2,1,0} %w)", 256, 2048, 1536, 128, 2),
+    "stacked_with_layer_index": ("%gmm.5 = f32[192,1408]{1,0} custom-call(s32[1]{0} %layer, s32[66]{0} %a, "
+                                 "bf16[192,2048]{1,0} %x, bf16[8,64,2048,1408]{3,2,1,0} %w)", 192, 2048, 1408, 64, 2),
+    "int8_weights": ("%gmm.7 = f32[256,768]{1,0} custom-call(s32[130]{0} %a, bf16[256,2048]{1,0} %x, "
+                     "s8[128,2048,768]{2,1,0} %w)", 256, 2048, 768, 128, 1),
+}
+
+
+@pytest.mark.parametrize("form", sorted(GMM_TEXTS))
+def test_gmm_call_cost_charges_the_experts_that_have_rows(form):
+    """Weight bytes = touched x ONE layer's E x K x N x width, whatever the
+    operand's form; activations, output and FLOPs do not depend on it."""
+    text, m, k, n, e, width = GMM_TEXTS[form]
+    cost = _reader("kernels.moe_gmm_roofline").call_cost
+    flops, all_bytes = cost(text, 1.0)
+    _, half_bytes = cost(text, 0.5)
+    rows = m * k * 2 + m * n * 4
+    assert flops == 2.0 * m * k * n
+    assert all_bytes == e * k * n * width + rows
+    assert half_bytes == 0.5 * e * k * n * width + rows
+
+
+@pytest.mark.parametrize("text", [
+    "%gmm.9 = f32[256,768]{1,0} custom-call(bf16[256,2048]{1,0} %x, bf16[1024,2048,768]{2,1,0} %w, bf16[128,2048,768]{2,1,0} %v)",
+    "%gmm.9 = f32[256,768]{1,0} custom-call(bf16[256,2048]{1,0} %x, bf16[2,8,128,2048,768]{4,3,2,1,0} %w)",
+    "%gmm.9 = f32[256,768]{1,0} custom-call(bf16[256,2048]{1,0} %x, bf16[128,2048,1536]{2,1,0} %w)",
+    "%gmm.9 = f32[256,768]{1,0} custom-call(bf16[256,2048]{1,0} %x)",
+    "%gmm.9 = f32[256,768]{1,0} fusion(bf16[256,2048]{1,0} %x, bf16[128,2048,768]{2,1,0} %w)",
+], ids=["two_weight_operands", "five_dims", "n_differs_from_output", "no_weights", "not_a_custom_call"])
+def test_gmm_call_cost_raises_on_a_form_it_does_not_know(text):
+    with pytest.raises(ValueError, match=r"%gmm\.9"):
+        _reader("kernels.moe_gmm_roofline").call_cost(text, 1.0)
+
+
+def _gmm_ctx(bench=BENCH, **over):
+    text = GMM_TEXTS["one_layer"][0]
+    ctx = {"trace": {"op_seconds": {text: 2e-3, "%fusion.1 = f32[8]{0} fusion()": 1.0}, "op_calls": {text: 2}, "busy_s": 1.1},
+           "bench_dir": str(bench), "device": {"kind": "TPU v5 lite"}, "series": {}, "counter_delta": {},
+           "config": {"num_experts": 128, "num_experts_per_tok": 8},
+           "counter_delta_traced": {"calls_total": 48, "groups_with_rows_total": 48 * 96, "live_tokens_total": 640}}
+    ctx.update(over)
+    return ctx
+
+
+def test_the_gmm_roofline_charges_every_expert_while_no_program_counts():
+    """This tree: ``layer_metrics/`` has no definition of the reader's
+    ``touched_metric``, so the reading is the every-expert upper bound, whatever
+    a program's counters are called; nothing caps it."""
+    d = reducers.definition("per_layer", "kernels.moe_gmm_roofline")
+    assert not (BENCH / "layer_metrics" / f"{d['touched_metric']}.json").exists()
+    text = GMM_TEXTS["one_layer"][0]
+    _, every = _reader("kernels.moe_gmm_roofline").call_cost(text)
+    assert reducers.reduce("per_layer", "kernels.moe_gmm_roofline", _gmm_ctx()) == pytest.approx(100 * 2 * (every / 819e9) / 2e-3)
+    fast = _gmm_ctx(trace={"op_seconds": {text: 0.8 * every / 819e9}, "op_calls": {text: 1}})
+    assert reducers.reduce("per_layer", "kernels.moe_gmm_roofline", fast) == pytest.approx(125.0)
+
+
+@pytest.fixture
+def counting_bench(tmp_path):
+    """What the PR that makes a program count adds, as DATA: the touched share
+    as a ``counter_ratio`` over the traced slice, under the name the roofline's
+    definition gives; no file of the benchmark is edited."""
+    lm = tmp_path / "layer_metrics"
+    lm.mkdir()
+    shutil.copy(BENCH / "peaks.json", tmp_path)
+    for f in ("kernels.moe_gmm_roofline.json", "kernels.moe_gmm_roofline.py"):
+        shutil.copy(BENCH / "layer_metrics" / f, lm)
+    (lm / "kernels.moe_experts_touched_share.json").write_text(json.dumps({
+        "kind": "counter_ratio", "over": "traced", "num": ["groups_with_rows_total"], "den": ["calls_total"],
+        "den_config": ["num_experts", "n_routed_experts"], "scale": 100.0,
+        "layer": "kernels", "source": "program_counter", "moves": "output_tok_s"}))
+    return tmp_path
+
+
+def test_the_gmm_roofline_takes_the_share_of_a_program_that_counts(counting_bench):
+    ctx = _gmm_ctx(counting_bench)
+    text = GMM_TEXTS["one_layer"][0]
+    _, nbytes = _reader("kernels.moe_gmm_roofline").call_cost(text, 0.75)
+    assert reducers.reduce("per_layer", "kernels.moe_experts_touched_share", ctx) == pytest.approx(75.0)
+    assert reducers.reduce("per_layer", "kernels.moe_gmm_roofline", ctx) == pytest.approx(100 * 2 * (nbytes / 819e9) / 2e-3)
+    # a kernel at 80 % of the weight stream with every expert charged reads 125 %; with its half, under 100
+    _, every = _reader("kernels.moe_gmm_roofline").call_cost(text)
+    fast = _gmm_ctx(counting_bench, trace={"op_seconds": {text: 0.8 * every / 819e9}, "op_calls": {text: 1}},
+                    counter_delta_traced={"calls_total": 2, "groups_with_rows_total": 128})
+    assert 60 < reducers.reduce("per_layer", "kernels.moe_gmm_roofline", fast) < 70
+    # DeepSeek's key for the routed experts; the window's counters are not the slice's
+    ds = _gmm_ctx(counting_bench, config={"n_routed_experts": 64}, counter_delta={"calls_total": 1, "groups_with_rows_total": 1})
+    assert reducers.reduce("per_layer", "kernels.moe_experts_touched_share", ds) == pytest.approx(150.0)
+
+
+@pytest.mark.parametrize("over", [
+    {"counter_delta_traced": {"engine_steps_total": 20, "live_tokens_total": 640}},
+    {"counter_delta_traced": {"calls_total": 0, "groups_with_rows_total": 0}},
+    {"counter_delta_traced": {"calls_total": 48, "groups_with_rows_total": 0}},
+    {"counter_delta_traced": None},
+    {"config": {"hidden_size": 2048}},
+    {"config": {"n_routed_experts": 64}},
+], ids=["counters_missing", "both_deltas_zero", "no_group_with_rows", "no_traced_slice", "no_routed_experts", "over_100"])
+def test_once_a_program_counts_gmm_calls_without_a_share_raise(counting_bench, over):
+    """Never a fall back to every expert once the share has a definition, and
+    none estimated from the steps' tokens (tokens that share a context share
+    experts: 32 rows touched 59-66 % of 128 experts where independence says 87)."""
+    with pytest.raises(RuntimeError, match="kernels.moe_experts_touched_share"):
+        reducers.reduce("per_layer", "kernels.moe_gmm_roofline", _gmm_ctx(counting_bench, **over))
+
+
+def test_a_trace_without_gmm_calls_gives_no_roofline(counting_bench):
+    for bench in (BENCH, counting_bench):
+        no_gmm = _gmm_ctx(bench, trace={"op_seconds": {"%fusion.1 = f32[8]{0} fusion()": 1.0}, "op_calls": {}},
+                          counter_delta_traced=None)
+        assert reducers.reduce("per_layer", "kernels.moe_gmm_roofline", no_gmm) is None
+        assert reducers.reduce("per_layer", "kernels.moe_gmm_roofline", _gmm_ctx(bench, trace=None)) is None
+    untraced = _gmm_ctx(counting_bench, trace=None, counter_delta_traced=None)  # --trace 0: no slice
+    assert reducers.reduce("per_layer", "kernels.moe_experts_touched_share", untraced) is None
+
+
 IDLE_PHASES = ("schedule", "launch", "finish", "unattributed")
 
 
@@ -275,6 +407,9 @@ def test_trace2_rehearsal_reports_both_sections(cell, capsys):
     assert counters - {"device.peak_hbm_gb"} <= reported  # and what a CPU can of --trace 1's
     assert {"runner.step_ms_p50", "runner.launch_ms", "runner.retraces_in_window"} <= reported
     assert not (run.OUT_DIR / "trace" / cell).exists()  # the trace is deleted once reduced
+    # the readers were given what the program counted while the profiler was on
+    detail = json.loads((run.OUT_DIR / f"{cell}.seed{2**31 + 11}.trace2.json").read_text())
+    assert 0 < detail["counter_delta_traced"]["live_tokens_total"] != detail["counter_delta"]["live_tokens_total"]
 
 
 @pytest.mark.parametrize("cell", ["qwen3-30b-a3b.chat", "deepseek-v2-lite.long-decode"])
