@@ -325,6 +325,18 @@ class EngineStats:
     # T granule. padded / live is the padding-waste gauge.
     live_tokens_total: int = 0
     padded_tokens_total: int = 0
+    # The grouped expert matmul (one-device "grouped" MoE backend; 0 for a
+    # dense model and under wide-EP, which has its own census): grouped
+    # MoE layer calls of the step programs (one a layer and step; gate, up
+    # and down share it), and over those calls the groups (experts) with
+    # at least one row AS THE KERNEL SEES THEM: the zero rows
+    # ops/grouped_gemm.py pads into the last group make it non-empty, and
+    # a pad token is a token. Counted on the device, read with each step's
+    # one readback and added at that step's finish. groups / (calls x
+    # experts) is the share of expert weights a call reads: what
+    # kernels.moe_gmm_roofline charges.
+    moe_grouped_calls_total: int = 0
+    moe_groups_with_rows_total: int = 0
     # Learned sparse attention (models with an indexer; 0 elsewhere),
     # counted on the host from each flat step's positions: computed query
     # tokens that had more than indexer_topk cached tokens (the selection
@@ -719,7 +731,7 @@ class LLMEngine:
         # steps. EPLB is leader-only single-host (the remap gather is a
         # host-driven reshard).
         pc = config.parallel
-        self._moe_active = self.runner._moe_census is not None
+        self._moe_active = self.runner._ep_active
         self._moe_expert_tokens = (
             np.zeros(config.model.num_experts, np.int64)
             if self._moe_active else None
@@ -2125,6 +2137,8 @@ class LLMEngine:
         self.stats.live_tokens_total = self.runner.live_tokens_total
         self.stats.padded_tokens_total = self.runner.padded_tokens_total
         r = self.runner
+        self.stats.moe_grouped_calls_total = r.moe_grouped_calls_total
+        self.stats.moe_groups_with_rows_total = r.moe_groups_with_rows_total
         self.stats.sparse_bound_tokens_total = r.sparse_bound_tokens_total
         self.stats.sparse_unbound_tokens_total = r.sparse_unbound_tokens_total
         self.stats.indexer_keys_scored_total = r.indexer_keys_scored_total

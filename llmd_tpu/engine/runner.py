@@ -385,6 +385,25 @@ class ModelRunner:
                 np.zeros(census_size(self.cfg), np.float32),
                 mesh_ctx.replicated,
             )
+        elif (
+            self.cfg.is_moe and pc.moe_backend == "grouped"
+            and mesh_ctx.world == 1
+        ):
+            # The one-device grouped backend threads the grouped expert
+            # matmul's count through the same argument (llama.
+            # forward_hidden): [2] i32, grouped MoE layer calls and the
+            # groups with rows as the kernel sees them. Every step
+            # program adds its own, hands the sum back in its packed
+            # output and this accumulator back at zero (_count_row), so
+            # the count comes home with the step's one readback
+            # (wait_step) and is the step's own. The wide-EP path keeps
+            # its census above and is not counted here; nor is the embed
+            # program, which is no step.
+            self._moe_census = jax.device_put(
+                np.zeros(2, np.int32), mesh_ctx.replicated
+            )
+        self.moe_grouped_calls_total = 0
+        self.moe_groups_with_rows_total = 0
         # Pristine logical [L, E, ...] expert leaves, stashed on first
         # EPLB remap so later placements regather from the un-replicated
         # originals; the host-side Placement mirrors params["moe_placement"].
@@ -639,7 +658,7 @@ class ModelRunner:
         a capacity-factor multiple). Called by the engine's stats refresh
         once per step — the read rides the sync the stats path already
         does."""
-        if self._moe_census is None:
+        if not self._ep_active:
             return None
         from llmd_tpu.parallel.distributed import replicated_to_host
 
@@ -959,6 +978,25 @@ class ModelRunner:
             kv_swa = out[2]
         return hidden, kv_cache, kv_swa, census
 
+    @property
+    def _counts_grouped(self) -> bool:
+        """The census argument is the grouped expert matmul's [2] count
+        (armed in __init__), not the wide-EP census."""
+        return self._moe_census is not None and not self._ep_active
+
+    def _count_row(self, packed: jax.Array, census):
+        """Last lines of every step program's body. The grouped count
+        leaves the device as one more row of the packed output, [calls,
+        groups with rows, 0, ...] in f32 (exact: a program counts at most
+        iterations x layers x experts, far under 2**24), and the
+        accumulator goes back zeroed for the next step. Any other census
+        (wide-EP, none) passes through."""
+        if not self._counts_grouped:
+            return packed, census
+        row = jnp.zeros((1, packed.shape[1]), packed.dtype)
+        row = row.at[0, :2].set(census.astype(packed.dtype))
+        return jnp.concatenate([packed, row]), jnp.zeros_like(census)
+
     def _note_traced(self, family: str, shape) -> None:
         """First line of every jitted step program's body, so it runs
         once per trace of the program (a new shape, or a rebuilt family):
@@ -1001,6 +1039,7 @@ class ModelRunner:
             packed = jnp.concatenate(
                 [tokens.astype(jnp.float32)[:, None], logprobs[:, None]], axis=1
             )
+            packed, census = self._count_row(packed, census)
             return kv_cache, kv_swa, replicate(packed), census
 
         return llmd_prefill_step
@@ -1050,6 +1089,7 @@ class ModelRunner:
                 ],
                 axis=1,
             )
+            packed, census = self._count_row(packed, census)
             return kv_cache, kv_swa, replicate(packed), census
 
         return llmd_verify_step
@@ -1160,6 +1200,7 @@ class ModelRunner:
                 ],
                 axis=1,
             )  # [B, 2S]
+            packed, census = self._count_row(packed, census)
             return kv_cache, kv_swa, replicate(packed), census
 
         return llmd_unified_step
@@ -1271,6 +1312,7 @@ class ModelRunner:
                 ],
                 axis=1,
             )  # [B, 2S]
+            packed, census = self._count_row(packed, census)
             return kv_cache, kv_swa, replicate(packed), census
 
         return llmd_flat_step
@@ -1455,6 +1497,7 @@ class ModelRunner:
             packed = jnp.concatenate(
                 [meta, out_t.astype(jnp.float32), out_l], axis=1
             )  # [B, 4 + 2*Wmax]
+            packed, census = self._count_row(packed, census)
             return kv_cache, kv_swa, replicate(packed), census
 
         return llmd_verify_window
@@ -1529,6 +1572,7 @@ class ModelRunner:
             packed = jnp.concatenate(
                 [out_t.astype(jnp.float32), out_l], axis=1
             )  # [B, 2K]
+            packed, census = self._count_row(packed, census)
             return kv_cache, kv_swa, replicate(packed), census
 
         return llmd_decode_window
@@ -3723,6 +3767,10 @@ class ModelRunner:
             hosts = [dist.replicated_to_host(p) for p in packs]
         else:
             hosts = [np.asarray(a) for a in jax.device_get(packs)]
+        if self._counts_grouped:
+            for arr in hosts:  # _count_row's line, below every result row
+                self.moe_grouped_calls_total += int(arr[-1, 0])
+                self.moe_groups_with_rows_total += int(arr[-1, 1])
         pres = dres = None
         base = 0
         if prefill is not None:

@@ -201,15 +201,19 @@ def forward_hidden(
 ):
     """Run the decoder stack; returns (hidden [B, Q, H], new kv_cache) —
     or (hidden, new kv_cache, new kv_swa) when ``kv_swa`` is given.
-    When ``moe_census`` (the runner's [E+2] accumulator) is given, the
-    updated census is appended to the return tuple.
+    When ``moe_census`` (the runner's accumulator) is given, the updated
+    census is appended to the return tuple.
 
     ``moe_overlap``/``moe_placement``/``moe_census`` plumb the wide-EP
     perf layers into ``moe_block_ep`` (parallel/moe_ep.py): microbatched
     overlapped dispatch, the EPLB physical-placement tables, and the
-    per-expert routed-token / dropped-slot / dispatch-demand stats vector
-    (merged across layers as a scan output: counts add, demand maxes).
-    All three are no-ops unless ``moe_backend == "ep"``.
+    [E+2] per-expert routed-token / dropped-slot / dispatch-demand stats
+    vector (merged across layers as a scan output: counts add, demand
+    maxes). The first two are no-ops unless ``moe_backend == "ep"``. Under
+    the one-device ``"grouped"`` backend ``moe_census`` is the [2] i32
+    count of the grouped expert matmul (ops/grouped_gemm.py::
+    grouped_census: grouped MoE layer calls, groups with rows as the
+    kernel sees them; both add) and rides the same scan output.
 
     ``kv_swa`` (CacheConfig.swa_ring) is a second, smaller pool holding
     ONLY the sliding-window layers; those layers index it through
@@ -288,9 +292,15 @@ def forward_hidden(
         and not cfg.is_mla and Q % cp_prefill == 0
     )
 
-    use_census = moe_census is not None and cfg.is_moe and moe_backend == "ep"
+    grouped = moe_backend == "grouped" and world_size == 1
+    use_census = (
+        moe_census is not None and cfg.is_moe
+        and (moe_backend == "ep" or grouped)
+    )
 
     def _census_merge(a, b):
+        if grouped:  # grouped_census: calls and groups with rows both add
+            return a + b
         # Census layout (moe_ep): counts in [:-1] add, the max-demand
         # element in [-1] maxes.
         return jnp.concatenate([a[:-1] + b[:-1], jnp.maximum(a[-1:], b[-1:])])
@@ -308,10 +318,13 @@ def forward_hidden(
                     emit_census=use_census,
                 )
                 return out if use_census else (out, None)
-            if moe_backend == "grouped" and world_size == 1:
+            if grouped:
                 from llmd_tpu.models.moe import moe_block_grouped
 
-                return moe_block_grouped(h2, lp, cfg, mesh=mesh), None
+                out = moe_block_grouped(
+                    h2, lp, cfg, mesh=mesh, emit_census=use_census
+                )
+                return out if use_census else (out, None)
             # Sharded jit without the EP backend: the dense combine is
             # the only path GSPMD can partition (expert weights are
             # EP-sharded; the grouped kernel has no partitioning rule
@@ -538,7 +551,10 @@ def forward_hidden(
 
     def _reduce_census(stacked):
         """Reduce per-layer census deltas [n, E+2] into the accumulator:
-        counts sum over layers; the demand element takes the max."""
+        counts sum over layers; the demand element takes the max. (The
+        grouped count's [n, 2] lines sum.)"""
+        if grouped:
+            return jnp.sum(stacked, axis=0)
         return jnp.concatenate([
             jnp.sum(stacked[:, :-1], axis=0),
             jnp.max(stacked[:, -1:], axis=0),

@@ -127,11 +127,14 @@ def expert_glu(gate: jax.Array, up: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 
 def moe_block_grouped(
-    h: jax.Array, lp: dict, cfg: ModelConfig, mesh=None
+    h: jax.Array, lp: dict, cfg: ModelConfig, mesh=None,
+    emit_census: bool = False,
 ) -> jax.Array:
     """MoE FFN via grouped GEMM (DeepGEMM role): tokens sorted by expert,
     each expert multiplies only its routed rows. Numerically equivalent to
-    the dense combine (same f32 weighted sum) at top_k/E of the FLOPs."""
+    the dense combine (same f32 weighted sum) at top_k/E of the FLOPs.
+    With ``emit_census`` the return is ``(y, census)``: this call's [2] i32
+    line of the step's count (``ops.grouped_gemm.grouped_census``)."""
     from llmd_tpu.ops.grouped_gemm import moe_apply_grouped
 
     B, Q, H = h.shape
@@ -143,11 +146,16 @@ def moe_block_grouped(
     out = moe_apply_grouped(
         ht, weights, ids, lp["we_gate"], lp["we_up"], lp["we_down"],
         scales=_expert_scales(lp), biases=_expert_biases(lp), cfg=cfg,
-        mesh=mesh,
-    ).astype(h.dtype)
+        mesh=mesh, emit_census=emit_census,
+    )
+    census = None
+    if emit_census:
+        out, census = out
+    out = out.astype(h.dtype)
     if cfg.shared_expert_intermediate_size:
         out = out + shared_expert_ffn(ht, lp)
-    return out.reshape(B, Q, H)
+    out = out.reshape(B, Q, H)
+    return (out, census) if emit_census else out
 
 
 def moe_block(h: jax.Array, lp: dict, cfg: ModelConfig) -> jax.Array:

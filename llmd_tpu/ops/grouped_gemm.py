@@ -35,6 +35,74 @@ def _use_megablox(H: int, F: int, mesh=None) -> bool:
     ) != "xla"
 
 
+# What the kernel's blocks may take of VMEM. megablox asks for no limit of its
+# own, so the compiler's scoped default bounds one call (16 MiB on a v5e), and
+# the compiler wants room beside the blocks for the operands it loads: a
+# described v5e accepts every expert shape of the registry at 12 MiB of blocks
+# (tests/test_chip_compile.py) and refuses some at 14.5.
+_VMEM_BUDGET = 12 << 20
+# Lanes of a weight tile where K itself has to be split (see gmm_tiles).
+_TN_SPLIT_K = 1024
+
+
+def _even_tile(total: int, most: int) -> int:
+    """The tile (a multiple of 128) that covers ``total`` in the fewest
+    tiles of at most ``most``, of even length: 1,408 under 1,152 is 768 +
+    640, not 1,152 + 256. A short last tile costs a masked pass."""
+    parts = -(-total // most)
+    return -(-total // (parts * 128)) * 128
+
+
+def gmm_tiles(
+    K: int, N: int, w_bytes: int, x_bytes: int = 2,
+    budget: int = _VMEM_BUDGET,
+) -> tuple[int, int]:
+    """megablox's (tk, tn) for a [*, K] x [G, K, N] grouped matmul, K and N
+    multiples of 128, from the expert's shape alone (sized for the largest
+    row tile, 128, so that a step's row count never moves them).
+
+    The grid is (n tiles, active m tiles, k tiles) and every step moves one
+    tk x tn weight block: at 128 x 128 (32 KB) the kernel is bound by its
+    grid steps at an eighth of the HBM bandwidth, so the block is made as
+    large as ``budget`` lets the double-buffered activation, weight and f32
+    output blocks plus the f32 accumulator be. What a sweep on a v5e kept
+    (PERF.md section 6, PR 30):
+
+    - ``tk`` is the whole K wherever that fits with any ``tn``: steps of
+      one row tile then find their activation block in place, where a split
+      K fetches it again at every step (15-17 % at 512 tokens).
+    - ``tn`` is then the widest that fits, in tiles of even length: the
+      whole N for 2,048 x 768 and 768 x 2,048, 768 + 640 for N 1,408
+      (1,024 + 384 read 10 % slower than either).
+    - Where not even ``tn`` 128 fits beside the whole K (Mixtral's down
+      projection), ``tn`` is N in even tiles of at most ``_TN_SPLIT_K``
+      and ``tk`` the fewest even tiles of K that fit beside it.
+
+    Never under 128 x 128.
+    """
+    tm = 128
+
+    def blocks(tk: int, tn: int) -> int:
+        return 2 * (tm * tk * x_bytes + tk * tn * w_bytes + tm * tn * 4) + tm * tn * 4
+
+    def widest(total: int, fits) -> int:
+        return max(
+            (t for t in range(128, total + 1, 128) if fits(t)), default=128
+        )
+
+    if blocks(K, 128) <= budget:
+        return K, _even_tile(N, widest(N, lambda tn: blocks(K, tn) <= budget))
+    tn = _even_tile(N, min(N, _TN_SPLIT_K))
+    return _even_tile(K, widest(K, lambda tk: blocks(tk, tn) <= budget)), tn
+
+
+def _row_tile(T: int) -> tuple[int, int]:
+    """megablox's row tile for T rows (sublane-aligned, at most 128) and
+    the zero rows that pad T up to a multiple of it."""
+    tm = min(128, -(-max(T, 1) // 8) * 8)
+    return tm, (-T) % tm
+
+
 @jax.named_scope("llmd.moe.gmm")
 def grouped_matmul(
     x: jax.Array,            # [T, K_dim] tokens sorted by group
@@ -51,15 +119,16 @@ def grouped_matmul(
         # up to the (8-aligned) tile. Pad rows are zero and land in the
         # LAST group (group_sizes must sum to m); their zero outputs are
         # sliced off below.
-        tm = min(128, -(-max(T, 1) // 8) * 8)
-        pad = (-T) % tm
+        tm, pad = _row_tile(T)
         if pad:
             x = jnp.concatenate([x, jnp.zeros((pad, K_dim), x.dtype)], axis=0)
             group_sizes = group_sizes.at[-1].add(pad)
         out = gmm(
             x, w, group_sizes.astype(jnp.int32),
             preferred_element_type=jnp.float32,
-            tiling=(tm, 128, 128),
+            tiling=(tm, *gmm_tiles(
+                K_dim, N, w.dtype.itemsize, x.dtype.itemsize, _VMEM_BUDGET
+            )),
             interpret=ops._interpret(),
         )
         return out[:T].astype(x.dtype)
@@ -67,6 +136,25 @@ def grouped_matmul(
         x, w, group_sizes.astype(jnp.int32),
         preferred_element_type=jnp.float32,
     ).astype(x.dtype)
+
+
+def grouped_census(
+    group_sizes: jax.Array,  # [G] rows per group, before any padding
+    T: int,                  # their sum, a trace-time number
+    padded: bool,
+) -> jax.Array:              # [2] i32
+    """One grouped expert layer's line of the step's count: (1 call, the
+    groups with at least one row AS THE KERNEL SEES THEM). The zero rows
+    ``grouped_matmul`` pads into the last group make that group non-empty:
+    the kernel reads its weights, so it counts (``padded``: the call takes
+    the megablox path, the only one that pads). Gate, up and down of a
+    layer see the same group sizes and share this one line."""
+    has_rows = group_sizes > 0
+    if padded and _row_tile(T)[1]:
+        has_rows = has_rows.at[-1].set(True)
+    return jnp.stack(
+        [jnp.int32(1), jnp.sum(has_rows, dtype=jnp.int32)]
+    )
 
 
 def expert_mlp_grouped(
@@ -121,8 +209,11 @@ def moe_apply_grouped(
     biases: tuple | None = None,
     cfg=None,
     mesh=None,
+    emit_census: bool = False,
 ) -> jax.Array:          # [T, H] f32
-    """Route -> sort-by-expert -> grouped MLP -> weighted unsort-combine."""
+    """Route -> sort-by-expert -> grouped MLP -> weighted unsort-combine.
+    With ``emit_census`` the return is ``(y, census)``, ``census`` this
+    layer's ``grouped_census`` line."""
     T, H = ht.shape
     k = ids.shape[1]
     E = we_gate.shape[0]
@@ -140,8 +231,14 @@ def moe_apply_grouped(
         biases=biases, cfg=cfg, mesh=mesh,
     )
     w_sorted = weights.reshape(-1)[order]
-    return (
+    y = (
         jnp.zeros((T, H), jnp.float32)
         .at[tok]
         .add(ys.astype(jnp.float32) * w_sorted[:, None])
+    )
+    if not emit_census:
+        return y
+    return y, grouped_census(
+        group_sizes, T * k,
+        padded=scales is None and _use_megablox(H, we_gate.shape[2], mesh),
     )

@@ -164,6 +164,12 @@ def render_metrics(
         "resume_replayed_tokens_total": stats.resume_replayed_tokens_total,
         "stream_resume_failures_total": stats.stream_resume_failures_total,
     }
+    if stats.moe_grouped_calls_total:
+        # The grouped expert matmul's count (one-device grouped MoE
+        # backend only): groups / (calls x experts) is the share of the
+        # expert weights a call reads.
+        counters["moe_grouped_calls_total"] = stats.moe_grouped_calls_total
+        counters["moe_groups_with_rows_total"] = stats.moe_groups_with_rows_total
     if stats.indexer_keys_written_total:
         # Learned sparse attention (models with an indexer only).
         counters["sparse_bound_tokens_total"] = stats.sparse_bound_tokens_total

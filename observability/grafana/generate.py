@@ -378,6 +378,20 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                    "binds. Keys scored per bound token is their context "
                    "length: the indexer's scores and the dense pass "
                    "under the mask grow with it."),
+        panel("Grouped expert matmul: experts with rows",
+              [f"rate(llmd:moe_groups_with_rows_total{M}[5m]) / "
+               f"rate(llmd:moe_grouped_calls_total{M}[5m])",
+               f"rate(llmd:moe_grouped_calls_total{M}[5m])"],
+              legends=["experts with rows per grouped layer call",
+                       "grouped layer calls/s"],
+              desc="One-device grouped MoE backend only "
+                   "(docs/architecture/observability.md). Experts that "
+                   "had at least one row in a grouped MoE layer call, as "
+                   "the kernel sees them (the rows padded into the last "
+                   "group count): over the model's experts it is the "
+                   "share of expert weights a call streams from HBM, "
+                   "which bounds the kernel's time from below. Few rows "
+                   "a step or tokens that share a context touch fewer."),
         row("Speculative decoding"),
         panel("Draft acceptance", [f"llmd:spec_acceptance_rate{M}"],
               unit="percentunit", max1=True,

@@ -163,12 +163,26 @@ def _mla_decode():
 def _gmm(hidden, ffn, experts, device):
     # The grouped GEMM picks megablox from the devices of the mesh it is
     # given: hand it the described chip (the default backend is the CPU).
+    # The tile comes from grouped_gemm.gmm_tiles: a weight block that
+    # overflows VMEM shows here (megablox sets no limit of its own).
     mesh = Mesh(np.asarray([device]).reshape(1, 1), ("dp", "tp"))
     return (
         lambda x, w, g: grouped_matmul(x, w, g, mesh),
         [((2048, hidden), BF16), ((experts, hidden, ffn), BF16),
          ((experts,), I32)],
     )
+
+
+# (hidden, expert width, experts): the two expert shapes of the benchmark's
+# cells, and the registry's shapes whose whole K does not fit VMEM beside a
+# wide tile. Each compiles as gate/up (hidden -> width) and down.
+GMM_MODELS = {
+    "qwen3-30b-a3b": (2048, 768, 128),
+    "deepseek-v2-lite": (2048, 1408, 64),
+    "mixtral-8x7b": (4096, 14336, 8),
+    "mixtral-8x22b": (6144, 16384, 8),
+    "deepseek-r1": (7168, 2048, 256),
+}
 
 
 CASES = {
@@ -184,8 +198,14 @@ CASES = {
     "decode_write-bf16": lambda d: _decode_write(LLAMA, BF16),
     "decode_write-int8": lambda d: _decode_write(LLAMA, I8),
     "mla_decode-deepseek-v2-lite": lambda d: _mla_decode(),
-    "gmm-deepseek-v2-lite": lambda d: _gmm(2048, 1408, 64, d),
-    "gmm-qwen3-30b-a3b": lambda d: _gmm(2048, 768, 128, d),
+    **{
+        f"gmm-{name}": lambda d, m=m: _gmm(m[0], m[1], m[2], d)
+        for name, m in GMM_MODELS.items()
+    },
+    **{
+        f"gmm_down-{name}": lambda d, m=m: _gmm(m[1], m[0], m[2], d)
+        for name, m in GMM_MODELS.items()
+    },
 }
 
 
