@@ -1025,8 +1025,6 @@ def _run_part(part: str):
         return bench_async_step()
     if part == "spec_decode":
         return bench_spec_decode()
-    if part == "spec_window":
-        return bench_spec_window()
     if part == "unified_step":
         return bench_unified_step()
     if part == "ragged_step":
@@ -2308,138 +2306,6 @@ def bench_spec_decode():
     return out
 
 
-def bench_spec_window():
-    """Fused verify window (spec x decode_window) CPU-sim microbench:
-    the SAME speculative engine at window 1 (one-shot verify, one
-    dispatch per verify step) vs window 4 (K verify iterations fused,
-    accept/reject on device, ONE readback per window). The headline is
-    DISPATCHES PER EMITTED TOKEN — on a remote-dispatch TPU runtime the
-    host round-trip per dispatch is the decode wall, so this ratio IS
-    the transferable number (the CPU sim is compute-bound and its
-    wall-clock understates the win). ``repetitive`` (periodic prompts,
-    greedy — drafts accept, windows run hot) must show the window=4
-    ratio at <= 0.5x the window=1 ratio; ``adversarial`` (random
-    prompts, temperature sampling — drafts never fire, every window
-    degrades to the plain fused decode program) guards the degrade
-    path: its tok/s ratio must stay within noise
-    (docs/architecture/speculative-decoding.md)."""
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    import statistics
-
-    import numpy as np
-
-    from llmd_tpu.config import (
-        CacheConfig, EngineConfig, ParallelConfig, SchedulerConfig,
-        tiny_model_config,
-    )
-    from llmd_tpu.engine import LLMEngine, SamplingParams
-
-    B, ISL, OSL, K = 8, 64, 64, 4
-    WINDOWS = (1, 4)
-    model = tiny_model_config(max_model_len=256)
-
-    def make_engine(window: int) -> LLMEngine:
-        cfg = EngineConfig(
-            model=model,
-            cache=CacheConfig(page_size=16, num_blocks=512, dtype="float32"),
-            scheduler=SchedulerConfig(
-                max_num_seqs=B, max_num_batched_tokens=B * ISL,
-                speculative_ngram=True, spec_ngram_k=K,
-                spec_ngram_min_match=2, decode_window=window,
-            ),
-            parallel=ParallelConfig(tensor_parallel_size=1),
-            seed=0,
-        )
-        return LLMEngine(cfg)
-
-    def run(workload: str) -> dict:
-        rng = np.random.default_rng(0)
-        if workload == "repetitive":
-            sp = SamplingParams(
-                temperature=0.0, max_tokens=OSL, ignore_eos=True
-            )
-            mk = lambda: [  # noqa: E731
-                list(rng.integers(1, model.vocab_size, size=8)) * (ISL // 8)
-                for _ in range(B)
-            ]
-        else:
-            sp = SamplingParams(
-                temperature=1.0, max_tokens=OSL, ignore_eos=True
-            )
-            mk = lambda: [  # noqa: E731
-                list(rng.integers(1, model.vocab_size, size=ISL))
-                for _ in range(B)
-            ]
-        engines = {w: make_engine(w) for w in WINDOWS}
-        for eng in engines.values():  # warm every shape family
-            eng.generate(mk(), sp)
-            eng.generate(mk(), sp)
-        for eng in engines.values():
-            st = eng.stats
-            st.decode_dispatches_total = 0
-            st.generation_tokens = 0
-            st.engine_steps_total = 0
-            st.step_host_gap_ms_total = 0.0
-            # The scheduler-side counter is what _refresh_gauges copies
-            # into spec_window_iters_total — reset it too, or the
-            # reported iters mix the warmup generations into the
-            # measured rounds.
-            eng.scheduler.spec_window_iters = 0
-            eng.scheduler.spec_window_early_exit = 0
-        # PAIRED rounds (see bench_spec_decode): same fresh prompts to
-        # both engines back to back so host drift cancels in the ratio.
-        rates: dict[int, list[float]] = {w: [] for w in WINDOWS}
-        for _ in range(5):
-            prompts = mk()
-            for w, eng in engines.items():
-                t0 = time.monotonic()
-                out = eng.generate([list(p) for p in prompts], sp)
-                dt = time.monotonic() - t0
-                total = sum(len(v) for v in out.values())
-                assert total == B * OSL, (total, B * OSL)
-                rates[w].append(total / dt)
-        res: dict = {}
-        for w, eng in engines.items():
-            st = eng.stats
-            res[f"window{w}"] = {
-                "tok_s": round(statistics.median(rates[w]), 1),
-                "dispatches_per_token": round(
-                    st.decode_dispatches_total / max(st.generation_tokens, 1),
-                    4,
-                ),
-                "host_gap_ms_mean": round(
-                    st.step_host_gap_ms_total / max(st.engine_steps_total, 1),
-                    3,
-                ),
-                "spec_window_iters": st.spec_window_iters_total,
-            }
-        d1 = res["window1"]["dispatches_per_token"]
-        d4 = res[f"window{WINDOWS[-1]}"]["dispatches_per_token"]
-        res["dispatch_ratio"] = round(d4 / max(d1, 1e-9), 3)
-        res["tok_s_ratio"] = round(
-            statistics.median(
-                hi / lo
-                for lo, hi in zip(rates[WINDOWS[0]], rates[WINDOWS[-1]])
-            ),
-            3,
-        )
-        return res
-
-    out: dict = {}
-    for workload in ("repetitive", "adversarial"):
-        out[workload] = run(workload)
-    out["substrate"] = (
-        "tiny model on CPU (compute-bound): dispatch_ratio (repetitive, "
-        "expect <= 0.5) and the adversarial tok_s_ratio (expect >= "
-        "0.95) are the transferable numbers — on an RTT-dominated TPU "
-        "runtime dispatches-per-token IS the decode wall the window "
-        "removes"
-    )
-    return out
-
-
 def _bench_dbo_delta():
     """Dual-batch-overlap on/off wall-clock on the virtual 8-device CPU
     mesh (the only multi-device substrate here; real-slice numbers come
@@ -2778,7 +2644,7 @@ def _part_in_subprocess(part: str, retries: int = 0, timeout: float = 1800):
 # Parts whose substrate is the CPU sim (forced inside the part itself):
 # runnable in CI / under --skip-chip without a device.
 _CPU_PARTS = frozenset({
-    "dbo", "async_step", "spec_decode", "spec_window", "unified_step",
+    "dbo", "async_step", "spec_decode", "unified_step",
     "ragged_step", "fault_degrade", "fleet_soak", "kv_federation",
     "stream_resume", "batch_backfill", "lora_pool", "pd_stream",
     "moe_ep", "moe_overlap", "long_context",
@@ -2793,7 +2659,7 @@ _CPU_PARTS = frozenset({
 # driver's kill) lands, the summary already holds everything cheaper.
 _ALL_PARTS = (
     "ragged_step", "unified_step", "async_step", "spec_decode",
-    "spec_window", "dbo", "moe_ep", "moe_overlap", "fault_degrade",
+    "dbo", "moe_ep", "moe_overlap", "fault_degrade",
     "fleet_soak", "kv_federation",
     "stream_resume", "batch_backfill", "lora_pool", "pd_stream",
     "long_context",
@@ -2950,7 +2816,6 @@ def main() -> None:
         "unified_step": (set_key("unified_step"), None),
         "async_step": (set_key("async_step"), None),
         "spec_decode": (set_key("spec_decode"), None),
-        "spec_window": (set_key("spec_window"), None),
         "dbo": (set_key("dbo"), None),
         "moe_ep": (set_key("moe_ep"), None),
         "moe_overlap": (set_key("moe_overlap"), None),

@@ -19,8 +19,8 @@ Rules:
   every distinct value compiles a new program).
 - TD003: ``donate_argnums`` index out of range for the wrapped function.
 - TD004: a method dispatching a shape-family opcode (``_sync`` with
-  ``_OP_PREFILL``/``_OP_DECODE``/``_OP_VERIFY``/``_OP_VERIFY_WINDOW``/
-  ``_OP_UNIFIED``/``_OP_FLAT``/``_OP_EMBED``) that neither buckets its
+  ``_OP_PREFILL``/``_OP_DECODE``/``_OP_VERIFY``/``_OP_UNIFIED``/
+  ``_OP_FLAT``/``_OP_EMBED``) that neither buckets its
   shapes (``pad_to_bucket``) nor consumes a prestaged ``Staged*`` batch
   nor is a declared warmup (``_warm_*``). The flattened-token family
   (``_OP_FLAT``) is shape-disciplined on its T axis alone: the stream
@@ -39,8 +39,8 @@ from llmd_tpu.analysis.core import Checker, Finding, Repo, register
 _CONSTRUCTION_PREFIXES = ("_build_", "_alloc_", "_warm_")
 _CONSTRUCTION_NAMES = {"__init__"}
 _SHAPE_FAMILY_OPS = {
-    "_OP_PREFILL", "_OP_DECODE", "_OP_VERIFY", "_OP_VERIFY_WINDOW",
-    "_OP_UNIFIED", "_OP_FLAT", "_OP_EMBED",
+    "_OP_PREFILL", "_OP_DECODE", "_OP_VERIFY", "_OP_UNIFIED", "_OP_FLAT",
+    "_OP_EMBED",
 }
 
 

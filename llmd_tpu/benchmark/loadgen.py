@@ -113,10 +113,10 @@ class LoadGenerator:
                             if not ln.startswith(b"data:") or b"[DONE]" in ln:
                                 continue
                             # The engine fuses multiple tokens per SSE
-                            # frame — up to decode_window for plain
-                            # fused windows, and up to window x (1 + k)
-                            # when speculative fused verify windows
-                            # accept a full draft — so frames undercount
+                            # frame — up to decode_window for fused
+                            # decode windows, and up to 1 + k when a
+                            # speculative verify step accepts a full
+                            # draft — so frames undercount
                             # tokens: trust the stream's usage frame and
                             # fall back to frame counting only when
                             # usage is absent.

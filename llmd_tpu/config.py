@@ -323,7 +323,9 @@ class SchedulerConfig:
     # Fused decode window: K decode iterations per jit call with on-device
     # token feedback (host sees one transfer per window). 1 = step-per-token.
     # Larger K amortizes dispatch latency at the cost of K-token streaming
-    # granularity and bounded overrun past stop tokens.
+    # granularity and bounded overrun past stop tokens. Inert on a
+    # speculative engine (speculative_ngram), which verifies one-shot
+    # every step.
     decode_window: int = 1
     # Async stepping (vLLM v1 --async-scheduling role): while step N
     # executes on device, the scheduler speculatively builds step N+1
@@ -355,17 +357,6 @@ class SchedulerConfig:
     # values cut spurious drafts (wasted verify compute) on low-repetition
     # traffic at the cost of missing short genuine repeats.
     spec_ngram_min_match: int = 2
-    # Fused verify window: max verify iterations fused into ONE dispatch
-    # when speculative_ngram composes with fused decode windows — the
-    # device runs up to this many [B, 1+k] verify forwards in a
-    # lax.fori_loop with ON-DEVICE accept/reject and token feedback, so
-    # the host pays one round-trip per window instead of one per verify
-    # step. 0 = inherit decode_window (the common case: one knob sizes
-    # both fused families); set explicitly to decouple them (a verify
-    # iteration emits up to 1+k tokens, so a smaller verify window often
-    # matches a larger plain decode window). 1 pins the one-shot verify
-    # path even when decode_window > 1.
-    spec_verify_window: int = 0
     # Unified single-dispatch step: pack an entire window=1 engine step —
     # chunked-prefill token runs, plain decode rows, and one-shot
     # [B, 1+k] verify rows — into ONE bucketed ragged program with one
@@ -373,9 +364,9 @@ class SchedulerConfig:
     # (prefill groups, verify split, plain decode) plus one lockstep
     # broadcast each on multi-host. Greedy and seeded streams stay
     # byte-identical to the split engine; turning this off restores the
-    # per-family dispatch paths (the split fallback). Windowed programs
-    # (fused decode windows, fused verify windows) keep their own
-    # dispatch either way — they already amortize the round-trip.
+    # per-family dispatch paths (the split fallback). Fused decode
+    # windows keep their own dispatch either way — they already amortize
+    # the round-trip.
     unified_step: bool = True
     # Genuinely ragged flattened-token forward (`cu_q_lens`): the unified
     # step runs over the PACKED token stream itself — a decode row costs
@@ -421,17 +412,6 @@ class SchedulerConfig:
                 f"batch_max_seqs={self.batch_max_seqs} must be >= 0 "
                 "(0 = no dedicated cap)"
             )
-        if self.spec_verify_window < 0:
-            raise ValueError(
-                f"spec_verify_window={self.spec_verify_window} must be >= 0 "
-                "(0 inherits decode_window)"
-            )
-        if self.spec_verify_window > 1 and not self.speculative_ngram:
-            raise ValueError(
-                "spec_verify_window > 1 without speculative_ngram configures "
-                "nothing: the fused verify window only exists for the "
-                "speculative engine"
-            )
         if self.speculative_ngram:
             if self.spec_ngram_k < 1:
                 raise ValueError(
@@ -443,48 +423,6 @@ class SchedulerConfig:
                     f"spec_ngram_min_match={self.spec_ngram_min_match} "
                     "must be >= 1"
                 )
-            if (
-                self.spec_window > 1
-                and 2 * (1 + self.spec_ngram_k) > self.max_num_batched_tokens
-            ):
-                # Window-aware validation: a windowed verify row plans
-                # window x (1 + k) budget tokens, so if even the
-                # SMALLEST fused window (2) cannot fit one row the
-                # composition silently never engages — refuse loudly
-                # instead of shipping a no-op flag combination.
-                raise ValueError(
-                    "speculative_ngram with a fused verify window needs "
-                    f"max_num_batched_tokens >= {2 * (1 + self.spec_ngram_k)} "
-                    f"(2 verify iterations x (1 + spec_ngram_k)); got "
-                    f"{self.max_num_batched_tokens}"
-                )
-
-    @property
-    def spec_window(self) -> int:
-        """Resolved fused-verify window cap (1 = one-shot verify steps).
-        ``spec_verify_window`` overrides; 0 inherits ``decode_window``."""
-        if not self.speculative_ngram:
-            return 1
-        w = self.spec_verify_window or self.decode_window
-        return max(1, w)
-
-    @property
-    def spec_window_set(self) -> tuple[int, ...]:
-        """Candidate fused-verify window sizes, ascending: powers of two
-        up to the cap, plus the cap itself. The scheduler picks the
-        largest candidate whose window x (1+k) x rows fits the step's
-        token budget (degrading toward one-shot verify instead of
-        starving rows), and warmup precompiles exactly this set so the
-        adaptive choice never eats a runtime compile."""
-        cap = self.spec_window
-        if cap <= 1:
-            return ()
-        out, w = [], 2
-        while w < cap:
-            out.append(w)
-            w *= 2
-        out.append(cap)
-        return tuple(out)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -556,13 +494,8 @@ def swa_ring_spec(
         min(_SWA_RING_CHUNK, sched.max_num_batched_tokens),
         sched.decode_window,
         # Speculative verify writes 1 + k provisional positions per row
-        # per verify iteration — and a fused verify window runs up to
-        # spec_window iterations in one step — so the ring's write-span
-        # invariant must cover window x (1 + k).
-        (
-            (1 + sched.spec_ngram_k) * sched.spec_window
-            if sched.speculative_ngram else 1
-        ),
+        # per step, so the ring's write-span invariant must cover them.
+        1 + sched.spec_ngram_k if sched.speculative_ngram else 1,
     )
     ring = math.ceil((wmax + chunk) / cache.page_size) + 1
     max_pages = cache.max_pages_per_seq(model.max_model_len)

@@ -37,7 +37,6 @@ from llmd_tpu.engine.runner import (
     StagedDecode,
     StagedUnified,
     StagedVerify,
-    StagedVerifyWindow,
     StepResult,
 )
 from llmd_tpu.engine.scheduler import EngineScheduler, ScheduledBatch
@@ -294,16 +293,10 @@ class EngineStats:
     spec_accepted_tokens_total: int = 0
     spec_acceptance_rate: float = 0.0
     spec_accepted_len_hist: tuple = ()
-    # Fused verify windows (spec x decode_window composition): verify
-    # row-iterations executed inside fused windows, and windowed rows
-    # that went inactive (emission limit reached) before their window's
-    # last iteration.
-    spec_window_iters_total: int = 0
-    spec_window_early_exit_total: int = 0
     # Decode-side device programs dispatched, and the ratio that is the
     # fused-window headline: decode dispatches per generated token —
-    # fused decode windows and fused verify windows both push it down
-    # by amortizing dispatch RTT over more emitted tokens.
+    # fused decode windows and accepted drafts both push it down by
+    # spreading one dispatch over more emitted tokens.
     decode_dispatches_total: int = 0
     dispatches_per_emitted_token: float = 0.0
     # Unified single-dispatch steps (SchedulerConfig.unified_step): engine
@@ -350,10 +343,9 @@ class EngineStats:
     indexer_keys_written_total: int = 0
     # Per-row verify depth histogram (speculative engines): index d
     # counts decode rows dispatched with a 1 + draft width of exactly d
-    # tokens (backed-off rows: 1; hot-draft rows: up to 1 + spec_k,
-    # deeper windowed plans clamp to the top bucket). Two rows in
-    # DIFFERENT buckets on one step is the per-row adaptive depth the
-    # flattened step dispatches in one program.
+    # tokens (backed-off rows: 1; hot-draft rows: up to 1 + spec_k).
+    # Two rows in DIFFERENT buckets on one step is the per-row adaptive
+    # depth the flattened step dispatches in one program.
     spec_row_depth_hist: tuple = ()
     # Batch serving tier (docs/architecture/batch-processing.md): the
     # backfill band's observability contract — waiting batch-band rows
@@ -1510,9 +1502,7 @@ class LLMEngine:
                     for seq in batch.prefills:
                         self.stats.prompt_tokens += seq.num_tokens
                 if batch.decodes:
-                    pend_d = self._dispatch_decodes(
-                        batch.decodes, batch.spec_window
-                    )
+                    pend_d = self._dispatch_decodes(batch.decodes)
             self.scheduler.note_dispatch(batch)
         t_dispatched = time.monotonic()
         # One coalesced readback for the whole step (prefill bucket
@@ -1566,8 +1556,7 @@ class LLMEngine:
         staged = self._schedule_spanned()  # speculative: pending counts
         t_sched = time.monotonic()
         staged_dec: (
-            StagedDecode | StagedVerify | StagedVerifyWindow
-            | StagedUnified | None
+            StagedDecode | StagedVerify | StagedUnified | None
         ) = None
         if self._unified_eligible(staged):
             # Unified single-dispatch step: the row structure and the
@@ -1580,15 +1569,9 @@ class LLMEngine:
             )
         elif staged.decodes:
             if self._spec_proposer is not None:
-                # Spec mode stages the verify(-window) shape; tokens,
-                # drafts and seeds fill at dispatch, after step N's
-                # readback commits.
-                if staged.spec_window > 1:
-                    staged_dec = self.runner.stage_spec_verify_window(
-                        staged.decodes, staged.spec_window
-                    )
-                else:
-                    staged_dec = self.runner.stage_spec_verify(staged.decodes)
+                # Spec mode stages the verify shape; tokens, drafts and
+                # seeds fill at dispatch, after step N's readback commits.
+                staged_dec = self.runner.stage_spec_verify(staged.decodes)
             else:
                 staged_dec = self.runner.stage_decode(
                     staged.decodes, k_steps=staged.decodes[0].num_tokens
@@ -1623,14 +1606,7 @@ class LLMEngine:
             # allocations included) via _finish/_release — the same
             # release the recompute-preemption path uses.
             self.stats.async_rollbacks_total += rolled
-            # Surviving rows keep their planned widths/draft caps, so
-            # the reconciled batch must keep its window too — dropping
-            # to the default would send window-planned rows down the
-            # one-shot verify path, whose arrays are only 1+k wide.
-            reconciled = ScheduledBatch(
-                prefills=live_p, decodes=live_d,
-                spec_window=staged.spec_window,
-            )
+            reconciled = ScheduledBatch(prefills=live_p, decodes=live_d)
             if isinstance(staged_dec, StagedUnified):
                 # Unified prestage survives a rollback by SLICING the
                 # surviving rows' row-independent arrays out of the
@@ -1696,8 +1672,7 @@ class LLMEngine:
         self,
         batch: ScheduledBatch,
         staged_dec: (
-            StagedDecode | StagedVerify | StagedVerifyWindow
-            | StagedUnified | None
+            StagedDecode | StagedVerify | StagedUnified | None
         ) = None,
     ) -> None:
         pend_p = pend_d = pend_u = None
@@ -1716,7 +1691,7 @@ class LLMEngine:
                         self.stats.prompt_tokens += seq.num_tokens
                 if batch.decodes:
                     pend_d = self._dispatch_decodes(
-                        batch.decodes, batch.spec_window,
+                        batch.decodes,
                         None if isinstance(staged_dec, StagedUnified)
                         else staged_dec,
                     )
@@ -1725,7 +1700,7 @@ class LLMEngine:
 
     def _unified_eligible(self, batch: ScheduledBatch) -> bool:
         """Does this batch ride the unified single-dispatch program?
-        Window=1 steps only (fused decode/verify windows keep their own
+        Window=1 steps only (fused decode windows keep their own
         dispatch — they already amortize the round-trip).
 
         Flattened-token engines (`--ragged-qlens`): EVERY window=1 step
@@ -1740,8 +1715,6 @@ class LLMEngine:
         are already one dispatch (mixed drafted/plain spec splits keep
         the two-program path — their staging shape depends on drafts
         only known at dispatch)."""
-        if batch.spec_window != 1:
-            return False
         if (
             self.runner.cp_prefill
             and batch.prefills
@@ -1813,18 +1786,16 @@ class LLMEngine:
     def _dispatch_decodes(
         self,
         decodes: list,
-        spec_window: int = 1,
-        staged: StagedDecode | StagedVerify | StagedVerifyWindow | None = None,
+        staged: StagedDecode | StagedVerify | None = None,
     ) -> PendingDecode:
-        """Dispatch the step's decode rows: the fused verify window when
-        the scheduler picked one, the one-shot speculative verify path
-        when drafting is on and any row drafted, the plain decode
-        program otherwise. ``staged`` reuses host arrays prebuilt by the
-        async pipeline when they still match the dispatch shape —
+        """Dispatch the step's decode rows: the one-shot speculative
+        verify path when drafting is on and any row drafted, the plain
+        decode program otherwise. ``staged`` reuses host arrays prebuilt
+        by the async pipeline when they still match the dispatch shape —
         including SLICING the row-independent page-table/knob rows for
         mixed-step subsets instead of restaging them in the blocking
         host region."""
-        pend = self._dispatch_decode_programs(decodes, spec_window, staged)
+        pend = self._dispatch_decode_programs(decodes, staged)
         self.stats.decode_dispatches_total += len(pend.entries)
         self.stats.step_dispatches_total += len(pend.entries)
         return pend
@@ -1832,46 +1803,10 @@ class LLMEngine:
     def _dispatch_decode_programs(
         self,
         decodes: list,
-        spec_window: int,
-        staged: StagedDecode | StagedVerify | StagedVerifyWindow | None,
+        staged: StagedDecode | StagedVerify | None,
     ) -> PendingDecode:
         if self._spec_proposer is not None:
             self._propose_drafts(decodes)
-            window_staged = (
-                isinstance(staged, StagedVerifyWindow)
-                and staged.window == spec_window
-                and len(staged.seqs) == len(decodes)
-                and all(a is b for a, b in zip(staged.seqs, decodes))
-            )
-            if spec_window > 1:
-                if any(s.draft_tokens for s in decodes):
-                    # Fused verify window: drafting AND non-drafting
-                    # rows ride the same program (query-length masking
-                    # degrades draft-less rows to one-token iterations
-                    # on device) — one dispatch, one readback per K
-                    # verify iterations.
-                    if not window_staged:
-                        staged = self.runner.stage_spec_verify_window(
-                            decodes, spec_window
-                        )
-                    return self.runner.dispatch_staged_verify_window(staged)
-                # NO row drafted this window: degrade to the plain fused
-                # decode program at the window depth — [B, 1] columns
-                # instead of [B, 1+k], so fully backed-off (adversarial)
-                # traffic keeps the window's dispatch amortization
-                # without paying idle verify columns. Depth stays at the
-                # WINDOW (the verify window's iteration count, and the
-                # proposer's probe cadence), capped by the smallest
-                # planned width so no row outruns its pages, then
-                # clamped DOWN to a warmed decode shape — an unwarmed K
-                # would block serving on a fresh XLA compile mid-step.
-                k = min(spec_window, min(s.num_tokens for s in decodes))
-                k = max(w for w in self.runner.decode_windows if w <= k)
-                if window_staged:
-                    return self.runner.dispatch_staged_decode(
-                        self.runner.degrade_staged_window(staged, k)
-                    )
-                return self.runner.dispatch_decode(decodes, k_steps=k)
             drafted = sum(1 for s in decodes if s.draft_tokens)
             if drafted == len(decodes):
                 if not isinstance(staged, StagedVerify):
@@ -1902,22 +1837,18 @@ class LLMEngine:
         """Fill each speculative decode row's draft from COMMITTED
         history, at dispatch time — async staging runs a step early,
         where the history is stale and the tail token unknown. The cap
-        — spec_draft_cap (windowed rows: up to window x (1+k) - 1, 0
-        for backed-off rows) or num_tokens - 1 (the one-shot planned
-        width) — guarantees the draft never writes a slot that wasn't
-        allocated, even when a short acceptance left the row behind its
-        planned position."""
+        — num_tokens - 1, the planned width — guarantees the draft never
+        writes a slot that wasn't allocated, even when a short
+        acceptance left the row behind its planned position."""
         max_len = self.config.model.max_model_len
         for seq in decodes:
             req = seq.request
-            # Rows planned draft-less (max_model_len cap or draft
-            # backoff, scheduler._spec_eligible) get no proposer call
-            # and no verify columns.
-            cap = (
-                seq.num_tokens - 1
-                if seq.spec_draft_cap is None else seq.spec_draft_cap
+            # Rows planned draft-less (max_model_len cap, draft backoff
+            # — scheduler._spec_eligible — or the batch band) get no
+            # proposer call and no verify columns.
+            cap = min(
+                seq.num_tokens - 1, max_len - req.num_computed_tokens - 1
             )
-            cap = min(cap, max_len - req.num_computed_tokens - 1)
             if cap <= 0:
                 seq.draft_tokens = []
                 continue
@@ -1927,10 +1858,7 @@ class LLMEngine:
                 req.all_token_ids, cap, req.spec_gram_state
             )
         for seq in decodes:
-            depth = 1 + len(seq.draft_tokens or [])
-            self._spec_row_depth[
-                min(depth, len(self._spec_row_depth) - 1)
-            ] += 1
+            self._spec_row_depth[1 + len(seq.draft_tokens or [])] += 1
 
     def _collect(
         self,
@@ -1954,22 +1882,10 @@ class LLMEngine:
         if batch.decodes and dres is not None:
             for i, seq in enumerate(batch.decodes):
                 toks, lps = dres.tokens[i], dres.logprobs[i]
-                if dres.meta is not None:
-                    # Fused verify window: the device already resolved
-                    # acceptance — the meta columns carry the emitted
-                    # count (plus drafted/accepted/iters for the
-                    # scheduler's accounting), and only that prefix of
-                    # the packed window is real.
-                    seq.device_accept = tuple(int(v) for v in dres.meta[i])
-                    m = int(dres.meta[i, 0])
-                    toks, lps = toks[:m], lps[:m]
-                elif seq.draft_tokens is not None and batch.spec_window == 1:
+                if seq.draft_tokens is not None:
                     # One-shot speculative row: only 1 + draft_len
                     # columns are real; the rest are the verify shape's
-                    # padding. (A windowed batch that degraded to the
-                    # plain fused decode program keeps every column —
-                    # each fused iteration emitted one committed
-                    # sample.)
+                    # padding.
                     m = 1 + len(seq.draft_tokens)
                     toks, lps = toks[:m], lps[:m]
                 sampled[seq.request.request_id] = toks.tolist()
@@ -2131,8 +2047,6 @@ class LLMEngine:
                 sch.spec_accepted_tokens / max(1, sch.spec_proposed_tokens), 6
             )
             self.stats.spec_accepted_len_hist = tuple(sch.spec_accept_len_hist)
-            self.stats.spec_window_iters_total = sch.spec_window_iters
-            self.stats.spec_window_early_exit_total = sch.spec_window_early_exit
             self.stats.spec_row_depth_hist = tuple(self._spec_row_depth)
         self.stats.live_tokens_total = self.runner.live_tokens_total
         self.stats.padded_tokens_total = self.runner.padded_tokens_total

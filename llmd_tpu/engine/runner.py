@@ -48,7 +48,6 @@ from llmd_tpu import ops
 from llmd_tpu.config import EngineConfig, swa_ring_spec
 from llmd_tpu.engine.sampler import (
     SamplingInputs,
-    accept_counts,
     sample_tokens,
     spec_seed,
 )
@@ -71,9 +70,8 @@ _OP_KV_GATHER, _OP_KV_SCATTER = 3, 4
 _OP_EMBED, _OP_LORA = 5, 6
 _OP_KV_COPY = 7
 _OP_VERIFY = 8  # speculative-decoding verify step ([B, 1+k] positions)
-# Fused verify window: K verify iterations in one dispatch, accept/reject
-# and token feedback ON DEVICE (header QK slot carries the window size).
-_OP_VERIFY_WINDOW = 9
+# 9 is retired and not reused: an opcode's number is its identity on the
+# lockstep wire.
 # Unified single-dispatch step: an entire window=1 engine step — chunked-
 # prefill token runs, plain decode rows, and one-shot [B, 1+k] verify
 # rows — packed into ONE ragged program (header QK slot packs
@@ -201,16 +199,10 @@ def _fuse_projection_tree(params: dict) -> dict:
 
 @dataclass
 class StepResult:
-    """Sampled tokens for each row; [B, K] (K=1 for single-shot calls).
-
-    ``meta`` is set only by fused verify-window programs: per-row
-    ``[emitted count, draft tokens scored, draft tokens accepted,
-    iterations active]`` i32 — the device-resolved acceptance the host
-    would otherwise have to recompute (and could not, mid-window)."""
+    """Sampled tokens for each row; [B, K] (K=1 for single-shot calls)."""
 
     tokens: np.ndarray
     logprobs: np.ndarray
-    meta: np.ndarray | None = None
 
 
 @dataclass
@@ -228,16 +220,14 @@ class PendingPrefill:
 class PendingDecode:
     """Dispatched-but-unread decode-side programs of one engine step,
     awaiting the coalesced readback: (packed device output, source row
-    indices, K, meta_cols) per program — the packed layout is
-    [B, meta_cols + 2K], with meta_cols == 0 for plain decode/verify
-    programs and 4 for fused verify windows (count/drafted/accepted/
-    iters leading columns). Plain steps carry ONE entry; a speculative
-    one-shot step may SPLIT its rows between the verify program (rows
+    indices, K) per program — the packed layout is [B, 2K]. Plain
+    steps carry ONE entry; a speculative one-shot step may SPLIT its
+    rows between the verify program (rows
     that drafted) and the plain one-token decode program (the rest), so
     low-repetition traffic pays verify columns only for rows that
     actually drafted."""
 
-    entries: list[tuple[jax.Array, list[int], int, int]]
+    entries: list[tuple[jax.Array, list[int], int]]
     n: int
     k: int  # widest K across entries == the StepResult window width
 
@@ -254,24 +244,6 @@ class StagedVerify:
     arrays: dict
     B: int
     q: int  # 1 + spec_ngram_k (the verify shape family's static Q)
-    all_greedy: bool
-
-
-@dataclass
-class StagedVerifyWindow:
-    """Host arrays for a fused verify-window dispatch built AHEAD of the
-    tokens/drafts they depend on (async stepping): page/ring tables,
-    sampling knobs, the active mask and per-row emission limits are
-    final at staging; ``first``/``start``, the pre-drafted token block,
-    seeds, and the seeded-row derivation inputs are filled by
-    ``dispatch_staged_verify_window`` once the previous step's readback
-    has committed and the window's drafts are proposed."""
-
-    seqs: list[ScheduledSeq]
-    arrays: dict
-    B: int
-    window: int  # verify iterations fused into this dispatch
-    q: int  # 1 + spec_ngram_k (columns per iteration)
     all_greedy: bool
 
 
@@ -557,28 +529,14 @@ class ModelRunner:
             1 + sched.spec_ngram_k if sched.speculative_ngram else 0
         )
         self._verify = self._build_verify() if self.spec_q else None
-        # Fused verify window (spec x decode_window composition): the
-        # window sizes the scheduler may pick (SchedulerConfig.
-        # spec_window_set); one traced family, window a static argument.
-        self.spec_windows = sched.spec_window_set
-        self._verify_window = (
-            self._build_verify_window() if self.spec_windows else None
+        # Decode depths warmup precompiles: the depths the scheduler can
+        # pick. On speculative engines it never takes the fused-window
+        # branch, so warming decode_window there would be dead compile
+        # time.
+        self.decode_windows = (
+            (1,) if sched.speculative_ngram
+            else tuple(sorted({1, sched.decode_window}))
         )
-        # Decode depths warmup precompiles — and the ONLY depths the
-        # engine's no-draft degrade path may dispatch at (an unwarmed K
-        # would block serving on a fresh XLA compile mid-step). Includes
-        # every fused-verify-window candidate: a degraded window step
-        # runs the plain decode program at the window's depth. On
-        # speculative engines the scheduler never takes the PLAIN fused-
-        # window branch, so decode_window itself is reachable only
-        # through the resolved spec window — warming it directly would
-        # be dead compile time when --spec-verify-window decouples them.
-        self.decode_windows = tuple(sorted({
-            1,
-            sched.spec_window if sched.speculative_ngram
-            else sched.decode_window,
-            *self.spec_windows,
-        }))
         # Unified single-dispatch step (SchedulerConfig.unified_step): one
         # ragged program packs a whole window=1 step — prefill chunk
         # runs, plain decode rows, one-shot verify rows. Sample columns
@@ -1317,191 +1275,6 @@ class ModelRunner:
 
         return llmd_flat_step
 
-    def _build_verify_window(self):
-        """Fused verify window: ``window`` verify iterations in ONE jit
-        call — a ``lax.fori_loop`` whose body runs the [B, 1+k] verify
-        forward, applies the acceptance rule ON DEVICE
-        (``sampler.accept_counts`` — the same rule the host one-shot
-        path uses — with the per-(seed, output-index) PRNG derivation
-        for seeded rows, ``sampler.spec_seed``), advances each row's
-        position by its accepted length, and feeds the device-side next
-        token back for the following iteration. The host pre-drafts up
-        to window x (1+k) - 1 tokens per row (``predraft``/
-        ``draft_len`` — each fully-accepted iteration consumes k scored
-        columns plus the bonus slot); a
-        row whose draft diverges (mismatch among scored columns) or
-        exhausts degrades to plain one-token decode iterations inside
-        the same loop via the query-length mask, and a row that reaches
-        its ``limit`` (planned emission cap: budget/pages/max_model_len)
-        goes fully inactive (qlen 0, the prefill pad-row convention).
-        One packed output per window: 4 meta columns (emitted/drafted/
-        accepted/iters-active) + window x (1+k) token and logprob
-        columns — ONE host round-trip per K verify iterations."""
-        cfg = self.cfg
-        dbo = self.config.parallel.enable_dbo
-        replicate = self._replicate_out
-        ring = self.swa is not None
-        Q = self.spec_q
-        k = Q - 1
-
-        @functools.partial(
-            jax.jit,
-            donate_argnums=(1, 2) if ring else (1,),
-            static_argnames=("window", "all_greedy"),
-        )
-        def llmd_verify_window(
-            params,
-            kv_cache,
-            kv_swa,  # ring pool (None unless swa_ring)
-            first_token: jax.Array,  # [B] next input token
-            start_pos: jax.Array,  # [B] position of first_token
-            predraft: jax.Array,  # [B, window*k] pre-drafted tokens
-            draft_len: jax.Array,  # [B] valid predraft width
-            limit: jax.Array,  # [B] max emissions this window
-            page_table: jax.Array,  # [B, max_pages]
-            swa_table,  # [B, max_pages] ring view, or None
-            active: jax.Array,  # [B] bool (pad rows False)
-            lora_ids,  # [B] i32 adapter slots, or None
-            temperature: jax.Array,
-            top_k: jax.Array,
-            top_p: jax.Array,
-            seeds: jax.Array,  # [B, window, Q] engine-RNG draws
-            seed_base: jax.Array,  # [B] u32 request seed (seeded rows)
-            seeded: jax.Array,  # [B] bool
-            out0: jax.Array,  # [B] output index of the first emission
-            census=None,  # [E+2] MoE census accumulator, or None
-            window: int = 1,
-            all_greedy: bool = False,
-        ):
-            B = first_token.shape[0]
-            self._note_traced("verify_window", (B, window * Q))
-            Wmax = window * Q
-            qcols = jnp.arange(Q)
-            dcols = jnp.arange(k)
-
-            def body(t, carry):
-                (kv_cache, kv_swa, census, tok, pos, emitted, dptr, alive,
-                 drafted, accepted, iters, out_t, out_l) = carry
-                rem = limit - emitted
-                row_on = active & (rem > 0)
-                avail = jnp.where(
-                    alive & row_on, jnp.clip(draft_len - dptr, 0, k), 0
-                )
-                qlen = jnp.minimum(1 + avail, jnp.maximum(rem, 1))
-                qlen = jnp.where(row_on, qlen, 0)
-                dlen = jnp.maximum(qlen - 1, 0)  # draft columns scored
-                # Each row reads its next k pre-drafted tokens at its
-                # OWN pointer; columns past dlen are zeroed like the
-                # one-shot verify's padding.
-                gcols = jnp.clip(
-                    dptr[:, None] + dcols[None, :], 0, predraft.shape[1] - 1
-                )
-                draft = jnp.take_along_axis(predraft, gcols, axis=1)
-                tokens = jnp.concatenate([tok[:, None], draft], axis=1)
-                tokens = jnp.where(qcols[None, :] < qlen[:, None], tokens, 0)
-                positions = pos[:, None] + qcols[None, :]
-                last_real = pos + jnp.maximum(qlen - 1, 0)
-                positions = jnp.where(
-                    qcols[None, :] < qlen[:, None],
-                    positions,
-                    last_real[:, None],
-                )
-                inp = StepInput(
-                    token_ids=tokens,
-                    positions=positions,
-                    query_lens=qlen.astype(jnp.int32),
-                    kv_lens=jnp.where(row_on, pos + qlen, 0).astype(jnp.int32),
-                    page_table=page_table,
-                    lora_ids=lora_ids,
-                    swa_page_table=swa_table,
-                )
-                hidden, kv_cache, kv_swa, census = self._fwd_hidden(
-                    params, kv_cache, kv_swa, inp, census, dbo=dbo
-                )
-                H = hidden.shape[-1]
-                logits = llama.compute_logits(
-                    params, hidden.reshape(B * Q, H), cfg
-                )
-                s_t = jax.lax.dynamic_index_in_dim(
-                    seeds, t, axis=1, keepdims=False
-                )  # [B, Q]
-                out_idx = (out0 + emitted)[:, None] + qcols[None, :]
-                derived = spec_seed(
-                    seed_base[:, None], out_idx.astype(jnp.uint32)
-                )
-                s_t = jnp.where(seeded[:, None], derived, s_t)
-                flat = SamplingInputs(
-                    temperature=jnp.repeat(temperature, Q),
-                    top_k=jnp.repeat(top_k, Q),
-                    top_p=jnp.repeat(top_p, Q),
-                    seeds=s_t.reshape(B * Q),
-                )
-                tgt, logp = sample_tokens(logits, flat, all_greedy)
-                tgt = tgt.reshape(B, Q)
-                logp = logp.reshape(B, Q)
-                n_emit, n_acc = accept_counts(draft, tgt, dlen)
-                n_emit = jnp.where(row_on, jnp.minimum(n_emit, qlen), 0)
-                # Scatter the emitted prefix at each row's output
-                # offset; rejected/pad columns route out of range and
-                # drop.
-                col = emitted[:, None] + qcols[None, :]
-                col = jnp.where(qcols[None, :] < n_emit[:, None], col, Wmax)
-                rows = jnp.broadcast_to(jnp.arange(B)[:, None], (B, Q))
-                out_t = out_t.at[rows, col].set(tgt, mode="drop")
-                out_l = out_l.at[rows, col].set(logp, mode="drop")
-                last = jnp.take_along_axis(
-                    tgt, jnp.maximum(n_emit - 1, 0)[:, None], axis=1
-                )[:, 0]
-                tok = jnp.where(row_on, last, tok)
-                pos = pos + n_emit
-                # The iteration consumed n_emit slots of the prediction
-                # stream: n_acc scored draft columns PLUS the
-                # correction/bonus sample, whose slot
-                # (predraft[dptr + n_acc]) the verify had no input
-                # column for. The remaining pre-draft stays valid only
-                # when nothing mismatched among the scored columns AND
-                # the bonus token equals its predicted slot — advancing
-                # by n_acc alone would re-verify the bonus slot next
-                # iteration and spuriously reject every later column.
-                bonus_idx = dptr + n_acc
-                bonus_pred = jnp.take_along_axis(
-                    predraft,
-                    jnp.clip(bonus_idx, 0, predraft.shape[1] - 1)[:, None],
-                    axis=1,
-                )[:, 0]
-                bonus_ok = (bonus_idx >= draft_len) | (bonus_pred == last)
-                alive = alive & jnp.where(
-                    row_on, (n_acc >= dlen) & bonus_ok, True
-                )
-                dptr = dptr + n_emit
-                emitted = emitted + n_emit
-                drafted = drafted + dlen
-                accepted = accepted + n_acc
-                iters = iters + row_on.astype(jnp.int32)
-                return (kv_cache, kv_swa, census, tok, pos, emitted, dptr,
-                        alive, drafted, accepted, iters, out_t, out_l)
-
-            zeros = jnp.zeros(B, jnp.int32)
-            carry = (
-                kv_cache, kv_swa, census, first_token, start_pos, zeros,
-                zeros, jnp.ones(B, bool), zeros, zeros, zeros,
-                jnp.zeros((B, Wmax), jnp.int32),
-                jnp.zeros((B, Wmax), jnp.float32),
-            )
-            (kv_cache, kv_swa, census, _, _, emitted, _, _, drafted,
-             accepted, iters, out_t, out_l) = jax.lax.fori_loop(
-                 0, window, body, carry)
-            meta = jnp.stack(
-                [emitted, drafted, accepted, iters], axis=1
-            ).astype(jnp.float32)
-            packed = jnp.concatenate(
-                [meta, out_t.astype(jnp.float32), out_l], axis=1
-            )  # [B, 4 + 2*Wmax]
-            packed, census = self._count_row(packed, census)
-            return kv_cache, kv_swa, replicate(packed), census
-
-        return llmd_verify_window
-
     def _build_multi(self):
         cfg = self.cfg
         dbo = self.config.parallel.enable_dbo
@@ -1885,10 +1658,8 @@ class ModelRunner:
         batch-mates or window size. ``sampler.spec_seed`` is the ONE
         derivation every dispatch path uses — prefill, fused decode
         windows, and the one-shot speculative verify step apply it here
-        on host; the fused verify window applies the same function on
-        device (its output indices depend on device-side acceptance) —
-        or seeded speculative acceptance silently loses its byte-parity
-        guarantee."""
+        on host — or seeded speculative acceptance silently loses its
+        byte-parity guarantee."""
         for i, s in enumerate(seqs):
             sp = s.request.sampling
             if sp.seed is not None:
@@ -2039,35 +1810,6 @@ class ModelRunner:
                 # per (row, position) — the one payload difference from
                 # the prefill family.
                 ("seeds", (B, QK) if op == _OP_VERIFY else (B,), np.uint32),
-            ]
-        elif op == _OP_VERIFY_WINDOW:
-            # QK carries the WINDOW size (verify iterations fused);
-            # the per-iteration column count Q derives from the shared
-            # engine config (1 + spec_ngram_k) on both sides.
-            q = self.spec_q
-            spec = [
-                ("first", (B,), np.int32),
-                ("start", (B,), np.int32),
-                # window x q - 1 slots: each fully-accepted iteration
-                # consumes q (= k scored columns + the bonus slot), and
-                # the last iteration's bonus needs no prediction.
-                ("predraft", (B, QK * q - 1), np.int32),
-                ("dlen", (B,), np.int32),
-                ("limit", (B,), np.int32),
-                ("page_table", (B, mp), np.int32),
-                ("active", (B,), np.uint8),
-                ("temp", (B,), np.float32),
-                ("top_k", (B,), np.int32),
-                ("top_p", (B,), np.float32),
-                # One engine-RNG seed block per (iteration, row,
-                # position); seeded rows are overridden ON DEVICE by
-                # the per-(seed, output-index) derivation, because
-                # their output indices depend on device-side
-                # acceptance.
-                ("seeds", (B, QK, q), np.uint32),
-                ("seed_base", (B,), np.uint32),
-                ("seeded", (B,), np.uint8),
-                ("out0", (B,), np.int32),
             ]
         elif op == _OP_UNIFIED:
             # QK packs (Q_bucket << 20) | T_bucket: the follower needs
@@ -2290,8 +2032,6 @@ class ModelRunner:
                 self._exec_prefill(arrays, bool(greedy))
             elif op == _OP_VERIFY:
                 self._exec_verify(arrays, bool(greedy))
-            elif op == _OP_VERIFY_WINDOW:
-                self._exec_verify_window(arrays, QK, bool(greedy))
             elif op == _OP_UNIFIED:
                 # QK packs (Q_bucket << 20) | T_bucket; the exec only
                 # needs the static per-row column count.
@@ -2464,39 +2204,6 @@ class ModelRunner:
                 if "wphys_swa" in arrays else None
             ),
             census=self._moe_census,
-            all_greedy=all_greedy,
-        )
-        return packed
-
-    def _exec_verify_window(
-        self, arrays: dict, window: int, all_greedy: bool
-    ) -> jax.Array:
-        (self.kv_cache, self.kv_swa, packed,
-         self._moe_census) = self._verify_window(
-            self.params,
-            self.kv_cache,
-            self.kv_swa,
-            jnp.asarray(arrays["first"]),
-            jnp.asarray(arrays["start"]),
-            jnp.asarray(arrays["predraft"]),
-            jnp.asarray(arrays["dlen"]),
-            jnp.asarray(arrays["limit"]),
-            jnp.asarray(arrays["page_table"]),
-            (
-                jnp.asarray(arrays["swa_table"])
-                if "swa_table" in arrays else None
-            ),
-            jnp.asarray(arrays["active"].astype(bool)),
-            jnp.asarray(arrays["lora"]) if "lora" in arrays else None,
-            jnp.asarray(arrays["temp"]),
-            jnp.asarray(arrays["top_k"]),
-            jnp.asarray(arrays["top_p"]),
-            jnp.asarray(arrays["seeds"]),
-            jnp.asarray(arrays["seed_base"]),
-            jnp.asarray(arrays["seeded"].astype(bool)),
-            jnp.asarray(arrays["out0"]),
-            census=self._moe_census,
-            window=window,
             all_greedy=all_greedy,
         )
         return packed
@@ -3145,7 +2852,7 @@ class ModelRunner:
             )
             packed = self._exec_decode(arrays, staged.k, staged.all_greedy)
         return PendingDecode(
-            [(packed, list(range(n)), staged.k, 0)], n, staged.k
+            [(packed, list(range(n)), staged.k)], n, staged.k
         )
 
     @profiling.spanned("llmd.runner.build")
@@ -3225,7 +2932,7 @@ class ModelRunner:
             packed = self._exec_verify(arrays, staged.all_greedy)
         n = len(staged.seqs)
         return PendingDecode(
-            [(packed, list(range(n)), staged.q, 0)], n, staged.q
+            [(packed, list(range(n)), staged.q)], n, staged.q
         )
 
     @staticmethod
@@ -3282,10 +2989,10 @@ class ModelRunner:
         return StagedVerify(sub, arrays, B, Q, all_greedy)
 
     def _subset_staged_decode(
-        self, staged: StagedVerify | StagedVerifyWindow,
+        self, staged: StagedVerify,
         seqs: list[ScheduledSeq], idxs: list[int], k_steps: int,
     ) -> StagedDecode:
-        """Derive a subset StagedDecode from prestaged verify(-window)
+        """Derive a subset StagedDecode from prestaged verify
         arrays — the degrade path when staged drafting rows turned out
         not to draft at dispatch time."""
         n = len(idxs)
@@ -3305,18 +3012,6 @@ class ModelRunner:
         all_greedy = all(s.request.sampling.greedy for s in sub)
         return StagedDecode(sub, arrays, B, k_steps, all_greedy)
 
-    def degrade_staged_window(
-        self, staged: StagedVerifyWindow, k_steps: int
-    ) -> StagedDecode:
-        """Reuse a prestaged verify window's row-independent arrays as a
-        plain fused-decode staging — the degrade path when no staged
-        row turned out to draft at dispatch time (fully backed-off
-        traffic keeps the window's dispatch amortization without paying
-        idle verify columns)."""
-        return self._subset_staged_decode(
-            staged, staged.seqs, list(range(len(staged.seqs))), k_steps
-        )
-
     def dispatch_spec_split(
         self,
         seqs: list[ScheduledSeq],
@@ -3333,7 +3028,7 @@ class ModelRunner:
         region."""
         drafted = [i for i, s in enumerate(seqs) if s.draft_tokens]
         plain = [i for i, s in enumerate(seqs) if not s.draft_tokens]
-        entries: list[tuple[jax.Array, list[int], int, int]] = []
+        entries: list[tuple[jax.Array, list[int], int]] = []
         reuse = (
             staged is not None
             and len(staged.seqs) == len(seqs)
@@ -3344,14 +3039,14 @@ class ModelRunner:
         else:
             sub_v = self.stage_spec_verify([seqs[i] for i in drafted])
         pv = self.dispatch_staged_verify(sub_v)
-        entries.append((pv.entries[0][0], drafted, self.spec_q, 0))
+        entries.append((pv.entries[0][0], drafted, self.spec_q))
         if plain:
             if reuse:
                 sub_d = self._subset_staged_decode(staged, seqs, plain, 1)
             else:
                 sub_d = self.stage_decode([seqs[i] for i in plain], k_steps=1)
             pd = self.dispatch_staged_decode(sub_d)
-            entries.append((pd.entries[0][0], plain, 1, 0))
+            entries.append((pd.entries[0][0], plain, 1))
         return PendingDecode(entries, len(seqs), self.spec_q)
 
     @profiling.spanned("llmd.runner.build")
@@ -3648,99 +3343,6 @@ class ModelRunner:
             pad_to_bucket(s.num_tokens, self.prefill_buckets) for s in seqs
         })
 
-    @profiling.spanned("llmd.runner.build")
-    def stage_spec_verify_window(
-        self, seqs: list[ScheduledSeq], window: int
-    ) -> StagedVerifyWindow:
-        """Build the fused verify window's host arrays AHEAD of the
-        tokens/drafts they depend on (async stepping). The window
-        engages only in the saturated all-decode regime, so rows bucket
-        over the DECODE batch buckets; page/ring tables, knobs, the
-        active mask and the per-row emission limits (the scheduler's
-        planned widths) are final here."""
-        n = len(seqs)
-        B = pad_to_bucket(n, self.batch_buckets)
-        Q = self.spec_q
-        temp, top_k, top_p = self._sampling_knobs(seqs, B)
-        active = np.zeros(B, np.uint8)
-        active[:n] = 1
-        limit = np.ones(B, np.int32)
-        for i, s in enumerate(seqs):
-            limit[i] = s.num_tokens
-        arrays = {
-            "first": np.zeros(B, np.int32),
-            "start": np.zeros(B, np.int32),
-            "predraft": np.zeros((B, window * Q - 1), np.int32),
-            "dlen": np.zeros(B, np.int32),
-            "limit": limit,
-            "page_table": self._page_table(seqs, B),
-            "active": active,
-            "temp": temp, "top_k": top_k, "top_p": top_p,
-            "seeds": np.zeros((B, window, Q), np.uint32),
-            "seed_base": np.zeros(B, np.uint32),
-            "seeded": np.zeros(B, np.uint8),
-            "out0": np.zeros(B, np.int32),
-        }
-        if self.swa is not None:
-            arrays["swa_table"] = self._swa_table(seqs, B)
-        if self.cfg.num_lora_adapters:
-            arrays["lora"] = self._lora_array(seqs, B)
-        all_greedy = all(s.request.sampling.greedy for s in seqs)
-        return StagedVerifyWindow(list(seqs), arrays, B, window, Q, all_greedy)
-
-    def dispatch_staged_verify_window(
-        self, staged: StagedVerifyWindow
-    ) -> PendingDecode:
-        """Fill the readback/draft-dependent slots of a staged verify
-        window and enqueue it. ONE [B, window, Q] rng block per
-        dispatch, drawn in dispatch order (the seed-parity rule of
-        dispatch_staged_decode); seeded rows are NOT overwritten on
-        host — the device derives their per-(seed, output-index) seeds,
-        because a row's output indices past the first iteration depend
-        on its own on-device acceptance."""
-        arrays = staged.arrays
-        first, start = arrays["first"], arrays["start"]
-        predraft, dlen = arrays["predraft"], arrays["dlen"]
-        seed_base, seeded = arrays["seed_base"], arrays["seeded"]
-        out0 = arrays["out0"]
-        arrays["seeds"] = self._np_rng.integers(
-            0, 2**32, size=(staged.B, staged.window, staged.q),
-            dtype=np.uint32,
-        )
-        for i, s in enumerate(staged.seqs):
-            req = s.request
-            nc = req.num_computed_tokens
-            first[i] = req.all_token_ids[nc]
-            start[i] = nc
-            draft = s.draft_tokens or []
-            predraft[i, : len(draft)] = draft
-            dlen[i] = len(draft)
-            out0[i] = req.total_output_tokens
-            sp = req.sampling
-            if sp.seed is not None:
-                seed_base[i] = np.uint32(sp.seed & 0xFFFFFFFF)
-                seeded[i] = 1
-        n = len(staged.seqs)
-        # Planned widths: actual emission is resolved on device, so the
-        # padding gauge charges the pad ROWS only (live rows' idle
-        # iterations are the window's own accounting).
-        self.live_tokens_total += n * staged.window * staged.q
-        self.padded_tokens_total += (
-            (staged.B - n) * staged.window * staged.q
-        )
-        with self._dispatch_lock, self._dispatching(
-            "verify_window", B=staged.B, W=staged.window, Q=staged.q
-        ):
-            arrays = self._sync_locked(
-                _OP_VERIFY_WINDOW, staged.B, staged.window,
-                staged.all_greedy, arrays,
-            )
-            packed = self._exec_verify_window(
-                arrays, staged.window, staged.all_greedy
-            )
-        wmax = staged.window * staged.q
-        return PendingDecode([(packed, list(range(n)), wmax, 4)], n, wmax)
-
     @profiling.spanned("llmd.runner.wait")
     def wait_step(
         self,
@@ -3758,7 +3360,7 @@ class ModelRunner:
         if prefill is not None:
             packs.extend(p for p, _ in prefill.entries)
         if decode is not None:
-            packs.extend(p for p, _, _, _ in decode.entries)
+            packs.extend(p for p, _, _ in decode.entries)
         if unified is not None:
             packs.append(unified.packed)
         if not packs:
@@ -3787,28 +3389,19 @@ class ModelRunner:
             K = decode.k
             tokens = np.zeros((decode.n, K), np.int32)
             logprobs = np.zeros((decode.n, K), np.float32)
-            meta = None
-            for gi, (_, idxs, k, mc) in enumerate(decode.entries):
+            for gi, (_, idxs, k) in enumerate(decode.entries):
                 arr = hosts[base + gi]
                 m = len(idxs)
-                if mc:
-                    # Fused verify window: leading meta columns carry
-                    # the device-resolved acceptance per row.
-                    if meta is None:
-                        meta = np.zeros((decode.n, mc), np.int32)
-                    meta[np.asarray(idxs, np.int64)] = arr[:m, :mc].astype(
-                        np.int32
-                    )
                 if idxs == list(range(decode.n)):
                     # Single whole-batch entry (the common, spec-off
                     # case): one vectorized block copy.
-                    tokens[:, :k] = arr[:m, mc : mc + k].astype(np.int32)
-                    logprobs[:, :k] = arr[:m, mc + k : mc + 2 * k]
+                    tokens[:, :k] = arr[:m, :k].astype(np.int32)
+                    logprobs[:, :k] = arr[:m, k : 2 * k]
                 else:
                     rows = np.asarray(idxs, np.int64)
-                    tokens[rows, :k] = arr[:m, mc : mc + k].astype(np.int32)
-                    logprobs[rows, :k] = arr[:m, mc + k : mc + 2 * k]
-            dres = StepResult(tokens, logprobs, meta)
+                    tokens[rows, :k] = arr[:m, :k].astype(np.int32)
+                    logprobs[rows, :k] = arr[:m, k : 2 * k]
+            dres = StepResult(tokens, logprobs)
         if unified is not None:
             arr = hosts[-1]
             S = unified.S
@@ -3869,8 +3462,8 @@ class ModelRunner:
             if flat and len(self.decode_windows) == 1:
                 # Window=1 decode steps ride the flat program; the plain
                 # decode family stays reachable only via explicit
-                # run_decode calls and the windowed degrade paths, which
-                # this engine (decode_windows == {1}) never takes.
+                # run_decode calls, which this engine (decode_windows ==
+                # {1}) never makes.
                 decode_shapes = []
         count = 0
         for B, Q in prefill_shapes:
@@ -3890,14 +3483,6 @@ class ModelRunner:
                 for greedy in (True, False):
                     self._warm_verify(B, greedy)
                     count += 1
-        # The fused verify-window family: the scheduler's adaptive pick
-        # stays within spec_windows (SchedulerConfig.spec_window_set),
-        # so compiling exactly that set at the largest decode batch
-        # keeps the budget-driven degrade from eating a runtime compile.
-        for w in self.spec_windows:
-            for greedy in (True, False):
-                self._warm_verify_window(self.batch_buckets[-1], w, greedy)
-                count += 1
         if flat:
             # The flat family's one shape axis is T: warm the largest
             # stream bucket (the saturated-step shape).
@@ -4033,34 +3618,6 @@ class ModelRunner:
         with self._dispatch_lock:
             arrays = self._sync_locked(_OP_VERIFY, B, Q, all_greedy, arrays)
             self._exec_verify(arrays, all_greedy)
-
-    def _warm_verify_window(
-        self, B: int, window: int, all_greedy: bool = False
-    ) -> None:
-        Q = self.spec_q
-        arrays = {
-            "first": np.zeros(B, np.int32),
-            "start": np.zeros(B, np.int32),
-            "predraft": np.zeros((B, window * Q - 1), np.int32),
-            "dlen": np.zeros(B, np.int32),
-            "limit": np.ones(B, np.int32),
-            "page_table": np.zeros((B, self.max_pages), np.int32),
-            "active": np.zeros(B, np.uint8),
-            "temp": np.zeros(B, np.float32),
-            "top_k": np.zeros(B, np.int32),
-            "top_p": np.ones(B, np.float32),
-            "seeds": np.zeros((B, window, Q), np.uint32),
-            "seed_base": np.zeros(B, np.uint32),
-            "seeded": np.zeros(B, np.uint8),
-            "out0": np.zeros(B, np.int32),
-        }
-        if self.swa is not None:
-            arrays["swa_table"] = np.zeros((B, self.max_pages), np.int32)
-        if self.cfg.num_lora_adapters:
-            arrays["lora"] = np.zeros(B, np.int32)
-        with self._dispatch_lock:
-            arrays = self._sync_locked(_OP_VERIFY_WINDOW, B, window, all_greedy, arrays)
-            self._exec_verify_window(arrays, window, all_greedy)
 
     def _warm_decode(self, B: int, K: int, all_greedy: bool = False) -> None:
         arrays = {

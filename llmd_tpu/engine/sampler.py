@@ -74,31 +74,19 @@ def sample_tokens(
     return tokens.astype(jnp.int32), chosen_logp
 
 
-def spec_seed(seed, out_index):
+def spec_seed(seed: int, out_index: int) -> int:
     """The per-(request seed, output index) sampling-seed derivation —
-    THE one definition every dispatch path uses. Prefill, fused decode
-    windows, and the one-shot verify step derive it on HOST
-    (``ModelRunner._overwrite_seeded_rows``, Python ints); the fused
-    verify window derives it ON DEVICE (uint32 arrays), because each
-    row's output index there depends on its own acceptance, which only
-    the device knows mid-window. Multiplication mod 2^32 respects
-    residues, so the uint32 array form equals the masked Python-int
-    form bit for bit — which is what keeps seeded speculative streams
-    byte-identical whichever path samples a given output index."""
-    if hasattr(seed, "dtype"):
-        # uint32 array path (device or numpy): the dtype's wraparound IS
-        # the mod-2^32 mask, and a literal 0xFFFFFFFF would overflow
-        # jax's weak-typed int32 promotion.
-        return seed * np.uint32(1000003) + out_index
+    THE one definition every dispatch path uses (prefill, fused decode
+    windows and the one-shot verify step, all on host in
+    ``ModelRunner._overwrite_seeded_rows``), which is what keeps seeded
+    speculative streams byte-identical whichever path samples a given
+    output index."""
     return (seed * 1000003 + out_index) & 0xFFFFFFFF
 
 
-def accept_counts(draft, target, draft_len, xp=jnp):
-    """Vectorized Leviathan-style acceptance rule, shared by BOTH
-    acceptance paths: the host one-shot verify (numpy, via
-    ``accept_draft_tokens``) and the fused verify window's on-device
-    accept/reject (jnp, inside ``ModelRunner._build_verify_window``'s
-    ``fori_loop`` body).
+def accept_counts(draft, target, draft_len):
+    """Vectorized Leviathan-style acceptance rule (numpy), behind
+    ``accept_draft_tokens``.
 
     ``draft [..., k]`` vs ``target [..., >=k]`` (the target model's
     per-position samples), with ``draft_len [...]`` masking each row's
@@ -108,9 +96,9 @@ def accept_counts(draft, target, draft_len, xp=jnp):
     plus the correction/bonus sample that always lands.
     """
     k = draft.shape[-1]
-    idx = xp.arange(k)
+    idx = np.arange(k)
     matches = (draft == target[..., :k]) & (idx < draft_len[..., None])
-    n_acc = xp.sum(xp.cumprod(matches.astype(xp.int32), axis=-1), axis=-1)
+    n_acc = np.sum(np.cumprod(matches.astype(np.int32), axis=-1), axis=-1)
     return n_acc + 1, n_acc
 
 
@@ -133,16 +121,14 @@ def accept_draft_tokens(
     greedy and seeded rows (Leviathan et al. 2023 specialized to
     deterministic per-position sampling).
 
-    Returns (emitted window, number of draft tokens accepted). A thin
-    numpy wrapper over ``accept_counts``, the jittable rule the fused
-    verify window applies on device.
+    Returns (emitted window, number of draft tokens accepted).
     """
     if not sampled:
         return [], 0
     k = min(len(draft), len(sampled))
     d = np.asarray(draft[:k], np.int64).reshape(1, k)
     t = np.asarray(sampled[:k], np.int64).reshape(1, k)
-    _, n_acc = accept_counts(d, t, np.asarray([k]), xp=np)
+    _, n_acc = accept_counts(d, t, np.asarray([k]))
     n_acc = int(n_acc[0])
     n_emit = min(n_acc + 1, len(sampled))
     return [int(tok) for tok in sampled[:n_emit]], n_acc

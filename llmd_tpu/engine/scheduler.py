@@ -37,23 +37,12 @@ class ScheduledSeq:
     num_tokens: int  # tokens to compute for this seq in this step
     # Speculative decoding: None for non-speculative rows; a (possibly
     # empty) draft for spec decode rows. The scheduler PLANS with the
-    # max-acceptance count (num_tokens = 1 + spec_ngram_k for one-shot
-    # verify, window x (1 + k) for fused verify windows, pages
-    # included) and the engine fills the actual draft at dispatch time
-    # from committed history — which is what lets async staging reuse
-    # its existing speculate/rollback machinery unchanged.
+    # max-acceptance count (num_tokens = 1 + spec_ngram_k, pages
+    # included) and the engine fills the actual draft — at most
+    # num_tokens - 1 tokens — at dispatch time from committed history,
+    # which is what lets async staging reuse its existing
+    # speculate/rollback machinery unchanged.
     draft_tokens: list[int] | None = None
-    # Fused verify windows: max draft tokens the engine may propose for
-    # this row at dispatch (None = derive from num_tokens - 1, the
-    # one-shot convention). Windowed rows need it explicit because a
-    # backed-off row still plans a multi-token width (window one-token
-    # iterations) without being allowed to draft.
-    spec_draft_cap: int | None = None
-    # Fused verify windows: the device-resolved acceptance meta
-    # (emitted, drafted, accepted, iterations active), attached by the
-    # engine from the readback before update_after_step — the host must
-    # NOT re-run the acceptance rule for these rows.
-    device_accept: tuple | None = None
 
     @property
     def start_pos(self) -> int:
@@ -64,10 +53,6 @@ class ScheduledSeq:
 class ScheduledBatch:
     prefills: list[ScheduledSeq]
     decodes: list[ScheduledSeq]
-    # Fused verify window chosen for this step's decode rows (1 =
-    # one-shot verify / plain decode; > 1 only when speculative_ngram
-    # composes with fused decode windows in the saturated regime).
-    spec_window: int = 1
 
     @property
     def seqs(self) -> list[ScheduledSeq]:
@@ -148,21 +133,8 @@ class EngineScheduler:
             scheduler_config.spec_ngram_k
             if scheduler_config.speculative_ngram else 0
         )
-        # Fused verify windows (spec x decode_window): the candidate
-        # window sizes (ascending) and the max planned width any staged
-        # row can carry — the async truncation keep-bound.
-        self.spec_windows = scheduler_config.spec_window_set
-        self.spec_plan_max = (
-            (1 + self.spec_k) * scheduler_config.spec_window
-            if self.spec_k else 0
-        )
         self.spec_proposed_tokens = 0
         self.spec_accepted_tokens = 0
-        # Fused verify-window accounting: row-iterations executed inside
-        # windows, and rows that went inactive (emission limit reached)
-        # before their window's last iteration.
-        self.spec_window_iters = 0
-        self.spec_window_early_exit = 0
         # Accepted-draft-length histogram over spec decode rows: index j
         # counts (row, step) pairs that accepted exactly j draft tokens.
         self.spec_accept_len_hist = [0] * (self.spec_k + 1)
@@ -265,32 +237,14 @@ class EngineScheduler:
         # Batch-backfill steps pin K=1: batch rows ride the same program
         # at one-token width (a K-token fused commitment would have to be
         # unwound the moment interactive load preempts them), and a
-        # uniform-K dispatch cannot mix widths.
+        # uniform-K dispatch cannot mix widths. Speculative engines pin
+        # K=1 too: every step is a one-shot verify.
         window = self.config.decode_window
         can_admit = bool(self.waiting) and len(self.running) < self.config.max_num_seqs
         k = 1
-        spec_w = 1
-        if self.spec_k:
-            # Fused verify window: under the same saturated-regime gate
-            # as the plain fused window, pick the LARGEST candidate
-            # (SchedulerConfig.spec_window_set, the precompiled shapes)
-            # whose max-acceptance width — window x (1 + k) per row —
-            # fits the whole decode batch in this step's token budget.
-            # Degrading the window instead of dropping rows keeps tail
-            # rows from starving behind budget-hungry window peers; no
-            # candidate fitting means one-shot verify steps as before.
-            if (
-                self.spec_windows and decoding and not mid_prefill
-                and not can_admit and not in_backfill
-            ):
-                per_batch = (1 + self.spec_k) * len(decoding)
-                for w in reversed(self.spec_windows):
-                    if w * per_batch <= budget:
-                        spec_w = w
-                        break
-        elif (
-            window > 1 and decoding and not mid_prefill and not can_admit
-            and not in_backfill
+        if (
+            not self.spec_k and window > 1 and decoding and not mid_prefill
+            and not can_admit and not in_backfill
         ):
             k = max(
                 1,
@@ -316,36 +270,17 @@ class EngineScheduler:
                 continue  # reset by a preemption earlier in this loop
             if budget <= 0:
                 break
-            draft_cap = None
             if self.spec_k:
                 # Speculative rows plan (budget, pages, pending counts)
                 # at the MAX-acceptance count; the actual draft — capped
-                # at spec_draft_cap (windowed) or num_tokens - 1
-                # (one-shot) — is proposed at dispatch, so the planned
-                # slots always cover its provisional KV writes.
+                # at num_tokens - 1 — is proposed at dispatch, so the
+                # planned slots always cover its provisional KV writes.
                 # Backed-off rows (consecutive full rejections) plan as
                 # plain rows until their aligned retry step.
                 cap = self.max_model_len - req.num_dispatched_tokens
-                if spec_w > 1:
-                    # Fused verify window: eligible rows plan the full
-                    # window x (1 + k) width; backed-off rows still ride
-                    # the window as plain one-token iterations (width
-                    # spec_w) but must not draft.
-                    if self._spec_eligible(req):
-                        k_row = max(1, min(spec_w * (1 + self.spec_k), cap))
-                        # Up to window x (1+k) - 1 pre-draft tokens: a
-                        # fully-accepted iteration consumes k scored
-                        # columns PLUS the bonus slot, so window x k
-                        # would run the stream dry before the window's
-                        # last iteration.
-                        draft_cap = k_row - 1
-                    else:
-                        k_row = max(1, min(spec_w, cap))
-                        draft_cap = 0
-                else:
-                    k_row = 1
-                    if self._spec_eligible(req):
-                        k_row += max(0, min(self.spec_k, cap - 1))
+                k_row = 1
+                if self._spec_eligible(req):
+                    k_row += max(0, min(self.spec_k, cap - 1))
             else:
                 k_row = k
             if not self._ensure_pages(req, k_row):
@@ -359,7 +294,6 @@ class EngineScheduler:
                 ScheduledSeq(
                     req, k_row,
                     draft_tokens=[] if self.spec_k else None,
-                    spec_draft_cap=draft_cap,
                 )
             )
             scheduled.add(req.request_id)
@@ -458,9 +392,7 @@ class EngineScheduler:
             if s.request.is_batch
         )
 
-        return ScheduledBatch(
-            prefills=prefills, decodes=decodes, spec_window=spec_w
-        )
+        return ScheduledBatch(prefills=prefills, decodes=decodes)
 
     def _note_admitted(self, req: Request) -> None:
         req.status = RequestStatus.RUNNING
@@ -528,11 +460,11 @@ class EngineScheduler:
             decodes.append(
                 ScheduledSeq(
                     req, 1,
-                    # Spec engines: batch rows stay draft-less (cap 0)
-                    # so acceptance accounting runs but no provisional
+                    # Spec engines: batch rows stay draft-less (their
+                    # one-token width leaves no room for a draft), so
+                    # acceptance accounting runs but no provisional
                     # verify columns are ever planned for them.
                     draft_tokens=[] if self.spec_k else None,
-                    spec_draft_cap=0 if self.spec_k else None,
                 )
             )
             scheduled.add(req.request_id)
@@ -837,39 +769,7 @@ class EngineScheduler:
             req = seq.request
             self._commit_pending(seq)
             window = sampled[req.request_id]
-            if seq.device_accept is not None:
-                # Fused verify window: the accept/reject decision ran ON
-                # DEVICE (the whole point — one host round-trip per K
-                # verify iterations), so the host only folds the meta
-                # into the same counters the one-shot path feeds. The
-                # emitted window then runs the SAME stop-check loop
-                # below.
-                _emitted, drafted, n_acc, iters = seq.device_accept
-                self.spec_proposed_tokens += drafted
-                self.spec_accepted_tokens += n_acc
-                req.spec_drafted_tokens += drafted
-                req.spec_accepted_tokens += n_acc
-                self.spec_window_iters += iters
-                if iters < batch.spec_window:
-                    self.spec_window_early_exit += 1
-                # Histogram: the per-iteration accept-length breakdown
-                # stays on device, so distribute (count += iters,
-                # sum += n_acc) across buckets — the mean-emitted
-                # reading (1 + sum/count) the panel derives is EXACT;
-                # only the shape within a window is approximated.
-                if iters > 0:
-                    full, part = divmod(n_acc, self.spec_k)
-                    self.spec_accept_len_hist[self.spec_k] += full
-                    used = full
-                    if part:
-                        self.spec_accept_len_hist[part] += 1
-                        used += 1
-                    self.spec_accept_len_hist[0] += max(0, iters - used)
-                if drafted and n_acc == 0:
-                    req.spec_consec_rejected += 1
-                elif n_acc > 0:
-                    req.spec_consec_rejected = 0
-            elif seq.draft_tokens:
+            if seq.draft_tokens:
                 # Speculative row: resolve the accepted prefix first
                 # (sampler.accept_draft_tokens), then run the emitted
                 # window through the SAME stop-check loop as a fused
@@ -891,13 +791,9 @@ class EngineScheduler:
                 else:
                     req.spec_consec_rejected = 0
             elif seq.draft_tokens is not None:
-                # Spec row that drafted nothing: plain committed
-                # samples, no provisional writes. A windowed fallback
-                # step (batch.spec_window > 1 with no row drafting)
-                # emitted one committed sample per fused iteration.
-                self.spec_accept_len_hist[0] += (
-                    len(window) if batch.spec_window > 1 else 1
-                )
+                # Spec row that drafted nothing: one plain committed
+                # sample, no provisional writes.
+                self.spec_accept_len_hist[0] += 1
             acc: list[int] = []
             reason = None
             for token in window:
@@ -914,15 +810,11 @@ class EngineScheduler:
                 self._finish(req, reason)
             else:
                 self._commit_full_pages(req)
-                if seq.draft_tokens or (
-                    batch.spec_window > 1 and seq.draft_tokens is not None
-                ):
-                    # Drafting rows made provisional KV writes; windowed
-                    # rows additionally planned pages at the full
-                    # window x (1 + k) width they may not have emitted.
-                    # Plain one-shot draft-less rows hold at most one
-                    # page of planned headroom, which the next step
-                    # reuses — no truncation walk for them.
+                if seq.draft_tokens:
+                    # Drafting rows made provisional KV writes. Draft-
+                    # less rows hold at most one page of planned
+                    # headroom, which the next step reuses — no
+                    # truncation walk for them.
                     self._truncate_spec_pages(req)
         return accepted
 
@@ -949,15 +841,14 @@ class EngineScheduler:
 
         Async engines keep the slots a staged-but-undispatched next
         batch may already be planned against (its verify writes reach at
-        most num_dispatched + the max planned width — 1 + spec_k for
-        one-shot verify, window x (1 + k) when fused verify windows are
-        on); sync engines have nothing in flight here and keep exactly
+        most num_dispatched + the planned width, 1 + spec_k); sync
+        engines have nothing in flight here and keep exactly
         the computed span — the next schedule's _ensure_pages re-extends
         as needed."""
         page = self.allocator.page_size
         slots = req.num_computed_tokens
         if self.config.async_scheduling:
-            slots = req.num_dispatched_tokens + self.spec_plan_max
+            slots = req.num_dispatched_tokens + 1 + self.spec_k
         keep = -(-slots // page)
         if keep < len(req.block_ids):
             self.allocator.free(req.block_ids[keep:])

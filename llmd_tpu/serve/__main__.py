@@ -115,7 +115,6 @@ def make_engine_config(args, lora_adapters=None):
             speculative_ngram=args.speculative_ngram,
             spec_ngram_k=args.spec_ngram_k,
             spec_ngram_min_match=args.spec_ngram_min_match,
-            spec_verify_window=args.spec_verify_window,
             unified_step=args.unified_step,
             ragged_qlens=args.ragged_qlens,
             batch_backfill=args.batch_backfill,
@@ -231,15 +230,6 @@ def build_parser() -> argparse.ArgumentParser:
         "--spec-ngram-min-match", type=int, default=2,
         help="minimum trailing n-gram length that must recur in the "
              "sequence's own history before a draft is proposed",
-    )
-    p.add_argument(
-        "--spec-verify-window", type=int, default=0,
-        help="max verify iterations fused into one dispatch when "
-             "--speculative-ngram composes with fused decode windows: "
-             "accept/reject runs ON DEVICE and the host pays one "
-             "round-trip per window. 0 (default) inherits "
-             "--decode-window; 1 pins one-shot verify steps "
-             "(docs/architecture/speculative-decoding.md)",
     )
     p.add_argument(
         "--unified-step", action=argparse.BooleanOptionalAction, default=True,

@@ -43,9 +43,9 @@ def render_metrics(
         # bench.py --parts async_step) compute a mean over any interval.
         "step_host_gap_ms": round(stats.step_host_gap_ms, 3),
         # Decode dispatches per generated token: the fused-window
-        # headline ratio — plain decode windows and fused verify
-        # windows both push it down by amortizing dispatch RTT over
-        # more emitted tokens per device program.
+        # headline ratio — fused decode windows and accepted drafts
+        # both push it down by spreading one dispatch over more emitted
+        # tokens per device program.
         "dispatches_per_emitted_token": round(
             stats.dispatches_per_emitted_token, 6
         ),
@@ -269,15 +269,6 @@ def render_metrics(
         for name, v in (
             ("spec_proposed_tokens_total", stats.spec_proposed_tokens_total),
             ("spec_accepted_tokens_total", stats.spec_accepted_tokens_total),
-            # Fused verify windows (spec x decode_window): verify
-            # row-iterations run inside fused windows, and windowed
-            # rows that hit their emission limit before the window's
-            # last iteration.
-            ("spec_window_iters_total", stats.spec_window_iters_total),
-            (
-                "spec_window_early_exit_total",
-                stats.spec_window_early_exit_total,
-            ),
         ):
             lines.append(f"# TYPE llmd:{name} counter")
             lines.append(f"llmd:{name}{label} {v}")

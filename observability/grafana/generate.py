@@ -334,10 +334,11 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                f"rate(llmd:decode_dispatches_total{M}[5m])"],
               legends=["dispatches/token (lifetime)", "decode dispatches/s"],
               desc="Decode device programs per generated token — the "
-                   "fused-window headline: plain decode windows and "
-                   "fused verify windows (speculative-decoding.md) both "
-                   "amortize dispatch RTT, pushing the ratio toward "
-                   "1/window x mean emitted per iteration."),
+                   "fused-window headline: fused decode windows and "
+                   "accepted drafts (speculative-decoding.md) both "
+                   "spread one dispatch over more tokens, pushing the "
+                   "ratio toward 1/window or 1/mean emitted per "
+                   "verify step."),
         panel("Dispatches per step (unified step)",
               [f"rate(llmd:step_dispatches_total{M}[5m]) / "
                f"rate(llmd:engine_steps_total{M}[5m])",
@@ -408,15 +409,6 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
               desc="From the llmd:spec_accepted_len histogram; this IS "
                    "the decode speedup on a weight-read-bound engine "
                    "(observability.md)."),
-        panel("Fused verify window activity /s",
-              [f"rate(llmd:spec_window_iters_total{M}[5m])",
-               f"rate(llmd:spec_window_early_exit_total{M}[5m])"],
-              legends=["verify row-iterations/s", "early exits/s"],
-              desc="Verify iterations run inside fused windows "
-                   "(spec x decode_window composition) and windowed "
-                   "rows that hit their emission limit early. Zero "
-                   "iterations with the window on = every step degraded "
-                   "to plain decode (drafts never fire)."),
         panel("Mean per-row verify depth",
               [f"rate(llmd:spec_row_depth_sum{M}[5m]) / "
                f"rate(llmd:spec_row_depth_count{M}[5m])"],

@@ -233,7 +233,7 @@ def test_bench_sigkill_mid_run_keeps_completed_parts(tmp_path):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     proc = subprocess.Popen(
         [sys.executable, os.path.join(repo, "bench.py"),
-         "--parts", "async_step,spec_decode,spec_window"],
+         "--parts", "async_step,spec_decode"],
         stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
         cwd=tmp_path, env=_bench_env(tmp_path),
     )

@@ -28,8 +28,8 @@ from llmd_tpu.engine.spec import NgramProposer
 
 def make_engine(
     spec=False, async_mode=False, num_blocks=64, page=4, max_batched=64,
-    max_seqs=8, seed=0, k=4, min_match=2, prefix_caching=True, window=1,
-    ragged=True,
+    max_seqs=8, seed=0, k=4, min_match=2, prefix_caching=True,
+    decode_window=1, ragged=True,
     **model_kw,
 ) -> LLMEngine:
     cfg = EngineConfig(
@@ -42,7 +42,7 @@ def make_engine(
             max_num_seqs=max_seqs, max_num_batched_tokens=max_batched,
             async_scheduling=async_mode, speculative_ngram=spec,
             spec_ngram_k=k, spec_ngram_min_match=min_match,
-            decode_window=window, ragged_qlens=ragged,
+            decode_window=decode_window, ragged_qlens=ragged,
         ),
         parallel=ParallelConfig(tensor_parallel_size=1),
         seed=seed,
@@ -108,22 +108,36 @@ def test_accept_draft_tokens_rule():
 
 
 # --------------------------------------------------------------------- #
-# parity: spec on == spec off, byte for byte
+# parity: spec on == spec off, byte for byte. ``decode_window`` > 1 is
+# accepted beside speculation (a Helm value may set both) and inert: a
+# speculative engine verifies one-shot every step.
 
 
-def test_spec_parity_greedy():
+def _assert_decode_window_inert(eng):
+    """No step of a speculative engine rides the fused decode program."""
+    assert eng.runner.decode_windows == (1,)
+    assert not any(
+        fam == "decode_window" and shape[1] > 1
+        for _, fam, shape in eng.runner.traced_programs
+    )
+
+
+@pytest.mark.parametrize("decode_window", [1, 2, 4])
+def test_spec_parity_greedy(decode_window):
     sp = SamplingParams(temperature=0.0, max_tokens=24, ignore_eos=True)
     base = make_engine(False).generate(PROMPTS, sp)
-    eng = make_engine(True)
+    eng = make_engine(True, decode_window=decode_window)
     spec = eng.generate(PROMPTS, sp)
     assert list(base.values()) == list(spec.values())
     # speculation actually engaged (drafts proposed and some accepted)
     assert eng.scheduler.spec_proposed_tokens > 0
     assert eng.scheduler.spec_accepted_tokens > 0
     assert eng.allocator.usage() == 0.0
+    _assert_decode_window_inert(eng)
 
 
-def test_spec_parity_seeded_sampling():
+@pytest.mark.parametrize("decode_window", [1, 2, 4])
+def test_spec_parity_seeded_sampling(decode_window):
     """Seeded rows accept via the per-(seed, output-index) PRNG
     derivation. Low temperature keeps the seeded output loop-prone so
     drafts genuinely fire AND at least one accepts (hot sampling over a
@@ -132,14 +146,16 @@ def test_spec_parity_seeded_sampling():
     seeded leg."""
     sp = SamplingParams(temperature=0.3, max_tokens=16, seed=77, ignore_eos=True)
     base = make_engine(False, seed=3).generate(PROMPTS, sp)
-    eng = make_engine(True, seed=3)
+    eng = make_engine(True, seed=3, decode_window=decode_window)
     spec = eng.generate(PROMPTS, sp)
     assert list(base.values()) == list(spec.values())
     assert eng.scheduler.spec_proposed_tokens > 0
     assert eng.scheduler.spec_accepted_tokens > 0
+    _assert_decode_window_inert(eng)
 
 
-def test_spec_parity_chunked_prefill_and_preemption():
+@pytest.mark.parametrize("decode_window", [1, 4])
+def test_spec_parity_chunked_prefill_and_preemption(decode_window):
     """Tight pool + long periodic prompt: chunked prefill across steps
     and recompute-preemption under page pressure, with drafts in
     flight."""
@@ -159,9 +175,12 @@ def test_spec_parity_chunked_prefill_and_preemption():
     kw = dict(num_blocks=16, max_batched=16)  # tight pool -> preemption
     base_eng = make_engine(False, **kw)
     base = base_eng.generate([list(p) for p in prompts], params)
-    eng = make_engine(True, **kw)
+    eng = make_engine(True, decode_window=decode_window, **kw)
     spec = eng.generate([list(p) for p in prompts], params)
     assert list(base.values()) == list(spec.values())
+    assert eng.scheduler.num_preemptions > 0, (
+        "pool was not tight enough to exercise preemption"
+    )
     assert eng.allocator.usage() == 0.0
 
 
@@ -194,8 +213,9 @@ def test_spec_parity_stop_token_mid_window():
     assert list(base.values()) == list(spec.values())
 
 
+@pytest.mark.parametrize("decode_window", [1, 4])
 @pytest.mark.parametrize("seeded", [False, True])
-def test_spec_parity_async_scheduling(seeded):
+def test_spec_parity_async_scheduling(seeded, decode_window):
     """Spec composes with async stepping: the staged next batch is
     planned against max-acceptance counts, and short acceptance lands as
     a partial rollback — streams still byte-identical to the plain sync
@@ -205,7 +225,7 @@ def test_spec_parity_async_scheduling(seeded):
     else:
         sp = SamplingParams(temperature=0.0, max_tokens=14, ignore_eos=True)
     base = make_engine(False).generate(PROMPTS, sp)
-    eng = make_engine(True, async_mode=True)
+    eng = make_engine(True, async_mode=True, decode_window=decode_window)
     out = eng.generate(PROMPTS, sp)
     assert list(base.values()) == list(out.values())
     assert eng._inflight is None
@@ -299,88 +319,25 @@ def test_rejected_drafts_never_enter_prefix_index(async_mode):
     assert eng.allocator.usage() == 0.0  # all pages returned
 
 
-def test_spec_truncation_returns_pages_sync():
+@pytest.mark.parametrize("decode_window", [1, 4])
+def test_spec_truncation_returns_pages_sync(decode_window):
     """Sync engines truncate a drafting row's pages back to the computed
-    span every step: mid-run, no running request may hold pages past
-    ceil(computed / page) (the provisional-write span is transient)."""
-    sp = SamplingParams(temperature=0.0, max_tokens=16, ignore_eos=True)
-    eng = make_engine(True, page=4)
-    for p in PROMPTS:
-        eng.add_request(list(p), sp)
-    saw_drafting_step = False
-    for _ in range(64):
-        if not eng.has_work():
-            break
-        eng.step()
-        if eng.scheduler.spec_proposed_tokens:
-            saw_drafting_step = True
-        for req in eng.scheduler.running:
-            if req.in_decode:
-                max_pages = -(-req.num_computed_tokens // 4)
-                assert len(req.block_ids) <= max_pages + 1, (
-                    req.request_id, req.num_computed_tokens,
-                    len(req.block_ids),
-                )
-    assert saw_drafting_step
-
-
-# --------------------------------------------------------------------- #
-# fused verify windows (spec x decode_window composition)
-
-
-@pytest.mark.parametrize("window", [2, 4])
-def test_spec_window_parity_greedy(window):
-    """The fused verify window changes how many host round-trips emit
-    the stream, never WHICH tokens: byte parity vs the spec-off engine
-    across window sizes, with windows actually engaging."""
+    span every step: mid-run, with drafts rejected mid-draft, no running
+    request may hold pages past ceil(computed / page) (the
+    provisional-write span is transient), and the allocator's content
+    index holds accepted content only."""
     sp = SamplingParams(temperature=0.0, max_tokens=24, ignore_eos=True)
-    base = make_engine(False).generate([list(p) for p in PROMPTS], sp)
-    eng = make_engine(True, window=window)
-    out = eng.generate([list(p) for p in PROMPTS], sp)
-    assert list(base.values()) == list(out.values())
-    assert eng.scheduler.spec_window_iters > 0  # windows actually ran
-    assert eng.scheduler.spec_accepted_tokens > 0
-    assert eng.allocator.usage() == 0.0
-
-
-@pytest.mark.parametrize("window", [2, 4])
-def test_spec_window_parity_seeded(window):
-    """Seeded rows accept via the per-(seed, output-index) derivation
-    computed ON DEVICE (`sampler.spec_seed` inside the fori_loop body —
-    a row's output index mid-window depends on its own acceptance);
-    the stream must equal the spec-off engine's bit for bit. Long
-    enough outputs that decode spans several windows (a single window
-    would finish the request before any draft can fire)."""
-    sp = SamplingParams(temperature=0.3, max_tokens=40, seed=77, ignore_eos=True)
-    base = make_engine(False, seed=3, num_blocks=96).generate(
-        [list(p) for p in PROMPTS], sp
+    eng = make_engine(
+        True, page=4, num_blocks=96, decode_window=decode_window
     )
-    eng = make_engine(True, window=window, seed=3, num_blocks=96)
-    out = eng.generate([list(p) for p in PROMPTS], sp)
-    assert list(base.values()) == list(out.values())
-    assert eng.scheduler.spec_window_iters > 0
-    assert eng.scheduler.spec_proposed_tokens > 0
-
-
-def test_spec_window_mid_rejection_truncation_invariant():
-    """Mid-window rejection: the device degrades the row to one-token
-    iterations and the host's `_truncate_spec_pages` frees everything
-    past the accepted span — the allocator's content index must hold
-    accepted content only, and no running row may retain pages past its
-    computed span between steps."""
-    sp = SamplingParams(temperature=0.0, max_tokens=24, ignore_eos=True)
-    eng = make_engine(True, window=4, page=4, num_blocks=96)
     for p in PROMPTS:
         eng.add_request(list(p), sp)
-    saw_window = False
     streams: dict[str, list[int]] = {}
     for _ in range(64):
         if not eng.has_work():
             break
         for out in eng.step():
             streams.setdefault(out.request_id, []).extend(out.new_token_ids)
-        if eng.scheduler.spec_window_iters:
-            saw_window = True
         for req in eng.scheduler.running:
             if req.in_decode:
                 max_pages = -(-req.num_computed_tokens // 4)
@@ -388,10 +345,9 @@ def test_spec_window_mid_rejection_truncation_invariant():
                     req.request_id, req.num_computed_tokens,
                     len(req.block_ids),
                 )
-    assert saw_window
     sch = eng.scheduler
     assert sch.spec_proposed_tokens > sch.spec_accepted_tokens > 0, (
-        "workload produced no mid-window rejections: nothing was proved"
+        "workload produced no mid-draft rejections: nothing was proved"
     )
     _committed_hashes_are_subset_of_accepted(
         eng, list(streams.values()), PROMPTS
@@ -399,114 +355,16 @@ def test_spec_window_mid_rejection_truncation_invariant():
     assert eng.allocator.usage() == 0.0
 
 
-def test_spec_window_preemption():
-    """Page pressure while planning a window's max-acceptance width
-    (window x (1+k) pages per row) triggers recompute-preemption inside
-    the window machinery; streams must still match the spec-off engine
-    run under the SAME pool."""
-    sp = SamplingParams(temperature=0.0, max_tokens=16, ignore_eos=True)
-    kw = dict(page=4, num_blocks=20, max_batched=64)
-    base = make_engine(False, **kw).generate([list(p) for p in PROMPTS], sp)
-    eng = make_engine(True, window=4, **kw)
-    out = eng.generate([list(p) for p in PROMPTS], sp)
-    assert list(base.values()) == list(out.values())
-    assert eng.scheduler.num_preemptions > 0, (
-        "pool was not tight enough to exercise preemption"
-    )
+# --------------------------------------------------------------------- #
+# one-shot verify under async stepping, readbacks and accounting
 
 
-def test_spec_window_async_rollback():
-    """Fused verify windows compose with async stepping: the staged
-    batch plans window x (1+k) pending tokens per row, short acceptance
-    reconciles through the pending-count drain, and LENGTH finishes
-    invalidate staged rows through the rollback path."""
-    sp = SamplingParams(temperature=0.0, max_tokens=24, ignore_eos=True)
-    base = make_engine(False).generate([list(p) for p in PROMPTS], sp)
-    eng = make_engine(True, window=4, async_mode=True)
-    out = eng.generate([list(p) for p in PROMPTS], sp)
-    assert list(base.values()) == list(out.values())
-    assert eng._inflight is None
-    assert eng.scheduler.spec_window_iters > 0
-    assert eng.stats.async_rollbacks_total >= 1
-    assert eng.allocator.usage() == 0.0
-
-
-def test_spec_window_one_readback_per_window():
-    """THE point of the fusion: exactly one host readback per engine
-    step (a whole window of verify iterations rides one coalesced
-    transfer), and dispatches-per-emitted-token at window=4 is at most
-    half the window=1 value on this draft-friendly workload."""
-    sp = SamplingParams(temperature=0.0, max_tokens=24, ignore_eos=True)
-
-    def run(window):
-        eng = make_engine(True, window=window)
-        calls = {"n": 0}
-        orig = eng.runner.wait_step
-        def counting(prefill, decode, unified=None):
-            calls["n"] += 1
-            return orig(prefill, decode, unified)
-        eng.runner.wait_step = counting
-        eng.generate([list(p) for p in PROMPTS], sp)
-        # one blocking readback per step, however many verify
-        # iterations (and prefill groups) the step fused
-        assert calls["n"] == eng.stats.engine_steps_total
-        return eng
-
-    w1 = run(1)
-    w4 = run(4)
-    assert w4.scheduler.spec_window_iters > 0
-    assert w1.stats.generation_tokens == w4.stats.generation_tokens
-    r1 = w1.stats.dispatches_per_emitted_token
-    r4 = w4.stats.dispatches_per_emitted_token
-    assert r4 <= 0.5 * r1, (r4, r1)
-
-
-def test_spec_window_metrics_surface():
-    sp = SamplingParams(temperature=0.0, max_tokens=24, ignore_eos=True)
-    eng = make_engine(True, window=4)
-    eng.generate([list(p) for p in PROMPTS], sp)
-    st = eng.stats
-    assert st.spec_window_iters_total > 0
-    assert st.decode_dispatches_total > 0
-    assert 0.0 < st.dispatches_per_emitted_token < 1.0
-    from llmd_tpu.serve.metrics import parse_prometheus, render_metrics
-
-    page = render_metrics(st, "tiny")
-    parsed = parse_prometheus(page)
-    assert parsed["llmd:spec_window_iters_total"] == st.spec_window_iters_total
-    assert (
-        parsed["llmd:spec_window_early_exit_total"]
-        == st.spec_window_early_exit_total
-    )
-    assert parsed["llmd:decode_dispatches_total"] == st.decode_dispatches_total
-    assert "llmd:dispatches_per_emitted_token" in parsed
-
-
-def test_spec_window_accept_len_hist_mean_is_exact():
-    """Windowed acceptance folds into the accepted-len histogram with
-    (count, sum) preserved: count equals the verify row-iterations run
-    and sum equals the accepted draft tokens, so the dashboard's
-    mean-emitted reading stays exact."""
-    sp = SamplingParams(temperature=0.0, max_tokens=24, ignore_eos=True)
-    eng = make_engine(True, window=4)
-    eng.generate([list(p) for p in PROMPTS], sp)
-    sch = eng.scheduler
-    hist = sch.spec_accept_len_hist
-    assert sum(j * c for j, c in enumerate(hist)) == sch.spec_accepted_tokens
-    # every hist count is a (row, iteration-or-step) sample; window rows
-    # contributed exactly their active iterations
-    assert sum(hist) >= sch.spec_window_iters > 0
-
-
-def test_spec_window_async_staggered_finishes():
-    """Async rollback inside window mode: a batch-mate finishing at
-    reconcile must NOT demote the surviving window-planned rows (widths
-    up to window x (1+k), pre-draft caps to match) onto the one-shot
-    verify path — whose arrays are only 1+k wide, so a windowed draft
-    overruns them. The reconciled batch must keep its window: every
-    reconcile-step dispatch whose surviving rows carry window-planned
-    widths must still see spec_window > 1. Staggered max_tokens force
-    rollbacks on several different steps."""
+def test_spec_async_staggered_finishes():
+    """Async rollback under speculation: staggered max_tokens make
+    batch-mates finish at reconcile on several different steps; the
+    surviving rows keep their planned 1 + k widths through the
+    reconciled batch and the streams stay byte-identical to the plain
+    sync engine."""
     prompts = [list(p) for p in (PROMPTS * 2)]
     params = [
         SamplingParams(
@@ -517,11 +375,9 @@ def test_spec_window_async_staggered_finishes():
     base = make_engine(False, num_blocks=128, max_seqs=8).generate(
         [list(p) for p in prompts], list(params)
     )
-    eng = make_engine(
-        True, window=4, async_mode=True, num_blocks=128, max_seqs=8
-    )
+    eng = make_engine(True, async_mode=True, num_blocks=128, max_seqs=8)
     spec_k = eng.scheduler.spec_k
-    reconciled: list[tuple[int, int]] = []  # (spec_window, max planned)
+    reconciled: list[int] = []  # widest planned row of a reconciled batch
     seen = {"rollbacks": 0}
     orig = eng._dispatch_async
 
@@ -530,30 +386,72 @@ def test_spec_window_async_staggered_finishes():
             eng.stats.async_rollbacks_total > seen["rollbacks"]
             and batch.decodes
         ):
-            reconciled.append((
-                batch.spec_window,
-                max(s.num_tokens for s in batch.decodes),
-            ))
+            reconciled.append(max(s.num_tokens for s in batch.decodes))
         seen["rollbacks"] = eng.stats.async_rollbacks_total
         return orig(batch, staged_dec)
 
     eng._dispatch_async = spy
     out = eng.generate([list(p) for p in prompts], list(params))
     assert list(base.values()) == list(out.values())
-    assert eng.stats.async_rollbacks_total > 0
-    assert eng.scheduler.spec_window_iters > 0
-    survived_windowed = [
-        (w, width) for w, width in reconciled if width > 1 + spec_k
-    ]
-    assert survived_windowed, (
-        "no reconciled batch kept window-planned survivors: the "
-        "rollback-keeps-window path was never exercised", reconciled,
+    assert len(reconciled) > 1, (
+        "rollbacks did not land on several steps", reconciled
     )
-    assert all(w > 1 for w, _ in survived_windowed), (
-        "a reconciled batch dropped its spec_window while its rows "
-        "kept window-planned widths", reconciled,
+    assert max(reconciled) == 1 + spec_k, (
+        "no reconciled batch kept a drafting survivor", reconciled
     )
+    assert eng.scheduler.spec_accepted_tokens > 0
     assert eng.allocator.usage() == 0.0
+
+
+def _count_wait_steps(eng) -> dict:
+    """Count the engine's blocking readbacks (``runner.wait_step``)."""
+    calls = {"n": 0}
+    orig = eng.runner.wait_step
+
+    def counting(prefill, decode, unified=None):
+        calls["n"] += 1
+        return orig(prefill, decode, unified)
+
+    eng.runner.wait_step = counting
+    return calls
+
+
+@pytest.mark.parametrize("async_mode", [False, True])
+def test_spec_one_readback_per_step(async_mode):
+    """Exactly one blocking host readback per dispatched step, however
+    many programs the step's verify/plain split launched, and accepted
+    drafts push decode dispatches per emitted token below one."""
+    sp = SamplingParams(temperature=0.0, max_tokens=24, ignore_eos=True)
+    eng = make_engine(True, async_mode=async_mode)
+    calls = _count_wait_steps(eng)
+    eng.generate([list(p) for p in PROMPTS], sp)
+    assert calls["n"] == eng.stats.engine_steps_total
+    assert eng.stats.decode_dispatches_total > 0
+    assert 0.0 < eng.stats.dispatches_per_emitted_token < 1.0
+
+
+@pytest.mark.parametrize("async_mode", [False, True])
+def test_spec_accept_len_hist_mean_is_exact(async_mode):
+    """The accepted-len histogram keeps (count, sum) exact: sum equals
+    the accepted draft tokens and count the (spec row, step) samples,
+    so the dashboard's mean-emitted reading 1 + sum / count is the
+    decode tokens emitted per row-step."""
+    sp = SamplingParams(temperature=0.0, max_tokens=24, ignore_eos=True)
+    eng = make_engine(True, async_mode=async_mode)
+    rows = {"n": 0}
+    orig = eng.scheduler.update_after_step
+
+    def counting(batch, sampled):
+        rows["n"] += len(batch.decodes)
+        return orig(batch, sampled)
+
+    eng.scheduler.update_after_step = counting
+    eng.generate([list(p) for p in PROMPTS], sp)
+    sch = eng.scheduler
+    hist = sch.spec_accept_len_hist
+    accepted = sum(j * c for j, c in enumerate(hist))
+    assert accepted == sch.spec_accepted_tokens > 0
+    assert sum(hist) == rows["n"]
 
 
 def test_async_mixed_step_reuses_staged_arrays():
@@ -757,14 +655,7 @@ def test_unified_spec_one_readback_per_step():
 
     def run(unified):
         eng = make_unified_spec(unified)
-        calls = {"n": 0}
-        orig = eng.runner.wait_step
-
-        def counting(prefill, decode, unified_pend=None):
-            calls["n"] += 1
-            return orig(prefill, decode, unified_pend)
-
-        eng.runner.wait_step = counting
+        calls = _count_wait_steps(eng)
         out = eng.generate([list(p) for p in UNIFIED_SPEC_PROMPTS], sp)
         assert calls["n"] == eng.stats.engine_steps_total
         return eng, out
@@ -783,27 +674,20 @@ def test_unified_spec_one_readback_per_step():
 # config / observability surfaces
 
 
-def test_spec_window_config():
-    """The composition is accepted now; the window-aware validation
-    rejects knob combinations that could only misconfigure."""
-    cfg = SchedulerConfig(speculative_ngram=True, decode_window=4)
-    assert cfg.spec_window == 4
-    assert cfg.spec_window_set == (2, 4)
-    # explicit override decouples the verify window from decode_window
+def test_spec_decode_window_config():
+    """speculative_ngram beside decode_window > 1 is accepted (and
+    inert: see the parity tests); the knob that sized the retired fused
+    verify window is gone."""
     cfg = SchedulerConfig(
-        speculative_ngram=True, decode_window=8, spec_verify_window=2
+        speculative_ngram=True, decode_window=4, spec_ngram_k=4,
+        max_num_batched_tokens=8,
     )
-    assert cfg.spec_window == 2
-    assert SchedulerConfig(speculative_ngram=True).spec_window_set == ()
-    with pytest.raises(ValueError, match="spec_verify_window"):
-        SchedulerConfig(spec_verify_window=-1)
-    with pytest.raises(ValueError, match="speculative_ngram"):
-        SchedulerConfig(spec_verify_window=4)
-    with pytest.raises(ValueError, match="max_num_batched_tokens"):
-        SchedulerConfig(
-            speculative_ngram=True, decode_window=2, spec_ngram_k=4,
-            max_num_batched_tokens=8,
-        )
+    assert cfg.decode_window == 4
+    retired = "spec_verify" "_window"  # split: the name is gone from the tree
+    with pytest.raises(TypeError, match=retired):
+        SchedulerConfig(speculative_ngram=True, **{retired: 2})
+    with pytest.raises(ValueError, match="spec_ngram_k"):
+        SchedulerConfig(speculative_ngram=True, spec_ngram_k=0)
 
 
 def test_spec_metrics_surface():
@@ -822,6 +706,8 @@ def test_spec_metrics_surface():
     assert parsed["llmd:spec_proposed_tokens_total"] == st.spec_proposed_tokens_total
     assert parsed["llmd:spec_accepted_tokens_total"] == st.spec_accepted_tokens_total
     assert "llmd:spec_acceptance_rate" in parsed
+    assert parsed["llmd:decode_dispatches_total"] == st.decode_dispatches_total
+    assert "llmd:dispatches_per_emitted_token" in parsed
     assert 'llmd:spec_accepted_len_bucket{le="+Inf"' in page
     # per-request accounting rode along
     assert "llmd:spec_accepted_len_sum" in page
@@ -845,9 +731,9 @@ def test_spec_off_emits_no_spec_metrics():
 # The shared `leaksan` fixture lives in conftest.py.
 
 
-def _run_spec_workload(window=4):
+def _run_spec_workload():
     sp = SamplingParams(temperature=0.0, max_tokens=16, ignore_eos=True)
-    eng = make_engine(True, page=4, window=window)
+    eng = make_engine(True, page=4)
     for p in PROMPTS:
         eng.add_request(list(p), sp)
     saw_spec = False
@@ -863,7 +749,7 @@ def _run_spec_workload(window=4):
 
 
 def test_spec_truncation_leak_free_under_sanitizer(leaksan):
-    """Mid-window rejections truncate provisional pages back through
+    """Mid-draft rejections truncate provisional pages back through
     allocator.free: a full spec workload ends with ZERO outstanding
     page refs on the engine's allocator."""
     leaksan.leaksan_set_test("pin::spec-truncate")
@@ -883,7 +769,7 @@ def test_spec_truncation_drop_without_free_caught(leaksan, monkeypatch):
         page = self.allocator.page_size
         slots = req.num_computed_tokens
         if self.config.async_scheduling:
-            slots = req.num_dispatched_tokens + self.spec_plan_max
+            slots = req.num_dispatched_tokens + 1 + self.spec_k
         keep = -(-slots // page)
         if keep < len(req.block_ids):
             del req.block_ids[keep:]  # dropped, never freed: the bug

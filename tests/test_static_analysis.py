@@ -447,24 +447,25 @@ class TestLockstep:
             f.code == "LS001" and "_OP_VERIFY" in f.message for f in findings
         )
 
-    def test_real_runner_missing_verify_window_arm_fails(self, tmp_path):
-        """Acceptance pin for the fused verify window's opcode: deleting
-        the _OP_VERIFY_WINDOW follower arm from the REAL runner must
-        fail the build (a follower would mirror the wrong program and
-        desynchronize the lockstep collective stream)."""
+    def test_real_runner_verify_never_broadcast_fails(self, tmp_path):
+        """The leader's side of the _OP_VERIFY arm: a REAL runner whose
+        verify dispatches broadcast another family's opcode must fail
+        the build (followers would mirror the prefill program while the
+        leader runs verify — the lockstep collective stream
+        desynchronizes)."""
         src = RUNNER.read_text()
-        arm = (
-            "            elif op == _OP_VERIFY_WINDOW:\n"
-            "                self._exec_verify_window(arrays, QK, bool(greedy))\n"
+        sites = ("                _OP_VERIFY, staged.B, staged.q,",
+                 "self._sync_locked(_OP_VERIFY, B, Q,")
+        assert all(src.count(site) == 1 for site in sites), (
+            "verify dispatch layout changed; update this pin"
         )
-        assert arm in src, "follower_loop layout changed; update this pin"
-        mutated = src.replace(arm, "")
+        for site in sites:
+            src = src.replace(site, site.replace("_OP_VERIFY", "_OP_PREFILL"))
         (tmp_path / "engine").mkdir(parents=True)
-        (tmp_path / "engine/runner.py").write_text(mutated)
+        (tmp_path / "engine/runner.py").write_text(src)
         findings, _ = run_analysis(tmp_path, [str(tmp_path)], ["lockstep"])
         assert any(
-            f.code == "LS001" and "_OP_VERIFY_WINDOW" in f.message
-            for f in findings
+            f.code == "LS003" and "_OP_VERIFY" in f.message for f in findings
         )
 
     def test_real_runner_missing_unified_arm_fails(self, tmp_path):
