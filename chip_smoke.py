@@ -734,7 +734,7 @@ def phase_kernels(profile: dict, seed: int, rehearse: bool) -> None:
     w = normal((g["experts"], g["hidden"], g["ffn"]), act, g["hidden"] ** -0.5)
     sizes = jnp.asarray(rng.multinomial(g["tokens"], np.ones(g["experts"]) / g["experts"]).astype(np.int32))
     if rehearse:
-        os.environ["LLMD_PALLAS"] = "interpret"  # the only switch megablox has
+        os.environ["LLMD_PALLAS"] = "interpret"  # the only switch the kernel has
     gplans: dict = {}
     with ops.record_plans(gplans):
         ggot = jax.jit(grouped_matmul)(xs, w, sizes)

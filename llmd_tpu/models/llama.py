@@ -22,7 +22,12 @@ from llmd_tpu.models.common import (
     StepInput, apply_rope, layer_norm, param_dtype, pdot, rms_norm,
     rope_tables,
 )
-from llmd_tpu.models.moe import moe_block
+from llmd_tpu.models.moe import (
+    STACKED_EXPERT_LEAVES,
+    experts_of_layer,
+    moe_block,
+    moe_block_grouped,
+)
 from llmd_tpu.ops import (
     paged_attention_full,
     paged_attention_full_flat,
@@ -305,9 +310,19 @@ def forward_hidden(
         # element in [-1] maxes.
         return jnp.concatenate([a[:-1] + b[:-1], jnp.maximum(a[-1:], b[-1:])])
 
-    def _ffn(h2, lp, use_moe: bool, cap_scale: float = 1.0):
-        """FFN/MoE of one slice; returns (y, census_delta | None)."""
+    def _ffn(h2, lp, use_moe: bool, cap_scale: float = 1.0, moe_layer=None):
+        """FFN/MoE of one slice; returns (y, census_delta | None).
+        ``moe_layer``: the layer's index into ``lp``'s stacked expert
+        leaves (the layer scans below do not slice them)."""
         if use_moe:
+            if grouped:
+                out = moe_block_grouped(
+                    h2, lp, cfg, mesh=mesh, emit_census=use_census,
+                    layer=moe_layer,
+                )
+                return out if use_census else (out, None)
+            # The other backends consume the experts in XLA operations.
+            lp = experts_of_layer(lp, moe_layer)
             if moe_backend == "ep":
                 from llmd_tpu.parallel.moe_ep import moe_block_ep
 
@@ -318,13 +333,6 @@ def forward_hidden(
                     emit_census=use_census,
                 )
                 return out if use_census else (out, None)
-            if grouped:
-                from llmd_tpu.models.moe import moe_block_grouped
-
-                out = moe_block_grouped(
-                    h2, lp, cfg, mesh=mesh, emit_census=use_census
-                )
-                return out if use_census else (out, None)
             # Sharded jit without the EP backend: the dense combine is
             # the only path GSPMD can partition (expert weights are
             # EP-sharded; the grouped kernel has no partitioning rule
@@ -333,12 +341,13 @@ def forward_hidden(
             return moe_block(h2, lp, cfg), None
         return _mlp(h2, lp), None
 
-    def _tail(x_sl, attn_sl, lp, use_moe, cap_scale: float = 1.0):
+    def _tail(x_sl, attn_sl, lp, use_moe, cap_scale: float = 1.0,
+              moe_layer=None):
         """Post-attention chain of one (micro)batch slice: residual +
         post-norm + FFN/MoE + residual. Returns (x, census_delta)."""
         x_sl = x_sl + attn_sl
         h2 = rms_norm(x_sl, lp["post_norm"], cfg.rms_norm_eps)
-        y, cd = _ffn(h2, lp, use_moe, cap_scale)
+        y, cd = _ffn(h2, lp, use_moe, cap_scale, moe_layer)
         return x_sl + y, cd
 
     def _tails_dbo(pairs):
@@ -350,8 +359,11 @@ def forward_hidden(
         return jnp.concatenate(xs, axis=0), cd
 
     def layer_body(x, cache, lp, layer_idx, use_moe: bool, window=None,
-                   table=None, run_phys=None):
-        """One decoder layer; returns (x, cache, census_delta | None)."""
+                   table=None, run_phys=None, moe_layer=None):
+        """One decoder layer; returns (x, cache, census_delta | None).
+        ``layer_idx`` is the layer's plane of ``cache``; ``moe_layer`` its
+        index into ``params["layers"]``, whose expert leaves ``lp`` holds
+        whole (the dense prefix shifts one against the other)."""
         if table is None:
             table = inp.page_table
         h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
@@ -373,7 +385,9 @@ def forward_hidden(
                         inp.positions[sl], cfg,
                         world_size=world_size, mesh=mesh,
                     )
-                    outs.append(_tail(x[sl], attn_sl, lp, use_moe, 2.0))
+                    outs.append(
+                        _tail(x[sl], attn_sl, lp, use_moe, 2.0, moe_layer)
+                    )
                 x2, cd = _tails_dbo(outs)
                 return x2, cache, cd
             attn_out, cache = mla_attention(
@@ -467,9 +481,10 @@ def forward_hidden(
                         world_size=world_size, mesh=mesh, window=window,
                         sinks=sinks,
                     )
-                    outs.append(
-                        _tail(x[sl], _project(attn_sl, half), lp, use_moe, 2.0)
-                    )
+                    outs.append(_tail(
+                        x[sl], _project(attn_sl, half), lp, use_moe, 2.0,
+                        moe_layer,
+                    ))
                 x2, cd = _tails_dbo(outs)
                 return x2, cache, cd
             if cfg.sparse_attention:
@@ -500,7 +515,7 @@ def forward_hidden(
                 )
             x = x + _project(attn, B)
         # attention residual already applied above; _tail adds 0
-        x, cd = _tail(x, 0.0, lp, use_moe)
+        x, cd = _tail(x, 0.0, lp, use_moe, moe_layer=moe_layer)
         return x, cache, cd
 
     # DeepSeek-style dense prefix: the first N layers (N static, 1-3)
@@ -547,7 +562,17 @@ def forward_hidden(
     scan_kinds = kinds[n_dense:]
     plane_arr = jnp.asarray(plane[n_dense:], jnp.int32)
     win_arr = windows[n_dense:] if windows is not None else None
-    lp_all = params["layers"]
+    # The stacked expert leaves [L, E, ..] do not ride the scans as ``xs``:
+    # XLA fuses a scanned slice into an XLA consumer and MATERIALISES it for
+    # a Pallas one, all E x 3 x K x N of a layer read and written in every
+    # layer of every step. The bodies close over the whole leaves (loop
+    # invariant, like the rope table) and get the layer's index into them
+    # beside its plane; the grouped kernel reads its layer in place.
+    experts = {
+        k: a for k, a in params["layers"].items() if k in STACKED_EXPERT_LEAVES
+    }
+    lp_all = {k: a for k, a in params["layers"].items() if k not in experts}
+    layer_arr = jnp.arange(n_scan, dtype=jnp.int32)
 
     def _reduce_census(stacked):
         """Reduce per-layer census deltas [n, E+2] into the accumulator:
@@ -560,7 +585,7 @@ def forward_hidden(
             jnp.max(stacked[:, -1:], axis=0),
         ])
 
-    def scan_group(x, cache, census, table, lp, plane_ids, wins,
+    def scan_group(x, cache, census, table, lp, plane_ids, layer_ids, wins,
                    run_phys=None):
         """One homogeneous run of layers sharing a pool/table. The census
         delta rides the scan as a per-layer OUTPUT (stacked then reduced)
@@ -569,18 +594,15 @@ def forward_hidden(
 
         def fn(carry, scanned):
             x, cache = carry
-            if wins is None:
-                lp_s, pid = scanned
-                w = None
-            else:
-                lp_s, pid, w = scanned
+            lp_s, pid, lid, *w = scanned
             x, cache, cd = layer_body(
-                x, cache, lp_s, pid, use_moe=cfg.is_moe, window=w,
-                table=table, run_phys=run_phys,
+                x, cache, {**lp_s, **experts}, pid, use_moe=cfg.is_moe,
+                window=w[0] if w else None, table=table, run_phys=run_phys,
+                moe_layer=lid,
             )
             return (x, cache), cd
 
-        scanned = (lp, plane_ids) if wins is None else (lp, plane_ids, wins)
+        scanned = (lp, plane_ids, layer_ids, *([] if wins is None else [wins]))
         (x, cache), cds = jax.lax.scan(fn, (x, cache), scanned)
         if census is not None and cds is not None:
             census = _census_merge(census, _reduce_census(cds))
@@ -589,8 +611,8 @@ def forward_hidden(
     if len(set(scan_kinds)) <= 1:
         g = scan_kinds[0] if scan_kinds else 0
         x, caches[g], census = scan_group(
-            x, caches[g], census, tables[g], lp_all, plane_arr, win_arr,
-            run_physes[g],
+            x, caches[g], census, tables[g], lp_all, plane_arr, layer_arr,
+            win_arr, run_physes[g],
         )
     elif (c := _scan_period(scan_kinds)) is not None:
         # Hybrid periodic pattern (gpt-oss alternating): scan over CYCLES
@@ -602,21 +624,22 @@ def forward_hidden(
             return a.reshape(T, c, *a.shape[1:])
 
         cyc_scanned = (
-            jax.tree.map(resh, lp_all), resh(plane_arr), resh(win_arr)
+            jax.tree.map(resh, lp_all), resh(plane_arr), resh(layer_arr),
+            resh(win_arr),
         )
 
         def cyc(carry, scanned):
             x, cf, cs = carry
             cc = [cf, cs]
-            lp_c, plane_c, win_c = scanned
+            lp_c, plane_c, layer_c, win_c = scanned
             cd_cyc = None
             for j in range(c):
-                lp_s = jax.tree.map(lambda a: a[j], lp_c)
+                lp_s = {**jax.tree.map(lambda a: a[j], lp_c), **experts}
                 g = scan_kinds[j]  # periodic: same kind for every cycle
                 x, cc[g], cd = layer_body(
                     x, cc[g], lp_s, plane_c[j], use_moe=cfg.is_moe,
                     window=win_c[j] if g else None, table=tables[g],
-                    run_phys=run_physes[g],
+                    run_phys=run_physes[g], moe_layer=layer_c[j],
                 )
                 if cd is not None:
                     cd_cyc = cd if cd_cyc is None else _census_merge(cd_cyc, cd)
@@ -640,7 +663,7 @@ def forward_hidden(
             x, caches[g], census = scan_group(
                 x, caches[g], census, tables[g],
                 jax.tree.map(lambda a: a[sl], lp_all),
-                plane_arr[sl], win_arr[sl] if g else None,
+                plane_arr[sl], layer_arr[sl], win_arr[sl] if g else None,
                 run_physes[g],
             )
             off += ln

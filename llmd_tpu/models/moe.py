@@ -126,13 +126,31 @@ def expert_glu(gate: jax.Array, up: jax.Array, cfg: ModelConfig) -> jax.Array:
     return jax.nn.silu(gate) * up
 
 
+# The expert leaves that the layer scan does not slice (forward_hidden): the
+# grouped kernel reads its layer of the stacked [L, E, ..] leaf in place.
+STACKED_EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
+
+
+def experts_of_layer(lp: dict, layer) -> dict:
+    """``lp`` with one layer's ``[E, ..]`` of each stacked expert leaf: for
+    the MoE paths whose consumers are XLA operations (the dense combine, the
+    EP backend's shard_map), at the point of use."""
+    from llmd_tpu.ops.grouped_gemm import layer_of
+
+    return {
+        **lp, **{k: layer_of(lp[k], layer) for k in STACKED_EXPERT_LEAVES}
+    }
+
+
 def moe_block_grouped(
     h: jax.Array, lp: dict, cfg: ModelConfig, mesh=None,
-    emit_census: bool = False,
+    emit_census: bool = False, layer=None,
 ) -> jax.Array:
     """MoE FFN via grouped GEMM (DeepGEMM role): tokens sorted by expert,
     each expert multiplies only its routed rows. Numerically equivalent to
     the dense combine (same f32 weighted sum) at top_k/E of the FLOPs.
+    ``lp``'s expert leaves are one layer's ``[E, ..]``, or all layers'
+    ``[L, E, ..]`` with ``layer``, the index into them.
     With ``emit_census`` the return is ``(y, census)``: this call's [2] i32
     line of the step's count (``ops.grouped_gemm.grouped_census``)."""
     from llmd_tpu.ops.grouped_gemm import moe_apply_grouped
@@ -146,7 +164,7 @@ def moe_block_grouped(
     out = moe_apply_grouped(
         ht, weights, ids, lp["we_gate"], lp["we_up"], lp["we_down"],
         scales=_expert_scales(lp), biases=_expert_biases(lp), cfg=cfg,
-        mesh=mesh, emit_census=emit_census,
+        mesh=mesh, emit_census=emit_census, layer=layer,
     )
     census = None
     if emit_census:

@@ -41,7 +41,7 @@ Three composable perf layers sit on top of the base dispatch:
 
 Local expert compute runs the grouped GEMM (``ops.grouped_gemm``, the
 DeepGEMM role): received slots sorted by local expert id feed
-``megablox.gmm`` on TPU or ``lax.ragged_dot`` elsewhere, sized by the
+the Pallas kernel on TPU or ``lax.ragged_dot`` elsewhere, sized by the
 *received* group sizes so balanced placement directly shrinks padded FLOPs.
 """
 

@@ -11,6 +11,7 @@ chip). Run this file alone; the cases take a second or two each.
 
 import functools
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -160,16 +161,23 @@ def _mla_decode():
     )
 
 
-def _gmm(hidden, ffn, experts, device):
-    # The grouped GEMM picks megablox from the devices of the mesh it is
+def _one_chip_mesh(device):
+    # The grouped GEMM picks its kernel from the devices of the mesh it is
     # given: hand it the described chip (the default backend is the CPU).
-    # The tile comes from grouped_gemm.gmm_tiles: a weight block that
-    # overflows VMEM shows here (megablox sets no limit of its own).
-    mesh = Mesh(np.asarray([device]).reshape(1, 1), ("dp", "tp"))
+    return Mesh(np.asarray([device]).reshape(1, 1), ("dp", "tp"))
+
+
+def _gmm(hidden, ffn, experts, device):
+    # The weight operand is the stacked leaf of all layers and a layer index,
+    # as forward_hidden hands it over (two layers stand for any number: the
+    # block is one layer's one expert's). The tile comes from
+    # grouped_gemm.gmm_tiles: a weight block that overflows VMEM shows here
+    # (the call sets no limit of its own).
+    mesh = _one_chip_mesh(device)
     return (
-        lambda x, w, g: grouped_matmul(x, w, g, mesh),
-        [((2048, hidden), BF16), ((experts, hidden, ffn), BF16),
-         ((experts,), I32)],
+        lambda x, w, g, layer: grouped_matmul(x, w, g, mesh, layer),
+        [((2048, hidden), BF16), ((2, experts, hidden, ffn), BF16),
+         ((experts,), I32), ((), I32)],
     )
 
 
@@ -219,6 +227,45 @@ def test_kernel_compiles_for_v5e(v5e, case):
     ]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_a_layer_scan_hands_the_kernel_the_stacked_leaf(v5e):
+    """What PR 32 removed, guarded without a chip: XLA fuses a scanned slice
+    of the stacked expert leaf into an XLA consumer and MATERIALISES it for a
+    Pallas one (`%dynamic-slice_bitcast_fusion`, the largest device operation
+    of four cells of five). With the leaf closed over and the layer an
+    operand, the custom call reads the 4-D parameter itself and nothing in
+    the loop body makes one layer's ``[E, K, N]``."""
+    L, E, K, N, T = 2, 128, 2048, 768, 256  # qwen3-30b-a3b's gate, two layers
+    mesh = _one_chip_mesh(v5e)
+
+    def step(x, w, sizes):
+        def layer_body(x, layer):
+            y = grouped_matmul(x, w, sizes, mesh, layer)
+            return x + jnp.pad(y, ((0, 0), (0, K - N))), None
+
+        return jax.lax.scan(layer_body, x, jnp.arange(L, dtype=I32))[0]
+
+    on_chip = SingleDeviceSharding(v5e)
+    hlo = jax.jit(step).lower(*[
+        jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+        for shape, dtype in [((T, K), BF16), ((L, E, K, N), BF16), ((E,), I32)]
+    ]).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1
+    name, operands = calls[0].split(" = ")[0], calls[0].split("custom-call(")[1]
+    # The event's name is a contract: the benchmark's readers match ^%gmm.
+    assert name.strip().startswith("%gmm")
+    assert f"bf16[{L},{E},{K},{N}]" in operands
+    leaf = operands.split(")")[0].split(", ")[-1].replace("/*index=5*/", "")
+    # ... and that operand is the loop's pass-through of the parameter, not
+    # a copy: the instruction that defines it is a get-tuple-element.
+    assert leaf.startswith("%get-tuple-element"), leaf
+    sizes = {
+        int(np.prod([int(d) for d in dims.split(",")]))
+        for dims in re.findall(r"bf16\[([\d,]+)\]", hlo)
+    }
+    assert L * E * K * N in sizes and E * K * N not in sizes  # in any shape
 
 
 def test_page_table_at_the_smem_bound_compiles(v5e):

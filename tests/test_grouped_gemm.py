@@ -1,7 +1,8 @@
 """The grouped expert matmul (ops/grouped_gemm.py): the tile rule as a pure
-function, the megablox path in interpret mode against ``lax.ragged_dot`` at
-shapes with more than one tile on every axis, and the step programs' count of
-grouped calls and of groups with rows against a ``numpy`` count."""
+function, the layer-indexed kernel in interpret mode against ``lax.ragged_dot``
+on the layer's slice at shapes with more than one tile on every axis (and
+against megablox, bit for bit), and the step programs' count of grouped calls
+and of groups with rows against a ``numpy`` count."""
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +28,7 @@ def _blocks(tm, tk, tn, w_bytes, x_bytes):
     return 2 * (tm * tk * x_bytes + tk * tn * w_bytes + tm * tn * 4) + tm * tn * 4
 
 
-# Every expert projection of the registry that takes the megablox path (both
+# Every expert projection of the registry that takes the kernel's path (both
 # dims lane-tiled; gpt-oss's 2,880 and the tiny models take ragged_dot).
 EXPERT_SHAPES = sorted({
     (K, N)
@@ -90,24 +91,65 @@ def _sizes(rng, rows, groups, empty=()):
     return sizes
 
 
+# shape -> (K, N, groups without rows, VMEM budget, the f32 tiles it gives)
+KERNEL_SHAPES = {
+    # Under 2 MiB an f32 K of 1,024 does not fit whole: eight k tiles of 128
+    # beside two n tiles, 640 + 512 (N irregular against tn). Groups 1 and 5
+    # are empty, so the pad rows land in a last group that had none.
+    "split-k": (1024, 1152, (1, 5), 2 << 20, (128, 640)),
+    # DeepSeek-V2-Lite's N beside a whole K, as the cells run it: 768 + 640.
+    # The first group, the last and one in the middle are empty.
+    "n1408": (512, 1408, (0, 3, 5), 6 << 20, (512, 768)),
+}
+LAYERS = 3
+
+
+@pytest.mark.parametrize("layer", [None, 0, 1, LAYERS - 1],
+                         ids=["3d", "first", "middle", "last"])
 @pytest.mark.parametrize("rows", [4, 30, 48, 192])
-def test_grouped_matmul_megablox_matches_ragged_dot(monkeypatch, rows):
+@pytest.mark.parametrize("shape", KERNEL_SHAPES)
+def test_grouped_matmul_megablox_matches_ragged_dot(monkeypatch, shape, rows, layer):
     """tests/test_wide_ep.py::test_grouped_matmul_megablox_parity's row counts
     at more than one tile on every axis (that test only sees K = N = 128).
-    Under 2 MiB an f32 K of 1,024 does not fit whole: eight k tiles of 128
-    beside two n tiles, 640 + 512 (N irregular against tn). Groups 1 and 5
-    are empty, so the pad rows land in a last group that had none."""
-    K, N, groups, budget = 1024, 1152, 6, 2 << 20
+    The kernel takes the stacked ``[L, G, K, N]`` and reads layer ``layer`` of
+    it alone: every other layer is NaN. A 3-D weight is its one-layer case."""
+    K, N, empty, budget, tiles = KERNEL_SHAPES[shape]
+    groups = 6
     monkeypatch.setenv("LLMD_PALLAS", "interpret")
     monkeypatch.setattr(grouped_gemm, "_VMEM_BUDGET", budget)
-    assert gmm_tiles(K, N, 4, 4, budget) == (128, 640)
+    assert gmm_tiles(K, N, 4, 4, budget) == tiles
     rng = np.random.default_rng(rows)
     x = jnp.asarray(rng.standard_normal((rows, K)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((groups, K, N)) / np.sqrt(K), jnp.float32)
-    gs = jnp.asarray(_sizes(rng, rows, groups, empty=(1, 5)), jnp.int32)
+    gs = jnp.asarray(_sizes(rng, rows, groups, empty=empty), jnp.int32)
+    assert gs[-1] == 0  # pad rows make an empty last group live
     ref = jax.lax.ragged_dot(x, w, gs)
-    got = grouped_matmul(x, w, gs)
+    if layer is None:
+        got = grouped_matmul(x, w, gs)
+    else:
+        stacked = jnp.full((LAYERS, *w.shape), jnp.nan, w.dtype).at[layer].set(w)
+        got = grouped_matmul(x, stacked, gs, layer=jnp.int32(layer))
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_the_kernel_is_megablox_on_the_layer_bit_for_bit(dtype):
+    """The same metadata, grid, accumulation order and masks as megablox's
+    ``gmm``: on ``w[layer]`` with the same tiles the results are EQUAL, a short
+    last k tile (1,024 in tiles of 384) and an uneven last n tile included."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as megablox_gmm
+
+    rows, K, N, groups, tiling = 64, 1024, 1152, 6, (32, 384, 640)
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.standard_normal((rows, K)), dtype)
+    w = jnp.asarray(rng.standard_normal((LAYERS, groups, K, N)) / np.sqrt(K), dtype)
+    gs = jnp.asarray(_sizes(rng, rows, groups, empty=(2,)), jnp.int32)
+    for layer in range(LAYERS):
+        want = megablox_gmm(x, w[layer], gs, tiling=tiling, interpret=True)
+        got = grouped_gemm.gmm(
+            x, w, gs, jnp.full((1,), layer, jnp.int32), tiling=tiling, interpret=True
+        )
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # --- the step programs' count ------------------------------------------------
@@ -116,7 +158,7 @@ PROMPTS = [list(range(1, 16)), [3, 3, 7, 1, 9, 9, 2], list(range(30, 41))]
 
 
 def _engine(flat: bool, **model):
-    # Lane-tiled expert dims take the megablox path under LLMD_PALLAS=
+    # Lane-tiled expert dims take the kernel's path under LLMD_PALLAS=
     # interpret, so the rows it pads into the last group are in the count.
     model = {"hidden_size": 128, "num_heads": 4, "num_kv_heads": 2,
              "intermediate_size": 128, "num_experts": 8,
