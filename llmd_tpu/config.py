@@ -133,6 +133,21 @@ class ModelConfig:
     indexer_topk: int = 0
     indexer_num_heads: int = 0
     indexer_head_dim: int = 0
+    # --- the held share of the routed experts (docs/architecture/wide-ep.md,
+    # "One rank's share") ---
+    # One rank of an expert-parallel deployment holds ``held_experts`` of the
+    # ``num_experts`` the router scores, ids ``held_experts_first`` onward:
+    # the router keeps its width, the expert leaves are [L, held, ..], a
+    # pick outside the range gives no row, and the layer returns the held
+    # experts' part of the sum (plus the shared expert). None = all of them,
+    # which is every model that is served whole: the same rule, a range that
+    # covers every pick.
+    held_experts: int | None = None
+    held_experts_first: int = 0
+    # The ``layer_types`` values whose attention rotates q and k (RoPE).
+    # None = every layer. The EXAONE-4.0 family rotates its sliding layers
+    # only: ("sliding_attention",).
+    rope_layer_types: tuple | None = None
 
     def __post_init__(self) -> None:
         if self.quantization not in (None, "int8"):
@@ -151,6 +166,22 @@ class ModelConfig:
                     f"layer_types has {len(self.layer_types)} entries for "
                     f"{self.num_layers} layers"
                 )
+        if self.held_experts is None:
+            self.held_experts = self.num_experts
+        if not (
+            0 <= self.held_experts_first
+            and self.held_experts_first + self.held_experts <= self.num_experts
+            and (self.held_experts > 0 or self.num_experts == 0)
+        ):
+            raise ValueError(
+                f"held experts [{self.held_experts_first}, "
+                f"{self.held_experts_first + self.held_experts}) are not a "
+                f"range of the router's {self.num_experts}"
+            )
+        if self.rope_layer_types is not None:
+            self.rope_layer_types = tuple(self.rope_layer_types)
+            if self.layer_types is None:
+                raise ValueError("rope_layer_types needs layer_types")
         if self.sliding_window > 0 and self.kv_lora_rank > 0:
             raise ValueError(
                 "sliding_window is not supported with MLA (no known MLA "
@@ -211,8 +242,19 @@ class ModelConfig:
         return tuple(self.window_for_layer(i) for i in range(self.num_layers))
 
     @property
+    def layer_rotates(self) -> tuple[bool, ...]:
+        """Whether layer ``i``'s attention applies RoPE."""
+        if self.rope_layer_types is None:
+            return (True,) * self.num_layers
+        return tuple(t in self.rope_layer_types for t in self.layer_types)
+
+    @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def holds_all_experts(self) -> bool:
+        return self.held_experts == self.num_experts
 
     @property
     def is_mla(self) -> bool:
@@ -468,6 +510,16 @@ class SwaRingSpec:
         the window span plus one page of offset straddle."""
         wmax = max(self.windows[i] for i in self.swa_layers)
         return -(-wmax // page_size) + 1
+
+
+def swa_section_count(cache: "CacheConfig", sched: "SchedulerConfig") -> int:
+    """How many retained sliding sections a ring engine provisions: every
+    sequence the scheduler may run leaves one for its own next turn, and
+    as many again may be shared prefixes captured on demand;
+    ``swa_section_cache`` is the floor (and 0 still turns retention off:
+    the caller's test). Eight sections under 32 sessions evicted each
+    turn's section before its next turn came."""
+    return max(cache.swa_section_cache, 2 * sched.max_num_seqs)
 
 
 # Per-seq prefill chunk cap that bounds the ring size independent of the

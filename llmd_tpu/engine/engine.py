@@ -9,6 +9,7 @@ exposes the queue/KV metrics the EPP scrapes
 
 from __future__ import annotations
 
+import dataclasses
 import itertools
 import logging
 import threading
@@ -20,7 +21,7 @@ import jax
 import numpy as np
 
 from llmd_tpu import faults
-from llmd_tpu.config import EngineConfig, swa_ring_spec
+from llmd_tpu.config import EngineConfig, swa_ring_spec, swa_section_count
 from llmd_tpu.engine.kv_cache import KVEventSink, PageAllocator
 from llmd_tpu.engine.request import (
     FinishReason,
@@ -44,6 +45,22 @@ from llmd_tpu.obs import profiling
 from llmd_tpu.parallel.mesh import MeshContext, build_mesh
 
 
+@dataclass
+class _Section:
+    """One retained sliding section: ring slots [s0, n_pre) copied into
+    ``pages``. ``shared``: captured at the end of a full-page run that the
+    main pool offered to ANOTHER request than the one that wrote it: a
+    prefix several sessions share. The others were captured at a prompt's
+    end and serve that session's next turn, once."""
+
+    s0: int
+    n_pre: int
+    # llmd: owns(pages)
+    pages: list[int]
+    shared: bool = False
+    hits: int = 0
+
+
 class SwaSectionCache:
     """Retained sliding-window sections for HYBRID prefix caching under
     the SWA ring (the reference's hybrid KV-cache manager role, pd gpu
@@ -60,34 +77,45 @@ class SwaSectionCache:
     pages and free them on eviction."""
 
     def __init__(
-        self, swa_allocator, runner, capacity: int, page_budget: int
+        self, swa_allocator, runner, capacity: int, page_budget: int,
+        is_live=None,
     ) -> None:
         import collections
 
         self._alloc = swa_allocator
         self._runner = runner
+        # ``is_live(key)``: whether the main pool still caches the full
+        # page the section is keyed by. A hit needs that page, so a
+        # section without it is dead and goes first.
+        self._is_live = is_live
         self.capacity = capacity
         # Retention pages are PROVISIONED on top of the ring pool
         # (engine sizing); this budget keeps retention from ever eating
         # ring capacity even transiently.
         self.page_budget = page_budget
         self.retained_pages = 0
-        # key -> (s0, n_pre, [section page ids])
         # llmd: owns(pages)
-        self._entries: "collections.OrderedDict[bytes, tuple]" = (
+        self._entries: "collections.OrderedDict[bytes, _Section]" = (
             collections.OrderedDict()
         )
         self.hits = 0
+        self.misses = 0
         self.captures = 0
 
-    def capture(self, key: bytes, ring_ids: list[int], s0: int, n_pre: int) -> None:
+    def capture(
+        self, key: bytes, ring_ids: list[int], s0: int, n_pre: int,
+        shared: bool = False,
+    ) -> None:
         """Copy ring slots [s0, n_pre) into retained pages (device op,
         no host bytes). No-op if the key is already retained or the SWA
         pool lacks headroom (a ring allocation must never fail because
         retention hoarded pages)."""
         from llmd_tpu.engine.kv_cache import NoFreePagesError
 
-        if self.capacity <= 0 or key in self._entries or n_pre <= s0:
+        if key in self._entries:
+            self._entries[key].shared |= shared
+            return
+        if self.capacity <= 0 or n_pre <= s0:
             return
         cnt = n_pre - s0
         R = len(ring_ids)
@@ -116,16 +144,33 @@ class SwaSectionCache:
             self.retained_pages -= cnt
             self._alloc.free(dst)
             raise
-        self._entries[key] = (s0, n_pre, dst)
+        self._entries[key] = _Section(s0, n_pre, pages=dst, shared=shared)
         self.captures += 1
 
     def evict_one(self) -> bool:
-        """Free the LRU retained section (ring-pressure relief: a live
-        sequence's ring allocation outranks idle retention). Returns
-        True if an entry was freed."""
+        """Free the retained section that is worth least (ring-pressure
+        relief: a live sequence's ring allocation outranks idle
+        retention). Returns True if an entry was freed. In this order,
+        the least recently used of each kind: an entry whose full page the
+        main pool no longer caches (it can serve no hit); a prompt's-end
+        section that has served its hit (the session has moved on to a
+        longer one); any prompt's-end section; a shared prefix's. Plain
+        LRU let 32 sessions' prompt ends, two rounds of turns, push the
+        eight shared prefixes out before the next new session came, and
+        each such miss is a 4,096-token prefill."""
         if not self._entries:
             return False
-        _, (_, _, ids) = self._entries.popitem(last=False)
+        ranked = (
+            lambda k, e: self._is_live is not None and not self._is_live(k),
+            lambda k, e: not e.shared and e.hits > 0,
+            lambda k, e: not e.shared,
+            lambda k, e: True,
+        )
+        victim = next(
+            k for worth_less in ranked
+            for k, e in self._entries.items() if worth_less(k, e)
+        )
+        ids = self._entries.pop(victim).pages
         self._alloc.free(ids)
         self.retained_pages -= len(ids)
         return True
@@ -140,7 +185,10 @@ class SwaSectionCache:
         continuation k*page, so an EXTENDED prompt sharing that prefix
         can still skip its first k pages (the multi-turn grow case)."""
         return sorted(
-            {e[1] for e in self._entries.values() if e[1] <= n_pre_max},
+            {
+                e.n_pre for e in self._entries.values()
+                if e.n_pre <= n_pre_max
+            },
             reverse=True,
         )
 
@@ -151,10 +199,11 @@ class SwaSectionCache:
         if entry is None:
             return None
         self._entries.move_to_end(key)
-        s0, n_pre, ids = entry
+        entry.hits += 1
+        s0, n_pre = entry.s0, entry.n_pre
         R = len(ring_ids)
         dst = [ring_ids[(s0 + i) % R] for i in range(n_pre - s0)]
-        self._runner.copy_pages_on_device(ids, dst, swa=True)
+        self._runner.copy_pages_on_device(entry.pages, dst, swa=True)
         self.hits += 1
         return s0, n_pre
 
@@ -162,6 +211,7 @@ class SwaSectionCache:
         return {
             "entries": len(self._entries),
             "hits": self.hits,
+            "misses": self.misses,
             "captures": self.captures,
         }
 
@@ -183,7 +233,12 @@ class EngineStats:
     swa_ring_pages: int = 0
     # Hybrid-APC section retention (SwaSectionCache)
     swa_sections: int = 0
-    swa_section_hits: int = 0
+    # Hybrid prefix hits taken (a fresh ring seeded from a retained
+    # section), and full-page runs the main pool offered at admission that
+    # were refused for want of a section (the request prefills the span and
+    # leaves the section behind for the next one).
+    swa_section_hits_total: int = 0
+    swa_section_misses_total: int = 0
     swa_section_captures: int = 0
     # counters
     prompt_tokens: int = 0
@@ -330,6 +385,20 @@ class EngineStats:
     # kernels.moe_gmm_roofline charges.
     moe_grouped_calls_total: int = 0
     moe_groups_with_rows_total: int = 0
+    # The router's picks over those calls (tokens x top-k, pad tokens
+    # included) and the picks whose expert this rank holds = the rows the
+    # grouped matmuls multiplied (ModelConfig.held_experts; equal where the
+    # model is served whole). Same count, same readback.
+    moe_picks_total: int = 0
+    moe_picks_held_total: int = 0
+    # KV bytes held by live references, summed over steps: the main pool's
+    # referenced pages x its layers' page bytes plus the ring pool's (rings
+    # and retained sections) x its layers'; and the tokens whose KV the
+    # scheduled sequences hold, summed over the same steps. bytes / tokens
+    # is what a cached token costs: every layer's page share without the
+    # ring, the full layers' plus the rings with it.
+    kv_bytes_in_use_total: int = 0
+    cached_tokens_total: int = 0
     # Learned sparse attention (models with an indexer; 0 elsewhere),
     # counted on the host from each flat step's positions: computed query
     # tokens that had more than indexer_topk cached tokens (the selection
@@ -519,19 +588,28 @@ class LLMEngine:
         # ring pool (the auto-sized pool is exactly max_num_seqs rings —
         # retention must never eat ring capacity).
         self._swa_retention_budget = 0
+        swa_sections_cap = swa_section_count(config.cache, config.scheduler)
         if (
             self._swa is not None
             and prefix_caching
             and config.cache.swa_section_cache > 0
         ):
             self._swa_retention_budget = (
-                config.cache.swa_section_cache
+                swa_sections_cap
                 * self._swa.max_section_pages(config.cache.page_size)
+            )
+        if self._swa_retention_budget:
+            # The ring pool ON THE DEVICE holds the retained sections too:
+            # the runner sizes it from this spec, the allocator hands out
+            # its page ids.
+            self._swa = dataclasses.replace(
+                self._swa,
+                num_swa_blocks=self._swa.num_swa_blocks
+                + self._swa_retention_budget,
             )
         self.swa_allocator = (
             PageAllocator(
-                num_pages=self._swa.num_swa_blocks
-                + self._swa_retention_budget,
+                num_pages=self._swa.num_swa_blocks,
                 page_size=config.cache.page_size,
                 enable_prefix_caching=False,
             )
@@ -556,11 +634,13 @@ class LLMEngine:
             and config.cache.swa_section_cache > 0
         ):
             self._swa_sections = SwaSectionCache(
-                self.swa_allocator, self.runner,
-                config.cache.swa_section_cache,
+                self.swa_allocator, self.runner, swa_sections_cap,
                 self._swa_retention_budget,
+                is_live=self.allocator.has_cached,
             )
             self.scheduler.prefill_complete_hook = self._capture_swa_section
+            self.scheduler.prefill_passed_hook = self._capture_passed_section
+            self.scheduler.hybrid_hit_hook = self._try_hybrid_ring_hit
             self.scheduler.ring_pressure_hook = self._swa_sections.evict_one
         self.stats = EngineStats(
             num_pages=config.cache.num_blocks, page_size=config.cache.page_size
@@ -787,6 +867,29 @@ class LLMEngine:
                 "swa section capture failed (serving unaffected)"
             )
 
+    def _capture_passed_section(self, req) -> None:
+        """Scheduler hook: ``req``'s prefill has just passed the full-page
+        run it was refused at admission (``Request.swa_capture``); the ring
+        holds the window before every page boundary of the chunk it has
+        just written, so the section at the run's end is captured now."""
+        try:
+            pages, key = req.swa_capture
+            # The one geometry (SwaRingSpec.section) of a prompt that
+            # continues right after the run.
+            n_pre, s0, _cnt = self._swa.section(
+                pages * self.config.cache.page_size + 1,
+                self.config.cache.page_size,
+            )
+            if req.swa_block_ids:
+                self._swa_sections.capture(
+                    key, req.swa_block_ids, s0, n_pre, shared=True
+                )
+        # llmd: allow(broad-except) -- best-effort section retention; a capture failure only costs a future cache hit
+        except Exception:
+            logging.getLogger(__name__).exception(
+                "swa section capture failed (serving unaffected)"
+            )
+
     # ------------------------------------------------------------------ #
 
     def add_request(
@@ -960,14 +1063,6 @@ class LLMEngine:
             req.swa_block_ids = list(preload["swa_block_ids"])
             req.num_computed_tokens = preload["tokens"]
             req.num_cached_tokens = preload["tokens"]
-        elif (
-            self._swa_sections is not None
-            and not park_adapter
-            and kv_stream is None
-        ):
-            # (Parked requests skip the hybrid probe: their cache salt
-            # needs the slot id the cold load has not assigned yet.)
-            self._try_hybrid_ring_hit(req)
         if kv_stream is not None:
             # Waiting on the group stream: schedulable the moment the
             # import resolves (apply on success, recompute on failure).
@@ -984,7 +1079,11 @@ class LLMEngine:
         return rid
 
     def _try_hybrid_ring_hit(self, req) -> None:
-        """Hybrid prefix hit under the ring: usable only when BOTH a
+        """Scheduler hook, at a request's admission (not at its arrival:
+        what the requests queued in front of it have cached and captured
+        meanwhile counts; of four sessions that open on one document
+        together, two prefill it and two take the hit). Hybrid prefix hit
+        under the ring: usable only when BOTH a
         full-pool prefix run AND a retained sliding section exist for
         the SAME span — then a fresh ring is seeded from the section
         (device copy) and the request starts past that span, like a
@@ -1001,14 +1100,10 @@ class LLMEngine:
         n_pre, _s0, _cnt = self._swa.section(len(req.prompt_token_ids), page)
         if n_pre <= 0:
             return
-        # Candidate lengths need only n_pre — unique-prompt traffic (no
-        # usable retained span) exits before paying the hash walk.
         lengths = self._swa_sections.candidate_lengths(n_pre)
-        if not lengths:
-            return
         extra = self.scheduler.hash_extra(req)
-        # ONE hash walk serves both the section probes and the full-pool
-        # lookup (the prompt is hashed nowhere else on this path).
+        # ONE hash walk serves the section probes, the full-pool lookup and
+        # the miss's note (the prompt is hashed nowhere else on this path).
         hashes = page_hashes_for_tokens(
             list(req.prompt_token_ids[: n_pre * page]), page, extra=extra
         )
@@ -1053,6 +1148,13 @@ class LLMEngine:
             # indexer.
             self.scheduler.seed_commit_chain(req, key, k)
             return
+        # No section served. Where the main pool offers a run of full pages
+        # all the same, that is a miss: the request prefills the span, and
+        # leaves the section at the run's end behind as it passes it.
+        run = self.allocator.peek_hash_run(hashes)
+        if run > 0:
+            self._swa_sections.misses += 1
+            req.swa_capture = (run, hashes[run - 1])
 
     def abort_request(self, request_id: str) -> bool:
         for i, r in enumerate(self._lora_parked):
@@ -1963,8 +2065,22 @@ class LLMEngine:
         self._step_carried = self._carried(batch)
         by_kind = f"steps_{self._step_carried[0]}_total"
         setattr(st, by_kind, getattr(st, by_kind) + 1)
+        st.kv_bytes_in_use_total += self._kv_bytes_in_use()
+        st.cached_tokens_total += sum(
+            s.request.num_computed_tokens for s in batch.seqs
+        )
         self._moe_tick()
         self._refresh_gauges()
+
+    def _kv_bytes_in_use(self) -> int:
+        """Bytes of KV pages that live references hold, over both pools."""
+        r = self.runner
+        a = self.allocator
+        n = (a.num_pages - a.num_free_pages) * r.kv_page_bytes
+        if self.swa_allocator is not None:
+            w = self.swa_allocator
+            n += (w.num_pages - w.num_free_pages) * r.kv_swa_page_bytes
+        return n
 
     def _moe_tick(self) -> None:
         """Drain the wide-EP census and run the slow control loops.
@@ -2022,7 +2138,8 @@ class LLMEngine:
             if self._swa_sections is not None:
                 s = self._swa_sections.stats()
                 self.stats.swa_sections = s["entries"]
-                self.stats.swa_section_hits = s["hits"]
+                self.stats.swa_section_hits_total = s["hits"]
+                self.stats.swa_section_misses_total = s["misses"]
                 self.stats.swa_section_captures = s["captures"]
         self.stats.prefix_hit_ratio = self.allocator.hit_ratio()
         self.stats.preemptions = self.scheduler.num_preemptions
@@ -2053,6 +2170,8 @@ class LLMEngine:
         r = self.runner
         self.stats.moe_grouped_calls_total = r.moe_grouped_calls_total
         self.stats.moe_groups_with_rows_total = r.moe_groups_with_rows_total
+        self.stats.moe_picks_total = r.moe_picks_total
+        self.stats.moe_picks_held_total = r.moe_picks_held_total
         self.stats.sparse_bound_tokens_total = r.sparse_bound_tokens_total
         self.stats.sparse_unbound_tokens_total = r.sparse_unbound_tokens_total
         self.stats.indexer_keys_scored_total = r.indexer_keys_scored_total

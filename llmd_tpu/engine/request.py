@@ -102,6 +102,12 @@ class Request:
     # Memoized [max_pages] ring-view table row (immutable once the ring is
     # allocated; invalidated whenever swa_block_ids is freed).
     swa_table_row: Any = None
+    # (pages, chain hash) of a full-page prefix run the main pool offered
+    # at admission and the hybrid cache had to refuse for want of a
+    # sliding section: this request prefills that span anyway, so the
+    # section is captured as its prefill passes the run's end (scheduler
+    # ``prefill_passed_hook``) and the next request takes the hit.
+    swa_capture: tuple | None = None
     # Tokens dispatched to the device but not yet committed by a step
     # readback (async stepping, SchedulerConfig.async_scheduling): the
     # scheduler speculates the next batch against dispatched positions

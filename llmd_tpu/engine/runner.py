@@ -363,8 +363,9 @@ class ModelRunner:
         ):
             # The one-device grouped backend threads the grouped expert
             # matmul's count through the same argument (llama.
-            # forward_hidden): [2] i32, grouped MoE layer calls and the
-            # groups with rows as the kernel sees them. Every step
+            # forward_hidden): [4] i32, grouped MoE layer calls, the groups
+            # with rows, the router's picks and the picks whose expert is
+            # held here (ops.grouped_gemm.grouped_census). Every step
             # program adds its own, hands the sum back in its packed
             # output and this accumulator back at zero (_count_row), so
             # the count comes home with the step's one readback
@@ -372,10 +373,12 @@ class ModelRunner:
             # its census above and is not counted here; nor is the embed
             # program, which is no step.
             self._moe_census = jax.device_put(
-                np.zeros(2, np.int32), mesh_ctx.replicated
+                np.zeros(4, np.int32), mesh_ctx.replicated
             )
         self.moe_grouped_calls_total = 0
         self.moe_groups_with_rows_total = 0
+        self.moe_picks_total = 0
+        self.moe_picks_held_total = 0
         # Pristine logical [L, E, ...] expert leaves, stashed on first
         # EPLB remap so later placements regather from the un-replicated
         # originals; the host-side Placement mirrors params["moe_placement"].
@@ -388,6 +391,14 @@ class ModelRunner:
         )
         self.kv_cache = self._alloc_kv()
         self.kv_swa = self._alloc_swa()
+        # Bytes of one page id over all of a pool's layers (every plane an
+        # IndexedPool or an int8 pool carries under that id).
+        self.kv_page_bytes, self.kv_swa_page_bytes = (
+            0 if pool is None else sum(
+                a.nbytes // a.shape[1] for a in jax.tree.leaves(pool)
+            )
+            for pool in (self.kv_cache, self.kv_swa)
+        )
         self._multihost = dist.is_multihost()
         # Serializes lockstep broadcast+dispatch pairs so NON-engine
         # threads (P/D fetch staging, embeds, adapter installs) can
@@ -938,22 +949,23 @@ class ModelRunner:
 
     @property
     def _counts_grouped(self) -> bool:
-        """The census argument is the grouped expert matmul's [2] count
+        """The census argument is the grouped expert matmul's [4] count
         (armed in __init__), not the wide-EP census."""
         return self._moe_census is not None and not self._ep_active
 
     def _count_row(self, packed: jax.Array, census):
         """Last lines of every step program's body. The grouped count
-        leaves the device as one more row of the packed output, [calls,
-        groups with rows, 0, ...] in f32 (exact: a program counts at most
-        iterations x layers x experts, far under 2**24), and the
+        leaves the device as two more rows of the packed output (which may
+        be two columns wide), [calls, groups with rows, 0, ...] and [picks,
+        picks held, 0, ...] in f32 (exact: a program counts at most
+        iterations x layers x tokens x top-k, far under 2**24), and the
         accumulator goes back zeroed for the next step. Any other census
         (wide-EP, none) passes through."""
         if not self._counts_grouped:
             return packed, census
-        row = jnp.zeros((1, packed.shape[1]), packed.dtype)
-        row = row.at[0, :2].set(census.astype(packed.dtype))
-        return jnp.concatenate([packed, row]), jnp.zeros_like(census)
+        rows = jnp.zeros((2, packed.shape[1]), packed.dtype)
+        rows = rows.at[:, :2].set(census.reshape(2, 2).astype(packed.dtype))
+        return jnp.concatenate([packed, rows]), jnp.zeros_like(census)
 
     def _note_traced(self, family: str, shape) -> None:
         """First line of every jitted step program's body, so it runs
@@ -3370,9 +3382,11 @@ class ModelRunner:
         else:
             hosts = [np.asarray(a) for a in jax.device_get(packs)]
         if self._counts_grouped:
-            for arr in hosts:  # _count_row's line, below every result row
-                self.moe_grouped_calls_total += int(arr[-1, 0])
-                self.moe_groups_with_rows_total += int(arr[-1, 1])
+            for arr in hosts:  # _count_row's lines, below every result row
+                self.moe_grouped_calls_total += int(arr[-2, 0])
+                self.moe_groups_with_rows_total += int(arr[-2, 1])
+                self.moe_picks_total += int(arr[-1, 0])
+                self.moe_picks_held_total += int(arr[-1, 1])
         pres = dres = None
         base = 0
         if prefill is not None:

@@ -110,6 +110,14 @@ class EngineScheduler:
         # (the ring still holds the prompt's trailing window) — the
         # hybrid-APC section capture point.
         self.prefill_complete_hook = None
+        # Called when a prefill chunk carried a request past the span it
+        # noted in ``Request.swa_capture`` (the ring holds the window
+        # before any page boundary of the chunk it has just written).
+        self.prefill_passed_hook = None
+        # Ring engines: the hybrid prefix hit, taken at admission
+        # (_apply_prefix_cache); fills the request's pages, ring and
+        # computed count like a locally-sourced preload, or notes the miss.
+        self.hybrid_hit_hook = None
         # Called when a ring allocation fails: frees idle retained
         # sections (hybrid APC) so live sequences outrank retention.
         # Returns True if anything was freed (retry the allocation).
@@ -546,11 +554,13 @@ class EngineScheduler:
         if req.block_ids:
             return
         if self.swa_ring_pages:
-            # Ring engines do HYBRID hits at engine admission only: a
-            # full-pool hit is usable solely when a retained sliding
-            # section seeds the fresh ring (engine SwaSectionCache) —
-            # a bare full-pool shortcut here would skip sliding-layer
-            # KV the ring never got and silently decode garbage.
+            # Ring engines do HYBRID hits only: a full-pool hit is usable
+            # solely when a retained sliding section seeds the fresh ring
+            # (engine SwaSectionCache) — a bare full-pool shortcut here
+            # would skip sliding-layer KV the ring never got and silently
+            # decode garbage. A noted miss is not probed again.
+            if self.hybrid_hit_hook is not None and req.swa_capture is None:
+                self.hybrid_hit_hook(req)
             return
         # Never satisfy the *entire* prompt from cache: the last token must be
         # computed so the step emits logits for sampling. Lookup + touch
@@ -752,6 +762,14 @@ class EngineScheduler:
             req.num_computed_tokens += seq.num_tokens
             if req.is_batch:
                 self.batch_tokens += seq.num_tokens
+            if (
+                req.swa_capture is not None
+                and self.prefill_passed_hook is not None
+                and req.num_computed_tokens
+                >= req.swa_capture[0] * self.cache_config.page_size
+            ):
+                self.prefill_passed_hook(req)
+                req.swa_capture = None
             if req.in_decode:  # this chunk completed the prompt -> 1st token
                 if self.prefill_complete_hook is not None:
                     # Hybrid-APC capture: the ring still holds the

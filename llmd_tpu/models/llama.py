@@ -12,6 +12,7 @@ wide-EP target (docs/architecture/foundations/wide-expert-parallelism.md).
 
 from __future__ import annotations
 
+import contextlib
 import zlib
 
 import jax
@@ -119,13 +120,20 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
             layers["lb_q"] = jnp.zeros((n, A1, r, Nq * D), dt)
             layers["lb_v"] = jnp.zeros((n, A1, r, K * D), dt)
         if moe:
-            E, Fm = cfg.num_experts, cfg.moe_intermediate_size
-            layers["router"] = mkp("router", (n, H, E), scale=H**-0.5)
-            if cfg.router_scoring == "sigmoid" or cfg.router_logit_bias:
-                # V3-style selection-only correction bias (noaux_tc), or
-                # gpt-oss's real logit bias — either way the leaf must
-                # exist in the init tree (load_params' shape contract).
-                layers["router_bias"] = jnp.zeros((n, E), jnp.float32)
+            # The router scores every expert; the leaves hold the experts
+            # this rank holds (all of them unless cfg says otherwise).
+            Er, E, Fm = cfg.num_experts, cfg.held_experts, cfg.moe_intermediate_size
+            layers["router"] = mkp("router", (n, H, Er), scale=H**-0.5)
+            if cfg.router_scoring == "sigmoid":
+                # V3-style selection-only correction bias (noaux_tc),
+                # seeded: a zero bias would leave the selection untested.
+                layers["router_bias"] = mkp(
+                    "router_bias", (n, Er), scale=0.1
+                ).astype(jnp.float32)
+            elif cfg.router_logit_bias:
+                # gpt-oss's real logit bias: the leaf must exist in the
+                # init tree (load_params' shape contract).
+                layers["router_bias"] = jnp.zeros((n, Er), jnp.float32)
             layers["we_gate"] = mkp("we_gate", (n, E, H, Fm))
             layers["we_up"] = mkp("we_up", (n, E, H, Fm))
             layers["we_down"] = mkp("we_down", (n, E, Fm, H))
@@ -359,11 +367,15 @@ def forward_hidden(
         return jnp.concatenate(xs, axis=0), cd
 
     def layer_body(x, cache, lp, layer_idx, use_moe: bool, window=None,
-                   table=None, run_phys=None, moe_layer=None):
+                   table=None, run_phys=None, moe_layer=None, rotate=None,
+                   attn_kind=None):
         """One decoder layer; returns (x, cache, census_delta | None).
         ``layer_idx`` is the layer's plane of ``cache``; ``moe_layer`` its
         index into ``params["layers"]``, whose expert leaves ``lp`` holds
-        whole (the dense prefix shifts one against the other)."""
+        whole (the dense prefix shifts one against the other). ``rotate``
+        (a bool, traced or not; None = yes) says whether this layer applies
+        RoPE; ``attn_kind`` ("window" | "full", where the caller knows it at
+        trace time) names the flat attention call ``llmd.attn.<kind>``."""
         if table is None:
             table = inp.page_table
         h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
@@ -425,8 +437,13 @@ def forward_hidden(
             if cfg.qk_norm:  # Qwen3: per-head RMS norm before RoPE
                 q = rms_norm(q, lp["attn_q_norm"], cfg.rms_norm_eps)
                 k = rms_norm(k, lp["attn_k_norm"], cfg.rms_norm_eps)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+            if rotate is None:
+                cos_l, sin_l = cos, sin
+            else:  # the identity rotation where the layer has none
+                cos_l = jnp.where(rotate, cos, 1.0)
+                sin_l = jnp.where(rotate, sin, 0.0)
+            q = apply_rope(q, cos_l, sin_l)
+            k = apply_rope(k, cos_l, sin_l)
             v = v.reshape(B, Q, K, D)
             if kv_rep > 1:
                 k = jnp.repeat(k, kv_rep, axis=2)
@@ -495,12 +512,19 @@ def forward_hidden(
                     world_size=world_size, mesh=mesh,
                 )
             elif flat:
-                attn = paged_attention_full_flat(
-                    q, cache, layer_idx, inp.token_rows, table,
-                    inp.kv_lens, inp.positions, sm_scale,
-                    world_size=world_size, mesh=mesh, window=window,
-                    sinks=sinks,
+                # A Pallas call takes its scope's name in the device trace:
+                # a model that mixes the two kinds reads them apart.
+                scope = (
+                    jax.named_scope(f"llmd.attn.{attn_kind}") if attn_kind
+                    else contextlib.nullcontext()
                 )
+                with scope:
+                    attn = paged_attention_full_flat(
+                        q, cache, layer_idx, inp.token_rows, table,
+                        inp.kv_lens, inp.positions, sm_scale,
+                        world_size=world_size, mesh=mesh, window=window,
+                        sinks=sinks,
+                    )
             elif cp_ring:
                 attn = ring_prefill_attention_full(
                     q, cache, layer_idx, k, v, table, inp.kv_lens,
@@ -549,6 +573,20 @@ def forward_hidden(
 
     census = moe_census if use_census else None
 
+    # Layers that do not rotate (cfg.rope_layer_types): a per-layer switch
+    # beside the window. None where every layer rotates keeps the scan
+    # signature (and compile cache) unchanged.
+    rot_static = cfg.layer_rotates
+    rotates = None if all(rot_static) else jnp.asarray(rot_static, bool)
+
+    mixes_kinds = sliding and len({w > 0 for w in win_static}) == 2
+
+    def kind_name(i: int):
+        """"window" | "full" for layer ``i`` where the model mixes the two."""
+        if not mixes_kinds:
+            return None
+        return "window" if win_static[i] > 0 else "full"
+
     for i in range(n_dense):
         lp_i = jax.tree.map(lambda a: a[i], params["dense_layers"])
         g = kinds[i]
@@ -556,12 +594,15 @@ def forward_hidden(
             x, caches[g], lp_i, jnp.int32(plane[i]), use_moe=False,
             window=None if windows is None else windows[i],
             table=tables[g], run_phys=run_physes[g],
+            rotate=None if rotates is None else rot_static[i],
+            attn_kind=kind_name(i),
         )
 
     n_scan = cfg.num_layers - n_dense
     scan_kinds = kinds[n_dense:]
     plane_arr = jnp.asarray(plane[n_dense:], jnp.int32)
     win_arr = windows[n_dense:] if windows is not None else None
+    rot_arr = rotates[n_dense:] if rotates is not None else None
     # The stacked expert leaves [L, E, ..] do not ride the scans as ``xs``:
     # XLA fuses a scanned slice into an XLA consumer and MATERIALISES it for
     # a Pallas one, all E x 3 x K x N of a layer read and written in every
@@ -586,23 +627,37 @@ def forward_hidden(
         ])
 
     def scan_group(x, cache, census, table, lp, plane_ids, layer_ids, wins,
-                   run_phys=None):
+                   run_phys=None, rots=None, attn_kind=None):
         """One homogeneous run of layers sharing a pool/table. The census
         delta rides the scan as a per-layer OUTPUT (stacked then reduced)
         so the carry signature — and the compile cache — only changes
-        when the census is actually armed."""
+        when the census is actually armed. ``lp`` None: the run is a PART
+        of the stack, and its body indexes the whole leaves by the layer
+        id (what a scan does with its ``xs``); a static slice of the
+        leaves in front of the scan would be a copy of the run's weights
+        in every step (5 ms of a 28 ms decode step at 6,144 wide, PERF.md
+        section 6, PR 33)."""
 
         def fn(carry, scanned):
             x, cache = carry
-            lp_s, pid, lid, *w = scanned
+            lp_s, pid, lid, per = scanned
+            if lp_s is None:
+                lp_s = {
+                    k: jax.lax.dynamic_index_in_dim(a, lid, 0, keepdims=False)
+                    for k, a in lp_all.items()
+                }
             x, cache, cd = layer_body(
                 x, cache, {**lp_s, **experts}, pid, use_moe=cfg.is_moe,
-                window=w[0] if w else None, table=table, run_phys=run_phys,
-                moe_layer=lid,
+                window=per.get("window"), table=table, run_phys=run_phys,
+                moe_layer=lid, rotate=per.get("rotate"), attn_kind=attn_kind,
             )
             return (x, cache), cd
 
-        scanned = (lp, plane_ids, layer_ids, *([] if wins is None else [wins]))
+        per = {
+            k: a for k, a in (("window", wins), ("rotate", rots))
+            if a is not None
+        }
+        scanned = (lp, plane_ids, layer_ids, per)
         (x, cache), cds = jax.lax.scan(fn, (x, cache), scanned)
         if census is not None and cds is not None:
             census = _census_merge(census, _reduce_census(cds))
@@ -612,7 +667,7 @@ def forward_hidden(
         g = scan_kinds[0] if scan_kinds else 0
         x, caches[g], census = scan_group(
             x, caches[g], census, tables[g], lp_all, plane_arr, layer_arr,
-            win_arr, run_physes[g],
+            win_arr, run_physes[g], rot_arr,
         )
     elif (c := _scan_period(scan_kinds)) is not None:
         # Hybrid periodic pattern (gpt-oss alternating): scan over CYCLES
@@ -625,13 +680,13 @@ def forward_hidden(
 
         cyc_scanned = (
             jax.tree.map(resh, lp_all), resh(plane_arr), resh(layer_arr),
-            resh(win_arr),
+            resh(win_arr), None if rot_arr is None else resh(rot_arr),
         )
 
         def cyc(carry, scanned):
             x, cf, cs = carry
             cc = [cf, cs]
-            lp_c, plane_c, layer_c, win_c = scanned
+            lp_c, plane_c, layer_c, win_c, rot_c = scanned
             cd_cyc = None
             for j in range(c):
                 lp_s = {**jax.tree.map(lambda a: a[j], lp_c), **experts}
@@ -640,6 +695,8 @@ def forward_hidden(
                     x, cc[g], lp_s, plane_c[j], use_moe=cfg.is_moe,
                     window=win_c[j] if g else None, table=tables[g],
                     run_phys=run_physes[g], moe_layer=layer_c[j],
+                    rotate=None if rot_c is None else rot_c[j],
+                    attn_kind=kind_name(n_dense + j),
                 )
                 if cd is not None:
                     cd_cyc = cd if cd_cyc is None else _census_merge(cd_cyc, cd)
@@ -661,10 +718,10 @@ def forward_hidden(
                 ln += 1
             sl = slice(off, off + ln)
             x, caches[g], census = scan_group(
-                x, caches[g], census, tables[g],
-                jax.tree.map(lambda a: a[sl], lp_all),
+                x, caches[g], census, tables[g], None,
                 plane_arr[sl], layer_arr[sl], win_arr[sl] if g else None,
-                run_physes[g],
+                run_physes[g], None if rot_arr is None else rot_arr[sl],
+                kind_name(n_dense + off),
             )
             off += ln
 

@@ -17,7 +17,9 @@ layouts onto this framework's stacked-layer param tree:
 
 Supported architectures: LlamaForCausalLM, Qwen2ForCausalLM,
 Qwen3ForCausalLM, MixtralForCausalLM, Qwen3MoeForCausalLM,
-DeepseekV2ForCausalLM, DeepseekV3ForCausalLM.
+DeepseekV2ForCausalLM, DeepseekV3ForCausalLM, ExaoneMoEForCausalLM
+(``model_type: exaone_moe``: the config's keys mapped; its tensor names are
+taken to follow DeepSeek-V3's, which the key names follow).
 """
 
 from __future__ import annotations
@@ -41,7 +43,10 @@ _DENSE_ARCHS = {
     "Qwen2ForCausalLM",
     "Qwen3ForCausalLM",
 }
-_MOE_ARCHS = {"MixtralForCausalLM", "Qwen3MoeForCausalLM", "GptOssForCausalLM"}
+_MOE_ARCHS = {
+    "MixtralForCausalLM", "Qwen3MoeForCausalLM", "GptOssForCausalLM",
+    "ExaoneMoEForCausalLM",
+}
 _MLA_ARCHS = {"DeepseekV2ForCausalLM", "DeepseekV3ForCausalLM"}
 SUPPORTED_ARCHS = _DENSE_ARCHS | _MOE_ARCHS | _MLA_ARCHS
 
@@ -88,7 +93,10 @@ def config_from_hf(model_dir: str, **overrides) -> ModelConfig:
         num_heads=hf["num_attention_heads"],
         num_kv_heads=hf.get("num_key_value_heads", hf["num_attention_heads"]),
         head_dim=hf.get("head_dim"),
-        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rope_theta=float(
+            hf.get("rope_theta")
+            or (hf.get("rope_parameters") or {}).get("rope_theta", 10000.0)
+        ),
         rope_scaling=rope_scaling,
         rms_norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
         max_model_len=int(hf.get("max_position_embeddings", 8192)),
@@ -151,6 +159,8 @@ def config_from_hf(model_dir: str, **overrides) -> ModelConfig:
             attention_out_bias=bool(hf.get("attention_bias", True)),
             attention_sinks=True,
         )
+    elif arch == "ExaoneMoEForCausalLM":
+        kw.update(exaone_moe_fields(hf))
     elif arch in _MLA_ARCHS:
         if arch == "DeepseekV3ForCausalLM":
             router_scoring, topk_method = "sigmoid", "group_top2"
@@ -183,6 +193,32 @@ def config_from_hf(model_dir: str, **overrides) -> ModelConfig:
         )
     kw.update(overrides)
     return ModelConfig(**kw)
+
+
+def exaone_moe_fields(hf: dict) -> dict:
+    """ModelConfig fields from the keys of an ``exaone_moe`` config.json
+    (K-EXAONE): sigmoid scores with a selection-only bias, top-k over the
+    whole router (``n_group`` 1), normalised and scaled; one dense layer
+    first; ``num_shared_experts`` shared experts of the routed width as one
+    SiLU GLU; QK-norm, and RoPE on the sliding layers only (the EXAONE-4.0
+    hybrid-attention convention; config.json has no key for either)."""
+    return dict(
+        qk_norm=True,
+        rope_layer_types=("sliding_attention",),
+        num_experts=hf["num_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        first_dense_layers=hf.get("first_k_dense_replace", 0),
+        shared_expert_intermediate_size=(
+            (hf.get("num_shared_experts") or 0) * hf["moe_intermediate_size"]
+        ),
+        router_scoring=hf.get("scoring_func", "sigmoid"),
+        topk_method="group_top2",  # noaux_tc; no group limit at n_group 1
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        n_group=hf.get("n_group") or 1,
+        topk_group=hf.get("topk_group") or 1,
+    )
 
 
 class _Checkpoint:
@@ -392,7 +428,10 @@ def load_params(
                  for i in layer_ids]
             ).astype(dt)
         elif moe:
-            E = cfg.num_experts
+            held = range(
+                cfg.held_experts_first,
+                cfg.held_experts_first + cfg.held_experts,
+            )
             if ckpt.has(proj(layer_ids[0], "block_sparse_moe.gate.weight")):
                 # Mixtral naming: w1=gate, w3=up, w2=down
                 gate_name = "block_sparse_moe.gate.weight"
@@ -422,7 +461,7 @@ def load_params(
             for which, key in (("gate", "we_gate"), ("up", "we_up"), ("down", "we_down")):
                 layers[key] = np.stack(
                     [
-                        np.stack([get(ename(i, e, which), True) for e in range(E)])
+                        np.stack([get(ename(i, e, which), True) for e in held])
                         for i in layer_ids
                     ]
                 )

@@ -150,8 +150,11 @@ def moe_block_grouped(
     each expert multiplies only its routed rows. Numerically equivalent to
     the dense combine (same f32 weighted sum) at top_k/E of the FLOPs.
     ``lp``'s expert leaves are one layer's ``[E, ..]``, or all layers'
-    ``[L, E, ..]`` with ``layer``, the index into them.
-    With ``emit_census`` the return is ``(y, census)``: this call's [2] i32
+    ``[L, E, ..]`` with ``layer``, the index into them; E is the experts
+    HELD here (``cfg.held_experts``), the router scores all
+    ``cfg.num_experts``, and the result is the held experts' part of the
+    sum plus the shared expert (docs/architecture/wide-ep.md).
+    With ``emit_census`` the return is ``(y, census)``: this call's [4] i32
     line of the step's count (``ops.grouped_gemm.grouped_census``)."""
     from llmd_tpu.ops.grouped_gemm import moe_apply_grouped
 
@@ -177,15 +180,20 @@ def moe_block_grouped(
 
 
 def moe_block(h: jax.Array, lp: dict, cfg: ModelConfig) -> jax.Array:
-    """MoE FFN on [B, Q, H] -> [B, Q, H] (dense-combine path)."""
+    """MoE FFN on [B, Q, H] -> [B, Q, H] (dense-combine path), over the
+    experts held here as ``moe_block_grouped``."""
+    from llmd_tpu.ops.grouped_gemm import held_slots
+
     B, Q, H = h.shape
     T = B * Q
-    E, k = cfg.num_experts, cfg.num_experts_per_tok
+    E, k = cfg.held_experts, cfg.num_experts_per_tok
     ht = h.reshape(T, H)
     weights, ids = router_topk(ht, lp["router"], k, cfg, lp.get("router_bias"))
-    # combine[t, e] = sum_j weights[t, j] * (ids[t, j] == e)
-    combine = jnp.zeros((T, E), jnp.float32)
-    combine = combine.at[jnp.arange(T)[:, None], ids].add(weights)
+    # combine[t, e] = sum_j weights[t, j] * (ids[t, j] == held expert e);
+    # a pick of an expert held elsewhere lands in column E, dropped.
+    slots = held_slots(ids, cfg, E)
+    combine = jnp.zeros((T, E + 1), jnp.float32)
+    combine = combine.at[jnp.arange(T)[:, None], slots].add(weights)[:, :E]
 
     # All experts on all tokens, the combine folded into the down
     # projection: weighting gate*up by combine[t, e] BEFORE contracting is

@@ -39,6 +39,8 @@ def get_model_config(name: str, **overrides) -> ModelConfig:
         and "moe_intermediate_size" not in overrides
     ):
         kw["moe_intermediate_size"] = None
+    if cfg.holds_all_experts and "held_experts" not in overrides:
+        kw["held_experts"] = None
     kw.update(overrides)
     return ModelConfig(**kw)
 
@@ -173,6 +175,51 @@ def _tiny_dsa() -> ModelConfig:
         name="tiny-dsa", qk_norm=True, num_experts=8, num_experts_per_tok=2,
         moe_intermediate_size=64, max_model_len=512,
         indexer_topk=32, indexer_num_heads=2, indexer_head_dim=8,
+    )
+
+
+_LLLG = ("sliding_attention",) * 3 + ("full_attention",)
+
+
+@register_model("k-exaone-236b-a23b")
+def _k_exaone_236b_a23b() -> ModelConfig:
+    """K-EXAONE-236B-A23B (HF LGAI-EXAONE/K-EXAONE-236B-A23B,
+    ``model_type: exaone_moe``) as published: 64 q / 8 kv heads of 128 over
+    hidden 6,144; three sliding layers (window 128, RoPE) to one full layer
+    (no RoPE); first layer dense, then 128 experts top-8 + 1 shared, sigmoid
+    scores with a selection-only bias, normalised, x 2.5. The one
+    multi-token-prediction module is not served (ROADMAP M6). A rank of an
+    expert-parallel deployment overrides ``held_experts`` /
+    ``held_experts_first`` (docs/architecture/wide-ep.md)."""
+    return ModelConfig(
+        name="k-exaone-236b-a23b", vocab_size=153600, hidden_size=6144,
+        intermediate_size=18432, num_layers=48, num_heads=64, num_kv_heads=8,
+        head_dim=128, rope_theta=1000000.0, max_model_len=262144,
+        rms_norm_eps=1e-5, qk_norm=True,
+        sliding_window=128, layer_types=_LLLG * 12,
+        rope_layer_types=("sliding_attention",),
+        num_experts=128, num_experts_per_tok=8, moe_intermediate_size=2048,
+        shared_expert_intermediate_size=2048, first_dense_layers=1,
+        router_scoring="sigmoid", topk_method="group_top2",
+        norm_topk_prob=True, routed_scaling_factor=2.5,
+    )
+
+
+@register_model("tiny-exaone")
+def _tiny_exaone() -> ModelConfig:
+    """K-EXAONE's architecture in miniature (CPU tests and the benchmark's
+    rehearsal): dense prefix 1, ``LLLG`` with window 16, sigmoid top-2 of 16
+    with bias and scaling, one shared expert, and a held share: this rank
+    holds experts 4-7 of the 16 the router scores."""
+    return tiny_model_config(
+        name="tiny-exaone", num_layers=8, qk_norm=True, max_model_len=512,
+        sliding_window=16, layer_types=_LLLG * 2,
+        rope_layer_types=("sliding_attention",),
+        num_experts=16, num_experts_per_tok=2, moe_intermediate_size=64,
+        shared_expert_intermediate_size=64, first_dense_layers=1,
+        router_scoring="sigmoid", topk_method="group_top2",
+        norm_topk_prob=True, routed_scaling_factor=2.5,
+        held_experts=4, held_experts_first=4,
     )
 
 

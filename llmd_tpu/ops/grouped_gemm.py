@@ -250,13 +250,14 @@ def grouped_matmul(
     if w.ndim == 3:
         w, layer = w[None], 0
     # The kernel requires m % tile_m == 0 and a sublane-aligned tile: pad
-    # rows up to the (8-aligned) tile. Pad rows are zero and land in the
-    # LAST group (group_sizes must sum to m); their zero outputs are
-    # sliced off below.
+    # rows up to the (8-aligned) tile. Rows past the groups' sum — these zero
+    # rows, and the rows of picks whose expert is held on another rank
+    # (moe_apply_grouped) — belong to no group: the kernel visits no tile for
+    # them, and whatever their output rows hold is sliced off or masked by the
+    # caller.
     tm, pad = _row_tile(T)
     if pad:
         x = jnp.concatenate([x, jnp.zeros((pad, K_dim), x.dtype)], axis=0)
-        group_sizes = group_sizes.at[-1].add(pad)
     out = gmm(
         x, w, group_sizes, jnp.asarray(layer, jnp.int32).reshape(1),
         tiling=(tm, *gmm_tiles(
@@ -268,22 +269,19 @@ def grouped_matmul(
 
 
 def grouped_census(
-    group_sizes: jax.Array,  # [G] rows per group, before any padding
-    T: int,                  # their sum, a trace-time number
-    padded: bool,
-) -> jax.Array:              # [2] i32
+    group_sizes: jax.Array,  # [G] rows per held group
+    picks: int,              # the router's picks, T x k: a trace-time number
+) -> jax.Array:              # [4] i32
     """One grouped expert layer's line of the step's count: (1 call, the
-    groups with at least one row AS THE KERNEL SEES THEM). The zero rows
-    ``grouped_matmul`` pads into the last group make that group non-empty:
-    the kernel reads its weights, so it counts (``padded``: the call takes
-    the kernel's path, the only one that pads). Gate, up and down of a
-    layer see the same group sizes and share this one line."""
-    has_rows = group_sizes > 0
-    if padded and _row_tile(T)[1]:
-        has_rows = has_rows.at[-1].set(True)
-    return jnp.stack(
-        [jnp.int32(1), jnp.sum(has_rows, dtype=jnp.int32)]
-    )
+    groups with at least one row, the router's picks, the picks whose expert
+    is held here = the rows the layer multiplies). A group without rows costs
+    the kernel no tile and no weight byte; gate, up and down of a layer see
+    the same group sizes and share this one line. Where every expert is held
+    the last two are equal."""
+    return jnp.stack([
+        jnp.int32(1), jnp.sum(group_sizes > 0, dtype=jnp.int32),
+        jnp.int32(picks), jnp.sum(group_sizes, dtype=jnp.int32),
+    ])
 
 
 def expert_mlp_grouped(
@@ -330,11 +328,20 @@ def expert_mlp_grouped(
     return out
 
 
+def held_slots(ids: jax.Array, cfg, E: int) -> jax.Array:
+    """The router's picks ``ids`` (any shape, ids over the router's whole
+    width) as slots of the ``E`` experts held here, ``cfg.
+    held_experts_first`` onward. A pick whose expert lives on another rank
+    gets slot ``E``, one past the held groups."""
+    local = ids - cfg.held_experts_first
+    return jnp.where((local >= 0) & (local < E), local, E)
+
+
 def moe_apply_grouped(
     ht: jax.Array,       # [T, H]
     weights: jax.Array,  # [T, k] f32 combine weights (scaled/normalized)
-    ids: jax.Array,      # [T, k] i32 expert ids
-    we_gate: jax.Array,  # one layer's [E, ..] experts, or all layers'
+    ids: jax.Array,      # [T, k] i32 expert ids over the router's width
+    we_gate: jax.Array,  # one layer's [E, ..] HELD experts, or all layers'
     we_up: jax.Array,    # stacked [L, E, ..] with ``layer``
     we_down: jax.Array,
     scales: tuple | None = None,
@@ -344,13 +351,19 @@ def moe_apply_grouped(
     emit_census: bool = False,
     layer=None,
 ) -> jax.Array:          # [T, H] f32
-    """Route -> sort-by-expert -> grouped MLP -> weighted unsort-combine.
-    With ``emit_census`` the return is ``(y, census)``, ``census`` this
-    layer's ``grouped_census`` line."""
+    """Route -> sort-by-expert -> grouped MLP -> weighted unsort-combine,
+    over the experts held here (``cfg.held_experts_first`` onward, as many
+    as the weights hold): the sum of the held experts' terms. A pick of an
+    expert held elsewhere sorts past the last group, so it is in no group:
+    no row of the matmuls, no weight byte. With ``emit_census`` the return
+    is ``(y, census)``, ``census`` this layer's ``grouped_census`` line."""
     T, H = ht.shape
     k = ids.shape[1]
     E = we_gate.shape[-3]
+    share = cfg is not None and not cfg.holds_all_experts
     flat_ids = ids.reshape(-1)                       # [T*k]
+    if share:
+        flat_ids = held_slots(flat_ids, cfg, E)
     # Explicitly stable: equal expert ids keep token order, so the sorted
     # row layout — and the f32 scatter-add accumulation order below — is
     # deterministic across backends (XLA's default sort is NOT guaranteed
@@ -358,12 +371,16 @@ def moe_apply_grouped(
     order = jnp.argsort(flat_ids, stable=True)
     tok = order // k                                 # source token per slot
     xs = ht[tok]                                     # [T*k, H]
-    group_sizes = jnp.bincount(flat_ids, length=E)
+    group_sizes = jnp.bincount(flat_ids, length=E + 1)[:E]
     ys = expert_mlp_grouped(
         xs, group_sizes, we_gate, we_up, we_down, scales=scales,
         biases=biases, cfg=cfg, mesh=mesh, layer=layer,
     )
     w_sorted = weights.reshape(-1)[order]
+    if share:
+        # Rows past the held picks were in no group: nothing computed them.
+        in_group = (jnp.arange(T * k) < jnp.sum(group_sizes))[:, None]
+        ys = jnp.where(in_group, ys, 0)
     y = (
         jnp.zeros((T, H), jnp.float32)
         .at[tok]
@@ -371,7 +388,4 @@ def moe_apply_grouped(
     )
     if not emit_census:
         return y
-    return y, grouped_census(
-        group_sizes, T * k,
-        padded=scales is None and _use_kernel(H, we_gate.shape[-1], mesh),
-    )
+    return y, grouped_census(group_sizes, T * k)

@@ -150,6 +150,11 @@ def render_metrics(
         # padding-waste gauge the ragged_step bench part bounds.
         "live_tokens_total": stats.live_tokens_total,
         "padded_tokens_total": stats.padded_tokens_total,
+        # What a cached token costs, over both KV pools: bytes held by
+        # live references and the scheduled sequences' tokens, each summed
+        # over steps (the ratio of two rates).
+        "kv_bytes_in_use_total": stats.kv_bytes_in_use_total,
+        "cached_tokens_total": stats.cached_tokens_total,
         # Robustness trail (docs/architecture/fault-tolerance.md):
         # watchdog trips on the step loop, CRC-rejected bundles, and
         # transfers that degraded to local recompute.
@@ -170,6 +175,10 @@ def render_metrics(
         # expert weights a call reads.
         counters["moe_grouped_calls_total"] = stats.moe_grouped_calls_total
         counters["moe_groups_with_rows_total"] = stats.moe_groups_with_rows_total
+        # held / picks is the share of the router's picks this rank's
+        # experts serve (100 % where the model is served whole).
+        counters["moe_picks_total"] = stats.moe_picks_total
+        counters["moe_picks_held_total"] = stats.moe_picks_held_total
     if stats.indexer_keys_written_total:
         # Learned sparse attention (models with an indexer only).
         counters["sparse_bound_tokens_total"] = stats.sparse_bound_tokens_total
@@ -178,7 +187,8 @@ def render_metrics(
         counters["indexer_keys_written_total"] = stats.indexer_keys_written_total
     if stats.swa_ring_pages:
         # Hybrid-APC section retention activity
-        counters["swa_section_hits_total"] = stats.swa_section_hits
+        counters["swa_section_hits_total"] = stats.swa_section_hits_total
+        counters["swa_section_misses_total"] = stats.swa_section_misses_total
         counters["swa_section_captures_total"] = stats.swa_section_captures
     lines: list[str] = []
     if stats.kv_transfer_failures:

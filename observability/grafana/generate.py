@@ -217,10 +217,27 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                    "the cap = retention budget is the prefix-reuse limit."),
         panel("SWA section activity /s",
               [f"rate(llmd:swa_section_hits_total{M}[5m])",
+               f"rate(llmd:swa_section_misses_total{M}[5m])",
                f"rate(llmd:swa_section_captures_total{M}[5m])"],
-              legends=["hits/s", "captures/s"],
+              legends=["hits/s", "misses/s", "captures/s"],
               desc="captures with zero hits = retention is paying copy "
-                   "cost for prefixes that never repeat."),
+                   "cost for prefixes that never repeat. A miss is a run "
+                   "of full pages the main pool offered and the hybrid "
+                   "cache refused for want of a section (the request "
+                   "prefills the span and leaves the section behind): "
+                   "misses that do not turn into hits = sections evicted "
+                   "before their prefix comes back."),
+        panel("KV bytes per cached token",
+              [f"rate(llmd:kv_bytes_in_use_total{M}[5m]) / "
+               f"rate(llmd:cached_tokens_total{M}[5m])"],
+              legends=["bytes a cached token"], unit="bytes",
+              desc="Bytes of KV pages held by live references over both "
+                   "pools (main pool + SWA ring pool with its retained "
+                   "sections), per token the scheduled sequences hold, "
+                   "each summed per step. Every layer's share without "
+                   "the ring; the full-attention layers' share plus the "
+                   "rings with it; pages shared through the prefix cache "
+                   "lower it."),
         row("Million-token context tier (long-context.md)"),
         panel("Ring prefill steps /s",
               [f"rate(llmd:cp_ring_steps_total{M}[5m])"],
@@ -388,11 +405,21 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
               desc="One-device grouped MoE backend only "
                    "(docs/architecture/observability.md). Experts that "
                    "had at least one row in a grouped MoE layer call, as "
-                   "the kernel sees them (the rows padded into the last "
-                   "group count): over the model's experts it is the "
+                   "the kernel sees them: over the experts held it is the "
                    "share of expert weights a call streams from HBM, "
                    "which bounds the kernel's time from below. Few rows "
                    "a step or tokens that share a context touch fewer."),
+        panel("Router picks served by the experts held here",
+              [f"rate(llmd:moe_picks_held_total{M}[5m]) / "
+               f"rate(llmd:moe_picks_total{M}[5m])"],
+              legends=["held / picks"], unit="percentunit", max1=True,
+              desc="Of the router's picks (tokens x top-k per grouped MoE "
+                   "layer call) the share whose expert this rank holds = "
+                   "the rows its grouped matmuls multiply "
+                   "(ModelConfig.held_experts, docs/architecture/"
+                   "wide-ep.md). 1 where the model is served whole; "
+                   "held / routed experts under balanced routing; off "
+                   "that = this rank's experts run hot or cold."),
         row("Speculative decoding"),
         panel("Draft acceptance", [f"llmd:spec_acceptance_rate{M}"],
               unit="percentunit", max1=True,

@@ -37,6 +37,8 @@ PAGE, PAGES = 16, 2048
 # (layers, q heads, kv heads, head dim): llama-3.2-3b and qwen3-30b-a3b.
 LLAMA = (28, 24, 8, 128)
 QWEN3 = (48, 32, 4, 128)
+# k-exaone-236b-a23b.1chip's ring pool: its 6 sliding layers, 64 q / 8 kv heads.
+EXAONE = (6, 64, 8, 128)
 # Serving defaults: 64 sequences, a 2048-token step, 8192-token contexts:
 # 96 flat rows x 512 pages, and up to 2064 tokens a step.
 ROWS, SEQS, MAX_PAGES = 96, 64, 512
@@ -94,6 +96,16 @@ def _flat_attention(model, dtype, T):
             args + [_scales(model)],
         )
     return flat_paged_attention_full, args
+
+
+def _window_attention(T):
+    """The flat kernel with a window, as the sliding layers of a model that
+    mixes the two kinds call it (``window`` a traced per-layer scalar)."""
+    fn, args = _flat_attention(EXAONE, BF16, T)
+    return (
+        lambda q, kv, l, r, pt, kl, w: fn(q, kv, l, r, pt, kl, window=w),
+        args + [((), I32)],
+    )
 
 
 def _sparse_attention(T):
@@ -190,6 +202,8 @@ GMM_MODELS = {
     "mixtral-8x7b": (4096, 14336, 8),
     "mixtral-8x22b": (6144, 16384, 8),
     "deepseek-r1": (7168, 2048, 256),
+    # one rank's 16 of the 128 experts (the held share)
+    "k-exaone-236b-a23b": (6144, 2048, 16),
 }
 
 
@@ -198,6 +212,9 @@ CASES = {
     "flat_attention-int8": lambda d: _flat_attention(LLAMA, I8, 256),
     "flat_attention-qwen3-30b-a3b": lambda d: _flat_attention(QWEN3, BF16, 256),
     "sparse_attention-keye-vl-2.0-30b-a3b": lambda d: _sparse_attention(144),
+    "flat_attention-k-exaone-236b-a23b": lambda d: _flat_attention(EXAONE, BF16, 528),
+    "window_attention-k-exaone-236b-a23b": lambda d: _window_attention(528),
+    "flat_write-k-exaone-236b-a23b": lambda d: _flat_write(EXAONE, BF16, 528),
     "flat_write-bf16": lambda d: _flat_write(LLAMA, BF16, 2064),
     "flat_write-int8": lambda d: _flat_write(LLAMA, I8, 256),
     "flat_write-qwen3-30b-a3b": lambda d: _flat_write(QWEN3, BF16, 256),

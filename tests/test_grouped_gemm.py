@@ -159,7 +159,7 @@ PROMPTS = [list(range(1, 16)), [3, 3, 7, 1, 9, 9, 2], list(range(30, 41))]
 
 def _engine(flat: bool, **model):
     # Lane-tiled expert dims take the kernel's path under LLMD_PALLAS=
-    # interpret, so the rows it pads into the last group are in the count.
+    # interpret, whose row tile pads the sorted rows with rows of no group.
     model = {"hidden_size": 128, "num_heads": 4, "num_kv_heads": 2,
              "intermediate_size": 128, "num_experts": 8,
              "num_experts_per_tok": 3, "moe_intermediate_size": 128, **model}
@@ -200,14 +200,14 @@ def test_step_programs_count_groups_with_rows(monkeypatch, routed, flat):
     outs = _generate(engine)
     jax.effects_barrier()
     E = engine.config.model.num_experts
-    calls = groups = padded_calls = 0
+    calls = groups = padded_calls = picks = 0
     for ids in routed:  # one [T, k] per executed grouped MoE layer
         has_rows = np.bincount(ids.ravel(), minlength=E) > 0
         rows = ids.size
         tm = min(128, -(-rows // 8) * 8)
-        if rows % tm:  # the kernel sees the zero rows padded into the last group
-            has_rows[-1] = True
+        if rows % tm:  # zero rows pad the row tile; they belong to no group
             padded_calls += 1
+        picks += rows
         calls += 1
         groups += int(has_rows.sum())
     st = engine.stats
@@ -215,6 +215,7 @@ def test_step_programs_count_groups_with_rows(monkeypatch, routed, flat):
     assert padded_calls  # the case has steps whose rows do not fill a tile
     assert (st.moe_grouped_calls_total, st.moe_groups_with_rows_total) == (calls, groups)
     assert 0 < groups <= calls * E
+    assert st.moe_picks_total == st.moe_picks_held_total == picks  # every expert is held here
 
     # the same engine with the count never armed: the same greedy tokens
     plain = _engine(flat)

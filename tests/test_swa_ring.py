@@ -202,7 +202,7 @@ def test_ring_pages_released_on_finish_and_reuse():
             # Rings release in full; the hybrid-APC section cache keeps
             # its retained pages (one section for the repeated prompt).
             retained = sum(
-                e[1] - e[0] for e in eng._swa_sections._entries.values()
+                e.n_pre - e.s0 for e in eng._swa_sections._entries.values()
             )
             assert retained > 0
             assert (
@@ -216,7 +216,7 @@ def test_ring_pages_released_on_finish_and_reuse():
         # The step completed this prompt's prefill, so its own section
         # was captured too — recount retention after the step.
         retained = sum(
-            e[1] - e[0] for e in eng._swa_sections._entries.values()
+            e.n_pre - e.s0 for e in eng._swa_sections._entries.values()
         )
         held = eng.swa_allocator.num_pages - eng.swa_allocator.num_free_pages
         assert held == R + retained
