@@ -333,6 +333,11 @@ class EngineStats:
     # Step programs traced (runner.traced_programs names them): a trace
     # after warm-up is a shape nobody warmed, seconds inside a step.
     programs_traced_total: int = 0
+    # Step payload buffers put on the device and their bytes (runner.
+    # _put_step): a step program's host inputs travel as ONE buffer, so
+    # step_h2d_transfers_total / step_dispatches_total reads 1.
+    step_h2d_transfers_total: int = 0
+    step_h2d_bytes_total: int = 0
     # Speculative rows invalidated by a late finish/abort at reconcile
     # (EOS / stop token / max-tokens landed after the next batch was
     # staged against the optimistic one-token-per-decode assumption).
@@ -2146,6 +2151,10 @@ class LLMEngine:
         self.stats.queue_wait_ms_total = self.scheduler.queue_wait_ms
         self.stats.queue_admitted_total = self.scheduler.queue_admitted
         self.stats.programs_traced_total = self.runner.programs_traced
+        self.stats.step_h2d_transfers_total = (
+            self.runner.step_h2d_transfers_total
+        )
+        self.stats.step_h2d_bytes_total = self.runner.step_h2d_bytes_total
         self.stats.batch_backlog_jobs = sum(
             1 for r in self.scheduler.waiting if r.is_batch
         )

@@ -34,6 +34,7 @@ from __future__ import annotations
 import collections
 import concurrent.futures
 import functools
+import gc
 import logging
 import os
 import threading
@@ -46,6 +47,7 @@ import numpy as np
 
 from llmd_tpu import ops
 from llmd_tpu.config import EngineConfig, swa_ring_spec
+from llmd_tpu.engine import payload
 from llmd_tpu.engine.sampler import (
     SamplingInputs,
     sample_tokens,
@@ -103,6 +105,13 @@ _OP_KV_SCATTER_Q8 = 13
 # broadcast anyway so followers and debugging tools see the same step
 # structure the leader staged.
 _KIND_PREFILL, _KIND_DECODE, _KIND_VERIFY = 0, 1, 2
+
+# The step programs: opcode -> the kind engine/payload.py describes the
+# inputs of (one description for the lockstep wire and the device buffer).
+_STEP_KINDS = {
+    _OP_PREFILL: "prefill", _OP_VERIFY: "verify", _OP_UNIFIED: "unified",
+    _OP_FLAT: "flat", _OP_DECODE: "decode",
+}
 
 # Max tokens one unified row carries: prefill chunks longer than this are
 # split into consecutive sub-rows of the SAME sequence (each layer writes
@@ -310,6 +319,11 @@ class PendingUnified:
 
 
 class ModelRunner:
+    # Step payload buffers put on the device and their bytes (_put_step;
+    # EngineStats fields of the same names). One a step program.
+    step_h2d_transfers_total = 0
+    step_h2d_bytes_total = 0
+
     def __init__(
         self,
         config: EngineConfig,
@@ -567,6 +581,10 @@ class ModelRunner:
         self._unified = (
             self._build_unified() if sched.unified_step else None
         )
+        # Step payload layouts by (op, B, QK), and the (program, B, QK,
+        # greedy) that have had their first call (_run_step).
+        self._layouts: dict[tuple[int, int, int], payload.PayloadLayout] = {}
+        self._called: set = set()
         # Genuinely ragged flattened-token step (SchedulerConfig.
         # ragged_qlens): the unified step's forward runs over the packed
         # [T] token stream with cu_q_lens row offsets — no [B, Q]
@@ -976,6 +994,25 @@ class ModelRunner:
         self.programs_traced += 1
         self._tracing = f"{family}:{shape}"
 
+    @staticmethod
+    def _rows_inputs(f: dict) -> tuple[StepInput, SamplingInputs]:
+        """The [B, Q] step programs' (prefill, verify) inputs out of their
+        unpacked payload."""
+        inp = StepInput(
+            token_ids=f["tokens"],
+            positions=f["positions"],
+            query_lens=f["qlens"],
+            kv_lens=f["kvlens"],
+            page_table=f["page_table"],
+            lora_ids=f.get("lora"),
+            swa_page_table=f.get("swa_table"),
+        )
+        s = SamplingInputs(
+            temperature=f["temp"], top_k=f["top_k"], top_p=f["top_p"],
+            seeds=f["seeds"],
+        )
+        return inp, s
+
     def _build_forward(self, cp: int = 0):
         """The prefill/one-shot-step program. ``cp`` > 1 builds the
         context-parallel ring variant (ops/ring_attention.py): same call
@@ -989,13 +1026,13 @@ class ModelRunner:
         @functools.partial(
             jax.jit,
             donate_argnums=(1, 2) if ring else (1,),
-            static_argnames=("all_greedy",),
+            static_argnames=("B", "Q", "all_greedy"),
         )
-        def llmd_prefill_step(params, kv_cache, kv_swa, inp: StepInput,
-                              s: SamplingInputs, census=None,
-                              all_greedy=False):
-            self._note_traced(
-                "prefill_cp" if cp else "prefill", inp.token_ids.shape
+        def llmd_prefill_step(params, kv_cache, kv_swa, step, census=None,
+                              B=0, Q=0, all_greedy=False):
+            self._note_traced("prefill_cp" if cp else "prefill", (B, Q))
+            inp, s = self._rows_inputs(
+                self._layout(_OP_PREFILL, B, Q).unpack(step)
             )
             hidden, kv_cache, kv_swa, census = self._fwd_hidden(
                 params, kv_cache, kv_swa, inp, census, dbo=dbo, cp=cp
@@ -1032,12 +1069,14 @@ class ModelRunner:
         @functools.partial(
             jax.jit,
             donate_argnums=(1, 2) if ring else (1,),
-            static_argnames=("all_greedy",),
+            static_argnames=("B", "Q", "all_greedy"),
         )
-        def llmd_verify_step(params, kv_cache, kv_swa, inp: StepInput,
-                             s: SamplingInputs, census=None,
-                             all_greedy=False):
-            self._note_traced("verify", inp.token_ids.shape)
+        def llmd_verify_step(params, kv_cache, kv_swa, step, census=None,
+                             B=0, Q=0, all_greedy=False):
+            self._note_traced("verify", (B, Q))
+            inp, s = self._rows_inputs(
+                self._layout(_OP_VERIFY, B, Q).unpack(step)
+            )
             hidden, kv_cache, kv_swa, census = self._fwd_hidden(
                 params, kv_cache, kv_swa, inp, census, dbo=dbo
             )
@@ -1093,31 +1132,32 @@ class ModelRunner:
         @functools.partial(
             jax.jit,
             donate_argnums=(1, 2) if ring else (1,),
-            static_argnames=("Q", "all_greedy"),
+            static_argnames=("B", "Q", "T", "all_greedy"),
         )
         def llmd_unified_step(
             params,
             kv_cache,
             kv_swa,  # ring pool (None unless swa_ring)
-            stream: jax.Array,  # [T] packed token stream
-            row_start: jax.Array,  # [B] row's offset into the stream
-            pos0: jax.Array,  # [B] absolute position of the row's first token
-            qlens: jax.Array,  # [B] valid token count per row
-            kvlens: jax.Array,  # [B] kv length after this row's writes
-            verify_row: jax.Array,  # [B] bool (kind == verify)
-            page_table: jax.Array,  # [B, max_pages]
-            swa_table,  # [B, max_pages] ring view, or None
-            lora_ids,  # [B] i32 adapter slots, or None
-            temperature: jax.Array,
-            top_k: jax.Array,
-            top_p: jax.Array,
-            seeds: jax.Array,  # [B, S]
+            step: jax.Array,  # the step's packed inputs (_put_step)
             census=None,  # [E+2] MoE census accumulator, or None
-            Q: int = 0,
+            B: int = 0,  # rows
+            Q: int = 0,  # columns a row
+            T: int = 0,  # the stream bucket
             all_greedy: bool = False,
         ):
-            B = row_start.shape[0]
             self._note_traced("unified", (B, Q))
+            f = self._layout(_OP_UNIFIED, B, (Q << 20) | T).unpack(step)
+            stream = f["stream"]  # [T] packed token stream
+            row_start = f["row_start"]  # [B] row's offset into the stream
+            pos0 = f["pos0"]  # [B] absolute position of the row's first token
+            qlens = f["qlens"]  # [B] valid token count per row
+            kvlens = f["kvlens"]  # [B] kv length after this row's writes
+            verify_row = f["kind"] == _KIND_VERIFY  # [B] bool
+            page_table = f["page_table"]  # [B, max_pages]
+            swa_table = f.get("swa_table")  # [B, max_pages] ring view, or None
+            lora_ids = f.get("lora")  # [B] i32 adapter slots, or None
+            temperature, top_k, top_p = f["temp"], f["top_k"], f["top_p"]
+            seeds = f["seeds"]  # [B, S]
             cols = jnp.arange(Q)
             gidx = jnp.clip(
                 row_start[:, None] + cols[None, :], 0, stream.shape[0] - 1
@@ -1201,35 +1241,35 @@ class ModelRunner:
         @functools.partial(
             jax.jit,
             donate_argnums=(1, 2) if ring else (1,),
-            static_argnames=("all_greedy",),
+            static_argnames=("T", "all_greedy"),
         )
         def llmd_flat_step(
             params,
             kv_cache,
             kv_swa,  # ring pool (None unless swa_ring)
-            stream: jax.Array,  # [T] packed token stream
-            row_start: jax.Array,  # [B] cu_q_lens offsets (pad rows: total)
-            pos0: jax.Array,  # [B] absolute position of the row's first token
-            qlens: jax.Array,  # [B] valid token count per row
-            verify_row: jax.Array,  # [B] bool (kind == verify)
-            page_table: jax.Array,  # [B, max_pages] COMPACT per-row table
-            swa_table,  # [B, max_pages] ring view, or None
-            lora_ids,  # [B] i32 adapter slots, or None
-            temperature: jax.Array,
-            top_k: jax.Array,
-            top_p: jax.Array,
-            seeds: jax.Array,  # [B, S]
-            wsrc: jax.Array,  # [R] flat-write run slab starts
-            woff: jax.Array,  # [R] first in-page slot per run
-            wcnt: jax.Array,  # [R] token count per run (0 = pad)
-            wphys: jax.Array,  # [R] physical page per run (main pool)
-            wphys_swa,  # [R] physical page per run (ring pool), or None
+            step: jax.Array,  # the step's packed inputs (_put_step)
             census=None,  # [E+2] MoE census accumulator, or None
+            T: int = 0,  # the stream bucket (sizes the layout)
             all_greedy: bool = False,
         ):
-            T = stream.shape[0]
-            B = row_start.shape[0]
             self._note_traced("flat", (T, 1))
+            B = self.flat_rows
+            f = self._layout(_OP_FLAT, B, T).unpack(step)
+            stream = f["stream"]  # [T] packed token stream
+            row_start = f["row_start"]  # [B] cu_q_lens offsets (pad rows: total)
+            pos0 = f["pos0"]  # [B] absolute position of the row's first token
+            qlens = f["qlens"]  # [B] valid token count per row
+            verify_row = f["kind"] == _KIND_VERIFY  # [B] bool
+            page_table = f["page_table"]  # [B, max_pages] COMPACT per-row table
+            swa_table = f.get("swa_table")  # [B, max_pages] ring view, or None
+            lora_ids = f.get("lora")  # [B] i32 adapter slots, or None
+            temperature, top_k, top_p = f["temp"], f["top_k"], f["top_p"]
+            seeds = f["seeds"]  # [B, S]
+            # The flat write plan, [R] each: run slab starts, first in-page
+            # slot, token count (0 = pad), physical page in the main pool
+            # and (ring) in the ring pool.
+            wsrc, woff, wcnt = f["wsrc"], f["woff"], f["wcnt"]
+            wphys, wphys_swa = f["wphys"], f.get("wphys_swa")
             t = jnp.arange(T)
             ends = row_start + qlens  # non-decreasing (pad rows = total)
             row_of = jnp.clip(
@@ -1296,28 +1336,28 @@ class ModelRunner:
         @functools.partial(
             jax.jit,
             donate_argnums=(1, 2) if ring else (1,),
-            static_argnames=("k_steps", "all_greedy"),
+            static_argnames=("B", "k_steps", "all_greedy"),
         )
         def llmd_decode_window(
             params,
             kv_cache,
             kv_swa,  # ring pool (None unless swa_ring)
-            first_token: jax.Array,  # [B]
-            start_pos: jax.Array,  # [B] position of first_token
-            page_table: jax.Array,  # [B, max_pages]
-            swa_table,  # [B, max_pages] ring view, or None
-            active: jax.Array,  # [B] bool (pad rows False)
-            lora_ids,  # [B] i32 adapter slots, or None
-            temperature: jax.Array,
-            top_k: jax.Array,
-            top_p: jax.Array,
-            seeds: jax.Array,  # [B, K]
+            step: jax.Array,  # the step's packed inputs (_put_step)
             census=None,  # [E+2] MoE census accumulator, or None
+            B: int = 0,  # rows
             k_steps: int = 1,
             all_greedy: bool = False,
         ):
-            B = first_token.shape[0]
             self._note_traced("decode_window", (B, k_steps))
+            f = self._layout(_OP_DECODE, B, k_steps).unpack(step)
+            first_token = f["first"]  # [B]
+            start_pos = f["start"]  # [B] position of first_token
+            page_table = f["page_table"]  # [B, max_pages]
+            swa_table = f.get("swa_table")  # [B, max_pages] ring view, or None
+            active = f["active"] != 0  # [B] bool (pad rows False)
+            lora_ids = f.get("lora")  # [B] i32 adapter slots, or None
+            temperature, top_k, top_p = f["temp"], f["top_k"], f["top_p"]
+            seeds = f["seeds"]  # [B, K]
 
             def body(i, carry):
                 kv_cache, kv_swa, census, tok, out_t, out_l = carry
@@ -1807,89 +1847,34 @@ class ModelRunner:
                 ("q8", (L, B, Kc, self.page, D2), np.int8),
                 ("scales", (L, B, Kc, self.page, 2), np.float16),
             ]
-        mp = self.max_pages
-        if op in (_OP_PREFILL, _OP_VERIFY):
-            spec = [
-                ("tokens", (B, QK), np.int32),
-                ("positions", (B, QK), np.int32),
-                ("qlens", (B,), np.int32),
-                ("kvlens", (B,), np.int32),
-                ("page_table", (B, mp), np.int32),
-                ("temp", (B,), np.float32),
-                ("top_k", (B,), np.int32),
-                ("top_p", (B,), np.float32),
-                # Verify samples at every position, so its seeds are
-                # per (row, position) — the one payload difference from
-                # the prefill family.
-                ("seeds", (B, QK) if op == _OP_VERIFY else (B,), np.uint32),
-            ]
-        elif op == _OP_UNIFIED:
-            # QK packs (Q_bucket << 20) | T_bucket: the follower needs
-            # BOTH the per-row column count and the token-stream length
-            # to derive the payload geometry; the sample width S derives
-            # from the shared engine config (spec_q or 1) on both sides.
-            t = QK & 0xFFFFF
-            spec = [
-                ("stream", (t,), np.int32),
-                ("row_start", (B,), np.int32),
-                ("pos0", (B,), np.int32),
-                ("qlens", (B,), np.int32),
-                ("kvlens", (B,), np.int32),
-                ("kind", (B,), np.uint8),
-                ("page_table", (B, mp), np.int32),
-                ("temp", (B,), np.float32),
-                ("top_k", (B,), np.int32),
-                ("top_p", (B,), np.float32),
-                ("seeds", (B, self.unified_s), np.uint32),
-            ]
-        elif op == _OP_FLAT:
-            # Flattened-token step: QK carries T_bucket directly (the
-            # flat family has no per-row column bucket). The run-plan
-            # width derives from (B, T, page) identically on both sides:
-            # a row touching p pages emits p runs, and p <= (w-1)//page
-            # + 2 (the +2 covers the first page AND a mid-page start's
-            # extra straddle — a 2-token row starting at slot page-1
-            # already touches two pages), so the total is bounded by
-            # 2*B + ceil(T / page).
-            t = QK
-            rn = 2 * B + -(-t // self.page)
-            spec = [
-                ("stream", (t,), np.int32),
-                ("row_start", (B,), np.int32),
-                ("pos0", (B,), np.int32),
-                ("qlens", (B,), np.int32),
-                ("kvlens", (B,), np.int32),
-                ("kind", (B,), np.uint8),
-                ("page_table", (B, mp), np.int32),
-                ("temp", (B,), np.float32),
-                ("top_k", (B,), np.int32),
-                ("top_p", (B,), np.float32),
-                ("seeds", (B, self.unified_s), np.uint32),
-                ("wsrc", (rn,), np.int32),
-                ("woff", (rn,), np.int32),
-                ("wcnt", (rn,), np.int32),
-                ("wphys", (rn,), np.int32),
-            ]
-            if self.swa is not None:
-                spec.append(("wphys_swa", (rn,), np.int32))
-        else:
-            spec = [
-                ("first", (B,), np.int32),
-                ("start", (B,), np.int32),
-                ("page_table", (B, mp), np.int32),
-                ("active", (B,), np.uint8),
-                ("temp", (B,), np.float32),
-                ("top_k", (B,), np.int32),
-                ("top_p", (B,), np.float32),
-                ("seeds", (B, QK), np.uint32),
-            ]
-        if self.swa is not None:
-            # Ring-view table for sliding layers; followers derive its
-            # presence from the shared engine config.
-            spec.append(("swa_table", (B, mp), np.int32))
-        if self.cfg.num_lora_adapters:
-            spec.append(("lora", (B,), np.int32))
-        return spec
+        # A step program's inputs: the description its device buffer is
+        # laid out by as well (engine/payload.py).
+        return payload.step_fields(
+            _STEP_KINDS[op], B, QK, max_pages=self.max_pages, page=self.page,
+            sample_cols=self.unified_s, ring=self.swa is not None,
+            lora=bool(self.cfg.num_lora_adapters),
+        )
+
+    def _layout(self, op: int, B: int, QK: int) -> payload.PayloadLayout:
+        """Where a step program's inputs lie in its one device buffer: a
+        function of the program kind and its shape, the same on the side
+        that packs (``_put_step``) and in the program's trace."""
+        key = (op, B, QK)
+        layout = self._layouts.get(key)
+        if layout is None:
+            layout = self._layouts[key] = payload.PayloadLayout(
+                self._payload_spec(op, B, QK)
+            )
+        return layout
+
+    def _put_step(self, op: int, B: int, QK: int, arrays: dict) -> jax.Array:
+        """A step's host inputs onto the device: packed through the
+        program's layout into ONE buffer, ONE transfer (the per-array
+        hand-over was 10-17 of them, ~0.27 ms each on a v5e host)."""
+        buf = self._layout(op, B, QK).pack(arrays)
+        self.step_h2d_transfers_total += 1
+        self.step_h2d_bytes_total += buf.nbytes
+        return jnp.asarray(buf)
 
     def _bounded(self, fn, what: str):
         """Run one lockstep collective leg with a bounded wait.
@@ -2095,30 +2080,44 @@ class ModelRunner:
                     np.asarray([_OP_STOP, 0, 0, 0], np.int32), is_source=True
                 )
 
+    def _run_step(
+        self, program, op: int, rows: int, qk: int, arrays: dict, **statics
+    ) -> jax.Array:
+        """One step program: its inputs onto the device as one buffer,
+        then the jitted call.
+
+        A shape's FIRST call (trace, lowering, then a compile or a cache
+        read: seconds of host work that build ~50k tracers and equations,
+        all of them cyclic garbage when it returns) runs with the cyclic
+        collector off and ends in one young collection. Left on, lowering
+        the shapes warmed next reads ~0.3 s slower each once a step also
+        allocates its payload buffer: the 10 s of a 32-bucket ladder that
+        refused PR 34 for ``setup_s``. Measured on the chip, not explained
+        to the bottom: the cost follows the state of the process's heap
+        and collector, not the program lowered (PERF.md section 6, PR
+        35). A host that runs with the collector off
+        keeps it off; a warmed shape never comes here twice."""
+        step = self._put_step(op, rows, qk, arrays)
+        key = (program, rows, qk, statics["all_greedy"])
+        first = key not in self._called and gc.isenabled()
+        if first:
+            self._called.add(key)
+            gc.disable()
+        try:
+            self.kv_cache, self.kv_swa, packed, self._moe_census = program(
+                self.params, self.kv_cache, self.kv_swa, step,
+                census=self._moe_census, **statics,
+            )
+        finally:
+            if first:
+                gc.enable()
+                gc.collect(0)
+        return packed
+
     def _exec_prefill(self, arrays: dict, all_greedy: bool) -> jax.Array:
-        inp = StepInput(
-            token_ids=jnp.asarray(arrays["tokens"]),
-            positions=jnp.asarray(arrays["positions"]),
-            query_lens=jnp.asarray(arrays["qlens"]),
-            kv_lens=jnp.asarray(arrays["kvlens"]),
-            page_table=jnp.asarray(arrays["page_table"]),
-            lora_ids=(
-                jnp.asarray(arrays["lora"]) if "lora" in arrays else None
-            ),
-            swa_page_table=(
-                jnp.asarray(arrays["swa_table"])
-                if "swa_table" in arrays else None
-            ),
-        )
-        s = SamplingInputs(
-            temperature=jnp.asarray(arrays["temp"]),
-            top_k=jnp.asarray(arrays["top_k"]),
-            top_p=jnp.asarray(arrays["top_p"]),
-            seeds=jnp.asarray(arrays["seeds"]),
-        )
         # Program selection is shape-deterministic (Q rides the lockstep
         # broadcast), so leader and followers always pick the same family.
-        Q = arrays["tokens"].shape[1]
+        B, Q = arrays["tokens"].shape
         fwd = self._forward
         if (
             self._forward_cp is not None
@@ -2127,122 +2126,37 @@ class ModelRunner:
         ):
             fwd = self._forward_cp
             self.cp_ring_steps_total += self.cp_prefill
-        self.kv_cache, self.kv_swa, packed, self._moe_census = fwd(
-            self.params, self.kv_cache, self.kv_swa, inp, s,
-            census=self._moe_census, all_greedy=all_greedy,
+        return self._run_step(
+            fwd, _OP_PREFILL, B, Q, arrays, B=B, Q=Q, all_greedy=all_greedy
         )
-        return packed
 
     def _exec_verify(self, arrays: dict, all_greedy: bool) -> jax.Array:
-        inp = StepInput(
-            token_ids=jnp.asarray(arrays["tokens"]),
-            positions=jnp.asarray(arrays["positions"]),
-            query_lens=jnp.asarray(arrays["qlens"]),
-            kv_lens=jnp.asarray(arrays["kvlens"]),
-            page_table=jnp.asarray(arrays["page_table"]),
-            lora_ids=(
-                jnp.asarray(arrays["lora"]) if "lora" in arrays else None
-            ),
-            swa_page_table=(
-                jnp.asarray(arrays["swa_table"])
-                if "swa_table" in arrays else None
-            ),
+        B, Q = arrays["tokens"].shape
+        return self._run_step(
+            self._verify, _OP_VERIFY, B, Q, arrays,
+            B=B, Q=Q, all_greedy=all_greedy,
         )
-        s = SamplingInputs(
-            temperature=jnp.asarray(arrays["temp"]),
-            top_k=jnp.asarray(arrays["top_k"]),
-            top_p=jnp.asarray(arrays["top_p"]),
-            seeds=jnp.asarray(arrays["seeds"]),
-        )
-        self.kv_cache, self.kv_swa, packed, self._moe_census = self._verify(
-            self.params, self.kv_cache, self.kv_swa, inp, s,
-            census=self._moe_census, all_greedy=all_greedy,
-        )
-        return packed
 
     def _exec_unified(self, arrays: dict, Q: int, all_greedy: bool) -> jax.Array:
-        self.kv_cache, self.kv_swa, packed, self._moe_census = self._unified(
-            self.params,
-            self.kv_cache,
-            self.kv_swa,
-            jnp.asarray(arrays["stream"]),
-            jnp.asarray(arrays["row_start"]),
-            jnp.asarray(arrays["pos0"]),
-            jnp.asarray(arrays["qlens"]),
-            jnp.asarray(arrays["kvlens"]),
-            jnp.asarray(arrays["kind"] == _KIND_VERIFY),
-            jnp.asarray(arrays["page_table"]),
-            (
-                jnp.asarray(arrays["swa_table"])
-                if "swa_table" in arrays else None
-            ),
-            jnp.asarray(arrays["lora"]) if "lora" in arrays else None,
-            jnp.asarray(arrays["temp"]),
-            jnp.asarray(arrays["top_k"]),
-            jnp.asarray(arrays["top_p"]),
-            jnp.asarray(arrays["seeds"]),
-            census=self._moe_census,
-            Q=Q,
-            all_greedy=all_greedy,
+        B, T = arrays["row_start"].shape[0], arrays["stream"].shape[0]
+        return self._run_step(
+            self._unified, _OP_UNIFIED, B, (Q << 20) | T, arrays,
+            B=B, Q=Q, T=T, all_greedy=all_greedy,
         )
-        return packed
 
     def _exec_flat(self, arrays: dict, all_greedy: bool) -> jax.Array:
-        self.kv_cache, self.kv_swa, packed, self._moe_census = self._flat(
-            self.params,
-            self.kv_cache,
-            self.kv_swa,
-            jnp.asarray(arrays["stream"]),
-            jnp.asarray(arrays["row_start"]),
-            jnp.asarray(arrays["pos0"]),
-            jnp.asarray(arrays["qlens"]),
-            jnp.asarray(arrays["kind"] == _KIND_VERIFY),
-            jnp.asarray(arrays["page_table"]),
-            (
-                jnp.asarray(arrays["swa_table"])
-                if "swa_table" in arrays else None
-            ),
-            jnp.asarray(arrays["lora"]) if "lora" in arrays else None,
-            jnp.asarray(arrays["temp"]),
-            jnp.asarray(arrays["top_k"]),
-            jnp.asarray(arrays["top_p"]),
-            jnp.asarray(arrays["seeds"]),
-            jnp.asarray(arrays["wsrc"]),
-            jnp.asarray(arrays["woff"]),
-            jnp.asarray(arrays["wcnt"]),
-            jnp.asarray(arrays["wphys"]),
-            (
-                jnp.asarray(arrays["wphys_swa"])
-                if "wphys_swa" in arrays else None
-            ),
-            census=self._moe_census,
-            all_greedy=all_greedy,
+        T = arrays["stream"].shape[0]
+        return self._run_step(
+            self._flat, _OP_FLAT, self.flat_rows, T, arrays,
+            T=T, all_greedy=all_greedy,
         )
-        return packed
 
     def _exec_decode(self, arrays: dict, K: int, all_greedy: bool) -> jax.Array:
-        self.kv_cache, self.kv_swa, packed, self._moe_census = self._multi(
-            self.params,
-            self.kv_cache,
-            self.kv_swa,
-            jnp.asarray(arrays["first"]),
-            jnp.asarray(arrays["start"]),
-            jnp.asarray(arrays["page_table"]),
-            (
-                jnp.asarray(arrays["swa_table"])
-                if "swa_table" in arrays else None
-            ),
-            jnp.asarray(arrays["active"].astype(bool)),
-            jnp.asarray(arrays["lora"]) if "lora" in arrays else None,
-            jnp.asarray(arrays["temp"]),
-            jnp.asarray(arrays["top_k"]),
-            jnp.asarray(arrays["top_p"]),
-            jnp.asarray(arrays["seeds"]),
-            census=self._moe_census,
-            k_steps=K,
-            all_greedy=all_greedy,
+        B = arrays["first"].shape[0]
+        return self._run_step(
+            self._multi, _OP_DECODE, B, K, arrays,
+            B=B, k_steps=K, all_greedy=all_greedy,
         )
-        return packed
 
     # ------------------------------------------------------------------ #
     # KV page staging (the HBM<->host leg of the P/D transfer path;
@@ -3537,31 +3451,16 @@ class ModelRunner:
             )
         return n
 
+    def _warm_arrays(self, op: int, B: int, QK: int) -> dict:
+        """A step program's inputs at rest: every field of its payload
+        zero, ``top_p`` at its neutral 1."""
+        arrays = self._layout(op, B, QK).zeros()
+        arrays["top_p"][:] = 1.0
+        return arrays
+
     def _warm_flat(self, T: int, all_greedy: bool = False) -> None:
         B = self.flat_rows
-        rn = 2 * B + -(-T // self.page)
-        arrays = {
-            "stream": np.zeros(T, np.int32),
-            "row_start": np.zeros(B, np.int32),
-            "pos0": np.zeros(B, np.int32),
-            "qlens": np.zeros(B, np.int32),
-            "kvlens": np.zeros(B, np.int32),
-            "kind": np.zeros(B, np.uint8),
-            "page_table": np.zeros((B, self.max_pages), np.int32),
-            "temp": np.zeros(B, np.float32),
-            "top_k": np.zeros(B, np.int32),
-            "top_p": np.ones(B, np.float32),
-            "seeds": np.zeros((B, self.unified_s), np.uint32),
-            "wsrc": np.zeros(rn, np.int32),
-            "woff": np.zeros(rn, np.int32),
-            "wcnt": np.zeros(rn, np.int32),
-            "wphys": np.zeros(rn, np.int32),
-        }
-        if self.swa is not None:
-            arrays["swa_table"] = np.zeros((B, self.max_pages), np.int32)
-            arrays["wphys_swa"] = np.zeros(rn, np.int32)
-        if self.cfg.num_lora_adapters:
-            arrays["lora"] = np.zeros(B, np.int32)
+        arrays = self._warm_arrays(_OP_FLAT, B, T)
         with self._dispatch_lock:
             arrays = self._sync_locked(_OP_FLAT, B, T, all_greedy, arrays)
             self._exec_flat(arrays, all_greedy)
@@ -3569,23 +3468,7 @@ class ModelRunner:
     def _warm_unified(
         self, B: int, Q: int, T: int, all_greedy: bool = False
     ) -> None:
-        arrays = {
-            "stream": np.zeros(T, np.int32),
-            "row_start": np.zeros(B, np.int32),
-            "pos0": np.zeros(B, np.int32),
-            "qlens": np.zeros(B, np.int32),
-            "kvlens": np.zeros(B, np.int32),
-            "kind": np.zeros(B, np.uint8),
-            "page_table": np.zeros((B, self.max_pages), np.int32),
-            "temp": np.zeros(B, np.float32),
-            "top_k": np.zeros(B, np.int32),
-            "top_p": np.ones(B, np.float32),
-            "seeds": np.zeros((B, self.unified_s), np.uint32),
-        }
-        if self.swa is not None:
-            arrays["swa_table"] = np.zeros((B, self.max_pages), np.int32)
-        if self.cfg.num_lora_adapters:
-            arrays["lora"] = np.zeros(B, np.int32)
+        arrays = self._warm_arrays(_OP_UNIFIED, B, (Q << 20) | T)
         with self._dispatch_lock:
             arrays = self._sync_locked(
                 _OP_UNIFIED, B, (Q << 20) | T, all_greedy, arrays
@@ -3593,61 +3476,20 @@ class ModelRunner:
             self._exec_unified(arrays, Q, all_greedy)
 
     def _warm_prefill(self, B: int, Q: int, all_greedy: bool = False) -> None:
-        arrays = {
-            "tokens": np.zeros((B, Q), np.int32),
-            "positions": np.zeros((B, Q), np.int32),
-            "qlens": np.zeros(B, np.int32),
-            "kvlens": np.zeros(B, np.int32),
-            "page_table": np.zeros((B, self.max_pages), np.int32),
-            "temp": np.zeros(B, np.float32),
-            "top_k": np.zeros(B, np.int32),
-            "top_p": np.ones(B, np.float32),
-            "seeds": np.zeros(B, np.uint32),
-        }
-        if self.swa is not None:
-            arrays["swa_table"] = np.zeros((B, self.max_pages), np.int32)
-        if self.cfg.num_lora_adapters:
-            arrays["lora"] = np.zeros(B, np.int32)
+        arrays = self._warm_arrays(_OP_PREFILL, B, Q)
         with self._dispatch_lock:
             arrays = self._sync_locked(_OP_PREFILL, B, Q, all_greedy, arrays)
             self._exec_prefill(arrays, all_greedy)
 
     def _warm_verify(self, B: int, all_greedy: bool = False) -> None:
         Q = self.spec_q
-        arrays = {
-            "tokens": np.zeros((B, Q), np.int32),
-            "positions": np.zeros((B, Q), np.int32),
-            "qlens": np.zeros(B, np.int32),
-            "kvlens": np.zeros(B, np.int32),
-            "page_table": np.zeros((B, self.max_pages), np.int32),
-            "temp": np.zeros(B, np.float32),
-            "top_k": np.zeros(B, np.int32),
-            "top_p": np.ones(B, np.float32),
-            "seeds": np.zeros((B, Q), np.uint32),
-        }
-        if self.swa is not None:
-            arrays["swa_table"] = np.zeros((B, self.max_pages), np.int32)
-        if self.cfg.num_lora_adapters:
-            arrays["lora"] = np.zeros(B, np.int32)
+        arrays = self._warm_arrays(_OP_VERIFY, B, Q)
         with self._dispatch_lock:
             arrays = self._sync_locked(_OP_VERIFY, B, Q, all_greedy, arrays)
             self._exec_verify(arrays, all_greedy)
 
     def _warm_decode(self, B: int, K: int, all_greedy: bool = False) -> None:
-        arrays = {
-            "first": np.zeros(B, np.int32),
-            "start": np.zeros(B, np.int32),
-            "page_table": np.zeros((B, self.max_pages), np.int32),
-            "active": np.zeros(B, np.uint8),
-            "temp": np.zeros(B, np.float32),
-            "top_k": np.zeros(B, np.int32),
-            "top_p": np.ones(B, np.float32),
-            "seeds": np.zeros((B, K), np.uint32),
-        }
-        if self.swa is not None:
-            arrays["swa_table"] = np.zeros((B, self.max_pages), np.int32)
-        if self.cfg.num_lora_adapters:
-            arrays["lora"] = np.zeros(B, np.int32)
+        arrays = self._warm_arrays(_OP_DECODE, B, K)
         with self._dispatch_lock:
             arrays = self._sync_locked(_OP_DECODE, B, K, all_greedy, arrays)
             self._exec_decode(arrays, K, all_greedy)

@@ -136,6 +136,10 @@ def render_metrics(
         "queue_wait_ms_total": round(stats.queue_wait_ms_total, 3),
         "queue_admitted_total": stats.queue_admitted_total,
         "programs_traced_total": stats.programs_traced_total,
+        # A step's host inputs as one packed buffer: transfers a
+        # dispatched step program reads 1.
+        "step_h2d_transfers_total": stats.step_h2d_transfers_total,
+        "step_h2d_bytes_total": stats.step_h2d_bytes_total,
         "async_rollbacks_total": stats.async_rollbacks_total,
         "decode_dispatches_total": stats.decode_dispatches_total,
         # Unified single-dispatch steps (the family split of

@@ -336,6 +336,15 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                    "warmed: seconds of trace, lowering and (on a cache "
                    "miss) compile inside a serving step. /admin/status "
                    "names the shapes."),
+        panel("Host-to-device transfers per step program",
+              [f"rate(llmd:step_h2d_transfers_total{M}[5m]) / "
+               f"rate(llmd:step_dispatches_total{M}[5m])"],
+              thresholds=[(None, "green"), (1.01, "red")],
+              desc="A step program's host inputs travel as ONE packed "
+                   "buffer (llmd:step_h2d_bytes_total has its bytes): 1 "
+                   "means the packed payload engages. A transfer costs "
+                   "the host about the same whatever its size, so more "
+                   "than 1 is launch time the chip idles through."),
         panel("Engine steps /s", [f"rate(llmd:engine_steps_total{M}[5m])"],
               desc="Step cadence; flat at 0 while requests run = the "
                    "step loop is wedged."),

@@ -413,6 +413,11 @@ def test_lease_renewal_keeps_chunked_export_alive():
             ]
             assert all(renewed), renewed
         assert producer.kv_connector.server.registered_count == n_cells
+        # The pull adopts pages through JAX: on a loaded host (tier-1 runs
+        # six workers) it can outlast one 300 ms lease, so the last renewal
+        # before it is a long one. What is under test is the hold above.
+        for k in transfer_keys(params):
+            assert shipper_mod.renew(host, port, k, lease_ms=10_000)
         n = consumer.kv_connector.import_for_prompt(prompt, params)
         assert n == 11  # every transferred page adopted
         assert consumer.kv_connector.import_failures == 0
