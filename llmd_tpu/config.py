@@ -148,6 +148,28 @@ class ModelConfig:
     # None = every layer. The EXAONE-4.0 family rotates its sliding layers
     # only: ("sliding_attention",).
     rope_layer_types: tuple | None = None
+    # Softmax scale of the attention layers; None = head_dim ** -0.5.
+    # granitemoehybrid states its own (``attention_multiplier``).
+    attention_multiplier: float | None = None
+    # muP-style multipliers (granite): on the embedding's output, on every
+    # residual branch (mixer and FFN), and the divisor of the logits.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # --- state-space mixers (Mamba-2; docs/architecture/kv-cache.md, "The
+    # state pool") ---
+    # ``layer_types`` entries "mamba" run a Mamba-2 mixer in place of
+    # attention: ``mamba_n_heads`` x ``mamba_d_head`` channels, a state of
+    # ``mamba_d_state`` a channel, B and C shared by the heads of a group,
+    # a causal depthwise conv of ``mamba_d_conv`` taps in front. Such a
+    # layer keeps a FIXED-size state a sequence (no pages): the SSM state
+    # [heads, d_head, d_state] in float32 and the conv's last ``d_conv - 1``
+    # inputs in the model's dtype.
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
 
     def __post_init__(self) -> None:
         if self.quantization not in (None, "int8"):
@@ -182,6 +204,28 @@ class ModelConfig:
             self.rope_layer_types = tuple(self.rope_layer_types)
             if self.layer_types is None:
                 raise ValueError("rope_layer_types needs layer_types")
+        if self.state_space:
+            if min(self.mamba_n_heads, self.mamba_d_head, self.mamba_d_state) <= 0:
+                raise ValueError(
+                    "mamba layers need mamba_n_heads, mamba_d_head and "
+                    "mamba_d_state"
+                )
+            if self.mamba_n_groups != 1:
+                raise ValueError(
+                    "mamba_n_groups > 1 is not supported: the mixer shares "
+                    "one B and C among all heads"
+                )
+            for what, on in (
+                ("MLA", self.kv_lora_rank > 0),
+                ("sliding_window", self.sliding_window > 0),
+                ("learned sparse attention", self.indexer_topk > 0),
+                ("LoRA adapters", self.num_lora_adapters > 0),
+                ("a dense layer prefix", self.first_dense_layers > 0),
+            ):
+                if on:
+                    raise ValueError(
+                        f"state-space layers are not supported with {what}"
+                    )
         if self.sliding_window > 0 and self.kv_lora_rank > 0:
             raise ValueError(
                 "sliding_window is not supported with MLA (no known MLA "
@@ -247,6 +291,39 @@ class ModelConfig:
         if self.rope_layer_types is None:
             return (True,) * self.num_layers
         return tuple(t in self.rope_layer_types for t in self.layer_types)
+
+    @property
+    def state_space(self) -> bool:
+        """Some layers are state-space mixers (``layer_types`` "mamba")."""
+        return self.layer_types is not None and "mamba" in self.layer_types
+
+    @property
+    def mamba_layers(self) -> tuple[int, ...]:
+        if not self.state_space:
+            return ()
+        return tuple(i for i, t in enumerate(self.layer_types) if t == "mamba")
+
+    @property
+    def attention_layers(self) -> tuple[int, ...]:
+        """The layers that cache K and V under page ids (all of them but the
+        state-space mixers)."""
+        m = set(self.mamba_layers)
+        return tuple(i for i in range(self.num_layers) if i not in m)
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels under the causal conv: x, B and C."""
+        return self.mamba_d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def sm_scale(self) -> float:
+        if self.attention_multiplier is not None:
+            return float(self.attention_multiplier)
+        return self.head_dim ** -0.5
 
     @property
     def is_moe(self) -> bool:
@@ -489,6 +566,9 @@ class SwaRingSpec:
     # Per-sequence prefill chunk cap the scheduler enforces while the
     # ring is on (R is sized from it; chunking finer is always correct).
     chunk_tokens: int
+    # The per-sequence state is K and V of a window: a section can be cut
+    # out of the ring after the chunk that wrote it (StateSlotSpec: True).
+    recurrent = False
 
     def section(self, prompt_len: int, page_size: int) -> tuple[int, int, int]:
         """Sliding-layer P/D export-section geometry: (n_pre, s0, count).
@@ -562,6 +642,56 @@ def swa_ring_spec(
         )
     blocks = cache.swa_blocks or sched.max_num_seqs * ring
     return SwaRingSpec(windows, full, swa, ring, blocks, chunk)
+
+
+@dataclasses.dataclass(frozen=True)
+class StateSlotSpec:
+    """Geometry of the state pool of a model with state-space layers, in the
+    terms the engine already has for the other kind of per-sequence state,
+    the sliding-window ring (``SwaRingSpec``): a running sequence holds a
+    "ring" of ONE slot; a retained snapshot is a "section" of one slot, the
+    state AT page boundary ``n_pre`` (``section``: ``s0 = n_pre - 1``), so
+    the one retained-state cache, its keys, its eviction order and the
+    scheduler's admission and release serve both kinds.
+
+    What differs is WHEN a section can be taken. A ring holds the window
+    before every page boundary of the chunk it has just written; a recurrent
+    state holds every token up to the last one, so the scheduler of such a
+    model ends a prefill chunk AT the boundaries a snapshot is wanted at (a
+    prompt's last full page; the end of a run of full pages the main pool
+    offered and the engine had to refuse): ``recurrent``."""
+
+    kv_layers: tuple[int, ...]     # layers with K and V pages (attention)
+    state_layers: tuple[int, ...]  # state-space layers
+    num_swa_blocks: int            # slots in the pool (running + retained)
+    ring_pages = 1                 # a "ring" of one slot
+    chunk_tokens = 0               # no per-sequence chunk cap
+    recurrent = True
+
+    @property
+    def full_layers(self) -> tuple[int, ...]:
+        return self.kv_layers
+
+    def section(self, prompt_len: int, page_size: int) -> tuple[int, int, int]:
+        n_pre = max(0, (prompt_len - 1) // page_size)
+        s0 = max(0, n_pre - 1)
+        return n_pre, s0, n_pre - s0
+
+    def max_section_pages(self, page_size: int) -> int:
+        return 1
+
+
+def state_slot_spec(
+    model: "ModelConfig", sched: "SchedulerConfig"
+) -> StateSlotSpec | None:
+    """The state pool's geometry, or None for a model without state-space
+    layers: one slot a sequence the scheduler may run (the engine adds the
+    retained snapshots' slots, ``swa_section_count`` of them)."""
+    if not model.state_space:
+        return None
+    return StateSlotSpec(
+        model.attention_layers, model.mamba_layers, sched.max_num_seqs
+    )
 
 
 @dataclasses.dataclass
@@ -800,6 +930,42 @@ class EngineConfig:
                 f"{self.model.indexer_topk}) does not run with " + "; ".join(on)
                 + ": that path would drop the indexer's key plane or attend "
                 "past its selection"
+            )
+
+
+    def check_state_space(self) -> None:
+        """Refuse, at start, what would move or reinterpret a sequence's
+        cache by a road that knows pages only (no silent fallback). A
+        state-space layer keeps a fixed-size state a sequence in the state
+        pool; it exists on the flat step of one device."""
+        if not self.model.state_space:
+            return
+        s, c, p = self.scheduler, self.cache, self.parallel
+        refused = {
+            "speculative decoding (speculative_ngram)": s.speculative_ngram,
+            "fused decode windows (decode_window > 1)": s.decode_window > 1,
+            "the bucketed or split step (unified_step / ragged_qlens off)":
+                not (s.unified_step and s.ragged_qlens),
+            "whole-prompt prefill (enable_chunked_prefill off)":
+                not s.enable_chunked_prefill,
+            "an int8 KV cache": c.quantized,
+            "the sliding-window ring (swa_ring)": c.swa_ring,
+            "prefix caching without retained snapshots (swa_section_cache=0)":
+                c.enable_prefix_caching and c.swa_section_cache <= 0,
+            "tiered KV offload": self.offload is not None and self.offload.enabled,
+            "P/D KV transfer (kv_role)": bool(self.kv_role),
+            "a sharded mesh (tp/dp/ep > 1)":
+                p.world_size > 1 or p.expert_parallel_size > 1,
+            "ring prefill or dual-batch overlap": p.cp_prefill > 1 or p.enable_dbo,
+            "int8 weights": self.model.quantization is not None,
+        }
+        on = [what for what, is_on in refused.items() if is_on]
+        if on:
+            raise ValueError(
+                f"{self.model.name}: state-space layers do not run with "
+                + "; ".join(on)
+                + ": that path knows pages only and would drop, skip or "
+                "misread the sequence's recurrent state"
             )
 
 

@@ -21,7 +21,9 @@ import jax
 import numpy as np
 
 from llmd_tpu import faults
-from llmd_tpu.config import EngineConfig, swa_ring_spec, swa_section_count
+from llmd_tpu.config import (
+    EngineConfig, state_slot_spec, swa_ring_spec, swa_section_count,
+)
 from llmd_tpu.engine.kv_cache import KVEventSink, PageAllocator
 from llmd_tpu.engine.request import (
     FinishReason,
@@ -61,8 +63,17 @@ class _Section:
     hits: int = 0
 
 
-class SwaSectionCache:
-    """Retained sliding-window sections for HYBRID prefix caching under
+class RetainedStateCache:
+    """Retained per-sequence state for HYBRID prefix caching: ONE policy
+    for both kinds of state that live outside the paged pool. A sliding-
+    window ring's SECTION (below), and a state-space model's SNAPSHOT: the
+    sequence's one slot of the state pool AT page boundary ``n_pre``, a
+    section of one "page" (``s0 = n_pre - 1``, a ring of one:
+    ``config.StateSlotSpec``). Keys, budget, eviction order, capture, seed
+    and counters are the same; the device copy is the same program over
+    the other pool's leaves (``runner.copy_pages_on_device(swa=True)``).
+
+    Retained sliding-window sections for HYBRID prefix caching under
     the SWA ring (the reference's hybrid KV-cache manager role, pd gpu
     patch-decode.yaml:19).
 
@@ -101,6 +112,7 @@ class SwaSectionCache:
         self.hits = 0
         self.misses = 0
         self.captures = 0
+        self.evictions = 0
 
     def capture(
         self, key: bytes, ring_ids: list[int], s0: int, n_pre: int,
@@ -137,7 +149,8 @@ class SwaSectionCache:
         self.retained_pages += cnt
         src = [ring_ids[l % R] for l in range(s0, n_pre)]
         try:
-            self._runner.copy_pages_on_device(src, dst, swa=True)
+            with profiling.span("llmd.state.capture", pages=cnt):
+                self._runner.copy_pages_on_device(src, dst, swa=True)
         except BaseException:
             # A failed device copy must refund the retained pages, or
             # the ring pool permanently shrinks by `cnt` on every retry.
@@ -173,6 +186,7 @@ class SwaSectionCache:
         ids = self._entries.pop(victim).pages
         self._alloc.free(ids)
         self.retained_pages -= len(ids)
+        self.evictions += 1
         return True
 
     def has(self, key: bytes) -> bool:
@@ -203,7 +217,8 @@ class SwaSectionCache:
         s0, n_pre = entry.s0, entry.n_pre
         R = len(ring_ids)
         dst = [ring_ids[(s0 + i) % R] for i in range(n_pre - s0)]
-        self._runner.copy_pages_on_device(entry.pages, dst, swa=True)
+        with profiling.span("llmd.state.seed", pages=len(dst)):
+            self._runner.copy_pages_on_device(entry.pages, dst, swa=True)
         self.hits += 1
         return s0, n_pre
 
@@ -213,6 +228,7 @@ class SwaSectionCache:
             "hits": self.hits,
             "misses": self.misses,
             "captures": self.captures,
+            "evictions": self.evictions,
         }
 
 
@@ -231,7 +247,7 @@ class EngineStats:
     # utilization-based routing, not just the main pool.
     swa_ring_usage: float = 0.0
     swa_ring_pages: int = 0
-    # Hybrid-APC section retention (SwaSectionCache)
+    # Hybrid-APC section retention (RetainedStateCache)
     swa_sections: int = 0
     # Hybrid prefix hits taken (a fresh ring seeded from a retained
     # section), and full-page runs the main pool offered at admission that
@@ -240,6 +256,25 @@ class EngineStats:
     swa_section_hits_total: int = 0
     swa_section_misses_total: int = 0
     swa_section_captures: int = 0
+    # The state pool of a model with state-space layers (0 elsewhere): slots
+    # that running sequences hold, snapshots retained, and the retained-
+    # state cache's counters under the meanings above (a hit seeds a fresh
+    # slot from a snapshot; a miss is a run of full pages the main pool
+    # offered at admission, refused for want of a snapshot at its end).
+    state_slots_in_use: int = 0
+    state_snapshots: int = 0
+    state_snapshot_hits_total: int = 0
+    state_snapshot_misses_total: int = 0
+    state_snapshot_captures_total: int = 0
+    state_snapshot_evictions_total: int = 0
+    # Bytes of state-pool slots held (running + retained), summed over
+    # steps: beside kv_bytes_in_use_total (which counts pages only there)
+    # over cached_tokens_total, what a cached token costs in both pools.
+    state_bytes_in_use_total: int = 0
+    # Decode rows the state-space layers updated and prefill tokens they
+    # scanned, each x the mixer layers (what the rooflines divide by).
+    ssm_update_rows_total: int = 0
+    ssm_scan_tokens_total: int = 0
     # counters
     prompt_tokens: int = 0
     generation_tokens: int = 0
@@ -512,10 +547,16 @@ class LLMEngine:
         # transient per sequence; prefix caching stays ON for the main
         # (full-attention) pool and becomes HYBRID: hits are taken only
         # when a retained sliding section can seed the fresh ring
-        # (SwaSectionCache — the reference's hybrid KV-cache manager
+        # (RetainedStateCache — the reference's hybrid KV-cache manager
         # role). Tiered offload still refuses (host-cached pages would
         # lack sliding-layer KV).
-        self._swa = swa_ring_spec(config.model, config.cache, config.scheduler)
+        config.check_state_space()
+        # A model with state-space layers: the same machinery over the state
+        # pool, a "ring" of one slot a sequence (config.StateSlotSpec).
+        self._swa = swa_ring_spec(
+            config.model, config.cache, config.scheduler
+        ) or state_slot_spec(config.model, config.scheduler)
+        self._state_pool = self._swa is not None and self._swa.recurrent
         if self._swa is not None:
             if not config.scheduler.enable_chunked_prefill:
                 raise ValueError(
@@ -531,7 +572,7 @@ class LLMEngine:
                 )
         # HYBRID prefix caching under the ring: the main pool (full-
         # attention layers) stays hashed/reusable; a hit is USABLE only
-        # when the retained sliding section (SwaSectionCache) can seed
+        # when the retained sliding section (RetainedStateCache) can seed
         # the fresh ring, so the scheduler's bare shortcut is disabled
         # (scheduler._apply_prefix_cache) and hits happen at admission.
         # With section retention off, hits are structurally impossible —
@@ -627,6 +668,7 @@ class LLMEngine:
             swa_allocator=self.swa_allocator,
             swa_ring_pages=self._swa.ring_pages if self._swa else 0,
             swa_chunk_tokens=self._swa.chunk_tokens if self._swa else 0,
+            state_aligned=self._state_pool,
         )
         self.runner = ModelRunner(
             config, self.ctx, params=params, swa_spec=self._swa
@@ -638,7 +680,7 @@ class LLMEngine:
             and prefix_caching
             and config.cache.swa_section_cache > 0
         ):
-            self._swa_sections = SwaSectionCache(
+            self._swa_sections = RetainedStateCache(
                 self.swa_allocator, self.runner, swa_sections_cap,
                 self._swa_retention_budget,
                 is_live=self.allocator.has_cached,
@@ -2071,6 +2113,11 @@ class LLMEngine:
         by_kind = f"steps_{self._step_carried[0]}_total"
         setattr(st, by_kind, getattr(st, by_kind) + 1)
         st.kv_bytes_in_use_total += self._kv_bytes_in_use()
+        if self._state_pool:
+            w = self.swa_allocator
+            st.state_bytes_in_use_total += (
+                (w.num_pages - w.num_free_pages) * self.runner.kv_swa_page_bytes
+            )
         st.cached_tokens_total += sum(
             s.request.num_computed_tokens for s in batch.seqs
         )
@@ -2082,7 +2129,7 @@ class LLMEngine:
         r = self.runner
         a = self.allocator
         n = (a.num_pages - a.num_free_pages) * r.kv_page_bytes
-        if self.swa_allocator is not None:
+        if self.swa_allocator is not None and not self._state_pool:
             w = self.swa_allocator
             n += (w.num_pages - w.num_free_pages) * r.kv_swa_page_bytes
         return n
@@ -2137,7 +2184,20 @@ class LLMEngine:
         self.stats.num_waiting = self.scheduler.num_waiting
         self.stats.num_running = self.scheduler.num_running
         self.stats.kv_usage = self.allocator.usage()
-        if self.swa_allocator is not None:
+        if self._state_pool:
+            st, w = self.stats, self.swa_allocator
+            s = self._swa_sections.stats() if self._swa_sections else {}
+            st.state_snapshots = s.get("entries", 0)
+            st.state_slots_in_use = (
+                w.num_pages - w.num_free_pages - st.state_snapshots
+            )
+            st.state_snapshot_hits_total = s.get("hits", 0)
+            st.state_snapshot_misses_total = s.get("misses", 0)
+            st.state_snapshot_captures_total = s.get("captures", 0)
+            st.state_snapshot_evictions_total = s.get("evictions", 0)
+            st.ssm_update_rows_total = self.runner.ssm_update_rows_total
+            st.ssm_scan_tokens_total = self.runner.ssm_scan_tokens_total
+        elif self.swa_allocator is not None:
             self.stats.swa_ring_usage = self.swa_allocator.usage()
             self.stats.swa_ring_pages = self.swa_allocator.num_pages
             if self._swa_sections is not None:

@@ -45,6 +45,7 @@ def step_fields(
     sample_cols: int,
     ring: bool,
     lora: bool,
+    state: bool = False,
 ) -> list[tuple[str, tuple[int, ...], type]]:
     """(name, shape, dtype) of one step program's host inputs.
 
@@ -54,7 +55,7 @@ def step_fields(
     stream bucket T of a flat step. ``sample_cols`` is the unified and flat
     steps' sample width S; ``ring`` adds the sliding layers' second page
     table (and the flat step's second write plan), ``lora`` the adapter
-    slots.
+    slots, ``state`` (flat only) each row's slot of the state pool.
     """
     mp = max_pages
     if kind in ("prefill", "verify"):
@@ -118,6 +119,8 @@ def step_fields(
         raise ValueError(f"no step program of kind {kind!r}")
     if ring:
         spec.append(("swa_table", (B, mp), np.int32))
+    if state and kind == "flat":
+        spec.append(("state_slots", (B,), np.int32))
     if lora:
         spec.append(("lora", (B,), np.int32))
     return spec

@@ -46,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from llmd_tpu import ops
-from llmd_tpu.config import EngineConfig, swa_ring_spec
+from llmd_tpu.config import EngineConfig, state_slot_spec, swa_ring_spec
 from llmd_tpu.engine import payload
 from llmd_tpu.engine.sampler import (
     SamplingInputs,
@@ -57,6 +57,7 @@ from llmd_tpu.engine.scheduler import ScheduledSeq
 from llmd_tpu.models import llama
 from llmd_tpu.models.common import StepInput
 from llmd_tpu.obs import profiling
+from llmd_tpu.ops import ssm as ssm_ops
 from llmd_tpu.parallel import distributed as dist
 from llmd_tpu.parallel.mesh import MeshContext, kv_cache_spec, shard_params
 
@@ -334,6 +335,7 @@ class ModelRunner:
         self.config = config
         self.cfg = config.model
         config.check_sparse_attention()
+        config.check_state_space()
         self.ctx = mesh_ctx
         self.max_pages = config.cache.max_pages_per_seq(self.cfg.max_model_len)
         self.page = config.cache.page_size
@@ -402,7 +404,10 @@ class ModelRunner:
         # second, smaller pool indexed through a ring-view page table.
         self.swa = self._swa_spec_arg or swa_ring_spec(
             self.cfg, config.cache, config.scheduler
-        )
+        ) or state_slot_spec(self.cfg, config.scheduler)
+        # State-space layers (``state_pool``): the second pool is the STATE
+        # pool, a slot a sequence (ops/ssm.py::StatePool), under the same
+        # argument, the same donation and the same device copies as the ring.
         self.kv_cache = self._alloc_kv()
         self.kv_swa = self._alloc_swa()
         # Bytes of one page id over all of a pool's layers (every plane an
@@ -534,6 +539,16 @@ class ModelRunner:
         self.sparse_unbound_tokens_total = 0
         self.indexer_keys_scored_total = 0
         self.indexer_keys_written_total = 0
+        # State-space layers (EngineStats fields of the same names): decode
+        # rows updated and prefill tokens scanned, x the mixer layers.
+        self.ssm_update_rows_total = 0
+        self.ssm_scan_tokens_total = 0
+
+    @property
+    def state_pool(self) -> bool:
+        """The second pool holds recurrent state, a slot a sequence (a model
+        with state-space layers), not the sliding layers' ring pages."""
+        return self.swa is not None and self.swa.recurrent
 
     def _build_programs(self) -> None:
         """(Re)build every jitted forward program. Called at init and
@@ -780,9 +795,24 @@ class ModelRunner:
         ))
 
     def _alloc_swa(self):
-        """The sliding-window ring pool (None unless swa_ring resolves)."""
+        """The second pool: the sliding-window ring pool, or the state pool
+        of a model with state-space layers (None where there is neither)."""
         if self.swa is None:
             return None
+        if self.state_pool:
+            m, rep = self.cfg, self.ctx.replicated
+            # One slot past the allocator's: the scan's scratch (ops/ssm.py).
+            Lm, S = len(self.swa.state_layers), self.swa.num_swa_blocks + 1
+            return ssm_ops.StatePool(
+                ssm=jnp.zeros(
+                    (Lm, S, m.mamba_n_heads, m.mamba_d_head, m.mamba_d_state),
+                    jnp.float32, device=rep,
+                ),
+                conv=jnp.zeros(
+                    (Lm, S, m.mamba_d_conv - 1, m.mamba_conv_dim),
+                    jnp.dtype(m.dtype), device=rep,
+                ),
+            )
         return self._alloc_pool(len(self.swa.swa_layers), self.swa.num_swa_blocks)
 
     def _alloc_pool(self, num_layers: int, num_blocks: int):
@@ -1262,6 +1292,7 @@ class ModelRunner:
             verify_row = f["kind"] == _KIND_VERIFY  # [B] bool
             page_table = f["page_table"]  # [B, max_pages] COMPACT per-row table
             swa_table = f.get("swa_table")  # [B, max_pages] ring view, or None
+            state_slots = f.get("state_slots")  # [B] state-pool slots, or None
             lora_ids = f.get("lora")  # [B] i32 adapter slots, or None
             temperature, top_k, top_p = f["temp"], f["top_k"], f["top_p"]
             seeds = f["seeds"]  # [B, S]
@@ -1293,6 +1324,12 @@ class ModelRunner:
                 swa_page_table=swa_table,
                 token_rows=row_of,
                 flat_runs=((wsrc, woff, wcnt), wphys, wphys_swa),
+                # A segment's "starts at position 0" comes from pos0: the
+                # payload carries the slots alone.
+                state_rows=None if state_slots is None else ssm_ops.state_rows(
+                    state_slots, row_start, qlens, pos0, f["kind"], row_of,
+                    live,
+                ),
             )
             hidden, kv_cache, kv_swa, census = self._fwd_hidden(
                 params, kv_cache, kv_swa, inp, census
@@ -1613,12 +1650,9 @@ class ModelRunner:
         sliding-section capture/seed; no host bytes move)."""
 
         def copy(kv, src, dst):
-            if isinstance(kv, tuple):
-                return (
-                    kv[0].at[:, dst].set(kv[0][:, src]),
-                    kv[1].at[:, dst].set(kv[1][:, src]),
-                )
-            return kv.at[:, dst].set(kv[:, src])
+            # Every leaf of a pool (an int8 pool's scales, a state pool's
+            # conv state) carries its page or slot ids on axis 1.
+            return jax.tree.map(lambda a: a.at[:, dst].set(a[:, src]), kv)
 
         return jax.jit(copy, donate_argnums=(0,))
 
@@ -1851,8 +1885,9 @@ class ModelRunner:
         # laid out by as well (engine/payload.py).
         return payload.step_fields(
             _STEP_KINDS[op], B, QK, max_pages=self.max_pages, page=self.page,
-            sample_cols=self.unified_s, ring=self.swa is not None,
-            lora=bool(self.cfg.num_lora_adapters),
+            sample_cols=self.unified_s,
+            ring=self.swa is not None and not self.state_pool,
+            lora=bool(self.cfg.num_lora_adapters), state=self.state_pool,
         )
 
     def _layout(self, op: int, B: int, QK: int) -> payload.PayloadLayout:
@@ -2475,6 +2510,11 @@ class ModelRunner:
         over a throwaway KV scratch pool — embeddings never touch the
         serving cache, so this is safe to run concurrently with the step
         loop (params are read-only)."""
+        if self.state_pool:
+            raise NotImplementedError(
+                f"{self.cfg.name}: the embedding program has no state pool; "
+                "state-space layers run on the flat step only"
+            )
         if not prompts:
             return np.zeros((0, self.cfg.hidden_size), np.float32)
         maxlen = max(len(p) for p in prompts)
@@ -2885,7 +2925,8 @@ class ModelRunner:
         return out
 
     _ROW_SLICE_NAMES = (
-        "page_table", "swa_table", "temp", "top_k", "top_p", "lora",
+        "page_table", "swa_table", "state_slots", "temp", "top_k", "top_p",
+        "lora",
     )
 
     def _subset_staged_verify(
@@ -3034,7 +3075,13 @@ class ModelRunner:
             "temp": temp, "top_k": top_k, "top_p": top_p,
             "seeds": np.zeros((B, S), np.uint32),
         }
-        if self.swa is not None:
+        if self.state_pool:
+            # A row's slot of the state pool (its sequence's "ring" of one).
+            arrays["state_slots"] = np.zeros(B, np.int32)
+            arrays["state_slots"][:n] = [
+                s.request.swa_block_ids[0] for s in row_seqs
+            ]
+        elif self.swa is not None:
             arrays["swa_table"] = self._swa_table(row_seqs, B)
         if self.cfg.num_lora_adapters:
             arrays["lora"] = self._lora_array(row_seqs, B)
@@ -3147,6 +3194,11 @@ class ModelRunner:
                 self.indexer_keys_written_total += w
         self._overwrite_seeded_rows(a["seeds"], staged.row_seqs, staged.S)
         self.live_tokens_total += t
+        if self.state_pool:
+            Lm = len(self.swa.state_layers)
+            n_dec = len(staged.row_seqs) - n_pre_rows
+            self.ssm_update_rows_total += n_dec * Lm
+            self.ssm_scan_tokens_total += (t - n_dec) * Lm
         if staged.flat:
             # Pad rows carry row_start = total so the cu_q_lens boundary
             # array the device searchsorts stays monotonic.

@@ -77,6 +77,7 @@ class EngineScheduler:
         swa_allocator: PageAllocator | None = None,
         swa_ring_pages: int = 0,
         swa_chunk_tokens: int = 0,
+        state_aligned: bool = False,
     ) -> None:
         self.config = scheduler_config
         self.cache_config = cache_config
@@ -89,6 +90,13 @@ class EngineScheduler:
         self.swa_allocator = swa_allocator
         self.swa_ring_pages = swa_ring_pages
         self.swa_chunk_tokens = swa_chunk_tokens
+        # A model with state-space layers: the "ring" is ONE slot of the
+        # state pool (config.StateSlotSpec), allocated, reclaimed and freed
+        # as a ring is. A recurrent state can be retained only AT the token
+        # it stands at, so a prefill chunk ENDS at the page boundaries a
+        # snapshot is wanted at (_chunk_for) and the capture hooks fire when
+        # the computed count stands there, not when it has passed.
+        self.state_aligned = state_aligned
         self.max_model_len = max_model_len
         # Ordered by (-priority, arrival_time): higher priority first, FCFS
         # within a priority class (the InferenceObjective priority semantics,
@@ -314,11 +322,9 @@ class EngineScheduler:
         for req in mid_prefill:
             if req.status is not RequestStatus.RUNNING or budget <= 0:
                 continue
-            chunk = min(
-                req.num_prompt_tokens - req.num_dispatched_tokens, budget
+            chunk = self._chunk_for(
+                req, req.num_prompt_tokens - req.num_dispatched_tokens, budget
             )
-            if self.swa_chunk_tokens:
-                chunk = min(chunk, self.swa_chunk_tokens)
             if chunk <= 0:
                 continue
             if not self._ensure_pages(req, chunk):
@@ -357,9 +363,7 @@ class EngineScheduler:
             if req.num_computed_tokens == 0:
                 self._apply_prefix_cache(req)
             remaining = req.num_prompt_tokens - req.num_dispatched_tokens
-            chunk = min(remaining, budget)
-            if self.swa_chunk_tokens:
-                chunk = min(chunk, self.swa_chunk_tokens)
+            chunk = self._chunk_for(req, remaining, budget)
             if chunk <= 0:
                 break
             if not self.config.enable_chunked_prefill and chunk < remaining:
@@ -401,6 +405,37 @@ class EngineScheduler:
         )
 
         return ScheduledBatch(prefills=prefills, decodes=decodes)
+
+    def _prompt_boundary(self, req: Request) -> int:
+        """The prompt's last full page, in tokens (the last token is always
+        computed, for its logits)."""
+        page = self.cache_config.page_size
+        return (req.num_prompt_tokens - 1) // page * page
+
+    def _snapshot_boundaries(self, req: Request) -> tuple[int, ...]:
+        """The token counts a state-space sequence's prefill must STAND at
+        for a snapshot to be taken: its prompt's last full page, and the end
+        of the run of full pages it was refused at admission."""
+        ends = (self._prompt_boundary(req),)
+        if req.swa_capture is not None:
+            ends += (req.swa_capture[0] * self.cache_config.page_size,)
+        return ends
+
+    def _chunk_for(self, req: Request, remaining: int, budget: int) -> int:
+        """The next prefill chunk of ``req``: what is left of its prompt,
+        within the step's budget and the ring's chunk cap, and, where the
+        per-sequence state is recurrent, not past the next snapshot
+        boundary (the <= page - 1 tokens left behind a prompt's last full
+        page are one more chunk)."""
+        chunk = min(remaining, budget)
+        if self.swa_chunk_tokens:
+            chunk = min(chunk, self.swa_chunk_tokens)
+        if self.state_aligned and self.allocator.enable_prefix_caching:
+            at = req.num_dispatched_tokens
+            for end in self._snapshot_boundaries(req):
+                if at < end:
+                    chunk = min(chunk, end - at)
+        return chunk
 
     def _note_admitted(self, req: Request) -> None:
         req.status = RequestStatus.RUNNING
@@ -480,11 +515,9 @@ class EngineScheduler:
         for req in batch_prefill:
             if req.status is not RequestStatus.RUNNING or budget <= 0:
                 continue
-            chunk = min(
-                req.num_prompt_tokens - req.num_dispatched_tokens, budget
+            chunk = self._chunk_for(
+                req, req.num_prompt_tokens - req.num_dispatched_tokens, budget
             )
-            if self.swa_chunk_tokens:
-                chunk = min(chunk, self.swa_chunk_tokens)
             if chunk <= 0:
                 continue
             if not self._ensure_pages(req, chunk):
@@ -511,9 +544,7 @@ class EngineScheduler:
             if req.num_computed_tokens == 0:
                 self._apply_prefix_cache(req)
             remaining = req.num_prompt_tokens - req.num_dispatched_tokens
-            chunk = min(remaining, budget)
-            if self.swa_chunk_tokens:
-                chunk = min(chunk, self.swa_chunk_tokens)
+            chunk = self._chunk_for(req, remaining, budget)
             if chunk <= 0:
                 break
             if not self.config.enable_chunked_prefill and chunk < remaining:
@@ -556,7 +587,7 @@ class EngineScheduler:
         if self.swa_ring_pages:
             # Ring engines do HYBRID hits only: a full-pool hit is usable
             # solely when a retained sliding section seeds the fresh ring
-            # (engine SwaSectionCache) — a bare full-pool shortcut here
+            # (engine RetainedStateCache) — a bare full-pool shortcut here
             # would skip sliding-layer KV the ring never got and silently
             # decode garbage. A noted miss is not probed again.
             if self.hybrid_hit_hook is not None and req.swa_capture is None:
@@ -762,16 +793,32 @@ class EngineScheduler:
             req.num_computed_tokens += seq.num_tokens
             if req.is_batch:
                 self.batch_tokens += seq.num_tokens
+            page = self.cache_config.page_size
             if (
                 req.swa_capture is not None
                 and self.prefill_passed_hook is not None
-                and req.num_computed_tokens
-                >= req.swa_capture[0] * self.cache_config.page_size
+                and req.num_computed_tokens >= req.swa_capture[0] * page
             ):
-                self.prefill_passed_hook(req)
+                # A recurrent state is the run's only AT its end (the chunk
+                # was cut there); one that has passed it is dropped.
+                if (
+                    not self.state_aligned
+                    or req.num_computed_tokens == req.swa_capture[0] * page
+                ):
+                    self.prefill_passed_hook(req)
                 req.swa_capture = None
+            if (
+                self.state_aligned
+                and self.prefill_complete_hook is not None
+                and req.num_computed_tokens == self._prompt_boundary(req)
+            ):
+                # The state stands at the prompt's last full page.
+                self.prefill_complete_hook(req)
             if req.in_decode:  # this chunk completed the prompt -> 1st token
-                if self.prefill_complete_hook is not None:
+                if (
+                    self.prefill_complete_hook is not None
+                    and not self.state_aligned
+                ):
                     # Hybrid-APC capture: the ring still holds the
                     # prompt's trailing window right now.
                     self.prefill_complete_hook(req)

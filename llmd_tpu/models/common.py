@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -52,11 +53,31 @@ class StepInput:
     # [K, T + 2*page, 2D] token slab (src = page + t0 - off, so slab row
     # off+j holds token t0+j). phys_swa is None without a SWA ring.
     flat_runs: tuple | None = None
+    # State-space layers (flattened layout only): the step's packing as
+    # they read it, derived from the per-row metadata and the rows' slots
+    # of the state pool (ops/ssm.py::StateRows). None for every other model.
+    state_rows: tuple | None = None
 
     @property
     def valid(self) -> jax.Array:  # [B, Q] bool
         B, Q = self.token_ids.shape
         return jnp.arange(Q)[None, :] < self.query_lens[:, None]
+
+
+class MixerKind(NamedTuple):
+    """A KIND of layer, by its mixer, for a model whose layers differ in it
+    (``layer_types``): what ``llama.forward_hidden`` dispatches a layer on.
+    A kind declares the weights it stacks, the cache its layers carry
+    through the layer scan and index by their plane, and its read and write
+    of that cache. ``init`` / ``mix`` None: the attention leaves of
+    ``llama.init_params`` and the attention body of ``llama.layer_body``."""
+
+    stack: str    # params[stack]: the kind's weights, a layer's at its plane
+    pool: int     # its cache: 0 the paged KV pool, 1 the per-sequence pool
+    # (cfg, n, mk, dtype) -> the n stacked mixers' leaves
+    init: Callable | None = None
+    # (h, lp, cache, plane, inp, cfg, mesh) -> (mixer output, cache)
+    mix: Callable | None = None
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 
 from llmd_tpu.config import ModelConfig
 from llmd_tpu.models.common import (
-    StepInput, apply_rope, layer_norm, param_dtype, pdot, rms_norm,
+    MixerKind, StepInput, apply_rope, layer_norm, param_dtype, pdot, rms_norm,
     rope_tables,
 )
 from llmd_tpu.models.moe import (
@@ -55,16 +55,9 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
             scale = shape[-2] ** -0.5 if len(shape) >= 2 else 1.0
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dt)
 
-    def layer_stack(n: int, moe: bool, prefix: str = "") -> dict[str, jax.Array]:
-        """n stacked layers: attention (MLA or GQA) + dense-MLP or MoE."""
-
-        def mkp(name, shape, scale=None):
-            return mk(prefix + name, shape, scale)
-
-        layers: dict[str, jax.Array] = {
-            "input_norm": jnp.ones((n, H), dt),
-            "post_norm": jnp.ones((n, H), dt),
-        }
+    def mixer_weights(n: int, mkp) -> dict[str, jax.Array]:
+        """n stacked attention mixers (MLA or GQA, with the model's extras)."""
+        layers: dict[str, jax.Array] = {}
         if cfg.is_mla:
             nope, rope, vd = (
                 cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
@@ -119,6 +112,23 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
             # (random B would perturb outputs for adapter-named requests).
             layers["lb_q"] = jnp.zeros((n, A1, r, Nq * D), dt)
             layers["lb_v"] = jnp.zeros((n, A1, r, K * D), dt)
+        return layers
+
+    def layer_stack(n: int, moe: bool, prefix: str = "",
+                    attention: bool = True) -> dict[str, jax.Array]:
+        """n stacked layers: attention (MLA or GQA) + dense-MLP or MoE.
+        ``attention`` False leaves the mixer's weights out (a model whose
+        layers differ in their mixer stacks those per kind)."""
+
+        def mkp(name, shape, scale=None):
+            return mk(prefix + name, shape, scale)
+
+        layers: dict[str, jax.Array] = {
+            "input_norm": jnp.ones((n, H), dt),
+            "post_norm": jnp.ones((n, H), dt),
+        }
+        if attention:
+            layers.update(mixer_weights(n, mkp))
         if moe:
             # The router scores every expert; the leaves hold the experts
             # this rank holds (all of them unless cfg says otherwise).
@@ -155,9 +165,19 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     n_dense = cfg.first_dense_layers if cfg.is_moe else 0
     params: dict = {
         "embed": mk("embed", (V, H), scale=0.02),
-        "layers": layer_stack(L - n_dense, moe=cfg.is_moe),
+        "layers": layer_stack(
+            L - n_dense, moe=cfg.is_moe, attention=not cfg.state_space
+        ),
         "final_norm": jnp.ones((H,), dt),
     }
+    kinds = mixer_kinds(cfg)
+    for kind in dict.fromkeys(k for k in kinds if k is not None):
+        # A per-kind stack beside the one every layer shares (norms, router,
+        # experts): a layer indexes its kind's by its plane.
+        n = kinds.count(kind)
+        params[kind.stack] = (
+            kind.init(cfg, n, mk, dt) if kind.init else mixer_weights(n, mk)
+        )
     if n_dense:
         params["dense_layers"] = layer_stack(n_dense, moe=False, prefix="dense_")
     if not cfg.tie_word_embeddings:
@@ -181,12 +201,32 @@ def _mlp(h: jax.Array, lp: dict) -> jax.Array:
     return pdot(gate * pdot(h, lp, "w_up"), lp, "w_down")
 
 
+# The attention kind of a model whose layers differ in their mixer: weights
+# and body are this file's (``init_params``, ``layer_body``).
+ATTENTION = MixerKind(stack="attn_layers", pool=0)
+
+
+def mixer_kinds(cfg: ModelConfig) -> tuple[MixerKind | None, ...]:
+    """Each layer's mixer kind; None throughout for a model of one kind
+    (attention, whose weights lie in the shared stack)."""
+    if not cfg.state_space:
+        return (None,) * cfg.num_layers
+    from llmd_tpu.models import mamba
+
+    by_type = {"mamba": mamba.KIND, "attention": ATTENTION}
+    return tuple(by_type[t] for t in cfg.layer_types)
+
+
 def _scan_period(kinds: tuple[int, ...]) -> int | None:
     """Smallest period c <= 4 of a layer-kind pattern (None if aperiodic).
 
     gpt-oss alternates sliding/full every layer (c=2); periodic patterns
     let the hybrid-pool scan run over CYCLES with the pool choice static
     per sub-layer — no lax.cond, so XLA keeps both pool carries in place.
+    The bound c <= 4 is the longest cycle whose body is worth unrolling: a
+    longer period (one attention layer in ten, or a pattern no deeper than
+    its period) falls to the aperiodic branch, one scan per homogeneous run
+    (period 10 at depth 10: three runs, 5 + 1 + 4).
     """
     n = len(kinds)
     for c in (2, 3, 4):
@@ -267,12 +307,19 @@ def forward_hidden(
     B, Q = inp.token_ids.shape
     D, Nq, K = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
     x = params["embed"][inp.token_ids]  # [B, Q, H]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     # one rope table for all layers (hoisted out of the scan); MLA rotates
     # only its rope sub-dim
     rope_dim = cfg.qk_rope_head_dim if cfg.is_mla else D
     cos, sin = rope_tables(inp.positions, rope_dim, cfg.rope_theta, cfg.rope_scaling)
     valid = inp.valid
-    sm_scale = D**-0.5
+    sm_scale = cfg.sm_scale
+    res_mult = cfg.residual_multiplier
+
+    def _res(y):
+        """A residual branch under the model's multiplier (1 for most)."""
+        return y if res_mult == 1.0 else y * res_mult
 
     # DBO also requires the HALF batch to stay dp-divisible, or the split
     # would silently demote attention from the sharded Pallas kernel to
@@ -284,6 +331,11 @@ def forward_hidden(
     # entry points below. DBO keeps the bucketed layout only (its
     # half-batch table slicing assumes per-row tables).
     flat = inp.token_rows is not None
+    if cfg.state_space and (not flat or inp.state_rows is None or kv_swa is None):
+        raise NotImplementedError(
+            f"{cfg.name}: state-space layers run on the flat step only, with "
+            "the state pool and the rows' slots; this program has no state"
+        )
     if cfg.sparse_attention and not flat:
         raise NotImplementedError(
             f"{cfg.name}: learned sparse attention runs on the flat step "
@@ -353,10 +405,10 @@ def forward_hidden(
               moe_layer=None):
         """Post-attention chain of one (micro)batch slice: residual +
         post-norm + FFN/MoE + residual. Returns (x, census_delta)."""
-        x_sl = x_sl + attn_sl
+        x_sl = x_sl + _res(attn_sl)
         h2 = rms_norm(x_sl, lp["post_norm"], cfg.rms_norm_eps)
         y, cd = _ffn(h2, lp, use_moe, cap_scale, moe_layer)
-        return x_sl + y, cd
+        return x_sl + _res(y), cd
 
     def _tails_dbo(pairs):
         """Concatenate DBO half-chain _tail results; merge census deltas."""
@@ -368,18 +420,24 @@ def forward_hidden(
 
     def layer_body(x, cache, lp, layer_idx, use_moe: bool, window=None,
                    table=None, run_phys=None, moe_layer=None, rotate=None,
-                   attn_kind=None):
+                   attn_kind=None, kind: MixerKind | None = None):
         """One decoder layer; returns (x, cache, census_delta | None).
         ``layer_idx`` is the layer's plane of ``cache``; ``moe_layer`` its
         index into ``params["layers"]``, whose expert leaves ``lp`` holds
         whole (the dense prefix shifts one against the other). ``rotate``
         (a bool, traced or not; None = yes) says whether this layer applies
         RoPE; ``attn_kind`` ("window" | "full", where the caller knows it at
-        trace time) names the flat attention call ``llmd.attn.<kind>``."""
+        trace time) names the flat attention call ``llmd.attn.<kind>``.
+        ``kind`` (a model whose layers differ in their mixer): the layer's
+        kind, whose ``mix`` stands for the attention of this body and whose
+        cache ``cache`` is."""
         if table is None:
             table = inp.page_table
         h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
-        if cfg.is_mla:
+        if kind is not None and kind.mix is not None:
+            out, cache = kind.mix(h, lp, cache, layer_idx, inp, cfg, mesh)
+            x = x + _res(out)
+        elif cfg.is_mla:
             from llmd_tpu.models.mla import mla_attention, mla_read, mla_write
 
             if use_dbo:
@@ -406,7 +464,7 @@ def forward_hidden(
                 h, lp, cache, layer_idx, inp, cfg, cos, sin,
                 world_size=world_size, mesh=mesh,
             )
-            x = x + attn_out
+            x = x + _res(attn_out)
         else:
             if "wqkv" in lp:  # fused q|k|v (runner._maybe_fuse; lossless)
                 qkv = pdot(h, lp, "wqkv")
@@ -439,11 +497,12 @@ def forward_hidden(
                 k = rms_norm(k, lp["attn_k_norm"], cfg.rms_norm_eps)
             if rotate is None:
                 cos_l, sin_l = cos, sin
-            else:  # the identity rotation where the layer has none
+            elif rotate is not False:  # the identity where the layer has none
                 cos_l = jnp.where(rotate, cos, 1.0)
                 sin_l = jnp.where(rotate, sin, 0.0)
-            q = apply_rope(q, cos_l, sin_l)
-            k = apply_rope(k, cos_l, sin_l)
+            if rotate is not False:  # False: no layer of the model rotates
+                q = apply_rope(q, cos_l, sin_l)
+                k = apply_rope(k, cos_l, sin_l)
             v = v.reshape(B, Q, K, D)
             if kv_rep > 1:
                 k = jnp.repeat(k, kv_rep, axis=2)
@@ -537,7 +596,7 @@ def forward_hidden(
                     sm_scale, world_size=world_size, mesh=mesh, window=window,
                     sinks=sinks,
                 )
-            x = x + _project(attn, B)
+            x = x + _res(_project(attn, B))
         # attention residual already applied above; _tail adds 0
         x, cd = _tail(x, 0.0, lp, use_moe, moe_layer=moe_layer)
         return x, cache, cd
@@ -559,6 +618,12 @@ def forward_hidden(
     # their own pool (planes count within the group) via the ring table.
     ring = kv_swa is not None and sliding
     kinds = tuple(1 if (ring and w > 0) else 0 for w in win_static)
+    layer_kinds = mixer_kinds(cfg)
+    per_kind = layer_kinds[0] is not None
+    if per_kind:
+        # Each mixer kind names its cache (the second pool is the state
+        # pool of the state-space mixers; attention keeps the paged pool).
+        kinds = tuple(k.pool for k in layer_kinds)
     plane, counts = [], [0, 0]
     for knd in kinds:
         plane.append(counts[knd])
@@ -578,6 +643,7 @@ def forward_hidden(
     # signature (and compile cache) unchanged.
     rot_static = cfg.layer_rotates
     rotates = None if all(rot_static) else jnp.asarray(rot_static, bool)
+    no_rope = not any(rot_static)
 
     mixes_kinds = sliding and len({w > 0 for w in win_static}) == 2
 
@@ -627,7 +693,8 @@ def forward_hidden(
         ])
 
     def scan_group(x, cache, census, table, lp, plane_ids, layer_ids, wins,
-                   run_phys=None, rots=None, attn_kind=None):
+                   run_phys=None, rots=None, attn_kind=None,
+                   kind: MixerKind | None = None):
         """One homogeneous run of layers sharing a pool/table. The census
         delta rides the scan as a per-layer OUTPUT (stacked then reduced)
         so the carry signature — and the compile cache — only changes
@@ -636,7 +703,10 @@ def forward_hidden(
         id (what a scan does with its ``xs``); a static slice of the
         leaves in front of the scan would be a copy of the run's weights
         in every step (5 ms of a 28 ms decode step at 6,144 wide, PERF.md
-        section 6, PR 33)."""
+        section 6, PR 33). ``kind``: the run's MIXER kind (a model whose
+        layers differ in their mixer), whose own stack is indexed by the
+        layer's plane as the shared stack is by its id."""
+        kind_lp = None if kind is None else params[kind.stack]
 
         def fn(carry, scanned):
             x, cache = carry
@@ -646,10 +716,17 @@ def forward_hidden(
                     k: jax.lax.dynamic_index_in_dim(a, lid, 0, keepdims=False)
                     for k, a in lp_all.items()
                 }
+            if kind_lp is not None:
+                lp_s = {**lp_s, **{
+                    k: jax.lax.dynamic_index_in_dim(a, pid, 0, keepdims=False)
+                    for k, a in kind_lp.items()
+                }}
             x, cache, cd = layer_body(
                 x, cache, {**lp_s, **experts}, pid, use_moe=cfg.is_moe,
                 window=per.get("window"), table=table, run_phys=run_phys,
-                moe_layer=lid, rotate=per.get("rotate"), attn_kind=attn_kind,
+                moe_layer=lid,
+                rotate=False if no_rope else per.get("rotate"),
+                attn_kind=attn_kind, kind=kind,
             )
             return (x, cache), cd
 
@@ -663,13 +740,13 @@ def forward_hidden(
             census = _census_merge(census, _reduce_census(cds))
         return x, cache, census
 
-    if len(set(scan_kinds)) <= 1:
+    if len(set(scan_kinds)) <= 1 and not per_kind:
         g = scan_kinds[0] if scan_kinds else 0
         x, caches[g], census = scan_group(
             x, caches[g], census, tables[g], lp_all, plane_arr, layer_arr,
             win_arr, run_physes[g], rot_arr,
         )
-    elif (c := _scan_period(scan_kinds)) is not None:
+    elif not per_kind and (c := _scan_period(scan_kinds)) is not None:
         # Hybrid periodic pattern (gpt-oss alternating): scan over CYCLES
         # of c layers; within a cycle the pool choice is static per
         # sub-layer, so both pool carries update in place every step.
@@ -719,9 +796,11 @@ def forward_hidden(
             sl = slice(off, off + ln)
             x, caches[g], census = scan_group(
                 x, caches[g], census, tables[g], None,
-                plane_arr[sl], layer_arr[sl], win_arr[sl] if g else None,
-                run_physes[g], None if rot_arr is None else rot_arr[sl],
-                kind_name(n_dense + off),
+                plane_arr[sl], layer_arr[sl],
+                win_arr[sl] if g and win_arr is not None else None,
+                run_physes[g],
+                None if rot_arr is None or no_rope else rot_arr[sl],
+                kind_name(n_dense + off), layer_kinds[n_dense + off],
             )
             off += ln
 
@@ -740,5 +819,9 @@ def forward_hidden(
 def compute_logits(params: dict, hidden: jax.Array, cfg: ModelConfig) -> jax.Array:
     """Project hidden states [N, H] -> logits [N, V] (f32 for sampling)."""
     if cfg.tie_word_embeddings:
-        return (hidden @ params["embed"].T).astype(jnp.float32)
-    return pdot(hidden, params, "lm_head").astype(jnp.float32)
+        logits = (hidden @ params["embed"].T).astype(jnp.float32)
+    else:
+        logits = pdot(hidden, params, "lm_head").astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits

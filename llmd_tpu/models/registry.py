@@ -223,6 +223,54 @@ def _tiny_exaone() -> ModelConfig:
     )
 
 
+# granitemoehybrid: one attention layer in ten, at index 5 of each period.
+_MMMMMAMMMM = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+
+
+@register_model("granite-4.0-h-small")
+def _granite_4_h_small() -> ModelConfig:
+    """granite-4.0-h-small (HF ibm-granite/granite-4.0-h-small,
+    ``granitemoehybrid``, 32B-A9B): 36 Mamba-2 mixers (128 heads x 64, state
+    128, conv 4) and 4 attention layers (GQA 32/8 x 128, NO positional
+    encoding, softmax scale 1/128), every layer's FFN 72 experts top-10 of
+    width 768 plus a shared GLU of 1,536, muP-style multipliers, tied
+    embedding."""
+    return ModelConfig(
+        name="granite-4.0-h-small", vocab_size=100352, hidden_size=4096,
+        intermediate_size=768, num_layers=40, num_heads=32, num_kv_heads=8,
+        head_dim=128, rope_theta=10000.0, max_model_len=131072,
+        rms_norm_eps=1e-5, tie_word_embeddings=True,
+        layer_types=_MMMMMAMMMM * 4, rope_layer_types=(),
+        attention_multiplier=0.0078125, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=16.0,
+        mamba_n_heads=128, mamba_d_head=64, mamba_d_state=128,
+        mamba_n_groups=1, mamba_d_conv=4,
+        num_experts=72, num_experts_per_tok=10, moe_intermediate_size=768,
+        shared_expert_intermediate_size=1536, norm_topk_prob=True,
+    )
+
+
+@register_model("tiny-granite-hybrid")
+def _tiny_granite_hybrid() -> ModelConfig:
+    """granite-4.0-h-small's architecture in miniature (CPU tests and the
+    benchmark's rehearsal): the same pattern of ten, 4 mixer heads x 8 with
+    state 16 and ONE group, GQA 4/2 without rope at the model's own scale,
+    top-2 of 8 experts with a shared GLU, the three multipliers, and a held
+    share: this rank holds experts 0-3 of the 8 the router scores."""
+    return tiny_model_config(
+        name="tiny-granite-hybrid", num_layers=10, max_model_len=512,
+        tie_word_embeddings=True,
+        layer_types=_MMMMMAMMMM, rope_layer_types=(),
+        attention_multiplier=1.0 / 16, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=16.0,
+        mamba_n_heads=4, mamba_d_head=8, mamba_d_state=16,
+        mamba_n_groups=1, mamba_d_conv=4,
+        num_experts=8, num_experts_per_tok=2, moe_intermediate_size=32,
+        shared_expert_intermediate_size=64, norm_topk_prob=True,
+        held_experts=4, held_experts_first=0,
+    )
+
+
 @register_model("mixtral-8x7b")
 def _mixtral_8x7b() -> ModelConfig:
     return ModelConfig(

@@ -112,6 +112,15 @@ PARAM_SPECS: dict[str, P] = {
     "wi_w": P(None, None, None),    # [L, H, J] per-head score weights
     "wi_k_norm": P(None, None),     # [L, Di] LayerNorm weight on the key
     "wi_k_norm_b": P(None, None),   # [L, Di] and its bias
+    # State-space mixers (models/mamba.py; one device only; replicated).
+    "m_in": P(None, None, None),      # [Lm, H, 2*d_in + 2N + heads]
+    "m_conv_w": P(None, None, None),  # [Lm, K, C]
+    "m_conv_b": P(None, None),        # [Lm, C]
+    "m_A_log": P(None, None),         # [Lm, heads]
+    "m_dt_bias": P(None, None),
+    "m_D": P(None, None),
+    "m_norm": P(None, None),          # [Lm, d_in]
+    "m_out": P(None, None, None),     # [Lm, d_in, H]
     # LoRA: down-projections replicated (rank is tiny), up-projections
     # head-sharded like their base weights.
     "la_q": P(None, None, None, None),       # [L, A+1, H, r]

@@ -66,6 +66,10 @@ def render_metrics(
         gauges["kv_main_usage_perc"] = round(stats.kv_usage, 6)
         # Hybrid-APC section retention
         gauges["swa_sections"] = stats.swa_sections
+    if stats.state_slots_in_use or stats.state_snapshots:
+        # The state pool: slots that running sequences hold, snapshots kept.
+        gauges["state_slots_in_use"] = stats.state_slots_in_use
+        gauges["state_snapshots"] = stats.state_snapshots
     gauges["kv_offload_cpu_pages"] = stats.offload_pages
     gauges["kv_offload_fs_pages"] = stats.offload_fs_pages
     # Decode-pager residency (long-context.md): LIVE-sequence bytes in
@@ -194,6 +198,18 @@ def render_metrics(
         counters["swa_section_hits_total"] = stats.swa_section_hits_total
         counters["swa_section_misses_total"] = stats.swa_section_misses_total
         counters["swa_section_captures_total"] = stats.swa_section_captures
+    if stats.state_bytes_in_use_total:
+        # The state pool of a model with state-space layers: the retained-
+        # state cache's activity, the slots' bytes beside the pages'
+        # (kv_bytes_in_use_total counts pages only there), and what the
+        # mixers computed (rows and tokens x mixer layers).
+        counters["state_snapshot_hits_total"] = stats.state_snapshot_hits_total
+        counters["state_snapshot_misses_total"] = stats.state_snapshot_misses_total
+        counters["state_snapshot_captures_total"] = stats.state_snapshot_captures_total
+        counters["state_snapshot_evictions_total"] = stats.state_snapshot_evictions_total
+        counters["state_bytes_in_use_total"] = stats.state_bytes_in_use_total
+        counters["ssm_update_rows_total"] = stats.ssm_update_rows_total
+        counters["ssm_scan_tokens_total"] = stats.ssm_scan_tokens_total
     lines: list[str] = []
     if stats.kv_transfer_failures:
         # Per-(stage, policy) transfer-failure breakdown (llmd-family

@@ -238,6 +238,55 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                    "the ring; the full-attention layers' share plus the "
                    "rings with it; pages shared through the prefix cache "
                    "lower it."),
+        panel("State snapshot hit share",
+              [f"rate(llmd:state_snapshot_hits_total{M}[5m]) / "
+               f"(rate(llmd:state_snapshot_hits_total{M}[5m]) + "
+               f"rate(llmd:state_snapshot_misses_total{M}[5m]))",
+               f"llmd:state_snapshots{M}", f"llmd:state_slots_in_use{M}"],
+              legends=["hits / (hits + misses)", "snapshots retained",
+                       "running slots"],
+              desc="Models with state-space layers: a hit seeds a fresh "
+                   "slot of the state pool from a snapshot at the end of "
+                   "a run of full pages; a miss is a run the main pool "
+                   "offered and the engine refused for want of a snapshot "
+                   "there (the request prefills the span, 4-8k tokens in "
+                   "a session, and leaves the snapshot behind). A share "
+                   "that stays low = snapshots evicted before their "
+                   "prefix comes back: more of them (swa_section_cache)."),
+        panel("State snapshot activity /s",
+              [f"rate(llmd:state_snapshot_hits_total{M}[5m])",
+               f"rate(llmd:state_snapshot_misses_total{M}[5m])",
+               f"rate(llmd:state_snapshot_captures_total{M}[5m])",
+               f"rate(llmd:state_snapshot_evictions_total{M}[5m])"],
+              legends=["hits/s", "misses/s", "captures/s", "evictions/s"],
+              desc="captures with zero hits = the device copies buy "
+                   "nothing; evictions at the rate of captures = the "
+                   "retained-state cache is too small for the sessions "
+                   "it serves (every turn leaves one snapshot behind)."),
+        panel("State-space work /s",
+              [f"rate(llmd:ssm_update_rows_total{M}[5m])",
+               f"rate(llmd:ssm_scan_tokens_total{M}[5m])"],
+              legends=["decode rows x mixer layers /s",
+                       "prefill tokens x mixer layers /s"],
+              desc="What the state-space layers computed: a decode row "
+                   "reads and writes its whole slot state a layer "
+                   "(bandwidth), a prefill token goes through the "
+                   "chunked scan (compute). Scan tokens that stay high "
+                   "under a high snapshot hit share = turns prefill "
+                   "their own last answers again (ROADMAP M4 (a))."),
+        panel("State bytes held",
+              [f"rate(llmd:state_bytes_in_use_total{M}[5m]) / "
+               f"rate(llmd:engine_steps_total{M}[5m])",
+               f"(rate(llmd:kv_bytes_in_use_total{M}[5m]) + "
+               f"rate(llmd:state_bytes_in_use_total{M}[5m])) / "
+               f"rate(llmd:cached_tokens_total{M}[5m])"],
+              legends=["state-pool bytes held a step",
+                       "bytes a cached token, both pools"], unit="bytes",
+              desc="Bytes of state-pool slots held (running sequences' "
+                   "and retained snapshots' alike: a fixed size a slot, "
+                   "whatever the context), and with the attention "
+                   "layers' pages what a cached token costs. The state's "
+                   "share falls as contexts grow."),
         row("Million-token context tier (long-context.md)"),
         panel("Ring prefill steps /s",
               [f"rate(llmd:cp_ring_steps_total{M}[5m])"],

@@ -204,7 +204,32 @@ GMM_MODELS = {
     "deepseek-r1": (7168, 2048, 256),
     # one rank's 16 of the 128 experts (the held share)
     "k-exaone-236b-a23b": (6144, 2048, 16),
+    # one rank's 36 of the 72 experts
+    "granite-4.0-h-small": (4096, 768, 36),
 }
+# granite-4.0-h-small.1chip's attention layer (1 layer, 32 q / 8 kv heads) and
+# its state pool: 9 mixers x 97 slots of 128 heads x 64 x state 128.
+GRANITE = (1, 32, 8, 128)
+STATE_POOL = (9, 97, 128, 64, 128)
+
+
+def _ssm_update(rows):
+    from llmd_tpu.ops.ssm import ssm_update_pallas
+
+    _, _, H, P, N = STATE_POOL
+    return ssm_update_pallas, [
+        (STATE_POOL, F32), ((), I32), ((rows,), I32), ((), I32), ((rows, H), F32),
+        ((rows, H, P), F32), ((rows, N), F32), ((rows, N), F32),
+    ]
+
+
+def _ssm_slot(write: bool):
+    from llmd_tpu.ops.ssm import read_slot, write_slot
+
+    if write:
+        return (lambda p, l, s, v: write_slot(p, l, s, v, "pallas")), [
+            (STATE_POOL, F32), ((), I32), ((), I32), (STATE_POOL[2:], F32)]
+    return (lambda p, l, s: read_slot(p, l, s, "pallas")), [(STATE_POOL, F32), ((), I32), ((), I32)]
 
 
 CASES = {
@@ -223,6 +248,11 @@ CASES = {
     "decode_write-bf16": lambda d: _decode_write(LLAMA, BF16),
     "decode_write-int8": lambda d: _decode_write(LLAMA, I8),
     "mla_decode-deepseek-v2-lite": lambda d: _mla_decode(),
+    "flat_attention-granite-4.0-h-small": lambda d: _flat_attention(GRANITE, BF16, 528),
+    "flat_write-granite-4.0-h-small": lambda d: _flat_write(GRANITE, BF16, 528),
+    "ssm_update-granite-4.0-h-small": lambda d: _ssm_update(40),
+    "ssm_slot_read-granite-4.0-h-small": lambda d: _ssm_slot(False),
+    "ssm_slot_write-granite-4.0-h-small": lambda d: _ssm_slot(True),
     **{
         f"gmm-{name}": lambda d, m=m: _gmm(m[0], m[1], m[2], d)
         for name, m in GMM_MODELS.items()
@@ -314,3 +344,56 @@ def test_page_table_at_the_smem_bound_compiles(v5e):
         jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
         for shape, dtype in shapes
     ]).compile()
+
+
+def test_the_state_space_mixers_update_the_state_pool_in_place(v5e):
+    """granite-4.0-h-small.1chip's nine mixers over its state pool (97 slots:
+    32 running, 64 snapshots, the scan's scratch; 3.45 GiB), a saturated flat
+    step of 528 tokens in 40 rows, under the layer scan that carries the pool:
+    the Pallas calls are named after their scopes (the benchmark's readers
+    match ``^%llmd\\.ssm\\.``), both pools come out aliased, and nothing
+    copies the pool: the compiled program's temporaries stay under 0.5 GiB
+    (an XLA slice of the pool inside the scan made them 3.4 GiB: the compiler
+    re-laid the whole pool out, in and back, every step)."""
+    from llmd_tpu.models import mamba
+    from llmd_tpu.models.registry import get_model_config
+    from llmd_tpu.ops import ssm
+
+    cfg = get_model_config("granite-4.0-h-small", num_layers=10,
+                           layer_types=("mamba",) * 5 + ("attention",) + ("mamba",) * 4)
+    Lm, S, T, B = 9, 97, 528, 40
+    mesh = _one_chip_mesh(v5e)
+    weights = jax.eval_shape(lambda: mamba.init_layers(
+        cfg, Lm, lambda name, shape, scale=None: jnp.zeros(shape, BF16), BF16))
+
+    def step(weights, pool, h, slot, start, qlen, pos0, kind):
+        t = jnp.arange(T)
+        ends = start + qlen
+        row_of = jnp.clip(jnp.searchsorted(ends, t, side="right"), 0, B - 1).astype(I32)
+        rows = ssm.state_rows(slot, start, qlen, pos0, kind, row_of, t < ends[-1])
+
+        def layer(carry, l):
+            h, pool = carry
+            lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False), weights)
+            out, pool = mamba.mix(h, lp, pool, l, rows, cfg, mesh)
+            return (h + out, pool), None
+
+        return jax.lax.scan(layer, (h, pool), jnp.arange(Lm, dtype=I32))[0]
+
+    on_chip = SingleDeviceSharding(v5e)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)  # noqa: E731
+    pool = ssm.StatePool(
+        ssm=sds((Lm, S, cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state), F32),
+        conv=sds((Lm, S, cfg.mamba_d_conv - 1, cfg.mamba_conv_dim), BF16),
+    )
+    compiled = jax.jit(step, donate_argnums=1).lower(
+        jax.tree.map(lambda a: sds(a.shape, a.dtype), weights), pool,
+        sds((T, 1, cfg.hidden_size), BF16), *[sds((B,), I32)] * 5,
+    ).compile()
+    calls = {ln.split(" = ")[0].strip().rstrip(".0123456789")
+             for ln in compiled.as_text().splitlines() if "tpu_custom_call" in ln}
+    assert calls == {"%llmd.ssm.update", "%llmd.ssm.scan"}
+    m = compiled.memory_analysis()
+    pool_bytes = Lm * S * (128 * 64 * 128 * 4 + 3 * 8448 * 2)
+    assert m.alias_size_in_bytes >= pool_bytes
+    assert m.temp_size_in_bytes < 0.5 * 2**30

@@ -27,6 +27,7 @@ from llmd_tpu.models.registry import get_model_config  # noqa: E402
 from llmd_tpu.ops import grouped_gemm  # noqa: E402
 from perfbench.references import _common as rc  # noqa: E402
 from perfbench.references import gqa_swa_moe_share as ref  # noqa: E402
+from perfbench.references import mamba2_gqa_moe_share as ref_granite  # noqa: E402
 from perfbench.topologies import engine_hybrid  # noqa: E402
 
 CONF_FILE = ROOT / "perfbench" / "configs" / "k-exaone-236b-a23b.1chip.json"
@@ -185,15 +186,28 @@ def _held(lp, first, n):
     return {k: (a[first:first + n] if k.startswith("we_") else a) for k, a in lp.items()}
 
 
+# (preset, its reference, what the benchmark's rehearsal hands that reference)
+SHARED_MODELS = {
+    "tiny-exaone": (ref, PUBLISHED),
+    "tiny-granite-hybrid": (
+        ref_granite,
+        json.loads((ROOT / "perfbench" / "configs" / "granite-4.0-h-small.1chip.json").read_text())["rehearse"]["published"],
+    ),
+}
+
+
+@pytest.mark.parametrize("model", SHARED_MODELS)
 @pytest.mark.parametrize("backend", ["grouped", "dense", "kernel"])
-def test_the_ranks_shares_add_up_to_the_uncut_layer(backend, monkeypatch):
+def test_the_ranks_shares_add_up_to_the_uncut_layer(backend, model, monkeypatch):
     """Over all ranks the held experts' parts, with the shared expert (which
     every rank computes alike) counted once, are the uncut layer — which is
-    what the uncut reference gives for it."""
+    what the uncut reference gives for it (under the model's residual
+    multiplier, where it has one)."""
     if backend == "kernel":
         monkeypatch.setenv("LLMD_PALLAS", "interpret")
     over = dict(hidden_size=128, moe_intermediate_size=128, num_heads=4) if backend == "kernel" else {}
-    cfg = get_model_config("tiny-exaone", **over)
+    cfg = get_model_config(model, **over)
+    ref, PUBLISHED = SHARED_MODELS[model]
     block = moe.moe_block if backend == "dense" else moe.moe_block_grouped
     lp, h = _moe_layer(cfg)
     whole_cfg = dataclasses.replace(cfg, held_experts=cfg.num_experts, held_experts_first=0)
@@ -213,7 +227,7 @@ def test_the_ranks_shares_add_up_to_the_uncut_layer(backend, monkeypatch):
     dims = rc.freeze(PUBLISHED, ref.KEYS)
     x = h.reshape(-1, cfg.hidden_size)
     with jax.default_matmul_precision("highest"):
-        want = ref._sparse_ffn(stacked, jnp.int32(0), x, dims, 0) - x
+        want = (ref._sparse_ffn(stacked, jnp.int32(0), x, dims, 0) - x) / cfg.residual_multiplier
     normed = (x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + cfg.rms_norm_eps)).reshape(h.shape)
     np.testing.assert_allclose(block(normed, lp, whole_cfg).reshape(x.shape), want, atol=5e-5)
 
