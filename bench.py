@@ -2100,7 +2100,8 @@ def bench_unified_step():
 
 
 def bench_async_step():
-    """Async stepping (SchedulerConfig.async_scheduling) host-gap
+    """The pipelined step against the synchronous one (reached through
+    LLMEngine's private ``_synchronous_step``, the parity tests' seam): host-gap
     microbench on the CPU substrate (chip-free: the host gap is a HOST
     property — schedule + page-table build + array prep + assembly — so
     the hidden-vs-exposed comparison carries; absolute tok/s here is a
@@ -2130,12 +2131,12 @@ def bench_async_step():
             cache=CacheConfig(page_size=16, num_blocks=512, dtype="float32"),
             scheduler=SchedulerConfig(
                 max_num_seqs=B, max_num_batched_tokens=B * ISL,
-                decode_window=1, async_scheduling=async_mode,
+                decode_window=1,
             ),
             parallel=ParallelConfig(tensor_parallel_size=1),
             seed=0,
         )
-        engine = LLMEngine(cfg)
+        engine = LLMEngine(cfg, _synchronous_step=not async_mode)
         rng = np.random.default_rng(0)
         sp = SamplingParams(temperature=0.0, max_tokens=OSL, ignore_eos=True)
         mk = lambda: [  # noqa: E731

@@ -446,15 +446,6 @@ class SchedulerConfig:
     # speculative engine (speculative_ngram), which verifies one-shot
     # every step.
     decode_window: int = 1
-    # Async stepping (vLLM v1 --async-scheduling role): while step N
-    # executes on device, the scheduler speculatively builds step N+1
-    # against dispatched token counts; the engine blocks on N's single
-    # coalesced readback only after N+1 is staged, reconciling late
-    # EOS/max-tokens finishes by invalidating the affected staged rows.
-    # Outputs arrive one step late. Forced OFF for multi-host lockstep
-    # engines and P/D eager-ACK producers (their response-ordering
-    # guarantees assume the synchronous step shape).
-    async_scheduling: bool = False
     # Model-free speculative decoding (prompt-lookup / n-gram drafting,
     # Saxena 2023; verified Leviathan-style in one pass): each decode row
     # drafts up to ``spec_ngram_k`` continuation tokens by matching the

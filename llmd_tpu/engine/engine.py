@@ -10,6 +10,7 @@ exposes the queue/KV metrics the EPP scrapes
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import logging
 import threading
@@ -328,12 +329,15 @@ class EngineStats:
     lora_load_failures_total: int = 0
     resident_lora_adapters: tuple = ()
     available_lora_adapters: tuple = ()
-    # Step pipeline observability (async stepping, serve/metrics.py):
-    # the host gap is the per-step host time the device sits idle for —
-    # schedule + array build + dispatch + output assembly in sync mode,
-    # only the post-readback reconcile/patch in async mode (the rest
-    # overlaps device execution). Last value + running sum + step count
-    # so a scrape (or the bench) can read both a gauge and a mean.
+    # Step pipeline observability (serve/metrics.py): the host gap is
+    # the per-step host time the device sits idle for. In the pipelined
+    # step (the default shape) it is the time from a readback's end to
+    # the next dispatch's return: commit + reconcile, a last top-up, the
+    # fill and the one put + call; everything else overlaps device
+    # execution. In the synchronous step (lockstep followers, P/D
+    # producers) it is schedule + launch + finish. Last value + running
+    # sum + step count so a scrape (or the bench) can read both a gauge
+    # and a mean.
     engine_steps_total: int = 0
     step_host_gap_ms: float = 0.0
     step_host_gap_ms_total: float = 0.0
@@ -343,15 +347,20 @@ class EngineStats:
     # admit (parked KV streams, cold adapter loads, the pager's pump),
     # schedule, launch (host arrays built and the program handed to the
     # device), wait (the device and the one readback), finish (collect,
-    # scheduler update, outputs, offload flush). In sync mode schedule +
-    # launch + finish IS step_host_gap_ms_total; in async mode schedule
-    # and most of launch overlap the device and the gap stays the
-    # post-readback part. step_ms_total is the whole of step().
+    # scheduler update, outputs, offload flush). Each is host time
+    # SPENT; in the synchronous step schedule + launch + finish IS
+    # step_host_gap_ms_total, in the pipelined step schedule, the staging
+    # half of launch and the assembly half of finish overlap the device
+    # and the gap is step_commit_ms_total (collect, scheduler update,
+    # late intake, reconcile) + step_redispatch_ms_total (last top-up,
+    # fill, put + call). step_ms_total is the whole of step().
     step_admit_ms_total: float = 0.0
     step_schedule_ms_total: float = 0.0
     step_launch_ms_total: float = 0.0
     step_wait_ms_total: float = 0.0
     step_finish_ms_total: float = 0.0
+    step_commit_ms_total: float = 0.0
+    step_redispatch_ms_total: float = 0.0
     step_ms_total: float = 0.0
     # Steps by what they carried (prefill chunks only, decode rows only,
     # both) and their whole-step time: a mean step time hides that a
@@ -377,6 +386,14 @@ class EngineStats:
     # (EOS / stop token / max-tokens landed after the next batch was
     # staged against the optimistic one-token-per-decode assumption).
     async_rollbacks_total: int = 0
+    # How often the pipeline engages: steps dispatched from a slot that
+    # was scheduled and staged under the step before (over
+    # engine_steps_total: near 1 while there is work; a pipeline that
+    # restarts from empty, or a slot rolled back whole, dispatches
+    # unstaged), and of those the steps whose staged batch was topped up
+    # with requests admitted after the speculative schedule.
+    steps_prestaged_total: int = 0
+    steps_topped_up_total: int = 0
     # Speculative decoding (SchedulerConfig.speculative_ngram; the
     # propose/verify/accept contract in
     # docs/architecture/speculative-decoding.md): draft tokens proposed
@@ -522,6 +539,19 @@ class _InflightStep:
     pending_unified: PendingUnified | None = None
 
 
+@dataclass
+class _StagedStep:
+    """The pipeline's other slot: the batch scheduled (speculatively)
+    and staged on the host while ``_InflightStep`` runs on the device."""
+
+    batch: ScheduledBatch
+    # What of its dispatch is built already (``LLMEngine._stage``).
+    staging: StagedDecode | StagedVerify | StagedUnified | None = None
+    topped_up: bool = False  # took in rows admitted after the schedule
+    admit_s: float = 0.0  # host seconds spent scheduling and topping up
+    in_wait_s: float = 0.0  # ... of them inside the wait for the readback
+
+
 class LLMEngine:
     def __init__(
         self,
@@ -529,6 +559,7 @@ class LLMEngine:
         mesh_ctx: MeshContext | None = None,
         params: dict | None = None,
         event_sink: KVEventSink | None = None,
+        _synchronous_step: bool = False,
     ) -> None:
         self.config = config
         import jax
@@ -793,27 +824,38 @@ class LLMEngine:
             self.kv_connector = TPUConnector(kv_cfg, self.runner, self.allocator)
             self.scheduler.finish_hook = self._on_finish
 
-        # Async stepping (SchedulerConfig.async_scheduling): a two-slot
-        # pipeline — one batch executing on device while the next is
-        # speculatively scheduled and staged on host. Forced OFF where
-        # the synchronous step shape is itself a correctness contract:
-        # multi-host lockstep followers mirror a totally ordered op
-        # stream whose cadence the leader's sync step defines, and P/D
-        # eager-ACK producers answer before the readback on the promise
-        # that nothing was reordered around the enqueued KV snapshots.
-        self._async = bool(config.scheduler.async_scheduling)
+        # The two-slot pipeline is how the engine steps: one batch
+        # executing on device while the next is speculatively scheduled,
+        # admitted and staged on host (_step_async). The synchronous step
+        # stays where its shape is itself a correctness contract, which
+        # the code can see: multi-host lockstep followers mirror a totally
+        # ordered op stream whose cadence the leader's sync step defines,
+        # and P/D eager-ACK producers answer before the readback on the
+        # promise that nothing was reordered around the enqueued KV
+        # snapshots. No configuration key selects the step:
+        # ``_synchronous_step`` is the seam through which the two-way
+        # parity tests and ``bench.py --parts async_step`` reach the
+        # synchronous shape on a single host.
+        self._async = not _synchronous_step
         if self._async and jax.process_count() > 1:
             logging.getLogger(__name__).info(
-                "async_scheduling disabled: multi-host lockstep engines "
-                "keep the synchronous step shape"
+                "synchronous step: multi-host lockstep engines keep the "
+                "synchronous step shape"
             )
             self._async = False
         if self._async and config.kv_role in ("kv_producer", "kv_both"):
             logging.getLogger(__name__).info(
-                "async_scheduling disabled: P/D eager-ACK producers rely "
-                "on synchronous step ordering"
+                "synchronous step: P/D eager-ACK producers rely on "
+                "synchronous step ordering"
             )
             self._async = False
+        self.scheduler.pipelined = self._async
+        # Installed by the serving layer (AsyncEngine), as finish_hook is
+        # by the connector: drains the requests and aborts that arrived
+        # since the last call into add_request / abort_request. The
+        # pipelined step polls it while the device runs, so that an
+        # arrival during step N rides step N+1 (_top_up).
+        self.intake_hook = None
         self._inflight: _InflightStep | None = None
         # (kind, rows, tokens) of the batch this call of step() finished
         # (async, first call of a pipeline: dispatched), for the llmd.step
@@ -1589,7 +1631,15 @@ class LLMEngine:
                     # (a wait state the scheduler skips, not a fault).
                     self.pager.pump(self.scheduler.waiting)
             t_admitted = time.monotonic()
-            outputs = self._step_async() if self._async else self._step_sync()
+            if self._inflight is not None:
+                outputs = self._step_async()
+            else:
+                # Nothing in flight (the synchronous roles always; the
+                # pipeline when it starts from empty): the step is landed
+                # here and now, and the pipeline entered behind it.
+                outputs = self._step_sync()
+                if self._async:
+                    self._prime()
             kind, rows, tokens = self._step_carried
             if rows:
                 step_span.set_metadata(
@@ -1683,131 +1733,248 @@ class LLMEngine:
         return outputs
 
     def _step_async(self) -> list[RequestOutput]:
-        """Two-slot pipelined step: while the in-flight batch executes on
-        device, schedule the next batch speculatively (each in-flight
-        decode assumed to land its tokens) and prestage its host arrays;
-        only then block on the in-flight readback. Late finishes
-        (EOS/stop token/max-tokens) invalidate their staged rows — the
-        released pages follow the recompute-preemption path — and
-        everything else dispatches immediately, so the host gap shrinks
-        to the reconcile/patch sliver. Outputs arrive one step late
-        (docs/architecture/async-scheduling.md)."""
+        """The pipelined step, two slots deep: while the in-flight batch
+        N executes on device, the host does everything that does not need
+        N's tokens — schedule N+1 speculatively (each in-flight decode
+        assumed to land its tokens), prestage its host arrays, and, all
+        through the wait for N's readback, take in the requests that
+        arrive and top the staged batch up with them. Between two
+        programs stand only commit, reconcile (late EOS/stop/max-tokens
+        finishes and aborts invalidate their staged rows — the released
+        pages follow the recompute-preemption path), a last top-up and
+        the fill-and-dispatch of N+1; output assembly and the offloader's
+        flush run after the re-dispatch, under N+1. Outputs arrive one
+        call late; the pipeline is entered by ``_prime`` behind a step
+        that landed synchronously (docs/architecture/async-scheduling.md)."""
         inflight = self._inflight
-        if inflight is None:
-            batch = self._schedule_spanned()
-            if batch.is_empty:
-                return []
-            self._dispatch_async(batch)
-            self._step_carried = self._carried(batch)
-            return []  # pipeline is one step deep: tokens land next call
         # ---- overlapped host region: the device is executing N ----
         t0 = time.monotonic()
-        staged = self._schedule_spanned()  # speculative: pending counts
-        t_sched = time.monotonic()
-        staged_dec: (
-            StagedDecode | StagedVerify | StagedUnified | None
-        ) = None
-        if self._unified_eligible(staged):
-            # Unified single-dispatch step: the row structure and the
-            # row-independent arrays (page/ring tables, knobs) prestage
-            # here; the packed stream, (start, qlen, kind) metadata,
-            # drafts and seeds fill at dispatch, after step N's
-            # readback commits.
-            staged_dec = self.runner.stage_unified(
-                staged.prefills, staged.decodes
-            )
-        elif staged.decodes:
-            if self._spec_proposer is not None:
-                # Spec mode stages the verify shape; tokens, drafts and
-                # seeds fill at dispatch, after step N's readback commits.
-                staged_dec = self.runner.stage_spec_verify(staged.decodes)
-            else:
-                staged_dec = self.runner.stage_decode(
-                    staged.decodes, k_steps=staged.decodes[0].num_tokens
-                )
+        slot = _StagedStep(self._schedule_spanned())  # speculative: pending counts
+        slot.admit_s = time.monotonic() - t0
+        slot.staging = self._stage(slot.batch)
         # ---- block on step N's single coalesced readback ----
         t_staged = time.monotonic()
         pres, dres = self.runner.wait_step(
             inflight.pending_prefill, inflight.pending_decode,
             inflight.pending_unified,
+            # Serving: what arrives while N runs rides N+1, as it would
+            # behind a synchronous step, and is admitted under the device.
+            poll=None if self.intake_hook is None
+            else functools.partial(self._admit_arrivals, slot),
         )
         t_read = time.monotonic()
-        sampled, logprobs = self._collect(inflight.batch, pres, dres)
-        accepted = self.scheduler.update_after_step(inflight.batch, sampled)
-        self._inflight = None
-        for rid in sorted(self._deferred_aborts):
-            self.scheduler.abort_request(rid)
-        self._deferred_aborts.clear()
-        # ---- reconcile the speculative slot against late finishes ----
-        live_p = [
-            s for s in staged.prefills
-            if s.request.status is RequestStatus.RUNNING
-        ]
-        live_d = [
-            s for s in staged.decodes
-            if s.request.status is RequestStatus.RUNNING
-        ]
-        rolled = (len(staged.prefills) - len(live_p)) + (
-            len(staged.decodes) - len(live_d)
-        )
-        if rolled:
-            # Rolled-back rows already returned every page (speculative
-            # allocations included) via _finish/_release — the same
-            # release the recompute-preemption path uses.
-            self.stats.async_rollbacks_total += rolled
-            reconciled = ScheduledBatch(prefills=live_p, decodes=live_d)
-            if isinstance(staged_dec, StagedUnified):
-                # Unified prestage survives a rollback by SLICING the
-                # surviving rows' row-independent arrays out of the
-                # full-batch staging (_slice_staged_rows) — unless the
-                # reconciled step is no longer unified-shaped (e.g. it
-                # collapsed to a single program's worth of work).
-                if not reconciled.is_empty and self._unified_eligible(
-                    reconciled
-                ):
-                    staged_dec = self.runner.subset_staged_unified(
-                        staged_dec, live_p, live_d
-                    )
-                else:
-                    staged_dec = None
-            elif len(live_d) != len(staged.decodes):
-                staged_dec = None  # row set changed: restage at dispatch
-            staged = reconciled
-        if staged.is_empty and rolled and self.scheduler.has_work():
-            # The whole slot was invalidated; the freed pages/budget may
-            # admit different work now that nothing is pending.
-            staged = self._schedule_spanned()
-            staged_dec = None
+        with profiling.span("llmd.step.commit") as commit_span:
+            sampled, logprobs = self._collect(inflight.batch, pres, dres)
+            accepted = self.scheduler.update_after_step(
+                inflight.batch, sampled
+            )
+            self._inflight = None
+            if self.intake_hook is not None:
+                # The last instants' arrivals and aborts: nothing is in
+                # flight now, so an abort releases its row at once and
+                # the reconcile below drops it with the late finishes.
+                self.intake_hook()
+            for rid in sorted(self._deferred_aborts):
+                self.scheduler.abort_request(rid)
+            self._deferred_aborts.clear()
+            if self.pager is not None:
+                # Spill pages that fell below the window + prefetch
+                # horizon HERE, the one point of the pipelined step where
+                # nothing is in flight (behind the re-dispatch every
+                # running row is protected and the tick would spill
+                # nothing, ever). The staged tables keep the spilled
+                # pages' stale ids, as Request.block_ids does: every read
+                # of those positions is window-masked.
+                self.pager.tick(self.scheduler.running)
+            # ---- reconcile the speculative slot against late finishes ----
+            rolled = self._reconcile(slot)
+            commit_span.set_metadata(rolled=rolled)
         t_reconciled = time.monotonic()
-        if not staged.is_empty:
-            self._dispatch_async(staged, staged_dec)
+        prestaged = not slot.batch.is_empty
+        if not prestaged:
+            if self.scheduler.has_work():
+                # The slot is empty (every staged row rolled back, or all
+                # of N's rows foreseen to end): nothing is pending, so
+                # the freed rows, pages and budget are scheduled whole.
+                t = time.monotonic()
+                slot.batch = self._schedule_spanned()
+                slot.admit_s += time.monotonic() - t
+        elif self.scheduler.waiting:
+            # Rows and budget that N's finishes gave back, and whatever
+            # arrived in the last instants: admitted now, one step sooner
+            # than the next speculative schedule would.
+            self._top_up(slot)
+        if not slot.batch.is_empty:
+            self._dispatch_async(slot.batch, slot.staging)
+            self.stats.steps_prestaged_total += prestaged
+            self.stats.steps_topped_up_total += slot.topped_up
         # Device idle ends at the re-dispatch above; output assembly and
         # gauge refresh below overlap step N+1's execution.
         t_redispatched = time.monotonic()
-        host_gap = t_redispatched - t_read
         with profiling.span("llmd.step.finish") as finish_span:
             outputs = self._assemble_outputs(
                 inflight.batch, accepted, logprobs
             )
             if self.offloader is not None:
                 self.offloader.flush()
-            if self.pager is not None:
-                # Protected (in-flight) rows are skipped inside the tick,
-                # so the staged batch's page tables stay valid.
-                self.pager.tick(self.scheduler.running)
             finish_span.set_metadata(outputs=len(outputs))
-        # The phases of THIS call, by the sync step's names: the staged
-        # schedule and prestaging ran while the device executed step N;
-        # collect/update/reconcile count as finish with the assembly.
+        # The phases of THIS call, by the sync step's names, as host time
+        # spent: schedule (with the top-ups, which are taken out of the
+        # wait they ran in) and prestaging ran while the device executed
+        # step N; commit/reconcile count as finish with the assembly. The
+        # host gap is what stood between N's readback and N+1's dispatch:
+        # commit + redispatch.
         self._finish_step(
-            inflight.batch, host_gap,
-            schedule_s=t_sched - t0,
-            launch_s=(t_staged - t_sched) + (t_redispatched - t_reconciled),
-            wait_s=t_read - t_staged,
+            inflight.batch, t_redispatched - t_read,
+            schedule_s=slot.admit_s,
+            launch_s=(t_staged - t0) + (t_redispatched - t_reconciled)
+            - (slot.admit_s - slot.in_wait_s),
+            wait_s=t_read - t_staged - slot.in_wait_s,
             finish_s=(t_reconciled - t_read)
             + (time.monotonic() - t_redispatched),
+            commit_s=t_reconciled - t_read,
+            redispatch_s=t_redispatched - t_reconciled,
         )
         return outputs
+
+    def _prime(self) -> None:
+        """Enter the pipeline behind a step that has just landed: what
+        arrived while it ran is taken in, what is left to run is scheduled
+        (nothing is pending) and dispatched, and the next call of
+        ``step()`` finds it in flight.
+
+        This entry is a SET-UP WORKAROUND, not a design: a pipeline could
+        as well start by dispatching from empty inside ``_step_async``,
+        but a shape's first call (trace + lowering) reached on that path
+        read +0.24 s a bucket on the v5e host, cause not found (PERF.md
+        section 6, PR 38; section 7 (k)). Landing the first step on the
+        synchronous path keeps a warm-up that starts from empty at every
+        shape on the code the set-up bound was set with; a shape first
+        reached INSIDE the pipeline (a top-up's, a lazily compiling
+        server's) still pays the slower lowering."""
+        if self.intake_hook is not None:
+            self.intake_hook()
+        if self.scheduler.has_work():
+            batch = self._schedule_spanned()
+            if not batch.is_empty:
+                self._dispatch_async(batch)
+
+    def _stage(
+        self, batch: ScheduledBatch
+    ) -> StagedDecode | StagedVerify | StagedUnified | None:
+        """Prestage ``batch``: everything of its dispatch that does not
+        depend on the in-flight step's tokens."""
+        if batch.is_empty:
+            return None
+        if self._unified_eligible(batch):
+            # Unified single-dispatch step: the row structure and the
+            # row-independent arrays (page/ring tables, knobs) prestage
+            # here; the packed stream, (start, qlen, kind) metadata,
+            # drafts and seeds fill at dispatch, after step N's
+            # readback commits.
+            return self.runner.stage_unified(batch.prefills, batch.decodes)
+        if not batch.decodes:
+            return None
+        if self._spec_proposer is not None:
+            # Spec mode stages the verify shape; tokens, drafts and
+            # seeds fill at dispatch, after step N's readback commits.
+            return self.runner.stage_spec_verify(batch.decodes)
+        return self.runner.stage_decode(
+            batch.decodes, k_steps=batch.decodes[0].num_tokens
+        )
+
+    def _restage(
+        self,
+        staged_dec: StagedDecode | StagedVerify | StagedUnified | None,
+        was: ScheduledBatch,
+        batch: ScheduledBatch,
+    ) -> StagedDecode | StagedVerify | StagedUnified | None:
+        """The staging of ``batch``, which is ``was`` (what ``staged_dec``
+        was built for) less the rows a rollback dropped or plus the rows a
+        top-up admitted. A unified prestage survives by SLICING the rows
+        both share out of the full staging (runner.restage_unified) and
+        building the added ones alone; a decode or verify prestage stands
+        while its decode rows do (the split path builds prefill rows at
+        dispatch); anything else is staged anew."""
+        if isinstance(staged_dec, StagedUnified):
+            if not batch.is_empty and self._unified_eligible(batch):
+                return self.runner.restage_unified(
+                    staged_dec, batch.prefills, batch.decodes
+                )
+        elif (
+            staged_dec is not None
+            and len(batch.decodes) == len(was.decodes)
+            and not self._unified_eligible(batch)
+        ):
+            return staged_dec
+        return self._stage(batch)
+
+    @staticmethod
+    def _running(batch: ScheduledBatch) -> ScheduledBatch:
+        """``batch`` less the rows whose request is no longer running
+        (``batch`` itself where all are)."""
+        live_p = [
+            s for s in batch.prefills
+            if s.request.status is RequestStatus.RUNNING
+        ]
+        live_d = [
+            s for s in batch.decodes
+            if s.request.status is RequestStatus.RUNNING
+        ]
+        if len(live_p) + len(live_d) == len(batch.prefills) + len(batch.decodes):
+            return batch
+        return ScheduledBatch(prefills=live_p, decodes=live_d)
+
+    def _reconcile(self, slot: _StagedStep) -> int:
+        """Drop the staged rows whose request is no longer running (a
+        late finish of the in-flight step, or an abort); returns how
+        many. Rolled-back rows already returned every page (speculative
+        allocations included), their ring or state slot and what they
+        held of a retained section via _finish/_release — the same
+        release the recompute-preemption path uses."""
+        batch = self._running(slot.batch)
+        rolled = len(slot.batch.seqs) - len(batch.seqs)
+        if rolled:
+            self.stats.async_rollbacks_total += rolled
+            slot.staging = self._restage(slot.staging, slot.batch, batch)
+            slot.batch = batch
+        return rolled
+
+    def _top_up(self, slot: _StagedStep) -> None:
+        """Admit into the staged batch what the scheduler would have
+        admitted from ``waiting`` had it been there at the speculative
+        schedule, under the budget and the rows the staged batch left
+        (prefix lookup, page allocation, ring section or state snapshot
+        of a hybrid hit included), and stage the added rows alone."""
+        t0 = time.monotonic()
+        in_flight = self._inflight is not None
+        with profiling.span("llmd.sched.schedule", top_up=True) as span:
+            added = self.scheduler.top_up(slot.batch, in_flight=in_flight)
+            span.set_metadata(prefills=len(added), decodes=0)
+        # An interactive head may have reclaimed a staged batch-band row's
+        # slot and pages, whether or not it was then admitted: a row that
+        # no longer runs leaves the staged batch either way.
+        kept = self._running(slot.batch)
+        if added or kept is not slot.batch:
+            batch = ScheduledBatch(
+                prefills=kept.prefills + added, decodes=kept.decodes
+            )
+            slot.staging = self._restage(slot.staging, slot.batch, batch)
+            slot.batch = batch
+            slot.topped_up |= bool(added)
+        spent = time.monotonic() - t0
+        slot.admit_s += spent
+        if in_flight:
+            slot.in_wait_s += spent
+
+    def _admit_arrivals(self, slot: _StagedStep) -> None:
+        """``wait_step``'s poll while step N runs: take in what arrived
+        and top the staged batch up with it (an abort among the arrivals
+        may have released a staged row that was never dispatched: it is
+        dropped here, before the wait ends)."""
+        if self.intake_hook():
+            self._reconcile(slot)
+            self._top_up(slot)
 
     def _schedule_spanned(self) -> ScheduledBatch:
         with profiling.span("llmd.sched.schedule") as sched_span:
@@ -2097,9 +2264,13 @@ class LLMEngine:
         launch_s: float,
         wait_s: float,
         finish_s: float,
+        commit_s: float = 0.0,
+        redispatch_s: float = 0.0,
     ) -> None:
         """Count one step that ran ``batch``: the host gap, the phase
-        sums and the step kind (``step()`` adds the whole-step sums)."""
+        sums and the step kind (``step()`` adds the whole-step sums).
+        ``commit_s`` and ``redispatch_s`` are the pipelined step's gap in
+        its two parts (the synchronous step's gap is its phases)."""
         st = self.stats
         gap_ms = host_gap_s * 1e3
         st.engine_steps_total += 1
@@ -2109,6 +2280,8 @@ class LLMEngine:
         st.step_launch_ms_total += launch_s * 1e3
         st.step_wait_ms_total += wait_s * 1e3
         st.step_finish_ms_total += finish_s * 1e3
+        st.step_commit_ms_total += commit_s * 1e3
+        st.step_redispatch_ms_total += redispatch_s * 1e3
         self._step_carried = self._carried(batch)
         by_kind = f"steps_{self._step_carried[0]}_total"
         setattr(st, by_kind, getattr(st, by_kind) + 1)

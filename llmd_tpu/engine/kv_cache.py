@@ -99,7 +99,7 @@ def _locked(fn):
 # declared `# llmd: transfers(pages)` boundary. The runtime twin
 # (LLMD_LEAKSAN=1) mirrors the refcounts per page with acquisition
 # backtraces and asserts zero outstanding at test teardown.
-# llmd: resource(pages, recv=alloc, acquire=allocate|allocate_with_floor|touch:arg|lookup_and_touch_prefix|lookup_and_touch_hashes, release=free, transfer=commit_page)
+# llmd: resource(pages, recv=alloc, acquire=allocate|allocate_with_floor|touch:arg|lookup_and_touch_hashes, release=free, transfer=commit_page)
 class PageAllocator:
     """Refcounted page allocator with a content-addressed reuse index."""
 
@@ -144,17 +144,18 @@ class PageAllocator:
         with self._lock:
             return 1.0 - len(self._free) / self.num_pages
 
-    def _cached_run_locked(self, hashes) -> list[int]:
+    def _cached_run_locked(self, hashes, count: bool = True) -> list[int]:
         """Leading cached run for a hash chain, with hit accounting —
         the ONE walk every lookup variant delegates to (caller holds
-        the lock)."""
+        the lock). ``count=False``: the repeat of a lookup that was
+        counted already."""
         pages: list[int] = []
         for h in hashes:
-            self.metrics_queries += 1
             pid = self._cached.get(h)
+            self.metrics_queries += count
             if pid is None:
                 break
-            self.metrics_hits += 1
+            self.metrics_hits += count
             pages.append(pid)
         return pages
 
@@ -185,14 +186,18 @@ class PageAllocator:
         return n
 
     @_locked
-    def lookup_and_touch_hashes(self, hashes) -> list[int]:
-        """lookup_and_touch_prefix for a PRE-COMPUTED hash chain: the
-        leading run of cached pages for exactly these hashes, touched
-        atomically. Lets callers that already hold the chain (hybrid
-        SWA-ring hits) avoid re-hashing the prompt."""
+    def lookup_and_touch_hashes(self, hashes, count: bool = True) -> list[int]:
+        """The leading run of cached pages for a hash chain
+        (``page_hashes_for_tokens``), touched ATOMICALLY with the lookup:
+        in two calls, a ref-0 cached page found by the lookup can be
+        stolen by a concurrent allocate() (e.g. the multi-host
+        streamed-import fetch thread) before touch() claims it — touch
+        would then ref-bump a page whose content is being overwritten,
+        silently attending over another request's KV. ``count=False``:
+        an admission tried again, counted the first time."""
         if not self.enable_prefix_caching:
             return []
-        pages = self._cached_run_locked(hashes)
+        pages = self._cached_run_locked(hashes, count)
         if pages:
             self.touch(pages)
         return pages
@@ -207,25 +212,6 @@ class PageAllocator:
         if len(self._free) - n < floor:
             raise NoFreePagesError(n + floor, len(self._free))
         return self.allocate(n)
-
-    @_locked
-    def lookup_and_touch_prefix(
-        self,
-        token_ids: Sequence[int],
-        extra: bytes = b"",
-        max_pages: int | None = None,
-    ) -> list[int]:
-        """Atomic lookup_cached_prefix + touch of (up to ``max_pages``
-        of) the hit run. The two-call form is NOT safe with concurrent
-        allocators: a ref-0 cached page found by lookup can be stolen by
-        a concurrent allocate() (e.g. the multi-host streamed-import
-        fetch thread) before touch() claims it — touch would then
-        ref-bump a page whose content is being overwritten, silently
-        attending over another request's KV."""
-        hashes = page_hashes_for_tokens(token_ids, self.page_size, extra)
-        if max_pages is not None:
-            hashes = hashes[:max_pages]
-        return self.lookup_and_touch_hashes(hashes)
 
     @_locked
     def has_cached(self, content_hash: bytes) -> bool:

@@ -108,11 +108,17 @@ class Request:
     # section is captured as its prefill passes the run's end (scheduler
     # ``prefill_passed_hook``) and the next request takes the hit.
     swa_capture: tuple | None = None
+    # The prompt's page-hash chain, left by an admission that looked the
+    # prefix cache up and then failed for want of fresh pages (what the
+    # cache lent went back): the next attempt walks it again instead of
+    # hashing the prompt, and is not counted as another query
+    # (scheduler._apply_prefix_cache).
+    prefix_hashes: list | None = None
     # Tokens dispatched to the device but not yet committed by a step
-    # readback (async stepping, SchedulerConfig.async_scheduling): the
-    # scheduler speculates the next batch against dispatched positions
-    # while the in-flight step executes. Always 0 in synchronous mode
-    # and between reconcile and the next dispatch.
+    # readback (the pipelined step): the scheduler speculates the next
+    # batch against dispatched positions while the in-flight step
+    # executes. Always 0 behind a synchronous step and between reconcile
+    # and the next dispatch.
     num_pending_tokens: int = 0
     # Number of prompt tokens satisfied from the prefix cache (skipped compute).
     num_cached_tokens: int = 0
@@ -181,6 +187,22 @@ class Request:
     @property
     def all_token_ids(self) -> list[int]:
         return self.prompt_token_ids + self.output_token_ids
+
+    def token_at(self, pos: int) -> int:
+        """``all_token_ids[pos]`` without building the list (a step's fill
+        reads one token of each decode row: a copy of a 4-24k-token
+        history a row a step is milliseconds the device waits for)."""
+        n = len(self.prompt_token_ids)
+        return self.prompt_token_ids[pos] if pos < n else self.output_token_ids[pos - n]
+
+    def tokens_between(self, start: int, stop: int) -> list[int]:
+        """``all_token_ids[start:stop]``, copying only what is asked for."""
+        n = len(self.prompt_token_ids)
+        if stop <= n:
+            return self.prompt_token_ids[start:stop]
+        if start >= n:
+            return self.output_token_ids[start - n:stop - n]
+        return self.prompt_token_ids[start:] + self.output_token_ids[:stop - n]
 
     @property
     def in_decode(self) -> bool:

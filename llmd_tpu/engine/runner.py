@@ -122,6 +122,10 @@ _STEP_KINDS = {
 # decode row pads to the Q bucket, so Q must stay small relative to the
 # token stream, not grow to the largest chunk.
 _UNIFIED_ROW_TOKENS = 64
+# wait_step's pause between two polls of the intake while the device runs:
+# long enough that the serving threads get the interpreter, short against
+# a step (what it adds to the gap is half of it on average).
+_POLL_S = 1e-4
 
 log = logging.getLogger(__name__)
 
@@ -2715,7 +2719,7 @@ class ModelRunner:
         kvlens = np.zeros(B, np.int32)
         for i, s in enumerate(seqs):
             req, start, m = s.request, s.start_pos, s.num_tokens
-            tokens[i, :m] = req.all_token_ids[start : start + m]
+            tokens[i, :m] = req.tokens_between(start, start + m)
             positions[i, :m] = np.arange(start, start + m)
             positions[i, m:] = start + max(m - 1, 0)
             qlens[i] = m
@@ -2803,7 +2807,7 @@ class ModelRunner:
         staged.arrays["seeds"] = seeds
         for i, s in enumerate(staged.seqs):
             req = s.request
-            first[i] = req.all_token_ids[req.num_computed_tokens]
+            first[i] = req.token_at(req.num_computed_tokens)
             start[i] = req.num_computed_tokens
         self._overwrite_seeded_rows(seeds, staged.seqs, staged.k)
         n = len(staged.seqs)
@@ -2878,7 +2882,7 @@ class ModelRunner:
             nc = req.num_computed_tokens
             draft = s.draft_tokens or []
             m = 1 + len(draft)
-            tokens[i, :m] = [req.all_token_ids[nc], *draft]
+            tokens[i, :m] = [req.token_at(nc), *draft]
             tokens[i, m:] = 0
             positions[i, :m] = np.arange(nc, nc + m)
             positions[i, m:] = nc + m - 1
@@ -3027,22 +3031,16 @@ class ModelRunner:
         the page/ring tables and sampling knobs (the O(rows x max_pages)
         cost) are final here; the packed stream, per-row (start, qlen,
         kind) metadata and seeds fill at dispatch."""
-        cap = self.unified_row_cap
         row_seqs: list[ScheduledSeq] = []
         row_off: list[int] = []
         row_plan: list[int] = []
         prefill_rows: list[int] = []
         decode_rows: list[int] = []
         for s in prefills:
-            off = 0
-            while True:
-                w = min(cap, s.num_tokens - off)
+            for off, w in self._chunk_rows(s):
                 row_seqs.append(s)
                 row_off.append(off)
                 row_plan.append(w)
-                off += w
-                if off >= s.num_tokens:
-                    break
             prefill_rows.append(len(row_seqs) - 1)
         for s in decodes:
             decode_rows.append(len(row_seqs))
@@ -3162,17 +3160,17 @@ class ModelRunner:
             if r < n_pre_rows:
                 start = seq.start_pos + off
                 w = min(staged.row_plan[r], seq.num_tokens - off)
-                toks = req.all_token_ids[start : start + w]
+                toks = req.tokens_between(start, start + w)
                 kind[r] = _KIND_PREFILL
             else:
                 nc = req.num_computed_tokens
                 start = nc
                 draft = seq.draft_tokens or []
                 if draft:
-                    toks = [req.all_token_ids[nc], *draft]
+                    toks = [req.token_at(nc), *draft]
                     kind[r] = _KIND_VERIFY
                 else:
-                    toks = [req.all_token_ids[nc]]
+                    toks = [req.token_at(nc)]
                     kind[r] = _KIND_DECODE
                 w = len(toks)
             stream[t : t + w] = toks
@@ -3250,53 +3248,87 @@ class ModelRunner:
         if wphys_swa is not None:
             a["wphys_swa"] = wphys_swa
 
-    def subset_staged_unified(
+    def _chunk_rows(self, s: ScheduledSeq) -> list[tuple[int, int]]:
+        """(offset, width) of a prefill chunk's sub-rows of at most
+        ``unified_row_cap`` tokens."""
+        cap = self.unified_row_cap
+        return [
+            (off, min(cap, s.num_tokens - off))
+            for off in range(0, max(s.num_tokens, 1), cap)
+        ]
+
+    @profiling.spanned("llmd.runner.build")
+    def restage_unified(
         self,
         staged: StagedUnified,
-        live_p: list[ScheduledSeq],
-        live_d: list[ScheduledSeq],
+        prefills: list[ScheduledSeq],
+        decodes: list[ScheduledSeq],
     ) -> StagedUnified:
-        """Derive a subset StagedUnified after an async rollback dropped
-        rows: the surviving rows' row-independent arrays (page/ring
-        tables, knobs, lora slots) are SLICED out of the prestaged
-        full-batch arrays via ``_slice_staged_rows`` — one vectorized
+        """Derive the staging of a batch that differs from the one
+        ``staged`` was built for by rows DROPPED (a rollback) or ADDED (a
+        top-up admission): the rows both share keep their row-independent
+        arrays (page/ring tables, knobs, lora slots), SLICED out of the
+        prestaged arrays via ``_slice_staged_rows`` — one vectorized
         gather each — instead of re-walking the requests' block lists
-        inside the blocking host region; the dispatch-filled arrays
-        come back as fresh zeros."""
+        inside the blocking host region; only the added rows are built;
+        the dispatch-filled arrays come back as fresh zeros."""
         keep_of: dict[int, list[int]] = {}
         for r, s in enumerate(staged.row_seqs):
             keep_of.setdefault(id(s), []).append(r)
-        rows: list[int] = []
+        src: list[int] = []  # row of staged.arrays; -1: a row to build
         row_seqs: list[ScheduledSeq] = []
         row_off: list[int] = []
         row_plan: list[int] = []
         prefill_rows: list[int] = []
         decode_rows: list[int] = []
-        for s in live_p:
-            for r in keep_of[id(s)]:
-                rows.append(r)
+        for s in prefills:
+            kept = keep_of.get(id(s))
+            if kept is not None:
+                subs = [(r, staged.row_off[r], staged.row_plan[r]) for r in kept]
+            else:
+                subs = [(-1, off, w) for off, w in self._chunk_rows(s)]
+            for r, off, w in subs:
+                src.append(r)
                 row_seqs.append(s)
-                row_off.append(staged.row_off[r])
-                row_plan.append(staged.row_plan[r])
-            prefill_rows.append(len(rows) - 1)
-        for s in live_d:
-            r = keep_of[id(s)][0]
-            decode_rows.append(len(rows))
-            rows.append(r)
+                row_off.append(off)
+                row_plan.append(w)
+            prefill_rows.append(len(src) - 1)
+        for s in decodes:
+            kept = keep_of.get(id(s))
+            decode_rows.append(len(src))
+            src.append(kept[0] if kept is not None else -1)
             row_seqs.append(s)
             row_off.append(0)
-            row_plan.append(staged.row_plan[r])
+            row_plan.append(s.num_tokens)
         if staged.flat:
             B = self.flat_rows
             T = pad_to_bucket(sum(row_plan), self.flat_t_buckets)
         else:
-            B = pad_to_bucket(len(rows), self.unified_row_buckets)
+            B = pad_to_bucket(len(src), self.unified_row_buckets)
             T = pad_to_bucket(sum(row_plan), self.prefill_buckets)
         Q = pad_to_bucket(max(row_plan), self.unified_q_buckets)
         S = staged.S
         arrays = self._slice_staged_rows(
-            staged.arrays, rows, B, self._ROW_SLICE_NAMES
+            staged.arrays, [max(r, 0) for r in src], B, self._ROW_SLICE_NAMES
         )
+        added = [i for i, r in enumerate(src) if r < 0]
+        if added:
+            seqs = [row_seqs[i] for i in added]
+            n = len(added)
+            built = dict(zip(
+                ("temp", "top_k", "top_p"), self._sampling_knobs(seqs, n)
+            ))
+            built["page_table"] = self._page_table(seqs, n)
+            if "state_slots" in arrays:
+                built["state_slots"] = [
+                    s.request.swa_block_ids[0] for s in seqs
+                ]
+            if "swa_table" in arrays:
+                built["swa_table"] = self._swa_table(seqs, n)
+            if "lora" in arrays:
+                built["lora"] = self._lora_array(seqs, n)
+            for name, rows in built.items():
+                arrays[name][added] = rows
         arrays.update({
             "stream": np.zeros(T, np.int32),
             "row_start": np.zeros(B, np.int32),
@@ -3308,7 +3340,7 @@ class ModelRunner:
         })
         all_greedy = all(s.request.sampling.greedy for s in row_seqs)
         return StagedUnified(
-            list(live_p), list(live_d), row_seqs, row_off, row_plan,
+            list(prefills), list(decodes), row_seqs, row_off, row_plan,
             prefill_rows, decode_rows, arrays, B, Q, T, S, all_greedy,
             flat=staged.flat,
         )
@@ -3327,13 +3359,19 @@ class ModelRunner:
         prefill: PendingPrefill | None,
         decode: PendingDecode | None,
         unified: PendingUnified | None = None,
+        poll=None,
     ) -> tuple[StepResult | None, StepResult | None]:
         """Block on one engine step's token readback: every dispatched
         program's packed output comes back in a SINGLE coalesced
         transfer (one host round-trip per step, however many prefill
         bucket groups and decode windows the step dispatched — or ONE
         packed array for a unified single-dispatch step, split back into
-        prefill/decode results by its row maps)."""
+        prefill/decode results by its row maps).
+
+        ``poll`` (the pipelined step's intake): called again and again
+        while the device runs, a short sleep apart so that the threads
+        that bring requests get the interpreter, until the outputs are
+        there; what it admits meanwhile costs the device nothing."""
         packs: list[jax.Array] = []
         if prefill is not None:
             packs.extend(p for p, _ in prefill.entries)
@@ -3343,6 +3381,12 @@ class ModelRunner:
             packs.append(unified.packed)
         if not packs:
             return None, None
+        if poll is not None:
+            while True:
+                poll()
+                if all(p.is_ready() for p in packs):
+                    break
+                time.sleep(_POLL_S)
         if dist.is_multihost():
             hosts = [dist.replicated_to_host(p) for p in packs]
         else:

@@ -26,6 +26,7 @@ from llmd_tpu.engine.kv_cache import (
     PageAllocator,
     _ROOT_HASH,
     hash_page,
+    page_hashes_for_tokens,
 )
 from llmd_tpu.engine.request import FinishReason, Request, RequestStatus
 from llmd_tpu.engine.sampler import accept_draft_tokens
@@ -98,6 +99,9 @@ class EngineScheduler:
         # the computed count stands there, not when it has passed.
         self.state_aligned = state_aligned
         self.max_model_len = max_model_len
+        # Does the engine step pipelined (a staged batch may be planned
+        # against dispatched positions)? The engine says, from its role.
+        self.pipelined = True
         # Ordered by (-priority, arrival_time): higher priority first, FCFS
         # within a priority class (the InferenceObjective priority semantics,
         # reference docs/api-reference/*.md).
@@ -284,6 +288,8 @@ class EngineScheduler:
                 or not req.in_decode_dispatched
             ):
                 continue  # reset by a preemption earlier in this loop
+            if self._ends_in_flight(req):
+                continue  # its last tokens are on the device already
             if budget <= 0:
                 break
             if self.spec_k:
@@ -334,12 +340,65 @@ class EngineScheduler:
             budget -= chunk
 
         # 3. Admit waiting sequences (priority order, FCFS within class).
-        #    Interactive only: batch-band heads defer to the backfill
-        #    phase below, and an interactive head blocked on slots or
-        #    pages reclaims them from RUNNING batch rows first (the
-        #    "preempted the moment interactive load returns" half of the
-        #    backfill contract — recompute-preemption frees the victims'
-        #    provisional pages immediately).
+        budget = self._admit_waiting(prefills, scheduled, budget)
+
+        # 4. Batch backfill: rows at or below PriorityClass.BATCH harvest
+        #    whatever token budget and pages the interactive phases left.
+        if self._batch_band and budget > 0:
+            budget = self._schedule_batch_backfill(
+                batch_decoding, batch_prefill, decodes, prefills,
+                scheduled, budget,
+            )
+        self.last_batch_backfill_tokens = sum(
+            s.num_tokens
+            for s in (*prefills, *decodes)
+            if s.request.is_batch
+        )
+
+        return ScheduledBatch(prefills=prefills, decodes=decodes)
+
+    def top_up(
+        self, batch: ScheduledBatch, in_flight: bool
+    ) -> list[ScheduledSeq]:
+        """Admission into a batch that is staged and not yet dispatched
+        (the pipelined step): what ``schedule()`` would have admitted from
+        ``waiting`` had it been there, under the token budget and the rows
+        ``batch`` left. Returns the prefill rows added; ``batch`` itself is
+        not changed. A staged fused decode window is left alone (its K was
+        chosen because nothing could be admitted), and new batch-band rows
+        wait for the next schedule: they are no one's latency. With nothing
+        ``in_flight`` (after the readback) a staged batch-band row is still
+        what an interactive head reclaims slots and pages from, as it would
+        be behind a synchronous step: the caller drops the rows that are no
+        longer running."""
+        if not self.waiting or any(
+            s.draft_tokens is None and s.num_tokens != 1
+            for s in batch.decodes
+        ):
+            return []
+        added: list[ScheduledSeq] = []
+        self._admit_waiting(
+            added,
+            {
+                s.request.request_id for s in batch.seqs
+                if in_flight or not s.request.is_batch
+            },
+            self.config.max_num_batched_tokens - batch.total_tokens,
+        )
+        return added
+
+    def _admit_waiting(
+        self, prefills: list[ScheduledSeq], scheduled: set[str], budget: int
+    ) -> int:
+        """Admit waiting sequences into ``prefills`` under ``budget``
+        (priority order, FCFS within class); returns the budget left.
+        Interactive only: batch-band heads defer to the backfill
+        phase, and an interactive head blocked on slots or
+        pages reclaims them from RUNNING batch rows first (the
+        "preempted the moment interactive load returns" half of the
+        backfill contract — recompute-preemption frees the victims'
+        provisional pages immediately). ``scheduled``: the rows already
+        placed in this step's batch, never victims."""
         while self.waiting and budget > 0:
             req = self.waiting[0]
             if req.kv_fetch_pending:
@@ -360,8 +419,9 @@ class EngineScheduler:
                 ):
                     break
                 continue
+            chain = None
             if req.num_computed_tokens == 0:
-                self._apply_prefix_cache(req)
+                chain = self._apply_prefix_cache(req)
             remaining = req.num_prompt_tokens - req.num_dispatched_tokens
             chunk = self._chunk_for(req, remaining, budget)
             if chunk <= 0:
@@ -373,6 +433,17 @@ class EngineScheduler:
             ):
                 break  # out of ring pages; retry next step
             if not self._ensure_pages_reclaiming_batch(req, chunk, scheduled):
+                if chain is not None:
+                    # What the prefix cache lent this attempt goes back
+                    # (the pages stay cached): held by a request that
+                    # still waits, they are what the running rows the
+                    # pool is short for cannot get, and no preemption
+                    # reaches a waiting request. The next attempt walks
+                    # the same chain: not hashed and not counted again.
+                    if req.block_ids:
+                        self._release(req)
+                        req.num_computed_tokens = req.num_cached_tokens = 0
+                    req.prefix_hashes = chain
                 # Return the ring: a still-waiting request holding R ring
                 # pages would break the pool's sizing guarantee and could
                 # stall a higher-priority arrival's admission. Safe only
@@ -390,21 +461,24 @@ class EngineScheduler:
             prefills.append(ScheduledSeq(req, chunk))
             scheduled.add(req.request_id)
             budget -= chunk
+        return budget
 
-        # 4. Batch backfill: rows at or below PriorityClass.BATCH harvest
-        #    whatever token budget and pages the interactive phases left.
-        if self._batch_band and budget > 0:
-            budget = self._schedule_batch_backfill(
-                batch_decoding, batch_prefill, decodes, prefills,
-                scheduled, budget,
-            )
-        self.last_batch_backfill_tokens = sum(
-            s.num_tokens
-            for s in (*prefills, *decodes)
-            if s.request.is_batch
+    def _ends_in_flight(self, req: Request) -> bool:
+        """The speculative schedule's one certainty: the step in flight
+        ends ``req`` by LENGTH whatever it samples (``max_tokens`` or the
+        model length reached by the LEAST it can emit: one token for a
+        prompt-completing chunk or a speculative row, the whole window
+        for a plain decode row). Such a row is not staged again, so it is
+        not rolled back either; a stop token stays a late finish."""
+        if not req.num_pending_tokens:
+            return False
+        least = 1
+        if req.in_decode and not self.spec_k:
+            least = req.num_pending_tokens
+        return (
+            req.total_output_tokens + least >= req.sampling.max_tokens
+            or req.num_tokens + least >= self.max_model_len
         )
-
-        return ScheduledBatch(prefills=prefills, decodes=decodes)
 
     def _prompt_boundary(self, req: Request) -> int:
         """The prompt's last full page, in tokens (the last token is always
@@ -580,10 +654,12 @@ class EngineScheduler:
             return f"lora:{req.lora_name}".encode()
         return f"lora-slot:{req.lora_id}".encode()
 
-    def _apply_prefix_cache(self, req: Request) -> None:
-        """Reuse cached full pages covering the prompt prefix."""
+    def _apply_prefix_cache(self, req: Request) -> list[bytes] | None:
+        """Reuse cached full pages covering the prompt prefix. Returns the
+        hash chain it looked up in the main pool (an admission that then
+        fails for want of pages keeps it for the next attempt)."""
         if req.block_ids:
-            return
+            return None
         if self.swa_ring_pages:
             # Ring engines do HYBRID hits only: a full-pool hit is usable
             # solely when a retained sliding section seeds the fresh ring
@@ -592,31 +668,29 @@ class EngineScheduler:
             # decode garbage. A noted miss is not probed again.
             if self.hybrid_hit_hook is not None and req.swa_capture is None:
                 self.hybrid_hit_hook(req)
-            return
+            return None
         # Never satisfy the *entire* prompt from cache: the last token must be
         # computed so the step emits logits for sampling. Lookup + touch
         # are one atomic allocator call: a concurrent allocate() (the
         # multi-host streamed-import fetch thread) must not steal a
         # ref-0 hit between the two.
-        max_cached = (req.num_prompt_tokens - 1) // self.allocator.page_size
-        cached = self.allocator.lookup_and_touch_prefix(
-            req.prompt_token_ids, extra=self._hash_extra(req),
-            max_pages=max_cached,
-        )
+        hashes, req.prefix_hashes = req.prefix_hashes, None
+        again = hashes is not None  # (a waiting request's prompt does not change)
+        if not again:
+            max_cached = (req.num_prompt_tokens - 1) // self.allocator.page_size
+            hashes = page_hashes_for_tokens(
+                req.prompt_token_ids, self.allocator.page_size,
+                self._hash_extra(req),
+            )[:max_cached]
+        cached = self.allocator.lookup_and_touch_hashes(hashes, count=not again)
         if not cached:
-            return
+            return hashes
         req.block_ids.extend(cached)
         n = len(cached)
         req.num_cached_tokens = n * self.allocator.page_size
         req.num_computed_tokens = req.num_cached_tokens
-        parent = _ROOT_HASH
-        for i in range(n):
-            parent = hash_page(
-                parent,
-                req.prompt_token_ids[i * self.allocator.page_size : (i + 1) * self.allocator.page_size],
-                extra=self._hash_extra(req),
-            )
-        self._chain[req.request_id] = (parent, n)
+        self._chain[req.request_id] = (hashes[n - 1], n)
+        return hashes
 
     def _ensure_ring(self, req: Request) -> bool:
         """Allocate the sequence's sliding-window ring (once, at admission).
@@ -912,7 +986,7 @@ class EngineScheduler:
         as needed."""
         page = self.allocator.page_size
         slots = req.num_computed_tokens
-        if self.config.async_scheduling:
+        if self.pipelined:
             slots = req.num_dispatched_tokens + 1 + self.spec_k
         keep = -(-slots // page)
         if keep < len(req.block_ids):
@@ -967,9 +1041,8 @@ class EngineScheduler:
         # Only KV already computed counts; the just-sampled token's KV is not
         # yet written (it is written when fed as input next step).
         full = req.num_computed_tokens // page
-        tokens = req.all_token_ids
-        while committed < full:
-            chunk = tokens[committed * page : (committed + 1) * page]
+        while committed < full:  # (a page's tokens, never the whole history)
+            chunk = req.tokens_between(committed * page, (committed + 1) * page)
             h = hash_page(parent, chunk, extra=self._hash_extra(req))
             self.allocator.commit_page(req.block_ids[committed], h, chunk, parent)
             parent = h

@@ -111,7 +111,6 @@ def make_engine_config(args, lora_adapters=None):
             max_num_seqs=args.max_num_seqs,
             max_num_batched_tokens=args.max_num_batched_tokens,
             decode_window=args.decode_window,
-            async_scheduling=args.async_scheduling,
             speculative_ngram=args.speculative_ngram,
             spec_ngram_k=args.spec_ngram_k,
             spec_ngram_min_match=args.spec_ngram_min_match,
@@ -205,14 +204,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--max-num-seqs", type=int, default=64)
     p.add_argument("--max-num-batched-tokens", type=int, default=2048)
     p.add_argument("--decode-window", type=int, default=1)
-    p.add_argument(
-        "--async-scheduling", action="store_true",
-        help="overlap host scheduling with device execution (vLLM v1 "
-             "--async-scheduling role): the next step is scheduled and "
-             "staged while the current one runs; tokens stream one step "
-             "late. Auto-disabled for multi-host lockstep engines and "
-             "P/D producers (docs/architecture/async-scheduling.md)",
-    )
     p.add_argument(
         "--speculative-ngram", action="store_true",
         help="model-free speculative decoding: n-gram prompt-lookup "

@@ -496,7 +496,40 @@ class AsyncEngine:
         assert self._loop is not None
         self._loop.call_soon_threadsafe(q.put_nowait, item)
 
+    def _intake(self) -> int:
+        """Hand the engine what arrived since the last call: aborts, then
+        new requests. Engine-thread only: between two steps (``_run``) and,
+        as ``LLMEngine.intake_hook``, from inside the pipelined step while
+        the device runs, so that an arrival during step N rides step N+1.
+        Returns how many of either there were."""
+        with self._lock:
+            if not self._inbox and not self._aborts:
+                return 0
+            pending, self._inbox = self._inbox, []
+            aborts, self._aborts = self._aborts, []
+        with profiling.span("llmd.serve.intake", added=len(pending)):
+            for rid in aborts:
+                self.engine.abort_request(rid)
+            for p in pending:
+                try:
+                    self.engine.add_request(
+                        p.prompt_token_ids,
+                        p.sampling,
+                        request_id=p.request_id,
+                        priority=p.priority,
+                        kv_transfer_params=p.kv_transfer_params,
+                        lora_id=p.lora_id,
+                        lora_name=p.lora_name,
+                        resume_output_tokens=p.resume_output_tokens,
+                    )
+                # llmd: allow(broad-except) -- surfaced: the caller receives it as a RequestFailed terminal item
+                except Exception as e:  # validation errors -> caller
+                    _release_pulled(self.engine, p.kv_transfer_params)
+                    self._deliver(p.request_id, RequestFailed(str(e)))
+        return len(pending) + len(aborts)
+
     def _run(self) -> None:
+        self.engine.intake_hook = self._intake
         while True:
             with self._lock:
                 while not self._stop and (
@@ -514,30 +547,10 @@ class AsyncEngine:
                     for p in self._inbox:
                         _release_pulled(self.engine, p.kv_transfer_params)
                     self._inbox = []
+                    self.engine.intake_hook = None
                     return
-                pending, self._inbox = self._inbox, []
-                aborts, self._aborts = self._aborts, []
-            with profiling.span("llmd.serve.intake", added=len(pending)):
-                for rid in aborts:
-                    self.engine.abort_request(rid)
-                for p in pending:
-                    try:
-                        self.engine.add_request(
-                            p.prompt_token_ids,
-                            p.sampling,
-                            request_id=p.request_id,
-                            priority=p.priority,
-                            kv_transfer_params=p.kv_transfer_params,
-                            lora_id=p.lora_id,
-                            lora_name=p.lora_name,
-                            resume_output_tokens=p.resume_output_tokens,
-                        )
-                    # llmd: allow(broad-except) -- surfaced: the caller receives it as a RequestFailed terminal item
-                    except Exception as e:  # validation errors -> caller
-                        _release_pulled(self.engine, p.kv_transfer_params)
-                        self._deliver(p.request_id, RequestFailed(str(e)))
-                idle = not self.engine.has_work()
-            if idle:
+            self._intake()
+            if not self.engine.has_work():
                 continue
             try:
                 # Watchdog heartbeat brackets the one blocking call.

@@ -119,7 +119,7 @@ def render_metrics(
         # context-parallel prefill (paged-out residency is a gauge above).
         "kv_pager_prefetch_late_total": stats.kv_pager_prefetch_late_total,
         "cp_ring_steps_total": stats.cp_ring_steps_total,
-        # Async stepping (speculate/rollback contract)
+        # The step pipeline (speculate/rollback contract)
         "engine_steps_total": stats.engine_steps_total,
         "step_host_gap_ms_total": round(stats.step_host_gap_ms_total, 3),
         # Where a step's time goes (EngineStats says what each phase
@@ -131,6 +131,10 @@ def render_metrics(
         "step_launch_ms_total": round(stats.step_launch_ms_total, 3),
         "step_wait_ms_total": round(stats.step_wait_ms_total, 3),
         "step_finish_ms_total": round(stats.step_finish_ms_total, 3),
+        # The pipelined step's host gap in its two parts: readback to
+        # reconciled, reconciled to the next dispatch's return.
+        "step_commit_ms_total": round(stats.step_commit_ms_total, 3),
+        "step_redispatch_ms_total": round(stats.step_redispatch_ms_total, 3),
         "step_ms_total": round(stats.step_ms_total, 3),
         "steps_prefill_total": stats.steps_prefill_total,
         "steps_decode_total": stats.steps_decode_total,
@@ -145,6 +149,11 @@ def render_metrics(
         "step_h2d_transfers_total": stats.step_h2d_transfers_total,
         "step_h2d_bytes_total": stats.step_h2d_bytes_total,
         "async_rollbacks_total": stats.async_rollbacks_total,
+        # How often the pipeline engages: steps dispatched from a slot
+        # staged under the step before, and those topped up with
+        # requests admitted after the speculative schedule.
+        "steps_prestaged_total": stats.steps_prestaged_total,
+        "steps_topped_up_total": stats.steps_topped_up_total,
         "decode_dispatches_total": stats.decode_dispatches_total,
         # Unified single-dispatch steps (the family split of
         # decode_dispatches_total) and EVERY program engine steps

@@ -334,16 +334,43 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                    "recompute (never an error upstream); a miss burst "
                    "with the master down rides the read breaker's "
                    "cooldown."),
-        row("Step pipeline (async stepping)"),
+        row("Step pipeline (the pipelined step)"),
         panel("Host gap per step",
               [f"llmd:step_host_gap_ms{M}",
                f"rate(llmd:step_host_gap_ms_total{M}[5m]) / "
                f"rate(llmd:engine_steps_total{M}[5m])"],
               legends=["last step (ms)", "mean (5m)"], unit="ms",
-              desc="Per-step host time the device idles for. Async "
-                   "scheduling shrinks it to the reconcile sliver; a "
-                   "regression here re-serializes the pipeline "
+              desc="Per-step host time the device idles for: in the "
+                   "pipelined step the time from a readback's end to the "
+                   "next dispatch's return (commit, reconcile, a last "
+                   "top-up, fill, put + call); a regression here "
+                   "re-serializes the pipeline "
                    "(docs/architecture/async-scheduling.md)."),
+        panel("Host gap by part",
+              [f"rate(llmd:step_commit_ms_total{M}[5m]) / "
+               f"rate(llmd:engine_steps_total{M}[5m])",
+               f"rate(llmd:step_redispatch_ms_total{M}[5m]) / "
+               f"rate(llmd:engine_steps_total{M}[5m])"],
+              legends=["commit + reconcile", "top-up + fill + put + call"],
+              unit="ms",
+              desc="The pipelined step's gap in its two parts: readback "
+                   "to reconciled (collect, scheduler update, late "
+                   "intake, rollbacks) and reconciled to the next "
+                   "dispatch's return. Both 0 on an engine that keeps "
+                   "the synchronous step (lockstep, P/D producer)."),
+        panel("Steps dispatched from a prestaged slot",
+              [f"rate(llmd:steps_prestaged_total{M}[5m]) / "
+               f"rate(llmd:engine_steps_total{M}[5m])",
+               f"rate(llmd:steps_topped_up_total{M}[5m]) / "
+               f"rate(llmd:engine_steps_total{M}[5m])"],
+              legends=["prestaged share", "topped-up share"],
+              unit="percentunit",
+              desc="How often the pipeline engages: the share of steps "
+                   "whose batch was scheduled and staged while the step "
+                   "before ran (near 1 under load; 0 on an engine that "
+                   "keeps the synchronous step), and of steps whose "
+                   "staged batch took in requests that arrived after the "
+                   "speculative schedule."),
         panel("Step time by phase",
               [f"rate(llmd:step_{ph}_ms_total{M}[5m]) / "
                f"rate(llmd:engine_steps_total{M}[5m])"
@@ -353,8 +380,10 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
               legends=["admit", "schedule", "launch", "wait (device + "
                        "readback)", "finish", "whole step"], unit="ms",
               desc="Mean ms a step spends in each phase (the spans of "
-                   "llmd_tpu/obs/profiling.py by the same names). In sync "
-                   "mode schedule + launch + finish is the host gap; "
+                   "llmd_tpu/obs/profiling.py by the same names), as host "
+                   "time spent: in the pipelined step schedule and most "
+                   "of launch run under the device; in the synchronous "
+                   "step schedule + launch + finish is the host gap; "
                    "whatever of the whole step the five do not cover is "
                    "the engine's own bookkeeping after the step."),
         panel("Step time by kind",

@@ -134,3 +134,16 @@ def devices():
     devs = jax.devices()
     assert len(devs) == 8, devs
     return devs
+
+
+@pytest.fixture
+def unforeseen_finishes(monkeypatch):
+    """A LENGTH finish is the one late finish the pipelined step's
+    speculative schedule foresees (``EngineScheduler._ends_in_flight``), so
+    it rolls nothing back. Tests of the rollback machinery that end their
+    requests by ``max_tokens`` take this fixture: the finishes then land
+    one speculated step late, as a stop token's does."""
+    from llmd_tpu.engine.scheduler import EngineScheduler
+
+    monkeypatch.setattr(EngineScheduler, "_ends_in_flight", lambda self, req: False)
+

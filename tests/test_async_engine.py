@@ -1,4 +1,4 @@
-"""Async stepping (SchedulerConfig.async_scheduling) tests.
+"""Async (pipelined) stepping tests.
 
 The contract (docs/architecture/async-scheduling.md): the two-slot
 pipeline — speculative scheduling against dispatched token counts, one
@@ -29,12 +29,12 @@ def make_engine(
         cache=CacheConfig(page_size=page, num_blocks=num_blocks, dtype="float32"),
         scheduler=SchedulerConfig(
             max_num_seqs=max_seqs, max_num_batched_tokens=max_batched,
-            decode_window=window, async_scheduling=async_mode,
+            decode_window=window,
         ),
         parallel=ParallelConfig(tensor_parallel_size=1),
         seed=seed,
     )
-    return LLMEngine(cfg)
+    return LLMEngine(cfg, _synchronous_step=not async_mode)
 
 
 PROMPTS = [
@@ -42,6 +42,15 @@ PROMPTS = [
     [3, 3, 7, 1],
     [1, 5, 9, 13, 2, 8, 4, 4, 4, 4, 6, 6, 6, 6, 11],
 ]
+
+
+def _warm(eng, prompts, max_tokens):
+    """Run other prompts of the same lengths through, so that every step
+    shape of the test proper is warm."""
+    eng.generate(
+        [[(t + 1) % 256 for t in p] for p in prompts],
+        SamplingParams(temperature=0.0, max_tokens=max_tokens),
+    )
 
 
 def test_async_parity_basic():
@@ -135,16 +144,26 @@ def test_async_rollback_on_eos():
     assert eng.allocator.usage() == 0.0
 
 
-def test_async_rollback_on_max_tokens():
-    """LENGTH finishes always land one speculated step late in async
-    mode: each completed request must roll its staged row back."""
+def test_async_max_tokens_finish_is_foreseen_not_rolled_back():
+    """A LENGTH finish is the one late finish the speculative schedule
+    can be certain of (the step in flight emits the request's last
+    token whatever it samples): the row is not staged again, so nothing
+    is rolled back, and the streams stay the synchronous engine's. (It
+    was rolled back once per request before PR 38.)"""
     params = SamplingParams(temperature=0.0, max_tokens=5)
     eng = make_engine(True)
     sync = make_engine(False).generate(PROMPTS, params)
     asyn = eng.generate(PROMPTS, params)
     assert list(sync.values()) == list(asyn.values())
-    assert eng.stats.async_rollbacks_total >= len(PROMPTS)
+    assert eng.stats.async_rollbacks_total == 0
     assert eng.allocator.usage() == 0.0
+    # the model length ends a request the same way
+    params = SamplingParams(temperature=0.0, max_tokens=500, ignore_eos=True)
+    short = dict(max_model_len=24)
+    eng = make_engine(True, **short)
+    sync = make_engine(False, **short).generate(PROMPTS[:2], params)
+    assert list(sync.values()) == list(eng.generate(PROMPTS[:2], params).values())
+    assert eng.stats.async_rollbacks_total == 0 and eng.allocator.usage() == 0.0
 
 
 def test_async_rollback_stop_token_mid_batch():
@@ -186,11 +205,14 @@ def test_async_deferred_abort_of_inflight_request():
     reconcile point (pages freed only after the device stops writing
     them); the other request keeps decoding to completion."""
     eng = make_engine(True)
+    _warm(eng, PROMPTS[:2], 6)
     keep = eng.add_request(PROMPTS[0], SamplingParams(temperature=0.0, max_tokens=6))
     victim = eng.add_request(PROMPTS[1], SamplingParams(temperature=0.0, max_tokens=6))
-    eng.step()  # primes the pipeline: both requests now in flight
-    assert eng.abort_request(victim)
     got: dict[str, list[int]] = {keep: [], victim: []}
+    for out in eng.step():  # lands the prompts, primes the pipeline:
+        got[out.request_id].extend(out.new_token_ids)
+    assert eng._inflight is not None  # both requests' decode rows in flight
+    assert eng.abort_request(victim)
     for _ in range(64):
         if not eng.has_work():
             break
@@ -205,13 +227,13 @@ def test_async_deferred_abort_of_inflight_request():
 
 
 def test_async_forced_off_for_producer_role():
-    """P/D eager-ACK producers keep the synchronous step shape even when
-    the flag is on (response-ordering guarantee)."""
+    """P/D eager-ACK producers keep the synchronous step shape by what
+    the engine observes of its role (response-ordering guarantee)."""
     cfg = EngineConfig(
         model=tiny_model_config(),
         cache=CacheConfig(page_size=4, num_blocks=64, dtype="float32"),
         scheduler=SchedulerConfig(
-            max_num_seqs=4, max_num_batched_tokens=64, async_scheduling=True
+            max_num_seqs=4, max_num_batched_tokens=64
         ),
         parallel=ParallelConfig(tensor_parallel_size=1),
         kv_role="kv_producer",
@@ -225,13 +247,20 @@ def test_async_forced_off_for_producer_role():
 
 
 def test_async_streams_one_step_late_then_drains():
-    """The first step primes the pipeline (no outputs); every token
-    still arrives, and has_work() stays true until the slot drains."""
+    """A pipeline that starts from empty lands its first step at once
+    (nothing to overlap with) and leaves the next in flight; from then on
+    a call returns the step before's tokens; every token still arrives,
+    and has_work() stays true until the slot drains."""
     eng = make_engine(True)
+    _warm(eng, [PROMPTS[1]], 4)
     eng.add_request(PROMPTS[1], SamplingParams(temperature=0.0, max_tokens=4))
-    assert eng.step() == []  # prime: dispatch only
-    assert eng.has_work()  # in flight, even though queues may look empty
-    toks: list[int] = []
+    toks: list[int] = [t for out in eng.step() for t in out.new_token_ids]
+    assert len(toks) == 1  # the prompt's first token, not a call late
+    assert eng._inflight is not None and eng.has_work()  # primed
+    steps = eng.stats.engine_steps_total
+    for out in eng.step():  # dispatches the step after, returns the one before
+        toks.extend(out.new_token_ids)
+    assert eng.stats.engine_steps_total == steps + 1 and eng._inflight is not None
     for _ in range(32):
         if not eng.has_work():
             break

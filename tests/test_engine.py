@@ -422,14 +422,14 @@ def test_unified_vs_split_parity_seeded_sampling():
     assert eng.stats.unified_steps_total > 0
 
 
-def test_unified_vs_split_parity_async_rollback():
+def test_unified_vs_split_parity_async_rollback(unforeseen_finishes):
     """Unified prestaging composes with async stepping: staged unified
     batches survive late-finish rollbacks (surviving rows sliced out of
     the prestaged arrays) and streams stay byte-identical to the split
     sync engine."""
     sp = SamplingParams(temperature=0.0, max_tokens=12, ignore_eos=True)
     base = make_unified(False).generate([list(p) for p in MIXED_PROMPTS], sp)
-    eng = make_unified(True, async_scheduling=True)
+    eng = make_unified(True)  # (pipelined, as every engine is)
     out = eng.generate([list(p) for p in MIXED_PROMPTS], sp)
     assert list(base.values()) == list(out.values())
     assert eng._inflight is None
@@ -447,9 +447,9 @@ def test_unified_one_readback_per_step():
     calls = {"n": 0}
     orig = eng.runner.wait_step
 
-    def counting(prefill, decode, unified=None):
+    def counting(*args, **kw):
         calls["n"] += 1
-        return orig(prefill, decode, unified)
+        return orig(*args, **kw)
 
     eng.runner.wait_step = counting
     eng.generate([list(p) for p in MIXED_PROMPTS], sp)

@@ -67,7 +67,9 @@ def test_resident_pages_bounded_by_window():
             break
         eng.step()
         for req in eng.scheduler.running:
-            if req.request_id == rid:
+            # (once the prompt has committed: the pipelined step returns
+            # from its first call with the whole prefill still in flight)
+            if req.request_id == rid and req.in_decode:
                 resident = len(req.block_ids) - len(req.paged_out)
                 peak_resident = max(peak_resident, resident)
     page = 4

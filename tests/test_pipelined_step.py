@@ -1,0 +1,422 @@
+"""The pipelined step as the engine's default shape.
+
+``tests/test_async_engine.py`` pins the two-slot pipeline to the
+synchronous engine's token streams over the paged pool. Here: the models
+whose caches were written for a scheduler whose dispatched and computed
+positions are equal (the K-EXAONE ring and its retained sections, the
+granite state pool and its snapshots, the sparse-attention indexer plane,
+the bucketed latent step), each with a hybrid or prefix hit in the batch and
+a stop token that rolls one row back while its mates go on; the top-up
+admission (a request that arrives while step N runs rides step N+1); the
+order in which ``AsyncEngine`` hands a step's outputs on; and the benchmark's
+shape ladder, one dispatch a bucket.
+"""
+
+import asyncio
+import pathlib
+import sys
+
+import numpy as np
+import pytest
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+from llmd_tpu.config import (  # noqa: E402
+    CacheConfig,
+    EngineConfig,
+    SchedulerConfig,
+)
+from llmd_tpu.engine import LLMEngine, SamplingParams  # noqa: E402
+from llmd_tpu.engine.request import PriorityClass, RequestStatus  # noqa: E402
+from llmd_tpu.models.registry import get_model_config  # noqa: E402
+
+
+def tokens(n, seed=0):
+    return np.random.default_rng(seed).integers(0, 256, size=n).tolist()
+
+
+# model -> (cache keywords, scheduler keywords, shared prefix tokens)
+GEOMETRY = {
+    "tiny": (dict(page_size=4, num_blocks=128), dict(max_num_batched_tokens=64), 0),
+    # 3 window layers to 1 full: two KV pools, retained sliding sections
+    "tiny-exaone": (dict(page_size=4, num_blocks=256, swa_ring=True), dict(max_num_batched_tokens=32), 48),
+    # Mamba-2 mixers + one attention layer: a state pool with snapshots
+    "tiny-granite-hybrid": (dict(page_size=4, num_blocks=256), dict(max_num_batched_tokens=16), 40),
+    # learned top-k sparse attention: the indexer plane under the page ids
+    "tiny-dsa": (dict(page_size=8, num_blocks=128), dict(max_num_batched_tokens=48), 112),
+    # latent attention: the bucketed unified step, not the flat one
+    "tiny-mla": (dict(page_size=4, num_blocks=256), dict(max_num_batched_tokens=32), 48),
+}
+HYBRID = sorted(set(GEOMETRY) - {"tiny"})
+
+
+def make_engine(model: str, pipelined: bool, max_seqs=4, num_blocks=None, **sched) -> LLMEngine:
+    cache, sched_kw, _ = GEOMETRY[model]
+    if num_blocks is not None:
+        cache = {**cache, "num_blocks": num_blocks}
+    return LLMEngine(EngineConfig(
+        model=get_model_config(model),
+        cache=CacheConfig(dtype="float32", **cache),
+        scheduler=SchedulerConfig(
+            max_num_seqs=max_seqs, **{**sched_kw, **sched}
+        ),
+    ), _synchronous_step=not pipelined)
+
+
+def serve(eng: LLMEngine, prompts, max_tokens=8, stop=()):
+    """[(tokens, log-probs, request)] per prompt, all in the engine at once."""
+    sp = SamplingParams(max_tokens=max_tokens, temperature=0.0, ignore_eos=not stop,
+                        stop_token_ids=tuple(stop), logprobs=True)
+    ids = [eng.add_request(list(p), sp) for p in prompts]
+    reqs = list(eng.scheduler.waiting)
+    toks = {rid: [] for rid in ids}
+    while eng.has_work():
+        for out in eng.step():
+            toks[out.request_id].extend(out.new_token_ids)
+    return [(toks[rid], np.asarray(r.output_logprobs), r) for rid, r in zip(ids, reqs)]
+
+
+def warm(eng: LLMEngine, prompts, max_tokens) -> None:
+    """Other prompts of the same lengths, served first: every step shape of
+    the test proper is then warm."""
+    serve(eng, [[(t + 1) % 256 for t in p] for p in prompts], max_tokens=max_tokens)
+
+
+def hit_counters(eng: LLMEngine) -> tuple:
+    eng._refresh_gauges()
+    s = eng.stats
+    return (s.swa_section_hits_total, s.swa_section_misses_total,
+            s.state_snapshot_hits_total, s.state_snapshot_misses_total)
+
+
+def pools_in_use(eng: LLMEngine) -> tuple:
+    """Pages that live references hold, main pool and ring / state pool."""
+    a, w = eng.allocator, eng.swa_allocator
+    return (a.num_pages - a.num_free_pages, None if w is None else w.num_pages - w.num_free_pages)
+
+
+def session(model: str, pipelined: bool, stop=()):
+    """Two requests over a shared prefix one after the other (the second
+    leaves the retained section or snapshot at the prefix's end behind, where
+    the model has one), then three together: two hits of the prefix and a
+    stranger, with ``stop`` to end one of them early."""
+    eng = make_engine(model, pipelined)
+    shared = tokens(GEOMETRY[model][2], seed=5)
+    warm = [shared + tokens(n, seed=s) for n, s in ((7, 6), (13, 7))]
+    batch = [shared + tokens(11, seed=8), tokens(23, seed=9), shared + tokens(9, seed=10)]
+    for p in warm:
+        serve(eng, [p], max_tokens=4)
+    got = serve(eng, batch, max_tokens=10, stop=stop)
+    return eng, got
+
+
+@pytest.mark.parametrize("model", HYBRID)
+def test_pipelined_equals_synchronous_with_a_hit_and_a_mid_batch_stop(model):
+    """The same tokens, request by request, and the same caches afterwards:
+    the hits taken, the pages and ring or state slots still held. The stop
+    token ends ONE row while its mates decode on, a step after the pipelined
+    engine has staged that row again: it is rolled back and gives back its
+    pages, its ring or slot."""
+    _, free_run = session(model, pipelined=False)
+    stop = free_run[0][0][3]  # ends request 0 early; the others may never emit it
+    sync, want = session(model, pipelined=False, stop=(stop,))
+    pipe, got = session(model, pipelined=True, stop=(stop,))
+    assert pipe._async and not sync._async
+    assert [t for t, _, _ in got] == [t for t, _, _ in want]
+    assert len(want[0][0]) < 10 and want[0][0][-1] == stop  # it did end early
+    for (_, lp, _), (_, ref, _) in zip(got, want):
+        np.testing.assert_allclose(lp, ref, atol=2e-5)
+    assert [r.num_cached_tokens for _, _, r in got] == [r.num_cached_tokens for _, _, r in want]
+    assert got[0][2].num_cached_tokens > 0  # the batch did take a hit
+    assert hit_counters(pipe) == hit_counters(sync)
+    assert pools_in_use(pipe) == pools_in_use(sync)
+    assert pipe._inflight is None and pipe.stats.async_rollbacks_total >= 1
+    assert pipe.stats.steps_prestaged_total > 0 and sync.stats.steps_prestaged_total == 0
+    # the host-counted kernel counters count dispatched rows, never a rolled-back one
+    for name in ("ssm_update_rows_total", "ssm_scan_tokens_total", "sparse_bound_tokens_total",
+                 "sparse_unbound_tokens_total", "indexer_keys_scored_total", "live_tokens_total"):
+        assert getattr(pipe.stats, name) == getattr(sync.stats, name), name
+
+
+# --- the top-up admission ----------------------------------------------------------
+
+
+class Arrivals:
+    """An ``intake_hook`` that hands the engine one request: at a poll while
+    the step in flight still runs, or at the one after its readback."""
+
+    def __init__(self, eng: LLMEngine, while_running: bool, prompt, sp):
+        self.eng, self.while_running, self.prompt, self.sp = eng, while_running, prompt, sp
+        self.polls_running, self.polls_read, self.rid = 0, 0, None
+
+    def __call__(self) -> int:
+        running = self.eng._inflight is not None
+        self.polls_running += running
+        self.polls_read += not running
+        if self.rid is not None or running != self.while_running:
+            return 0
+        self.rid = self.eng.add_request(list(self.prompt), self.sp)
+        return 1
+
+
+@pytest.mark.parametrize("model", ["tiny", "tiny-granite-hybrid"])
+@pytest.mark.parametrize("while_running", [True, False], ids=["while_the_device_runs", "after_the_readback"])
+def test_a_request_that_arrives_while_a_step_runs_rides_the_next(model, while_running):
+    """Step N is in flight and step N+1 staged (the hook is polled while the
+    device runs), or N has just been read back (polled once more): the
+    arrival is admitted into the
+    staged batch and dispatched with it, as the synchronous engine, whose
+    intake runs between two steps, would have it; the step is counted."""
+    sp = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
+    first, late = tokens(9, seed=1), tokens(13, seed=2)
+
+    eng = make_engine(model, pipelined=True)
+    warm(eng, [first, late], 6)
+    topped = eng.stats.steps_topped_up_total
+    a = eng.add_request(first, sp)
+    got: dict = {a: []}
+    for out in eng.step():  # the pipeline starts: a's prompt lands, step N is in flight behind it
+        got[out.request_id].extend(out.new_token_ids)
+    assert eng._inflight is not None
+    hook = eng.intake_hook = Arrivals(eng, while_running, late, sp)
+    for out in eng.step():  # N+1 staged, N read back, N+1 dispatched
+        got[out.request_id].extend(out.new_token_ids)
+    assert hook.rid is not None and hook.polls_running >= 1 and hook.polls_read == 1
+    batch = eng._inflight.batch  # (a state-space prompt ends in a chunk of its own: a may still prefill)
+    assert batch.prefills[-1].request.request_id == hook.rid and eng.stats.steps_topped_up_total == topped + 1
+    assert [s.request.request_id for s in batch.seqs if s.request.request_id != hook.rid] == [a]
+    got[hook.rid] = []
+    while eng.has_work():
+        for out in eng.step():
+            got[out.request_id].extend(out.new_token_ids)
+    assert eng.stats.steps_topped_up_total == topped + 1
+
+    sync = make_engine(model, pipelined=False)
+    warm(sync, [first, late], 6)  # (the same prefix cache and state pool as the pipelined engine's)
+    want: dict = {sync.add_request(first, sp): []}
+    for _ in range(2):  # the step that landed, and step N
+        for out in sync.step():
+            want[out.request_id].extend(out.new_token_ids)
+    want[sync.add_request(late, sp)] = []  # between two steps
+    while sync.has_work():
+        for out in sync.step():
+            want[out.request_id].extend(out.new_token_ids)
+    assert list(got.values()) == list(want.values())
+    assert pools_in_use(eng) == pools_in_use(sync)
+
+
+def test_an_arrival_during_the_step_a_pipeline_starts_with_rides_the_step_behind_it():
+    """A pipeline that starts from empty lands its first step synchronously
+    (no poll in that wait) and dispatches the next behind it: what arrived
+    meanwhile is taken in before that dispatch, not a step later."""
+    sp = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
+    first, late = tokens(9, seed=1), tokens(13, seed=2)
+    eng = make_engine("tiny", pipelined=True)
+    warm(eng, [first, late], 6)
+    a = eng.add_request(first, sp)
+    hook = eng.intake_hook = Arrivals(eng, False, late, sp)
+    got: dict = {a: [t for out in eng.step() for t in out.new_token_ids]}
+    assert len(got[a]) == 1 and hook.polls_read == 1 and hook.polls_running == 0
+    assert {s.request.request_id for s in eng._inflight.batch.seqs} == {a, hook.rid}
+    got[hook.rid] = []
+    while eng.has_work():
+        for out in eng.step():
+            got[out.request_id].extend(out.new_token_ids)
+
+    sync = make_engine("tiny", pipelined=False)
+    warm(sync, [first, late], 6)
+    want: dict = {sync.add_request(first, sp): []}
+    for out in sync.step():
+        want[out.request_id].extend(out.new_token_ids)
+    want[sync.add_request(late, sp)] = []  # between the first step and the second
+    while sync.has_work():
+        for out in sync.step():
+            want[out.request_id].extend(out.new_token_ids)
+    assert list(got.values()) == list(want.values())
+
+
+def test_an_abort_of_a_staged_row_that_was_never_dispatched_is_dropped_before_the_wait():
+    """The hook's first poll brings an abort of a request that the
+    speculative schedule has just admitted: its pages are free at once
+    (nothing of it is in flight), and the row must not be dispatched."""
+    sp = SamplingParams(max_tokens=5, temperature=0.0, ignore_eos=True)
+    eng = make_engine("tiny", pipelined=True)
+    warm(eng, [tokens(9, seed=1), tokens(7, seed=2)], 5)
+    keep = eng.add_request(tokens(9, seed=1), sp)
+    got = [t for out in eng.step() for t in out.new_token_ids]  # lands, and primes the pipeline
+    gone = eng.add_request(tokens(7, seed=2), sp)  # waiting: the next schedule admits it
+
+    def hook() -> int:
+        hook.calls += 1
+        return int(hook.calls == 1 and eng.abort_request(gone))
+
+    hook.calls = 0
+    eng.intake_hook = hook
+    got += [t for out in eng.step() for t in out.new_token_ids]
+    assert [s.request.request_id for s in eng._inflight.batch.seqs] == [keep]
+    assert eng.stats.async_rollbacks_total == 1
+    while eng.has_work():
+        got += [t for out in eng.step() for t in out.new_token_ids]
+    (want, _, _), = serve(make_engine("tiny", pipelined=False), [tokens(9, seed=1)], max_tokens=5)
+    assert got == want and eng.allocator.usage() == 0.0
+
+
+def test_a_staged_batch_row_reclaimed_by_a_head_that_then_fails_admission_is_not_dispatched():
+    """After the readback an interactive arrival takes the staged batch-band
+    row's pages (recompute-preemption) and still does not fit: nothing was
+    added to the staged batch, and the preempted row must leave it all the
+    same, or the device would write its KV into pages that are free."""
+    page = GEOMETRY["tiny"][0]["page_size"]
+    sp = SamplingParams(max_tokens=12, temperature=0.0, ignore_eos=True)
+    held, batch_row, head = tokens(30, seed=1), tokens(6, seed=2), tokens(34, seed=3)
+    alone = [serve(make_engine("tiny", pipelined=False), [p], max_tokens=12)[0][0]
+             for p in (held, batch_row, head)]
+
+    eng = make_engine("tiny", pipelined=True, num_blocks=16)
+    warm(eng, [held, batch_row], 12)
+    dispatch = eng._dispatch_async
+
+    def only_running_rows(batch, staged=None):
+        assert all(s.request.status is RequestStatus.RUNNING for s in batch.seqs)
+        assert all(len(s.request.block_ids) * page >= s.request.num_dispatched_tokens + s.num_tokens
+                   for s in batch.seqs)
+        return dispatch(batch, staged)
+
+    eng._dispatch_async = only_running_rows
+    r = eng.add_request(held, sp)
+    b = eng.add_request(batch_row, sp, priority=int(PriorityClass.BATCH))
+    got: dict = {r: [], b: []}
+    for out in eng.step():  # both prompts land, the first decode step is in flight
+        got[out.request_id].extend(out.new_token_ids)
+    assert [s.request.request_id for s in eng._inflight.batch.seqs] == [r, b]
+    hook = eng.intake_hook = Arrivals(eng, False, head, sp)
+    for out in eng.step():  # (r, b) staged again; the head arrives behind the readback
+        got[out.request_id].extend(out.new_token_ids)
+    waiting = {q.request_id: q for q in eng.scheduler.waiting}
+    assert set(waiting) == {hook.rid, b} and not waiting[b].block_ids  # reclaimed, and still short
+    assert eng.stats.batch_preemptions == 1 and eng.stats.steps_topped_up_total == 0
+    assert [s.request.request_id for s in eng._inflight.batch.seqs] == [r]
+    got[hook.rid] = []
+    eng.intake_hook = None
+    while eng.has_work():
+        for out in eng.step():
+            got[out.request_id].extend(out.new_token_ids)
+    assert [got[r], got[b], got[hook.rid]] == alone  # (the preempted row recomputed its own)
+    assert eng.allocator.usage() == 0.0 and eng._inflight is None
+
+
+# --- the serving loop ---------------------------------------------------------------
+
+
+def test_a_steps_outputs_are_delivered_after_the_next_steps_dispatch():
+    """``AsyncEngine._run``: step N's outputs reach their queues while step
+    N+1 runs, so between two deliveries there is a dispatch; the stream a
+    client sees is the synchronous engine's: the same tokens in order, one
+    terminal item, the last."""
+    from llmd_tpu.serve.async_engine import AsyncEngine
+
+    sp = SamplingParams(max_tokens=7, temperature=0.0, ignore_eos=True)
+    prompts = [tokens(9, seed=1), tokens(5, seed=2)]
+    eng = make_engine("tiny", pipelined=True)
+    events: list = []
+    dispatch, assemble = eng._dispatch_async, eng._assemble_outputs
+
+    def dispatching(*a, **kw):
+        events.append("dispatch")
+        return dispatch(*a, **kw)
+
+    def assembling(*a, **kw):
+        outs = assemble(*a, **kw)
+        events.append(("outputs", len(outs)))
+        return outs
+
+    eng._dispatch_async, eng._assemble_outputs = dispatching, assembling
+
+    async def run():
+        served = AsyncEngine(eng, watchdog_s=0)
+        served.start(asyncio.get_running_loop())
+        deliver = served._deliver
+
+        def delivering(rid, item):
+            events.append("deliver")
+            return deliver(rid, item)
+
+        served._deliver = delivering
+
+        async def one(i, p):
+            return [out async for out in served.generate(f"r{i}", p, sp)]
+
+        try:
+            return await asyncio.wait_for(asyncio.gather(*(one(i, p) for i, p in enumerate(prompts))), 120)
+        finally:
+            served.stop()
+
+    streams = asyncio.run(run())
+    assert eng.intake_hook is None  # the hook goes with the serving thread
+    want = serve(make_engine("tiny", pipelined=False), prompts, max_tokens=7)
+    for items, (toks, _, _) in zip(streams, want):
+        assert [t for it in items for t in it.new_token_ids] == toks
+        assert [it.finished for it in items] == [False] * (len(items) - 1) + [True]
+        assert [it.num_output_tokens for it in items] == sorted(it.num_output_tokens for it in items)
+    # Every step's outputs were assembled after the next step's dispatch and
+    # delivered after it too (but the step the pipeline started with, which
+    # lands at once and is followed by the first pipelined dispatch, and the
+    # last: nothing is left to dispatch).
+    groups = [i for i, e in enumerate(events) if isinstance(e, tuple)]
+    assert len(groups) >= 7
+    assert events[groups[0] + 1] == "dispatch"
+    for i in groups[1:-1]:
+        assert events[i - 1] == "dispatch", events[max(0, i - 3): i + 2]
+    first_deliver = events.index("deliver")
+    assert events[:first_deliver].count("dispatch") >= 1  # the next step is on the device before any output goes out
+    assert eng.stats.steps_prestaged_total >= 5
+
+
+# --- the benchmark's shape ladder ------------------------------------------------------
+
+
+def test_the_shape_ladder_reaches_each_bucket_once():
+    """``perfbench/topologies/engine.py::_run_shape`` steps once, aborts and
+    drains. The pipeline starts from empty at every bucket, so its one step
+    lands at once on the synchronous step's path (a shape's first call is
+    seconds of tracing and lowering whose time follows the path it is
+    called on: PERF.md section 6, PR 38) and nothing is left in flight.
+    Every T bucket of the flat step is traced and dispatched exactly once,
+    and traffic afterwards traces nothing."""
+    from perfbench.topologies import engine as topology
+
+    eng = make_engine("tiny", pipelined=True, max_seqs=8)
+    runner = eng.runner
+    assert runner.flat_t_buckets and eng._async
+
+    class Harness:  # what _run_shape and _ladder read of a System
+        engine, config = eng, eng.config
+        geo: dict = {}
+        vocab_size, max_model_len = eng.config.model.vocab_size, eng.config.model.max_model_len
+        _SamplingParams = SamplingParams
+        _ladder, _run_shape = topology.System._ladder, topology.System._run_shape
+        _tokens, _sampling = topology.System._tokens, topology.System._sampling
+
+    dispatched: list = []
+    exec_flat = runner._exec_flat
+
+    def counting(arrays, all_greedy):
+        dispatched.append(arrays["stream"].shape[0])
+        return exec_flat(arrays, all_greedy)
+
+    runner._exec_flat = counting
+    h, rng = Harness(), np.random.default_rng(0)
+    ladder = h._ladder()
+    buckets = [T for _, T in ladder]
+    assert buckets == [T for T in runner.flat_t_buckets if T <= 64] and len(buckets) >= 4
+    for shape in ladder:
+        h._run_shape(rng, *shape)
+        assert not eng.has_work() and eng._inflight is None
+    assert dispatched == buckets  # one dispatch a bucket, in the ladder's order
+    assert sorted(shape[0] for _, fam, shape in runner.traced_programs if fam == "flat") == buckets
+    assert eng.allocator.usage() == 0.0
+    traced = runner.programs_traced
+    serve(eng, [tokens(n, seed=n) for n in (5, 17, 40)], max_tokens=6)
+    assert runner.programs_traced == traced  # every shape the traffic reaches was warmed

@@ -39,7 +39,7 @@ def anyio_backend():
     return "asyncio"
 
 
-def make_engine(num_blocks=128, max_batched=64, **sched) -> LLMEngine:
+def make_engine(num_blocks=128, max_batched=64, pipelined=True, **sched) -> LLMEngine:
     cfg = EngineConfig(
         model=tiny_model_config(vocab_size=512, max_model_len=128),
         cache=CacheConfig(page_size=4, num_blocks=num_blocks, dtype="float32"),
@@ -47,7 +47,7 @@ def make_engine(num_blocks=128, max_batched=64, **sched) -> LLMEngine:
             max_num_seqs=8, max_num_batched_tokens=max_batched, **sched
         ),
     )
-    return LLMEngine(cfg)
+    return LLMEngine(cfg, _synchronous_step=not pipelined)
 
 
 def host_events(trace_dir) -> dict:
@@ -122,30 +122,36 @@ def test_engine_steps_write_their_phase_spans(tmp_path):
     assert step["kind"] == "decode" and step["rows"] == 1 and step["tokens"] == 1
     assert step["program"] == eng.runner.last_program != ""
     assert events["llmd.runner.dispatch"]["program"] == eng.runner.last_program
-    assert events["llmd.sched.schedule"] == {"prefills": 0, "decodes": 1}
+    # (the last one written: scheduled under the request's last step, whose
+    # end by max_tokens the pipelined step foresees: nothing is staged)
+    assert events["llmd.sched.schedule"] == {"prefills": 0, "decodes": 0}
+    assert events["llmd.step.commit"] == {"rolled": 0}
 
 
-def test_async_first_dispatch_is_named_by_its_batch(tmp_path):
-    eng = make_engine(async_scheduling=True)
+def test_async_first_step_lands_at_once_and_is_named_by_its_batch(tmp_path):
+    eng = make_engine(pipelined=True)
     eng.generate([[1, 2, 3, 4, 5]], SamplingParams(temperature=0.0, max_tokens=2))  # compiled
     eng.add_request([9, 8, 7, 6, 5], SamplingParams(temperature=0.0, max_tokens=2))
     steps = eng.stats.engine_steps_total
     profiling.start(tmp_path)
     try:
-        assert eng.step() == []  # dispatched only: its tokens land in the next call
+        outs = eng.step()  # a pipeline starts with a step that lands, and the next in flight
     finally:
         profiling.stop()
-    assert eng.stats.engine_steps_total == steps  # counted when it is finished
-    step = host_events(tmp_path)["llmd.step"]
+    assert [len(o.new_token_ids) for o in outs] == [1]
+    assert eng.stats.engine_steps_total == steps + 1 and eng._inflight is not None
+    events = host_events(tmp_path)
+    step = events["llmd.step"]
     assert step["kind"] == "prefill" and step["rows"] == 1 and step["tokens"] == 5
     assert step["program"] == eng.runner.last_program
+    assert "llmd.step.commit" not in events  # the synchronous step's phases
     while eng.has_work():
         eng.step()
 
 
-@pytest.mark.parametrize("async_scheduling", [False, True])
-def test_phase_counters_add_up(async_scheduling):
-    eng = make_engine(max_batched=16, async_scheduling=async_scheduling)
+@pytest.mark.parametrize("pipelined", [False, True])
+def test_phase_counters_add_up(pipelined):
+    eng = make_engine(max_batched=16, pipelined=pipelined)
     prompts = [list(range(1, 41)), [5, 6, 7], list(range(50, 75))]
     eng.generate(prompts, SamplingParams(temperature=0.0, max_tokens=6))
     s = eng.stats
@@ -158,11 +164,18 @@ def test_phase_counters_add_up(async_scheduling):
     )
     assert 0 < phases <= s.step_ms_total + 1e-6
     assert s.step_ms_decode_total + s.step_ms_prefill_total == pytest.approx(s.step_ms_total)
-    if not async_scheduling:  # the host gap IS these three phases, unrounded
+    if not pipelined:  # the host gap IS these three phases, unrounded
         assert (
             s.step_schedule_ms_total + s.step_launch_ms_total + s.step_finish_ms_total
             == pytest.approx(s.step_host_gap_ms_total, rel=1e-9)
         )
+        assert s.step_commit_ms_total == s.step_redispatch_ms_total == 0.0
+        assert s.steps_prestaged_total == s.steps_topped_up_total == 0
+    else:  # readback to the next dispatch's return, in its two parts
+        # (+ the whole host side of the step that started the pipeline)
+        assert 0 < s.step_commit_ms_total + s.step_redispatch_ms_total < s.step_host_gap_ms_total
+        assert s.step_commit_ms_total > 0 and s.step_redispatch_ms_total > 0
+        assert 0 < s.steps_prestaged_total <= s.engine_steps_total
     assert s.queue_admitted_total == len(prompts) and s.queue_wait_ms_total >= 0
     assert s.programs_traced_total == eng.runner.programs_traced > 0
     assert len(eng.runner.traced_programs) == min(eng.runner.programs_traced, 256)

@@ -51,12 +51,12 @@ def make_engine(
         scheduler=SchedulerConfig(
             max_num_seqs=max_seqs, max_num_batched_tokens=max_batched,
             unified_step=unified, ragged_qlens=ragged,
-            speculative_ngram=spec, async_scheduling=async_s,
+            speculative_ngram=spec,
         ),
         parallel=ParallelConfig(tensor_parallel_size=1),
         seed=seed,
     )
-    return LLMEngine(cfg)
+    return LLMEngine(cfg, _synchronous_step=not async_s)
 
 
 PROMPTS = [
@@ -140,7 +140,7 @@ def test_int8_pool_parity():
     assert _toks(ref) == _toks(flat)
 
 
-def test_async_rollback_parity():
+def test_async_rollback_parity(unforeseen_finishes):
     """max_tokens finishes land late under async stepping; rolled-back
     staged rows must leave the stream byte-identical."""
     sp = SamplingParams(temperature=0.0, max_tokens=5)

@@ -40,14 +40,14 @@ def make_engine(
         ),
         scheduler=SchedulerConfig(
             max_num_seqs=max_seqs, max_num_batched_tokens=max_batched,
-            async_scheduling=async_mode, speculative_ngram=spec,
+            speculative_ngram=spec,
             spec_ngram_k=k, spec_ngram_min_match=min_match,
             decode_window=decode_window, ragged_qlens=ragged,
         ),
         parallel=ParallelConfig(tensor_parallel_size=1),
         seed=seed,
     )
-    return LLMEngine(cfg)
+    return LLMEngine(cfg, _synchronous_step=not async_mode)
 
 
 # Periodic prompts drive the tiny model's greedy output into loops the
@@ -215,7 +215,7 @@ def test_spec_parity_stop_token_mid_window():
 
 @pytest.mark.parametrize("decode_window", [1, 4])
 @pytest.mark.parametrize("seeded", [False, True])
-def test_spec_parity_async_scheduling(seeded, decode_window):
+def test_spec_parity_async_scheduling(seeded, decode_window, unforeseen_finishes):
     """Spec composes with async stepping: the staged next batch is
     planned against max-acceptance counts, and short acceptance lands as
     a partial rollback — streams still byte-identical to the plain sync
@@ -359,7 +359,7 @@ def test_spec_truncation_returns_pages_sync(decode_window):
 # one-shot verify under async stepping, readbacks and accounting
 
 
-def test_spec_async_staggered_finishes():
+def test_spec_async_staggered_finishes(unforeseen_finishes):
     """Async rollback under speculation: staggered max_tokens make
     batch-mates finish at reconcile on several different steps; the
     surviving rows keep their planned 1 + k widths through the
@@ -408,9 +408,9 @@ def _count_wait_steps(eng) -> dict:
     calls = {"n": 0}
     orig = eng.runner.wait_step
 
-    def counting(prefill, decode, unified=None):
+    def counting(*args, **kw):
         calls["n"] += 1
-        return orig(prefill, decode, unified)
+        return orig(*args, **kw)
 
     eng.runner.wait_step = counting
     return calls
@@ -520,12 +520,12 @@ def make_unified_spec(unified, spec=True, async_mode=False, seed=0):
         scheduler=SchedulerConfig(
             max_num_seqs=8, max_num_batched_tokens=16,
             speculative_ngram=spec, spec_ngram_k=4, spec_ngram_min_match=2,
-            unified_step=unified, async_scheduling=async_mode,
+            unified_step=unified,
         ),
         parallel=ParallelConfig(tensor_parallel_size=1),
         seed=seed,
     )
-    return LLMEngine(cfg)
+    return LLMEngine(cfg, _synchronous_step=not async_mode)
 
 
 def test_unified_spec_one_shot_parity_greedy():
@@ -595,7 +595,7 @@ def test_unified_spec_rejected_drafts_never_enter_prefix_index():
     assert eng.allocator.usage() == 0.0
 
 
-def test_unified_spec_async_rollback_parity():
+def test_unified_spec_async_rollback_parity(unforeseen_finishes):
     """Unified prestaging x spec x async: staged unified batches plan
     verify rows at max acceptance, late finishes roll staged rows back
     (surviving rows sliced from the prestaged arrays), and the stream
@@ -613,16 +613,16 @@ def test_unified_spec_async_rollback_parity():
     assert eng.allocator.usage() == 0.0
 
 
-def test_unified_async_rollback_slices_staged_arrays():
+def test_unified_async_rollback_slices_staged_arrays(unforeseen_finishes):
     """A rollback that drops rows from a staged unified batch must
     SLICE the surviving rows' row-independent arrays out of the
-    prestaged staging (ModelRunner.subset_staged_unified over
+    prestaged staging (ModelRunner.restage_unified over
     _slice_staged_rows) instead of restaging in the blocking host
     region — and the sliced dispatch must stay byte-identical."""
     from llmd_tpu.engine.runner import ModelRunner
 
     hits = {"subset": 0}
-    orig = ModelRunner.subset_staged_unified
+    orig = ModelRunner.restage_unified
 
     def counting(self, *a, **k):
         hits["subset"] += 1
@@ -634,10 +634,10 @@ def test_unified_async_rollback_slices_staged_arrays():
     )
     eng = make_unified_spec(True, async_mode=True)
     try:
-        ModelRunner.subset_staged_unified = counting
+        ModelRunner.restage_unified = counting
         out = eng.generate([list(p) for p in UNIFIED_SPEC_PROMPTS], sp)
     finally:
-        ModelRunner.subset_staged_unified = orig
+        ModelRunner.restage_unified = orig
     assert list(base.values()) == list(out.values())
     assert hits["subset"] > 0, (
         "no rollback reused the staged unified arrays: the slicing "
@@ -768,7 +768,7 @@ def test_spec_truncation_drop_without_free_caught(leaksan, monkeypatch):
     def leaky_truncate(self, req):
         page = self.allocator.page_size
         slots = req.num_computed_tokens
-        if self.config.async_scheduling:
+        if self.pipelined:
             slots = req.num_dispatched_tokens + 1 + self.spec_k
         keep = -(-slots // page)
         if keep < len(req.block_ids):

@@ -177,10 +177,10 @@ def _engine(program, extras, async_s=False):
         ),
         scheduler=SchedulerConfig(
             max_num_seqs=4, max_num_batched_tokens=32,
-            async_scheduling=async_s, **PROGRAMS[program],
+            **PROGRAMS[program],
         ),
         seed=0,
-    ))
+    ), _synchronous_step=not async_s)
 
 
 class _PerField:

@@ -302,7 +302,9 @@ def test_failed_admission_returns_ring_pages():
     try:
         sp = SamplingParams(temperature=0.0, max_tokens=64, ignore_eos=True)
         eng.add_request([1, 2, 3, 4] * 6, sp)  # 24 toks -> 6 of 8 pages
-        eng.step()
+        first = eng.scheduler.waiting[0]
+        while not first.output_token_ids:  # its prompt committed (the
+            eng.step()  # pipelined step commits one call after it dispatches)
         free_before = eng.swa_allocator.num_free_pages
         # Second request: ring allocates, pages fail -> ring must return.
         eng.add_request([9, 8, 7, 6] * 5, sp)
