@@ -334,10 +334,12 @@ class EngineStats:
     # step (the default shape) it is the time from a readback's end to
     # the next dispatch's return: commit + reconcile, a last top-up, the
     # fill and the one put + call; everything else overlaps device
-    # execution. In the synchronous step (lockstep followers, P/D
-    # producers) it is schedule + launch + finish. Last value + running
-    # sum + step count so a scrape (or the bench) can read both a gauge
-    # and a mean.
+    # execution (the readback itself, which the device also waits for,
+    # is step_readback_ms_total: the host's whole turn between two
+    # programs is readback + commit + redispatch). In the synchronous
+    # step (lockstep followers, P/D producers) it is schedule + launch +
+    # finish. Last value + running sum + step count so a scrape (or the
+    # bench) can read both a gauge and a mean.
     engine_steps_total: int = 0
     step_host_gap_ms: float = 0.0
     step_host_gap_ms_total: float = 0.0
@@ -362,6 +364,35 @@ class EngineStats:
     step_commit_ms_total: float = 0.0
     step_redispatch_ms_total: float = 0.0
     step_ms_total: float = 0.0
+    # The host's turn between two step programs, timed where it happens
+    # (runner.wait_step, _step_async; docs/architecture/async-scheduling.md
+    # "The turn"). ready_lag_bound: per step the time from the last
+    # is_ready() that was false (the wait's entry where the first was
+    # true) to the first that was true; the device finished somewhere in
+    # it, so it bounds from above how late the host noticed, and holds
+    # what the poll did meanwhile (the pause's oversleep, an intake, a
+    # top-up); 0 for a blocking wait. readback: from that notice to the
+    # parsed results (span llmd.runner.readback; both kinds of step).
+    # gap_admit: the part of step_redispatch_ms_total that is admission,
+    # a top-up or the whole schedule of an empty slot, run AFTER the
+    # readback with the device empty (steps_topped_up_total does not say
+    # where a top-up ran).
+    step_ready_lag_bound_ms_total: float = 0.0
+    step_readback_ms_total: float = 0.0
+    step_gap_admit_ms_total: float = 0.0
+    # The serving loop (serve/async_engine.py; 0 for an engine stepped
+    # directly). engine_idle: time the loop waited with nothing to run
+    # (span llmd.serve.idle; not paused): 1 - idle / wall is the
+    # replica's duty cycle, which tells "no load" from "slow host".
+    # intake_wait: submit() to the engine thread's intake, a request (it
+    # ends where queue_wait_ms and ttft_ms start). deliver_lag: a step's
+    # readback's end to each of its outputs handed to its stream (it
+    # starts where ttft_ms ends).
+    engine_idle_ms_total: float = 0.0
+    intake_wait_ms_total: float = 0.0
+    intake_requests_total: int = 0
+    deliver_lag_ms_total: float = 0.0
+    outputs_delivered_total: int = 0
     # Steps by what they carried (prefill chunks only, decode rows only,
     # both) and their whole-step time: a mean step time hides that a
     # step with a long prefill chunk is another thing than a decode step.
@@ -550,6 +581,7 @@ class _StagedStep:
     topped_up: bool = False  # took in rows admitted after the schedule
     admit_s: float = 0.0  # host seconds spent scheduling and topping up
     in_wait_s: float = 0.0  # ... of them inside the wait for the readback
+    in_gap_s: float = 0.0  # ... and behind it, with the device empty
 
 
 class LLMEngine:
@@ -857,6 +889,9 @@ class LLMEngine:
         # arrival during step N rides step N+1 (_top_up).
         self.intake_hook = None
         self._inflight: _InflightStep | None = None
+        # time.monotonic() at the end of the newest step readback: the
+        # serving loop counts an output's deliver lag from it.
+        self.last_readback_at = time.monotonic()
         # (kind, rows, tokens) of the batch this call of step() finished
         # (async, first call of a pipeline: dispatched), for the llmd.step
         # span and the by-kind sums.
@@ -1710,7 +1745,8 @@ class LLMEngine:
         pres, dres = self.runner.wait_step(
             None if eager_ack else pend_p, pend_d, pend_u
         )
-        t_read = time.monotonic()
+        waited = self.runner.last_wait
+        t_read = self.last_readback_at = waited.read_at
         with profiling.span("llmd.step.finish") as finish_span:
             sampled, logprobs = self._collect(batch, pres, dres)
             accepted = self.scheduler.update_after_step(batch, sampled)
@@ -1729,6 +1765,7 @@ class LLMEngine:
             batch, (t_dispatched - t0) + finish_s,
             schedule_s=now - t0, launch_s=t_dispatched - now,
             wait_s=t_read - t_dispatched, finish_s=finish_s,
+            readback_s=waited.readback_s,
         )
         return outputs
 
@@ -1739,13 +1776,17 @@ class LLMEngine:
         assumed to land its tokens), prestage its host arrays, and, all
         through the wait for N's readback, take in the requests that
         arrive and top the staged batch up with them. Between two
-        programs stand only commit, reconcile (late EOS/stop/max-tokens
-        finishes and aborts invalidate their staged rows — the released
-        pages follow the recompute-preemption path), a last top-up and
-        the fill-and-dispatch of N+1; output assembly and the offloader's
-        flush run after the re-dispatch, under N+1. Outputs arrive one
-        call late; the pipeline is entered by ``_prime`` behind a step
-        that landed synchronously (docs/architecture/async-scheduling.md)."""
+        programs stand only the readback, commit, reconcile (late
+        EOS/stop/max-tokens finishes and aborts invalidate their staged
+        rows — the released pages follow the recompute-preemption path),
+        a last top-up and the fill-and-dispatch of N+1: the host's TURN,
+        tiled without a hole by the spans llmd.runner.readback,
+        llmd.step.commit, llmd.sched.schedule and llmd.runner.launch
+        (docs/architecture/observability.md); output assembly and the
+        offloader's flush run after the re-dispatch, under N+1. Outputs
+        arrive one call late; the pipeline is entered by ``_prime``
+        behind a step that landed synchronously
+        (docs/architecture/async-scheduling.md)."""
         inflight = self._inflight
         # ---- overlapped host region: the device is executing N ----
         t0 = time.monotonic()
@@ -1762,7 +1803,8 @@ class LLMEngine:
             poll=None if self.intake_hook is None
             else functools.partial(self._admit_arrivals, slot),
         )
-        t_read = time.monotonic()
+        waited = self.runner.last_wait
+        t_read = self.last_readback_at = waited.read_at
         with profiling.span("llmd.step.commit") as commit_span:
             sampled, logprobs = self._collect(inflight.batch, pres, dres)
             accepted = self.scheduler.update_after_step(
@@ -1796,9 +1838,9 @@ class LLMEngine:
                 # The slot is empty (every staged row rolled back, or all
                 # of N's rows foreseen to end): nothing is pending, so
                 # the freed rows, pages and budget are scheduled whole.
-                t = time.monotonic()
                 slot.batch = self._schedule_spanned()
-                slot.admit_s += time.monotonic() - t
+                slot.in_gap_s = time.monotonic() - t_reconciled
+                slot.admit_s += slot.in_gap_s
         elif self.scheduler.waiting:
             # Rows and budget that N's finishes gave back, and whatever
             # arrived in the last instants: admitted now, one step sooner
@@ -1808,8 +1850,10 @@ class LLMEngine:
             self._dispatch_async(slot.batch, slot.staging)
             self.stats.steps_prestaged_total += prestaged
             self.stats.steps_topped_up_total += slot.topped_up
-        # Device idle ends at the re-dispatch above; output assembly and
-        # gauge refresh below overlap step N+1's execution.
+        # The host's turn ends at the re-dispatch's return above (the
+        # device's idle time a little later: the program's launch latency
+        # is no host code's to hold); output assembly and gauge refresh
+        # below overlap step N+1's execution.
         t_redispatched = time.monotonic()
         with profiling.span("llmd.step.finish") as finish_span:
             outputs = self._assemble_outputs(
@@ -1822,8 +1866,8 @@ class LLMEngine:
         # spent: schedule (with the top-ups, which are taken out of the
         # wait they ran in) and prestaging ran while the device executed
         # step N; commit/reconcile count as finish with the assembly. The
-        # host gap is what stood between N's readback and N+1's dispatch:
-        # commit + redispatch.
+        # host gap is what stood between N's readback's END and N+1's
+        # dispatch: commit + redispatch; the turn is the readback more.
         self._finish_step(
             inflight.batch, t_redispatched - t_read,
             schedule_s=slot.admit_s,
@@ -1832,8 +1876,11 @@ class LLMEngine:
             wait_s=t_read - t_staged - slot.in_wait_s,
             finish_s=(t_reconciled - t_read)
             + (time.monotonic() - t_redispatched),
+            readback_s=waited.readback_s,
+            ready_lag_bound_s=waited.ready_lag_bound_s,
             commit_s=t_reconciled - t_read,
             redispatch_s=t_redispatched - t_reconciled,
+            gap_admit_s=slot.in_gap_s,
         )
         return outputs
 
@@ -1948,24 +1995,28 @@ class LLMEngine:
         of a hybrid hit included), and stage the added rows alone."""
         t0 = time.monotonic()
         in_flight = self._inflight is not None
+        # (the restage is under the span too: after the readback the
+        # turn's spans leave no hole)
         with profiling.span("llmd.sched.schedule", top_up=True) as span:
             added = self.scheduler.top_up(slot.batch, in_flight=in_flight)
             span.set_metadata(prefills=len(added), decodes=0)
-        # An interactive head may have reclaimed a staged batch-band row's
-        # slot and pages, whether or not it was then admitted: a row that
-        # no longer runs leaves the staged batch either way.
-        kept = self._running(slot.batch)
-        if added or kept is not slot.batch:
-            batch = ScheduledBatch(
-                prefills=kept.prefills + added, decodes=kept.decodes
-            )
-            slot.staging = self._restage(slot.staging, slot.batch, batch)
-            slot.batch = batch
-            slot.topped_up |= bool(added)
+            # An interactive head may have reclaimed a staged batch-band
+            # row's slot and pages, whether or not it was then admitted: a
+            # row that no longer runs leaves the staged batch either way.
+            kept = self._running(slot.batch)
+            if added or kept is not slot.batch:
+                batch = ScheduledBatch(
+                    prefills=kept.prefills + added, decodes=kept.decodes
+                )
+                slot.staging = self._restage(slot.staging, slot.batch, batch)
+                slot.batch = batch
+                slot.topped_up |= bool(added)
         spent = time.monotonic() - t0
         slot.admit_s += spent
         if in_flight:
             slot.in_wait_s += spent
+        else:
+            slot.in_gap_s += spent
 
     def _admit_arrivals(self, slot: _StagedStep) -> None:
         """``wait_step``'s poll while step N runs: take in what arrived
@@ -2264,13 +2315,22 @@ class LLMEngine:
         launch_s: float,
         wait_s: float,
         finish_s: float,
+        readback_s: float,
+        ready_lag_bound_s: float = 0.0,
         commit_s: float = 0.0,
         redispatch_s: float = 0.0,
+        gap_admit_s: float = 0.0,
     ) -> None:
         """Count one step that ran ``batch``: the host gap, the phase
         sums and the step kind (``step()`` adds the whole-step sums).
-        ``commit_s`` and ``redispatch_s`` are the pipelined step's gap in
-        its two parts (the synchronous step's gap is its phases)."""
+        ``host_gap_s`` of the pipelined step starts at the readback's END
+        and ends at the next dispatch's return: ``commit_s`` +
+        ``redispatch_s``, its two parts (``gap_admit_s``: the admission
+        inside the second). ``readback_s``, which precedes it (first
+        ready to parsed results, inside ``wait_s``), makes it the host's
+        whole turn between two programs; ``ready_lag_bound_s`` is the
+        most the host can have noticed the device's end late. The
+        synchronous step's gap is its phases."""
         st = self.stats
         gap_ms = host_gap_s * 1e3
         st.engine_steps_total += 1
@@ -2282,6 +2342,9 @@ class LLMEngine:
         st.step_finish_ms_total += finish_s * 1e3
         st.step_commit_ms_total += commit_s * 1e3
         st.step_redispatch_ms_total += redispatch_s * 1e3
+        st.step_readback_ms_total += readback_s * 1e3
+        st.step_ready_lag_bound_ms_total += ready_lag_bound_s * 1e3
+        st.step_gap_admit_ms_total += gap_admit_s * 1e3
         self._step_carried = self._carried(batch)
         by_kind = f"steps_{self._step_carried[0]}_total"
         setattr(st, by_kind, getattr(st, by_kind) + 1)

@@ -125,6 +125,9 @@ _UNIFIED_ROW_TOKENS = 64
 # wait_step's pause between two polls of the intake while the device runs:
 # long enough that the serving threads get the interpreter, short against
 # a step (what it adds to the gap is half of it on average).
+# 0.1 ms because a sleep hands the interpreter to whichever thread wants it
+# and comes back when that thread lets go: what a pause REALLY costs, with
+# the poll's own work, is counted as step_ready_lag_bound_ms_total.
 _POLL_S = 1e-4
 
 log = logging.getLogger(__name__)
@@ -321,6 +324,23 @@ class PendingUnified:
     decode_rows: list[int]
     n_prefills: int
     n_decodes: int
+
+
+@dataclass
+class WaitTiming:
+    """When the last ``wait_step`` learnt that its outputs were ready and
+    when it had them parsed (``time.monotonic()``), and the most that the
+    notice can have lagged the device: the time from the last
+    ``is_ready()`` that was false (the wait's entry where the first was
+    true) to the first that was true; 0 for a blocking wait."""
+
+    ready_lag_bound_s: float = 0.0
+    ready_at: float = 0.0
+    read_at: float = 0.0
+
+    @property
+    def readback_s(self) -> float:
+        return self.read_at - self.ready_at
 
 
 class ModelRunner:
@@ -527,6 +547,7 @@ class ModelRunner:
         # "family:shape" of the step program dispatched last (the
         # llmd.step span's ``program``).
         self.last_program = ""
+        self.last_wait = WaitTiming()  # of the newest wait_step
         self._build_programs()
         self._check_page_table_fits_smem()
         # Padding-efficiency accounting (EngineStats padded/live tokens):
@@ -3353,7 +3374,6 @@ class ModelRunner:
             pad_to_bucket(s.num_tokens, self.prefill_buckets) for s in seqs
         })
 
-    @profiling.spanned("llmd.runner.wait")
     def wait_step(
         self,
         prefill: PendingPrefill | None,
@@ -3371,7 +3391,12 @@ class ModelRunner:
         ``poll`` (the pipelined step's intake): called again and again
         while the device runs, a short sleep apart so that the threads
         that bring requests get the interpreter, until the outputs are
-        there; what it admits meanwhile costs the device nothing."""
+        there; what it admits meanwhile costs the device nothing.
+
+        Two spans, one after the other: ``llmd.runner.wait`` ends when
+        the host KNOWS that every output is ready, ``llmd.runner.readback``
+        holds the transfer and the parsing. ``last_wait`` keeps both
+        instants and the bound on how late the first was."""
         packs: list[jax.Array] = []
         if prefill is not None:
             packs.extend(p for p, _ in prefill.entries)
@@ -3380,17 +3405,43 @@ class ModelRunner:
         if unified is not None:
             packs.append(unified.packed)
         if not packs:
+            now = time.monotonic()
+            self.last_wait = WaitTiming(0.0, now, now)
             return None, None
-        if poll is not None:
-            while True:
-                poll()
-                if all(p.is_ready() for p in packs):
-                    break
-                time.sleep(_POLL_S)
-        if dist.is_multihost():
-            hosts = [dist.replicated_to_host(p) for p in packs]
-        else:
-            hosts = [np.asarray(a) for a in jax.device_get(packs)]
+        with profiling.span("llmd.runner.wait"):
+            if poll is None:
+                jax.block_until_ready(packs)
+            else:
+                t_unready = time.monotonic()
+                while True:
+                    poll()
+                    if all(p.is_ready() for p in packs):
+                        break
+                    t_unready = time.monotonic()
+                    time.sleep(_POLL_S)
+            t_ready = time.monotonic()
+        with profiling.span("llmd.runner.readback") as span:
+            if dist.is_multihost():
+                hosts = [dist.replicated_to_host(p) for p in packs]
+            else:
+                hosts = [np.asarray(a) for a in jax.device_get(packs)]
+            span.set_metadata(bytes=sum(a.nbytes for a in hosts))
+            results = self._split_results(prefill, decode, unified, hosts)
+            self.last_wait = WaitTiming(
+                0.0 if poll is None else t_ready - t_unready,
+                t_ready, time.monotonic(),
+            )
+        return results
+
+    def _split_results(
+        self,
+        prefill: PendingPrefill | None,
+        decode: PendingDecode | None,
+        unified: PendingUnified | None,
+        hosts: list[np.ndarray],
+    ) -> tuple[StepResult | None, StepResult | None]:
+        """``wait_step``'s packed outputs, on the host, as the step's
+        prefill and decode results (and the MoE count lanes added up)."""
         if self._counts_grouped:
             for arr in hosts:  # _count_row's lines, below every result row
                 self.moe_grouped_calls_total += int(arr[-2, 0])

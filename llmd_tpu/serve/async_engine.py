@@ -38,6 +38,9 @@ class _Pending:
     # delivered to the client by a dead replica; generation continues at
     # output position N (docs/architecture/fault-tolerance.md).
     resume_output_tokens: int = 0
+    # When submit() queued it: the intake counts the wait from here
+    # (EngineStats.intake_wait_ms_total).
+    submitted_at: float = field(default_factory=time.monotonic)
 
 
 def _release_pulled(engine, kv_transfer_params) -> None:
@@ -507,6 +510,12 @@ class AsyncEngine:
                 return 0
             pending, self._inbox = self._inbox, []
             aborts, self._aborts = self._aborts, []
+        if pending:
+            now, stats = time.monotonic(), self.engine.stats
+            stats.intake_wait_ms_total += sum(
+                now - p.submitted_at for p in pending
+            ) * 1e3
+            stats.intake_requests_total += len(pending)
         with profiling.span("llmd.serve.intake", added=len(pending)):
             for rid in aborts:
                 self.engine.abort_request(rid)
@@ -528,19 +537,30 @@ class AsyncEngine:
                     self._deliver(p.request_id, RequestFailed(str(e)))
         return len(pending) + len(aborts)
 
+    def _wait_for_work_locked(self) -> None:
+        """Sleep on the lock while the engine is paused or has nothing to
+        run, each sleep under the span that says which: ``llmd.serve.idle``
+        (no inbox, no aborts, no work; summed into ``engine_idle_ms_total``)
+        and ``llmd.serve.paused`` are the only spans under which the chip
+        is idle for want of load."""
+        stats = self.engine.stats
+        while not self._stop:
+            if self._paused:
+                with profiling.span("llmd.serve.paused"):
+                    self._lock.wait()
+            elif self._inbox or self._aborts or self.engine.has_work():
+                return
+            else:
+                t = time.monotonic()
+                with profiling.span("llmd.serve.idle"):
+                    self._lock.wait()
+                stats.engine_idle_ms_total += (time.monotonic() - t) * 1e3
+
     def _run(self) -> None:
         self.engine.intake_hook = self._intake
         while True:
             with self._lock:
-                while not self._stop and (
-                    self._paused
-                    or (
-                        not self._inbox
-                        and not self._aborts
-                        and not self.engine.has_work()
-                    )
-                ):
-                    self._lock.wait()
+                self._wait_for_work_locked()
                 if self._stop:
                     # Queued entries die with the loop — their fetched
                     # bundles (stream-reserved pool pages) must not.
@@ -570,5 +590,8 @@ class AsyncEngine:
                 self._steps_done += 1
                 self._stall_flagged = False
             with profiling.span("llmd.serve.deliver", outputs=len(outputs)):
+                stats, t_read = self.engine.stats, self.engine.last_readback_at
                 for out in outputs:
                     self._deliver(out.request_id, out)
+                    stats.deliver_lag_ms_total += (time.monotonic() - t_read) * 1e3
+                stats.outputs_delivered_total += len(outputs)

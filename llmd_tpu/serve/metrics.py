@@ -135,6 +135,23 @@ def render_metrics(
         # reconciled, reconciled to the next dispatch's return.
         "step_commit_ms_total": round(stats.step_commit_ms_total, 3),
         "step_redispatch_ms_total": round(stats.step_redispatch_ms_total, 3),
+        # The host's turn between two programs, timed where it happens:
+        # the most the notice of the device's end can have lagged, the
+        # readback (first ready to parsed results; readback + commit +
+        # redispatch is the turn), and the admission inside redispatch.
+        "step_ready_lag_bound_ms_total": round(
+            stats.step_ready_lag_bound_ms_total, 3
+        ),
+        "step_readback_ms_total": round(stats.step_readback_ms_total, 3),
+        "step_gap_admit_ms_total": round(stats.step_gap_admit_ms_total, 3),
+        # The serving loop: time waited with nothing to run (1 - idle /
+        # wall is the replica's duty cycle), submit() to intake, and a
+        # step's readback's end to its outputs handed to their streams.
+        "engine_idle_ms_total": round(stats.engine_idle_ms_total, 3),
+        "intake_wait_ms_total": round(stats.intake_wait_ms_total, 3),
+        "intake_requests_total": stats.intake_requests_total,
+        "deliver_lag_ms_total": round(stats.deliver_lag_ms_total, 3),
+        "outputs_delivered_total": stats.outputs_delivered_total,
         "step_ms_total": round(stats.step_ms_total, 3),
         "steps_prefill_total": stats.steps_prefill_total,
         "steps_decode_total": stats.steps_decode_total,

@@ -347,17 +347,36 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                    "re-serializes the pipeline "
                    "(docs/architecture/async-scheduling.md)."),
         panel("Host gap by part",
-              [f"rate(llmd:step_commit_ms_total{M}[5m]) / "
-               f"rate(llmd:engine_steps_total{M}[5m])",
-               f"rate(llmd:step_redispatch_ms_total{M}[5m]) / "
-               f"rate(llmd:engine_steps_total{M}[5m])"],
-              legends=["commit + reconcile", "top-up + fill + put + call"],
+              [f"rate(llmd:step_{part}_ms_total{M}[5m]) / "
+               f"rate(llmd:engine_steps_total{M}[5m])"
+               for part in ("readback", "commit", "redispatch",
+                            "gap_admit", "ready_lag_bound")],
+              legends=["readback (before the gap)", "commit + reconcile",
+                       "top-up + fill + put + call",
+                       "of it: admission in the gap",
+                       "ready lag, upper bound"],
               unit="ms",
-              desc="The pipelined step's gap in its two parts: readback "
-                   "to reconciled (collect, scheduler update, late "
-                   "intake, rollbacks) and reconciled to the next "
-                   "dispatch's return. Both 0 on an engine that keeps "
-                   "the synchronous step (lockstep, P/D producer)."),
+              desc="The host's turn between two step programs, part by "
+                   "part: the readback (first ready to parsed results; "
+                   "the host gap starts at its end), the gap's two parts "
+                   "(readback to reconciled: collect, scheduler update, "
+                   "late intake, rollbacks; reconciled to the next "
+                   "dispatch's return), the admission that ran inside "
+                   "the second with the device empty, and the most the "
+                   "host can have noticed the device's end late (last "
+                   "poll that found it running to the first that found "
+                   "it ready). Commit and redispatch are 0 on an engine "
+                   "that keeps the synchronous step (lockstep, P/D "
+                   "producer)."),
+        panel("Engine duty cycle",
+              [f"1 - rate(llmd:engine_idle_ms_total{M}[5m]) / 1000"],
+              unit="percentunit", max1=True,
+              desc="The share of wall time the serving loop had "
+                   "something to run (1 - time waited with no inbox, no "
+                   "aborts and no work; a paused engine is not counted "
+                   "idle). Low duty with a high time to first token is "
+                   "a slow host or device, not load: the saturation "
+                   "signal that tells the two apart."),
         panel("Steps dispatched from a prestaged slot",
               [f"rate(llmd:steps_prestaged_total{M}[5m]) / "
                f"rate(llmd:engine_steps_total{M}[5m])",
@@ -402,11 +421,24 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                    "where one is: the share says how often."),
         panel("Queue wait before first scheduling",
               [f"rate(llmd:queue_wait_ms_total{M}[5m]) / "
-               f"rate(llmd:queue_admitted_total{M}[5m])"],
+               f"rate(llmd:queue_admitted_total{M}[5m])",
+               f"rate(llmd:intake_wait_ms_total{M}[5m]) / "
+               f"rate(llmd:intake_requests_total{M}[5m])",
+               f"rate(llmd:deliver_lag_ms_total{M}[5m]) / "
+               f"rate(llmd:outputs_delivered_total{M}[5m])"],
+              legends=["arrival to first admission",
+                       "submit to intake (before arrival)",
+                       "readback to delivery (per output)"],
               unit="ms",
               desc="Mean ms between a request's arrival at the engine and "
                    "its first admission by the scheduler: the part of the "
-                   "time to first token that is waiting, not computing."),
+                   "time to first token that is waiting, not computing. "
+                   "Beside it the two waits outside the engine's own "
+                   "clock: a submitted request in the serving loop's "
+                   "inbox until the engine thread takes it in, and a "
+                   "step's outputs from its readback's end to their "
+                   "streams (commit, re-dispatch and assembly come "
+                   "first in the pipelined step)."),
         panel("Step programs traced /s",
               [f"rate(llmd:programs_traced_total{M}[5m])"],
               thresholds=[(None, "green"), (0.01, "red")],

@@ -8,13 +8,17 @@ granite state pool and its snapshots, the sparse-attention indexer plane,
 the bucketed latent step), each with a hybrid or prefix hit in the batch and
 a stop token that rolls one row back while its mates go on; the top-up
 admission (a request that arrives while step N runs rides step N+1); the
-order in which ``AsyncEngine`` hands a step's outputs on; and the benchmark's
-shape ladder, one dispatch a bucket.
+host's turn between two programs (its spans tile it, its counters add up to
+it); the order in which ``AsyncEngine`` hands a step's outputs on, and the
+serving loop's own waits (idle, intake, deliver); and the benchmark's shape
+ladder, one dispatch a bucket.
 """
 
 import asyncio
 import pathlib
+import statistics
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -29,8 +33,11 @@ from llmd_tpu.config import (  # noqa: E402
     SchedulerConfig,
 )
 from llmd_tpu.engine import LLMEngine, SamplingParams  # noqa: E402
+from llmd_tpu.engine import runner as runner_mod  # noqa: E402
 from llmd_tpu.engine.request import PriorityClass, RequestStatus  # noqa: E402
 from llmd_tpu.models.registry import get_model_config  # noqa: E402
+from llmd_tpu.obs import profiling  # noqa: E402
+from tests.host_trace import host_spans  # noqa: E402
 
 
 def tokens(n, seed=0):
@@ -307,6 +314,183 @@ def test_a_staged_batch_row_reclaimed_by_a_head_that_then_fails_admission_is_not
     assert eng.allocator.usage() == 0.0 and eng._inflight is None
 
 
+# --- the host's turn between two programs ----------------------------------------------
+
+TURN = ("llmd.runner.readback", "llmd.step.commit", "llmd.sched.schedule", "llmd.runner.launch")
+
+
+class LateArrivals:
+    """An ``intake_hook`` that brings one new request at every poll AFTER a
+    readback (nothing in flight), so that every turn has a top-up in it."""
+
+    def __init__(self, eng: LLMEngine, sp):
+        self.eng, self.sp, self.n, self.on = eng, sp, 0, True
+
+    def __call__(self) -> int:
+        if not self.on or self.eng._inflight is not None:
+            return 0
+        self.n += 1
+        self.eng.add_request(tokens(5, seed=100 + self.n), self.sp)
+        return 1
+
+
+@pytest.mark.parametrize("top_up_in_the_gap", [False, True], ids=["plain_turn", "top_up_after_the_readback"])
+def test_the_spans_of_a_pipelined_step_tile_the_turn(tmp_path, top_up_in_the_gap):
+    """From the end of ``llmd.runner.wait`` to the dispatch's return every
+    instant lies in exactly one of readback, commit, schedule (the top-up
+    WITH its restage) and launch: in that order, each one's end the next
+    one's start, and the readback behind the wait, not inside it. (A
+    junction is a few microseconds of Python; the median over the steps is
+    held to 50 us, so that one preempted step of a busy machine does not
+    fail what every step would show were there code between two spans.)"""
+    eng = make_engine("tiny", pipelined=True, max_seqs=8)
+    warm(eng, [tokens(9, seed=1), tokens(5, seed=2), tokens(5, seed=3)], 10)
+    sp = SamplingParams(max_tokens=12, temperature=0.0, ignore_eos=True)
+    eng.add_request(tokens(9, seed=1), sp)
+    eng.step()  # lands, and primes the pipeline
+    hook = eng.intake_hook = LateArrivals(eng, SamplingParams(max_tokens=2, temperature=0.0, ignore_eos=True))
+    hook.on = top_up_in_the_gap
+    admit = eng.stats.step_gap_admit_ms_total
+    profiling.start(tmp_path)
+    try:
+        for _ in range(6):
+            eng.step()
+    finally:
+        profiling.stop()
+    hook.on = False
+    while eng.has_work():
+        eng.step()
+    spans = host_spans(tmp_path)
+    steps = [e for e in spans if e[0] == "llmd.step"]
+    assert len(steps) == 6
+    worst = []
+    for _, s0, s1, _ in steps:
+        inside = [e for e in spans if s0 <= e[1] and e[2] <= s1]
+        (wait,) = [e for e in inside if e[0] == "llmd.runner.wait"]
+        turn = [e for e in inside if e[0] in TURN and e[1] >= wait[2]]  # (the speculative schedule lies before the wait)
+        want = [n for n in TURN if top_up_in_the_gap or n != "llmd.sched.schedule"]
+        assert [e[0] for e in turn] == want, [e[0] for e in inside]
+        chain = [wait, *turn]
+        junctions = [b[1] - a[2] for a, b in zip(chain, chain[1:])]
+        assert all(j >= 0 for j in junctions)  # siblings: none starts inside the one before
+        worst.append(max(junctions))
+    assert statistics.median(worst) < 50_000, worst
+    assert (eng.stats.step_gap_admit_ms_total > admit) == top_up_in_the_gap
+
+
+def test_the_turns_counters_add_up_to_first_ready_to_dispatch_return():
+    """readback + commit + redispatch of a step, as counted, is the time
+    from the instant the host knew the outputs were ready to the return of
+    the next dispatch, as a clock around both reads it; the host gap is
+    commit + redispatch and starts at the readback's END."""
+    eng = make_engine("tiny", pipelined=True)
+    warm(eng, [tokens(9, seed=1), tokens(7, seed=2)], 12)
+    sp = SamplingParams(max_tokens=12, temperature=0.0, ignore_eos=True)
+    returned: list = []
+    counted: list = []
+    dispatch, finish = eng._dispatch_async, eng._finish_step
+
+    def dispatching(*a, **kw):
+        out = dispatch(*a, **kw)
+        returned.append(time.monotonic())
+        return out
+
+    def finishing(batch, host_gap_s, **kw):
+        if kw.get("commit_s"):  # a pipelined step
+            counted.append((eng.runner.last_wait.ready_at, returned[-1], host_gap_s, kw))
+        return finish(batch, host_gap_s, **kw)
+
+    eng._dispatch_async, eng._finish_step = dispatching, finishing
+    eng.intake_hook = lambda: 0  # a serving loop's poll: the wait polls is_ready()
+    for p in (tokens(9, seed=1), tokens(7, seed=2)):
+        eng.add_request(p, sp)
+    s0 = {k: getattr(eng.stats, k) for k in ("step_readback_ms_total", "step_commit_ms_total",
+                                             "step_redispatch_ms_total", "step_host_gap_ms_total")}
+    while eng.has_work():
+        eng.step()
+    dispatched = [c for c in counted if c[1] > c[0]]  # (the last step has nothing to dispatch)
+    assert len(dispatched) >= 8
+    clock = sum(ret - ready for ready, ret, _, _ in dispatched)
+    parts = sum(kw["readback_s"] + kw["commit_s"] + kw["redispatch_s"] for _, _, _, kw in dispatched)
+    assert parts == pytest.approx(clock, rel=0.05)
+    for _, _, gap, kw in counted:
+        assert gap == pytest.approx(kw["commit_s"] + kw["redispatch_s"], rel=1e-9)
+        assert kw["readback_s"] > 0 and 0 <= kw["gap_admit_s"] <= kw["redispatch_s"]
+    st = eng.stats
+    assert st.step_readback_ms_total - s0["step_readback_ms_total"] >= 1e3 * sum(c[3]["readback_s"] for c in counted)
+    assert st.step_gap_admit_ms_total <= st.step_redispatch_ms_total
+
+
+@pytest.mark.parametrize("while_running", [True, False], ids=["under_the_device", "in_the_gap"])
+def test_gap_admit_counts_only_the_admission_behind_the_readback(while_running):
+    """A top-up that runs inside the wait, while the device executes, costs
+    the device nothing and is no part of ``step_gap_admit_ms_total``; one
+    behind the readback is, and is a part of the redispatch."""
+    sp = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
+    first, late = tokens(9, seed=1), tokens(13, seed=2)
+    eng = make_engine("tiny", pipelined=True)
+    warm(eng, [first, late], 6)
+    eng.add_request(first, sp)
+    eng.step()
+    st = eng.stats
+    before = (st.step_gap_admit_ms_total, st.step_redispatch_ms_total, st.steps_topped_up_total)
+    hook = eng.intake_hook = Arrivals(eng, while_running, late, sp)
+    eng.step()
+    assert hook.rid is not None and st.steps_topped_up_total == before[2] + 1
+    admit, redispatch = st.step_gap_admit_ms_total - before[0], st.step_redispatch_ms_total - before[1]
+    if while_running:
+        assert admit == 0.0
+    else:
+        assert 0.0 < admit <= redispatch
+    while eng.has_work():
+        eng.step()
+
+
+class FakePack:
+    """A step's packed output whose ``is_ready()`` turns true at its n-th call."""
+
+    def __init__(self, rows: int, ready_at_call: int):
+        self.arr, self.calls, self.ready_at_call = np.zeros((rows, 2), np.float32), 0, ready_at_call
+
+    def is_ready(self) -> bool:
+        self.calls += 1
+        return self.calls >= self.ready_at_call
+
+    def __array__(self, dtype=None, copy=None):
+        return self.arr
+
+
+@pytest.mark.parametrize("case", ["blocking", "ready_during_a_pause", "ready_at_the_first_look"])
+def test_the_ready_lag_bound(case):
+    """0 for a blocking wait; where the pack turns ready during a pause of
+    the poll, at least that pause (the last look that found it running to
+    the first that found it ready); where the first look finds it ready, what
+    the poll did in front of that look."""
+    runner = make_engine("tiny", pipelined=True).runner
+    pack = FakePack(3, {"blocking": 1, "ready_during_a_pause": 4, "ready_at_the_first_look": 1}[case])
+    pending = runner_mod.PendingUnified(pack, S=1, prefill_rows=[], decode_rows=[0, 1], n_prefills=0, n_decodes=2)
+    polls: list = []
+
+    def poll():
+        polls.append(time.monotonic())
+        if case == "ready_at_the_first_look":
+            time.sleep(2e-3)  # an intake with a top-up in it
+
+    t0 = time.monotonic()
+    pres, dres = runner.wait_step(None, None, pending, poll=None if case == "blocking" else poll)
+    t1 = time.monotonic()
+    w = runner.last_wait
+    assert pres is None and dres.tokens.shape == (2, 1)
+    assert t0 <= w.ready_at <= w.read_at <= t1 and w.readback_s > 0
+    if case == "blocking":
+        assert w.ready_lag_bound_s == 0.0 and pack.calls == 0
+    elif case == "ready_during_a_pause":
+        assert len(polls) == 4 and pack.calls == 4
+        assert runner_mod._POLL_S <= w.ready_lag_bound_s <= w.ready_at - polls[-2]
+    else:
+        assert len(polls) == 1 and 2e-3 <= w.ready_lag_bound_s <= w.ready_at - t0
+
+
 # --- the serving loop ---------------------------------------------------------------
 
 
@@ -372,6 +556,99 @@ def test_a_steps_outputs_are_delivered_after_the_next_steps_dispatch():
     first_deliver = events.index("deliver")
     assert events[:first_deliver].count("dispatch") >= 1  # the next step is on the device before any output goes out
     assert eng.stats.steps_prestaged_total >= 5
+    # every output handed over is counted with its way from the readback's end
+    assert eng.stats.outputs_delivered_total == events.count("deliver") == sum(len(items) for items in streams)
+    assert eng.stats.deliver_lag_ms_total > 0 and eng.stats.intake_requests_total == len(prompts)
+
+
+class SpanLog:
+    """Stands in for ``profiling.span`` in the serving loop: every span's
+    name, length and what ``check`` said when it opened."""
+
+    def __init__(self, check):
+        self.check, self.seen = check, []
+
+    def __call__(self, name, **attrs):
+        log = self
+
+        class _Span:
+            def __enter__(self):
+                self.t, self.state = time.monotonic(), log.check(name)
+                return self
+
+            def __exit__(self, *exc):
+                log.seen.append((name, time.monotonic() - self.t, self.state))
+
+            def set_metadata(self, **kw):
+                pass
+
+        return _Span()
+
+
+def test_the_idle_span_opens_only_with_nothing_to_run_and_is_counted(monkeypatch):
+    """``llmd.serve.idle``: no inbox, no aborts, no work, not paused, and
+    ``engine_idle_ms_total`` grows by its length; a paused engine waits under
+    ``llmd.serve.paused`` and counts nothing, whatever it holds."""
+    from llmd_tpu.serve import async_engine
+
+    eng = make_engine("tiny", pipelined=True)
+    warm(eng, [tokens(9, seed=1)], 4)
+    sp = SamplingParams(max_tokens=4, temperature=0.0, ignore_eos=True)
+
+    async def run():
+        served = async_engine.AsyncEngine(eng, watchdog_s=0)
+        log = SpanLog(lambda name: (served._paused, bool(served._inbox), bool(served._aborts), eng.has_work()))
+        monkeypatch.setattr(async_engine.profiling, "span", log)
+        served.start(asyncio.get_running_loop())
+        try:
+            await asyncio.sleep(0.05)  # nothing to run
+            served.pause()
+            queue = served.submit("held", tokens(9, seed=1), sp)  # a paused engine with an inbox
+            await asyncio.sleep(0.05)
+            idle_when_paused = eng.stats.engine_idle_ms_total
+            await asyncio.sleep(0.03)
+            assert eng.stats.engine_idle_ms_total == idle_when_paused
+            served.resume()
+            while not (await asyncio.wait_for(queue.get(), 60)).finished:
+                pass
+            await asyncio.sleep(0.03)  # nothing to run again
+        finally:
+            served.stop()
+        return log.seen
+
+    seen = asyncio.run(run())
+    idle = [(length, state) for name, length, state in seen if name == "llmd.serve.idle"]
+    paused = [(length, state) for name, length, state in seen if name == "llmd.serve.paused"]
+    assert idle and all(state == (False, False, False, False) for _, state in idle)
+    assert paused and all(state[0] for _, state in paused) and any(state[1] for _, state in paused)
+    assert sum(length for length, _ in idle) >= 0.07
+    # (the counter's clock is read just outside the span's)
+    assert eng.stats.engine_idle_ms_total == pytest.approx(1e3 * sum(length for length, _ in idle), rel=0.02, abs=0.5)
+
+
+def test_intake_wait_is_the_time_a_request_sat_in_the_inbox():
+    """``submit`` stamps the request, ``_intake`` counts now - that: the wait
+    that lies in front of ``arrival_time``, and so of queue wait and TTFT."""
+    from llmd_tpu.serve.async_engine import AsyncEngine
+
+    eng = make_engine("tiny", pipelined=True)
+    served = AsyncEngine(eng, watchdog_s=0)  # no serving thread: the intake is called by hand
+    sp = SamplingParams(max_tokens=2, temperature=0.0, ignore_eos=True)
+    t0 = time.monotonic()
+    served.submit("a", tokens(5, seed=1), sp)
+    time.sleep(0.02)
+    served.submit("b", tokens(5, seed=2), sp)
+    time.sleep(0.01)
+    assert eng.stats.intake_requests_total == 0
+    assert served._intake() == 2
+    held = time.monotonic() - t0
+    st = eng.stats
+    assert st.intake_requests_total == 2 and len(eng.scheduler.waiting) == 2
+    assert 1e3 * (0.03 + 0.01) <= st.intake_wait_ms_total <= 1e3 * 2 * held
+    assert min(r.arrival_time for r in eng.scheduler.waiting) >= t0 + 0.03  # stamped at the intake
+    assert served._intake() == 0 and st.intake_requests_total == 2
+    for rid in ("a", "b"):
+        eng.abort_request(rid)
 
 
 # --- the benchmark's shape ladder ------------------------------------------------------

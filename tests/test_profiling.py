@@ -4,8 +4,6 @@ counters of EngineStats, the queue-wait counter, and their serving surfaces
 CPU, tiny engine: what is counted and what is written, never a time."""
 
 import dataclasses
-import glob
-import os
 
 import pytest
 from aiohttp.test_utils import TestClient, TestServer
@@ -24,6 +22,7 @@ from llmd_tpu.serve.api import build_app
 from llmd_tpu.serve.async_engine import AsyncEngine
 from llmd_tpu.serve.metrics import parse_prometheus, render_metrics
 from llmd_tpu.serve.tokenizer import ByteTokenizer
+from tests.host_trace import host_spans
 
 NEW_COUNTERS = (
     "step_admit_ms_total", "step_schedule_ms_total", "step_launch_ms_total",
@@ -31,6 +30,10 @@ NEW_COUNTERS = (
     "steps_prefill_total", "steps_decode_total", "steps_mixed_total",
     "step_ms_decode_total", "step_ms_prefill_total",
     "queue_wait_ms_total", "queue_admitted_total", "programs_traced_total",
+    # the host's turn between two programs, and the serving loop's waits
+    "step_ready_lag_bound_ms_total", "step_readback_ms_total", "step_gap_admit_ms_total",
+    "engine_idle_ms_total", "intake_wait_ms_total", "intake_requests_total",
+    "deliver_lag_ms_total", "outputs_delivered_total",
 )
 
 
@@ -51,19 +54,9 @@ def make_engine(num_blocks=128, max_batched=64, pipelined=True, **sched) -> LLME
 
 
 def host_events(trace_dir) -> dict:
-    """{span name: stats dict} of the trace's ``llmd.*`` host events."""
-    from jax.profiler import ProfileData
-
-    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
-    out = {}
-    for plane in ProfileData.from_file(path).planes:
-        if plane.name != "/host:CPU":
-            continue
-        for line in plane.lines:
-            for ev in line.events:
-                if ev.name.startswith("llmd."):
-                    out[ev.name] = dict(ev.stats)
-    return out
+    """{span name: stats dict} of the trace's ``llmd.*`` host events (the
+    last written of each name)."""
+    return {name: stats for name, _, _, stats in host_spans(trace_dir)}
 
 
 def test_start_stop_refuse_a_second_session(tmp_path):
@@ -126,6 +119,37 @@ def test_engine_steps_write_their_phase_spans(tmp_path):
     # end by max_tokens the pipelined step foresees: nothing is staged)
     assert events["llmd.sched.schedule"] == {"prefills": 0, "decodes": 0}
     assert events["llmd.step.commit"] == {"rolled": 0}
+    assert events["llmd.runner.readback"]["bytes"] > 0  # the one packed output of the step
+
+
+@pytest.mark.parametrize("pipelined", [False, True])
+def test_wait_and_readback_are_two_spans_and_the_readback_is_counted(tmp_path, pipelined):
+    """Both kinds of step go through ``wait_step``: ``llmd.runner.wait`` ends
+    where the host knows the outputs are ready, ``llmd.runner.readback``
+    follows it (a sibling, not a child) and ``step_readback_ms_total`` sums
+    its length; a blocking wait (no serving loop polls) has no ready lag."""
+    eng = make_engine(pipelined=pipelined)
+    eng.generate([[1, 2, 3, 4, 5]], SamplingParams(temperature=0.0, max_tokens=3))  # compiled
+    s = eng.stats
+    before = (s.engine_steps_total, s.step_readback_ms_total, s.step_wait_ms_total)
+    profiling.start(tmp_path)
+    try:
+        eng.generate([[9, 8, 7, 6, 5]], SamplingParams(temperature=0.0, max_tokens=5))
+    finally:
+        profiling.stop()
+    steps = s.engine_steps_total - before[0]
+    spans = host_spans(tmp_path)
+    waits, reads = ([(b, e) for name, b, e, _ in spans if name == want]
+                    for want in ("llmd.runner.wait", "llmd.runner.readback"))
+    assert len(waits) == len(reads) == steps >= 5
+    for (_, wait_end), (read_start, read_end) in zip(waits, reads):
+        assert wait_end <= read_start < read_end
+    counted = s.step_readback_ms_total - before[1]
+    traced = sum(e - b for b, e in reads) / 1e6
+    assert 0 < counted <= traced * 1.05 + 0.05 * steps  # (the span closes a little after the clock is read)
+    assert counted < s.step_wait_ms_total - before[2] + 1e-6  # a part of the wait as counted
+    assert s.step_ready_lag_bound_ms_total == 0.0  # no poll: nothing was looked at twice
+    assert (s.step_commit_ms_total > 0) == pipelined and s.step_gap_admit_ms_total == 0.0
 
 
 def test_async_first_step_lands_at_once_and_is_named_by_its_batch(tmp_path):
