@@ -98,10 +98,12 @@ class ModelConfig:
     # softmax-topk-renormalize already equals once the bias is folded in),
     # unlike DeepSeek-V3's selection-only correction bias.
     router_logit_bias: bool = False
-    # Expert MLP family: "silu" (Mixtral/Qwen/DeepSeek SwiGLU) or
+    # Expert MLP family: "silu" (Mixtral/Qwen/DeepSeek SwiGLU),
     # "swiglu_oss" (gpt-oss: interleaved-loaded gate/up WITH biases,
     # gate clamped to [-inf, limit], up to [-limit, limit],
-    # glu = gate * sigmoid(alpha * gate), out = (up + 1) * glu).
+    # glu = gate * sigmoid(alpha * gate), out = (up + 1) * glu) or
+    # "relu2" (nemotron_h: NOT gated, down(relu(up x)^2): two matrices an
+    # expert, the leaves ``we_up`` / ``we_down``; the shared expert alike).
     moe_activation: str = "silu"
     swiglu_limit: float = 7.0
     norm_topk_prob: bool = True
@@ -170,6 +172,13 @@ class ModelConfig:
     mamba_d_state: int = 0
     mamba_n_groups: int = 1
     mamba_d_conv: int = 4
+    # Which layers have an FFN behind their mixer; None = every layer. A
+    # model whose blocks are ONE mixer each (nemotron_h: a mamba, an
+    # attention or an expert block) is served as layers of mixer + FFN where
+    # an expert block follows a mixer, and as a layer WITHOUT FFN where the
+    # next block is a mixer again: the FFN leaves (post-norm, router,
+    # experts) are stacked over the layers that have one.
+    layer_ffn: tuple | None = None
 
     def __post_init__(self) -> None:
         if self.quantization not in (None, "int8"):
@@ -188,6 +197,23 @@ class ModelConfig:
                     f"layer_types has {len(self.layer_types)} entries for "
                     f"{self.num_layers} layers"
                 )
+        if self.layer_ffn is not None:
+            self.layer_ffn = tuple(bool(f) for f in self.layer_ffn)
+            if len(self.layer_ffn) != self.num_layers:
+                raise ValueError(
+                    f"layer_ffn has {len(self.layer_ffn)} entries for "
+                    f"{self.num_layers} layers"
+                )
+            if all(self.layer_ffn):
+                self.layer_ffn = None
+            elif not self.state_space:
+                raise ValueError(
+                    "layer_ffn (layers without FFN) is supported for models "
+                    "whose layers differ in their mixer (layer_types with "
+                    "\"mamba\") only"
+                )
+        if self.moe_activation not in ("silu", "swiglu_oss", "relu2"):
+            raise ValueError(f"moe_activation={self.moe_activation!r} not supported")
         if self.held_experts is None:
             self.held_experts = self.num_experts
         if not (
@@ -210,10 +236,11 @@ class ModelConfig:
                     "mamba layers need mamba_n_heads, mamba_d_head and "
                     "mamba_d_state"
                 )
-            if self.mamba_n_groups != 1:
+            if self.mamba_n_groups < 1 or self.mamba_n_heads % self.mamba_n_groups:
                 raise ValueError(
-                    "mamba_n_groups > 1 is not supported: the mixer shares "
-                    "one B and C among all heads"
+                    f"mamba_n_groups={self.mamba_n_groups} does not divide "
+                    f"mamba_n_heads={self.mamba_n_heads}: a group's B and C "
+                    "serve a whole number of heads"
                 )
             for what, on in (
                 ("MLA", self.kv_lora_rank > 0),
@@ -309,6 +336,25 @@ class ModelConfig:
         state-space mixers)."""
         m = set(self.mamba_layers)
         return tuple(i for i in range(self.num_layers) if i not in m)
+
+    @property
+    def ffn_layers(self) -> tuple[int, ...]:
+        """The layers that have an FFN (all of them unless ``layer_ffn``)."""
+        if self.layer_ffn is None:
+            return tuple(range(self.num_layers))
+        return tuple(i for i, f in enumerate(self.layer_ffn) if f)
+
+    @property
+    def moe_storage_width(self) -> int:
+        """The width the expert leaves are STORED at. Non-gated experts of a
+        width the grouped kernel cannot tile (no multiple of 128 lanes, under
+        a hidden size that is one) are stored padded up to the next multiple
+        with zero columns of ``we_up`` and zero rows of ``we_down``: exact,
+        relu(0)^2 = 0 meets a zero row. No width of the model changes."""
+        F = self.moe_intermediate_size
+        if self.moe_activation == "relu2" and self.hidden_size % 128 == 0:
+            return -(-F // 128) * 128
+        return F
 
     @property
     def mamba_d_inner(self) -> int:
@@ -407,6 +453,11 @@ class CacheConfig:
     # prefix seeds a fresh ring from the retained section and skips the
     # full prefill. 0 disables retention (ring hits then never shortcut).
     swa_section_cache: int = 8
+    # How many retained sections (or, over a state pool, snapshots) the
+    # second pool provisions; 0 = auto (``swa_section_count``: twice the
+    # sequences the scheduler may run). A geometry whose sequences all come
+    # back to their OWN retained state (resident decode) says one each.
+    swa_sections: int = 0
     # Ring-pool page count; 0 = auto (max_num_seqs x ring_pages: one ring
     # per possible running sequence; P/D preloads allocate extra rings at
     # arrival and the scheduler reclaims waiting preloads' rings if the
@@ -589,7 +640,10 @@ def swa_section_count(cache: "CacheConfig", sched: "SchedulerConfig") -> int:
     as many again may be shared prefixes captured on demand;
     ``swa_section_cache`` is the floor (and 0 still turns retention off:
     the caller's test). Eight sections under 32 sessions evicted each
-    turn's section before its next turn came."""
+    turn's section before its next turn came. ``swa_sections`` > 0 says
+    the count outright."""
+    if cache.swa_sections > 0 and cache.swa_section_cache > 0:
+        return cache.swa_sections
     return max(cache.swa_section_cache, 2 * sched.max_num_seqs)
 
 

@@ -263,6 +263,9 @@ class EngineStats:
     # slot from a snapshot; a miss is a run of full pages the main pool
     # offered at admission, refused for want of a snapshot at its end).
     state_slots_in_use: int = 0
+    # The slots the pool provisions for RUNNING sequences (max_num_seqs: a
+    # slot a sequence), which ``state_slots_in_use`` is read against.
+    state_slots: int = 0
     state_snapshots: int = 0
     state_snapshot_hits_total: int = 0
     state_snapshot_misses_total: int = 0
@@ -2431,6 +2434,7 @@ class LLMEngine:
             st, w = self.stats, self.swa_allocator
             s = self._swa_sections.stats() if self._swa_sections else {}
             st.state_snapshots = s.get("entries", 0)
+            st.state_slots = self.config.scheduler.max_num_seqs
             st.state_slots_in_use = (
                 w.num_pages - w.num_free_pages - st.state_snapshots
             )

@@ -115,20 +115,24 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
         return layers
 
     def layer_stack(n: int, moe: bool, prefix: str = "",
-                    attention: bool = True) -> dict[str, jax.Array]:
+                    attention: bool = True,
+                    n_ffn: int | None = None) -> dict[str, jax.Array]:
         """n stacked layers: attention (MLA or GQA) + dense-MLP or MoE.
         ``attention`` False leaves the mixer's weights out (a model whose
-        layers differ in their mixer stacks those per kind)."""
+        layers differ in their mixer stacks those per kind). ``n_ffn``: how
+        many of the layers have an FFN, whose leaves (``is_ffn_leaf``) are
+        stacked over those layers alone."""
 
         def mkp(name, shape, scale=None):
             return mk(prefix + name, shape, scale)
 
         layers: dict[str, jax.Array] = {
             "input_norm": jnp.ones((n, H), dt),
-            "post_norm": jnp.ones((n, H), dt),
         }
         if attention:
             layers.update(mixer_weights(n, mkp))
+        n = n if n_ffn is None else n_ffn
+        layers["post_norm"] = jnp.ones((n, H), dt)
         if moe:
             # The router scores every expert; the leaves hold the experts
             # this rank holds (all of them unless cfg says otherwise).
@@ -144,16 +148,28 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
                 # gpt-oss's real logit bias: the leaf must exist in the
                 # init tree (load_params' shape contract).
                 layers["router_bias"] = jnp.zeros((n, Er), jnp.float32)
-            layers["we_gate"] = mkp("we_gate", (n, E, H, Fm))
+            gated = cfg.moe_activation != "relu2"
+            if gated:
+                layers["we_gate"] = mkp("we_gate", (n, E, H, Fm))
             layers["we_up"] = mkp("we_up", (n, E, H, Fm))
             layers["we_down"] = mkp("we_down", (n, E, Fm, H))
+            if (pad := cfg.moe_storage_width - Fm) > 0:
+                # Stored at a width the grouped kernel tiles: zero columns
+                # and zero rows, exact (ModelConfig.moe_storage_width).
+                layers["we_up"] = jnp.pad(
+                    layers["we_up"], ((0, 0), (0, 0), (0, 0), (0, pad))
+                )
+                layers["we_down"] = jnp.pad(
+                    layers["we_down"], ((0, 0), (0, 0), (0, pad), (0, 0))
+                )
             if cfg.moe_activation == "swiglu_oss":
                 layers["we_gate_b"] = jnp.zeros((n, E, Fm), dt)
                 layers["we_up_b"] = jnp.zeros((n, E, Fm), dt)
                 layers["we_down_b"] = jnp.zeros((n, E, H), dt)
             if cfg.shared_expert_intermediate_size:
                 Fs = cfg.shared_expert_intermediate_size
-                layers["ws_gate"] = mkp("ws_gate", (n, H, Fs))
+                if gated:
+                    layers["ws_gate"] = mkp("ws_gate", (n, H, Fs))
                 layers["ws_up"] = mkp("ws_up", (n, H, Fs))
                 layers["ws_down"] = mkp("ws_down", (n, Fs, H))
         else:
@@ -166,7 +182,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     params: dict = {
         "embed": mk("embed", (V, H), scale=0.02),
         "layers": layer_stack(
-            L - n_dense, moe=cfg.is_moe, attention=not cfg.state_space
+            L - n_dense, moe=cfg.is_moe, attention=not cfg.state_space,
+            n_ffn=None if cfg.layer_ffn is None else len(cfg.ffn_layers),
         ),
         "final_norm": jnp.ones((H,), dt),
     }
@@ -217,6 +234,16 @@ def mixer_kinds(cfg: ModelConfig) -> tuple[MixerKind | None, ...]:
     return tuple(by_type[t] for t in cfg.layer_types)
 
 
+def is_ffn_leaf(name: str) -> bool:
+    """Leaves of ``params["layers"]`` that belong to a layer's FFN (its
+    pre-norm, router, experts, shared expert, dense MLP): stacked over the
+    layers that HAVE one (``ModelConfig.layer_ffn``), indexed by the layer's
+    place among those. Every other leaf is stacked over all layers."""
+    return name == "post_norm" or name.startswith(
+        ("router", "we_", "ws_", "w_gate", "w_up", "w_down", "w_gu")
+    )
+
+
 def _scan_period(kinds: tuple[int, ...]) -> int | None:
     """Smallest period c <= 4 of a layer-kind pattern (None if aperiodic).
 
@@ -226,13 +253,33 @@ def _scan_period(kinds: tuple[int, ...]) -> int | None:
     The bound c <= 4 is the longest cycle whose body is worth unrolling: a
     longer period (one attention layer in ten, or a pattern no deeper than
     its period) falls to the aperiodic branch, one scan per homogeneous run
-    (period 10 at depth 10: three runs, 5 + 1 + 4).
+    (period 10 at depth 10: three runs, 5 + 1 + 4). A model whose layers
+    differ in their MIXER cycles too, by ``_kind_cycles``.
     """
     n = len(kinds)
     for c in (2, 3, 4):
         if n % c == 0 and n > c and all(kinds[i] == kinds[i % c] for i in range(n)):
             return c
     return None
+
+
+def _kind_cycles(kinds: tuple) -> tuple[int, int] | None:
+    """(c, n): the layer pattern ``kinds`` (any hashable a layer: its mixer
+    kind and whether it has an FFN) STARTS with n >= 2 whole cycles of c <= 4
+    layers that are not all alike, the (c, n) that covers most layers; None
+    where no such cycle repeats. What lies behind the cycles is the caller's
+    to peel. Nemotron-H's ``[ME][ME][M.][*E]`` has c = 4; one attention layer
+    in ten (or a homogeneous run, whose period is 1) has none."""
+    best = None
+    for c in (2, 3, 4):
+        if len(set(kinds[:c])) < 2:
+            continue
+        n = 1
+        while kinds[n * c : (n + 1) * c] == kinds[:c]:
+            n += 1
+        if n >= 2 and (best is None or n * c > best[0] * best[1]):
+            best = (c, n)
+    return best
 
 
 def forward_hidden(
@@ -420,8 +467,11 @@ def forward_hidden(
 
     def layer_body(x, cache, lp, layer_idx, use_moe: bool, window=None,
                    table=None, run_phys=None, moe_layer=None, rotate=None,
-                   attn_kind=None, kind: MixerKind | None = None):
+                   attn_kind=None, kind: MixerKind | None = None,
+                   ffn: bool = True):
         """One decoder layer; returns (x, cache, census_delta | None).
+        ``ffn`` False: the mixer's residual branch alone (a layer without
+        FFN, or a caller that runs the FFN itself).
         ``layer_idx`` is the layer's plane of ``cache``; ``moe_layer`` its
         index into ``params["layers"]``, whose expert leaves ``lp`` holds
         whole (the dense prefix shifts one against the other). ``rotate``
@@ -597,6 +647,8 @@ def forward_hidden(
                     sinks=sinks,
                 )
             x = x + _res(_project(attn, B))
+        if not ffn:
+            return x, cache, None
         # attention residual already applied above; _tail adds 0
         x, cd = _tail(x, 0.0, lp, use_moe, moe_layer=moe_layer)
         return x, cache, cd
@@ -680,6 +732,33 @@ def forward_hidden(
     }
     lp_all = {k: a for k, a in params["layers"].items() if k not in experts}
     layer_arr = jnp.arange(n_scan, dtype=jnp.int32)
+    # Layers without FFN (cfg.layer_ffn): the FFN leaves are stacked over
+    # the layers that have one, so such a model's layers carry a second
+    # index. None where every layer has its FFN: the layer's id serves, and
+    # the scans' signature is what it was.
+    has_ffn = (True,) * n_scan if cfg.layer_ffn is None else cfg.layer_ffn
+    ffn_arr = None
+    if cfg.layer_ffn is not None:
+        ffn_arr = jnp.asarray(
+            [sum(has_ffn[: i + 1]) - 1 for i in range(n_scan)], jnp.int32
+        )
+
+    def layer_leaves(lid, fid, with_ffn: bool = True) -> dict:
+        """One layer's slices of the leaves the scans do not carry."""
+        return {
+            k: jax.lax.dynamic_index_in_dim(
+                a, fid if is_ffn_leaf(k) else lid, 0, keepdims=False
+            )
+            for k, a in lp_all.items() if with_ffn or not is_ffn_leaf(k)
+        }
+
+    def kind_leaves(kind: MixerKind | None, pid) -> dict:
+        if kind is None:
+            return {}
+        return {
+            k: jax.lax.dynamic_index_in_dim(a, pid, 0, keepdims=False)
+            for k, a in params[kind.stack].items()
+        }
 
     def _reduce_census(stacked):
         """Reduce per-layer census deltas [n, E+2] into the accumulator:
@@ -694,7 +773,8 @@ def forward_hidden(
 
     def scan_group(x, cache, census, table, lp, plane_ids, layer_ids, wins,
                    run_phys=None, rots=None, attn_kind=None,
-                   kind: MixerKind | None = None):
+                   kind: MixerKind | None = None, ffn_ids=None,
+                   ffn: bool = True):
         """One homogeneous run of layers sharing a pool/table. The census
         delta rides the scan as a per-layer OUTPUT (stacked then reduced)
         so the carry signature — and the compile cache — only changes
@@ -705,33 +785,28 @@ def forward_hidden(
         in every step (5 ms of a 28 ms decode step at 6,144 wide, PERF.md
         section 6, PR 33). ``kind``: the run's MIXER kind (a model whose
         layers differ in their mixer), whose own stack is indexed by the
-        layer's plane as the shared stack is by its id."""
-        kind_lp = None if kind is None else params[kind.stack]
+        layer's plane as the shared stack is by its id. ``ffn_ids`` / ``ffn``
+        (a model with layers without FFN): the run's indices into the FFN
+        leaves, and whether the run's layers have one."""
 
         def fn(carry, scanned):
             x, cache = carry
             lp_s, pid, lid, per = scanned
+            fid = per.get("ffn", lid)
             if lp_s is None:
-                lp_s = {
-                    k: jax.lax.dynamic_index_in_dim(a, lid, 0, keepdims=False)
-                    for k, a in lp_all.items()
-                }
-            if kind_lp is not None:
-                lp_s = {**lp_s, **{
-                    k: jax.lax.dynamic_index_in_dim(a, pid, 0, keepdims=False)
-                    for k, a in kind_lp.items()
-                }}
+                lp_s = layer_leaves(lid, fid, ffn)
+            lp_s = {**lp_s, **kind_leaves(kind, pid)}
             x, cache, cd = layer_body(
                 x, cache, {**lp_s, **experts}, pid, use_moe=cfg.is_moe,
                 window=per.get("window"), table=table, run_phys=run_phys,
-                moe_layer=lid,
+                moe_layer=fid,
                 rotate=False if no_rope else per.get("rotate"),
-                attn_kind=attn_kind, kind=kind,
+                attn_kind=attn_kind, kind=kind, ffn=ffn,
             )
             return (x, cache), cd
 
         per = {
-            k: a for k, a in (("window", wins), ("rotate", rots))
+            k: a for k, a in (("window", wins), ("rotate", rots), ("ffn", ffn_ids))
             if a is not None
         }
         scanned = (lp, plane_ids, layer_ids, per)
@@ -785,13 +860,72 @@ def forward_hidden(
         if census is not None and cds is not None:
             census = _census_merge(census, _reduce_census(cds))
     else:
-        # Aperiodic hybrid (e.g. Qwen2 upper-layer sliding): contiguous
-        # homogeneous runs, one scan each.
         off = 0
+        if per_kind and (cyc_n := _kind_cycles(
+            tuple(zip(layer_kinds, has_ffn))
+        )) is not None:
+            # A model whose BLOCKS are one mixer each (nemotron_h), as
+            # layers of mixer (+ FFN where an expert block follows): the
+            # whole cycles as ONE scan body, both pools in the carry, the
+            # pool, the mixer and the FFN static per position of the cycle.
+            # A block runs under its own scope, so the op profile splits a
+            # cycle by kind. The leaves stay loop invariants indexed by the
+            # scanned ids, as in ``scan_group``.
+            c, n_cyc = cyc_n
+            off = c * n_cyc
+
+            def resh(a):
+                return a[:off].reshape(n_cyc, c)
+
+            def cyc(carry, scanned):
+                x, *cc = carry
+                plane_c, layer_c, ffn_c = scanned
+                cd_cyc = None
+                for j in range(c):
+                    kind, g = layer_kinds[j], kinds[j]
+                    lid, fid = layer_c[j], layer_c[j] if ffn_c is None else ffn_c[j]
+                    lp_s = {
+                        **layer_leaves(lid, fid, has_ffn[j]),
+                        **kind_leaves(kind, plane_c[j]), **experts,
+                    }
+                    name = "mamba" if kind.mix is not None else "attn"
+                    with jax.named_scope(f"llmd.block.{name}"):
+                        x, cc[g], _ = layer_body(
+                            x, cc[g], lp_s, plane_c[j], use_moe=cfg.is_moe,
+                            table=tables[g], run_phys=run_physes[g],
+                            rotate=False if no_rope else None, kind=kind,
+                            ffn=False,
+                        )
+                    if has_ffn[j]:
+                        with jax.named_scope(
+                            "llmd.block.moe" if cfg.is_moe else "llmd.block.mlp"
+                        ):
+                            x, cd = _tail(
+                                x, 0.0, lp_s, cfg.is_moe, moe_layer=fid
+                            )
+                        if cd is not None:
+                            cd_cyc = (
+                                cd if cd_cyc is None else _census_merge(cd_cyc, cd)
+                            )
+                return (x, *cc), cd_cyc
+
+            (x, caches[0], caches[1]), cds = jax.lax.scan(
+                cyc, (x, caches[0], caches[1]),
+                (resh(plane_arr), resh(layer_arr),
+                 None if ffn_arr is None else resh(ffn_arr)),
+            )
+            if census is not None and cds is not None:
+                census = _census_merge(census, _reduce_census(cds))
+        # Aperiodic hybrid (e.g. Qwen2 upper-layer sliding), and what lies
+        # behind a per-kind model's whole cycles: contiguous homogeneous
+        # runs, one scan each.
         while off < n_scan:
             g = scan_kinds[off]
             ln = 1
-            while off + ln < n_scan and scan_kinds[off + ln] == g:
+            while (
+                off + ln < n_scan and scan_kinds[off + ln] == g
+                and has_ffn[off + ln] == has_ffn[off]
+            ):
                 ln += 1
             sl = slice(off, off + ln)
             x, caches[g], census = scan_group(
@@ -801,6 +935,7 @@ def forward_hidden(
                 run_physes[g],
                 None if rot_arr is None or no_rope else rot_arr[sl],
                 kind_name(n_dense + off), layer_kinds[n_dense + off],
+                None if ffn_arr is None else ffn_arr[sl], has_ffn[off],
             )
             off += ln
 

@@ -1,5 +1,6 @@
 """The Mamba-2 mixer of a hybrid state-space model (HF ``GraniteMoeHybrid``,
-whose mixer is Bamba's), on the flat step.
+whose mixer is Bamba's, one group of B and C; HF ``NemotronH``, several), on
+the flat step.
 
 ``KIND`` (``common.MixerKind``) is what ``llama.forward_hidden`` dispatches a
 "mamba" layer on: the weights it stacks (``init_layers``), its cache (the
@@ -7,12 +8,18 @@ state pool, a layer's plane of it) and ``mix``: normed input in, the mixer's
 output and the updated pool out. Attention is the other kind and stays in
 ``llama.layer_body``.
 
-    [z | xBC | dt] = u @ W_in                  widths d_in | d_in + 2N | H
+    [z | xBC | dt] = u @ W_in                  widths d_in | d_in + 2GN | H
     xBC = silu(causal_conv_k(xBC) + b_conv)    the slot's conv state in front
-    [x | B | C] = xBC                          d_in | N | N (one group)
+    [x | B | C] = xBC                          d_in | GN | GN: B, C are [G, N],
+                                               head h reads group h // (H / G)
     dt = softplus(dt + dt_bias), A = -exp(A_log)
     H_t = exp(dt_t A) H_{t-1} + dt_t x_t B_t^T, y_t = H_t C_t + D x_t
-    out = (RMSNorm(y * silu(z)) * w_norm) @ W_out
+    out = (RMSNorm_G(y * silu(z)) * w_norm) @ W_out   the norm over each of
+                                               the G groups' d_in / G channels
+
+G = ``mamba_n_groups`` is static: at G = 1 B and C are the ``[T, N]``
+operands and the norm the one ``rms_norm`` call they always were (the traced
+program of a one-group model does not depend on this file knowing groups).
 """
 
 from __future__ import annotations
@@ -37,11 +44,11 @@ def _inv_softplus(y):
 def init_layers(cfg: ModelConfig, n: int, mk, dt) -> dict[str, jax.Array]:
     """The ``n`` stacked mixers' weights (``mk(name, shape, scale=None)``
     draws a seeded leaf)."""
-    Hd, Hh, N, K = cfg.hidden_size, cfg.mamba_n_heads, cfg.mamba_d_state, cfg.mamba_d_conv
+    Hd, Hh, K = cfg.hidden_size, cfg.mamba_n_heads, cfg.mamba_d_conv
     d_in, C = cfg.mamba_d_inner, cfg.mamba_conv_dim
     heads = jnp.arange(1, Hh + 1, dtype=jnp.float32)
     return {
-        "m_in": mk("m_in", (n, Hd, 2 * d_in + 2 * N + Hh)),
+        "m_in": mk("m_in", (n, Hd, d_in + C + Hh)),
         "m_conv_w": mk("m_conv_w", (n, K, C), scale=K**-0.5),
         "m_conv_b": mk("m_conv_b", (n, C), scale=0.1),
         # Mamba-2's own initialisation: A in [1, 16]; dt log-spread over
@@ -75,13 +82,15 @@ def mix(h, lp, pool: ssm.StatePool, layer, rows: ssm.StateRows,
     is longer than ``row_cap``. Returns (out [T, 1, Hd], pool)."""
     T = h.shape[0]
     Hh, P, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
-    d_in, C = cfg.mamba_d_inner, cfg.mamba_conv_dim
+    G, d_in, C = cfg.mamba_n_groups, cfg.mamba_d_inner, cfg.mamba_conv_dim
     zxbcdt = pdot(h[:, 0], lp, "m_in")
     z, xbc, dt = zxbcdt[:, :d_in], zxbcdt[:, d_in : d_in + C], zxbcdt[:, d_in + C :]
     conv, conv_pool = ssm.causal_conv(xbc, lp["m_conv_w"], pool.conv, layer, rows)
     xbc = jax.nn.silu(conv + lp["m_conv_b"].astype(jnp.float32)).astype(h.dtype)
     x = xbc[:, :d_in].reshape(T, Hh, P)
-    Bm, Cm = xbc[:, d_in : d_in + N], xbc[:, d_in + N :]
+    Bm, Cm = xbc[:, d_in : d_in + G * N], xbc[:, d_in + G * N :]
+    if G > 1:
+        Bm, Cm = Bm.reshape(T, G, N), Cm.reshape(T, G, N)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["m_dt_bias"])
     # A pad token moves no state: dt 0 is decay 1 and no input.
     dt = jnp.where(rows.live[:, None], dt, 0.0)
@@ -93,7 +102,14 @@ def mix(h, lp, pool: ssm.StatePool, layer, rows: ssm.StateRows,
     )
     y = y + lp["m_D"][None, :, None] * x.astype(jnp.float32)
     y = y.reshape(T, d_in) * jax.nn.silu(z.astype(jnp.float32))
-    y = rms_norm(y, lp["m_norm"].astype(jnp.float32), cfg.rms_norm_eps)
+    w_norm = lp["m_norm"].astype(jnp.float32)
+    if G > 1:  # the gated norm over each group's channels
+        y = rms_norm(
+            y.reshape(T, G, d_in // G), w_norm.reshape(G, d_in // G),
+            cfg.rms_norm_eps,
+        ).reshape(T, d_in)
+    else:
+        y = rms_norm(y, w_norm, cfg.rms_norm_eps)
     out = pdot(y.astype(h.dtype), lp, "m_out")
     return out[:, None, :], ssm.StatePool(ssm_pool, conv_pool)
 

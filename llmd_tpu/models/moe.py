@@ -90,9 +90,12 @@ def router_topk(
 @jax.named_scope("llmd.moe.shared")
 def shared_expert_ffn(ht: jax.Array, lp: dict) -> jax.Array:
     """DeepSeek/Qwen2-MoE always-on shared expert (one place, three
-    backends: dense / grouped / EP)."""
+    backends: dense / grouped / EP). Without a ``ws_gate`` leaf it is the
+    non-gated one (``moe_activation`` "relu2"): down(relu(up x)^2)."""
     from llmd_tpu.models.common import pdot
 
+    if "ws_gate" not in lp:
+        return pdot(relu2(pdot(ht, lp, "ws_up")), lp, "ws_down")
     g = jax.nn.silu(pdot(ht, lp, "ws_gate"))
     return pdot(g * pdot(ht, lp, "ws_up"), lp, "ws_down")
 
@@ -109,6 +112,12 @@ def _expert_biases(lp: dict) -> tuple | None:
     if "we_gate_b" not in lp:
         return None
     return (lp["we_gate_b"], lp["we_up_b"], lp["we_down_b"])
+
+
+def relu2(up: jax.Array) -> jax.Array:
+    """The non-gated experts' nonlinearity (nemotron_h ``mlp_hidden_act``
+    "relu2"): relu(x)^2 between an expert's two matrices."""
+    return jnp.square(jax.nn.relu(up))
 
 
 def expert_glu(gate: jax.Array, up: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -128,6 +137,7 @@ def expert_glu(gate: jax.Array, up: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 # The expert leaves that the layer scan does not slice (forward_hidden): the
 # grouped kernel reads its layer of the stacked [L, E, ..] leaf in place.
+# (Non-gated experts have no ``we_gate``.)
 STACKED_EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
 
 
@@ -138,7 +148,8 @@ def experts_of_layer(lp: dict, layer) -> dict:
     from llmd_tpu.ops.grouped_gemm import layer_of
 
     return {
-        **lp, **{k: layer_of(lp[k], layer) for k in STACKED_EXPERT_LEAVES}
+        **lp,
+        **{k: layer_of(lp[k], layer) for k in STACKED_EXPERT_LEAVES if k in lp},
     }
 
 
@@ -165,7 +176,7 @@ def moe_block_grouped(
         ht, lp["router"], cfg.num_experts_per_tok, cfg, lp.get("router_bias")
     )
     out = moe_apply_grouped(
-        ht, weights, ids, lp["we_gate"], lp["we_up"], lp["we_down"],
+        ht, weights, ids, lp.get("we_gate"), lp["we_up"], lp["we_down"],
         scales=_expert_scales(lp), biases=_expert_biases(lp), cfg=cfg,
         mesh=mesh, emit_census=emit_census, layer=layer,
     )
@@ -203,7 +214,7 @@ def moe_block(h: jax.Array, lp: dict, cfg: ModelConfig) -> jax.Array:
     # GEMM + psum over the expert axis; the old [E, T, H] per-expert
     # intermediate instead forced an involuntary full rematerialization
     # (all-gather of expert activations) every MoE layer.
-    we_gate, we_up, we_down = lp["we_gate"], lp["we_up"], lp["we_down"]
+    we_gate, we_up, we_down = lp.get("we_gate"), lp["we_up"], lp["we_down"]
     if "we_gate_scale" in lp:
         # Dense combine is the numerics oracle / GSPMD-fallback path:
         # dequantize in place (the serving int8 paths are grouped/EP).
@@ -212,13 +223,17 @@ def moe_block(h: jax.Array, lp: dict, cfg: ModelConfig) -> jax.Array:
         we_gate = dequantize(we_gate, lp["we_gate_scale"], dtype=ht.dtype)
         we_up = dequantize(we_up, lp["we_up_scale"], dtype=ht.dtype)
         we_down = dequantize(we_down, lp["we_down_scale"], dtype=ht.dtype)
-    gate = jnp.einsum("th,ehf->etf", ht, we_gate)
-    up = jnp.einsum("th,ehf->etf", ht, we_up)
     biases = _expert_biases(lp)
-    if biases is not None:
-        gate = gate + biases[0][:, None, :]
-        up = up + biases[1][:, None, :]
-    act = expert_glu(gate, up, cfg) * combine.T[:, :, None].astype(gate.dtype)
+    if we_gate is None:  # non-gated experts
+        act = relu2(jnp.einsum("th,ehf->etf", ht, we_up))
+    else:
+        gate = jnp.einsum("th,ehf->etf", ht, we_gate)
+        up = jnp.einsum("th,ehf->etf", ht, we_up)
+        if biases is not None:
+            gate = gate + biases[0][:, None, :]
+            up = up + biases[1][:, None, :]
+        act = expert_glu(gate, up, cfg)
+    act = act * combine.T[:, :, None].astype(act.dtype)
     out = jnp.einsum(
         "etf,efh->th", act, we_down,
         preferred_element_type=jnp.float32,

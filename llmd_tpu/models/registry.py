@@ -271,6 +271,79 @@ def _tiny_granite_hybrid() -> ModelConfig:
     )
 
 
+def nemotron_h_layers(pattern: str) -> tuple[tuple[str, ...], tuple[bool, ...]]:
+    """``(layer_types, layer_ffn)`` of a ``nemotron_h``
+    ``hybrid_override_pattern``: its blocks are ONE mixer each (``M`` a
+    Mamba-2 mixer, ``*`` attention, ``E`` an expert FFN), ``x = x + Block(
+    RMSNorm(x))``. A mixer and the ``E`` behind it are one layer of mixer +
+    FFN, as every other model's; a mixer that another mixer follows is a
+    layer WITHOUT FFN. 52 blocks are 29 layers."""
+    types, ffn = [], []
+    for ch in pattern:
+        if ch in "M*":
+            types.append("mamba" if ch == "M" else "attention")
+            ffn.append(False)
+        elif ch == "E" and ffn and not ffn[-1]:
+            ffn[-1] = True
+        else:
+            raise ValueError(
+                f"hybrid_override_pattern {pattern!r}: an expert block has no "
+                "mixer in front of it (or an unknown block)"
+            )
+    return tuple(types), tuple(ffn)
+
+
+_NEMOTRON_3_NANO = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+
+
+@register_model("nemotron-3-nano-30b-a3b")
+def _nemotron_3_nano() -> ModelConfig:
+    """NVIDIA-Nemotron-3-Nano-30B-A3B (HF nvidia/NVIDIA-Nemotron-3-Nano-30B-
+    A3B-BF16, ``nemotron_h``, 31.6B-A3.2B): 52 blocks of one mixer each: 23
+    Mamba-2 (64 heads x 64, state 128, B and C in 8 groups, conv 4), 23
+    expert blocks (128 non-gated relu^2 experts top-6 of width 1,856 + one
+    shared of 3,712; sigmoid scores, selection bias, normalised, x 2.5) and
+    6 attention blocks (GQA 32/2 x 128, NO positional encoding); untied
+    vocabulary. Served as 29 layers (``nemotron_h_layers``)."""
+    types, ffn = nemotron_h_layers(_NEMOTRON_3_NANO)
+    return ModelConfig(
+        name="nemotron-3-nano-30b-a3b", vocab_size=131072, hidden_size=2688,
+        intermediate_size=1856, num_layers=len(types), num_heads=32,
+        num_kv_heads=2, head_dim=128, rope_theta=10000.0,
+        max_model_len=262144, rms_norm_eps=1e-5,
+        layer_types=types, layer_ffn=ffn, rope_layer_types=(),
+        mamba_n_heads=64, mamba_d_head=64, mamba_d_state=128,
+        mamba_n_groups=8, mamba_d_conv=4,
+        num_experts=128, num_experts_per_tok=6, moe_intermediate_size=1856,
+        shared_expert_intermediate_size=3712, moe_activation="relu2",
+        router_scoring="sigmoid", norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+    )
+
+
+@register_model("tiny-nemotron-h")
+def _tiny_nemotron_h() -> ModelConfig:
+    """nemotron-3-nano's architecture in miniature (CPU tests and the
+    benchmark's rehearsal): two whole cycles ``MEMEM*E`` and a tail
+    ``MEM*E`` that is none (11 layers, 3 of them without FFN), 4 mixer heads
+    x 8 with state 16 and B, C in TWO groups, GQA 4/2 without rope, 8
+    non-gated relu^2 experts top-2 of width 24 (no multiple of a lane) + a
+    shared one of 48, sigmoid scores with a selection bias, x 2.5, an untied
+    vocabulary, and a held share: experts 0-3 of the 8 the router scores."""
+    types, ffn = nemotron_h_layers("MEMEM*E" * 2 + "MEM*E")
+    return tiny_model_config(
+        name="tiny-nemotron-h", num_layers=len(types), max_model_len=512,
+        rms_norm_eps=1e-5, layer_types=types, layer_ffn=ffn,
+        rope_layer_types=(),
+        mamba_n_heads=4, mamba_d_head=8, mamba_d_state=16,
+        mamba_n_groups=2, mamba_d_conv=4,
+        num_experts=8, num_experts_per_tok=2, moe_intermediate_size=24,
+        shared_expert_intermediate_size=48, moe_activation="relu2",
+        router_scoring="sigmoid", norm_topk_prob=True,
+        routed_scaling_factor=2.5, held_experts=4, held_experts_first=0,
+    )
+
+
 @register_model("mixtral-8x7b")
 def _mixtral_8x7b() -> ModelConfig:
     return ModelConfig(

@@ -287,19 +287,20 @@ def grouped_census(
 def expert_mlp_grouped(
     x_sorted: jax.Array,     # [T', H] rows sorted by expert
     group_sizes: jax.Array,  # [E]
-    we_gate: jax.Array,      # [E, H, F] (bf16, or int8 with scales), or
+    we_gate: jax.Array | None,  # [E, H, F] (bf16, or int8 with scales), or
     we_up: jax.Array,        # [E, H, F]   all layers' [L, E, ..] with
-    we_down: jax.Array,      # [E, F, H]   ``layer``
+    we_down: jax.Array,      # [E, F, H]   ``layer``; ``we_gate`` None: the
+                             # non-gated experts, down(relu(up x)^2)
     scales: tuple | None = None,  # int8 experts: (s_gate [E,F], s_up [E,F], s_down [E,H])
     biases: tuple | None = None,  # gpt-oss experts: (b_gate [E,F], b_up [E,F], b_down [E,H])
     cfg=None,                # ModelConfig for the activation family
     mesh=None,               # the mesh whose devices run this, if any
     layer=None,              # i32 scalar: the layer of stacked weights
 ) -> jax.Array:              # [T', H]
-    from llmd_tpu.models.moe import expert_glu
+    from llmd_tpu.models.moe import expert_glu, relu2
 
     T = x_sorted.shape[0]
-    E = we_gate.shape[-3]
+    E = we_down.shape[-3]
     if scales is not None:
         from llmd_tpu.ops.quant import grouped_matmul_q
 
@@ -309,6 +310,10 @@ def expert_mlp_grouped(
     else:
         mm = lambda x, w, s: grouped_matmul(x, w, group_sizes, mesh, layer)  # noqa: E731
     s_gate, s_up, s_down = scales if scales is not None else (None,) * 3
+    if we_gate is None:
+        # Two grouped matmuls a layer, relu^2 between them.
+        act = relu2(mm(x_sorted, we_up, s_up))
+        return mm(act.astype(x_sorted.dtype), we_down, s_down)
     gate = mm(x_sorted, we_gate, s_gate)
     up = mm(x_sorted, we_up, s_up)
     gid = None
@@ -341,9 +346,9 @@ def moe_apply_grouped(
     ht: jax.Array,       # [T, H]
     weights: jax.Array,  # [T, k] f32 combine weights (scaled/normalized)
     ids: jax.Array,      # [T, k] i32 expert ids over the router's width
-    we_gate: jax.Array,  # one layer's [E, ..] HELD experts, or all layers'
-    we_up: jax.Array,    # stacked [L, E, ..] with ``layer``
-    we_down: jax.Array,
+    we_gate: jax.Array | None,  # one layer's [E, ..] HELD experts, or all
+    we_up: jax.Array,    # layers' stacked [L, E, ..] with ``layer``; the
+    we_down: jax.Array,  # non-gated experts have no ``we_gate``
     scales: tuple | None = None,
     biases: tuple | None = None,
     cfg=None,
@@ -359,7 +364,7 @@ def moe_apply_grouped(
     is ``(y, census)``, ``census`` this layer's ``grouped_census`` line."""
     T, H = ht.shape
     k = ids.shape[1]
-    E = we_gate.shape[-3]
+    E = we_down.shape[-3]
     share = cfg is not None and not cfg.holds_all_experts
     flat_ids = ids.reshape(-1)                       # [T*k]
     if share:

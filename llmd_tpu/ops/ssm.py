@@ -180,22 +180,30 @@ def _update_kernel(
     slots_ref, cnt_ref, layer_ref,  # scalar prefetch
     h_ref, ax_ref, b_ref, c_ref,    # inputs
     o_ref, y_ref,                   # outputs (o aliases the pool)
-    *, hb: int,
+    *, hb: int, hpg: int = 0,
 ):
+    """``hpg`` 0: the block's heads share ONE b and c (``[1, N]``: one
+    group, or a block inside a group). Else the block spans several groups
+    of ``hpg`` heads and b, c hold a row a group."""
     del slots_ref, layer_ref
     i = pl.program_id(1)
     cnt = cnt_ref[0]
 
+    def of_head(v, hh):
+        return v[hh // hpg : hh // hpg + 1] if hpg else v
+
     @pl.when(i < cnt)
     def _():
-        b = b_ref[...]  # [1, N]
+        b = b_ref[...]  # [1, N], or [the block's groups, N]
         c = c_ref[...]
         for hh in range(hb):
             a = ax_ref[0, :, hh : hh + 1]   # [P, 1] decay
             xc = ax_ref[1, :, hh : hh + 1]  # [P, 1] dt * x
-            hn = h_ref[hh] * a + xc * b     # [P, N]
+            hn = h_ref[hh] * a + xc * of_head(b, hh)  # [P, N]
             o_ref[hh] = hn
-            y_ref[:, hh : hh + 1] = jnp.sum(hn * c, axis=1, keepdims=True)
+            y_ref[:, hh : hh + 1] = jnp.sum(
+                hn * of_head(c, hh), axis=1, keepdims=True
+            )
 
     @pl.when(cnt == 0)
     def _():
@@ -204,22 +212,36 @@ def _update_kernel(
         o_ref[...] = h_ref[...]
 
 
-def _head_block(H: int) -> int:
+def _head_block(H: int, hpg: int = 0) -> int:
+    """Heads a block of the pool. ``hpg`` (heads a group of B and C, where
+    there are several groups): a block is then whole groups or lies inside
+    one, so that its b and c are rows of one operand block."""
     for hb in (32, 16, 8):
-        if H % hb == 0:
+        if H % hb == 0 and (not hpg or hb % hpg == 0 or hpg % hb == 0):
             return hb
     return H
+
+
+def _block_groups(b, nb: int, hb: int, hpg: int):
+    """``b`` [U, G, N] as [U, nb, the groups of a head block, N]."""
+    gpb = max(1, hb // hpg)
+    idx = [(j * hb) // hpg + r for j in range(nb) for r in range(gpb)]
+    U, _, N = b.shape
+    return jnp.take(b, jnp.asarray(idx, jnp.int32), axis=1).reshape(U, nb, gpb, N)
 
 
 def ssm_update_pallas(ssm, layer, slots, count, a, dtx, b, c, *, interpret=False):
     """``ssm`` [Lm, S, H, P, N] f32 updated in place for entries
     ``[0, count)``: ``H = a * H + dtx (x) b`` and ``y = H . c``. ``slots``
     [U] i32, ``a`` [U, H] f32 (the decay; 0 starts from zeros), ``dtx`` [U,
-    H, P] f32, ``b``, ``c`` [U, N] f32. Returns (ssm, y [U, H, P] f32; rows
-    past ``count`` hold nothing)."""
+    H, P] f32, ``b``, ``c`` [U, N] f32, or [U, G, N] where the heads read B
+    and C in G groups (head h its group ``h // (H / G)``'s). Returns (ssm, y
+    [U, H, P] f32; rows past ``count`` hold nothing)."""
     _, _, H, P, N = ssm.shape
     U = slots.shape[0]
-    hb = _head_block(H)
+    grouped = b.ndim == 3
+    hpg = H // b.shape[1] if grouped else 0
+    hb = _head_block(H, hpg)
     nb = H // hb
     ax = jnp.stack([jnp.broadcast_to(a[:, :, None], dtx.shape), dtx], axis=1)
     ax = ax.reshape(U, 2, nb, hb, P).transpose(0, 2, 1, 4, 3)  # [U, nb, 2, P, hb]
@@ -233,6 +255,19 @@ def ssm_update_pallas(ssm, layer, slots, count, a, dtx, b, c, *, interpret=False
         (None, None, hb, P, N),
         lambda j, i, sl, cnt, ly: (ly[0], sl[eff(i, cnt)], j, 0, 0),
     )
+    if grouped:
+        # A head block's groups: [U, nb, groups of the block, N], the block
+        # (entry, head block)'s.
+        b, c = (_block_groups(v, nb, hb, hpg) for v in (b, c))
+        bc_spec = pl.BlockSpec(
+            (None, None, b.shape[2], N),
+            lambda j, i, sl, cnt, ly: (eff(i, cnt), j, 0, 0),
+        )
+    else:
+        b, c = b[:, None, :], c[:, None, :]
+        bc_spec = pl.BlockSpec(
+            (None, 1, N), lambda j, i, sl, cnt, ly: (eff(i, cnt), 0, 0)
+        )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(nb, U),
@@ -242,8 +277,7 @@ def ssm_update_pallas(ssm, layer, slots, count, a, dtx, b, c, *, interpret=False
                 (None, None, 2, P, hb),
                 lambda j, i, sl, cnt, ly: (eff(i, cnt), j, 0, 0, 0),
             ),
-            pl.BlockSpec((None, 1, N), lambda j, i, sl, cnt, ly: (eff(i, cnt), 0, 0)),
-            pl.BlockSpec((None, 1, N), lambda j, i, sl, cnt, ly: (eff(i, cnt), 0, 0)),
+            bc_spec, bc_spec,
         ],
         out_specs=[
             pool_spec,
@@ -251,7 +285,8 @@ def ssm_update_pallas(ssm, layer, slots, count, a, dtx, b, c, *, interpret=False
         ],
     )
     ssm, y = pl.pallas_call(
-        functools.partial(_update_kernel, hb=hb),
+        # hpg 0 where the block's heads share one row of b and c.
+        functools.partial(_update_kernel, hb=hb, hpg=hpg if hb > hpg else 0),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(ssm.shape, ssm.dtype),
@@ -263,25 +298,30 @@ def ssm_update_pallas(ssm, layer, slots, count, a, dtx, b, c, *, interpret=False
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(slots.astype(jnp.int32), count, layer, ssm, ax, b[:, None, :], c[:, None, :])
+    )(slots.astype(jnp.int32), count, layer, ssm, ax, b, c)
     return ssm, y.transpose(0, 1, 3, 2).reshape(U, H, P)
 
 
 def ssm_update_xla(ssm, layer, slots, count, a, dtx, b, c):
     """``ssm_update_pallas`` as XLA operations (a gather, the update, a
     scatter that drops the entries past ``count``)."""
-    S = ssm.shape[1]
+    S, H = ssm.shape[1], ssm.shape[2]
     U = slots.shape[0]
     plane = jax.lax.dynamic_index_in_dim(ssm, layer, 0, keepdims=False)
-    h = plane[slots] * a[:, :, None, None] + dtx[..., None] * b[:, None, None, :]
-    y = jnp.sum(h * c[:, None, None, :], axis=-1)
+    if b.ndim == 3:  # [U, G, N]: a head reads its group's
+        b, c = (jnp.repeat(v, H // v.shape[1], axis=1)[:, :, None, :] for v in (b, c))
+        h = plane[slots] * a[:, :, None, None] + dtx[..., None] * b
+        y = jnp.sum(h * c, axis=-1)
+    else:
+        h = plane[slots] * a[:, :, None, None] + dtx[..., None] * b[:, None, None, :]
+        y = jnp.sum(h * c[:, None, None, :], axis=-1)
     dst = jnp.where(jnp.arange(U) < count, slots, S)
     return ssm.at[layer, dst].set(h, mode="drop"), y
 
 
 def ssm_update(ssm, layer, rows: StateRows, x, dt, dA, Bm, Cm, plan: str):
     """The decode rows of a step. ``x`` [T, H, P], ``dt``, ``dA`` [T, H]
-    f32, ``Bm``, ``Cm`` [T, N]. Returns (ssm, y [T, H, P] f32 that holds the
+    f32, ``Bm``, ``Cm`` [T, N] (one group) or [T, G, N]. Returns (ssm, y [T, H, P] f32 that holds the
     decode rows' outputs and zeros elsewhere)."""
     T = x.shape[0]
     r = rows.upd_rows
@@ -386,11 +426,13 @@ def ssm_scan(ssm, layer, rows: StateRows, x, dt, dA, Bm, Cm, y, row_cap: int,
     """The prefill rows of a step, in stream order, the running state
     carried from a row to the next row of its segment. ``y`` [T, H, P] f32
     comes in holding the decode rows' outputs and leaves with the prefill
-    rows' added. ``row_cap`` bounds a row's tokens (the runner cuts chunks
+    rows' added. ``Bm``, ``Cm`` [T, N], or [T, G, N] where the heads read
+    them in G groups. ``row_cap`` bounds a row's tokens (the runner cuts chunks
     to it). The pool's LAST slot is scratch: a row that does not end its
     segment writes there, so every row makes one read and one write."""
     T, H, P = x.shape
     N = Bm.shape[-1]
+    G = Bm.shape[1] if Bm.ndim == 3 else 0  # 0: one group, ``Bm`` [T, N]
     Lr = row_cap
     scratch = ssm.shape[1] - 1
     pad = lambda a: jnp.concatenate(  # noqa: E731
@@ -417,20 +459,42 @@ def ssm_scan(ssm, layer, rows: StateRows, x, dt, dA, Bm, Cm, y, row_cap: int,
         dts = jnp.where(m[:, None], sl(dtp), 0.0)
         cum = jnp.cumsum(jnp.where(m[:, None], sl(dAp), 0.0), axis=0)  # [Lr, H]
         # Within the row: y_l += sum_{s <= l} exp(cum_l - cum_s) dt_s (C_l . B_s) x_s
-        g = jnp.einsum("ln,sn->ls", cs_, bs, precision=HIGHEST)
-        decay = jnp.where(
-            tril[:, :, None], jnp.exp(cum[:, None, :] - cum[None, :, :]), 0.0
-        )  # [l, s, H]
-        w = g[:, :, None] * decay * dts[None, :, :]
-        y_row = jnp.einsum("lsh,shp->lhp", w, xs, precision=HIGHEST)
-        # From the state the row entered with.
-        y_row = y_row + jnp.exp(cum)[:, :, None] * jnp.einsum(
-            "ln,hpn->lhp", cs_, hc, precision=HIGHEST
-        )
-        to_end = jnp.exp(cum[-1][None, :] - cum) * dts  # [Lr, H]
-        hn = jnp.exp(cum[-1])[:, None, None] * hc + jnp.einsum(
-            "shp,sn->hpn", to_end[:, :, None] * xs, bs, precision=HIGHEST
-        )
+        if not G:
+            g = jnp.einsum("ln,sn->ls", cs_, bs, precision=HIGHEST)
+            decay = jnp.where(
+                tril[:, :, None], jnp.exp(cum[:, None, :] - cum[None, :, :]), 0.0
+            )  # [l, s, H]
+            w = g[:, :, None] * decay * dts[None, :, :]
+            y_row = jnp.einsum("lsh,shp->lhp", w, xs, precision=HIGHEST)
+            # From the state the row entered with.
+            y_row = y_row + jnp.exp(cum)[:, :, None] * jnp.einsum(
+                "ln,hpn->lhp", cs_, hc, precision=HIGHEST
+            )
+            to_end = jnp.exp(cum[-1][None, :] - cum) * dts  # [Lr, H]
+            hn = jnp.exp(cum[-1])[:, None, None] * hc + jnp.einsum(
+                "shp,sn->hpn", to_end[:, :, None] * xs, bs, precision=HIGHEST
+            )
+        else:
+            # B and C in G groups of R heads: the same sums with the head
+            # axis split into (group, head of the group).
+            byg = lambda a: a.reshape(*a.shape[:-1], G, H // G)  # noqa: E731
+            g = jnp.einsum("lgn,sgn->lsg", cs_, bs, precision=HIGHEST)
+            decay = jnp.where(
+                tril[:, :, None], jnp.exp(cum[:, None, :] - cum[None, :, :]), 0.0
+            )
+            w = g[..., None] * byg(decay) * byg(dts)[None]  # [l, s, G, R]
+            xg = xs.reshape(Lr, G, H // G, P)
+            y_row = jnp.einsum("lsgr,sgrp->lgrp", w, xg, precision=HIGHEST)
+            y_row = y_row + byg(jnp.exp(cum))[..., None] * jnp.einsum(
+                "lgn,grpn->lgrp", cs_, hc.reshape(G, H // G, P, N),
+                precision=HIGHEST,
+            )
+            y_row = y_row.reshape(Lr, H, P)
+            to_end = jnp.exp(cum[-1][None, :] - cum) * dts
+            hn = jnp.exp(cum[-1])[:, None, None] * hc + jnp.einsum(
+                "sgrp,sgn->grpn", byg(to_end)[..., None] * xg, bs,
+                precision=HIGHEST,
+            ).reshape(H, P, N)
         old = jax.lax.dynamic_slice_in_dim(yp, t0, Lr, 0)
         yp = jax.lax.dynamic_update_slice_in_dim(
             yp, jnp.where(m[:, None, None], y_row, old), t0, 0

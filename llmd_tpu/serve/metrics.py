@@ -69,6 +69,7 @@ def render_metrics(
     if stats.state_slots_in_use or stats.state_snapshots:
         # The state pool: slots that running sequences hold, snapshots kept.
         gauges["state_slots_in_use"] = stats.state_slots_in_use
+        gauges["state_slots"] = stats.state_slots
         gauges["state_snapshots"] = stats.state_snapshots
     gauges["kv_offload_cpu_pages"] = stats.offload_pages
     gauges["kv_offload_fs_pages"] = stats.offload_fs_pages

@@ -253,6 +253,18 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                    "a session, and leaves the snapshot behind). A share "
                    "that stays low = snapshots evicted before their "
                    "prefix comes back: more of them (swa_section_cache)."),
+        panel("State slots: running against provisioned",
+              [f"llmd:state_slots_in_use{M}", f"llmd:state_slots{M}",
+               f"llmd:state_slots_in_use{M} / llmd:state_slots{M}"],
+              legends=["running slots", "max_num_seqs (a slot a sequence)",
+                       "share in use"],
+              desc="Models with state-space layers: a running sequence "
+                   "holds ONE slot of the state pool whatever its length "
+                   "(megabytes a slot), so the pool is sized by the "
+                   "sequences the scheduler may run. In use = provisioned "
+                   "for long = the state pool, not the pages, bounds the "
+                   "batch: more slots if memory allows (a decode-heavy, "
+                   "high-concurrency deployment runs 128 and more)."),
         panel("State snapshot activity /s",
               [f"rate(llmd:state_snapshot_hits_total{M}[5m])",
                f"rate(llmd:state_snapshot_misses_total{M}[5m])",

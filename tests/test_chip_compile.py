@@ -217,6 +217,9 @@ GMM_MODELS = {
     "k-exaone-236b-a23b": (6144, 2048, 16),
     # one rank's 36 of the 72 experts
     "granite-4.0-h-small": (4096, 768, 36),
+    # one rank's 16 of the 128 non-gated experts, stored at 1,920 = 15 x 128
+    # lanes for their 1,856 (ModelConfig.moe_storage_width)
+    "nemotron-3-nano-30b-a3b": (2688, 1920, 16),
 }
 # granite-4.0-h-small.1chip's attention layer (1 layer, 32 q / 8 kv heads) and
 # its state pool: 9 mixers x 97 slots of 128 heads x 64 x state 128.
@@ -224,13 +227,19 @@ GRANITE = (1, 32, 8, 128)
 STATE_POOL = (9, 97, 128, 64, 128)
 
 
-def _ssm_update(rows):
+# nemotron-3-nano-30b-a3b.1chip's state pool: 12 mixers x 257 slots of 64 heads
+# x 64 x state 128, B and C in 8 groups: a head block of 32 spans four.
+GROUPED_STATE_POOL, GROUPS = (12, 257, 64, 64, 128), 8
+
+
+def _ssm_update(rows, pool=STATE_POOL, groups=0):
     from llmd_tpu.ops.ssm import ssm_update_pallas
 
-    _, _, H, P, N = STATE_POOL
+    _, _, H, P, N = pool
+    bc = (rows, groups, N) if groups else (rows, N)
     return ssm_update_pallas, [
-        (STATE_POOL, F32), ((), I32), ((rows,), I32), ((), I32), ((rows, H), F32),
-        ((rows, H, P), F32), ((rows, N), F32), ((rows, N), F32),
+        (pool, F32), ((), I32), ((rows,), I32), ((), I32), ((rows, H), F32),
+        ((rows, H, P), F32), (bc, F32), (bc, F32),
     ]
 
 
@@ -264,6 +273,8 @@ CASES = {
     "flat_attention-granite-4.0-h-small": lambda d: _flat_attention(GRANITE, BF16, 528),
     "flat_write-granite-4.0-h-small": lambda d: _flat_write(GRANITE, BF16, 528),
     "ssm_update-granite-4.0-h-small": lambda d: _ssm_update(40),
+    "ssm_update-nemotron-3-nano-30b-a3b": lambda d: _ssm_update(136, GROUPED_STATE_POOL, GROUPS),
+    "ssm_update-groups-inside-a-block": lambda d: _ssm_update(40, (2, 9, 64, 64, 128), 2),
     "ssm_slot_read-granite-4.0-h-small": lambda d: _ssm_slot(False),
     "ssm_slot_write-granite-4.0-h-small": lambda d: _ssm_slot(True),
     **{
@@ -443,3 +454,78 @@ def test_the_state_space_mixers_update_the_state_pool_in_place(v5e):
     pool_bytes = Lm * S * (128 * 64 * 128 * 4 + 3 * 8448 * 2)
     assert m.alias_size_in_bytes >= pool_bytes
     assert m.temp_size_in_bytes < 0.5 * 2**30
+
+
+@pytest.fixture(scope="module")
+def nemotron_step(v5e):
+    """nemotron-3-nano-30b-a3b.1chip's runner as shapes on the described chip
+    (``perfbench/rehearse_compile_mixer.py``): 16 layers in four cycles, both
+    pools at the cell's sizes."""
+    import json
+    import pathlib
+    import sys
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from perfbench import rehearse_compile_mixer as rc
+    from perfbench.topologies.engine_mixer import engine_config
+
+    conf = json.loads((root / rc.CONFIG).read_text())
+    return rc, rc.build_runner(engine_config(conf, 0, False), v5e)
+
+
+@pytest.mark.parametrize("T", [128, 272])
+def test_the_mixer_only_hybrids_steps_compile_with_both_pools_in_place(nemotron_step, T):
+    """The decode-only step of 128 resident rows and the saturated step of the
+    new geometry, for the described v5e: both pools aliased (2.0 GiB of pages,
+    6.1 GiB of state), nothing copies them, the whole step under the chip's
+    15.75 GiB; the Pallas calls carry the names the benchmark's readers match
+    (``%gmm``, ``%llmd.ssm.update``, ``%llmd.ssm.scan``) and the operand forms
+    they parse: the experts' stacked ``[L, E, K, N]`` leaf at the ``%gmm``
+    calls (two a layer: up, down), the state pool ``f32[Lm, slots, H, P, N]``
+    at the update."""
+    rc, r = nemotron_step
+    _lowered, compiled = rc.compile_step(r, T)
+    text = compiled.as_text()
+    calls = [ln.strip() for ln in text.splitlines() if "tpu_custom_call" in ln and " custom-call(" in ln]
+    names = {ln.split(" = ")[0].rstrip(".0123456789") for ln in calls}
+    assert {"%gmm", "%llmd.ssm.update", "%llmd.ssm.scan"} <= names
+    # the cycle body's three FFN positions: an up and a down projection each
+    gmm_shapes = [re.search(r"bf16\[(\d+),(\d+),(\d+),(\d+)\]", ln.partition(" custom-call(")[2])
+                  for ln in calls if ln.startswith("%gmm")]
+    assert len(gmm_shapes) == 6 and all(gmm_shapes)
+    assert {tuple(int(x) for x in m.groups()) for m in gmm_shapes} == {(12, 16, 2688, 1920), (12, 16, 1920, 2688)}
+    update = [ln for ln in calls if ln.startswith("%llmd.ssm.update")]
+    assert len(update) == 3 and all("f32[12,257,64,64,128]" in ln.partition(" custom-call(")[2] for ln in update)
+    for scope in ("llmd.block.mamba", "llmd.block.moe", "llmd.block.attn"):
+        assert scope in text
+    m = compiled.memory_analysis()
+    pools = 4 * 33024 * 2 * 16 * 256 * 2 + 12 * 257 * (64 * 64 * 128 * 4 + 3 * 6144 * 2)
+    assert m.alias_size_in_bytes >= pools
+    assert m.temp_size_in_bytes < 0.5 * 2**30
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+    assert total < 15.75 * 2**30
+
+
+def test_a_one_group_models_step_keeps_its_pallas_calls(nemotron_step, v5e):
+    """granite's architecture in miniature, its flat step lowered for the
+    described chip: six Pallas calls, as before the mixer learned groups (the
+    state update, the scan's slot read and write, the KV write, flat attention
+    and the scan's second read), and the update's B and C the one-group
+    ``[rows, 1, N]`` operands."""
+    from llmd_tpu.config import CacheConfig, EngineConfig, SchedulerConfig
+    from llmd_tpu.models.registry import get_model_config
+
+    rc, _r = nemotron_step
+    config = EngineConfig(
+        model=get_model_config("tiny-granite-hybrid"),
+        cache=CacheConfig(page_size=16, num_blocks=256, dtype="bfloat16"),
+        scheduler=SchedulerConfig(max_num_seqs=4, max_num_batched_tokens=32),
+    )
+    r = rc.build_runner(config, v5e)
+    lowered, _none = rc.compile_step(r, r.flat_t_buckets[-1], compile=False)
+    text = lowered.as_text()
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == 6
+    update = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and "x4x8x16xf32>" in ln and ") -> (" in ln]
+    assert update and all("x1x16xf32>" in ln for ln in update)
