@@ -137,6 +137,29 @@ def _sparse_attention(T):
     )
 
 
+def _indexer(T):
+    """The indexer's scoring at the same geometry: one layer's plane of
+    24,576 pages of [16, 64] keys, 34 flat rows x 2,048 pages in SMEM, 16
+    index heads a token."""
+    from llmd_tpu.ops.sparse_attention import index_scores_pallas
+
+    J, Di = 16, 64
+    rows, max_pages = 34, 2048
+
+    def under_the_references_precision(*args):
+        # The benchmark's comparison calls the scoring inside the reference's
+        # ``default_matmul_precision("highest")``, which reaches the kernel's
+        # dot: the chip's compiler refuses that for bfloat16 operands
+        # ("Bad lhs type") unless the kernel states its own.
+        with jax.default_matmul_precision("highest"):
+            return index_scores_pallas(*args)
+
+    return under_the_references_precision, [
+        ((T, J, Di), BF16), ((T, J), BF16), ((24576, PAGE, Di), BF16),
+        ((rows, max_pages), I32), ((T,), I32), ((T,), I32),
+    ]
+
+
 def _decode_attention(model, dtype):
     _, H, _, D = model
     args = [
@@ -259,6 +282,7 @@ CASES = {
     "flat_attention-qwen3-30b-a3b-528": lambda d: _flat_attention(QWEN3, BF16, 528),
     "flat_attention-sinks": lambda d: _sink_attention(256),
     "sparse_attention-keye-vl-2.0-30b-a3b": lambda d: _sparse_attention(144),
+    "indexer-keye-vl-2.0-30b-a3b": lambda d: _indexer(144),
     "flat_attention-k-exaone-236b-a23b": lambda d: _flat_attention(EXAONE, BF16, 528),
     "window_attention-k-exaone-236b-a23b": lambda d: _window_attention(528),
     "flat_write-k-exaone-236b-a23b": lambda d: _flat_write(EXAONE, BF16, 528),

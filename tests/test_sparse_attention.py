@@ -154,10 +154,12 @@ def test_the_reference_holds_the_systems_selected_sets_to_its_own():
     assert np.all(np.isfinite(np.asarray(ref.forward(bound(), prompt + toks, PUBLISHED)[0])))
 
 
-def test_selected_sets_equal_the_references_exactly():
+@pytest.mark.parametrize("scoring", ["the XLA map", "the kernel"])
+def test_selected_sets_equal_the_references_exactly(scoring, monkeypatch):
     """Per layer: the reference's indexer queries scored against the ENGINE's
     cached plane of indexer keys (written chunk by chunk through the page
-    table) by the engine's ops select exactly the reference's sets."""
+    table) by the engine's ops select exactly the reference's sets, whichever
+    of the two scorings ``index_scores`` dispatches to."""
     eng = make_engine(max_batched=48)
     prompt = tokens(5 * TOPK + 3, seed=11)
     rid = eng.add_request(prompt, SamplingParams(max_tokens=64, temperature=0.0, ignore_eos=True))
@@ -185,7 +187,10 @@ def test_selected_sets_equal_the_references_exactly():
             # The engine cached what the reference computes, at the page table's slots.
             cached = np.asarray(pool.index[i])[table[0, np.arange(n) // page], np.arange(n) % page]
             np.testing.assert_allclose(cached, np.asarray(ki), atol=2e-5)
-            scores = sa.index_scores(qi, wi, pool.index[i], jnp.asarray(table), rows, kv_lens)
+            with monkeypatch.context() as m:
+                if scoring == "the kernel":
+                    m.setenv("LLMD_PALLAS", "interpret")
+                scores = sa.index_scores(qi, wi, pool.index[i], jnp.asarray(table), rows, kv_lens)
             mine = np.asarray(sa.select_topk(scores, TOPK))[:, :n] & np.tril(np.ones((n, n), bool))
             assert np.array_equal(mine, np.asarray(layer["selected"])[:n, :n]), f"layer {i}"
             assert mine.sum(1).tolist() == [min(t + 1, TOPK) for t in range(n)]
@@ -231,6 +236,129 @@ def test_a_shared_tile_keeps_each_tokens_own_selection():
     np.testing.assert_allclose(out[live], np.asarray(oracle)[live], atol=2e-5)
     np.testing.assert_allclose(out[live], run(*token_by_token(tok_rows, pt))[live], atol=1e-5)
     assert np.abs(out - np.asarray(dense))[live].max() > 1e-2  # the mask binds
+
+
+def _decode_rows(rng, page, num_pages):
+    """Sixteen sequences, one token each: no tile shares a row."""
+    lens = rng.integers(1, 16 * page, size=16)
+    pt = rng.permutation(num_pages)[: 16 * 16].reshape(16, 16).astype(np.int32)
+    return np.arange(16, dtype=np.int32), lens.astype(np.int32), pt
+
+
+def _edges(rng, page, num_pages):
+    """Contexts below one page, at a page's and a block's edge (blocks of two
+    pages), one past it and ending mid-block, as decode rows and as a chunk
+    whose last token sits on the block's edge; then a tile of pad tokens."""
+    S = 2 * page
+    lens = [1, page - 1, page, page + 1, S - 1, S, S + 1, 3 * S, 3 * S + page // 2,
+            5 * S - 3, 7 * page, 8 * S, 1, 2, 3, 4]
+    rows = list(range(16)) + [16] * 16 + [0] * 16
+    kv_lens = lens + list(range(4 * S - 15, 4 * S + 1)) + [0] * 16
+    pt = rng.permutation(num_pages)[: 17 * 16].reshape(17, 16).astype(np.int32)
+    return np.asarray(rows, np.int32), np.asarray(kv_lens, np.int32), pt
+
+
+def _every_kind_of_tile(rng, page, num_pages):
+    """``tests/flat_streams.py``: a chunk whose body fills whole tiles with a
+    ragged head and tail, two rows meeting inside a tile, decode rows, a
+    verify row and pad tokens."""
+    tok_rows, positions, live, pt = tiled_stream(rng, page, num_pages)
+    return tok_rows, np.where(live, positions + 1, 0).astype(np.int32), pt
+
+
+def _the_benchmarks_call(rng, page, num_pages, n=203):
+    """``perfbench/topologies/engine_longctx.py::System.selection``: a
+    ONE-layer plane, a one-row table over the sequence's pages, T a multiple
+    of 256, ``kv_lens`` 1..n and n from there on."""
+    T = -(-n // 256) * 256
+    pt = np.minimum(np.arange(-(-T // page)), -(-n // page) - 1)[None, :].astype(np.int32)
+    return np.zeros(T, np.int32), np.minimum(np.arange(1, T + 1), n).astype(np.int32), pt
+
+
+INDEXER_STREAMS = {
+    "decode rows only": (_decode_rows, 64, np.float32),
+    "every kind of tile": (_every_kind_of_tile, 64, np.float32),
+    "contexts at the edges of pages and blocks, and pad tiles": (_edges, 64, np.float32),
+    "the benchmark's call: a one-row table, T a multiple of 256": (_the_benchmarks_call, 64, np.float32),
+    "keys a lane tile wide: the plane goes in as it is": (_every_kind_of_tile, 128, np.float32),
+    "the served dtype: bfloat16 queries, weights and keys": (_every_kind_of_tile, 64, jnp.bfloat16),
+    "the benchmark's call in bfloat16": (_the_benchmarks_call, 64, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("stream", sorted(INDEXER_STREAMS))
+def test_the_indexer_kernel_scores_what_the_xla_map_scores(stream):
+    """The Pallas indexer (interpreted) against the XLA map it replaces on the
+    chip: scores equal to float32 tolerance, -inf exactly at and past each
+    token's ``kv_lens``, and the same exact top-k out of both."""
+    make, Di, dtype = INDEXER_STREAMS[stream]
+    rng = np.random.default_rng(7)
+    P, page, J, topk = 288, 8, 4, 24
+    rows, kv_lens, pt = make(rng, page, P)
+    T, S = len(rows), pt.shape[1] * page
+    plane = jnp.asarray(rng.normal(size=(P, page, Di)), dtype)
+    iq = jnp.asarray(rng.normal(size=(T, J, Di)), dtype)
+    iw = jnp.asarray(rng.normal(size=(T, J)), dtype)
+    args = (jnp.asarray(pt), jnp.asarray(rows), jnp.asarray(kv_lens))
+    want = sa._index_scores_xla(iq, iw, plane, *args)
+    got = sa.index_scores_pallas(iq, iw, plane, *args, interpret=True, pages_per_block=2)
+    want, got = np.asarray(want), np.asarray(got)
+    assert got.shape == (T, S) and got.dtype == np.float32
+    dead = np.arange(S)[None, :] >= kv_lens[:, None]
+    assert np.array_equal(np.isneginf(got), dead) and np.array_equal(np.isneginf(want), dead)
+    np.testing.assert_allclose(got[~dead], want[~dead], rtol=1e-5, atol=1e-4)
+    assert np.array_equal(np.asarray(sa.select_topk(jnp.asarray(got), topk)),
+                          np.asarray(sa.select_topk(jnp.asarray(want), topk)))
+
+
+def test_the_weights_reach_the_kernel_in_the_dtype_the_caller_rounded_them_to():
+    """PR 43's fault on the chip: the benchmark's call rounds float32 weights
+    to the served dtype in its own jit (``iw[:rows].astype(bfloat16)``), the
+    call widened them again OUTSIDE the kernel, and XLA fused the pair into a
+    slice that never rounded: the kernel scored with float32 weights and the
+    exact-top-k overlap read 0.9996 for the XLA map's 0.9999997 (PERF.md
+    section 6). No compiler on the CPU folds the pair, so the cause is held
+    here: every operand of the ``pallas_call`` that carries queries, weights
+    or keys has the dtype the caller gave, and nothing float32 goes in."""
+    rng = np.random.default_rng(5)
+    rows, kv_lens, pt = _the_benchmarks_call(rng, 8, 96)
+    T = len(rows)
+    shapes = [(T, 4, 64), (T, 4), (96, 8, 64)]
+    iq, iw, plane = (jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in shapes)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: sa.index_scores_pallas(*a, jnp.asarray(pt), jnp.asarray(rows), jnp.asarray(kv_lens))
+    )(iq, iw, plane)
+    (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    floating = [v.aval.dtype for v in call.invars if jnp.issubdtype(v.aval.dtype, jnp.floating)]
+    assert len(floating) == 5 and all(d == jnp.bfloat16 for d in floating), floating
+
+
+def test_index_scores_takes_the_kernel_where_the_dispatch_does(monkeypatch):
+    """``LLMD_PALLAS=interpret`` stands in for the chip: the call the
+    benchmark and the step make takes the kernel (recorded as the plan
+    ``indexer``); off the chip it takes the XLA map."""
+    from llmd_tpu import ops
+
+    rng = np.random.default_rng(3)
+    rows, kv_lens, pt = _every_kind_of_tile(rng, 8, 96)
+    plane = jnp.asarray(rng.normal(size=(96, 8, 64)).astype(np.float32))
+    iq = jnp.asarray(rng.normal(size=(len(rows), 4, 64)).astype(np.float32))
+    iw = jnp.asarray(rng.normal(size=(len(rows), 4)).astype(np.float32))
+    args = (jnp.asarray(pt), jnp.asarray(rows), jnp.asarray(kv_lens))
+
+    def scored():
+        plans: dict = {}
+        with ops.record_plans(plans):
+            one = sa.index_scores(iq, iw, plane, *args)
+        return plans["indexer"], np.asarray(one)
+
+    plan_x, one_x = scored()
+    monkeypatch.setenv("LLMD_PALLAS", "interpret")
+    plan_k, one_k = scored()
+    assert plan_x == {"xla:platform"} and plan_k == {"pallas"}
+    finite = np.isfinite(one_x)
+    assert np.array_equal(finite, np.isfinite(one_k))
+    np.testing.assert_allclose(one_k[finite], one_x[finite], rtol=1e-5, atol=1e-4)
 
 
 def test_select_topk_is_exact_and_breaks_ties_to_the_lower_position():
