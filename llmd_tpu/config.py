@@ -179,6 +179,33 @@ class ModelConfig:
     # next block is a mixer again: the FFN leaves (post-norm, router,
     # experts) are stacked over the layers that have one.
     layer_ffn: tuple | None = None
+    # --- gated delta-rule mixers (HF ``qwen3_next``'s Gated DeltaNet; docs/
+    # architecture/kv-cache.md, "The state pool") ---
+    # ``layer_types`` entries "linear_attention" run a gated delta-rule mixer
+    # in place of attention: ``linear_num_value_heads`` heads, each a state
+    # [``linear_key_head_dim``, ``linear_value_head_dim``] in float32 that is
+    # READ before it is written (S = a S + b k (v - (a S)^T k)^T), fed by
+    # ``linear_num_key_heads`` q and k heads (each serving value heads / key
+    # heads consecutive value heads), a causal depthwise conv of
+    # ``linear_conv_kernel_dim`` taps over q, k and v in front. Such a layer
+    # keeps its state in the state pool as the Mamba-2 mixers do; a model has
+    # one kind of recurrent mixer.
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    # The attention layers of the same family: the share of a head's
+    # dimensions that RoPE rotates (the first ones, rotate-half among
+    # themselves); a q projection of twice the width whose second half per
+    # head gates the attention's output through a sigmoid; RMS norms whose
+    # weight is stored zero-centred (applied as 1 + w, the block's, the final
+    # and the q/k norms; a mixer's own gated norm keeps a plain weight); the
+    # shared expert's output scaled by sigmoid(x . w), one number a token.
+    partial_rotary_factor: float = 1.0
+    attn_output_gate: bool = False
+    norm_zero_centered: bool = False
+    shared_expert_gate: bool = False
 
     def __post_init__(self) -> None:
         if self.quantization not in (None, "int8"):
@@ -231,12 +258,26 @@ class ModelConfig:
             if self.layer_types is None:
                 raise ValueError("rope_layer_types needs layer_types")
         if self.state_space:
-            if min(self.mamba_n_heads, self.mamba_d_head, self.mamba_d_state) <= 0:
+            if self.delta_rule and "mamba" in self.layer_types:
+                raise ValueError(
+                    "layer_types mixes \"mamba\" and \"linear_attention\": the "
+                    "state pool holds one kind of recurrent state"
+                )
+            if self.delta_rule:
+                hk, hv = self.linear_num_key_heads, self.linear_num_value_heads
+                if min(hk, hv, self.linear_key_head_dim,
+                       self.linear_value_head_dim) <= 0 or hv % hk:
+                    raise ValueError(
+                        "linear_attention layers need linear_num_key_heads, "
+                        "linear_num_value_heads (a multiple of the key heads), "
+                        "linear_key_head_dim and linear_value_head_dim"
+                    )
+            elif min(self.mamba_n_heads, self.mamba_d_head, self.mamba_d_state) <= 0:
                 raise ValueError(
                     "mamba layers need mamba_n_heads, mamba_d_head and "
                     "mamba_d_state"
                 )
-            if self.mamba_n_groups < 1 or self.mamba_n_heads % self.mamba_n_groups:
+            elif self.mamba_n_groups < 1 or self.mamba_n_heads % self.mamba_n_groups:
                 raise ValueError(
                     f"mamba_n_groups={self.mamba_n_groups} does not divide "
                     f"mamba_n_heads={self.mamba_n_heads}: a group's B and C "
@@ -253,6 +294,26 @@ class ModelConfig:
                     raise ValueError(
                         f"state-space layers are not supported with {what}"
                     )
+        if self.partial_rotary_factor != 1.0 and not (
+            0.0 < self.partial_rotary_factor < 1.0
+            and self.rotary_dim > 0 and self.rotary_dim % 2 == 0
+        ):
+            raise ValueError(
+                f"partial_rotary_factor={self.partial_rotary_factor} of "
+                f"head_dim={self.head_dim} is no even number of rotated "
+                "dimensions"
+            )
+        if (self.attn_output_gate or self.rotary_dim < self.head_dim) and (
+            self.kv_lora_rank > 0 or self.num_lora_adapters > 0
+            or self.indexer_topk > 0
+        ):
+            raise ValueError(
+                "attn_output_gate / partial_rotary_factor are not supported "
+                "with MLA, LoRA adapters or learned sparse attention: those "
+                "paths would rotate the whole head or drop the gate"
+            )
+        if self.shared_expert_gate and not self.shared_expert_intermediate_size:
+            raise ValueError("shared_expert_gate needs a shared expert")
         if self.sliding_window > 0 and self.kv_lora_rank > 0:
             raise ValueError(
                 "sliding_window is not supported with MLA (no known MLA "
@@ -321,14 +382,28 @@ class ModelConfig:
 
     @property
     def state_space(self) -> bool:
-        """Some layers are state-space mixers (``layer_types`` "mamba")."""
-        return self.layer_types is not None and "mamba" in self.layer_types
+        """Some layers are recurrent mixers with a fixed-size state a
+        sequence (``layer_types`` "mamba" or "linear_attention")."""
+        return self.delta_rule or (
+            self.layer_types is not None and "mamba" in self.layer_types
+        )
+
+    @property
+    def delta_rule(self) -> bool:
+        """The recurrent mixers are gated delta-rule ones (``layer_types``
+        "linear_attention"), not Mamba-2."""
+        return self.layer_types is not None and "linear_attention" in self.layer_types
 
     @property
     def mamba_layers(self) -> tuple[int, ...]:
+        """The layers whose state lies in the state pool (of either kind of
+        recurrent mixer; the name is the first kind's)."""
         if not self.state_space:
             return ()
-        return tuple(i for i, t in enumerate(self.layer_types) if t == "mamba")
+        return tuple(
+            i for i, t in enumerate(self.layer_types)
+            if t in ("mamba", "linear_attention")
+        )
 
     @property
     def attention_layers(self) -> tuple[int, ...]:
@@ -336,6 +411,34 @@ class ModelConfig:
         state-space mixers)."""
         m = set(self.mamba_layers)
         return tuple(i for i in range(self.num_layers) if i not in m)
+
+    @property
+    def state_shapes(self) -> tuple[tuple[int, ...], tuple[int, ...]]:
+        """A state-pool slot of ONE recurrent layer: (the recurrent state's
+        shape, float32; the conv state's, the model's dtype)."""
+        if self.delta_rule:
+            return (
+                (self.linear_num_value_heads, self.linear_key_head_dim,
+                 self.linear_value_head_dim),
+                (self.linear_conv_kernel_dim - 1, self.linear_conv_dim),
+            )
+        return (
+            (self.mamba_n_heads, self.mamba_d_head, self.mamba_d_state),
+            (self.mamba_d_conv - 1, self.mamba_conv_dim),
+        )
+
+    @property
+    def linear_conv_dim(self) -> int:
+        """Channels under the delta-rule mixer's conv: q, k and v."""
+        return (
+            2 * self.linear_num_key_heads * self.linear_key_head_dim
+            + self.linear_num_value_heads * self.linear_value_head_dim
+        )
+
+    @property
+    def rotary_dim(self) -> int:
+        """The leading dimensions of a head that RoPE rotates."""
+        return int(self.head_dim * self.partial_rotary_factor)
 
     @property
     def ffn_layers(self) -> tuple[int, ...]:

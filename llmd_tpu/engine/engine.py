@@ -279,6 +279,13 @@ class EngineStats:
     # scanned, each x the mixer layers (what the rooflines divide by).
     ssm_update_rows_total: int = 0
     ssm_scan_tokens_total: int = 0
+    # The same where the recurrent mixers are gated delta-rule ones: decode
+    # rows, the scan's rows (chunks) and tokens, each x the mixer layers,
+    # and the bytes of state those rows read and wrote.
+    gdn_update_rows_total: int = 0
+    gdn_scan_rows_total: int = 0
+    gdn_scan_tokens_total: int = 0
+    gdn_state_bytes_moved_total: int = 0
     # counters
     prompt_tokens: int = 0
     generation_tokens: int = 0
@@ -2444,6 +2451,16 @@ class LLMEngine:
             st.state_snapshot_evictions_total = s.get("evictions", 0)
             st.ssm_update_rows_total = self.runner.ssm_update_rows_total
             st.ssm_scan_tokens_total = self.runner.ssm_scan_tokens_total
+            r = self.runner
+            st.gdn_update_rows_total = r.gdn_update_rows_total
+            st.gdn_scan_rows_total = r.gdn_scan_rows_total
+            st.gdn_scan_tokens_total = r.gdn_scan_tokens_total
+            # A row moves its slot's state of a layer once in and once out.
+            plane = r.kv_swa.ssm
+            st.gdn_state_bytes_moved_total = (
+                2 * (plane.nbytes // (plane.shape[0] * plane.shape[1]))
+                * (r.gdn_update_rows_total + r.gdn_scan_rows_total)
+            )
         elif self.swa_allocator is not None:
             self.stats.swa_ring_usage = self.swa_allocator.usage()
             self.stats.swa_ring_pages = self.swa_allocator.num_pages

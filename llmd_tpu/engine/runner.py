@@ -574,6 +574,12 @@ class ModelRunner:
         # rows updated and prefill tokens scanned, x the mixer layers.
         self.ssm_update_rows_total = 0
         self.ssm_scan_tokens_total = 0
+        # The same of gated delta-rule mixers (ops/gdn.py), which count under
+        # names of their own: decode rows updated, prefill ROWS (the scan's
+        # chunks) and tokens scanned, each x the mixer layers.
+        self.gdn_update_rows_total = 0
+        self.gdn_scan_rows_total = 0
+        self.gdn_scan_tokens_total = 0
 
     @property
     def state_pool(self) -> bool:
@@ -834,15 +840,10 @@ class ModelRunner:
             m, rep = self.cfg, self.ctx.replicated
             # One slot past the allocator's: the scan's scratch (ops/ssm.py).
             Lm, S = len(self.swa.state_layers), self.swa.num_swa_blocks + 1
+            state, conv = m.state_shapes
             return ssm_ops.StatePool(
-                ssm=jnp.zeros(
-                    (Lm, S, m.mamba_n_heads, m.mamba_d_head, m.mamba_d_state),
-                    jnp.float32, device=rep,
-                ),
-                conv=jnp.zeros(
-                    (Lm, S, m.mamba_d_conv - 1, m.mamba_conv_dim),
-                    jnp.dtype(m.dtype), device=rep,
-                ),
+                ssm=jnp.zeros((Lm, S, *state), jnp.float32, device=rep),
+                conv=jnp.zeros((Lm, S, *conv), jnp.dtype(m.dtype), device=rep),
             )
         return self._alloc_pool(len(self.swa.swa_layers), self.swa.num_swa_blocks)
 
@@ -3227,8 +3228,13 @@ class ModelRunner:
         if self.state_pool:
             Lm = len(self.swa.state_layers)
             n_dec = len(staged.row_seqs) - n_pre_rows
-            self.ssm_update_rows_total += n_dec * Lm
-            self.ssm_scan_tokens_total += (t - n_dec) * Lm
+            if self.cfg.delta_rule:
+                self.gdn_update_rows_total += n_dec * Lm
+                self.gdn_scan_rows_total += n_pre_rows * Lm
+                self.gdn_scan_tokens_total += (t - n_dec) * Lm
+            else:
+                self.ssm_update_rows_total += n_dec * Lm
+                self.ssm_scan_tokens_total += (t - n_dec) * Lm
         if staged.flat:
             # Pad rows carry row_start = total so the cu_q_lens boundary
             # array the device searchsorts stays monotonic.
@@ -3240,13 +3246,17 @@ class ModelRunner:
 
     def _fill_flat_runs(self, staged: StagedUnified, a: dict) -> None:
         """Host half of the flat KV-write plan: walk each row's token
-        span page by page and emit one run per (row, physical page) —
-        maximal spans of consecutive stream tokens landing in one page,
-        so runs target distinct pages (the Pallas write pipeline's
-        precondition). ``src`` is pre-shifted (page + t0 - off) so the
-        kernel's fixed-size slab DMA lands token t0+j at page row off+j.
-        The run width derives from (B, T, page) on both lockstep sides;
-        see the _OP_FLAT payload spec for the bound's derivation.
+        span page by page and emit one run per maximal span of
+        consecutive stream tokens landing in one page, so runs target
+        distinct pages (the Pallas write pipeline's precondition: it
+        loads run r+1's page before run r's is stored). A chunk's
+        sub-rows are consecutive in the stream, so where one ends inside
+        a page the next goes on in the SAME run (two runs there would
+        lose the first one's rows). ``src`` is pre-shifted (page + t0 -
+        off) so the kernel's fixed-size slab DMA lands token t0+j at
+        page row off+j. The run width derives from (B, T, page) on both
+        lockstep sides; see the _OP_FLAT payload spec for the bound's
+        derivation.
         """
         page = self.page
         rn = 2 * staged.B + -(-staged.T // page)
@@ -3267,7 +3277,15 @@ class ModelRunner:
                 p = p0 + consumed
                 pg, o = p // page, p % page
                 take = min(page - o, w - consumed)
-                wsrc[i] = page + t0 + consumed - o
+                src = page + t0 + consumed - o
+                if (
+                    i and o and wphys[i - 1] == pt[r, pg]
+                    and wsrc[i - 1] == src and woff[i - 1] + wcnt[i - 1] == o
+                ):
+                    wcnt[i - 1] += take
+                    consumed += take
+                    continue
+                wsrc[i] = src
                 woff[i] = o
                 wcnt[i] = take
                 wphys[i] = pt[r, pg]

@@ -80,10 +80,18 @@ class MixerKind(NamedTuple):
     mix: Callable | None = None
 
 
-def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+def rms_norm(
+    x: jax.Array, weight: jax.Array, eps: float, zero_centered: bool = False
+) -> jax.Array:
+    """``zero_centered`` (qwen3_next's block, final and q/k norms): the
+    weight is stored around 0 and applied as 1 + w, in float32 before the
+    cast back (HF ``Qwen3NextRMSNorm``)."""
     dtype = x.dtype
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    if zero_centered:
+        w = 1.0 + weight.astype(jnp.float32)
+        return (xf * jax.lax.rsqrt(var + eps) * w).astype(dtype)
     return (xf * jax.lax.rsqrt(var + eps)).astype(dtype) * weight
 
 
@@ -208,8 +216,14 @@ def rope_tables(
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """Rotate [B, Q, N, D] with tables [B, Q, half] (HF 'split-half' layout)."""
-    half = x.shape[-1] // 2
+    """Rotate [B, Q, N, D] with tables [B, Q, half] (HF 'split-half' layout).
+    Tables of fewer than D / 2 frequencies rotate the first ``2 * half``
+    dimensions among themselves and pass the rest (a partial rotation)."""
+    half = cos.shape[-1]
+    if 2 * half < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., : 2 * half], cos, sin), x[..., 2 * half :]], axis=-1
+        )
     x1, x2 = x[..., :half], x[..., half:]
     c = cos[..., None, :]  # broadcast over heads
     s = sin[..., None, :]

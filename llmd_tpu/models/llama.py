@@ -55,6 +55,13 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
             scale = shape[-2] ** -0.5 if len(shape) >= 2 else 1.0
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dt)
 
+    def norm_w(name: str, shape: tuple[int, ...]) -> jax.Array:
+        """An RMS norm's weight: ones, or seeded near 0 where the model
+        stores it zero-centred (applied as 1 + w)."""
+        if cfg.norm_zero_centered:
+            return mk(name, shape, scale=0.02)
+        return jnp.ones(shape, dt)
+
     def mixer_weights(n: int, mkp) -> dict[str, jax.Array]:
         """n stacked attention mixers (MLA or GQA, with the model's extras)."""
         layers: dict[str, jax.Array] = {}
@@ -76,7 +83,9 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
             else:
                 layers["wq"] = mkp("wq", (n, H, Nq * (nope + rope)))
         else:
-            layers["wq"] = mkp("wq", (n, H, Nq * D))
+            # ``attn_output_gate``: per head (q[D], gate[D]), as published.
+            qw = 2 * D if cfg.attn_output_gate else D
+            layers["wq"] = mkp("wq", (n, H, Nq * qw))
             layers["wk"] = mkp("wk", (n, H, K * D))
             layers["wv"] = mkp("wv", (n, H, K * D))
             layers["wo"] = mkp("wo", (n, Nq * D, H))
@@ -89,8 +98,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
         if cfg.attention_sinks:
             layers["sinks"] = mk("sinks", (n, Nq), scale=1.0)
         if cfg.qk_norm:
-            layers["attn_q_norm"] = jnp.ones((n, D), dt)
-            layers["attn_k_norm"] = jnp.ones((n, D), dt)
+            layers["attn_q_norm"] = norm_w("attn_q_norm", (n, D))
+            layers["attn_k_norm"] = norm_w("attn_k_norm", (n, D))
         if cfg.sparse_attention:
             # The indexer: J query heads and per-head score weights from
             # the layer's normed input, ONE shared key per token.
@@ -127,12 +136,12 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
             return mk(prefix + name, shape, scale)
 
         layers: dict[str, jax.Array] = {
-            "input_norm": jnp.ones((n, H), dt),
+            "input_norm": norm_w(prefix + "input_norm", (n, H)),
         }
         if attention:
             layers.update(mixer_weights(n, mkp))
         n = n if n_ffn is None else n_ffn
-        layers["post_norm"] = jnp.ones((n, H), dt)
+        layers["post_norm"] = norm_w(prefix + "post_norm", (n, H))
         if moe:
             # The router scores every expert; the leaves hold the experts
             # this rank holds (all of them unless cfg says otherwise).
@@ -172,6 +181,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
                     layers["ws_gate"] = mkp("ws_gate", (n, H, Fs))
                 layers["ws_up"] = mkp("ws_up", (n, H, Fs))
                 layers["ws_down"] = mkp("ws_down", (n, Fs, H))
+                if cfg.shared_expert_gate:
+                    layers["ws_sig"] = mkp("ws_sig", (n, H, 1))
         else:
             layers["w_gate"] = mkp("w_gate", (n, H, F))
             layers["w_up"] = mkp("w_up", (n, H, F))
@@ -185,7 +196,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
             L - n_dense, moe=cfg.is_moe, attention=not cfg.state_space,
             n_ffn=None if cfg.layer_ffn is None else len(cfg.ffn_layers),
         ),
-        "final_norm": jnp.ones((H,), dt),
+        "final_norm": norm_w("final_norm", (H,)),
     }
     kinds = mixer_kinds(cfg)
     for kind in dict.fromkeys(k for k in kinds if k is not None):
@@ -228,9 +239,14 @@ def mixer_kinds(cfg: ModelConfig) -> tuple[MixerKind | None, ...]:
     (attention, whose weights lie in the shared stack)."""
     if not cfg.state_space:
         return (None,) * cfg.num_layers
-    from llmd_tpu.models import mamba
+    if cfg.delta_rule:
+        from llmd_tpu.models import gdn
 
-    by_type = {"mamba": mamba.KIND, "attention": ATTENTION}
+        by_type = {"linear_attention": gdn.KIND, "full_attention": ATTENTION}
+    else:
+        from llmd_tpu.models import mamba
+
+        by_type = {"mamba": mamba.KIND, "attention": ATTENTION}
     return tuple(by_type[t] for t in cfg.layer_types)
 
 
@@ -358,7 +374,7 @@ def forward_hidden(
         x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     # one rope table for all layers (hoisted out of the scan); MLA rotates
     # only its rope sub-dim
-    rope_dim = cfg.qk_rope_head_dim if cfg.is_mla else D
+    rope_dim = cfg.qk_rope_head_dim if cfg.is_mla else cfg.rotary_dim
     cos, sin = rope_tables(inp.positions, rope_dim, cfg.rope_theta, cfg.rope_scaling)
     valid = inp.valid
     sm_scale = cfg.sm_scale
@@ -367,6 +383,9 @@ def forward_hidden(
     def _res(y):
         """A residual branch under the model's multiplier (1 for most)."""
         return y if res_mult == 1.0 else y * res_mult
+
+    def norm(x, w):
+        return rms_norm(x, w, cfg.rms_norm_eps, cfg.norm_zero_centered)
 
     # DBO also requires the HALF batch to stay dp-divisible, or the split
     # would silently demote attention from the sharded Pallas kernel to
@@ -453,7 +472,7 @@ def forward_hidden(
         """Post-attention chain of one (micro)batch slice: residual +
         post-norm + FFN/MoE + residual. Returns (x, census_delta)."""
         x_sl = x_sl + _res(attn_sl)
-        h2 = rms_norm(x_sl, lp["post_norm"], cfg.rms_norm_eps)
+        h2 = norm(x_sl, lp["post_norm"])
         y, cd = _ffn(h2, lp, use_moe, cap_scale, moe_layer)
         return x_sl + _res(y), cd
 
@@ -483,7 +502,7 @@ def forward_hidden(
         cache ``cache`` is."""
         if table is None:
             table = inp.page_table
-        h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
+        h = norm(x, lp["input_norm"])
         if kind is not None and kind.mix is not None:
             out, cache = kind.mix(h, lp, cache, layer_idx, inp, cfg, mesh)
             x = x + _res(out)
@@ -540,11 +559,17 @@ def forward_hidden(
                 v = v + jnp.einsum(
                     "bqr,brd->bqd", jnp.einsum("bqh,bhr->bqr", h, la_v), lb_v
                 )
+            out_gate = None
+            if cfg.attn_output_gate:
+                # A head's projection is (q[D], gate[D]); the gate meets the
+                # attention's output below.
+                q = q.reshape(B, Q, Nq, 2 * D)
+                q, out_gate = q[..., :D], q[..., D:]
             q = q.reshape(B, Q, Nq, D)
             k = k.reshape(B, Q, K, D)
             if cfg.qk_norm:  # Qwen3: per-head RMS norm before RoPE
-                q = rms_norm(q, lp["attn_q_norm"], cfg.rms_norm_eps)
-                k = rms_norm(k, lp["attn_k_norm"], cfg.rms_norm_eps)
+                q = norm(q, lp["attn_q_norm"])
+                k = norm(k, lp["attn_k_norm"])
             if rotate is None:
                 cos_l, sin_l = cos, sin
             elif rotate is not False:  # the identity where the layer has none
@@ -599,6 +624,11 @@ def forward_hidden(
                 return out
 
             if use_dbo:
+                if out_gate is not None:
+                    raise NotImplementedError(
+                        f"{cfg.name}: dual-batch overlap would drop the "
+                        "attention's output gate"
+                    )
                 outs = []
                 for sl in (slice(0, half), slice(half, B)):
                     attn_sl = paged_attention_full(
@@ -646,6 +676,8 @@ def forward_hidden(
                     sm_scale, world_size=world_size, mesh=mesh, window=window,
                     sinks=sinks,
                 )
+            if out_gate is not None:
+                attn = attn.reshape(B, Q, Nq, D) * jax.nn.sigmoid(out_gate)
             x = x + _res(_project(attn, B))
         if not ffn:
             return x, cache, None
@@ -888,7 +920,8 @@ def forward_hidden(
                         **layer_leaves(lid, fid, has_ffn[j]),
                         **kind_leaves(kind, plane_c[j]), **experts,
                     }
-                    name = "mamba" if kind.mix is not None else "attn"
+                    # A block's scope is its kind's: "mamba", "gdn", "attn".
+                    name = kind.stack.removesuffix("_layers")
                     with jax.named_scope(f"llmd.block.{name}"):
                         x, cc[g], _ = layer_body(
                             x, cc[g], lp_s, plane_c[j], use_moe=cfg.is_moe,
@@ -939,7 +972,7 @@ def forward_hidden(
             )
             off += ln
 
-    hidden = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    hidden = norm(x, params["final_norm"])
     out = (hidden, caches[0]) if kv_swa is None else (
         hidden, caches[0], caches[1]
     )

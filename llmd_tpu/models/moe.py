@@ -91,13 +91,19 @@ def router_topk(
 def shared_expert_ffn(ht: jax.Array, lp: dict) -> jax.Array:
     """DeepSeek/Qwen2-MoE always-on shared expert (one place, three
     backends: dense / grouped / EP). Without a ``ws_gate`` leaf it is the
-    non-gated one (``moe_activation`` "relu2"): down(relu(up x)^2)."""
+    non-gated one (``moe_activation`` "relu2"): down(relu(up x)^2); with a
+    ``ws_sig`` leaf its output is scaled by sigmoid(x . ws_sig)."""
     from llmd_tpu.models.common import pdot
 
     if "ws_gate" not in lp:
         return pdot(relu2(pdot(ht, lp, "ws_up")), lp, "ws_down")
     g = jax.nn.silu(pdot(ht, lp, "ws_gate"))
-    return pdot(g * pdot(ht, lp, "ws_up"), lp, "ws_down")
+    out = pdot(g * pdot(ht, lp, "ws_up"), lp, "ws_down")
+    if "ws_sig" in lp:
+        # qwen3_next (``shared_expert_gate``): the shared expert's output
+        # under a learned gate, one number a token.
+        out = jax.nn.sigmoid(ht @ lp["ws_sig"]) * out
+    return out
 
 
 def _expert_scales(lp: dict) -> tuple | None:

@@ -271,6 +271,58 @@ def _tiny_granite_hybrid() -> ModelConfig:
     )
 
 
+# qwen3_next: three Gated DeltaNet layers, then one of full attention
+# (``full_attention_interval`` 4).
+_LLLF = ("linear_attention",) * 3 + ("full_attention",)
+
+
+@register_model("qwen3-next-80b-a3b")
+def _qwen3_next_80b_a3b() -> ModelConfig:
+    """Qwen3-Next-80B-A3B-Instruct (HF Qwen/Qwen3-Next-80B-A3B-Instruct,
+    ``qwen3_next``): 48 layers ``L L L F``: 36 Gated DeltaNet mixers (16 key
+    heads x 128, 32 value heads x 128, conv 4) and 12 gated attention layers
+    (GQA 16/2 x 256, the q projection twice as wide and its second half a
+    sigmoid gate on the output, zero-centred QK-norm, RoPE over the first 64
+    of 256 dimensions at theta 1e7); every layer's FFN 512 experts top-10 of
+    width 512 plus a shared expert of 512 under its own sigmoid gate;
+    zero-centred RMS norms; untied vocabulary. (Its MTP module is not served.)"""
+    return ModelConfig(
+        name="qwen3-next-80b-a3b", vocab_size=151936, hidden_size=2048,
+        intermediate_size=5120, num_layers=48, num_heads=16, num_kv_heads=2,
+        head_dim=256, rope_theta=1e7, max_model_len=262144,
+        rms_norm_eps=1e-6, qk_norm=True, layer_types=_LLLF * 12,
+        partial_rotary_factor=0.25, attn_output_gate=True,
+        norm_zero_centered=True,
+        linear_num_key_heads=16, linear_num_value_heads=32,
+        linear_key_head_dim=128, linear_value_head_dim=128,
+        linear_conv_kernel_dim=4,
+        num_experts=512, num_experts_per_tok=10, moe_intermediate_size=512,
+        shared_expert_intermediate_size=512, shared_expert_gate=True,
+        norm_topk_prob=True,
+    )
+
+
+@register_model("tiny-qwen3-next")
+def _tiny_qwen3_next() -> ModelConfig:
+    """qwen3-next's architecture in miniature (CPU tests and the benchmark's
+    rehearsal): two whole periods ``L L L F``, 2 key heads x 8 serving 4 value
+    heads x 8, gated GQA 4/2 x 16 with a quarter-width rotation and
+    zero-centred norms, top-2 of 8 experts with a gated shared one, an untied
+    vocabulary, and a held share: experts 0-3 of the 8 the router scores."""
+    return tiny_model_config(
+        name="tiny-qwen3-next", num_layers=8, max_model_len=512,
+        rms_norm_eps=1e-6, qk_norm=True, layer_types=_LLLF * 2,
+        partial_rotary_factor=0.25, attn_output_gate=True,
+        norm_zero_centered=True,
+        linear_num_key_heads=2, linear_num_value_heads=4,
+        linear_key_head_dim=8, linear_value_head_dim=8,
+        linear_conv_kernel_dim=4,
+        num_experts=8, num_experts_per_tok=2, moe_intermediate_size=32,
+        shared_expert_intermediate_size=32, shared_expert_gate=True,
+        norm_topk_prob=True, held_experts=4, held_experts_first=0,
+    )
+
+
 def nemotron_h_layers(pattern: str) -> tuple[tuple[str, ...], tuple[bool, ...]]:
     """``(layer_types, layer_ffn)`` of a ``nemotron_h``
     ``hybrid_override_pattern``: its blocks are ONE mixer each (``M`` a

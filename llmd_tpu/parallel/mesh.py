@@ -121,6 +121,14 @@ PARAM_SPECS: dict[str, P] = {
     "m_D": P(None, None),
     "m_norm": P(None, None),          # [Lm, d_in]
     "m_out": P(None, None, None),     # [Lm, d_in, H]
+    # Gated delta-rule mixers (models/gdn.py; one device only; replicated).
+    "g_in": P(None, None, None),      # [Lg, H, q | k | v | z]
+    "g_ba": P(None, None, None),      # [Lg, H, b | a]
+    "g_conv_w": P(None, None, None),  # [Lg, K, C]
+    "g_A_log": P(None, None),         # [Lg, value heads]
+    "g_dt_bias": P(None, None),
+    "g_norm": P(None, None),          # [Lg, value head dim]
+    "g_out": P(None, None, None),     # [Lg, value dim, H]
     # LoRA: down-projections replicated (rank is tiny), up-projections
     # head-sharded like their base weights.
     "la_q": P(None, None, None, None),       # [L, A+1, H, r]
@@ -139,6 +147,7 @@ PARAM_SPECS: dict[str, P] = {
     "we_gate_b": P(None, EP_AXES, None),      # gpt-oss expert biases
     "we_up_b": P(None, EP_AXES, None),
     "we_down_b": P(None, EP_AXES, None),
+    "ws_sig": P(None, None, None),       # [L, H, 1] the shared expert's own gate
     "ws_gate": P(None, None, TP_AXIS),   # shared expert, TP like dense mlp
     "ws_up": P(None, None, TP_AXIS),
     "ws_down": P(None, TP_AXIS, None),

@@ -240,6 +240,10 @@ def render_metrics(
         counters["state_bytes_in_use_total"] = stats.state_bytes_in_use_total
         counters["ssm_update_rows_total"] = stats.ssm_update_rows_total
         counters["ssm_scan_tokens_total"] = stats.ssm_scan_tokens_total
+        counters["gdn_update_rows_total"] = stats.gdn_update_rows_total
+        counters["gdn_scan_rows_total"] = stats.gdn_scan_rows_total
+        counters["gdn_scan_tokens_total"] = stats.gdn_scan_tokens_total
+        counters["gdn_state_bytes_moved_total"] = stats.gdn_state_bytes_moved_total
     lines: list[str] = []
     if stats.kv_transfer_failures:
         # Per-(stage, policy) transfer-failure breakdown (llmd-family

@@ -276,10 +276,16 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                    "retained-state cache is too small for the sessions "
                    "it serves (every turn leaves one snapshot behind)."),
         panel("State-space work /s",
-              [f"rate(llmd:ssm_update_rows_total{M}[5m])",
-               f"rate(llmd:ssm_scan_tokens_total{M}[5m])"],
+              [f"rate(llmd:ssm_update_rows_total{M}[5m]) + "
+               f"rate(llmd:gdn_update_rows_total{M}[5m])",
+               f"rate(llmd:ssm_scan_tokens_total{M}[5m]) + "
+               f"rate(llmd:gdn_scan_tokens_total{M}[5m])",
+               f"rate(llmd:gdn_scan_rows_total{M}[5m])",
+               f"rate(llmd:gdn_state_bytes_moved_total{M}[5m])"],
               legends=["decode rows x mixer layers /s",
-                       "prefill tokens x mixer layers /s"],
+                       "prefill tokens x mixer layers /s",
+                       "delta-rule scan rows x layers /s",
+                       "delta-rule state bytes moved /s"],
               desc="What the state-space layers computed: a decode row "
                    "reads and writes its whole slot state a layer "
                    "(bandwidth), a prefill token goes through the "

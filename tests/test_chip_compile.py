@@ -275,7 +275,38 @@ def _ssm_slot(write: bool):
     return (lambda p, l, s: read_slot(p, l, s, "pallas")), [(STATE_POOL, F32), ((), I32), ((), I32)]
 
 
+# qwen3-next-80b-a3b.1chip: 3 attention layers of 16 q / 2 kv heads at head
+# size 256 (the first the flat kernels meet); its state pool: 9 delta-rule
+# layers x 121 slots of 32 value heads x a [128, 128] state.
+QWEN3_NEXT = (3, 16, 2, 256)
+DELTA_POOL = (9, 121, 32, 128, 128)
+
+
+def _gdn_update(rows):
+    from llmd_tpu.ops.gdn import gdn_update_pallas
+
+    _, _, H, Dk, Dv = DELTA_POOL
+    return gdn_update_pallas, [
+        (DELTA_POOL, F32), ((), I32), ((rows,), I32), ((), I32), ((rows, H), F32), ((rows, H), F32),
+        ((rows, H, Dk), F32), ((rows, H, Dk), F32), ((rows, H, Dv), F32),
+    ]
+
+
+def _gdn_slot(write: bool):
+    from llmd_tpu.ops.gdn import read_slot, write_slot
+
+    if write:
+        return (lambda p, l, s, v: write_slot(p, l, s, v, "pallas")), [
+            (DELTA_POOL, F32), ((), I32), ((), I32), (DELTA_POOL[2:], F32)]
+    return (lambda p, l, s: read_slot(p, l, s, "pallas")), [(DELTA_POOL, F32), ((), I32), ((), I32)]
+
+
 CASES = {
+    "flat_attention-qwen3-next-80b-a3b": lambda d: _flat_attention(QWEN3_NEXT, BF16, 144),
+    "flat_write-qwen3-next-80b-a3b": lambda d: _flat_write(QWEN3_NEXT, BF16, 144),
+    "gdn_update-qwen3-next-80b-a3b": lambda d: _gdn_update(32),
+    "gdn_slot_read-qwen3-next-80b-a3b": lambda d: _gdn_slot(False),
+    "gdn_slot_write-qwen3-next-80b-a3b": lambda d: _gdn_slot(True),
     "flat_attention-bf16": lambda d: _flat_attention(LLAMA, BF16, 2064),
     "flat_attention-int8": lambda d: _flat_attention(LLAMA, I8, 256),
     "flat_attention-qwen3-30b-a3b": lambda d: _flat_attention(QWEN3, BF16, 256),
@@ -553,3 +584,48 @@ def test_a_one_group_models_step_keeps_its_pallas_calls(nemotron_step, v5e):
     assert text.count("stablehlo.custom_call @tpu_custom_call") == 6
     update = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and "x4x8x16xf32>" in ln and ") -> (" in ln]
     assert update and all("x1x16xf32>" in ln for ln in update)
+
+
+def test_the_delta_rule_hybrids_step_compiles_with_both_pools_in_place(v5e):
+    """qwen3-next-80b-a3b.1chip's saturated step for the described v5e
+    (``perfbench/rehearse_compile_gdn.py``): 12 layers in three cycles ``L L L
+    F`` as ONE scanned body, both pools aliased (2.25 GiB of pages at head size
+    256, 2.2 GiB of state), nothing copies them, the whole step under the
+    chip's 15.75 GiB; the Pallas calls carry the names the benchmark's readers
+    match (``%gmm``, ``%llmd.gdn.update``, ``%llmd.gdn.scan``, the attention
+    layer's ``%llmd.block.attn``) and the operand forms they parse."""
+    import json
+    import pathlib
+    import sys
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from perfbench import rehearse_compile_gdn as rc
+    from perfbench.topologies.engine_gdn import engine_config
+
+    conf = json.loads((root / rc.CONFIG).read_text())
+    r = rc.build_runner(engine_config(conf, 0, False), v5e)
+    assert list(r.flat_t_buckets)[:8] == list(range(16, 144, 16))
+    _lowered, compiled = rc.mixer.compile_step(r, 144)
+    text = compiled.as_text()
+    calls = [ln.strip() for ln in text.splitlines() if "tpu_custom_call" in ln and " custom-call(" in ln]
+    names = {ln.split(" = ")[0].rstrip(".0123456789") for ln in calls}
+    assert {"%gmm", "%llmd.gdn.update", "%llmd.gdn.scan", "%llmd.block.attn"} <= names
+    # the cycle body's four FFN positions: gate, up and down each
+    gmm_shapes = [re.search(r"bf16\[(\d+),(\d+),(\d+),(\d+)\]", ln.partition(" custom-call(")[2])
+                  for ln in calls if ln.startswith("%gmm")]
+    assert len(gmm_shapes) == 12 and all(gmm_shapes)
+    assert {tuple(int(x) for x in m.groups()) for m in gmm_shapes} == {(12, 64, 2048, 512), (12, 64, 512, 2048)}
+    update = [ln for ln in calls if ln.startswith("%llmd.gdn.update")]
+    assert len(update) == 3 and all("f32[9,121,32,128,128]" in ln.partition(" custom-call(")[2] for ln in update)
+    attn = [ln for ln in calls if ln.startswith("%llmd.block.attn")]
+    assert len(attn) == 2 and all("bf16[3,24576,2,16,512]" in ln for ln in attn)  # the KV write and the flat attention
+    for scope in ("llmd.block.gdn", "llmd.block.moe", "llmd.gdn.conv"):
+        assert scope in text
+    m = compiled.memory_analysis()
+    pools = 3 * 24576 * 2 * 16 * 512 * 2 + 9 * 121 * (32 * 128 * 128 * 4 + 3 * 8192 * 2)
+    assert m.alias_size_in_bytes >= pools
+    assert m.temp_size_in_bytes < 0.5 * 2**30
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+    assert total < 15.75 * 2**30

@@ -243,6 +243,63 @@ def test_straddle_rows_fit_run_plan():
     assert spec["wcnt"] == a["wcnt"].shape
 
 
+def test_a_chunks_sub_rows_share_the_run_of_the_page_one_ends_in():
+    """A chunk that starts inside a page and is split into sub-rows of
+    the row cap ends a sub-row inside a page too. Its next sub-row goes on
+    in the SAME run: two runs of one launch on one page would lose the
+    first one's rows (the write pipeline loads run r+1's page before run
+    r's is stored)."""
+    from types import SimpleNamespace
+
+    eng = make_engine(True, page=16, num_blocks=32, max_batched=256, max_model_len=512)
+    r = eng.runner
+    page, cap, B = r.page, r.unified_row_cap, r.flat_rows
+    p0, w = 122, 150  # positions [122, 272) as sub-rows of 64, 64 and 22 tokens behind 6 decode tokens
+    a = {
+        "row_start": np.zeros(B, np.int32), "pos0": np.zeros(B, np.int32),
+        "qlens": np.zeros(B, np.int32),
+        "page_table": np.tile(np.arange(100, 100 + r.max_pages, dtype=np.int32), (B, 1)),
+    }
+    widths = [cap, cap, w - 2 * cap]
+    for i, wi in enumerate(widths):
+        a["row_start"][i], a["pos0"][i], a["qlens"][i] = 6 + i * cap, p0 + i * cap, wi
+    a["row_start"][len(widths):] = 6 + w
+    staged = SimpleNamespace(B=B, T=160, row_seqs=[None] * len(widths), arrays=a)
+    r._fill_flat_runs(staged, a)
+    live = a["wcnt"] > 0
+    assert int(a["wcnt"].sum()) == w and len(set(a["wphys"][live])) == int(live.sum())  # a page a run
+    first, last = p0 // page, (p0 + w - 1) // page
+    assert list(a["wphys"][live]) == list(range(100 + first, 100 + last + 1))
+    # every token lands where its position says: page row off + j <- stream token src - page + off + j
+    for src, off, cnt, phys in zip(a["wsrc"][live], a["woff"][live], a["wcnt"][live], a["wphys"][live]):
+        stream0 = src - page + off
+        assert (phys - 100) * page + off == p0 + (stream0 - 6) and off + cnt <= page
+
+
+def test_interpreted_flat_write_keeps_every_row_of_a_split_chunk(monkeypatch):
+    """The engine's pages after a prompt that the budget cuts into chunks
+    which start inside a page: with the Pallas write interpreted, no
+    cached row is left as the pool was made (zeros), and the rows are the
+    XLA scatter's."""
+    prompt = list(np.random.default_rng(5).integers(0, 256, size=200))
+    sp = SamplingParams(temperature=0.0, max_tokens=2, ignore_eos=True)
+
+    def cached_rows(eng):
+        eng.generate([prompt], sp)
+        pages = eng.allocator.lookup_cached_prefix(prompt)
+        assert len(pages) == len(prompt) // 16
+        return np.asarray(eng.runner.kv_cache[:, np.asarray(pages)])  # [L, pages, K, page, 2D]
+
+    kw = dict(page=16, num_blocks=32, max_batched=72, max_model_len=512, head_dim=128, num_heads=2, num_kv_heads=1)
+    want = cached_rows(make_engine(True, **kw))
+    monkeypatch.setenv("LLMD_PALLAS", "interpret")
+    eng = make_engine(True, **kw)
+    got = cached_rows(eng)
+    assert eng.runner.kernel_plans["flat_kv_write"] == {"pallas"}  # interpreted
+    assert np.abs(got).sum(axis=(2, 4)).min() > 0  # chunks [0, 72), [72, 144), ..: sub-rows end at 136, 208
+    np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
+
+
 def test_spec_seeded_parity():
     sp = [
         SamplingParams(temperature=0.8, max_tokens=10, seed=7),
