@@ -114,6 +114,15 @@ class RetainedStateCache:
         self.misses = 0
         self.captures = 0
         self.evictions = 0
+        # Captures whose key had to be hashed from the prompt again (the
+        # request came without its admission's walk), and the host time of
+        # all captures, wherever in the step it is spent.
+        self.rehashed = 0
+        self.capture_host_ms = 0.0
+        # Keys captured behind a step that has not been committed yet: the
+        # main pool registers their full page at that commit (``settle``),
+        # so until then ``is_live`` says nothing about them.
+        self._unsettled: set[bytes] = set()
 
     def capture(
         self, key: bytes, ring_ids: list[int], s0: int, n_pre: int,
@@ -159,7 +168,12 @@ class RetainedStateCache:
             self._alloc.free(dst)
             raise
         self._entries[key] = _Section(s0, n_pre, pages=dst, shared=shared)
+        self._unsettled.add(key)
         self.captures += 1
+
+    def settle(self) -> None:
+        """The step the last captures were dispatched behind is committed."""
+        self._unsettled.clear()
 
     def evict_one(self) -> bool:
         """Free the retained section that is worth least (ring-pressure
@@ -175,7 +189,8 @@ class RetainedStateCache:
         if not self._entries:
             return False
         ranked = (
-            lambda k, e: self._is_live is not None and not self._is_live(k),
+            lambda k, e: self._is_live is not None
+            and k not in self._unsettled and not self._is_live(k),
             lambda k, e: not e.shared and e.hits > 0,
             lambda k, e: not e.shared,
             lambda k, e: True,
@@ -271,6 +286,13 @@ class EngineStats:
     state_snapshot_misses_total: int = 0
     state_snapshot_captures_total: int = 0
     state_snapshot_evictions_total: int = 0
+    # Retained-state captures (sections and snapshots alike) that had to
+    # hash their prompt again because the request carried no key from its
+    # admission (a P/D preload, a request resumed from the pager), and the
+    # host time of all captures: spent behind the dispatch of the step that
+    # writes the state, under the device, and no part of the host's turn.
+    retained_capture_rehashed_total: int = 0
+    retained_capture_host_ms_total: float = 0.0
     # Bytes of state-pool slots held (running + retained), summed over
     # steps: beside kv_bytes_in_use_total (which counts pages only there)
     # over cached_tokens_total, what a cached token costs in both pools.
@@ -989,47 +1011,81 @@ class LLMEngine:
             return None, 0, 0
         return hashes[n_pre - 1], n_pre, s0
 
-    def _capture_swa_section(self, req) -> None:
-        """Scheduler hook at prompt completion: the ring still holds the
-        prompt's trailing window — retain a copy for later hybrid hits.
-        (At FINISH time the ring has advanced past the prompt, which is
-        why capture happens here, mirroring the P/D export's staleness
-        rule.)"""
-        try:
-            key, n_pre, s0 = self._section_key(
-                req.prompt_token_ids, self.scheduler.hash_extra(req)
-            )
-            if key is None or not req.swa_block_ids:
-                return
-            self._swa_sections.capture(key, req.swa_block_ids, s0, n_pre)
-        # llmd: allow(broad-except) -- best-effort section retention; a capture failure only costs a future cache hit
-        except Exception:
-            logging.getLogger(__name__).exception(
-                "swa section capture failed (serving unaffected)"
-            )
+    def _capture_key(self, req):
+        """``_section_key`` of ``req``'s prompt, from the hash the admission's
+        walk left on the request where it is there for this ``n_pre``; a
+        request that came another way (a P/D preload, one the pager
+        resumed) is hashed again, and counted."""
+        page = self.config.cache.page_size
+        n_pre, s0, _cnt = self._swa.section(len(req.prompt_token_ids), page)
+        if n_pre <= s0:
+            return None, 0, 0
+        if req.capture_key is not None and req.capture_key[0] == n_pre:
+            return req.capture_key[1], n_pre, s0
+        self._swa_sections.rehashed += 1
+        return self._section_key(
+            req.prompt_token_ids, self.scheduler.hash_extra(req)
+        )
 
-    def _capture_passed_section(self, req) -> None:
-        """Scheduler hook: ``req``'s prefill has just passed the full-page
-        run it was refused at admission (``Request.swa_capture``); the ring
-        holds the window before every page boundary of the chunk it has
-        just written, so the section at the run's end is captured now."""
+    def _capture_section(self, req, passed: bool) -> None:
+        """The one capture behind both scheduler hooks, best effort: a
+        failure costs a future hit and nothing else."""
+        t0 = time.monotonic()
         try:
-            pages, key = req.swa_capture
-            # The one geometry (SwaRingSpec.section) of a prompt that
-            # continues right after the run.
-            n_pre, s0, _cnt = self._swa.section(
-                pages * self.config.cache.page_size + 1,
-                self.config.cache.page_size,
-            )
-            if req.swa_block_ids:
+            if passed:
+                pages, key = req.swa_capture
+                # The one geometry (SwaRingSpec.section) of a prompt that
+                # continues right after the run.
+                page = self.config.cache.page_size
+                n_pre, s0, _cnt = self._swa.section(pages * page + 1, page)
+            else:
+                key, n_pre, s0 = self._capture_key(req)
+            if key is not None and req.swa_block_ids:
                 self._swa_sections.capture(
-                    key, req.swa_block_ids, s0, n_pre, shared=True
+                    key, req.swa_block_ids, s0, n_pre, shared=passed
                 )
         # llmd: allow(broad-except) -- best-effort section retention; a capture failure only costs a future cache hit
         except Exception:
             logging.getLogger(__name__).exception(
                 "swa section capture failed (serving unaffected)"
             )
+        finally:
+            self._swa_sections.capture_host_ms += (time.monotonic() - t0) * 1e3
+
+    def _capture_swa_section(self, req) -> None:
+        """Scheduler hook behind the dispatch of the chunk that completes
+        the prompt (for a recurrent state: that leaves it at the prompt's
+        last full page): behind that step the ring holds the prompt's
+        trailing window — retain a copy for later hybrid hits. (At FINISH
+        time the ring has advanced past the prompt, which is why capture
+        happens here, mirroring the P/D export's staleness rule.)"""
+        self._capture_section(req, passed=False)
+
+    def _capture_passed_section(self, req) -> None:
+        """Scheduler hook: ``req``'s prefill chunk just dispatched passes
+        the full-page run it was refused at admission
+        (``Request.swa_capture``); behind it the ring holds the window
+        before every page boundary of the chunk, so the section at the
+        run's end is captured now."""
+        self._capture_section(req, passed=True)
+
+    def _commit(self, batch: ScheduledBatch, sampled) -> dict:
+        """The scheduler's commit of a step that has been read back."""
+        accepted = self.scheduler.update_after_step(batch, sampled)
+        if self._swa_sections is not None:
+            # The full pages behind the step's captures are registered now.
+            self._swa_sections.settle()
+        return accepted
+
+    def _capture_behind(self, batch: ScheduledBatch) -> None:
+        """Behind ``batch``'s dispatch: the retained-state captures of its
+        prefill rows (``EngineScheduler.capture_dispatched``). The copy is
+        a program of its own that reads the pool the step has just been
+        handed, so the device runs it behind the step and in front of the
+        next one; in the multi-host leg its ``_OP_KV_COPY`` goes out behind
+        the step's opcode, from the one thread that sends both."""
+        if self._swa_sections is not None:
+            self.scheduler.capture_dispatched(batch)
 
     # ------------------------------------------------------------------ #
 
@@ -1248,6 +1304,8 @@ class LLMEngine:
         hashes = page_hashes_for_tokens(
             list(req.prompt_token_ids[: n_pre * page]), page, extra=extra
         )
+        # ... and the capture at this prompt's end (``_capture_key``).
+        req.capture_key = (n_pre, hashes[n_pre - 1])
         for k in lengths:
             key = hashes[k - 1]
             if not self._swa_sections.has(key):
@@ -1756,6 +1814,7 @@ class LLMEngine:
                     pend_d = self._dispatch_decodes(batch.decodes)
             self.scheduler.note_dispatch(batch)
         t_dispatched = time.monotonic()
+        self._capture_behind(batch)  # under the device, like the wait
         # One coalesced readback for the whole step (prefill bucket
         # groups + the decode window — or the one unified program —
         # come back in a single transfer).
@@ -1766,7 +1825,7 @@ class LLMEngine:
         t_read = self.last_readback_at = waited.read_at
         with profiling.span("llmd.step.finish") as finish_span:
             sampled, logprobs = self._collect(batch, pres, dres)
-            accepted = self.scheduler.update_after_step(batch, sampled)
+            accepted = self._commit(batch, sampled)
             outputs = self._assemble_outputs(batch, accepted, logprobs)
             if self.offloader is not None:
                 # One bucketed HBM->host gather for the step's committed
@@ -1824,9 +1883,7 @@ class LLMEngine:
         t_read = self.last_readback_at = waited.read_at
         with profiling.span("llmd.step.commit") as commit_span:
             sampled, logprobs = self._collect(inflight.batch, pres, dres)
-            accepted = self.scheduler.update_after_step(
-                inflight.batch, sampled
-            )
+            accepted = self._commit(inflight.batch, sampled)
             self._inflight = None
             if self.intake_hook is not None:
                 # The last instants' arrivals and aborts: nothing is in
@@ -1873,6 +1930,7 @@ class LLMEngine:
         # below overlap step N+1's execution.
         t_redispatched = time.monotonic()
         with profiling.span("llmd.step.finish") as finish_span:
+            self._capture_behind(slot.batch)
             outputs = self._assemble_outputs(
                 inflight.batch, accepted, logprobs
             )
@@ -1922,6 +1980,7 @@ class LLMEngine:
             batch = self._schedule_spanned()
             if not batch.is_empty:
                 self._dispatch_async(batch)
+                self._capture_behind(batch)
 
     def _stage(
         self, batch: ScheduledBatch
@@ -2470,6 +2529,10 @@ class LLMEngine:
                 self.stats.swa_section_hits_total = s["hits"]
                 self.stats.swa_section_misses_total = s["misses"]
                 self.stats.swa_section_captures = s["captures"]
+        if self._swa_sections is not None:
+            kept = self._swa_sections
+            self.stats.retained_capture_rehashed_total = kept.rehashed
+            self.stats.retained_capture_host_ms_total = kept.capture_host_ms
         self.stats.prefix_hit_ratio = self.allocator.hit_ratio()
         self.stats.preemptions = self.scheduler.num_preemptions
         self.stats.queue_wait_ms_total = self.scheduler.queue_wait_ms

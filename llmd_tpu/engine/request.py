@@ -108,6 +108,12 @@ class Request:
     # section is captured as its prefill passes the run's end (scheduler
     # ``prefill_passed_hook``) and the next request takes the hit.
     swa_capture: tuple | None = None
+    # (n_pre, chain hash of page n_pre - 1) of the prompt's own retained
+    # section, kept from the admission's hash walk
+    # (``LLMEngine._try_hybrid_ring_hit``) so that the capture at the
+    # prompt's end hashes nothing. A prompt only ever grows (a preemption
+    # folds outputs in), so the hash stands while ``n_pre`` does.
+    capture_key: tuple | None = None
     # The prompt's page-hash chain, left by an admission that looked the
     # prefix cache up and then failed for want of fresh pages (what the
     # cache lent went back): the next attempt walks it again instead of

@@ -118,13 +118,14 @@ class EngineScheduler:
         # Called with the finished Request before its pages are released
         # (P/D producer KV export point).
         self.finish_hook = None
-        # Ring engines: called once when a request's prompt completes
-        # (the ring still holds the prompt's trailing window) — the
+        # Ring engines: called once, behind the DISPATCH of the chunk that
+        # completes a request's prompt (``capture_dispatched``: behind that
+        # step the ring holds the prompt's trailing window) — the
         # hybrid-APC section capture point.
         self.prefill_complete_hook = None
-        # Called when a prefill chunk carried a request past the span it
-        # noted in ``Request.swa_capture`` (the ring holds the window
-        # before any page boundary of the chunk it has just written).
+        # Called behind the dispatch of the prefill chunk that carries a
+        # request past the span it noted in ``Request.swa_capture`` (the
+        # ring then holds the window before any page boundary of the chunk).
         self.prefill_passed_hook = None
         # Ring engines: the hybrid prefix hit, taken at admission
         # (_apply_prefix_cache); fills the request's pages, ring and
@@ -844,6 +845,37 @@ class EngineScheduler:
             seq.request.num_pending_tokens += seq.num_tokens
             self.protected.add(seq.request.request_id)
 
+    def capture_dispatched(self, batch: ScheduledBatch) -> None:
+        """Call the capture hooks for the prefill rows of ``batch``, which
+        has JUST been dispatched, whose chunk leaves the per-sequence state
+        at a capture boundary. The device runs programs in dispatch order
+        and a dispatched step is never rolled back (in-flight rows are
+        protected, their aborts deferred), so the copy a hook dispatches
+        now runs behind the step that writes the state and in front of the
+        next one, which overwrites it: the order it had when the hooks
+        fired at the step's commit, at no cost to the host's turn."""
+        if self.prefill_complete_hook is None:
+            return
+        page = self.cache_config.page_size
+        for seq in batch.prefills:
+            req = seq.request
+            at = req.num_dispatched_tokens
+            run_end = req.swa_capture[0] * page if req.swa_capture else None
+            if run_end is not None and at >= run_end:
+                # A recurrent state is the run's only AT its end (the chunk
+                # was cut there); one that has passed it is dropped.
+                if not self.state_aligned or at == run_end:
+                    self.prefill_passed_hook(req)
+                req.swa_capture = None
+            if self.state_aligned:
+                # The state stands at the prompt's last full page.
+                if at == self._prompt_boundary(req):
+                    self.prefill_complete_hook(req)
+            elif req.in_decode_dispatched:
+                # The chunk completes the prompt: behind it the ring holds
+                # the prompt's trailing window.
+                self.prefill_complete_hook(req)
+
     def _commit_pending(self, seq: ScheduledSeq) -> None:
         req = seq.request
         req.num_pending_tokens = max(0, req.num_pending_tokens - seq.num_tokens)
@@ -867,35 +899,7 @@ class EngineScheduler:
             req.num_computed_tokens += seq.num_tokens
             if req.is_batch:
                 self.batch_tokens += seq.num_tokens
-            page = self.cache_config.page_size
-            if (
-                req.swa_capture is not None
-                and self.prefill_passed_hook is not None
-                and req.num_computed_tokens >= req.swa_capture[0] * page
-            ):
-                # A recurrent state is the run's only AT its end (the chunk
-                # was cut there); one that has passed it is dropped.
-                if (
-                    not self.state_aligned
-                    or req.num_computed_tokens == req.swa_capture[0] * page
-                ):
-                    self.prefill_passed_hook(req)
-                req.swa_capture = None
-            if (
-                self.state_aligned
-                and self.prefill_complete_hook is not None
-                and req.num_computed_tokens == self._prompt_boundary(req)
-            ):
-                # The state stands at the prompt's last full page.
-                self.prefill_complete_hook(req)
             if req.in_decode:  # this chunk completed the prompt -> 1st token
-                if (
-                    self.prefill_complete_hook is not None
-                    and not self.state_aligned
-                ):
-                    # Hybrid-APC capture: the ring still holds the
-                    # prompt's trailing window right now.
-                    self.prefill_complete_hook(req)
                 token = sampled[req.request_id][0]
                 req.output_token_ids.append(token)
                 accepted[req.request_id] = [token]

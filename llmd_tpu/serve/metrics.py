@@ -244,6 +244,12 @@ def render_metrics(
         counters["gdn_scan_rows_total"] = stats.gdn_scan_rows_total
         counters["gdn_scan_tokens_total"] = stats.gdn_scan_tokens_total
         counters["gdn_state_bytes_moved_total"] = stats.gdn_state_bytes_moved_total
+    if stats.swa_ring_pages or stats.state_bytes_in_use_total:
+        # Retained-state captures of either kind: those that hashed their
+        # prompt again (0 where every request was hashed at its admission),
+        # and their host time, spent behind a step's dispatch.
+        counters["retained_capture_rehashed_total"] = stats.retained_capture_rehashed_total
+        counters["retained_capture_host_ms_total"] = round(stats.retained_capture_host_ms_total, 3)
     lines: list[str] = []
     if stats.kv_transfer_failures:
         # Per-(stage, policy) transfer-failure breakdown (llmd-family

@@ -275,6 +275,22 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                    "nothing; evictions at the rate of captures = the "
                    "retained-state cache is too small for the sessions "
                    "it serves (every turn leaves one snapshot behind)."),
+        panel("Retained-state capture: host ms, and prompts hashed again",
+              [f"rate(llmd:retained_capture_host_ms_total{M}[5m]) / "
+               f"(rate(llmd:state_snapshot_captures_total{M}[5m]) + "
+               f"rate(llmd:swa_section_captures_total{M}[5m]))",
+               f"rate(llmd:retained_capture_rehashed_total{M}[5m])"],
+              legends=["host ms a capture", "captures that hashed their "
+                       "prompt again /s"],
+              desc="A ring's sections and a state pool's snapshots alike. "
+                   "A capture's host work (key, eviction, allocation, two "
+                   "index puts, the copy's dispatch) runs behind the "
+                   "dispatch of the step that writes the state, under the "
+                   "device: no part of the host gap. Its key comes from "
+                   "the admission's hash walk; a capture that hashed its "
+                   "prompt again (a P/D preload, a request the pager "
+                   "resumed) costs ~3 us a page of context on the host: "
+                   "0 for session traffic."),
         panel("State-space work /s",
               [f"rate(llmd:ssm_update_rows_total{M}[5m]) + "
                f"rate(llmd:gdn_update_rows_total{M}[5m])",

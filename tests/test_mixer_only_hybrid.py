@@ -259,6 +259,9 @@ COUNTERS = {
     "state_snapshot_misses_total": lambda s, st: st.state_snapshot_misses_total == 0,
     "state_snapshot_captures_total": lambda s, st: s["state_snapshot_captures_total"] >= 3,
     "state_snapshot_evictions_total": lambda s, st: st.state_snapshot_evictions_total == 0,
+    # every capture took its key from its admission's walk, and was timed
+    "retained_capture_rehashed_total": lambda s, st: st.retained_capture_rehashed_total == 0,
+    "retained_capture_host_ms_total": lambda s, st: 0 < s["retained_capture_host_ms_total"] <= st.retained_capture_host_ms_total,
     # one count a grouped expert layer for its TWO matmuls: 8 expert layers a step, none for the 3 layers without FFN
     "moe_grouped_calls_total": lambda s, st: s["moe_grouped_calls_total"] == LE * s["engine_steps_total"],
     "moe_picks_total": lambda s, st: s["moe_picks_total"] % (LE * 2 * 16) == 0 and s["moe_picks_total"] > 0,

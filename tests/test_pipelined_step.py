@@ -37,6 +37,7 @@ from llmd_tpu.engine import runner as runner_mod  # noqa: E402
 from llmd_tpu.engine.request import PriorityClass, RequestStatus  # noqa: E402
 from llmd_tpu.models.registry import get_model_config  # noqa: E402
 from llmd_tpu.obs import profiling  # noqa: E402
+from llmd_tpu.serve.metrics import parse_prometheus, render_metrics  # noqa: E402
 from tests.host_trace import host_spans  # noqa: E402
 
 
@@ -146,6 +147,226 @@ def test_pipelined_equals_synchronous_with_a_hit_and_a_mid_batch_stop(model):
                  "sparse_unbound_tokens_total", "indexer_keys_scored_total", "live_tokens_total",
                  "attn_shared_tile_tokens_total"):
         assert getattr(pipe.stats, name) == getattr(sync.stats, name), name
+
+
+# --- a retained-state capture costs the host's turn nothing -------------------------
+
+RETAINING = ["tiny-exaone", "tiny-granite-hybrid"]  # a ring's sections / a state pool's snapshots
+
+
+class Recorder:
+    """Recording fakes around one engine: every hash walk and ``hash_page``
+    call, every step dispatch, device copy, capture and commit, in order."""
+
+    def __init__(self, eng: LLMEngine, monkeypatch):
+        from llmd_tpu.engine import kv_cache, scheduler as scheduler_mod
+
+        self.eng, self.events, self.walks, self.pages_hashed = eng, [], [], 0
+        self.captured, self.hashed_in_a_capture = [], 0
+        walk, hash_page = kv_cache.page_hashes_for_tokens, kv_cache.hash_page
+
+        def walking(token_ids, page_size, extra=b""):
+            self.walks.append(len(token_ids))
+            return walk(token_ids, page_size, extra)
+
+        def hashing(*a, **kw):
+            self.pages_hashed += 1
+            return hash_page(*a, **kw)
+
+        monkeypatch.setattr(kv_cache, "page_hashes_for_tokens", walking)
+        monkeypatch.setattr(kv_cache, "hash_page", hashing)  # (the walk's own calls)
+        monkeypatch.setattr(scheduler_mod, "hash_page", hashing)  # (the commit chain's)
+        depth = [0]
+
+        def dispatching(fn):
+            def call(*a, **kw):
+                if not depth[0]:
+                    self.events.append("dispatch")
+                depth[0] += 1
+                try:
+                    return fn(*a, **kw)
+                finally:
+                    depth[0] -= 1
+            return call
+
+        r = eng.runner
+        for name in dir(r):
+            if name.startswith("dispatch_"):
+                setattr(r, name, dispatching(getattr(r, name)))
+        copy, commit = r.copy_pages_on_device, eng.scheduler.update_after_step
+        capture, section = eng._swa_sections.capture, eng._capture_section
+        in_capture = [False]
+
+        def copying(src, dst, swa=False):
+            self.events.append("capture-copy" if in_capture[0] else "seed-copy")
+            return copy(src, dst, swa=swa)
+
+        def committing(batch, sampled):
+            self.events.append("commit")
+            return commit(batch, sampled)
+
+        def capturing(key, ring_ids, s0, n_pre, shared=False):
+            self.captured.append((key, n_pre, shared))
+            return capture(key, ring_ids, s0, n_pre, shared=shared)
+
+        def sectioning(req, passed):
+            before, in_capture[0] = (len(self.walks), self.pages_hashed), True
+            try:
+                return section(req, passed)
+            finally:
+                in_capture[0] = False
+                self.hashed_in_a_capture += (len(self.walks) - before[0]) + (self.pages_hashed - before[1])
+
+        r.copy_pages_on_device = copying
+        eng.scheduler.update_after_step = committing
+        eng._swa_sections.capture = capturing
+        eng._capture_section = sectioning
+
+
+def turns(eng: LLMEngine, n_turns=3, first=37, more=9, max_tokens=6, seed=40):
+    """A session of ``n_turns``: each prompt is the last one, its answer and
+    ``more`` new tokens. [(prompt, tokens, request)]."""
+    prompt, out = tokens(first, seed=seed), []
+    for i in range(n_turns):
+        (toks, _lp, req), = serve(eng, [prompt], max_tokens=max_tokens)
+        out.append((prompt, toks, req))
+        prompt = prompt + toks + tokens(more, seed=seed + 1 + i)
+    return out
+
+
+def forget_the_admissions_key(eng: LLMEngine) -> None:
+    """The parent's path: the capture finds no key on the request and walks
+    the prompt (``_section_key``)."""
+    hook = eng.scheduler.hybrid_hit_hook
+
+    def hit(req):
+        hook(req)
+        req.capture_key = None
+
+    eng.scheduler.hybrid_hit_hook = hit
+
+
+@pytest.mark.parametrize("model", RETAINING)
+@pytest.mark.parametrize("pipelined", [True, False], ids=["pipelined", "synchronous"])
+def test_a_capture_walks_no_prompt_and_hashes_no_page(model, pipelined, monkeypatch):
+    """Each turn's prompt is walked ONCE, at its admission; the capture at
+    its end takes its key from that walk: no walk, no ``hash_page`` call of
+    its own, and nothing counted as hashed again. What is hashed in all is
+    the admission's walk and the commit chain's new pages."""
+    eng = make_engine(model, pipelined)
+    rec = Recorder(eng, monkeypatch)
+    page = eng.config.cache.page_size
+    session = turns(eng)
+    own = [c for c in rec.captured if not c[2]]
+    assert len(own) == len(session) == 3 and rec.hashed_in_a_capture == 0
+    assert rec.walks == [(len(p) - 1) // page * page for p, _, _ in session]
+    chain = sum((len(p) + len(t) - 1) // page - r.num_cached_tokens // page for p, t, r in session)
+    assert rec.pages_hashed == sum(w // page for w in rec.walks) + chain
+    eng._refresh_gauges()
+    assert eng.stats.retained_capture_rehashed_total == 0
+    assert eng.stats.retained_capture_host_ms_total > 0
+    page_of_metrics = parse_prometheus(render_metrics(eng.stats, model))
+    for family in ("vllm", "llmd"):
+        assert page_of_metrics[f"{family}:retained_capture_rehashed_total"] == 0
+        assert page_of_metrics[f"{family}:retained_capture_host_ms_total"] > 0
+    assert [r.num_cached_tokens for _, _, r in session[1:]] == [(len(p) - 1) // page * page for p, _, _ in session[:-1]]
+
+
+@pytest.mark.parametrize("model", RETAINING)
+@pytest.mark.parametrize("extra", [b"", b"lora:tenant-a", b"salt:7"], ids=["plain", "lora", "cache_salt"])
+def test_a_captured_key_is_the_section_key_of_its_prompt(model, extra, monkeypatch):
+    """Byte for byte ``_section_key``'s (the fallback and the oracle), with
+    the identity that the page hashes fold folded in; a hit is found by it:
+    the next turn skips the whole of the last one's full pages, as it does
+    when the capture walks the prompt (the parent's path)."""
+    page, cached = None, {}
+    for parents_path in (False, True):
+        eng = make_engine(model, pipelined=True)
+        eng.scheduler._hash_extra = lambda req: extra  # (a LoRA's name / a cache salt: what `hash_extra` folds)
+        rec = Recorder(eng, monkeypatch)
+        if parents_path:
+            forget_the_admissions_key(eng)
+        page = eng.config.cache.page_size
+        session = turns(eng)
+        own = [c for c in rec.captured if not c[2]]
+        assert [k for k, _, _ in own] == [eng._section_key(p, extra)[0] for p, _, _ in session]
+        assert [n for _, n, _ in own] == [(len(p) - 1) // page for p, _, _ in session]
+        if extra:
+            assert own[0][0] != eng._section_key(session[0][0], b"")[0]
+        assert eng._swa_sections.rehashed == (3 if parents_path else 0)
+        cached[parents_path] = [r.num_cached_tokens for _, _, r in session]
+    assert cached[False] == cached[True] == [0] + [(len(p) - 1) // page * page for p, _, _ in session[:-1]]
+
+
+@pytest.mark.parametrize("model", RETAINING)
+@pytest.mark.parametrize("pipelined", [True, False], ids=["pipelined", "synchronous"])
+def test_a_captures_copy_is_dispatched_behind_its_step_and_outside_the_commit(model, pipelined, monkeypatch, tmp_path):
+    """Dispatch N, the copy, dispatch N+1, on both steps: the device runs
+    the copy behind the step that wrote the state and in front of the one
+    that overwrites it. The step's commit comes after the copy, and the
+    ``llmd.state.capture`` span lies in no ``llmd.step.commit`` span (in
+    the pipelined step: inside ``llmd.step.finish``, under step N+1)."""
+    eng = make_engine(model, pipelined)
+    shared = tokens(GEOMETRY[model][2], seed=5)
+    serve(eng, [shared + tokens(7, seed=6)], max_tokens=4)  # (warm; leaves the shared pages behind)
+    rec = Recorder(eng, monkeypatch)
+    profiling.start(tmp_path)
+    try:
+        # a miss at the shared run's end (its section is captured as the
+        # prefill passes it), a stranger, and each prompt's own end
+        serve(eng, [shared + tokens(13, seed=7), tokens(23, seed=9)], max_tokens=5)
+        turns(eng, n_turns=2)
+    finally:
+        profiling.stop()
+    ev = rec.events
+    copies = [i for i, e in enumerate(ev) if e == "capture-copy"]
+    assert len(copies) == len(rec.captured) >= 5 and any(c[2] for c in rec.captured)
+    for i in copies:
+        before = next(e for e in reversed(ev[:i]) if e != "capture-copy")
+        after = next(e for e in ev[i + 1:] if e != "capture-copy")
+        assert (before, after) == ("dispatch", "commit"), ev[max(0, i - 3): i + 3]
+    assert "seed-copy" in ev  # (the session's second turn took its hit)
+    spans = host_spans(tmp_path)
+    captures, commits = ([(b, e) for name, b, e, _ in spans if name == want]
+                         for want in ("llmd.state.capture", "llmd.step.commit"))
+    assert len(captures) == len(copies)
+    assert not any(b <= cb < e for cb, _ in captures for b, e in commits)
+    if pipelined:
+        # in a step with a commit (not the one a pipeline starts with): behind
+        # the re-dispatch, inside the finish span, under the step just launched
+        behind = 0
+        for _, s0, s1, _ in (e for e in spans if e[0] == "llmd.step"):
+            names = {n: (b, e) for n, b, e, _ in spans if s0 <= b and e <= s1}
+            mine = [c for c in captures if s0 <= c[0] and c[1] <= s1]
+            if "llmd.step.commit" in names and mine:
+                launch, finish = names["llmd.runner.launch"], names["llmd.step.finish"]
+                assert all(launch[1] <= cb and finish[0] <= cb and ce <= finish[1] for cb, ce in mine)
+                behind += len(mine)
+        assert behind >= 1
+
+
+@pytest.mark.parametrize("model", RETAINING)
+@pytest.mark.parametrize("pipelined", [True, False], ids=["pipelined", "synchronous"])
+def test_a_session_reads_the_same_with_the_key_kept_as_with_the_prompt_walked(model, pipelined):
+    """Greedy tokens and log-probs of a multi-turn session, the hits taken
+    and the pools afterwards, against the parent's path (the key forgotten
+    at admission, so that every capture falls back to ``_section_key``)."""
+    got = {}
+    for parents_path in (False, True):
+        eng = make_engine(model, pipelined)
+        if parents_path:
+            forget_the_admissions_key(eng)
+        session = turns(eng, n_turns=3, max_tokens=8)
+        got[parents_path] = (
+            [t for _, t, _ in session], [np.asarray(r.output_logprobs) for _, _, r in session],
+            [r.num_cached_tokens for _, _, r in session], hit_counters(eng), pools_in_use(eng),
+        )
+        assert eng._swa_sections.rehashed == (3 if parents_path else 0)
+    new, parent = got[False], got[True]
+    assert new[0] == parent[0] and new[2:] == parent[2:]
+    for a, b in zip(new[1], parent[1]):
+        np.testing.assert_array_equal(a, b)
+    assert all(c > 0 for c in new[2][1:])
 
 
 # --- the top-up admission ----------------------------------------------------------

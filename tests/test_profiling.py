@@ -123,13 +123,38 @@ def test_engine_steps_write_their_phase_spans(tmp_path):
 
 
 @pytest.mark.parametrize("pipelined", [False, True])
-def test_wait_and_readback_are_two_spans_and_the_readback_is_counted(tmp_path, pipelined):
+def test_wait_and_readback_are_two_spans_and_the_readback_is_counted(tmp_path, pipelined, monkeypatch):
     """Both kinds of step go through ``wait_step``: ``llmd.runner.wait`` ends
     where the host knows the outputs are ready, ``llmd.runner.readback``
     follows it (a sibling, not a child) and ``step_readback_ms_total`` sums
-    its length; a blocking wait (no serving loop polls) has no ready lag."""
+    its length; a blocking wait (no serving loop polls) has no ready lag.
+
+    The counter's clock and the span's are read some microseconds apart, and
+    under six test workers a thread may lose the processor for milliseconds
+    between the two. So the wait and the readback are each given a BODY of
+    a known least length (a pause before the outputs are ready, one in the
+    parsing), and the counter is held to what it shares with the spans
+    whatever the scheduler does: it holds every readback's body, and lies
+    inside wait + readback less every wait's."""
+    import time
+
+    import jax
+
+    body_ms = 2.0
     eng = make_engine(pipelined=pipelined)
     eng.generate([[1, 2, 3, 4, 5]], SamplingParams(temperature=0.0, max_tokens=3))  # compiled
+    block, split = jax.block_until_ready, eng.runner._split_results
+
+    def blocking(x):  # (the wait's body: before the host knows the outputs are ready)
+        time.sleep(body_ms / 1e3)
+        return block(x)
+
+    def splitting(*a):  # (the readback's body: the parsing, behind the transfer)
+        time.sleep(body_ms / 1e3)
+        return split(*a)
+
+    monkeypatch.setattr(jax, "block_until_ready", blocking)
+    eng.runner._split_results = splitting
     s = eng.stats
     before = (s.engine_steps_total, s.step_readback_ms_total, s.step_wait_ms_total)
     profiling.start(tmp_path)
@@ -142,11 +167,12 @@ def test_wait_and_readback_are_two_spans_and_the_readback_is_counted(tmp_path, p
     waits, reads = ([(b, e) for name, b, e, _ in spans if name == want]
                     for want in ("llmd.runner.wait", "llmd.runner.readback"))
     assert len(waits) == len(reads) == steps >= 5
-    for (_, wait_end), (read_start, read_end) in zip(waits, reads):
-        assert wait_end <= read_start < read_end
+    for (wait_start, wait_end), (read_start, read_end) in zip(waits, reads):
+        assert wait_start < wait_end <= read_start < read_end
+        assert wait_end - wait_start >= body_ms * 1e6 and read_end - read_start >= body_ms * 1e6
     counted = s.step_readback_ms_total - before[1]
-    traced = sum(e - b for b, e in reads) / 1e6
-    assert 0 < counted <= traced * 1.05 + 0.05 * steps  # (the span closes a little after the clock is read)
+    both = sum(r[1] - w[0] for w, r in zip(waits, reads)) / 1e6
+    assert body_ms * steps <= counted <= both - body_ms * steps
     assert counted < s.step_wait_ms_total - before[2] + 1e-6  # a part of the wait as counted
     assert s.step_ready_lag_bound_ms_total == 0.0  # no poll: nothing was looked at twice
     assert (s.step_commit_ms_total > 0) == pipelined and s.step_gap_admit_ms_total == 0.0
