@@ -43,7 +43,12 @@ from llmd_tpu.engine.runner import (
     StagedVerify,
     StepResult,
 )
-from llmd_tpu.engine.scheduler import EngineScheduler, ScheduledBatch
+from llmd_tpu.engine.scheduler import (
+    AT_FINISH,
+    AT_RUN_END,
+    EngineScheduler,
+    ScheduledBatch,
+)
 from llmd_tpu.obs import profiling
 from llmd_tpu.parallel.mesh import MeshContext, build_mesh
 
@@ -119,6 +124,9 @@ class RetainedStateCache:
         # all captures, wherever in the step it is spent.
         self.rehashed = 0
         self.capture_host_ms = 0.0
+        # Entries made at a sequence's finish boundary (the engine counts:
+        # to the cache they are entries like a prompt's end's).
+        self.finish_captures = 0
         # Keys captured behind a step that has not been committed yet: the
         # main pool registers their full page at that commit (``settle``),
         # so until then ``is_live`` says nothing about them.
@@ -127,35 +135,43 @@ class RetainedStateCache:
     def capture(
         self, key: bytes, ring_ids: list[int], s0: int, n_pre: int,
         shared: bool = False,
-    ) -> None:
+    ) -> bool:
         """Copy ring slots [s0, n_pre) into retained pages (device op,
         no host bytes). No-op if the key is already retained or the SWA
         pool lacks headroom (a ring allocation must never fail because
-        retention hoarded pages)."""
+        retention hoarded pages). Returns whether an entry was made."""
         from llmd_tpu.engine.kv_cache import NoFreePagesError
 
         if key in self._entries:
             self._entries[key].shared |= shared
-            return
+            return False
         if self.capacity <= 0 or n_pre <= s0:
-            return
+            return False
         cnt = n_pre - s0
         R = len(ring_ids)
         # Entry-count LRU + page budget, evicted BEFORE allocating so
         # the budget invariant holds at the allocate call.
+        evicted = False
         while self._entries and (
             len(self._entries) >= self.capacity
             or self.retained_pages + cnt > self.page_budget
         ):
-            self.evict_one()
+            evicted = self.evict_one()
         if self.retained_pages + cnt > self.page_budget:
-            return  # a single oversized section cannot fit the budget
+            return False  # a single oversized section cannot fit the budget
         try:
-            dst = self._alloc.allocate(cnt)
+            # The pages its eviction has just given back (the free list's
+            # head), else the pages free longest: never what a SEQUENCE has
+            # just released. A ring or slot stays as its sequence left it
+            # until an admission takes it, and a capture may run steps
+            # after a batch mate's finish, where there was none before a
+            # decode row took one (a finished sequence's state can still
+            # be read out: the benchmark's state comparison does).
+            dst = self._alloc.allocate(cnt, longest_free=not evicted)
         except NoFreePagesError:
             # Pool transiently drained past the provisioned budget
             # (preload bursts hold extra rings): skip this capture.
-            return
+            return False
         self.retained_pages += cnt
         src = [ring_ids[l % R] for l in range(s0, n_pre)]
         try:
@@ -170,6 +186,7 @@ class RetainedStateCache:
         self._entries[key] = _Section(s0, n_pre, pages=dst, shared=shared)
         self._unsettled.add(key)
         self.captures += 1
+        return True
 
     def settle(self) -> None:
         """The step the last captures were dispatched behind is committed."""
@@ -293,6 +310,11 @@ class EngineStats:
     # writes the state, under the device, and no part of the host's turn.
     retained_capture_rehashed_total: int = 0
     retained_capture_host_ms_total: float = 0.0
+    # Of those captures, the ones taken where a sequence fills its last
+    # page before a finish by length that its admission foresaw (the next
+    # turn of its session then hits behind its own last answer); over
+    # requests_finished, how often that mechanism engages.
+    retained_finish_captures_total: int = 0
     # Bytes of state-pool slots held (running + retained), summed over
     # steps: beside kv_bytes_in_use_total (which counts pages only there)
     # over cached_tokens_total, what a cached token costs in both pools.
@@ -787,8 +809,7 @@ class LLMEngine:
                 self._swa_retention_budget,
                 is_live=self.allocator.has_cached,
             )
-            self.scheduler.prefill_complete_hook = self._capture_swa_section
-            self.scheduler.prefill_passed_hook = self._capture_passed_section
+            self.scheduler.capture_hook = self._capture_section
             self.scheduler.hybrid_hit_hook = self._try_hybrid_ring_hit
             self.scheduler.ring_pressure_hook = self._swa_sections.evict_one
         self.stats = EngineStats(
@@ -1027,47 +1048,69 @@ class LLMEngine:
             req.prompt_token_ids, self.scheduler.hash_extra(req)
         )
 
-    def _capture_section(self, req, passed: bool) -> None:
-        """The one capture behind both scheduler hooks, best effort: a
-        failure costs a future hit and nothing else."""
+    def _finish_key(self, req):
+        """(key, n_pre, s0) of the section at ``req``'s finish boundary, the
+        value the next turn's admission walk computes for that page: the
+        commit chain holds the hash of the page before it (every commit
+        registers the pages it filled, and the step in flight computes the
+        boundary's last position, whose token is its input), so ONE page is
+        hashed and no prompt walked. No key where the chain stands
+        elsewhere: an entry under another key could never be hit."""
+        from llmd_tpu.engine.kv_cache import hash_page
+
+        page = self.config.cache.page_size
+        at = req.finish_capture_at
+        n_pre, s0, _cnt = self._swa.section(at + 1, page)
+        tail, committed = self.scheduler.commit_chain_state(req)
+        if committed != n_pre - 1:
+            return None, 0, 0
+        key = hash_page(
+            tail, req.tokens_between(at - page, at),
+            self.scheduler.hash_extra(req),
+        )
+        return key, n_pre, s0
+
+    def _capture_section(self, req, point: str) -> None:
+        """Scheduler hook (``capture_hook``), the one capture behind all
+        three capture points, best effort: a failure costs a future hit
+        and nothing else. It runs behind the DISPATCH of the step that
+        leaves the state at the point: the chunk that completes the prompt
+        (for a recurrent state: that leaves it at the prompt's last full
+        page; behind it the ring holds the prompt's trailing window), the
+        chunk that passes the full-page run the request was refused at
+        admission (``Request.swa_capture``; the ring then holds the window
+        before every page boundary of the chunk), and the decode step that
+        fills the last page before a finish by length. At FINISH time
+        itself the ring has advanced past every boundary and the slot is
+        released, which is why a capture is taken on the way, mirroring
+        the P/D export's staleness rule."""
         t0 = time.monotonic()
+        kept = self._swa_sections
         try:
-            if passed:
+            if point == AT_RUN_END:
                 pages, key = req.swa_capture
                 # The one geometry (SwaRingSpec.section) of a prompt that
                 # continues right after the run.
                 page = self.config.cache.page_size
                 n_pre, s0, _cnt = self._swa.section(pages * page + 1, page)
+            elif point == AT_FINISH:
+                key, n_pre, s0 = self._finish_key(req)
             else:
                 key, n_pre, s0 = self._capture_key(req)
             if key is not None and req.swa_block_ids:
-                self._swa_sections.capture(
-                    key, req.swa_block_ids, s0, n_pre, shared=passed
+                made = kept.capture(
+                    key, req.swa_block_ids, s0, n_pre,
+                    shared=point == AT_RUN_END,
                 )
+                if made and point == AT_FINISH:
+                    kept.finish_captures += 1
         # llmd: allow(broad-except) -- best-effort section retention; a capture failure only costs a future cache hit
         except Exception:
             logging.getLogger(__name__).exception(
                 "swa section capture failed (serving unaffected)"
             )
         finally:
-            self._swa_sections.capture_host_ms += (time.monotonic() - t0) * 1e3
-
-    def _capture_swa_section(self, req) -> None:
-        """Scheduler hook behind the dispatch of the chunk that completes
-        the prompt (for a recurrent state: that leaves it at the prompt's
-        last full page): behind that step the ring holds the prompt's
-        trailing window — retain a copy for later hybrid hits. (At FINISH
-        time the ring has advanced past the prompt, which is why capture
-        happens here, mirroring the P/D export's staleness rule.)"""
-        self._capture_section(req, passed=False)
-
-    def _capture_passed_section(self, req) -> None:
-        """Scheduler hook: ``req``'s prefill chunk just dispatched passes
-        the full-page run it was refused at admission
-        (``Request.swa_capture``); behind it the ring holds the window
-        before every page boundary of the chunk, so the section at the
-        run's end is captured now."""
-        self._capture_section(req, passed=True)
+            kept.capture_host_ms += (time.monotonic() - t0) * 1e3
 
     def _commit(self, batch: ScheduledBatch, sampled) -> dict:
         """The scheduler's commit of a step that has been read back."""
@@ -1078,8 +1121,9 @@ class LLMEngine:
         return accepted
 
     def _capture_behind(self, batch: ScheduledBatch) -> None:
-        """Behind ``batch``'s dispatch: the retained-state captures of its
-        prefill rows (``EngineScheduler.capture_dispatched``). The copy is
+        """Behind ``batch``'s dispatch: the retained-state captures of the
+        rows it leaves at a capture point
+        (``EngineScheduler.capture_dispatched``). The copy is
         a program of its own that reads the pool the step has just been
         handed, so the device runs it behind the step and in front of the
         next one; in the multi-host leg its ``_OP_KV_COPY`` goes out behind
@@ -2533,6 +2577,7 @@ class LLMEngine:
             kept = self._swa_sections
             self.stats.retained_capture_rehashed_total = kept.rehashed
             self.stats.retained_capture_host_ms_total = kept.capture_host_ms
+            self.stats.retained_finish_captures_total = kept.finish_captures
         self.stats.prefix_hit_ratio = self.allocator.hit_ratio()
         self.stats.preemptions = self.scheduler.num_preemptions
         self.stats.queue_wait_ms_total = self.scheduler.queue_wait_ms

@@ -228,13 +228,17 @@ class PageAllocator:
             meta.ref_count += 1
 
     @_locked
-    def allocate(self, n: int) -> list[int]:
-        """Allocate n fresh pages (ref=1), evicting cached content LRU-first."""
+    def allocate(self, n: int, longest_free: bool = False) -> list[int]:
+        """Allocate n fresh pages (ref=1), evicting cached content LRU-first.
+
+        ``longest_free`` (a pool WITHOUT prefix caching, whose free list is
+        ordered by release alone): take the pages that have been free
+        longest instead of the ones released last."""
         if n > len(self._free):
             raise NoFreePagesError(n, len(self._free))
         out: list[int] = []
         for _ in range(n):
-            pid, _ = self._free.popitem(last=False)
+            pid, _ = self._free.popitem(last=longest_free)
             meta = self._meta[pid]
             if meta.content_hash is not None:
                 # Evict: the page is being reused for new content.

@@ -106,7 +106,7 @@ class Request:
     # at admission and the hybrid cache had to refuse for want of a
     # sliding section: this request prefills that span anyway, so the
     # section is captured as its prefill passes the run's end (scheduler
-    # ``prefill_passed_hook``) and the next request takes the hit.
+    # ``capture_hook`` at AT_RUN_END) and the next request takes the hit.
     swa_capture: tuple | None = None
     # (n_pre, chain hash of page n_pre - 1) of the prompt's own retained
     # section, kept from the admission's hash walk
@@ -114,6 +114,11 @@ class Request:
     # prompt's end hashes nothing. A prompt only ever grows (a preemption
     # folds outputs in), so the hash stands while ``n_pre`` does.
     capture_key: tuple | None = None
+    # The last page boundary the sequence fills before the finish its
+    # admission foresees, in tokens (``EngineScheduler._finish_boundary``;
+    # 0: none worth a capture): the decode step that leaves the state
+    # there leaves a copy behind for the session's next turn.
+    finish_capture_at: int = 0
     # The prompt's page-hash chain, left by an admission that looked the
     # prefix cache up and then failed for want of fresh pages (what the
     # cache lent went back): the next attempt walks it again instead of

@@ -31,6 +31,9 @@ from llmd_tpu.engine.kv_cache import (
 from llmd_tpu.engine.request import FinishReason, Request, RequestStatus
 from llmd_tpu.engine.sampler import accept_draft_tokens
 
+# The points a retained-state capture is taken at (``capture_dispatched``).
+AT_PROMPT_END, AT_RUN_END, AT_FINISH = "prompt_end", "run_end", "finish"
+
 
 @dataclasses.dataclass
 class ScheduledSeq:
@@ -118,15 +121,17 @@ class EngineScheduler:
         # Called with the finished Request before its pages are released
         # (P/D producer KV export point).
         self.finish_hook = None
-        # Ring engines: called once, behind the DISPATCH of the chunk that
-        # completes a request's prompt (``capture_dispatched``: behind that
-        # step the ring holds the prompt's trailing window) — the
-        # hybrid-APC section capture point.
-        self.prefill_complete_hook = None
-        # Called behind the dispatch of the prefill chunk that carries a
-        # request past the span it noted in ``Request.swa_capture`` (the
-        # ring then holds the window before any page boundary of the chunk).
-        self.prefill_passed_hook = None
+        # Ring engines: ``capture_hook(req, point)``, called behind the
+        # DISPATCH of the step that leaves ``req``'s per-sequence state at
+        # a capture point (``capture_dispatched``), where hybrid APC
+        # retains a copy of it: AT_PROMPT_END (the chunk that completes
+        # the prompt: behind it the ring holds the prompt's trailing
+        # window), AT_RUN_END (the chunk that carries the request past
+        # the span it noted in ``Request.swa_capture``; the ring then holds
+        # the window before any page boundary of the chunk) and AT_FINISH
+        # (the decode step that fills the last page before a finish by
+        # length: ``_finish_boundary``).
+        self.capture_hook = None
         # Ring engines: the hybrid prefix hit, taken at admission
         # (_apply_prefix_cache); fills the request's pages, ring and
         # computed count like a locally-sourced preload, or notes the miss.
@@ -487,6 +492,28 @@ class EngineScheduler:
         page = self.cache_config.page_size
         return (req.num_prompt_tokens - 1) // page * page
 
+    def _finish_boundary(self, req: Request) -> int:
+        """The last page boundary ``req`` fills before the finish that its
+        admission foresees, in tokens, or 0 where a state retained there
+        could serve nobody. A request ends by LENGTH at the latest: its
+        state then covers ``end`` tokens (the last sampled token is never
+        fed). The boundary is worth a capture where it lies behind the
+        prompt's own (which is captured anyway) and a continuation still
+        fits the model: the ``end + 1`` tokens and one more are a prompt,
+        which has to be shorter than the model length. A sequence that has
+        filled the model cannot grow, so whoever sends it again sends a
+        prompt this boundary is not in (a resident sequence comes back with
+        its own prompt), and the entry would only push another's out. A
+        stop token may end the request sooner; that is not foreseen, and
+        the prompt's-end entry serves the next turn."""
+        page = self.cache_config.page_size
+        left = req.sampling.max_tokens - req.num_prior_output_tokens
+        end = min(req.num_prompt_tokens + left, self.max_model_len) - 1
+        at = end // page * page
+        if at > self._prompt_boundary(req) and end + 2 < self.max_model_len:
+            return at
+        return 0
+
     def _snapshot_boundaries(self, req: Request) -> tuple[int, ...]:
         """The token counts a state-space sequence's prefill must STAND at
         for a snapshot to be taken: its prompt's last full page, and the end
@@ -514,6 +541,8 @@ class EngineScheduler:
 
     def _note_admitted(self, req: Request) -> None:
         req.status = RequestStatus.RUNNING
+        if self.capture_hook is not None:
+            req.finish_capture_at = self._finish_boundary(req)
         if req.queue_wait_ms is None:
             req.queue_wait_ms = (time.monotonic() - req.arrival_time) * 1e3
             self.queue_wait_ms += req.queue_wait_ms
@@ -846,15 +875,18 @@ class EngineScheduler:
             self.protected.add(seq.request.request_id)
 
     def capture_dispatched(self, batch: ScheduledBatch) -> None:
-        """Call the capture hooks for the prefill rows of ``batch``, which
-        has JUST been dispatched, whose chunk leaves the per-sequence state
-        at a capture boundary. The device runs programs in dispatch order
-        and a dispatched step is never rolled back (in-flight rows are
-        protected, their aborts deferred), so the copy a hook dispatches
-        now runs behind the step that writes the state and in front of the
-        next one, which overwrites it: the order it had when the hooks
-        fired at the step's commit, at no cost to the host's turn."""
-        if self.prefill_complete_hook is None:
+        """Call the capture hook for the rows of ``batch``, which has JUST
+        been dispatched, that the step leaves with their per-sequence state
+        at a capture point: a prefill row at its prompt's end or at the end
+        of the run it was refused at, a decode row at the last page it
+        fills before its foreseen finish. The device runs programs in
+        dispatch order and a dispatched step is never rolled back
+        (in-flight rows are protected, their aborts deferred), so the copy
+        the hook dispatches now runs behind the step that writes the state
+        and in front of the next one, which overwrites it: the order it had
+        when the hooks fired at the step's commit, at no cost to the host's
+        turn."""
+        if self.capture_hook is None:
             return
         page = self.cache_config.page_size
         for seq in batch.prefills:
@@ -865,16 +897,24 @@ class EngineScheduler:
                 # A recurrent state is the run's only AT its end (the chunk
                 # was cut there); one that has passed it is dropped.
                 if not self.state_aligned or at == run_end:
-                    self.prefill_passed_hook(req)
+                    self.capture_hook(req, AT_RUN_END)
                 req.swa_capture = None
             if self.state_aligned:
                 # The state stands at the prompt's last full page.
                 if at == self._prompt_boundary(req):
-                    self.prefill_complete_hook(req)
+                    self.capture_hook(req, AT_PROMPT_END)
             elif req.in_decode_dispatched:
                 # The chunk completes the prompt: behind it the ring holds
                 # the prompt's trailing window.
-                self.prefill_complete_hook(req)
+                self.capture_hook(req, AT_PROMPT_END)
+        for seq in batch.decodes:
+            # One token a step: the row lands ON the boundary with its
+            # input in the host's hands (a fused window's tokens are not,
+            # a speculative row's may be taken back), ring and slot alike.
+            req = seq.request
+            at = req.finish_capture_at  # (0: none, the one test of most rows)
+            if at and seq.num_tokens == 1 and req.num_dispatched_tokens == at:
+                self.capture_hook(req, AT_FINISH)
 
     def _commit_pending(self, seq: ScheduledSeq) -> None:
         req = seq.request

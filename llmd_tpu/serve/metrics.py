@@ -247,9 +247,11 @@ def render_metrics(
     if stats.swa_ring_pages or stats.state_bytes_in_use_total:
         # Retained-state captures of either kind: those that hashed their
         # prompt again (0 where every request was hashed at its admission),
-        # and their host time, spent behind a step's dispatch.
+        # their host time, spent behind a step's dispatch, and those taken
+        # at a sequence's last page before its foreseen finish.
         counters["retained_capture_rehashed_total"] = stats.retained_capture_rehashed_total
         counters["retained_capture_host_ms_total"] = round(stats.retained_capture_host_ms_total, 3)
+        counters["retained_finish_captures_total"] = stats.retained_finish_captures_total
     lines: list[str] = []
     if stats.kv_transfer_failures:
         # Per-(stage, policy) transfer-failure breakdown (llmd-family

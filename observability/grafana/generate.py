@@ -275,13 +275,17 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                    "nothing; evictions at the rate of captures = the "
                    "retained-state cache is too small for the sessions "
                    "it serves (every turn leaves one snapshot behind)."),
-        panel("Retained-state capture: host ms, and prompts hashed again",
+        panel("Retained-state capture: host ms, prompts hashed again, "
+              "captures at a foreseen finish",
               [f"rate(llmd:retained_capture_host_ms_total{M}[5m]) / "
                f"(rate(llmd:state_snapshot_captures_total{M}[5m]) + "
                f"rate(llmd:swa_section_captures_total{M}[5m]))",
-               f"rate(llmd:retained_capture_rehashed_total{M}[5m])"],
+               f"rate(llmd:retained_capture_rehashed_total{M}[5m])",
+               f"rate(llmd:retained_finish_captures_total{M}[5m]) / "
+               f"rate(llmd:request_success_total{M}[5m])"],
               legends=["host ms a capture", "captures that hashed their "
-                       "prompt again /s"],
+                       "prompt again /s", "captures at a sequence's last "
+                       "page, a finished request"],
               desc="A ring's sections and a state pool's snapshots alike. "
                    "A capture's host work (key, eviction, allocation, two "
                    "index puts, the copy's dispatch) runs behind the "
@@ -290,7 +294,14 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                    "the admission's hash walk; a capture that hashed its "
                    "prompt again (a P/D preload, a request the pager "
                    "resumed) costs ~3 us a page of context on the host: "
-                   "0 for session traffic."),
+                   "0 for session traffic. A request whose end by length "
+                   "is foreseen leaves its state behind at the last page "
+                   "it fills, so its session's next turn does not prefill "
+                   "its own last answer: ~1 a finished request for "
+                   "sessions that end by max_tokens, 0 for sequences that "
+                   "fill the model and restart, low where answers end on "
+                   "a stop token (then each turn prefills the last answer "
+                   "again from its prompt's end)."),
         panel("State-space work /s",
               [f"rate(llmd:ssm_update_rows_total{M}[5m]) + "
                f"rate(llmd:gdn_update_rows_total{M}[5m])",

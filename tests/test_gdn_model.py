@@ -33,6 +33,7 @@ from llmd_tpu.serve.metrics import render_metrics  # noqa: E402
 from perfbench.references import _common as rc  # noqa: E402
 from perfbench.references import gdn_gqa_gated_moe_share as ref  # noqa: E402
 from perfbench.topologies import engine_gdn  # noqa: E402
+from tests import retained_state  # noqa: E402
 
 CONF = json.loads((ROOT / "perfbench" / "configs" / "qwen3-next-80b-a3b.1chip.json").read_text())
 PUBLISHED = CONF["rehearse"]["published"]  # what the benchmark's rehearsal hands the reference
@@ -196,9 +197,9 @@ def test_a_snapshot_hit_equals_cold_and_chunks_share_their_steps():
     shared = tokens(40, seed=5)
     a, b, c = (shared + tokens(n, seed=s) for n, s in ((7, 6), (13, 7), (11, 8)))
     (_t, _l, req), = greedy(eng, [a])
-    assert snapshots(eng) == (0, 0, 1) and req.num_cached_tokens == 0
+    assert snapshots(eng) == (0, 0, 2) and req.num_cached_tokens == 0  # a's prompt end, and the last page its answer fills
     (toks, lps, req), = greedy(eng, [b])
-    assert snapshots(eng) == (0, 1, 3) and req.num_cached_tokens == 0
+    assert snapshots(eng) == (0, 1, 5) and req.num_cached_tokens == 0  # the run's end, b's own end, its answer's last page
     assert_matches_reference(eng, b, toks, lps)
     eng.add_request(tokens(9, seed=9), SamplingParams(max_tokens=30, temperature=0.0, ignore_eos=True))
     for _ in range(3):  # the other decodes while c's chunk comes
@@ -212,6 +213,12 @@ def test_a_snapshot_hit_equals_cold_and_chunks_share_their_steps():
     assert eng.stats.steps_mixed_total > mixed
     # == the same request served cold and alone: the reference has no cache
     assert_matches_reference(eng, c, toks, np.asarray(req.output_logprobs))
+
+
+def test_the_snapshot_at_a_sequences_last_page_is_the_references_state_there():
+    """The delta-rule slot: ``tests/retained_state.py``."""
+    retained_state.check_the_snapshot_at_a_sequences_last_page(
+        make_engine(max_batched=16), greedy, ref, PUBLISHED, assert_matches_reference)
 
 
 # --- the layers, each against a hand-written case ------------------------------

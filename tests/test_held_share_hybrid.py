@@ -147,10 +147,11 @@ def test_sections_are_sized_from_the_sequences_and_the_dead_go_first():
 
 
 def test_a_spent_prompt_end_goes_before_a_shared_prefix():
-    """Eviction order among live sections: a prompt's-end section that has
-    served its hit, then any prompt's end, then a shared prefix's (captured
-    on demand after a miss), however recently each was used."""
-    eng = make_engine()
+    """Eviction order among live sections: a session's own section (at a
+    prompt's end, or at the last page an answer filled) that has served its
+    hit, then any of a session's own, then a shared prefix's (captured on
+    demand after a miss), however recently each was used."""
+    eng = make_engine(max_seqs=6)  # (room for the nine sections the four sequences leave)
     cache = eng._swa_sections
     shared = tokens(4 * WINDOW, seed=41)
     a, b, c = (shared + tokens(n, seed=s) for n, s in ((9, 42), (13, 43), (11, 44)))
@@ -158,9 +159,9 @@ def test_a_spent_prompt_end_goes_before_a_shared_prefix():
     greedy(eng, [b])  # a miss: the shared prefix's section is captured on demand
     (toks, _lps, _r), = greedy(eng, [c])  # hits it
     nxt = c + toks + tokens(5, seed=45)
-    greedy(eng, [nxt])  # hits c's prompt end, which is spent now
+    greedy(eng, [nxt])  # hits the section c's answer left at the last page it filled, which is spent now
     key_shared = eng._section_key(shared + [0], b"")[0]  # the chain hash of the shared pages
-    key_c = eng._section_key(c, b"")[0]
+    key_c = eng._section_key(c + toks, b"")[0]
     kinds = {k: (e.shared, e.hits) for k, e in cache._entries.items()}
     assert kinds[key_shared] == (True, 1) and kinds[key_c] == (False, 1)
     assert cache.evict_one() and not cache.has(key_c)  # spent: first

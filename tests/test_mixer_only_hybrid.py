@@ -213,7 +213,7 @@ def test_a_snapshot_miss_then_hits_then_a_restart_at_the_model_length():
     shared = tokens(40, seed=5)
     a, b, c = (shared + tokens(n, seed=s) for n, s in ((7, 6), (13, 7), (11, 8)))
     (_t, _l, req), = greedy(eng, [a])
-    assert snapshots(eng) == (0, 0, 1) and req.num_cached_tokens == 0
+    assert snapshots(eng) == (0, 0, 2) and req.num_cached_tokens == 0  # a's prompt end, and the last page its answer fills
     (toks, lps, req), = greedy(eng, [b])
     assert snapshots(eng)[:2] == (0, 1) and req.num_cached_tokens == 0  # a MISS that leaves the snapshot
     assert_matches_reference(eng, b, toks, lps)
@@ -226,9 +226,11 @@ def test_a_snapshot_miss_then_hits_then_a_restart_at_the_model_length():
     (toks, lps, req), = greedy(eng, [ctx], max_tokens=room)
     assert len(toks) == room and req.num_cached_tokens == 0
     assert_matches_reference(eng, ctx, toks, lps)
-    hits = snapshots(eng)[0]
+    hits, _, captures = snapshots(eng)
     (toks2, lps2, req), = greedy(eng, [ctx], max_tokens=room)
     assert snapshots(eng)[0] == hits + 1 and req.num_cached_tokens == (len(ctx) - 1) // PAGE * PAGE
+    # (a sequence that fills the model leaves nothing at its last page: no prompt goes on from there)
+    assert snapshots(eng)[2] == captures and eng.stats.retained_finish_captures_total == 3
     assert toks2 == toks
     assert_matches_reference(eng, ctx, toks2, lps2)
 

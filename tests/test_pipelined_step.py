@@ -15,6 +15,7 @@ ladder, one dispatch a bucket.
 """
 
 import asyncio
+import dataclasses
 import pathlib
 import statistics
 import sys
@@ -35,10 +36,12 @@ from llmd_tpu.config import (  # noqa: E402
 from llmd_tpu.engine import LLMEngine, SamplingParams  # noqa: E402
 from llmd_tpu.engine import runner as runner_mod  # noqa: E402
 from llmd_tpu.engine.request import PriorityClass, RequestStatus  # noqa: E402
+from llmd_tpu.engine.scheduler import AT_FINISH, AT_PROMPT_END, AT_RUN_END  # noqa: E402
 from llmd_tpu.models.registry import get_model_config  # noqa: E402
 from llmd_tpu.obs import profiling  # noqa: E402
 from llmd_tpu.serve.metrics import parse_prometheus, render_metrics  # noqa: E402
 from tests.host_trace import host_spans  # noqa: E402
+from tests.retained_state import ANSWER, FIRST, MORE  # noqa: E402
 
 
 def tokens(n, seed=0):
@@ -56,16 +59,22 @@ GEOMETRY = {
     "tiny-dsa": (dict(page_size=8, num_blocks=128), dict(max_num_batched_tokens=48), 112),
     # latent attention: the bucketed unified step, not the flat one
     "tiny-mla": (dict(page_size=4, num_blocks=256), dict(max_num_batched_tokens=32), 48),
+    # gated delta-rule mixers: the state pool's other kind of slot
+    "tiny-qwen3-next": (dict(page_size=4, num_blocks=256), dict(max_num_batched_tokens=16), 40),
 }
-HYBRID = sorted(set(GEOMETRY) - {"tiny"})
+HYBRID = sorted(set(GEOMETRY) - {"tiny", "tiny-qwen3-next"})
 
 
-def make_engine(model: str, pipelined: bool, max_seqs=4, num_blocks=None, **sched) -> LLMEngine:
+def make_engine(model: str, pipelined: bool, max_seqs=4, num_blocks=None, model_len=None, cache_kw=None, **sched) -> LLMEngine:
     cache, sched_kw, _ = GEOMETRY[model]
+    cache = {**cache, **(cache_kw or {})}
     if num_blocks is not None:
         cache = {**cache, "num_blocks": num_blocks}
+    model_cfg = get_model_config(model)
+    if model_len is not None:
+        model_cfg = dataclasses.replace(model_cfg, max_model_len=model_len)
     return LLMEngine(EngineConfig(
-        model=get_model_config(model),
+        model=model_cfg,
         cache=CacheConfig(dtype="float32", **cache),
         scheduler=SchedulerConfig(
             max_num_seqs=max_seqs, **{**sched_kw, **sched}
@@ -194,11 +203,13 @@ class Recorder:
             if name.startswith("dispatch_"):
                 setattr(r, name, dispatching(getattr(r, name)))
         copy, commit = r.copy_pages_on_device, eng.scheduler.update_after_step
-        capture, section = eng._swa_sections.capture, eng._capture_section
-        in_capture = [False]
+        capture, hook = eng._swa_sections.capture, eng.scheduler.capture_hook
+        at = [None]  # the capture point of the hook that is running
+        # per hook call: (point, request, its dispatched position, walks, pages hashed)
+        self.hooked = []
 
         def copying(src, dst, swa=False):
-            self.events.append("capture-copy" if in_capture[0] else "seed-copy")
+            self.events.append("capture-copy" if at[0] else "seed-copy")
             return copy(src, dst, swa=swa)
 
         def committing(batch, sampled):
@@ -207,20 +218,29 @@ class Recorder:
 
         def capturing(key, ring_ids, s0, n_pre, shared=False):
             self.captured.append((key, n_pre, shared))
+            self.captured_at.append(at[0])
             return capture(key, ring_ids, s0, n_pre, shared=shared)
 
-        def sectioning(req, passed):
-            before, in_capture[0] = (len(self.walks), self.pages_hashed), True
+        def hooking(req, point):
+            before, at[0] = (len(self.walks), self.pages_hashed), point
             try:
-                return section(req, passed)
+                return hook(req, point)
             finally:
-                in_capture[0] = False
-                self.hashed_in_a_capture += (len(self.walks) - before[0]) + (self.pages_hashed - before[1])
+                at[0] = None
+                cost = (len(self.walks) - before[0], self.pages_hashed - before[1])
+                self.hooked.append((point, req, req.num_dispatched_tokens, *cost))
+                if point != AT_FINISH:
+                    self.hashed_in_a_capture += sum(cost)
 
+        self.captured_at = []
         r.copy_pages_on_device = copying
         eng.scheduler.update_after_step = committing
         eng._swa_sections.capture = capturing
-        eng._capture_section = sectioning
+        eng.scheduler.capture_hook = hooking
+
+    def own(self, point=AT_PROMPT_END):
+        """The (key, n_pre, shared) captured at ``point``."""
+        return [c for c, p in zip(self.captured, self.captured_at) if p == point]
 
 
 def turns(eng: LLMEngine, n_turns=3, first=37, more=9, max_tokens=6, seed=40):
@@ -257,11 +277,12 @@ def test_a_capture_walks_no_prompt_and_hashes_no_page(model, pipelined, monkeypa
     rec = Recorder(eng, monkeypatch)
     page = eng.config.cache.page_size
     session = turns(eng)
-    own = [c for c in rec.captured if not c[2]]
-    assert len(own) == len(session) == 3 and rec.hashed_in_a_capture == 0
+    assert len(rec.own()) == len(rec.own(AT_FINISH)) == len(session) == 3 and rec.hashed_in_a_capture == 0
+    # (the capture at a sequence's last page hashes that ONE page onto the commit chain's tail)
+    assert [(w, h) for point, _, _, w, h in rec.hooked if point == AT_FINISH] == [(0, 1)] * 3
     assert rec.walks == [(len(p) - 1) // page * page for p, _, _ in session]
     chain = sum((len(p) + len(t) - 1) // page - r.num_cached_tokens // page for p, t, r in session)
-    assert rec.pages_hashed == sum(w // page for w in rec.walks) + chain
+    assert rec.pages_hashed == sum(w // page for w in rec.walks) + chain + 3
     eng._refresh_gauges()
     assert eng.stats.retained_capture_rehashed_total == 0
     assert eng.stats.retained_capture_host_ms_total > 0
@@ -269,7 +290,7 @@ def test_a_capture_walks_no_prompt_and_hashes_no_page(model, pipelined, monkeypa
     for family in ("vllm", "llmd"):
         assert page_of_metrics[f"{family}:retained_capture_rehashed_total"] == 0
         assert page_of_metrics[f"{family}:retained_capture_host_ms_total"] > 0
-    assert [r.num_cached_tokens for _, _, r in session[1:]] == [(len(p) - 1) // page * page for p, _, _ in session[:-1]]
+    assert [r.num_cached_tokens for _, _, r in session[1:]] == [(len(p) + len(t) - 1) // page * page for p, t, _ in session[:-1]]
 
 
 @pytest.mark.parametrize("model", RETAINING)
@@ -288,14 +309,16 @@ def test_a_captured_key_is_the_section_key_of_its_prompt(model, extra, monkeypat
             forget_the_admissions_key(eng)
         page = eng.config.cache.page_size
         session = turns(eng)
-        own = [c for c in rec.captured if not c[2]]
+        own = rec.own()
         assert [k for k, _, _ in own] == [eng._section_key(p, extra)[0] for p, _, _ in session]
+        # (and the one at a sequence's last page that of a prompt that goes on there)
+        assert [k for k, _, _ in rec.own(AT_FINISH)] == [eng._section_key(p + t, extra)[0] for p, t, _ in session]
         assert [n for _, n, _ in own] == [(len(p) - 1) // page for p, _, _ in session]
         if extra:
             assert own[0][0] != eng._section_key(session[0][0], b"")[0]
         assert eng._swa_sections.rehashed == (3 if parents_path else 0)
         cached[parents_path] = [r.num_cached_tokens for _, _, r in session]
-    assert cached[False] == cached[True] == [0] + [(len(p) - 1) // page * page for p, _, _ in session[:-1]]
+    assert cached[False] == cached[True] == [0] + [(len(p) + len(t) - 1) // page * page for p, t, _ in session[:-1]]
 
 
 @pytest.mark.parametrize("model", RETAINING)
@@ -320,7 +343,7 @@ def test_a_captures_copy_is_dispatched_behind_its_step_and_outside_the_commit(mo
         profiling.stop()
     ev = rec.events
     copies = [i for i, e in enumerate(ev) if e == "capture-copy"]
-    assert len(copies) == len(rec.captured) >= 5 and any(c[2] for c in rec.captured)
+    assert len(copies) == len(rec.captured) >= 5 and {AT_RUN_END, AT_PROMPT_END, AT_FINISH} == set(rec.captured_at)
     for i in copies:
         before = next(e for e in reversed(ev[:i]) if e != "capture-copy")
         after = next(e for e in ev[i + 1:] if e != "capture-copy")
@@ -367,6 +390,166 @@ def test_a_session_reads_the_same_with_the_key_kept_as_with_the_prompt_walked(mo
     for a, b in zip(new[1], parent[1]):
         np.testing.assert_array_equal(a, b)
     assert all(c > 0 for c in new[2][1:])
+
+
+# --- a sequence leaves its retained state behind at its last page before a foreseen finish
+
+
+KINDS = RETAINING + ["tiny-qwen3-next"]  # a ring's sections, a Mamba-2 slot, a delta-rule slot
+BOTH_STEPS = pytest.mark.parametrize("pipelined", [True, False], ids=["pipelined", "synchronous"])
+
+
+def uncached(model: str, pipelined: bool, prompts, max_tokens: int):
+    """The same prompts one after the other, with prefix caching off."""
+    eng = make_engine(model, pipelined, cache_kw=dict(enable_prefix_caching=False))
+    assert eng._swa_sections is None
+    return [serve(eng, [p], max_tokens=max_tokens)[0] for p in prompts]
+
+
+def finish_captures(eng: LLMEngine) -> int:
+    eng._refresh_gauges()
+    return eng.stats.retained_finish_captures_total
+
+
+@pytest.mark.parametrize("model", KINDS)
+@BOTH_STEPS
+def test_a_sessions_next_turn_starts_behind_its_own_last_answer(model, pipelined, monkeypatch):
+    """Turn 2 is turn 1's prompt + answer + a question. It starts at the last
+    page turn 1's answer filled, not at turn 1's prompt's end, and reads,
+    tokens and log-probs, as a run with prefix caching off. The capture fired
+    once a turn, behind the dispatch of the decode step that left the state
+    AT that page (dispatch, copy, commit), under the key the next admission's
+    walk computes for it, for one page hashed and no walk."""
+    from llmd_tpu.engine.kv_cache import page_hashes_for_tokens
+
+    eng = make_engine(model, pipelined)
+    rec = Recorder(eng, monkeypatch)
+    page = eng.config.cache.page_size
+    (first, answer, r1), (second, answer2, r2) = turns(eng, n_turns=2, first=FIRST, more=MORE, max_tokens=ANSWER)
+    at = (FIRST + ANSWER - 1) // page * page
+    assert r2.num_cached_tokens == at == 44 > (FIRST - 1) // page * page
+    fired = [(req, pos, cost) for point, req, pos, *cost in rec.hooked if point == AT_FINISH]
+    assert fired == [(r1, at, [0, 1]), (r2, (len(second) + ANSWER - 1) // page * page, [0, 1])]
+    key, n_pre, shared = rec.own(AT_FINISH)[0]
+    assert (key, n_pre, shared) == (page_hashes_for_tokens(second, page)[at // page - 1], at // page, False)
+    ev = rec.events
+    for i in (i for i, e in enumerate(ev) if e == "capture-copy"):
+        around = (next(e for e in reversed(ev[:i]) if e != "capture-copy"), next(e for e in ev[i + 1:] if e != "capture-copy"))
+        assert around == ("dispatch", "commit"), ev[max(0, i - 3): i + 3]
+    for (toks, lps, _), got, req in zip(uncached(model, pipelined, [first, second], ANSWER), (answer, answer2), (r1, r2)):
+        assert got == toks
+        np.testing.assert_allclose(np.asarray(req.output_logprobs), lps, atol=2e-5)
+    assert finish_captures(eng) == 2 and eng.stats.retained_capture_rehashed_total == 0
+    page_of_metrics = parse_prometheus(render_metrics(eng.stats, model))
+    assert page_of_metrics["vllm:retained_finish_captures_total"] == page_of_metrics["llmd:retained_finish_captures_total"] == 2
+
+
+NO_FORESEEN_FINISH = {
+    # fed through 38: no page past the prompt's own (36)
+    "an_answer_under_a_page": dict(max_tokens=3),
+    # 37 + 26 tokens and one more are no prompt of a model of 64 (a resident-decode sequence stops there)
+    "a_sequence_that_fills_the_model": dict(max_tokens=26, model_len=64),
+    # ends at its fourth token, before the page its length would have filled
+    "a_stop_token": dict(max_tokens=ANSWER, stop_at=3),
+}
+
+
+@pytest.mark.parametrize("model", KINDS)
+@pytest.mark.parametrize("case", NO_FORESEEN_FINISH)
+def test_no_capture_where_no_finish_is_foreseen_that_a_next_turn_could_use(model, case, monkeypatch):
+    """Nothing fires; the sequence that comes back (the turn's continuation,
+    or the same prompt again where the model is full) hits the entry at the
+    prompt's end, as before, and reads as a run with prefix caching off."""
+    kw = dict(NO_FORESEEN_FINISH[case])
+    stop_at, max_tokens = kw.pop("stop_at", None), kw.pop("max_tokens")
+    first = tokens(FIRST, seed=40)
+    stop = ()
+    if stop_at is not None:
+        (free, _, _), = serve(make_engine(model, True, **kw), [first], max_tokens=max_tokens)
+        stop = (free[stop_at],)
+        assert stop[0] not in free[:stop_at]
+    eng = make_engine(model, True, **kw)
+    rec = Recorder(eng, monkeypatch)
+    page = eng.config.cache.page_size
+    (answer, _, r1), = serve(eng, [first], max_tokens=max_tokens, stop=stop)
+    assert len(answer) == (max_tokens if stop_at is None else stop_at + 1)
+    assert AT_FINISH not in [point for point, *_ in rec.hooked] and finish_captures(eng) == 0
+    again = first if "model_len" in kw else first + answer + tokens(MORE, seed=41)
+    (toks, lps, r2), = serve(eng, [again], max_tokens=3)
+    assert r2.num_cached_tokens == (FIRST - 1) // page * page and hit_counters(eng) in ((1, 0, 0, 0), (0, 0, 1, 0))
+    ref_eng = make_engine(model, True, cache_kw=dict(enable_prefix_caching=False), **kw)
+    (want, want_lps, _), = serve(ref_eng, [again], max_tokens=3)
+    assert toks == want
+    np.testing.assert_allclose(lps, want_lps, atol=2e-5)
+
+
+@pytest.mark.parametrize("model", KINDS)
+def test_resident_sequences_that_restart_hit_their_prompts_end_with_the_cache_at_capacity(model):
+    """As many retained entries as sequences, each run to the model length
+    and sent again with its own prompt (a resident-decode cell): a capture at
+    the last page could serve nobody and would evict another sequence's
+    prompt's-end entry, a whole prefill a miss. None is taken, every restart
+    hits, nothing is evicted."""
+    eng = make_engine(model, True, max_seqs=2, model_len=64, cache_kw=dict(swa_sections=2))
+    page = eng.config.cache.page_size
+    prompts = [tokens(37, seed=50), tokens(45, seed=51)]
+    # (the resident-decode generator's rule: up to one token short of the model length)
+    sp = [SamplingParams(max_tokens=64 - len(p) - 1, temperature=0.0, ignore_eos=True) for p in prompts]
+    for _ in range(3):
+        reqs = []
+        for p, s in zip(prompts, sp):
+            eng.add_request(list(p), s)
+            reqs.append(eng.scheduler.waiting[-1])
+        while eng.has_work():
+            eng.step()
+        assert [r.num_tokens for r in reqs] == [63, 63]
+    assert [r.num_cached_tokens for r in reqs] == [(len(p) - 1) // page * page for p in prompts]
+    kept = eng._swa_sections
+    assert finish_captures(eng) == 0 and (kept.hits, kept.misses, kept.evictions, kept.captures) == (4, 0, 0, 2)
+    assert len(kept._entries) == kept.capacity == 2
+
+
+@pytest.mark.parametrize("model", KINDS)
+@BOTH_STEPS
+def test_an_aborted_row_leaves_a_whole_entry_or_none(model, pipelined, monkeypatch):
+    """Aborted while the step that fills its last page is in flight: the
+    abort waits for that step's commit, which registers the page the entry
+    is keyed by, so the entry is whole (a prompt that goes on there hits it
+    and reads as uncached) and the cache's pages are the pool's. A copy that
+    fails makes no entry and gives its pages back."""
+    eng = make_engine(model, pipelined)
+    rec = Recorder(eng, monkeypatch)
+    kept, page = eng._swa_sections, eng.config.cache.page_size
+    first = tokens(FIRST, seed=60)
+    sp = SamplingParams(max_tokens=ANSWER, temperature=0.0, ignore_eos=True)
+    rid, out = eng.add_request(list(first), sp), []
+    while not any(point == AT_FINISH for point, *_ in rec.hooked):
+        out += [t for o in eng.step() for t in o.new_token_ids]
+    req = rec.hooked[-1][1]
+    assert eng.abort_request(rid)
+    while eng.has_work():
+        out += [t for o in eng.step() for t in o.new_token_ids]
+    at = (FIRST + ANSWER - 1) // page * page
+    assert req.finish_reason.name == "ABORT" and req.num_computed_tokens <= at + 1
+    assert kept.retained_pages == sum(len(e.pages) for e in kept._entries.values()) == pools_in_use(eng)[1]
+    assert finish_captures(eng) == 1 and all(eng.allocator.has_cached(k) for k in kept._entries)
+    goes_on = first + list(req.output_token_ids)[: at - FIRST] + tokens(MORE, seed=61)
+    (toks, lps, r2), = serve(eng, [goes_on], max_tokens=4)
+    assert r2.num_cached_tokens == at
+    (want, want_lps, _), = uncached(model, pipelined, [goes_on], 4)
+    assert toks == want
+    np.testing.assert_allclose(lps, want_lps, atol=2e-5)
+    # a copy that fails: no entry, its pages refunded, serving unaffected
+    copy, before = eng.runner.copy_pages_on_device, (kept.retained_pages, len(kept._entries), kept.captures)
+
+    def failing(src, dst, swa=False):
+        raise RuntimeError("the device refused the copy")
+
+    eng.runner.copy_pages_on_device = failing
+    (toks, _, _), = serve(eng, [tokens(FIRST, seed=62)], max_tokens=ANSWER)
+    eng.runner.copy_pages_on_device = copy
+    assert len(toks) == ANSWER and (kept.retained_pages, len(kept._entries), kept.captures) == before
+    assert kept.retained_pages == pools_in_use(eng)[1]
 
 
 # --- the top-up admission ----------------------------------------------------------

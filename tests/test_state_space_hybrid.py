@@ -31,6 +31,7 @@ from llmd_tpu.ops import ssm  # noqa: E402
 from perfbench.references import _common as rc  # noqa: E402
 from perfbench.references import mamba2_gqa_moe_share as ref  # noqa: E402
 from perfbench.topologies import engine_state  # noqa: E402
+from tests import retained_state  # noqa: E402
 
 CONF_FILE = ROOT / "perfbench" / "configs" / "granite-4.0-h-small.1chip.json"
 CONF = json.loads(CONF_FILE.read_text())
@@ -256,17 +257,17 @@ def test_a_snapshot_hit_equals_cold_and_an_evicted_snapshot_is_a_plain_prefill()
     shared = tokens(40, seed=5)
     a, b, c, d = (shared + tokens(n, seed=s) for n, s in ((7, 6), (13, 7), (11, 8), (9, 9)))
     (_t, _l, req), = greedy(eng, [a])
-    assert snapshots(eng) == (0, 0, 1) and req.num_cached_tokens == 0
+    assert snapshots(eng) == (0, 0, 2) and req.num_cached_tokens == 0  # a's prompt end, and the last page its answer fills
     (toks, lps, req), = greedy(eng, [b])
-    assert snapshots(eng) == (0, 1, 3) and req.num_cached_tokens == 0  # the run's end, and b's own end
+    assert snapshots(eng) == (0, 1, 5) and req.num_cached_tokens == 0  # the run's end, b's own end, its answer's last page
     assert_matches_reference(eng, b, toks, lps)
     (toks, lps, req), = greedy(eng, [c])
     assert snapshots(eng)[:2] == (1, 1) and req.num_cached_tokens == len(shared)
     assert_matches_reference(eng, c, toks, lps)  # == the same request served cold: the reference has no cache
-    # a session's next turn hits the snapshot its own prompt left behind
+    # a session's next turn hits the snapshot its own answer left behind at the last page it filled
     nxt = c + toks + tokens(5, seed=10)
     (toks2, lps2, req), = greedy(eng, [nxt])
-    assert snapshots(eng)[0] == 2 and req.num_cached_tokens == (len(c) - 1) // PAGE * PAGE
+    assert snapshots(eng)[0] == 2 and req.num_cached_tokens == (len(c) + len(toks) - 1) // PAGE * PAGE
     assert_matches_reference(eng, nxt, toks2, lps2)
     # every snapshot evicted: the pages alone serve no hit
     while eng._swa_sections.evict_one():
@@ -276,6 +277,12 @@ def test_a_snapshot_hit_equals_cold_and_an_evicted_snapshot_is_a_plain_prefill()
     (toks, lps, req), = greedy(eng, [d])
     assert req.num_cached_tokens == 0 and snapshots(eng)[:2] == (2, 2)
     assert_matches_reference(eng, d, toks, lps)
+
+
+def test_the_snapshot_at_a_sequences_last_page_is_the_references_state_there():
+    """The Mamba-2 slot: ``tests/retained_state.py``."""
+    retained_state.check_the_snapshot_at_a_sequences_last_page(
+        make_engine(max_batched=16), greedy, ref, PUBLISHED, assert_matches_reference)
 
 
 def test_preemption_and_resume_match_the_reference():

@@ -260,6 +260,36 @@ def test_hybrid_prefix_cache_hits_under_ring():
         eng.close()
 
 
+def test_a_sessions_next_turn_seeds_its_ring_at_the_last_page_its_answer_filled():
+    """A sequence leaves TWO sections behind: the window before its prompt's
+    last full page and, copied behind the decode step that fills it, the
+    window before the last page of its answer (the ring has moved on by the
+    time it ends). The session's next turn, prompt + answer + question,
+    seeds its ring from the second and skips its own last answer; greedy
+    parity with a cold engine is the witness that the window is the right
+    one. An answer that fills no page past the prompt's leaves one."""
+    eng, cold = _make_engine(ALTERNATING, True), _make_engine(ALTERNATING, True)
+    try:
+        prompt = [(31 * i + 6) % 47 for i in range(21)]  # last full page: 20
+        answer, f1 = _pd_run(eng, prompt, max_tokens=14)  # fed through 33: the last page filled is 32
+        assert f1.num_cached_tokens == 0
+        # each the window (8) before its boundary, and the page it straddles
+        assert sorted((e.s0, e.n_pre) for e in eng._swa_sections._entries.values()) == [(3, 5), (6, 8)]
+        nxt = prompt + answer + [5, 4, 3, 2, 1]
+        second, f2 = _pd_run(eng, nxt, max_tokens=6)
+        assert f2.num_cached_tokens == 32 and eng._swa_sections.hits == 1
+        ref, _ = _pd_run(cold, nxt, max_tokens=6)
+        assert second == ref
+        eng._refresh_gauges()
+        assert eng.stats.retained_finish_captures_total == 2
+        _pd_run(eng, [7] + prompt, max_tokens=2)  # fed through 22: no page past the prompt's own (20)
+        eng._refresh_gauges()
+        assert eng.stats.retained_finish_captures_total == 2 and eng._swa_sections.captures == 5
+    finally:
+        eng.close()
+        cold.close()
+
+
 def test_composition_gates():
     from llmd_tpu.config import OffloadConfig
 
