@@ -343,7 +343,6 @@ class ModelConfig:
                     "indexer_head_dim (HF sa_config)"
                 )
             for what, on in (
-                ("MLA", self.kv_lora_rank > 0),
                 ("sliding_window", self.sliding_window > 0),
                 ("attention_sinks", self.attention_sinks),
                 ("LoRA adapters", self.num_lora_adapters > 0),
@@ -354,6 +353,21 @@ class ModelConfig:
                         f"supported with {what}: that attention path would "
                         "silently attend past the indexer's selection"
                     )
+            # Over a latent cache (HF ``deepseek_v32``) the indexer's queries
+            # come from the normed query latent and it rotates
+            # ``qk_rope_head_dim`` of its dimensions (models/mla_dsa.py); such
+            # a model exists on the flat step only, which
+            # EngineConfig.check_sparse_attention holds it to.
+            if self.kv_lora_rank > 0 and not (
+                self.q_lora_rank > 0
+                and self.qk_rope_head_dim <= self.indexer_head_dim
+            ):
+                raise ValueError(
+                    "learned sparse attention over a latent cache takes its "
+                    "indexer's queries from the query latent (q_lora_rank > "
+                    "0) and rotates qk_rope_head_dim <= indexer_head_dim of "
+                    "their dimensions"
+                )
 
     def window_for_layer(self, i: int) -> int:
         """Attention window for layer ``i`` (0 = full attention)."""
@@ -491,6 +505,13 @@ class ModelConfig:
         """Learned sparse attention: an indexer picks the cached tokens a
         query token may read (``indexer_topk`` > 0)."""
         return self.indexer_topk > 0
+
+    @property
+    def indexer_rope_dim(self) -> int:
+        """The indexer's dimensions that RoPE rotates (the first ones, among
+        themselves): ``qk_rope_head_dim`` of them over a latent cache (HF
+        ``deepseek_v32``), the whole head over K and V (``sa_config``)."""
+        return self.qk_rope_head_dim if self.is_mla else self.indexer_head_dim
 
     @property
     def mla_latent_dim(self) -> int:

@@ -559,6 +559,12 @@ class EngineStats:
     sparse_unbound_tokens_total: int = 0
     indexer_keys_scored_total: int = 0
     indexer_keys_written_total: int = 0
+    # The same over a latent cache (models/mla_dsa.py; 0 elsewhere), each
+    # x the model's layers: the rows the sparse latent read selected (per
+    # computed token min(cached tokens, indexer_topk): what it must fetch
+    # and multiply) and the latent rows written (one a computed token).
+    sparse_rows_selected_total: int = 0
+    latent_rows_written_total: int = 0
     # Per-row verify depth histogram (speculative engines): index d
     # counts decode rows dispatched with a 1 + draft width of exactly d
     # tokens (backed-off rows: 1; hot-draft rows: up to 1 + spec_k).
@@ -2620,6 +2626,8 @@ class LLMEngine:
         self.stats.sparse_unbound_tokens_total = r.sparse_unbound_tokens_total
         self.stats.indexer_keys_scored_total = r.indexer_keys_scored_total
         self.stats.indexer_keys_written_total = r.indexer_keys_written_total
+        self.stats.sparse_rows_selected_total = r.sparse_rows_selected_total
+        self.stats.latent_rows_written_total = r.latent_rows_written_total
         self.stats.dispatches_per_emitted_token = round(
             self.stats.decode_dispatches_total
             / max(1, self.stats.generation_tokens),

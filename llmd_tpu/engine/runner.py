@@ -570,6 +570,11 @@ class ModelRunner:
         self.sparse_unbound_tokens_total = 0
         self.indexer_keys_scored_total = 0
         self.indexer_keys_written_total = 0
+        # The same over a latent cache (models/mla_dsa.py), x the layers:
+        # per computed token min(cached tokens, indexer_topk) selected rows,
+        # and one latent row written.
+        self.sparse_rows_selected_total = 0
+        self.latent_rows_written_total = 0
         # State-space layers (EngineStats fields of the same names): decode
         # rows updated and prefill tokens scanned, x the mixer layers.
         self.ssm_update_rows_total = 0
@@ -644,12 +649,16 @@ class ModelRunner:
         # waste is bounded by 15 tokens/step) replaces the bucketed
         # unified family's (rows x Q x T) cross-product; the row-
         # metadata width is FIXED at the largest row bucket (metadata is
-        # O(rows), not O(tokens) — a few KB). MLA keeps the bucketed
-        # layout (latent writes have their own addressing).
+        # O(rows), not O(tokens) — a few KB). Plain MLA keeps the
+        # bucketed layout (``mla.KIND`` writes and reads a row's whole
+        # context there); MLA with an indexer exists on the flat stream only
+        # (``mla_dsa.KIND``: ops/sparse_mla.py).
         self._flat = None
         self.flat_rows = 0
         self.flat_t_buckets: tuple[int, ...] = ()
-        if sched.unified_step and sched.ragged_qlens and not self.cfg.is_mla:
+        if sched.unified_step and sched.ragged_qlens and (
+            not self.cfg.is_mla or self.cfg.sparse_attention
+        ):
             limit = sched.max_num_batched_tokens + max(self.unified_s, 1)
             limit = -(-limit // 16) * 16
             self.flat_t_buckets = tuple(range(16, limit + 1, 16))
@@ -3223,6 +3232,13 @@ class ModelRunner:
                     bound * (lo + start + w + 1) // 2
                 )
                 self.indexer_keys_written_total += w
+                if self.cfg.is_mla:
+                    # Positions [start, lo) read position + 1 rows each.
+                    free = max(0, min(start + w, lo) - start)
+                    self.sparse_rows_selected_total += self.cfg.num_layers * (
+                        bound * sparse_topk + free * (2 * start + free + 1) // 2
+                    )
+                    self.latent_rows_written_total += self.cfg.num_layers * w
         self._overwrite_seeded_rows(a["seeds"], staged.row_seqs, staged.S)
         self.live_tokens_total += t
         if self.state_pool:

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from llmd_tpu.config import ModelConfig
-from llmd_tpu.models import attention, dsa, mla
+from llmd_tpu.models import attention, dsa, mla, mla_dsa
 from llmd_tpu.models.common import (
     LayerCtx, MixerKind, StepCtx, StepInput, norm_weight, param_dtype, pdot,
     rms_norm, rope_tables,
@@ -161,10 +161,10 @@ def mixer_kinds(cfg: ModelConfig) -> tuple[MixerKind, ...]:
     """Each layer's mixer kind. A model of one kind has it L times, over the
     shared stack (``stack == "layers"``: its leaves lie with the norms)."""
     if not cfg.state_space:
-        kind = (
-            mla.KIND if cfg.is_mla
-            else dsa.KIND if cfg.sparse_attention else attention.KIND
-        )
+        if cfg.is_mla:
+            kind = mla_dsa.KIND if cfg.sparse_attention else mla.KIND
+        else:
+            kind = dsa.KIND if cfg.sparse_attention else attention.KIND
         return (kind,) * cfg.num_layers
     if cfg.delta_rule:
         from llmd_tpu.models import gdn

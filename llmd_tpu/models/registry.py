@@ -472,6 +472,68 @@ def _deepseek_v2_lite() -> ModelConfig:
     )
 
 
+@register_model("deepseek-v3.2")
+def _deepseek_v32() -> ModelConfig:
+    """DeepSeek-V3.2 (HF deepseek-ai/DeepSeek-V3.2, ``model_type:
+    deepseek_v32``) as published: latent attention (q latent 1,536, cached
+    row 512 + 64) under yarn x 40 with its softmax temperature, the
+    lightning indexer of 64 heads x 128 over one key a token (queries from
+    the query latent, 64 of its 128 dimensions rotated) picking the 2,048
+    rows a query token reads (models/mla_dsa.py); first 3 layers dense, then
+    256 experts top-8 in 8 groups of which 4 are kept, sigmoid scores with a
+    selection-only bias, normalised, x 2.5, and one shared expert. The one
+    multi-token-prediction module is not served (ROADMAP M6); the latent
+    and the indexer key are cached in the served dtype, not FP8. A rank of
+    an expert-parallel deployment overrides ``held_experts`` /
+    ``held_experts_first`` (docs/architecture/wide-ep.md)."""
+    return ModelConfig(
+        name="deepseek-v3.2", vocab_size=129280, hidden_size=7168,
+        intermediate_size=18432, num_layers=61, num_heads=128,
+        num_kv_heads=128, rope_theta=10000.0, max_model_len=163840,
+        rms_norm_eps=1e-6,
+        rope_scaling={
+            "type": "yarn", "factor": 40, "beta_fast": 32, "beta_slow": 1,
+            "mscale": 1, "mscale_all_dim": 1,
+            "original_max_position_embeddings": 4096,
+        },
+        kv_lora_rank=512, q_lora_rank=1536,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        indexer_topk=2048, indexer_num_heads=64, indexer_head_dim=128,
+        num_experts=256, num_experts_per_tok=8, moe_intermediate_size=2048,
+        shared_expert_intermediate_size=2048, first_dense_layers=3,
+        router_scoring="sigmoid", topk_method="group_top2",
+        n_group=8, topk_group=4, norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+    )
+
+
+@register_model("tiny-mla-dsa")
+def _tiny_mla_dsa() -> ModelConfig:
+    """DeepSeek-V3.2's architecture in miniature (CPU tests and the
+    benchmark's rehearsal): one dense layer then two expert layers, a query
+    latent, an indexer of 2 heads x 16 of which 8 dimensions rotate, top-32,
+    yarn with its temperature, sigmoid top-2 of 16 experts in 4 groups of
+    which 2 are kept, one shared expert, and a held share: this rank holds
+    experts 4-7 of the 16 the router scores."""
+    return tiny_model_config(
+        name="tiny-mla-dsa", num_layers=3, num_kv_heads=4, max_model_len=512,
+        rope_scaling={
+            "type": "yarn", "factor": 4, "beta_fast": 32, "beta_slow": 1,
+            "mscale": 1, "mscale_all_dim": 1,
+            "original_max_position_embeddings": 128,
+        },
+        kv_lora_rank=32, q_lora_rank=24,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        indexer_topk=32, indexer_num_heads=2, indexer_head_dim=16,
+        num_experts=16, num_experts_per_tok=2, moe_intermediate_size=64,
+        shared_expert_intermediate_size=64, first_dense_layers=1,
+        router_scoring="sigmoid", topk_method="group_top2",
+        n_group=4, topk_group=2, norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        held_experts=4, held_experts_first=4,
+    )
+
+
 @register_model("deepseek-r1")
 def _deepseek_r1() -> ModelConfig:
     """DeepSeek-V3/R1 (HF deepseek-ai/DeepSeek-R1): full MLA (q LoRA 1536,

@@ -223,6 +223,10 @@ def render_metrics(
         counters["sparse_unbound_tokens_total"] = stats.sparse_unbound_tokens_total
         counters["indexer_keys_scored_total"] = stats.indexer_keys_scored_total
         counters["indexer_keys_written_total"] = stats.indexer_keys_written_total
+    if stats.latent_rows_written_total:
+        # ... over a latent cache (models/mla_dsa.py), x the layers.
+        counters["sparse_rows_selected_total"] = stats.sparse_rows_selected_total
+        counters["latent_rows_written_total"] = stats.latent_rows_written_total
     if stats.swa_ring_pages:
         # Hybrid-APC section retention activity
         counters["swa_section_hits_total"] = stats.swa_section_hits_total

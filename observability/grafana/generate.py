@@ -563,16 +563,22 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                f"rate(llmd:sparse_unbound_tokens_total{M}[5m]))",
                f"rate(llmd:indexer_keys_scored_total{M}[5m]) / "
                f"rate(llmd:sparse_bound_tokens_total{M}[5m])",
-               f"rate(llmd:indexer_keys_written_total{M}[5m])"],
+               f"rate(llmd:indexer_keys_written_total{M}[5m])",
+               f"rate(llmd:sparse_rows_selected_total{M}[5m]) / "
+               f"rate(llmd:latent_rows_written_total{M}[5m])"],
               legends=["bound token share", "indexer keys scored/bound token",
-                       "indexer keys written/s"],
+                       "indexer keys written/s",
+                       "latent rows selected/computed token (latent cache)"],
               desc="Models with learned sparse attention only "
                    "(docs/architecture/sparse-attention.md). Bound token "
                    "share: computed query tokens that had more cached "
                    "tokens than the indexer's top-k, so the selection "
                    "binds. Keys scored per bound token is their context "
                    "length: the indexer's scores and the dense pass "
-                   "under the mask grow with it."),
+                   "under the mask grow with it. Over a latent cache "
+                   "(DeepSeek-V3.2) the read gathers the selected rows: "
+                   "rows selected per computed token and layer is "
+                   "min(cached tokens, top-k), what it must fetch."),
         panel("Grouped expert matmul: experts with rows",
               [f"rate(llmd:moe_groups_with_rows_total{M}[5m]) / "
                f"rate(llmd:moe_grouped_calls_total{M}[5m])",

@@ -53,7 +53,7 @@ SUITES = {
     "cpu": [
         "tiny-moe", "tiny-swa", "tiny-swa:swa_ring=true", "tiny-mla", "tiny-dsa",
         "tiny-exaone:swa_ring=true", "tiny-granite-hybrid", "tiny-nemotron-h",
-        "tiny-qwen3-next",
+        "tiny-qwen3-next", "tiny-mla-dsa",
         "tiny:num_lora_adapters=2",
         "tiny:attention_bias=true,attention_sinks=true",
         "tiny:num_heads=8,num_kv_heads=2,tensor_parallel_size=8",

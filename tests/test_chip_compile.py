@@ -137,13 +137,13 @@ def _sparse_attention(T):
     )
 
 
-def _indexer(T):
+def _indexer(T, J=16, Di=64, pages=24576):
     """The indexer's scoring at the same geometry: one layer's plane of
     24,576 pages of [16, 64] keys, 34 flat rows x 2,048 pages in SMEM, 16
-    index heads a token."""
+    index heads a token. (deepseek-v3.2.1chip: 64 heads x 128, no lane pad,
+    over all five layers' planes as one of 5 x 24,576 pages.)"""
     from llmd_tpu.ops.sparse_attention import index_scores_pallas
 
-    J, Di = 16, 64
     rows, max_pages = 34, 2048
 
     def under_the_references_precision(*args):
@@ -155,8 +155,32 @@ def _indexer(T):
             return index_scores_pallas(*args)
 
     return under_the_references_precision, [
-        ((T, J, Di), BF16), ((T, J), BF16), ((24576, PAGE, Di), BF16),
+        ((T, J, Di), BF16), ((T, J), BF16), ((pages, PAGE, Di), BF16),
         ((rows, max_pages), I32), ((T,), I32), ((T,), I32),
+    ]
+
+
+def _latent_write(T, device):
+    """The flat write of deepseek-v3.2.1chip: the stream's latent rows
+    (640 lanes, one "head") and indexer keys (128 lanes) through the run plan
+    into layer ``l`` of both planes of the pool, in place."""
+    from llmd_tpu.ops.sparse_attention import IndexedPool
+    from llmd_tpu.ops.sparse_mla import write_latent_rows_full_flat
+
+    L, pages, Dl, Di, rows, max_pages = 5, 24576, 640, 128, 34, 2048
+    runs = 2 * rows + -(-T // PAGE)
+
+    def write(kv, index, l, latent, keys, pt, r, pos, valid, src, off, cnt, phys):
+        pool = write_latent_rows_full_flat(
+            IndexedPool(kv=kv, index=index), l, latent, keys, pt, r, pos,
+            valid, (src, off, cnt, phys), mesh=_one_chip_mesh(device),
+        )
+        return pool.kv, pool.index
+
+    return write, [
+        ((L, pages, 1, PAGE, Dl), BF16), ((L, pages, PAGE, Di), BF16), ((), I32),
+        ((T, Dl), BF16), ((T, Di), BF16), ((rows, max_pages), I32),
+        ((T,), I32), ((T,), I32), ((T,), jnp.bool_), *[((runs,), I32)] * 4,
     ]
 
 
@@ -314,6 +338,8 @@ CASES = {
     "flat_attention-sinks": lambda d: _sink_attention(256),
     "sparse_attention-keye-vl-2.0-30b-a3b": lambda d: _sparse_attention(144),
     "indexer-keye-vl-2.0-30b-a3b": lambda d: _indexer(144),
+    "indexer-deepseek-v3.2": lambda d: _indexer(144, J=64, Di=128, pages=5 * 24576),
+    "latent_write-deepseek-v3.2": lambda d: _latent_write(144, d),
     "flat_attention-k-exaone-236b-a23b": lambda d: _flat_attention(EXAONE, BF16, 528),
     "window_attention-k-exaone-236b-a23b": lambda d: _window_attention(528),
     "flat_write-k-exaone-236b-a23b": lambda d: _flat_write(EXAONE, BF16, 528),

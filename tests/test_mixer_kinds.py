@@ -16,11 +16,11 @@ from llmd_tpu.models.registry import get_model_config
 from llmd_tpu.ops import ssm
 
 TINY = ("tiny-moe", "tiny-swa", "tiny-mla", "tiny-dsa", "tiny-exaone",
-        "tiny-granite-hybrid", "tiny-nemotron-h", "tiny-qwen3-next")
+        "tiny-granite-hybrid", "tiny-nemotron-h", "tiny-qwen3-next", "tiny-mla-dsa")
 # The benchmark's registries, cut to two periods of their layer pattern.
 BENCH = {"qwen3-30b-a3b": 2, "deepseek-v2-lite": 3, "keye-vl-2.0-30b-a3b": 2,
          "k-exaone-236b-a23b": 8, "granite-4.0-h-small": 20,
-         "nemotron-3-nano-30b-a3b": 8, "qwen3-next-80b-a3b": 8}
+         "nemotron-3-nano-30b-a3b": 8, "qwen3-next-80b-a3b": 8, "deepseek-v3.2": 4}
 PAGE, PAGES, SLOTS = 8, 16, 5
 
 
@@ -129,7 +129,8 @@ def test_forward_hidden_hands_back_the_hidden_and_the_caches_it_was_given(name, 
         return llama.forward_hidden(params, kv, step_input(cfg, flat, ring), cfg, **second)
 
     # a layout a kind does not serve is refused, not misread
-    if (cfg.is_mla if flat else cfg.state_space or cfg.sparse_attention):
+    # (latent attention WITH an indexer exists on the flat stream only)
+    if (cfg.is_mla and not cfg.sparse_attention if flat else cfg.state_space or cfg.sparse_attention):
         with pytest.raises(NotImplementedError, match="bucketed step only" if flat else "flat step only"):
             jax.eval_shape(forward, param_shapes(cfg), kv, second)
         return
