@@ -479,6 +479,18 @@ class EngineStats:
     # with requests admitted after the speculative schedule.
     steps_prestaged_total: int = 0
     steps_topped_up_total: int = 0
+    # Steps dispatched BEFORE the step in front of them was read back (the
+    # moment it was seen ready: a decode row takes its token from the
+    # device, so the staged step needs nothing of the host's copy). On
+    # such a step step_host_gap_ms and step_redispatch_ms run from the
+    # first ready to the dispatch's return (what the device still waits
+    # for host code), and the readback and the commit are under the
+    # device. Rows that landed for a request which had ended meanwhile (a
+    # stop token or an abort met at the commit, one step after the row was
+    # dispatched): computed, dropped, and what the request held released
+    # only then. A finish by length is foreseen and wastes none.
+    steps_dispatched_before_readback_total: int = 0
+    async_wasted_rows_total: int = 0
     # Speculative decoding (SchedulerConfig.speculative_ngram; the
     # propose/verify/accept contract in
     # docs/architecture/speculative-decoding.md): draft tokens proposed
@@ -649,6 +661,9 @@ class _StagedStep:
     admit_s: float = 0.0  # host seconds spent scheduling and topping up
     in_wait_s: float = 0.0  # ... of them inside the wait for the readback
     in_gap_s: float = 0.0  # ... and behind it, with the device empty
+    # Dispatched the moment the step in flight was seen ready, before its
+    # readback (``LLMEngine._dispatch_early``): when the dispatch returned.
+    early_at: float | None = None
 
 
 class LLMEngine:
@@ -1900,26 +1915,38 @@ class LLMEngine:
         N executes on device, the host does everything that does not need
         N's tokens — schedule N+1 speculatively (each in-flight decode
         assumed to land its tokens), prestage its host arrays, and, all
-        through the wait for N's readback, take in the requests that
-        arrive and top the staged batch up with them. Between two
-        programs stand only the readback, commit, reconcile (late
-        EOS/stop/max-tokens finishes and aborts invalidate their staged
-        rows — the released pages follow the recompute-preemption path),
-        a last top-up and the fill-and-dispatch of N+1: the host's TURN,
-        tiled without a hole by the spans llmd.runner.readback,
+        through the wait for N's outputs, take in the requests that
+        arrive and top the staged batch up with them. The moment N is
+        seen ready N+1 is DISPATCHED (``_dispatch_early``): its decode
+        rows take their input tokens from the device, where N left them
+        (``ModelRunner.last_tokens``), so nothing of it waits for the
+        host's copy. Then, under N+1: the readback, the commit (a late
+        EOS/stop finish or an abort finds its row already in N+1: that
+        row is wasted, dropped at N+1's commit, and what the request
+        holds is released only then), N+1's retained-state captures,
+        output assembly and the offloader's flush. Between two programs
+        stand the ready lag and the jitted call (llmd.runner.launch: the
+        fill and the put of N+1's payload were done ahead, ``_prepare``).
+
+        A staged step that needs N's tokens ON THE HOST before it can be
+        dispatched keeps the order the step had before (``_dispatches_early``
+        reads it off the batch and the engine): readback, commit,
+        reconcile (late finishes and aborts invalidate their staged rows —
+        the released pages follow the recompute-preemption path), a last
+        top-up and the fill-and-dispatch of N+1, the host's TURN, tiled
+        without a hole by the spans llmd.runner.readback,
         llmd.step.commit, llmd.sched.schedule and llmd.runner.launch
-        (docs/architecture/observability.md); output assembly and the
-        offloader's flush run after the re-dispatch, under N+1. Outputs
-        arrive one call late; the pipeline is entered by ``_prime``
-        behind a step that landed synchronously
-        (docs/architecture/async-scheduling.md)."""
+        (docs/architecture/observability.md). Outputs arrive one call
+        late; the pipeline is entered by ``_prime`` behind a step that
+        landed synchronously (docs/architecture/async-scheduling.md)."""
         inflight = self._inflight
         # ---- overlapped host region: the device is executing N ----
         t0 = time.monotonic()
         slot = _StagedStep(self._schedule_spanned())  # speculative: pending counts
         slot.admit_s = time.monotonic() - t0
         slot.staging = self._stage(slot.batch)
-        # ---- block on step N's single coalesced readback ----
+        self._prepare(slot)
+        # ---- wait for step N; dispatch N+1; N's one coalesced readback ----
         t_staged = time.monotonic()
         pres, dres = self.runner.wait_step(
             inflight.pending_prefill, inflight.pending_decode,
@@ -1928,57 +1955,82 @@ class LLMEngine:
             # behind a synchronous step, and is admitted under the device.
             poll=None if self.intake_hook is None
             else functools.partial(self._admit_arrivals, slot),
+            at_ready=functools.partial(self._dispatch_early, slot),
         )
         waited = self.runner.last_wait
+        early = slot.early_at is not None
         t_read = self.last_readback_at = waited.read_at
         with profiling.span("llmd.step.commit") as commit_span:
             sampled, logprobs = self._collect(inflight.batch, pres, dres)
             accepted = self._commit(inflight.batch, sampled)
-            self._inflight = None
+            if not early:
+                self._inflight = None
             if self.intake_hook is not None:
-                # The last instants' arrivals and aborts: nothing is in
-                # flight now, so an abort releases its row at once and
-                # the reconcile below drops it with the late finishes.
+                # The last instants' arrivals and aborts. With nothing in
+                # flight an abort releases its row at once and the
+                # reconcile below drops it with the late finishes; an
+                # abort of a row of N+1 in flight is deferred to the
+                # lines below.
                 self.intake_hook()
+            # (a request with a row still in flight keeps what it holds
+            # until that row has landed: EngineScheduler._retire)
             for rid in sorted(self._deferred_aborts):
                 self.scheduler.abort_request(rid)
             self._deferred_aborts.clear()
             if self.pager is not None:
                 # Spill pages that fell below the window + prefetch
                 # horizon HERE, the one point of the pipelined step where
-                # nothing is in flight (behind the re-dispatch every
-                # running row is protected and the tick would spill
-                # nothing, ever). The staged tables keep the spilled
-                # pages' stale ids, as Request.block_ids does: every read
-                # of those positions is window-masked.
+                # nothing is in flight (an engine with the pager never
+                # dispatches early; behind the re-dispatch every running
+                # row is protected and the tick would spill nothing,
+                # ever). The staged tables keep the spilled pages' stale
+                # ids, as Request.block_ids does: every read of those
+                # positions is window-masked.
                 self.pager.tick(self.scheduler.running)
             # ---- reconcile the speculative slot against late finishes ----
-            rolled = self._reconcile(slot)
-            commit_span.set_metadata(rolled=rolled)
+            rolled = 0 if early else self._reconcile(slot)
+            commit_span.set_metadata(rolled=rolled, early=early)
         t_reconciled = time.monotonic()
         prestaged = not slot.batch.is_empty
-        if not prestaged:
-            if self.scheduler.has_work():
-                # The slot is empty (every staged row rolled back, or all
-                # of N's rows foreseen to end): nothing is pending, so
-                # the freed rows, pages and budget are scheduled whole.
-                slot.batch = self._schedule_spanned()
-                slot.in_gap_s = time.monotonic() - t_reconciled
-                slot.admit_s += slot.in_gap_s
-        elif self.scheduler.waiting:
-            # Rows and budget that N's finishes gave back, and whatever
-            # arrived in the last instants: admitted now, one step sooner
-            # than the next speculative schedule would.
-            self._top_up(slot)
+        if early:
+            # N+1 has been on the device since N was seen ready: the
+            # device waited for the ready lag and the dispatch alone.
+            t_redispatched = slot.early_at
+            redispatch_s = t_redispatched - waited.ready_at
+            host_gap_s = redispatch_s
+            self.stats.steps_dispatched_before_readback_total += 1
+        else:
+            if not prestaged:
+                if self.scheduler.has_work():
+                    # The slot is empty (every staged row rolled back, or
+                    # all of N's rows foreseen to end): nothing is
+                    # pending, so the freed rows, pages and budget are
+                    # scheduled whole.
+                    slot.batch = self._schedule_spanned()
+                    slot.in_gap_s = time.monotonic() - t_reconciled
+                    slot.admit_s += slot.in_gap_s
+            elif self.scheduler.waiting:
+                # Rows and budget that N's finishes gave back, and whatever
+                # arrived in the last instants: admitted now, one step
+                # sooner than the next speculative schedule would.
+                self._top_up(slot)
+            if not slot.batch.is_empty:
+                self._dispatch_async(slot.batch, slot.staging)
+            # The host's turn ends at the re-dispatch's return above (the
+            # device's idle time a little later: the program's launch
+            # latency is no host code's to hold).
+            t_redispatched = time.monotonic()
+            redispatch_s = t_redispatched - t_reconciled
+            host_gap_s = t_redispatched - t_read
         if not slot.batch.is_empty:
-            self._dispatch_async(slot.batch, slot.staging)
             self.stats.steps_prestaged_total += prestaged
             self.stats.steps_topped_up_total += slot.topped_up
-        # The host's turn ends at the re-dispatch's return above (the
-        # device's idle time a little later: the program's launch latency
-        # is no host code's to hold); output assembly and gauge refresh
-        # below overlap step N+1's execution.
-        t_redispatched = time.monotonic()
+        # Output assembly and gauge refresh below overlap step N+1's
+        # execution; so do N+1's captures, which want N's commit (a row
+        # of a request that has just ended leaves nothing behind, and a
+        # finish boundary's key hashes the token N sampled) and are on
+        # the device's queue before the next dispatch either way.
+        t_finish = time.monotonic()
         with profiling.span("llmd.step.finish") as finish_span:
             self._capture_behind(slot.batch)
             outputs = self._assemble_outputs(
@@ -1991,23 +2043,92 @@ class LLMEngine:
         # spent: schedule (with the top-ups, which are taken out of the
         # wait they ran in) and prestaging ran while the device executed
         # step N; commit/reconcile count as finish with the assembly. The
-        # host gap is what stood between N's readback's END and N+1's
-        # dispatch: commit + redispatch; the turn is the readback more.
+        # host gap is what the device waited for host code: from N's
+        # readback's END to N+1's dispatch (commit + redispatch; the turn
+        # is the readback more), or, on a step dispatched early, from the
+        # first ready to the dispatch's return.
+        early_s = redispatch_s if early else 0.0
         self._finish_step(
-            inflight.batch, t_redispatched - t_read,
+            inflight.batch, host_gap_s,
             schedule_s=slot.admit_s,
-            launch_s=(t_staged - t0) + (t_redispatched - t_reconciled)
+            launch_s=(t_staged - t0) + redispatch_s
             - (slot.admit_s - slot.in_wait_s),
-            wait_s=t_read - t_staged - slot.in_wait_s,
+            wait_s=t_read - t_staged - slot.in_wait_s - early_s,
             finish_s=(t_reconciled - t_read)
-            + (time.monotonic() - t_redispatched),
+            + (time.monotonic() - t_finish),
             readback_s=waited.readback_s,
             ready_lag_bound_s=waited.ready_lag_bound_s,
             commit_s=t_reconciled - t_read,
-            redispatch_s=t_redispatched - t_reconciled,
+            redispatch_s=redispatch_s,
             gap_admit_s=slot.in_gap_s,
         )
         return outputs
+
+    def _dispatches_early(self, slot: _StagedStep) -> bool:
+        """May ``slot``'s batch, staged under the step in flight, go to the
+        device before that step is read back? Read off the batch and the
+        engine, never an option. It may where every row's input is the
+        host's already or the device's own: a prefill chunk's tokens, and
+        a one-token decode row, whose token the step in flight leaves in
+        ``ModelRunner.last_tokens``. Not a step with drafts (the proposer
+        wants the token on the host), not a fused decode window (its K
+        was chosen because nothing could be admitted; it amortises the
+        turn already), not an engine with the decode pager (``pager.tick``
+        wants the one instant with nothing in flight, which this order
+        does not have), not a batch-band row while an interactive request
+        waits (the head reclaims such a row's slot and pages at the last
+        top-up, which wants the row out of flight: dispatched early step
+        after step it would be protected until it ends), and not an empty
+        slot (nothing to dispatch). Nor a step whose program has not been
+        called at its shape yet, or is not staged whole (the split prefill
+        programs, which are built at their dispatch): a shape's FIRST call
+        is seconds of tracing and lowering whose time follows the Python
+        path it is reached on (PERF.md section 7 (k); reached from
+        ``wait_step``'s hook it read +0.2 s a bucket on the v5e host, 40 %
+        of ``long-decode``'s warm ladder), so it stays on the path the
+        set-up bound was set with, and loses nothing: the device waits
+        seconds either way. The two roles whose order is a protocol
+        (multi-host lockstep, the P/D producer) never come here: they step
+        synchronously."""
+        batch, staged = slot.batch, slot.staging
+        waiting = self.scheduler.waiting
+        if (
+            batch.is_empty
+            or self.pager is not None
+            or any(
+                s.draft_tokens is not None or s.num_tokens != 1
+                for s in batch.decodes
+            )
+            or (
+                waiting and not waiting[0].is_batch
+                and any(s.request.is_batch for s in batch.seqs)
+            )
+        ):
+            return False
+        if self._unified_eligible(batch):
+            whole = isinstance(staged, StagedUnified)
+        else:
+            # The decode program alone (a prefill program beside it is
+            # filled at the dispatch, and draws its seeds first).
+            whole = isinstance(staged, StagedDecode) and not batch.prefills
+        return whole and self.runner.shape_is_warm(staged)
+
+    def _dispatch_early(self, slot: _StagedStep) -> None:
+        """``wait_step``'s ``at_ready``: step N has just been seen ready
+        and is not read back yet. Dispatch the staged N+1 now where it
+        needs nothing of N's host copy."""
+        if self._deferred_aborts:
+            # An abort the poll brought for a row of N: its staged row is
+            # not dispatched (the abort itself waits for N's commit).
+            batch = self._rows_where(
+                slot.batch,
+                lambda s: s.request.request_id not in self._deferred_aborts,
+            )
+            if batch is not slot.batch:
+                self._restaged(slot, batch)
+        if self._dispatches_early(slot):
+            self._dispatch_async(slot.batch, slot.staging)
+            slot.early_at = time.monotonic()
 
     def _prime(self) -> None:
         """Enter the pipeline behind a step that has just landed: what
@@ -2082,21 +2203,41 @@ class LLMEngine:
             return staged_dec
         return self._stage(batch)
 
+    def _restaged(self, slot: _StagedStep, batch: ScheduledBatch) -> None:
+        """``slot`` becomes ``batch``: what it had staged less the rows a
+        rollback dropped, plus the rows a top-up admitted."""
+        self.runner.unprepare(slot.staging)
+        slot.staging = self._restage(slot.staging, slot.batch, batch)
+        slot.batch = batch
+        self._prepare(slot)
+
+    def _prepare(self, slot: _StagedStep) -> None:
+        """Under the step in flight: where the staged step will go out
+        the moment that step is seen ready (``_dispatches_early``), its
+        fill and the put of its payload are done NOW
+        (``ModelRunner.prepare_staged``), so that the device then waits for
+        the jitted call alone. Nothing of the fill reads what the step in
+        flight will commit: positions and seeds follow the dispatched
+        position, and a decode row's un-read token is the device's."""
+        if self._inflight is not None and self._dispatches_early(slot):
+            self.runner.prepare_staged(slot.staging)
+
     @staticmethod
-    def _running(batch: ScheduledBatch) -> ScheduledBatch:
-        """``batch`` less the rows whose request is no longer running
-        (``batch`` itself where all are)."""
-        live_p = [
-            s for s in batch.prefills
-            if s.request.status is RequestStatus.RUNNING
-        ]
-        live_d = [
-            s for s in batch.decodes
-            if s.request.status is RequestStatus.RUNNING
-        ]
+    def _rows_where(batch: ScheduledBatch, keep) -> ScheduledBatch:
+        """``batch`` less the rows ``keep`` refuses (``batch`` itself where
+        it refuses none)."""
+        live_p = [s for s in batch.prefills if keep(s)]
+        live_d = [s for s in batch.decodes if keep(s)]
         if len(live_p) + len(live_d) == len(batch.prefills) + len(batch.decodes):
             return batch
         return ScheduledBatch(prefills=live_p, decodes=live_d)
+
+    @classmethod
+    def _running(cls, batch: ScheduledBatch) -> ScheduledBatch:
+        """``batch`` less the rows whose request is no longer running."""
+        return cls._rows_where(
+            batch, lambda s: s.request.status is RequestStatus.RUNNING
+        )
 
     def _reconcile(self, slot: _StagedStep) -> int:
         """Drop the staged rows whose request is no longer running (a
@@ -2109,8 +2250,7 @@ class LLMEngine:
         rolled = len(slot.batch.seqs) - len(batch.seqs)
         if rolled:
             self.stats.async_rollbacks_total += rolled
-            slot.staging = self._restage(slot.staging, slot.batch, batch)
-            slot.batch = batch
+            self._restaged(slot, batch)
         return rolled
 
     def _top_up(self, slot: _StagedStep) -> None:
@@ -2131,11 +2271,9 @@ class LLMEngine:
             # row that no longer runs leaves the staged batch either way.
             kept = self._running(slot.batch)
             if added or kept is not slot.batch:
-                batch = ScheduledBatch(
+                self._restaged(slot, ScheduledBatch(
                     prefills=kept.prefills + added, decodes=kept.decodes
-                )
-                slot.staging = self._restage(slot.staging, slot.batch, batch)
-                slot.batch = batch
+                ))
                 slot.topped_up |= bool(added)
         spent = time.monotonic() - t0
         slot.admit_s += spent
@@ -2265,6 +2403,7 @@ class LLMEngine:
         if reuse:
             pend_u = self.runner.dispatch_staged_unified(staged)
         else:
+            self.runner.unprepare(staged)
             pend_u = self.runner.dispatch_unified(
                 batch.prefills, batch.decodes
             )
@@ -2586,6 +2725,7 @@ class LLMEngine:
             self.stats.retained_finish_captures_total = kept.finish_captures
         self.stats.prefix_hit_ratio = self.allocator.hit_ratio()
         self.stats.preemptions = self.scheduler.num_preemptions
+        self.stats.async_wasted_rows_total = self.scheduler.wasted_rows
         self.stats.queue_wait_ms_total = self.scheduler.queue_wait_ms
         self.stats.queue_admitted_total = self.scheduler.queue_admitted
         self.stats.programs_traced_total = self.runner.programs_traced

@@ -56,6 +56,13 @@ def step_fields(
     steps' sample width S; ``ring`` adds the sliding layers' second page
     table (and the flat step's second write plan), ``lora`` the adapter
     slots, ``state`` (flat only) each row's slot of the state pool.
+
+    ``tok_slot`` is each row's entry of the runner's ``last_tokens`` (the
+    token the device sampled last for the row's sequence; an index past the
+    array's end: the row keeps none), which every program but the verify
+    step writes; ``tok_dev`` flags the rows that READ their first input
+    token from there, not from the host's stream
+    (``ModelRunner._token_input``).
     """
     mp = max_pages
     if kind in ("prefill", "verify"):
@@ -72,6 +79,8 @@ def step_fields(
             # (row, position) — the one difference from the prefill family.
             ("seeds", (B, QK) if kind == "verify" else (B,), np.uint32),
         ]
+        if kind == "prefill":
+            spec.append(("tok_slot", (B,), np.int32))
     elif kind in ("unified", "flat"):
         # Unified: the per-row column count rides the high bits and only
         # the stream length sizes the payload. Flat: QK is T itself.
@@ -88,6 +97,8 @@ def step_fields(
             ("top_k", (B,), np.int32),
             ("top_p", (B,), np.float32),
             ("seeds", (B, sample_cols), np.uint32),
+            ("tok_slot", (B,), np.int32),
+            ("tok_dev", (B,), np.uint8),
         ]
         if kind == "flat":
             # The run-plan width derives from (B, T, page): a row touching
@@ -114,6 +125,8 @@ def step_fields(
             ("top_k", (B,), np.int32),
             ("top_p", (B,), np.float32),
             ("seeds", (B, QK), np.uint32),
+            ("tok_slot", (B,), np.int32),
+            ("tok_dev", (B,), np.uint8),
         ]
     else:
         raise ValueError(f"no step program of kind {kind!r}")

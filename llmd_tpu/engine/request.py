@@ -131,6 +131,11 @@ class Request:
     # executes. Always 0 behind a synchronous step and between reconcile
     # and the next dispatch.
     num_pending_tokens: int = 0
+    # The request's entry of the runner's ``last_tokens`` (the token the
+    # device sampled last for it, which the next step's decode row reads
+    # there and not from the host): handed out by the scheduler when the
+    # request starts to run, taken back with its pages. -1: none.
+    token_slot: int = -1
     # Number of prompt tokens satisfied from the prefix cache (skipped compute).
     num_cached_tokens: int = 0
     # Decode-time KV paging (OffloadConfig.decode_paging): logical page
@@ -232,6 +237,18 @@ class Request:
         a prompt-completing chunk in flight makes the seq decode-ready
         for the next staged batch)."""
         return self.num_dispatched_tokens >= self.num_prompt_tokens
+
+    @property
+    def num_dispatched_outputs(self) -> int:
+        """``total_output_tokens`` once the steps in flight land: one more
+        for every pending decode position, one for a pending chunk that
+        completes the prompt (a speculative row's pending count is its
+        widest acceptance, and is never asked while it pends)."""
+        if not self.num_pending_tokens:
+            return self.total_output_tokens
+        if self.in_decode:
+            return self.total_output_tokens + self.num_pending_tokens
+        return self.total_output_tokens + int(self.in_decode_dispatched)
 
     @property
     def is_finished(self) -> bool:

@@ -53,7 +53,7 @@ from llmd_tpu.engine.sampler import (
     sample_tokens,
     spec_seed,
 )
-from llmd_tpu.engine.scheduler import ScheduledSeq
+from llmd_tpu.engine.scheduler import ScheduledSeq, token_slot_count
 from llmd_tpu.models import llama
 from llmd_tpu.models.common import StepInput
 from llmd_tpu.obs import profiling
@@ -278,6 +278,8 @@ class StagedDecode:
     B: int
     k: int
     all_greedy: bool
+    # Filled, and its payload on the device already (``prepare_staged``).
+    prepared: "_Prepared | None" = None
 
 
 @dataclass
@@ -310,6 +312,21 @@ class StagedUnified:
     # metadata width, T a fine-grained flat bucket) instead of the
     # bucketed [B, Q] gather.
     flat: bool = False
+    # Filled, and its payload on the device already (``prepare_staged``).
+    prepared: "_Prepared | None" = None
+
+
+@dataclass
+class _Prepared:
+    """A staged step made ready AHEAD of its dispatch: its payload buffer on
+    the device, and what the fill changed of the runner (the rng it drew the
+    seeds from, the counters it added to), so that a step which is staged
+    anew before it is ever dispatched (a top-up, a rollback) is forgotten
+    whole (``ModelRunner.unprepare``)."""
+
+    step: jax.Array
+    rng_state: dict
+    counters: dict
 
 
 @dataclass
@@ -329,19 +346,21 @@ class PendingUnified:
 
 @dataclass
 class WaitTiming:
-    """When the last ``wait_step`` learnt that its outputs were ready and
-    when it had them parsed (``time.monotonic()``), and the most that the
-    notice can have lagged the device: the time from the last
+    """When the last ``wait_step`` learnt that its outputs were ready, when
+    it began to read them back (behind ``at_ready``, where one was given)
+    and when it had them parsed (``time.monotonic()``), and the most that
+    the notice can have lagged the device: the time from the last
     ``is_ready()`` that was false (the wait's entry where the first was
     true) to the first that was true; 0 for a blocking wait."""
 
     ready_lag_bound_s: float = 0.0
     ready_at: float = 0.0
+    read_from: float = 0.0
     read_at: float = 0.0
 
     @property
     def readback_s(self) -> float:
-        return self.read_at - self.ready_at
+        return self.read_at - self.read_from
 
 
 class ModelRunner:
@@ -349,6 +368,28 @@ class ModelRunner:
     # EngineStats fields of the same names). One a step program.
     step_h2d_transfers_total = 0
     step_h2d_bytes_total = 0
+    # [token_slots] i32 on the device: the token sampled last for each
+    # running request, at ``Request.token_slot``. Every step program writes
+    # what it sampled for its rows and hands the array on (donated, like
+    # the pools); a decode row whose token the host has not read yet takes
+    # its input from there (``_token_input``), which is what lets the
+    # engine dispatch step N+1 before step N's readback. ONE shape: no
+    # program is compiled for it. (``_build_programs`` leaves host zeros
+    # where there is none yet, which is all that a runner put together
+    # without ``__init__``, to lower a program for a described chip, ever
+    # hands its programs; ``__init__`` puts the array on the device.)
+    last_tokens = None
+    # What a step's fill and put add to (``_Prepared.counters``).
+    _FILL_COUNTERS = (
+        "step_h2d_transfers_total", "step_h2d_bytes_total",
+        "live_tokens_total", "padded_tokens_total",
+        "attn_shared_tile_tokens_total", "sparse_bound_tokens_total",
+        "sparse_unbound_tokens_total", "indexer_keys_scored_total",
+        "indexer_keys_written_total", "sparse_rows_selected_total",
+        "latent_rows_written_total", "ssm_update_rows_total",
+        "ssm_scan_tokens_total", "gdn_update_rows_total",
+        "gdn_scan_rows_total", "gdn_scan_tokens_total",
+    )
 
     def __init__(
         self,
@@ -550,6 +591,9 @@ class ModelRunner:
         self.last_program = ""
         self.last_wait = WaitTiming()  # of the newest wait_step
         self._build_programs()
+        self.last_tokens = jax.device_put(
+            np.zeros(self.token_slots, np.int32), mesh_ctx.replicated
+        )
         self._check_page_table_fits_smem()
         # Padding-efficiency accounting (EngineStats padded/live tokens):
         # every dispatch path adds its live token count and the padded
@@ -598,6 +642,11 @@ class ModelRunner:
         ep_capacity step or an EPLB remap (the we_* leaves change shape)
         — so no compiled family ever runs a stale capacity/placement."""
         sched = self.config.scheduler
+        # ``last_tokens``' length, which is also the "no entry" a row's
+        # ``tok_slot`` carries (a write there is dropped).
+        self.token_slots = token_slot_count(sched.max_num_seqs)
+        if self.last_tokens is None:
+            self.last_tokens = np.zeros(self.token_slots, np.int32)
         self._forward = self._build_forward()
         self._forward_cp = (
             self._build_forward(cp=self.cp_prefill) if self.cp_prefill else None
@@ -1056,6 +1105,29 @@ class ModelRunner:
         rows = rows.at[:, :2].set(census.reshape(2, 2).astype(packed.dtype))
         return jnp.concatenate([packed, rows]), jnp.zeros_like(census)
 
+    @staticmethod
+    def _token_input(f: dict, last_tokens, host, row_of, is_first):
+        """The token input of every step program the pipelined step
+        dispatches without drafts: ``host`` (the stream the host packed),
+        but the first token of a row flagged ``tok_dev`` is the one the
+        device sampled last for the row's sequence, ``last_tokens[
+        tok_slot]``: the step that sampled it may not have been read back
+        yet. ``row_of`` maps ``host``'s elements to rows and ``is_first``
+        marks a row's first token (both broadcast against ``host``)."""
+        slot = jnp.clip(f["tok_slot"], 0, last_tokens.shape[0] - 1)
+        from_device = (f["tok_dev"] != 0)[row_of] & is_first
+        return jnp.where(from_device, last_tokens[slot][row_of], host)
+
+    @staticmethod
+    def _keep_sampled(f: dict, last_tokens, sampled):
+        """``last_tokens`` with what the step sampled for each of its rows
+        ([B]) at the row's ``tok_slot``; a row without one (a pad row, a
+        chunk that does not complete its prompt, a speculative row)
+        carries the array's length, and its write is dropped."""
+        return last_tokens.at[f["tok_slot"]].set(
+            sampled.astype(jnp.int32), mode="drop"
+        )
+
     def _note_traced(self, family: str, shape) -> None:
         """First line of every jitted step program's body, so it runs
         once per trace of the program (a new shape, or a rebuilt family):
@@ -1096,15 +1168,14 @@ class ModelRunner:
 
         @functools.partial(
             jax.jit,
-            donate_argnums=(1, 2) if ring else (1,),
+            donate_argnums=(1, 2, 4) if ring else (1, 4),
             static_argnames=("B", "Q", "all_greedy"),
         )
-        def llmd_prefill_step(params, kv_cache, kv_swa, step, census=None,
-                              B=0, Q=0, all_greedy=False):
+        def llmd_prefill_step(params, kv_cache, kv_swa, step, last_tokens,
+                              census=None, B=0, Q=0, all_greedy=False):
             self._note_traced("prefill_cp" if cp else "prefill", (B, Q))
-            inp, s = self._rows_inputs(
-                self._layout(_OP_PREFILL, B, Q).unpack(step)
-            )
+            f = self._layout(_OP_PREFILL, B, Q).unpack(step)
+            inp, s = self._rows_inputs(f)
             hidden, kv_cache, kv_swa, census = self._fwd_hidden(
                 params, kv_cache, kv_swa, inp, census, dbo=dbo, cp=cp
             )
@@ -1118,7 +1189,8 @@ class ModelRunner:
                 [tokens.astype(jnp.float32)[:, None], logprobs[:, None]], axis=1
             )
             packed, census = self._count_row(packed, census)
-            return kv_cache, kv_swa, replicate(packed), census
+            last_tokens = self._keep_sampled(f, last_tokens, tokens)
+            return kv_cache, kv_swa, replicate(packed), last_tokens, census
 
         return llmd_prefill_step
 
@@ -1139,11 +1211,11 @@ class ModelRunner:
 
         @functools.partial(
             jax.jit,
-            donate_argnums=(1, 2) if ring else (1,),
+            donate_argnums=(1, 2, 4) if ring else (1, 4),
             static_argnames=("B", "Q", "all_greedy"),
         )
-        def llmd_verify_step(params, kv_cache, kv_swa, step, census=None,
-                             B=0, Q=0, all_greedy=False):
+        def llmd_verify_step(params, kv_cache, kv_swa, step, last_tokens,
+                             census=None, B=0, Q=0, all_greedy=False):
             self._note_traced("verify", (B, Q))
             inp, s = self._rows_inputs(
                 self._layout(_OP_VERIFY, B, Q).unpack(step)
@@ -1170,7 +1242,9 @@ class ModelRunner:
                 axis=1,
             )
             packed, census = self._count_row(packed, census)
-            return kv_cache, kv_swa, replicate(packed), census
+            # (how many of a row's samples are accepted is the host's to
+            # say: a speculative row keeps no entry)
+            return kv_cache, kv_swa, replicate(packed), last_tokens, census
 
         return llmd_verify_step
 
@@ -1202,7 +1276,7 @@ class ModelRunner:
 
         @functools.partial(
             jax.jit,
-            donate_argnums=(1, 2) if ring else (1,),
+            donate_argnums=(1, 2, 4) if ring else (1, 4),
             static_argnames=("B", "Q", "T", "all_greedy"),
         )
         def llmd_unified_step(
@@ -1210,6 +1284,7 @@ class ModelRunner:
             kv_cache,
             kv_swa,  # ring pool (None unless swa_ring)
             step: jax.Array,  # the step's packed inputs (_put_step)
+            last_tokens: jax.Array,  # [token_slots] the tokens sampled last
             census=None,  # [E+2] MoE census accumulator, or None
             B: int = 0,  # rows
             Q: int = 0,  # columns a row
@@ -1235,6 +1310,10 @@ class ModelRunner:
             )
             tokens = jnp.where(
                 cols[None, :] < qlens[:, None], stream[gidx], 0
+            )
+            tokens = self._token_input(
+                f, last_tokens, tokens, jnp.arange(B)[:, None],
+                cols[None, :] == 0,
             )
             last = jnp.maximum(qlens - 1, 0)
             # Pad columns repeat the last real position (the prefill
@@ -1282,7 +1361,10 @@ class ModelRunner:
                 axis=1,
             )  # [B, 2S]
             packed, census = self._count_row(packed, census)
-            return kv_cache, kv_swa, replicate(packed), census
+            last_tokens = self._keep_sampled(
+                f, last_tokens, tok.reshape(B, S)[:, 0]
+            )
+            return kv_cache, kv_swa, replicate(packed), last_tokens, census
 
         return llmd_unified_step
 
@@ -1311,7 +1393,7 @@ class ModelRunner:
 
         @functools.partial(
             jax.jit,
-            donate_argnums=(1, 2) if ring else (1,),
+            donate_argnums=(1, 2, 4) if ring else (1, 4),
             static_argnames=("T", "all_greedy"),
         )
         def llmd_flat_step(
@@ -1319,6 +1401,7 @@ class ModelRunner:
             kv_cache,
             kv_swa,  # ring pool (None unless swa_ring)
             step: jax.Array,  # the step's packed inputs (_put_step)
+            last_tokens: jax.Array,  # [token_slots] the tokens sampled last
             census=None,  # [E+2] MoE census accumulator, or None
             T: int = 0,  # the stream bucket (sizes the layout)
             all_greedy: bool = False,
@@ -1350,6 +1433,9 @@ class ModelRunner:
             live = t < ends[-1]
             local = t - row_start[row_of]
             positions_t = jnp.where(live, pos0[row_of] + local, 0)
+            stream = self._token_input(
+                f, last_tokens, stream, row_of, local == 0
+            )
             inp = StepInput(
                 token_ids=jnp.where(live, stream, 0)[:, None],
                 positions=positions_t[:, None],
@@ -1401,7 +1487,10 @@ class ModelRunner:
                 axis=1,
             )  # [B, 2S]
             packed, census = self._count_row(packed, census)
-            return kv_cache, kv_swa, replicate(packed), census
+            last_tokens = self._keep_sampled(
+                f, last_tokens, tok.reshape(B, S)[:, 0]
+            )
+            return kv_cache, kv_swa, replicate(packed), last_tokens, census
 
         return llmd_flat_step
 
@@ -1413,7 +1502,7 @@ class ModelRunner:
 
         @functools.partial(
             jax.jit,
-            donate_argnums=(1, 2) if ring else (1,),
+            donate_argnums=(1, 2, 4) if ring else (1, 4),
             static_argnames=("B", "k_steps", "all_greedy"),
         )
         def llmd_decode_window(
@@ -1421,6 +1510,7 @@ class ModelRunner:
             kv_cache,
             kv_swa,  # ring pool (None unless swa_ring)
             step: jax.Array,  # the step's packed inputs (_put_step)
+            last_tokens: jax.Array,  # [token_slots] the tokens sampled last
             census=None,  # [E+2] MoE census accumulator, or None
             B: int = 0,  # rows
             k_steps: int = 1,
@@ -1428,7 +1518,9 @@ class ModelRunner:
         ):
             self._note_traced("decode_window", (B, k_steps))
             f = self._layout(_OP_DECODE, B, k_steps).unpack(step)
-            first_token = f["first"]  # [B]
+            first_token = self._token_input(
+                f, last_tokens, f["first"], jnp.arange(B), True
+            )  # [B]
             start_pos = f["start"]  # [B] position of first_token
             page_table = f["page_table"]  # [B, max_pages]
             swa_table = f.get("swa_table")  # [B, max_pages] ring view, or None
@@ -1468,7 +1560,7 @@ class ModelRunner:
 
             out_t = jnp.zeros((B, k_steps), jnp.int32)
             out_l = jnp.zeros((B, k_steps), jnp.float32)
-            kv_cache, kv_swa, census, _, out_t, out_l = jax.lax.fori_loop(
+            kv_cache, kv_swa, census, nxt, out_t, out_l = jax.lax.fori_loop(
                 0, k_steps, body,
                 (kv_cache, kv_swa, census, first_token, out_t, out_l),
             )
@@ -1476,7 +1568,8 @@ class ModelRunner:
                 [out_t.astype(jnp.float32), out_l], axis=1
             )  # [B, 2K]
             packed, census = self._count_row(packed, census)
-            return kv_cache, kv_swa, replicate(packed), census
+            last_tokens = self._keep_sampled(f, last_tokens, nxt)
+            return kv_cache, kv_swa, replicate(packed), last_tokens, census
 
         return llmd_decode_window
 
@@ -1790,7 +1883,7 @@ class ModelRunner:
         for i, s in enumerate(seqs):
             sp = s.request.sampling
             if sp.seed is not None:
-                pos = s.request.total_output_tokens
+                pos = s.request.num_dispatched_outputs
                 for j in range(K):
                     seeds[i, j] = np.uint32(spec_seed(sp.seed, pos + j))
 
@@ -1815,6 +1908,19 @@ class ModelRunner:
         seeds = self._np_rng.integers(0, 2**32, size=(B, K), dtype=np.uint32)
         self._overwrite_seeded_rows(seeds, seqs, K)
         return temp, top_k, top_p, seeds
+
+    def _token_slot(self, seq: ScheduledSeq) -> int:
+        """The ``tok_slot`` of the row that samples for ``seq``: its
+        request's entry of ``last_tokens`` where the sample is the
+        request's next token for certain (a decode row; a chunk that
+        completes the prompt), else none (a chunk inside the prompt, a
+        speculative row, a request no scheduler admitted)."""
+        req = seq.request
+        if seq.draft_tokens is not None or req.token_slot < 0:
+            return self.token_slots
+        if seq.start_pos + seq.num_tokens < req.num_prompt_tokens:
+            return self.token_slots
+        return req.token_slot
 
     def _lora_array(self, seqs: list[ScheduledSeq], B: int) -> np.ndarray:
         """[B] adapter slots (pad rows 0 = base model) for the payload."""
@@ -2157,7 +2263,8 @@ class ModelRunner:
                 )
 
     def _run_step(
-        self, program, op: int, rows: int, qk: int, arrays: dict, **statics
+        self, program, op: int, rows: int, qk: int, arrays: dict,
+        step: jax.Array | None = None, **statics,
     ) -> jax.Array:
         """One step program: its inputs onto the device as one buffer,
         then the jitted call.
@@ -2173,16 +2280,21 @@ class ModelRunner:
         and collector, not the program lowered (PERF.md section 6, PR
         35). A host that runs with the collector off
         keeps it off; a warmed shape never comes here twice."""
-        step = self._put_step(op, rows, qk, arrays)
+        if step is None:  # (else: put ahead, ``prepare_staged``)
+            step = self._put_step(op, rows, qk, arrays)
         key = (program, rows, qk, statics["all_greedy"])
-        first = key not in self._called and gc.isenabled()
+        first = key not in self._called
+        self._called.add(key)
+        first = first and gc.isenabled()
         if first:
-            self._called.add(key)
             gc.disable()
         try:
-            self.kv_cache, self.kv_swa, packed, self._moe_census = program(
+            (
+                self.kv_cache, self.kv_swa, packed, self.last_tokens,
+                self._moe_census,
+            ) = program(
                 self.params, self.kv_cache, self.kv_swa, step,
-                census=self._moe_census, **statics,
+                self.last_tokens, census=self._moe_census, **statics,
             )
         finally:
             if first:
@@ -2213,24 +2325,28 @@ class ModelRunner:
             B=B, Q=Q, all_greedy=all_greedy,
         )
 
-    def _exec_unified(self, arrays: dict, Q: int, all_greedy: bool) -> jax.Array:
+    def _exec_unified(
+        self, arrays: dict, Q: int, all_greedy: bool, step=None
+    ) -> jax.Array:
         B, T = arrays["row_start"].shape[0], arrays["stream"].shape[0]
         return self._run_step(
-            self._unified, _OP_UNIFIED, B, (Q << 20) | T, arrays,
+            self._unified, _OP_UNIFIED, B, (Q << 20) | T, arrays, step,
             B=B, Q=Q, T=T, all_greedy=all_greedy,
         )
 
-    def _exec_flat(self, arrays: dict, all_greedy: bool) -> jax.Array:
+    def _exec_flat(self, arrays: dict, all_greedy: bool, step=None) -> jax.Array:
         T = arrays["stream"].shape[0]
         return self._run_step(
-            self._flat, _OP_FLAT, self.flat_rows, T, arrays,
+            self._flat, _OP_FLAT, self.flat_rows, T, arrays, step,
             T=T, all_greedy=all_greedy,
         )
 
-    def _exec_decode(self, arrays: dict, K: int, all_greedy: bool) -> jax.Array:
+    def _exec_decode(
+        self, arrays: dict, K: int, all_greedy: bool, step=None
+    ) -> jax.Array:
         B = arrays["first"].shape[0]
         return self._run_step(
-            self._multi, _OP_DECODE, B, K, arrays,
+            self._multi, _OP_DECODE, B, K, arrays, step,
             B=B, k_steps=K, all_greedy=all_greedy,
         )
 
@@ -2754,6 +2870,7 @@ class ModelRunner:
         positions = np.zeros((B, Q), np.int32)
         qlens = np.zeros(B, np.int32)
         kvlens = np.zeros(B, np.int32)
+        tok_slot = np.full(B, self.token_slots, np.int32)
         for i, s in enumerate(seqs):
             req, start, m = s.request, s.start_pos, s.num_tokens
             tokens[i, :m] = req.tokens_between(start, start + m)
@@ -2761,12 +2878,13 @@ class ModelRunner:
             positions[i, m:] = start + max(m - 1, 0)
             qlens[i] = m
             kvlens[i] = start + m
+            tok_slot[i] = self._token_slot(s)
         temp, top_k, top_p, seeds = self._sampling_arrays(seqs, B, 1)
         arrays = {
             "tokens": tokens, "positions": positions, "qlens": qlens,
             "kvlens": kvlens, "page_table": self._page_table(seqs, B),
             "temp": temp, "top_k": top_k, "top_p": top_p,
-            "seeds": seeds[:, 0],
+            "seeds": seeds[:, 0], "tok_slot": tok_slot,
         }
         if self.swa is not None:
             arrays["swa_table"] = self._swa_table(seqs, B)
@@ -2818,6 +2936,7 @@ class ModelRunner:
             "page_table": self._page_table(seqs, B), "active": active,
             "temp": temp, "top_k": top_k, "top_p": top_p,
             "seeds": np.zeros((B, k_steps), np.uint32),
+            **self._token_source_arrays(B),
         }
         if self.swa is not None:
             arrays["swa_table"] = self._swa_table(seqs, B)
@@ -2826,12 +2945,55 @@ class ModelRunner:
         all_greedy = all(s.request.sampling.greedy for s in seqs)
         return StagedDecode(list(seqs), arrays, B, k_steps, all_greedy)
 
+    def _token_source_arrays(self, B: int) -> dict:
+        """``tok_slot`` / ``tok_dev`` at rest (no entry, the host's token):
+        filled at dispatch, like the tokens themselves."""
+        return {
+            "tok_slot": np.full(B, self.token_slots, np.int32),
+            "tok_dev": np.zeros(B, np.uint8),
+        }
+
+    def _fill_token_source(self, a: dict, r: int, seq: ScheduledSeq) -> int:
+        """Decode row ``r``'s input token and where it comes from, at
+        dispatch: the host's where the host has it, else (a step of the
+        sequence is in flight and not read back) the device's own
+        ``last_tokens`` entry. Returns the token for the host's stream (0
+        where the device feeds it)."""
+        req = seq.request
+        a["tok_slot"][r] = self._token_slot(seq)
+        if not req.num_pending_tokens:
+            return req.token_at(req.num_computed_tokens)
+        assert a["tok_slot"][r] < self.token_slots, req.request_id
+        a["tok_dev"][r] = 1
+        return 0
+
     def dispatch_staged_decode(self, staged: StagedDecode) -> PendingDecode:
         """Fill the readback-dependent slots of a staged decode and
         enqueue it. By dispatch time the previous step has committed, so
         ``num_computed_tokens``/``all_token_ids`` hold exactly what a
         synchronous engine would see here — async staging never changes
         the dispatched bytes, only when the host work happened."""
+        if staged.prepared is None:
+            self._fill_decode(staged)
+        n = len(staged.seqs)
+        with self._dispatch_lock, self._dispatching(
+            "decode_window", B=staged.B, K=staged.k
+        ):
+            arrays = self._sync_locked(
+                _OP_DECODE, staged.B, staged.k, staged.all_greedy,
+                staged.arrays,
+            )
+            packed = self._exec_decode(
+                arrays, staged.k, staged.all_greedy, self._take_step(staged)
+            )
+        return PendingDecode(
+            [(packed, list(range(n)), staged.k)], n, staged.k
+        )
+
+    def _fill_decode(self, staged: StagedDecode) -> None:
+        """The host half of ``dispatch_staged_decode``: tokens (or where
+        the device finds them), positions and seeds into
+        ``staged.arrays``."""
         first = staged.arrays["first"]
         start = staged.arrays["start"]
         # ONE [B, K] rng block per decode dispatch, drawn here so the
@@ -2843,24 +3005,70 @@ class ModelRunner:
         )
         staged.arrays["seeds"] = seeds
         for i, s in enumerate(staged.seqs):
-            req = s.request
-            first[i] = req.token_at(req.num_computed_tokens)
-            start[i] = req.num_computed_tokens
+            first[i] = self._fill_token_source(staged.arrays, i, s)
+            start[i] = s.start_pos
         self._overwrite_seeded_rows(seeds, staged.seqs, staged.k)
         n = len(staged.seqs)
         self.live_tokens_total += n * staged.k
         self.padded_tokens_total += (staged.B - n) * staged.k
-        with self._dispatch_lock, self._dispatching(
-            "decode_window", B=staged.B, K=staged.k
-        ):
-            arrays = self._sync_locked(
-                _OP_DECODE, staged.B, staged.k, staged.all_greedy,
-                staged.arrays,
+
+    def prepare_staged(self, staged: StagedDecode | StagedUnified) -> None:
+        """Fill ``staged`` and put its payload on the device AHEAD of its
+        dispatch, while the step in front of it still runs: with its decode
+        rows' tokens taken from the device, nothing of the fill waits for
+        that step, so the dispatch itself is the jitted call alone. The
+        caller dispatches ``staged`` next, or forgets it (``unprepare``)
+        before it stages the step anew. (Not in a lockstep group: the
+        broadcast of the filled arrays IS the dispatch there.)"""
+        if self._multihost or staged.prepared is not None:
+            return
+        rng = self._np_rng.bit_generator.state
+        counters = {k: getattr(self, k) for k in self._FILL_COUNTERS}
+        if isinstance(staged, StagedDecode):
+            self._fill_decode(staged)
+            shape = (_OP_DECODE, staged.B, staged.k)
+        else:
+            self._fill_unified(staged)
+            shape = (
+                (_OP_FLAT, staged.B, staged.T) if staged.flat
+                else (_OP_UNIFIED, staged.B, (staged.Q << 20) | staged.T)
             )
-            packed = self._exec_decode(arrays, staged.k, staged.all_greedy)
-        return PendingDecode(
-            [(packed, list(range(n)), staged.k)], n, staged.k
+        staged.prepared = _Prepared(
+            self._put_step(*shape, staged.arrays), rng, counters
         )
+
+    def shape_is_warm(self, staged: StagedDecode | StagedUnified) -> bool:
+        """Has the step program ``staged`` goes to been called at its shape
+        (``_run_step``'s key)? A first call is seconds of tracing and
+        lowering, and its time follows the Python path it is reached on
+        (PERF.md section 7 (k)): the engine keeps it on the path the set-up
+        bound was set with."""
+        if isinstance(staged, StagedDecode):
+            key = (self._multi, staged.B, staged.k)
+        elif staged.flat:
+            key = (self._flat, self.flat_rows, staged.T)
+        else:
+            key = (self._unified, staged.B, (staged.Q << 20) | staged.T)
+        return (*key, staged.all_greedy) in self._called
+
+    def unprepare(self, staged) -> None:
+        """Forget that ``staged`` was prepared: it will not be dispatched
+        as it is. The seeds' rng and the counters go back to where its fill
+        found them (nothing else draws or counts in between: the prepared
+        step is the next one out)."""
+        p = getattr(staged, "prepared", None)
+        if p is None:
+            return
+        staged.prepared = None
+        self._np_rng.bit_generator.state = p.rng_state
+        for k, v in p.counters.items():
+            setattr(self, k, v)
+
+    @staticmethod
+    def _take_step(staged) -> jax.Array | None:
+        """The payload ``prepare_staged`` put ahead, once."""
+        p, staged.prepared = staged.prepared, None
+        return None if p is None else p.step
 
     @profiling.spanned("llmd.runner.build")
     def stage_spec_verify(self, seqs: list[ScheduledSeq]) -> StagedVerify:
@@ -3015,6 +3223,7 @@ class ModelRunner:
             "start": np.zeros(B, np.int32),
             "active": active,
             "seeds": np.zeros((B, k_steps), np.uint32),
+            **self._token_source_arrays(B),
         })
         sub = [seqs[i] for i in idxs]
         all_greedy = all(s.request.sampling.greedy for s in sub)
@@ -3109,6 +3318,7 @@ class ModelRunner:
             "page_table": self._page_table(row_seqs, B),
             "temp": temp, "top_k": top_k, "top_p": top_p,
             "seeds": np.zeros((B, S), np.uint32),
+            **self._token_source_arrays(B),
         }
         if self.state_pool:
             # A row's slot of the state pool (its sequence's "ring" of one).
@@ -3149,13 +3359,15 @@ class ModelRunner:
         so hot sampling is reproducible within a mode, not across the
         unified/split switch — the same contract as spec on/off.)"""
         a = staged.arrays
-        self._fill_unified(staged)
+        if staged.prepared is None:
+            self._fill_unified(staged)
+        step = self._take_step(staged)
         if staged.flat:
             with self._dispatch_lock, self._dispatching("flat", T=staged.T):
                 arrays = self._sync_locked(
                     _OP_FLAT, staged.B, staged.T, staged.all_greedy, a
                 )
-                packed = self._exec_flat(arrays, staged.all_greedy)
+                packed = self._exec_flat(arrays, staged.all_greedy, step)
         else:
             with self._dispatch_lock, self._dispatching(
                 "unified", B=staged.B, Q=staged.Q, T=staged.T
@@ -3165,7 +3377,7 @@ class ModelRunner:
                     staged.all_greedy, a,
                 )
                 packed = self._exec_unified(
-                    arrays, staged.Q, staged.all_greedy
+                    arrays, staged.Q, staged.all_greedy, step
                 )
         return PendingUnified(
             packed, staged.S, list(staged.prefill_rows),
@@ -3199,16 +3411,13 @@ class ModelRunner:
                 w = min(staged.row_plan[r], seq.num_tokens - off)
                 toks = req.tokens_between(start, start + w)
                 kind[r] = _KIND_PREFILL
+                if off + w == seq.num_tokens:  # the sub-row that samples
+                    a["tok_slot"][r] = self._token_slot(seq)
             else:
-                nc = req.num_computed_tokens
-                start = nc
+                start = seq.start_pos
                 draft = seq.draft_tokens or []
-                if draft:
-                    toks = [req.token_at(nc), *draft]
-                    kind[r] = _KIND_VERIFY
-                else:
-                    toks = [req.token_at(nc)]
-                    kind[r] = _KIND_DECODE
+                toks = [self._fill_token_source(a, r, seq), *draft]
+                kind[r] = _KIND_VERIFY if draft else _KIND_DECODE
                 w = len(toks)
             stream[t : t + w] = toks
             row_start[r] = t
@@ -3403,6 +3612,7 @@ class ModelRunner:
             "kvlens": np.zeros(B, np.int32),
             "kind": np.zeros(B, np.uint8),
             "seeds": np.zeros((B, S), np.uint32),
+            **self._token_source_arrays(B),
         })
         all_greedy = all(s.request.sampling.greedy for s in row_seqs)
         return StagedUnified(
@@ -3425,6 +3635,7 @@ class ModelRunner:
         decode: PendingDecode | None,
         unified: PendingUnified | None = None,
         poll=None,
+        at_ready=None,
     ) -> tuple[StepResult | None, StepResult | None]:
         """Block on one engine step's token readback: every dispatched
         program's packed output comes back in a SINGLE coalesced
@@ -3438,10 +3649,18 @@ class ModelRunner:
         that bring requests get the interpreter, until the outputs are
         there; what it admits meanwhile costs the device nothing.
 
-        Two spans, one after the other: ``llmd.runner.wait`` ends when
-        the host KNOWS that every output is ready, ``llmd.runner.readback``
-        holds the transfer and the parsing. ``last_wait`` keeps both
-        instants and the bound on how late the first was."""
+        ``at_ready`` (the pipelined step's early dispatch): called once,
+        the moment the outputs are known to be ready and BEFORE they are
+        read back, so that the next program is on the device's queue while
+        the host reads, commits and delivers this one. A ``device_get`` of
+        a ready 1-2 KB output does not queue behind a running program
+        (0.47-0.50 ms under a 7 or 20 ms program against 0.55 ms alone on
+        a v5e; PERF.md section 6, PR 49).
+
+        Two spans, ``llmd.runner.wait`` ends when the host KNOWS that every
+        output is ready, ``llmd.runner.readback`` holds the transfer and
+        the parsing; ``at_ready`` runs between them. ``last_wait`` keeps
+        the instants and the bound on how late the first was."""
         packs: list[jax.Array] = []
         if prefill is not None:
             packs.extend(p for p, _ in prefill.entries)
@@ -3451,7 +3670,9 @@ class ModelRunner:
             packs.append(unified.packed)
         if not packs:
             now = time.monotonic()
-            self.last_wait = WaitTiming(0.0, now, now)
+            self.last_wait = WaitTiming(0.0, now, now, now)
+            if at_ready is not None:
+                at_ready()
             return None, None
         with profiling.span("llmd.runner.wait"):
             if poll is None:
@@ -3465,6 +3686,9 @@ class ModelRunner:
                     t_unready = time.monotonic()
                     time.sleep(_POLL_S)
             t_ready = time.monotonic()
+        if at_ready is not None:
+            at_ready()
+        t_from = time.monotonic()
         with profiling.span("llmd.runner.readback") as span:
             if dist.is_multihost():
                 hosts = [dist.replicated_to_host(p) for p in packs]
@@ -3474,7 +3698,7 @@ class ModelRunner:
             results = self._split_results(prefill, decode, unified, hosts)
             self.last_wait = WaitTiming(
                 0.0 if poll is None else t_ready - t_unready,
-                t_ready, time.monotonic(),
+                t_ready, t_from, time.monotonic(),
             )
         return results
 

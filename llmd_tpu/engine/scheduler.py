@@ -35,6 +35,14 @@ from llmd_tpu.engine.sampler import accept_draft_tokens
 AT_PROMPT_END, AT_RUN_END, AT_FINISH = "prompt_end", "run_end", "finish"
 
 
+def token_slot_count(max_num_seqs: int) -> int:
+    """Entries of the runner's ``last_tokens``: one a running request, and
+    one a request that ended under a step still in flight (it keeps its
+    entry, with its pages, until that step has landed, while its row
+    already runs another request)."""
+    return 2 * max_num_seqs
+
+
 @dataclasses.dataclass
 class ScheduledSeq:
     request: Request
@@ -50,7 +58,9 @@ class ScheduledSeq:
 
     @property
     def start_pos(self) -> int:
-        return self.request.num_computed_tokens
+        """Where the row starts, asked before its own dispatch is noted:
+        behind what is committed and what is still in flight."""
+        return self.request.num_dispatched_tokens
 
 
 @dataclasses.dataclass
@@ -151,6 +161,15 @@ class EngineScheduler:
         # (their pages would be freed under the device's feet). Sync
         # engines leave this empty.
         self.protected: set[str] = set()
+        # ``Request.token_slot``s not handed out (lowest first).
+        self._token_slots = list(
+            reversed(range(token_slot_count(scheduler_config.max_num_seqs)))
+        )
+        # Rows that landed for a request which had ended meanwhile (a stop
+        # token or an abort the schedule could not foresee, met at the
+        # commit of the step before, with the row already dispatched):
+        # EngineStats.async_wasted_rows_total.
+        self.wasted_rows = 0
         # Speculative decoding (SchedulerConfig.speculative_ngram):
         # decode rows are planned at the max-acceptance token count
         # (1 + spec_k) and the accepted prefix is resolved per row at
@@ -193,8 +212,7 @@ class EngineScheduler:
     def abort_request(self, request_id: str) -> Request | None:
         for req in self.running:
             if req.request_id == request_id:
-                self._release(req)
-                self.running.remove(req)
+                self._retire(req)
                 req.finish(FinishReason.ABORT)
                 return req
         for req in list(self.waiting):
@@ -415,7 +433,7 @@ class EngineScheduler:
                 break
             if self._batch_band and req.is_batch:
                 break  # backfill phase owns batch admission
-            if len(self.running) >= self.config.max_num_seqs:
+            if self._rows_taken() >= self.config.max_num_seqs:
                 # A running batch row's slot yields to an interactive
                 # admission; without batch victims the step is full.
                 if not (
@@ -468,6 +486,21 @@ class EngineScheduler:
             scheduled.add(req.request_id)
             budget -= chunk
         return budget
+
+    def _rows_taken(self) -> int:
+        """The rows of ``max_num_seqs`` that are not free for an admission:
+        the running requests less those that the step in flight ends for
+        certain. The staged step is dispatched before that step's commit
+        (``LLMEngine._dispatch_early``), so a row its FORESEEN finish
+        frees is given to the speculative schedule, or a waiting request
+        would ride one step later than behind a synchronous step. (The
+        row: the pages, the ring or state slot of the request that ends
+        are free only once the step has landed, and an admission that
+        needs them waits for that as before.)"""
+        n = len(self.running)
+        if n < self.config.max_num_seqs:
+            return n  # (the one test of most calls)
+        return n - sum(1 for r in self.running if self._ends_in_flight(r))
 
     def _ends_in_flight(self, req: Request) -> bool:
         """The speculative schedule's one certainty: the step in flight
@@ -541,6 +574,8 @@ class EngineScheduler:
 
     def _note_admitted(self, req: Request) -> None:
         req.status = RequestStatus.RUNNING
+        if req.token_slot < 0:
+            req.token_slot = self._token_slots.pop()
         if self.capture_hook is not None:
             req.finish_capture_at = self._finish_boundary(req)
         if req.queue_wait_ms is None:
@@ -596,6 +631,8 @@ class EngineScheduler:
                 or not req.in_decode_dispatched
             ):
                 continue  # reset by a preemption earlier in this pass
+            if self._ends_in_flight(req):
+                continue  # (as an interactive row: its row is given away)
             if budget <= 0:
                 break
             if not self._ensure_pages(req, 1):
@@ -816,6 +853,7 @@ class EngineScheduler:
             # recomputing the whole prefix.
             victim.num_pending_tokens = 0
             self.protected.discard(victim.request_id)
+            self._return_token_slot(victim)
         else:
             self._release(victim)
         self.running.remove(victim)
@@ -835,9 +873,15 @@ class EngineScheduler:
         )
         return True
 
+    def _return_token_slot(self, req: Request) -> None:
+        if req.token_slot >= 0:
+            self._token_slots.append(req.token_slot)
+            req.token_slot = -1
+
     def _release(self, req: Request) -> None:
         req.num_pending_tokens = 0
         self.protected.discard(req.request_id)
+        self._return_token_slot(req)
         if req.block_ids:
             # Paged-out indexes hold stale ids — the pager freed (and the
             # allocator may have recycled) those pages when it spilled
@@ -891,6 +935,8 @@ class EngineScheduler:
         page = self.cache_config.page_size
         for seq in batch.prefills:
             req = seq.request
+            if req.is_finished:
+                continue  # a wasted row leaves nothing behind
             at = req.num_dispatched_tokens
             run_end = req.swa_capture[0] * page if req.swa_capture else None
             if run_end is not None and at >= run_end:
@@ -913,13 +959,26 @@ class EngineScheduler:
             # a speculative row's may be taken back), ring and slot alike.
             req = seq.request
             at = req.finish_capture_at  # (0: none, the one test of most rows)
-            if at and seq.num_tokens == 1 and req.num_dispatched_tokens == at:
+            if (
+                at and seq.num_tokens == 1 and req.num_dispatched_tokens == at
+                and not req.is_finished
+            ):
                 self.capture_hook(req, AT_FINISH)
 
     def _commit_pending(self, seq: ScheduledSeq) -> None:
         req = seq.request
         req.num_pending_tokens = max(0, req.num_pending_tokens - seq.num_tokens)
-        self.protected.discard(req.request_id)
+        if not req.num_pending_tokens:  # (else: a row of the next step)
+            self.protected.discard(req.request_id)
+
+    def _land_wasted(self, seq: ScheduledSeq) -> None:
+        """A row that has landed for a request which ended at the commit
+        of the step before (``_retire`` found the row in flight): nothing
+        of it is kept, and what the request held goes back now."""
+        self._commit_pending(seq)
+        self.wasted_rows += 1
+        if not seq.request.num_pending_tokens:
+            self._release(seq.request)
 
     def update_after_step(
         self, batch: ScheduledBatch, sampled: dict[str, list[int]]
@@ -935,6 +994,9 @@ class EngineScheduler:
         accepted: dict[str, list[int]] = {}
         for seq in batch.prefills:
             req = seq.request
+            if req.is_finished:
+                self._land_wasted(seq)
+                continue
             self._commit_pending(seq)
             req.num_computed_tokens += seq.num_tokens
             if req.is_batch:
@@ -950,6 +1012,9 @@ class EngineScheduler:
             self._commit_full_pages(req)
         for seq in batch.decodes:
             req = seq.request
+            if req.is_finished:
+                self._land_wasted(seq)
+                continue
             self._commit_pending(seq)
             window = sampled[req.request_id]
             if seq.draft_tokens:
@@ -1044,9 +1109,18 @@ class EngineScheduler:
         if self.finish_hook is not None:
             # P/D producer export runs here, while block_ids are live.
             self.finish_hook(req)
-        self._release(req)
-        self.running.remove(req)
+        self._retire(req)
         req.finish(reason)
+
+    def _retire(self, req: Request) -> None:
+        """``req`` stops running. What it holds (pages, ring or state slot,
+        token slot) goes back at once, or, where a step still in flight
+        carries a row of it (dispatched before the commit that ended it),
+        when that step has landed (``_land_wasted``): a dispatched program
+        may still write there."""
+        self.running.remove(req)
+        if not req.num_pending_tokens:
+            self._release(req)
 
     def _check_stop(self, req: Request, token: int) -> FinishReason | None:
         s = req.sampling

@@ -49,6 +49,12 @@ class ShipperServer:
         self._native = native.load()
         self._handle = None
         self._py = None
+        # Registrations inside the native call (ctypes lets go of the
+        # interpreter there): ``close`` waits them out before it destroys
+        # the server under them.
+        self._lock = threading.Lock()
+        self._idle = threading.Condition(self._lock)
+        self._registering = 0  # llmd: guarded_by(_lock)
         if self._native is not None:
             self._handle = self._native.kvship_server_create(port)
         if self._handle:
@@ -73,12 +79,23 @@ class ShipperServer:
         native server, which makes the single owning copy — no Python-side
         concat or intermediate copy of a multi-hundred-MB KV payload.
         """
-        if self._handle is None and self._py is None:
-            # Closed/crashed shipper: a clean error for the staging thread
-            # to log — NOT an AttributeError that could leak upward and
-            # take the engine step loop down with it.
-            raise RuntimeError("shipper server is closed")
-        if self._handle:
+        with self._lock:
+            handle = self._handle
+            if handle is None and self._py is None:
+                # Closed/crashed shipper: a clean error for the staging
+                # thread to log — NOT an AttributeError that could leak
+                # upward and take the engine step loop down with it.
+                raise RuntimeError("shipper server is closed")
+            self._registering += 1
+        try:
+            self._register(handle, key, data, lease_ms, header)
+        finally:
+            with self._lock:
+                self._registering -= 1
+                self._idle.notify_all()
+
+    def _register(self, handle, key: str, data, lease_ms: int, header: bytes) -> None:
+        if handle:
             mv = memoryview(data).cast("B")
             n = len(mv)
             if mv.readonly:  # bytes path (tests / small payloads): copy
@@ -91,10 +108,13 @@ class ShipperServer:
             )
             hptr = ctypes.cast(hbuf, ctypes.POINTER(ctypes.c_uint8))
             self._native.kvship_register2(
-                self._handle, key.encode(), hptr, len(header), dptr, n, lease_ms
+                handle, key.encode(), hptr, len(header), dptr, n, lease_ms
             )
         else:
-            self._py.register(key, header + bytes(data), lease_ms)
+            py = self._py
+            if py is None:  # closed meanwhile
+                raise RuntimeError("shipper server is closed")
+            py.register(key, header + bytes(data), lease_ms)
 
     def unregister(self, key: str) -> bool:
         if self._handle:
@@ -120,9 +140,14 @@ class ShipperServer:
         return self._py.expired_count if self._py else 0
 
     def close(self) -> None:
-        if self._handle:
-            self._native.kvship_server_destroy(self._handle)
-            self._handle = None
+        with self._lock:
+            # No registration starts on the native server from here on,
+            # and those inside it are waited out.
+            handle, self._handle = self._handle, None
+            while handle and self._registering:
+                self._idle.wait()
+        if handle:
+            self._native.kvship_server_destroy(handle)
         elif self._py:
             self._py.close()
             self._py = None

@@ -172,6 +172,12 @@ def render_metrics(
         # requests admitted after the speculative schedule.
         "steps_prestaged_total": stats.steps_prestaged_total,
         "steps_topped_up_total": stats.steps_topped_up_total,
+        # Steps dispatched before the step in front of them was read
+        # back, and rows computed for a request that had ended meanwhile.
+        "steps_dispatched_before_readback_total": (
+            stats.steps_dispatched_before_readback_total
+        ),
+        "async_wasted_rows_total": stats.async_wasted_rows_total,
         "decode_dispatches_total": stats.decode_dispatches_total,
         # Unified single-dispatch steps (the family split of
         # decode_dispatches_total) and EVERY program engine steps

@@ -426,15 +426,22 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
               [f"rate(llmd:steps_prestaged_total{M}[5m]) / "
                f"rate(llmd:engine_steps_total{M}[5m])",
                f"rate(llmd:steps_topped_up_total{M}[5m]) / "
+               f"rate(llmd:engine_steps_total{M}[5m])",
+               f"rate(llmd:steps_dispatched_before_readback_total{M}[5m]) / "
                f"rate(llmd:engine_steps_total{M}[5m])"],
-              legends=["prestaged share", "topped-up share"],
+              legends=["prestaged share", "topped-up share",
+                       "dispatched before the readback"],
               unit="percentunit",
               desc="How often the pipeline engages: the share of steps "
                    "whose batch was scheduled and staged while the step "
                    "before ran (near 1 under load; 0 on an engine that "
-                   "keeps the synchronous step), and of steps whose "
+                   "keeps the synchronous step), of steps whose "
                    "staged batch took in requests that arrived after the "
-                   "speculative schedule."),
+                   "speculative schedule, and of steps dispatched the "
+                   "moment the step before was seen ready, before its "
+                   "readback (decode rows take their token from the "
+                   "device; a step with drafts or a fused window waits "
+                   "for the commit)."),
         panel("Step time by phase",
               [f"rate(llmd:step_{ph}_ms_total{M}[5m]) / "
                f"rate(llmd:engine_steps_total{M}[5m])"
@@ -504,12 +511,16 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
               desc="Step cadence; flat at 0 while requests run = the "
                    "step loop is wedged."),
         panel("Async rollbacks /s",
-              [f"rate(llmd:async_rollbacks_total{M}[5m])"],
+              [f"rate(llmd:async_rollbacks_total{M}[5m])",
+               f"rate(llmd:async_wasted_rows_total{M}[5m])"],
+              legends=["staged rows rolled back", "dispatched rows wasted"],
               thresholds=[(None, "green"), (5, "yellow")],
-              desc="Staged rows invalidated by late EOS/max-tokens "
-                   "finishes. A few per second is the async contract "
-                   "working; a surge means the speculate-ahead window "
-                   "mismatches the workload's stop behavior."),
+              desc="Rows a late EOS / stop-token finish or an abort "
+                   "found staged (dropped before their dispatch) or "
+                   "already dispatched (computed, their token dropped, "
+                   "one row-step each). A few per second is the async "
+                   "contract working; a surge means the speculate-ahead "
+                   "window mismatches the workload's stop behavior."),
         panel("Dispatches per emitted token",
               [f"llmd:dispatches_per_emitted_token{M}",
                f"rate(llmd:decode_dispatches_total{M}[5m])"],

@@ -50,7 +50,7 @@ def tokens(n, seed=0):
 
 # model -> (cache keywords, scheduler keywords, shared prefix tokens)
 GEOMETRY = {
-    "tiny": (dict(page_size=4, num_blocks=128), dict(max_num_batched_tokens=64), 0),
+    "tiny": (dict(page_size=4, num_blocks=128), dict(max_num_batched_tokens=64), 48),
     # 3 window layers to 1 full: two KV pools, retained sliding sections
     "tiny-exaone": (dict(page_size=4, num_blocks=256, swa_ring=True), dict(max_num_batched_tokens=32), 48),
     # Mamba-2 mixers + one attention layer: a state pool with snapshots
@@ -61,8 +61,12 @@ GEOMETRY = {
     "tiny-mla": (dict(page_size=4, num_blocks=256), dict(max_num_batched_tokens=32), 48),
     # gated delta-rule mixers: the state pool's other kind of slot
     "tiny-qwen3-next": (dict(page_size=4, num_blocks=256), dict(max_num_batched_tokens=16), 40),
+    # latent attention with the indexer: the latent plane and its index plane on the flat step
+    "tiny-mla-dsa": (dict(page_size=8, num_blocks=128), dict(max_num_batched_tokens=48), 112),
 }
-HYBRID = sorted(set(GEOMETRY) - {"tiny", "tiny-qwen3-next"})
+# the paged pool, the ring, the state pool, the sparse-attention plane, the
+# latent plane and the bucketed latent step
+HYBRID = sorted(set(GEOMETRY) - {"tiny-qwen3-next"})
 
 
 def make_engine(model: str, pipelined: bool, max_seqs=4, num_blocks=None, model_len=None, cache_kw=None, **sched) -> LLMEngine:
@@ -133,9 +137,12 @@ def session(model: str, pipelined: bool, stop=()):
 def test_pipelined_equals_synchronous_with_a_hit_and_a_mid_batch_stop(model):
     """The same tokens, request by request, and the same caches afterwards:
     the hits taken, the pages and ring or state slots still held. The stop
-    token ends ONE row while its mates decode on, a step after the pipelined
-    engine has staged that row again: it is rolled back and gives back its
-    pages, its ring or slot."""
+    token ends ONE row while its mates decode on, met at the commit a step
+    after the pipelined engine has DISPATCHED that row again: the row is
+    wasted (its token dropped), and gives back its pages, its ring or slot
+    when it has landed. Every step but the ones a pipeline starts with was
+    dispatched before the step in front of it was read back, among them the
+    decode step behind the chunk that completes a prompt."""
     _, free_run = session(model, pipelined=False)
     stop = free_run[0][0][3]  # ends request 0 early; the others may never emit it
     sync, want = session(model, pipelined=False, stop=(stop,))
@@ -149,13 +156,81 @@ def test_pipelined_equals_synchronous_with_a_hit_and_a_mid_batch_stop(model):
     assert got[0][2].num_cached_tokens > 0  # the batch did take a hit
     assert hit_counters(pipe) == hit_counters(sync)
     assert pools_in_use(pipe) == pools_in_use(sync)
-    assert pipe._inflight is None and pipe.stats.async_rollbacks_total >= 1
+    wasted = pipe.stats.async_wasted_rows_total
+    assert pipe._inflight is None and wasted >= 1 and pipe.stats.async_rollbacks_total == 0
     assert pipe.stats.steps_prestaged_total > 0 and sync.stats.steps_prestaged_total == 0
-    # the host-counted kernel counters count dispatched rows, never a rolled-back one
+    early = pipe.stats.steps_dispatched_before_readback_total
+    assert 0 < early <= pipe.stats.steps_prestaged_total and sync.stats.steps_dispatched_before_readback_total == 0
+    assert early >= pipe.stats.steps_decode_total - 4  # (four pipelines start here)
+    # the host-counted kernel counters count dispatched rows: the synchronous
+    # engine's, and one decode token more for each wasted row
+    assert pipe.stats.live_tokens_total == sync.stats.live_tokens_total + wasted
     for name in ("ssm_update_rows_total", "ssm_scan_tokens_total", "sparse_bound_tokens_total",
-                 "sparse_unbound_tokens_total", "indexer_keys_scored_total", "live_tokens_total",
-                 "attn_shared_tile_tokens_total"):
-        assert getattr(pipe.stats, name) == getattr(sync.stats, name), name
+                 "sparse_unbound_tokens_total", "indexer_keys_scored_total", "attn_shared_tile_tokens_total"):
+        assert 0 <= getattr(pipe.stats, name) - getattr(sync.stats, name) <= wasted * 65536, name  # (a row scores its whole context)
+    # nothing of the wasted row is in the prefix index or a retained entry
+    assert set(pipe.allocator._cached) == set(sync.allocator._cached)
+    if pipe._swa_sections is not None:
+        assert set(pipe._swa_sections._entries) == set(sync._swa_sections._entries)
+        assert pipe._swa_sections.stats() == sync._swa_sections.stats()
+
+
+class Payloads:
+    """What ``_put_step`` was handed, dispatch by dispatch: the rows' token
+    slots, which of them take their token from the device, the tokens the
+    host packed for them, and whether the step in front was still unread."""
+
+    def __init__(self, eng: LLMEngine):
+        self.eng, self.steps = eng, []
+        put = eng.runner._put_step
+
+        def putting(op, B, QK, arrays):
+            host = None  # (a prefill program's rows read no token of the device's)
+            if "first" in arrays:
+                host = arrays["first"]
+            elif "stream" in arrays:  # (pad rows start at the stream's end)
+                host = arrays["stream"][np.minimum(arrays["row_start"], len(arrays["stream"]) - 1)]
+            dev = arrays.get("tok_dev", np.zeros(B, np.uint8))
+            self.steps.append((eng._inflight is not None, arrays["tok_slot"].copy(), dev.copy(),
+                               None if host is None else np.asarray(host).copy()))
+            return put(op, B, QK, arrays)
+
+        eng.runner._put_step = putting
+
+
+@pytest.mark.parametrize("model", HYBRID)
+def test_the_decode_row_behind_an_unread_step_takes_its_token_from_the_device(model):
+    """A prompt of three chunks and a batch mate that decodes meanwhile. The
+    chunk that completes the prompt goes out with step N; the sequence's first
+    decode row goes out with N+1, before N is read back: the host packs no
+    token for it (it has none), flags the row, and names the slot that N's row
+    was told to leave its sample in. Every later decode row of a pipelined
+    step is fed the same way, the step a pipeline starts with feeds the host's
+    tokens, and the streams are the synchronous engine's."""
+    budget = min(GEOMETRY[model][1]["max_num_batched_tokens"], 32)
+    mate, long = tokens(7, seed=3), tokens(2 * budget + 5, seed=4)
+    (want_mate, _, _), (want, _, _) = serve(
+        make_engine(model, pipelined=False, max_num_batched_tokens=budget), [mate, long], max_tokens=6)
+    eng = make_engine(model, pipelined=True, max_num_batched_tokens=budget)
+    warm(eng, [mate, long], 6)
+    seen = Payloads(eng)
+    (got_mate, _, _), (got, _, r) = serve(eng, [mate, long], max_tokens=6)
+    assert (got_mate, got) == (want_mate, want)
+    none = eng.runner.token_slots
+    fed = [(i, row) for i, (_, slots, dev, _) in enumerate(seen.steps) for row in np.flatnonzero(dev)]
+    assert fed and all(seen.steps[i][0] for i, _ in fed)  # only behind a step not read back
+    for i, row in fed:
+        early, slots, dev, host = seen.steps[i]
+        assert slots[row] < none and host[row] == 0  # the device's token: the host packed none
+        # the step in front wrote that slot: a decode row, or the chunk that completed the prompt
+        assert slots[row] in seen.steps[i - 1][1]
+    # the long prompt's first decode row is among them, behind its last chunk
+    slot_of_long = [s for _, slots, dev, _ in seen.steps for s in slots[dev != 0]]
+    assert len(set(slot_of_long)) == 2  # both sequences were fed from the device
+    # every decode row of an early step is device-fed; a step with nothing in flight feeds host tokens
+    assert not any(dev.any() for early, _, dev, _ in seen.steps if not early)
+    assert eng.stats.steps_dispatched_before_readback_total > 0 and eng.stats.async_wasted_rows_total == 0
+    assert r.token_slot == -1 and sorted(eng.scheduler._token_slots) == list(range(none))
 
 
 # --- a retained-state capture costs the host's turn nothing -------------------------
@@ -203,6 +278,13 @@ class Recorder:
             if name.startswith("dispatch_"):
                 setattr(r, name, dispatching(getattr(r, name)))
         copy, commit = r.copy_pages_on_device, eng.scheduler.update_after_step
+        note, self.noted = eng.scheduler.note_dispatch, None  # the batch dispatched last
+        # per hook call: is the request a row of the batch dispatched last?
+        self.behind_own_dispatch = []
+
+        def noting(batch):
+            self.noted = batch
+            return note(batch)
         capture, hook = eng._swa_sections.capture, eng.scheduler.capture_hook
         at = [None]  # the capture point of the hook that is running
         # per hook call: (point, request, its dispatched position, walks, pages hashed)
@@ -222,6 +304,7 @@ class Recorder:
             return capture(key, ring_ids, s0, n_pre, shared=shared)
 
         def hooking(req, point):
+            self.behind_own_dispatch.append(any(s.request is req for s in self.noted.seqs))
             before, at[0] = (len(self.walks), self.pages_hashed), point
             try:
                 return hook(req, point)
@@ -235,12 +318,28 @@ class Recorder:
         self.captured_at = []
         r.copy_pages_on_device = copying
         eng.scheduler.update_after_step = committing
+        eng.scheduler.note_dispatch = noting
         eng._swa_sections.capture = capturing
         eng.scheduler.capture_hook = hooking
 
     def own(self, point=AT_PROMPT_END):
         """The (key, n_pre, shared) captured at ``point``."""
         return [c for c, p in zip(self.captured, self.captured_at) if p == point]
+
+    def copies_lie_between_their_step_and_the_next(self) -> int:
+        """Every capture's copy was enqueued with its own step the one
+        dispatched last (the device runs it behind the step that wrote the
+        state and in front of the next, which overwrites it) and not yet
+        committed, and every step before it committed (a finish boundary's
+        key hashes the token the step before sampled): dispatch, copy, commit
+        behind a synchronous step; dispatch N+1, commit N, copy, dispatch N+2
+        where N+1 went out before N's readback. Returns the copies."""
+        assert all(self.behind_own_dispatch) and self.behind_own_dispatch
+        ev = self.events
+        copies = [i for i, e in enumerate(ev) if e == "capture-copy"]
+        for i in copies:
+            assert ev[:i].count("commit") == ev[:i].count("dispatch") - 1, ev[max(0, i - 4): i + 3]
+        return len(copies)
 
 
 def turns(eng: LLMEngine, n_turns=3, first=37, more=9, max_tokens=6, seed=40):
@@ -324,11 +423,13 @@ def test_a_captured_key_is_the_section_key_of_its_prompt(model, extra, monkeypat
 @pytest.mark.parametrize("model", RETAINING)
 @pytest.mark.parametrize("pipelined", [True, False], ids=["pipelined", "synchronous"])
 def test_a_captures_copy_is_dispatched_behind_its_step_and_outside_the_commit(model, pipelined, monkeypatch, tmp_path):
-    """Dispatch N, the copy, dispatch N+1, on both steps: the device runs
-    the copy behind the step that wrote the state and in front of the one
-    that overwrites it. The step's commit comes after the copy, and the
-    ``llmd.state.capture`` span lies in no ``llmd.step.commit`` span (in
-    the pipelined step: inside ``llmd.step.finish``, under step N+1)."""
+    """Dispatch N, the copy, dispatch N+1, on both steps and at every
+    capture point: the device runs the copy behind the step that wrote the
+    state and in front of the one that overwrites it. The step's own commit
+    comes after the copy, and the ``llmd.state.capture`` span lies in no
+    ``llmd.step.commit`` span (in the pipelined step: inside
+    ``llmd.step.finish``, under the step just dispatched, behind the commit
+    of the step before it)."""
     eng = make_engine(model, pipelined)
     shared = tokens(GEOMETRY[model][2], seed=5)
     serve(eng, [shared + tokens(7, seed=6)], max_tokens=4)  # (warm; leaves the shared pages behind)
@@ -341,18 +442,15 @@ def test_a_captures_copy_is_dispatched_behind_its_step_and_outside_the_commit(mo
         turns(eng, n_turns=2)
     finally:
         profiling.stop()
-    ev = rec.events
-    copies = [i for i, e in enumerate(ev) if e == "capture-copy"]
-    assert len(copies) == len(rec.captured) >= 5 and {AT_RUN_END, AT_PROMPT_END, AT_FINISH} == set(rec.captured_at)
-    for i in copies:
-        before = next(e for e in reversed(ev[:i]) if e != "capture-copy")
-        after = next(e for e in ev[i + 1:] if e != "capture-copy")
-        assert (before, after) == ("dispatch", "commit"), ev[max(0, i - 3): i + 3]
-    assert "seed-copy" in ev  # (the session's second turn took its hit)
+    copies = rec.copies_lie_between_their_step_and_the_next()
+    assert copies == len(rec.captured) >= 5 and {AT_RUN_END, AT_PROMPT_END, AT_FINISH} == set(rec.captured_at)
+    assert "seed-copy" in rec.events  # (the session's second turn took its hit)
+    if pipelined:
+        assert eng.stats.steps_dispatched_before_readback_total > 0
     spans = host_spans(tmp_path)
     captures, commits = ([(b, e) for name, b, e, _ in spans if name == want]
                          for want in ("llmd.state.capture", "llmd.step.commit"))
-    assert len(captures) == len(copies)
+    assert len(captures) == copies
     assert not any(b <= cb < e for cb, _ in captures for b, e in commits)
     if pipelined:
         # in a step with a commit (not the one a pipeline starts with): behind
@@ -418,7 +516,7 @@ def test_a_sessions_next_turn_starts_behind_its_own_last_answer(model, pipelined
     page turn 1's answer filled, not at turn 1's prompt's end, and reads,
     tokens and log-probs, as a run with prefix caching off. The capture fired
     once a turn, behind the dispatch of the decode step that left the state
-    AT that page (dispatch, copy, commit), under the key the next admission's
+    AT that page and in front of the next, under the key the next admission's
     walk computes for it, for one page hashed and no walk."""
     from llmd_tpu.engine.kv_cache import page_hashes_for_tokens
 
@@ -432,10 +530,7 @@ def test_a_sessions_next_turn_starts_behind_its_own_last_answer(model, pipelined
     assert fired == [(r1, at, [0, 1]), (r2, (len(second) + ANSWER - 1) // page * page, [0, 1])]
     key, n_pre, shared = rec.own(AT_FINISH)[0]
     assert (key, n_pre, shared) == (page_hashes_for_tokens(second, page)[at // page - 1], at // page, False)
-    ev = rec.events
-    for i in (i for i, e in enumerate(ev) if e == "capture-copy"):
-        around = (next(e for e in reversed(ev[:i]) if e != "capture-copy"), next(e for e in ev[i + 1:] if e != "capture-copy"))
-        assert around == ("dispatch", "commit"), ev[max(0, i - 3): i + 3]
+    assert rec.copies_lie_between_their_step_and_the_next() >= 2
     for (toks, lps, _), got, req in zip(uncached(model, pipelined, [first, second], ANSWER), (answer, answer2), (r1, r2)):
         assert got == toks
         np.testing.assert_allclose(np.asarray(req.output_logprobs), lps, atol=2e-5)
@@ -562,9 +657,10 @@ class Arrivals:
     def __init__(self, eng: LLMEngine, while_running: bool, prompt, sp):
         self.eng, self.while_running, self.prompt, self.sp = eng, while_running, prompt, sp
         self.polls_running, self.polls_read, self.rid = 0, 0, None
+        self.step_n = eng._inflight  # (behind its readback another step is in flight, or none)
 
     def __call__(self) -> int:
-        running = self.eng._inflight is not None
+        running = self.step_n is not None and self.eng._inflight is self.step_n
         self.polls_running += running
         self.polls_read += not running
         if self.rid is not None or running != self.while_running:
@@ -577,10 +673,12 @@ class Arrivals:
 @pytest.mark.parametrize("while_running", [True, False], ids=["while_the_device_runs", "after_the_readback"])
 def test_a_request_that_arrives_while_a_step_runs_rides_the_next(model, while_running):
     """Step N is in flight and step N+1 staged (the hook is polled while the
-    device runs), or N has just been read back (polled once more): the
-    arrival is admitted into the
-    staged batch and dispatched with it, as the synchronous engine, whose
-    intake runs between two steps, would have it; the step is counted."""
+    device runs): the arrival is admitted into the staged batch and
+    dispatched with it, as the synchronous engine, whose intake runs between
+    two steps, would have it; the step is counted. One that arrives when N
+    has been read back finds N+1 on the device already (it was dispatched the
+    moment N was seen ready, before the readback): it rides N+2, as one that
+    arrives while a synchronous engine's step N+1 runs."""
     sp = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
     first, late = tokens(9, seed=1), tokens(13, seed=2)
 
@@ -596,19 +694,26 @@ def test_a_request_that_arrives_while_a_step_runs_rides_the_next(model, while_ru
     for out in eng.step():  # N+1 staged, N read back, N+1 dispatched
         got[out.request_id].extend(out.new_token_ids)
     assert hook.rid is not None and hook.polls_running >= 1 and hook.polls_read == 1
-    batch = eng._inflight.batch  # (a state-space prompt ends in a chunk of its own: a may still prefill)
-    assert batch.prefills[-1].request.request_id == hook.rid and eng.stats.steps_topped_up_total == topped + 1
-    assert [s.request.request_id for s in batch.seqs if s.request.request_id != hook.rid] == [a]
+    assert eng.stats.steps_dispatched_before_readback_total >= 1
     got[hook.rid] = []
+    if not while_running:  # N+1 was on the device before the arrival
+        assert [s.request.request_id for s in eng._inflight.batch.seqs] == [a]
+        assert [r.request_id for r in eng.scheduler.waiting] == [hook.rid]
+        for out in eng.step():  # N+2: scheduled with the arrival waiting, no top-up
+            got[out.request_id].extend(out.new_token_ids)
+    batch = eng._inflight.batch  # (a state-space prompt ends in a chunk of its own: a may still prefill)
+    assert batch.prefills[-1].request.request_id == hook.rid
+    assert eng.stats.steps_topped_up_total == topped + while_running
+    assert [s.request.request_id for s in batch.seqs if s.request.request_id != hook.rid] == [a]
     while eng.has_work():
         for out in eng.step():
             got[out.request_id].extend(out.new_token_ids)
-    assert eng.stats.steps_topped_up_total == topped + 1
+    assert eng.stats.steps_topped_up_total == topped + while_running
 
     sync = make_engine(model, pipelined=False)
     warm(sync, [first, late], 6)  # (the same prefix cache and state pool as the pipelined engine's)
     want: dict = {sync.add_request(first, sp): []}
-    for _ in range(2):  # the step that landed, and step N
+    for _ in range(2 if while_running else 3):  # the step that landed, step N (and N+1)
         for out in sync.step():
             want[out.request_id].extend(out.new_token_ids)
     want[sync.add_request(late, sp)] = []  # between two steps
@@ -679,14 +784,17 @@ def test_a_staged_batch_row_reclaimed_by_a_head_that_then_fails_admission_is_not
     """After the readback an interactive arrival takes the staged batch-band
     row's pages (recompute-preemption) and still does not fit: nothing was
     added to the staged batch, and the preempted row must leave it all the
-    same, or the device would write its KV into pages that are free."""
+    same, or the device would write its KV into pages that are free. (On an
+    engine whose staged step waits for the commit, here one that drafts:
+    where the step is dispatched before the readback, the row is in flight
+    by then and protected.)"""
     page = GEOMETRY["tiny"][0]["page_size"]
     sp = SamplingParams(max_tokens=12, temperature=0.0, ignore_eos=True)
     held, batch_row, head = tokens(30, seed=1), tokens(6, seed=2), tokens(34, seed=3)
     alone = [serve(make_engine("tiny", pipelined=False), [p], max_tokens=12)[0][0]
              for p in (held, batch_row, head)]
 
-    eng = make_engine("tiny", pipelined=True, num_blocks=16)
+    eng = make_engine("tiny", pipelined=True, num_blocks=16, speculative_ngram=True, spec_ngram_k=1)
     warm(eng, [held, batch_row], 12)
     dispatch = eng._dispatch_async
 
@@ -722,6 +830,11 @@ def test_a_staged_batch_row_reclaimed_by_a_head_that_then_fails_admission_is_not
 # --- the host's turn between two programs ----------------------------------------------
 
 TURN = ("llmd.runner.readback", "llmd.step.commit", "llmd.sched.schedule", "llmd.runner.launch")
+# a step dispatched the moment the one before it is seen ready: the launch first
+EARLY_TURN = ("llmd.runner.launch", "llmd.runner.readback", "llmd.step.commit")
+# an engine whose staged step wants the tokens on the host (its proposer drafts
+# from them) keeps the order readback, commit, dispatch
+DRAFTS = dict(speculative_ngram=True, spec_ngram_k=1)
 
 
 class LateArrivals:
@@ -739,16 +852,21 @@ class LateArrivals:
         return 1
 
 
-@pytest.mark.parametrize("top_up_in_the_gap", [False, True], ids=["plain_turn", "top_up_after_the_readback"])
-def test_the_spans_of_a_pipelined_step_tile_the_turn(tmp_path, top_up_in_the_gap):
-    """From the end of ``llmd.runner.wait`` to the dispatch's return every
-    instant lies in exactly one of readback, commit, schedule (the top-up
-    WITH its restage) and launch: in that order, each one's end the next
-    one's start, and the readback behind the wait, not inside it. (A
-    junction is a few microseconds of Python; the median over the steps is
-    held to 50 us, so that one preempted step of a busy machine does not
-    fail what every step would show were there code between two spans.)"""
-    eng = make_engine("tiny", pipelined=True, max_seqs=8)
+@pytest.mark.parametrize("case", ["dispatched_at_ready", "plain_turn", "top_up_after_the_readback"])
+def test_the_spans_of_a_pipelined_step_tile_the_turn(tmp_path, case):
+    """From the end of ``llmd.runner.wait`` on, every instant lies in exactly
+    one span, each one's end the next one's start. A step dispatched the
+    moment the one before it was seen ready: launch (all the device waits
+    for), then readback and commit, under the device. A step that waits for
+    the commit: readback, commit, schedule (the top-up WITH its restage) and
+    launch, to the dispatch's return. The readback lies behind the wait, not
+    inside it. (A junction is a few microseconds of Python; the median over
+    the steps is held to 50 us, so that one preempted step of a busy machine
+    does not fail what every step would show were there code between two
+    spans.) What a step dispatched at ready is filled with, and the put of
+    its payload, were done ahead, while the step before it ran."""
+    early, top_up_in_the_gap = case == "dispatched_at_ready", case == "top_up_after_the_readback"
+    eng = make_engine("tiny", pipelined=True, max_seqs=8, **({} if early else DRAFTS))
     warm(eng, [tokens(9, seed=1), tokens(5, seed=2), tokens(5, seed=3)], 10)
     sp = SamplingParams(max_tokens=12, temperature=0.0, ignore_eos=True)
     eng.add_request(tokens(9, seed=1), sp)
@@ -773,22 +891,30 @@ def test_the_spans_of_a_pipelined_step_tile_the_turn(tmp_path, top_up_in_the_gap
         inside = [e for e in spans if s0 <= e[1] and e[2] <= s1]
         (wait,) = [e for e in inside if e[0] == "llmd.runner.wait"]
         turn = [e for e in inside if e[0] in TURN and e[1] >= wait[2]]  # (the speculative schedule lies before the wait)
-        want = [n for n in TURN if top_up_in_the_gap or n != "llmd.sched.schedule"]
+        want = [n for n in (EARLY_TURN if early else TURN) if top_up_in_the_gap or n != "llmd.sched.schedule"]
         assert [e[0] for e in turn] == want, [e[0] for e in inside]
+        if early:  # filled and put ahead, under the device: the launch is the call alone
+            assert not [e for e in inside if e[0] == "llmd.runner.build" and e[1] >= wait[2]]
+            assert [e for e in inside if e[0] == "llmd.runner.build" and e[2] <= wait[2]]
         chain = [wait, *turn]
         junctions = [b[1] - a[2] for a, b in zip(chain, chain[1:])]
         assert all(j >= 0 for j in junctions)  # siblings: none starts inside the one before
         worst.append(max(junctions))
     assert statistics.median(worst) < 50_000, worst
     assert (eng.stats.step_gap_admit_ms_total > admit) == top_up_in_the_gap
+    assert (eng.stats.steps_dispatched_before_readback_total > 0) == early
 
 
-def test_the_turns_counters_add_up_to_first_ready_to_dispatch_return():
-    """readback + commit + redispatch of a step, as counted, is the time
-    from the instant the host knew the outputs were ready to the return of
-    the next dispatch, as a clock around both reads it; the host gap is
-    commit + redispatch and starts at the readback's END."""
-    eng = make_engine("tiny", pipelined=True)
+@pytest.mark.parametrize("early", [True, False], ids=["dispatched_at_ready", "behind_the_commit"])
+def test_the_turns_counters_add_up_to_first_ready_to_dispatch_return(early):
+    """What the device waits for host code, as counted, is what a clock
+    around both reads from the instant the host knew the outputs were ready
+    to the return of the next dispatch. A step dispatched at that instant:
+    the redispatch alone, which is the whole host gap (readback and commit
+    are counted too, and are under the device). A step that waits for the
+    commit: readback + commit + redispatch, and the host gap is commit +
+    redispatch and starts at the readback's END."""
+    eng = make_engine("tiny", pipelined=True, **({} if early else DRAFTS))
     warm(eng, [tokens(9, seed=1), tokens(7, seed=2)], 12)
     sp = SamplingParams(max_tokens=12, temperature=0.0, ignore_eos=True)
     returned: list = []
@@ -810,18 +936,28 @@ def test_the_turns_counters_add_up_to_first_ready_to_dispatch_return():
     for p in (tokens(9, seed=1), tokens(7, seed=2)):
         eng.add_request(p, sp)
     s0 = {k: getattr(eng.stats, k) for k in ("step_readback_ms_total", "step_commit_ms_total",
-                                             "step_redispatch_ms_total", "step_host_gap_ms_total")}
+                                             "step_redispatch_ms_total", "step_host_gap_ms_total",
+                                             "steps_dispatched_before_readback_total")}
     while eng.has_work():
         eng.step()
     dispatched = [c for c in counted if c[1] > c[0]]  # (the last step has nothing to dispatch)
     assert len(dispatched) >= 8
     clock = sum(ret - ready for ready, ret, _, _ in dispatched)
-    parts = sum(kw["readback_s"] + kw["commit_s"] + kw["redispatch_s"] for _, _, _, kw in dispatched)
+    if early:
+        parts = sum(kw["redispatch_s"] for _, _, _, kw in dispatched)
+    else:
+        parts = sum(kw["readback_s"] + kw["commit_s"] + kw["redispatch_s"] for _, _, _, kw in dispatched)
     assert parts == pytest.approx(clock, rel=0.05)
-    for _, _, gap, kw in counted:
-        assert gap == pytest.approx(kw["commit_s"] + kw["redispatch_s"], rel=1e-9)
-        assert kw["readback_s"] > 0 and 0 <= kw["gap_admit_s"] <= kw["redispatch_s"]
+    for c in counted:
+        _, _, gap, kw = c
+        if early and c in dispatched:
+            assert gap == kw["redispatch_s"]
+        else:
+            assert gap == pytest.approx(kw["commit_s"] + kw["redispatch_s"], rel=1e-9)
+        assert kw["readback_s"] > 0 and kw["commit_s"] > 0 and 0 <= kw["gap_admit_s"] <= kw["redispatch_s"]
     st = eng.stats
+    assert st.steps_dispatched_before_readback_total - s0["steps_dispatched_before_readback_total"] == (
+        len(dispatched) if early else 0)
     assert st.step_readback_ms_total - s0["step_readback_ms_total"] >= 1e3 * sum(c[3]["readback_s"] for c in counted)
     assert st.step_gap_admit_ms_total <= st.step_redispatch_ms_total
 
@@ -833,7 +969,9 @@ def test_gap_admit_counts_only_the_admission_behind_the_readback(while_running):
     behind the readback is, and is a part of the redispatch."""
     sp = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
     first, late = tokens(9, seed=1), tokens(13, seed=2)
-    eng = make_engine("tiny", pipelined=True)
+    # (behind the readback there is an admission only where the staged step
+    # waits for the commit: an engine that drafts)
+    eng = make_engine("tiny", pipelined=True, **({} if while_running else DRAFTS))
     warm(eng, [first, late], 6)
     eng.add_request(first, sp)
     eng.step()
@@ -1084,9 +1222,9 @@ def test_the_shape_ladder_reaches_each_bucket_once():
     dispatched: list = []
     exec_flat = runner._exec_flat
 
-    def counting(arrays, all_greedy):
+    def counting(arrays, all_greedy, *put_ahead):
         dispatched.append(arrays["stream"].shape[0])
-        return exec_flat(arrays, all_greedy)
+        return exec_flat(arrays, all_greedy, *put_ahead)
 
     runner._exec_flat = counting
     h, rng = Harness(), np.random.default_rng(0)

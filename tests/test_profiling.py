@@ -118,7 +118,7 @@ def test_engine_steps_write_their_phase_spans(tmp_path):
     # (the last one written: scheduled under the request's last step, whose
     # end by max_tokens the pipelined step foresees: nothing is staged)
     assert events["llmd.sched.schedule"] == {"prefills": 0, "decodes": 0}
-    assert events["llmd.step.commit"] == {"rolled": 0}
+    assert events["llmd.step.commit"] == {"rolled": 0, "early": 0}
     assert events["llmd.runner.readback"]["bytes"] > 0  # the one packed output of the step
 
 
@@ -128,6 +128,8 @@ def test_wait_and_readback_are_two_spans_and_the_readback_is_counted(tmp_path, p
     where the host knows the outputs are ready, ``llmd.runner.readback``
     follows it (a sibling, not a child) and ``step_readback_ms_total`` sums
     its length; a blocking wait (no serving loop polls) has no ready lag.
+    A pipelined step launches the next program BETWEEN the two (the moment
+    the outputs are seen ready, before they are read back), and counts it.
 
     The counter's clock and the span's are read some microseconds apart, and
     under six test workers a thread may lose the processor for milliseconds
@@ -156,7 +158,8 @@ def test_wait_and_readback_are_two_spans_and_the_readback_is_counted(tmp_path, p
     monkeypatch.setattr(jax, "block_until_ready", blocking)
     eng.runner._split_results = splitting
     s = eng.stats
-    before = (s.engine_steps_total, s.step_readback_ms_total, s.step_wait_ms_total)
+    before = (s.engine_steps_total, s.step_readback_ms_total, s.step_wait_ms_total,
+              s.steps_dispatched_before_readback_total)
     profiling.start(tmp_path)
     try:
         eng.generate([[9, 8, 7, 6, 5]], SamplingParams(temperature=0.0, max_tokens=5))
@@ -176,6 +179,16 @@ def test_wait_and_readback_are_two_spans_and_the_readback_is_counted(tmp_path, p
     assert counted < s.step_wait_ms_total - before[2] + 1e-6  # a part of the wait as counted
     assert s.step_ready_lag_bound_ms_total == 0.0  # no poll: nothing was looked at twice
     assert (s.step_commit_ms_total > 0) == pipelined and s.step_gap_admit_ms_total == 0.0
+    # the launch of the next step lies between a step's wait and its readback
+    launches = [(b, e) for name, b, e, _ in spans if name == "llmd.runner.launch"]
+    between = [1 for w, r in zip(waits, reads) for lb, le in launches if w[1] <= lb and le <= r[0]]
+    assert len(between) == s.steps_dispatched_before_readback_total - before[3]
+    assert (len(between) >= 3) == pipelined
+    from llmd_tpu.serve.metrics import parse_prometheus, render_metrics
+
+    page = parse_prometheus(render_metrics(s, "tiny"))
+    assert page["llmd:steps_dispatched_before_readback_total"] == s.steps_dispatched_before_readback_total
+    assert page["llmd:async_wasted_rows_total"] == s.async_wasted_rows_total == 0
 
 
 def test_async_first_step_lands_at_once_and_is_named_by_its_batch(tmp_path):

@@ -142,8 +142,9 @@ def test_int8_pool_parity():
 
 
 def test_async_rollback_parity(unforeseen_finishes):
-    """max_tokens finishes land late under async stepping; rolled-back
-    staged rows must leave the stream byte-identical."""
+    """max_tokens finishes land late under async stepping; the rows
+    dispatched for them meanwhile (wasted, dropped at their commit) must
+    leave the stream byte-identical."""
     sp = SamplingParams(temperature=0.0, max_tokens=5)
     ref = make_engine(False, async_s=True)
     flat = make_engine(True, async_s=True)
@@ -152,7 +153,7 @@ def test_async_rollback_parity(unforeseen_finishes):
     f = flat.generate([list(p) for p in PROMPTS], sp)
     s = sync.generate([list(p) for p in PROMPTS], sp)
     assert _toks(r) == _toks(f) == _toks(s)
-    assert flat.stats.async_rollbacks_total > 0, "no rollback exercised"
+    assert flat.stats.async_wasted_rows_total > 0, "no late finish exercised"
 
 
 # --------------------------------------------------------------------- #

@@ -134,6 +134,37 @@ class TestHostSync:
         assert len(fs) == 1 and fs[0].code == "HS001"
         assert fs[0].line == 9  # the `other` method's call, not wait_step's
 
+    def test_the_early_dispatch_is_no_second_readback_site(self, tmp_path):
+        """The pipelined step dispatches the next program from
+        ``wait_step``'s ``at_ready`` hook, between the wait and the readback.
+        The hook is the engine's code: a sync there would put the readback
+        back in front of the dispatch, and is flagged like any other; the one
+        declared site of a step's readback is still ``wait_step`` itself."""
+        from llmd_tpu.analysis.checkers.host_sync import ALLOWED_SITES
+
+        fs = check(tmp_path, {
+            "engine/runner.py": """
+                import jax
+
+                class ModelRunner:
+                    def wait_step(self, packs, at_ready=None):
+                        jax.block_until_ready(packs)
+                        if at_ready is not None:
+                            at_ready()
+                        return jax.device_get(packs)
+            """,
+            "engine/engine.py": """
+                import jax
+
+                class LLMEngine:
+                    def _dispatch_early(self, slot):
+                        return jax.device_get(slot)
+            """,
+        }, ["host-sync"])
+        assert [(f.code, f.path.rsplit("/", 1)[-1]) for f in fs] == [("HS001", "engine.py")]
+        assert {q for f, q in ALLOWED_SITES if f == "runner.py"} == {
+            "ModelRunner.wait_step", "ModelRunner.download_pages"}
+
     def test_pragma_suppresses_with_reason(self, tmp_path):
         fs = check(tmp_path, {
             "engine/bad.py": """
