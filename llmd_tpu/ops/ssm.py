@@ -20,7 +20,9 @@ state there:
   aliased in place: the rows that decode are compacted in front, the grid
   runs (head blocks, entries), an entry's block is ``pool[layer,
   slot[entry], block]``, and the entries behind the last live one name ITS
-  block again, so they move nothing.
+  block again, so they move nothing. The state's multiply-add runs on the
+  vector unit; ``y``, a sum across lanes, on the matrix unit
+  (``_update_kernel``), so that the body hides under the block's copies.
 - ``ssm_scan`` (scope ``llmd.ssm.scan``): the prefill rows, as the chunked
   (SSD) form with the ROW as the chunk: a loop over the step's prefill rows
   that carries the running state, computes a row's outputs from the state it
@@ -182,28 +184,40 @@ def _update_kernel(
     o_ref, y_ref,                   # outputs (o aliases the pool)
     *, hb: int, hpg: int = 0,
 ):
-    """``hpg`` 0: the block's heads share ONE b and c (``[1, N]``: one
-    group, or a block inside a group). Else the block spans several groups
-    of ``hpg`` heads and b, c hold a row a group."""
+    """``hpg`` 0: the block's heads share ONE b and c (row 0: one group, or
+    a block inside a group). Else the block spans several groups of ``hpg``
+    heads and b, c hold a row a group. ``c`` comes padded with zero rows to
+    whole sublane tiles.
+
+    A head's ``[P, N]`` tile has the state dimension on the LANES, so ``y =
+    H . c`` sums across lanes. The vector unit did that a register at a time
+    and stored a one-lane column a head: 4.0 us a 1 MiB block where the
+    block's two copies take 3.2 (chip, PR 50). The matrix unit, idle in this
+    kernel, takes it instead: ``c . H^T`` over the whole block at ``HIGHEST``
+    (float32 in six passes; as close to ``ssm_update_xla``'s sum as a
+    float32 sum in another order), whose result is a lane-dense ROW of
+    ``hb * P`` a group. The body is then 1.4 us and hides under the copies.
+    The state's own multiply-add stays on the vector unit in float32."""
     del slots_ref, layer_ref
     i = pl.program_id(1)
     cnt = cnt_ref[0]
-
-    def of_head(v, hh):
-        return v[hh // hpg : hh // hpg + 1] if hpg else v
+    P, N = h_ref.shape[1:]
 
     @pl.when(i < cnt)
     def _():
         b = b_ref[...]  # [1, N], or [the block's groups, N]
-        c = c_ref[...]
         for hh in range(hb):
             a = ax_ref[0, :, hh : hh + 1]   # [P, 1] decay
             xc = ax_ref[1, :, hh : hh + 1]  # [P, 1] dt * x
-            hn = h_ref[hh] * a + xc * of_head(b, hh)  # [P, N]
-            o_ref[hh] = hn
-            y_ref[:, hh : hh + 1] = jnp.sum(
-                hn * of_head(c, hh), axis=1, keepdims=True
-            )
+            bh = b[hh // hpg : hh // hpg + 1] if hpg else b
+            o_ref[hh] = h_ref[hh] * a + xc * bh  # [P, N]
+        y = jax.lax.dot_general(
+            c_ref[...], o_ref[...].reshape(hb * P, N), (((1,), (1,)), ((), ())),
+            precision=HIGHEST, preferred_element_type=jnp.float32,
+        )  # [rows of c, hb * P]: row g holds every head's product with group g's c
+        per = (hpg or hb) * P  # a group's heads x P lanes of the row
+        for g in range(hb * P // per):
+            y_ref[:, g * per : (g + 1) * per] = y[g : g + 1, g * per : (g + 1) * per]
 
     @pl.when(cnt == 0)
     def _():
@@ -259,15 +273,18 @@ def ssm_update_pallas(ssm, layer, slots, count, a, dtx, b, c, *, interpret=False
         # A head block's groups: [U, nb, groups of the block, N], the block
         # (entry, head block)'s.
         b, c = (_block_groups(v, nb, hb, hpg) for v in (b, c))
-        bc_spec = pl.BlockSpec(
-            (None, None, b.shape[2], N),
-            lambda j, i, sl, cnt, ly: (eff(i, cnt), j, 0, 0),
-        )
     else:
-        b, c = b[:, None, :], c[:, None, :]
-        bc_spec = pl.BlockSpec(
-            (None, 1, N), lambda j, i, sl, cnt, ly: (eff(i, cnt), 0, 0)
+        b, c = b[:, None, None, :], c[:, None, None, :]
+    # The matrix unit takes c in whole sublane tiles: zero rows behind the
+    # block's groups.
+    c = jnp.pad(c, ((0, 0), (0, 0), (0, -c.shape[2] % 8), (0, 0)))
+
+    def bc_spec(v):
+        return pl.BlockSpec(
+            (None, None, v.shape[2], N),
+            lambda j, i, sl, cnt, ly: (eff(i, cnt), j if grouped else 0, 0, 0),
         )
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(nb, U),
@@ -277,11 +294,12 @@ def ssm_update_pallas(ssm, layer, slots, count, a, dtx, b, c, *, interpret=False
                 (None, None, 2, P, hb),
                 lambda j, i, sl, cnt, ly: (eff(i, cnt), j, 0, 0, 0),
             ),
-            bc_spec, bc_spec,
+            bc_spec(b), bc_spec(c),
         ],
         out_specs=[
             pool_spec,
-            pl.BlockSpec((None, None, P, hb), lambda j, i, sl, cnt, ly: (i, j, 0, 0)),
+            # y lane-dense: a row of the block's heads x P.
+            pl.BlockSpec((None, None, 1, hb * P), lambda j, i, sl, cnt, ly: (i, j, 0, 0)),
         ],
     )
     ssm, y = pl.pallas_call(
@@ -290,7 +308,7 @@ def ssm_update_pallas(ssm, layer, slots, count, a, dtx, b, c, *, interpret=False
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(ssm.shape, ssm.dtype),
-            jax.ShapeDtypeStruct((U, nb, P, hb), jnp.float32),
+            jax.ShapeDtypeStruct((U, nb, 1, hb * P), jnp.float32),
         ],
         # Operand 3 (after the three prefetched scalars) is the pool.
         input_output_aliases={3: 0},
@@ -299,7 +317,7 @@ def ssm_update_pallas(ssm, layer, slots, count, a, dtx, b, c, *, interpret=False
         ),
         interpret=interpret,
     )(slots.astype(jnp.int32), count, layer, ssm, ax, b, c)
-    return ssm, y.transpose(0, 1, 3, 2).reshape(U, H, P)
+    return ssm, y.reshape(U, H, P)
 
 
 def ssm_update_xla(ssm, layer, slots, count, a, dtx, b, c):

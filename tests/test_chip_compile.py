@@ -290,6 +290,31 @@ def _ssm_update(rows, pool=STATE_POOL, groups=0):
     ]
 
 
+def _layer_metric(name: str):
+    """The benchmark's reader ``perfbench/layer_metrics/<name>.py`` as a module."""
+    import importlib.util
+    import pathlib
+
+    path = pathlib.Path(__file__).resolve().parent.parent / "perfbench" / "layer_metrics" / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(name.replace(".", "_"), path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    return reader
+
+
+def _first_pool_of_each_update(hlo_text: str) -> set:
+    """What the benchmark's two update rooflines take H, P and N from
+    (``perfbench/layer_metrics/kernels.ssm_update_roofline.py`` and
+    ``kernels.ssm_grouped_update_roofline.py``): the FIRST five-dimensional
+    ``f32[...]`` in the operand text of every ``%llmd.ssm.update`` call, found
+    with the reader's own pattern."""
+    reader = _layer_metric("kernels.ssm_grouped_update_roofline")
+    calls = [ln.strip() for ln in hlo_text.splitlines()
+             if "tpu_custom_call" in ln and ln.strip().startswith("%llmd.ssm.update")]
+    assert calls
+    return {tuple(int(x) for x in reader.POOL.search(ln.partition(" custom-call(")[2]).groups()) for ln in calls}
+
+
 def _ssm_slot(write: bool):
     from llmd_tpu.ops.ssm import read_slot, write_slot
 
@@ -392,14 +417,7 @@ def test_the_attention_rooflines_can_read_the_tiled_call(v5e, case):
     FIRST output ``bf16[T,K,G,D]``, the pool the only 5-dimensional
     operand. The tiled call tiles through its BlockSpecs, not through a
     reshape of the stream, so the reader still finds both."""
-    import importlib.util
-    import pathlib
-
-    path = (pathlib.Path(__file__).resolve().parent.parent / "perfbench"
-            / "layer_metrics" / "kernels.sparse_attention_roofline.py")
-    spec = importlib.util.spec_from_file_location("sparse_attention_roofline", path)
-    reader = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(reader)
+    reader = _layer_metric("kernels.sparse_attention_roofline")
     fn, shapes = CASES[case](v5e)
     on_chip = SingleDeviceSharding(v5e)
     hlo = jax.jit(fn).lower(*[
@@ -531,6 +549,8 @@ def test_the_state_space_mixers_update_the_state_pool_in_place(v5e):
     calls = {ln.split(" = ")[0].strip().rstrip(".0123456789")
              for ln in compiled.as_text().splitlines() if "tpu_custom_call" in ln}
     assert calls == {"%llmd.ssm.update", "%llmd.ssm.scan"}
+    # the update's smaller operands stand BEHIND the pool (or have fewer dimensions)
+    assert _first_pool_of_each_update(compiled.as_text()) == {(Lm, S, 128, 64, 128)}
     m = compiled.memory_analysis()
     pool_bytes = Lm * S * (128 * 64 * 128 * 4 + 3 * 8448 * 2)
     assert m.alias_size_in_bytes >= pool_bytes
@@ -578,7 +598,8 @@ def test_the_mixer_only_hybrids_steps_compile_with_both_pools_in_place(nemotron_
     assert len(gmm_shapes) == 6 and all(gmm_shapes)
     assert {tuple(int(x) for x in m.groups()) for m in gmm_shapes} == {(12, 16, 2688, 1920), (12, 16, 1920, 2688)}
     update = [ln for ln in calls if ln.startswith("%llmd.ssm.update")]
-    assert len(update) == 3 and all("f32[12,257,64,64,128]" in ln.partition(" custom-call(")[2] for ln in update)
+    # the pool is the FIRST five-dimensional f32 of each, which is where the roofline's reader looks
+    assert len(update) == 3 and _first_pool_of_each_update(text) == {(12, 257, 64, 64, 128)}
     for scope in ("llmd.block.mamba", "llmd.block.moe", "llmd.block.attn"):
         assert scope in text
     m = compiled.memory_analysis()
@@ -594,7 +615,8 @@ def test_a_one_group_models_step_keeps_its_pallas_calls(nemotron_step, v5e):
     described chip: six Pallas calls, as before the mixer learned groups (the
     state update, the scan's slot read and write, the KV write, flat attention
     and the scan's second read), and the update's B and C the one-group
-    ``[rows, 1, N]`` operands."""
+    operands: ``[rows, 1, 1, N]``, C's one row padded to the matrix unit's
+    sublane tile of 8."""
     from llmd_tpu.config import CacheConfig, EngineConfig, SchedulerConfig
     from llmd_tpu.models.registry import get_model_config
 
@@ -609,7 +631,7 @@ def test_a_one_group_models_step_keeps_its_pallas_calls(nemotron_step, v5e):
     text = lowered.as_text()
     assert text.count("stablehlo.custom_call @tpu_custom_call") == 6
     update = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and "x4x8x16xf32>" in ln and ") -> (" in ln]
-    assert update and all("x1x16xf32>" in ln for ln in update)
+    assert update and all("x1x1x16xf32>" in ln and "x1x8x16xf32>" in ln for ln in update)
 
 
 def test_the_delta_rule_hybrids_step_compiles_with_both_pools_in_place(v5e):

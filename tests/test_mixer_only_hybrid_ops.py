@@ -65,6 +65,74 @@ def test_the_grouped_update_kernel_equals_the_recurrence(H, G):
     np.testing.assert_allclose(xla_pool, want_pool, atol=1e-5)
 
 
+# The update kernel's three forms (``ssm_update_pallas`` reads them off its
+# operands): H 64 heads in two head blocks of 32.
+FORMS = {
+    "one-group": 0,                # b, c [U, N]
+    "groups-spanning-a-block": 8,  # [U, 8, N]: a block of 32 heads spans four groups
+    "groups-inside-a-block": 2,    # [U, 2, N]: a block lies inside one group
+}
+# (count, the row whose decay is 0, the entries behind ``count`` name a LIVE slot again)
+SITUATIONS = {
+    "count-0": (0, None, False),
+    "count-below-U": (3, None, False),
+    "count-U": (5, None, False),
+    "a-fresh-row": (3, 1, False),
+    "a-repeated-slot-behind-count": (3, None, True),
+}
+
+
+@pytest.mark.parametrize("situation", SITUATIONS)
+@pytest.mark.parametrize("form", FORMS)
+def test_the_update_kernel_leaves_the_xla_forms_pool_bit_for_bit(form, situation):
+    """``ssm_update_pallas`` in interpret mode against ``ssm_update_xla``.
+
+    The POOL is compared bit for bit: the state's multiply-add ``a H + dt x (x)
+    b`` is float32 on the vector unit in both, and the inputs here are rounded
+    to bfloat16's 8 significant bits, so that both products are EXACT in
+    float32 and the one rounding left is the add's: a CPU that fuses the
+    multiply into the add in one form and not in the other still gives the same
+    bits, and a state or product kept narrower than float32 does not. Live
+    entries equal the XLA form's, every other slot is as it was.
+
+    ``y = H . c`` is a sum of N float32 products that the kernel takes in
+    another order (the matrix unit at ``HIGHEST``; here XLA's dot): each side
+    is within ``N u sum|H c|`` of the true sum (u = 2^-24), so they differ by
+    at most twice that; the limit is four times, element by element."""
+    G = FORMS[form]
+    count, fresh, repeated = SITUATIONS[situation]
+    ks = jax.random.split(jax.random.key(7 + G), 6)
+    L, S, H, P, N, U = 2, 7, 64, 8, 16, 5
+    short = lambda v: v.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
+    pool = short(jax.random.normal(ks[0], (L, S, H, P, N), jnp.float32))
+    slots = jax.random.permutation(ks[1], S - 1)[:U].astype(jnp.int32)
+    if repeated:
+        slots = slots.at[count:].set(slots[1])
+    a = short(jax.random.uniform(ks[2], (U, H)))
+    if fresh is not None:
+        a = a.at[fresh].set(0.0)
+    dtx = short(jax.random.normal(ks[3], (U, H, P)))
+    bc = (U, G, N) if G else (U, N)
+    b, c = short(jax.random.normal(ks[4], bc)), short(jax.random.normal(ks[5], bc))
+    args = (pool, jnp.int32(1), slots, jnp.int32(count), a, dtx, b, c)
+    want_pool, want_y = ssm.ssm_update_xla(*args)
+    got_pool, got_y = ssm.ssm_update_pallas(*args, interpret=True)
+    assert got_pool.dtype == jnp.float32 and got_y.dtype == jnp.float32 and got_y.shape == (U, H, P)
+    np.testing.assert_array_equal(got_pool, want_pool)
+    live = np.asarray(slots[:count]).tolist()
+    untouched = [s for s in range(S) if s not in live]
+    np.testing.assert_array_equal(got_pool[1, untouched], pool[1, untouched])
+    np.testing.assert_array_equal(got_pool[0], pool[0])
+    if fresh is not None:  # a fresh row's state is dt x (x) b alone, whatever the slot held
+        bh = np.repeat(np.asarray(b[fresh]), H // G, axis=0)[:, None, :] if G else np.asarray(b[fresh])
+        np.testing.assert_array_equal(got_pool[1, slots[fresh]], np.asarray(dtx[fresh])[..., None] * bh)
+    if count:
+        h = np.asarray(want_pool[1][slots[:count]], np.float64)
+        ch = np.asarray(jnp.repeat(c, H // G, axis=1) if G else c[:, None, :], np.float64)[:count, :, None, :]
+        limit = 4 * N * 2.0**-24 * np.sum(np.abs(h * ch), axis=-1)
+        assert np.all(np.abs(np.asarray(got_y[:count]) - np.asarray(want_y[:count])) <= limit)
+
+
 def test_a_head_block_is_whole_groups_or_lies_inside_one():
     assert ssm._head_block(64) == ssm._head_block(128) == 32  # one group: as it was
     assert ssm._head_block(64, 8) == 32 and ssm._head_block(16, 8) == 16 and ssm._head_block(4, 2) == 4
