@@ -146,9 +146,20 @@ class ModelConfig:
     # covers every pick.
     held_experts: int | None = None
     held_experts_first: int = 0
-    # The ``layer_types`` values whose attention rotates q and k (RoPE).
-    # None = every layer. The EXAONE-4.0 family rotates its sliding layers
-    # only: ("sliding_attention",).
+    # A RoPE table per KIND of layer (HF ``rope_parameters`` keyed by layer
+    # type): ``layer_types`` value -> that kind's own ``{"rope_theta",
+    # "rope_type", "factor", ...}``, or None: layers of that kind do not
+    # rotate q and k at all. A kind that is not named takes the model's
+    # table (``rope_theta`` / ``rope_scaling``). Mellum2 rotates its full
+    # layers under YaRN and its sliding ones under the plain table; the
+    # EXAONE-4.0 family rotates its sliding layers only
+    # (``{"full_attention": None}``); granite's and Nemotron-H's attention
+    # has no positional encoding (every kind None). ``rope_specs`` and
+    # ``layer_rope`` are how the model reads it.
+    rope_parameters: dict | None = None
+    # The older spelling of "no table", folded into ``rope_parameters`` at
+    # construction: the ``layer_types`` values that rotate (under the
+    # model's table); every other kind gets None.
     rope_layer_types: tuple | None = None
     # Softmax scale of the attention layers; None = head_dim ** -0.5.
     # granitemoehybrid states its own (``attention_multiplier``).
@@ -254,9 +265,17 @@ class ModelConfig:
                 f"range of the router's {self.num_experts}"
             )
         if self.rope_layer_types is not None:
-            self.rope_layer_types = tuple(self.rope_layer_types)
             if self.layer_types is None:
                 raise ValueError("rope_layer_types needs layer_types")
+            own = dict(self.rope_parameters or {})
+            for t in dict.fromkeys(self.layer_types):
+                if t not in self.rope_layer_types:
+                    own[t] = None
+                elif t in own and own[t] is None:
+                    del own[t]
+            self.rope_parameters, self.rope_layer_types = own, None
+        if self.rope_parameters is not None and self.layer_types is None:
+            raise ValueError("rope_parameters is keyed by layer_types' values")
         if self.state_space:
             if self.delta_rule and "mamba" in self.layer_types:
                 raise ValueError(
@@ -387,12 +406,38 @@ class ModelConfig:
     def layer_windows(self) -> tuple[int, ...]:
         return tuple(self.window_for_layer(i) for i in range(self.num_layers))
 
+    def _rope_spec(self, own: dict) -> tuple:
+        """(theta, scaling) of one entry of ``rope_parameters``; a plain
+        table has no scaling, so that it is the model's own where the theta
+        is."""
+        scaling = {k: v for k, v in own.items() if k != "rope_theta"}
+        if (scaling.get("rope_type") or scaling.get("type") or "default") == "default":
+            scaling = None
+        return float(own.get("rope_theta", self.rope_theta)), scaling
+
     @property
-    def layer_rotates(self) -> tuple[bool, ...]:
-        """Whether layer ``i``'s attention applies RoPE."""
-        if self.rope_layer_types is None:
-            return (True,) * self.num_layers
-        return tuple(t in self.rope_layer_types for t in self.layer_types)
+    def rope_specs(self) -> tuple:
+        """The model's RoPE tables as (theta, scaling): the model's own
+        first, then each other one that ``rope_parameters`` gives a kind of
+        layer, once."""
+        specs = [(float(self.rope_theta), self.rope_scaling)]
+        for own in (self.rope_parameters or {}).values():
+            if own is not None and (spec := self._rope_spec(own)) not in specs:
+                specs.append(spec)
+        return tuple(specs)
+
+    @property
+    def layer_rope(self) -> tuple:
+        """Per layer the index of its table in ``rope_specs``; None: the
+        layer's attention does not rotate q and k."""
+        if not self.rope_parameters:
+            return (0,) * self.num_layers
+        specs, own = self.rope_specs, self.rope_parameters
+        by_type = {
+            t: None if p is None else specs.index(self._rope_spec(p))
+            for t, p in own.items()
+        }
+        return tuple(by_type.get(t, 0) for t in self.layer_types)
 
     @property
     def state_space(self) -> bool:

@@ -289,6 +289,13 @@ class EngineStats:
     swa_section_hits_total: int = 0
     swa_section_misses_total: int = 0
     swa_section_captures: int = 0
+    # Rings seeded from a retained section (at an admission: in the schedule
+    # or in a top-up), the pages copied, and the host time the copies'
+    # dispatch took (span ``llmd.ring.seed``): a section of a 1,024-token
+    # window is 65 pages x the sliding layers.
+    swa_ring_seeds_total: int = 0
+    swa_ring_seed_pages_total: int = 0
+    swa_ring_seed_host_ms_total: float = 0.0
     # The state pool of a model with state-space layers (0 elsewhere): slots
     # that running sequences hold, snapshots retained, and the retained-
     # state cache's counters under the meanings above (a hit seeds a fresh
@@ -1388,7 +1395,7 @@ class LLMEngine:
             ring_ids: list[int] = []
             try:
                 ring_ids = self.swa_allocator.allocate(self._swa.ring_pages)
-                if self._swa_sections.seed(key, ring_ids) is None:
+                if self._seed(key, ring_ids) is None:
                     raise KeyError("section evicted between has() and seed()")
             # llmd: allow(broad-except) -- a retained-section hit must never fail the request; degrades to a plain prefill
             except Exception as e:
@@ -1419,6 +1426,23 @@ class LLMEngine:
         if run > 0:
             self._swa_sections.misses += 1
             req.swa_capture = (run, hashes[run - 1])
+
+    def _seed(self, key: bytes, ring_ids: list[int]):
+        """``RetainedStateCache.seed``; a RING's seed under its own span and
+        counters (a state pool's slot is one page, under ``llmd.state.seed``
+        alone)."""
+        if self._state_pool:
+            return self._swa_sections.seed(key, ring_ids)
+        t0 = time.monotonic()
+        with profiling.span("llmd.ring.seed") as span:
+            seeded = self._swa_sections.seed(key, ring_ids)
+            if seeded is not None:
+                s0, n_pre = seeded
+                span.set_metadata(pages=n_pre - s0)
+                self.stats.swa_ring_seeds_total += 1
+                self.stats.swa_ring_seed_pages_total += n_pre - s0
+        self.stats.swa_ring_seed_host_ms_total += (time.monotonic() - t0) * 1e3
+        return seeded
 
     def abort_request(self, request_id: str) -> bool:
         for i, r in enumerate(self._lora_parked):

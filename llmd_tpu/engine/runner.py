@@ -1785,8 +1785,23 @@ class ModelRunner:
 
         def copy(kv, src, dst):
             # Every leaf of a pool (an int8 pool's scales, a state pool's
-            # conv state) carries its page or slot ids on axis 1.
-            return jax.tree.map(lambda a: a.at[:, dst].set(a[:, src]), kv)
+            # conv state) carries its page or slot ids on axis 1. Page by
+            # page, a slice read and a slice written into the donated pool:
+            # in place whatever the pool's size. One gather of the pages
+            # (``a.at[:, dst].set(a[:, src])``) is re-laid by the chip's
+            # compiler through a copy of the WHOLE pool once a ring pool has
+            # 21 layers of 4,352 pages (2.79 GiB of temporaries beside 14 GiB
+            # of weights and pools: the program did not load, PR 51). The
+            # callers' pages are disjoint (a ring's against a section's), so
+            # read-then-write a page is read-all-then-write-all.
+            def leaf(a):
+                def page(i, a):
+                    one = jax.lax.dynamic_slice_in_dim(a, src[i], 1, axis=1)
+                    return jax.lax.dynamic_update_slice_in_dim(a, one, dst[i], axis=1)
+
+                return jax.lax.fori_loop(0, src.shape[0], page, a)
+
+            return jax.tree.map(leaf, kv)
 
         return jax.jit(copy, donate_argnums=(0,))
 

@@ -132,13 +132,11 @@ def project(h, lp, step: StepCtx, layer: LayerCtx):
     if cfg.qk_norm:  # Qwen3: per-head RMS norm before RoPE
         q = rms_norm(q, lp["attn_q_norm"], cfg.rms_norm_eps, cfg.norm_zero_centered)
         k = rms_norm(k, lp["attn_k_norm"], cfg.rms_norm_eps, cfg.norm_zero_centered)
-    rotate = layer.rotate
-    if rotate is None:
-        cos_l, sin_l = step.cos, step.sin
-    elif rotate is not False:  # the identity where the layer has none
-        cos_l = jnp.where(rotate, step.cos, 1.0)
-        sin_l = jnp.where(rotate, step.sin, 0.0)
-    if rotate is not False:  # False: no layer of the model rotates
+    if layer.rope is not None:  # None: the layer's kind has no table
+        if isinstance(layer.rope, int):
+            cos_l, sin_l = step.ropes[layer.rope]
+        else:  # a scan over layers of several kinds: the layer's row
+            cos_l, sin_l = (t[layer.rope] for t in step.rope_stack)
         q = apply_rope(q, cos_l, sin_l)
         k = apply_rope(k, cos_l, sin_l)
     v = v.reshape(B, Q, K, D)

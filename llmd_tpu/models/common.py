@@ -73,11 +73,15 @@ class StepCtx(NamedTuple):
     mesh: object        # the Mesh, or None
     world_size: int
     kv_rep: int
-    cos: jax.Array      # the model's rope tables over ``inp.positions``
-    sin: jax.Array
+    # (cos, sin) over ``inp.positions`` per entry of ``cfg.rope_specs``: the
+    # model's own table first, then each one a kind of layer has to itself.
+    ropes: tuple
     valid: jax.Array    # ``inp.valid``, traced once
     cp: int             # the ring degree of this program's prefill attention; 0: no ring
     hoisted: dict       # kind -> what its ``hoist`` traced for the step
+    # (cos [n, ...], sin [n, ...]): ``ropes`` stacked, for the one scan whose
+    # layers differ in their table (``LayerCtx.rope`` traced); None elsewhere.
+    rope_stack: tuple | None = None
 
 
 class LayerCtx(NamedTuple):
@@ -87,9 +91,11 @@ class LayerCtx(NamedTuple):
     table: jax.Array         # its pool's page table
     run_phys: jax.Array | None = None  # its pool's page a run of the flat write plan
     window: jax.Array | None = None    # sliding window (0: none); None: no layer slides
-    # Whether the layer applies RoPE (a bool, traced or not); None: every
-    # layer does, False: none of the model's does.
-    rotate: jax.Array | bool | None = None
+    # The layer's RoPE table: its index into ``StepCtx.ropes`` (static
+    # wherever the layer's kind is: a cycle body's position, a homogeneous
+    # run), None: the kind has no table and q, k stay as projected; traced
+    # (a row of ``StepCtx.rope_stack``) in a scan over layers of several kinds.
+    rope: jax.Array | int | None = 0
     # "window" | "full" where the model mixes the two: the flat attention
     # call's ``llmd.attn.<kind>`` scope.
     attn_kind: str | None = None

@@ -285,10 +285,16 @@ def forward_hidden(
     x = params["embed"][inp.token_ids]  # [B, Q, H]
     if cfg.embedding_multiplier != 1.0:
         x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
-    # one rope table for all layers (hoisted out of the scan); MLA rotates
-    # only its rope sub-dim
+    # One rope table per KIND of layer (``cfg.rope_specs``: the model's own,
+    # then each one ``rope_parameters`` gives a kind to itself), built once
+    # and hoisted out of the scans; MLA rotates only its rope sub-dim. Which
+    # of them a layer takes is ``cfg.layer_rope`` (None: it does not rotate).
     rope_dim = cfg.qk_rope_head_dim if cfg.is_mla else cfg.rotary_dim
-    cos, sin = rope_tables(inp.positions, rope_dim, cfg.rope_theta, cfg.rope_scaling)
+    ropes = tuple(
+        rope_tables(inp.positions, rope_dim, theta, scaling)
+        for theta, scaling in cfg.rope_specs
+    )
+    rope_static = cfg.layer_rope
     res_mult = cfg.residual_multiplier
 
     def _res(y):
@@ -324,7 +330,7 @@ def forward_hidden(
     # has no ring ignores it).
     step = StepCtx(
         cfg=cfg, inp=inp, mesh=mesh, world_size=world_size, kv_rep=kv_rep,
-        cos=cos, sin=sin, valid=inp.valid,
+        ropes=ropes, valid=inp.valid,
         cp=cp_prefill if (
             cp_prefill > 1 and mesh is not None and not flat and not use_dbo
             and Q % cp_prefill == 0
@@ -461,13 +467,6 @@ def forward_hidden(
 
     census = moe_census if use_census else None
 
-    # Layers that do not rotate (cfg.rope_layer_types): a per-layer switch
-    # beside the window. None where every layer rotates keeps the scan
-    # signature (and compile cache) unchanged.
-    rot_static = cfg.layer_rotates
-    rotates = None if all(rot_static) else jnp.asarray(rot_static, bool)
-    no_rope = not any(rot_static)
-
     mixes_kinds = sliding and len({w > 0 for w in win_static}) == 2
 
     def kind_name(i: int):
@@ -484,16 +483,15 @@ def forward_hidden(
                 plane=jnp.int32(plane[i]), table=tables[g],
                 run_phys=run_physes[g],
                 window=None if windows is None else windows[i],
-                rotate=None if rotates is None else rot_static[i],
-                attn_kind=kind_name(i),
+                rope=rope_static[i], attn_kind=kind_name(i),
             ), use_moe=False,
         )
 
     n_scan = cfg.num_layers - n_dense
     scan_kinds = kinds[n_dense:]
+    scan_ropes = rope_static[n_dense:]
     plane_arr = jnp.asarray(plane[n_dense:], jnp.int32)
     win_arr = windows[n_dense:] if windows is not None else None
-    rot_arr = rotates[n_dense:] if rotates is not None else None
     # The stacked expert leaves [L, E, ..] do not ride the scans as ``xs``:
     # XLA fuses a scanned slice into an XLA consumer and MATERIALISES it for
     # a Pallas one, all E x 3 x K x N of a layer read and written in every
@@ -547,7 +545,7 @@ def forward_hidden(
         ])
 
     def scan_group(x, cache, census, table, lp, plane_ids, layer_ids, wins,
-                   kind: MixerKind, run_phys=None, rots=None, attn_kind=None,
+                   kind: MixerKind, run_phys=None, rope=0, attn_kind=None,
                    ffn_ids=None, ffn: bool = True):
         """One homogeneous run of layers sharing a pool/table. The census
         delta rides the scan as a per-layer OUTPUT (stacked then reduced)
@@ -561,7 +559,8 @@ def forward_hidden(
         model's layers differ in it, its own stack is indexed by the
         layer's plane as the shared stack is by its id. ``ffn_ids`` / ``ffn``
         (a model with layers without FFN): the run's indices into the FFN
-        leaves, and whether the run's layers have one."""
+        leaves, and whether the run's layers have one. ``rope``: the run's
+        table (``LayerCtx.rope``), or an array of one row a layer."""
 
         def fn(carry, scanned):
             x, cache = carry
@@ -573,17 +572,17 @@ def forward_hidden(
             x, cache, cd = layer_body(
                 x, cache, {**lp_s, **experts}, kind, LayerCtx(
                     plane=pid, table=table, run_phys=run_phys,
-                    window=per.get("window"),
-                    rotate=False if no_rope else per.get("rotate"),
+                    window=per.get("window"), rope=per.get("rope", rope),
                     attn_kind=attn_kind, moe_layer=fid,
                 ), use_moe=cfg.is_moe, ffn=ffn,
             )
             return (x, cache), cd
 
         per = {
-            k: a for k, a in (("window", wins), ("rotate", rots), ("ffn", ffn_ids))
-            if a is not None
+            k: a for k, a in (("window", wins), ("ffn", ffn_ids)) if a is not None
         }
+        if isinstance(rope, jax.Array):
+            per["rope"] = rope
         scanned = (lp, plane_ids, layer_ids, per)
         (x, cache), cds = jax.lax.scan(fn, (x, cache), scanned)
         if census is not None and cds is not None:
@@ -592,55 +591,68 @@ def forward_hidden(
 
     if len(set(scan_kinds)) <= 1 and not per_kind:
         g = scan_kinds[0] if scan_kinds else 0
+        rope = scan_ropes[0] if scan_ropes else 0
+        if len(set(scan_ropes)) > 1:
+            # The one place a layer's table is not static: ONE scan over
+            # layers that share a pool and differ in their table (a model that
+            # mixes kinds, served without the ring). A layer takes its row of
+            # the stacked tables; the identity where its kind has none.
+            step = step._replace(rope_stack=(
+                jnp.stack([c for c, _ in ropes] + [jnp.ones_like(ropes[0][0])]),
+                jnp.stack([s for _, s in ropes] + [jnp.zeros_like(ropes[0][1])]),
+            ))
+            rope = jnp.asarray(
+                [len(ropes) if r is None else r for r in scan_ropes], jnp.int32
+            )
         x, caches[g], census = scan_group(
             x, caches[g], census, tables[g], lp_all, plane_arr, layer_arr,
-            win_arr, layer_kinds[0], run_physes[g], rot_arr,
+            win_arr, layer_kinds[0], run_physes[g], rope,
         )
-    elif not per_kind and (c := _scan_period(scan_kinds)) is not None:
-        # Hybrid periodic pattern (gpt-oss alternating): scan over CYCLES
-        # of c layers; within a cycle the pool choice is static per
-        # sub-layer, so both pool carries update in place every step.
-        T = n_scan // c
+    elif not per_kind and (
+        c := _scan_period(tuple(zip(scan_kinds, scan_ropes)))
+    ) is not None:
+        # Hybrid periodic pattern (gpt-oss alternating, Mellum2's three
+        # sliding layers to one full): ONE scan over CYCLES of c layers, both
+        # pools in the carry. Within a cycle the pool, the window, the RoPE
+        # table and the attention call's scope are static per position, so
+        # both pool carries update in place every step. The leaves stay loop
+        # invariants indexed by the scanned ids, as in ``scan_group``: a
+        # reshaped ``xs`` would hand each cycle a copy of its c layers.
+        n_cyc = n_scan // c
 
         def resh(a):
-            return a.reshape(T, c, *a.shape[1:])
-
-        cyc_scanned = (
-            jax.tree.map(resh, lp_all), resh(plane_arr), resh(layer_arr),
-            resh(win_arr), None if rot_arr is None else resh(rot_arr),
-        )
+            return a.reshape(n_cyc, c)
 
         def cyc(carry, scanned):
-            x, cf, cs = carry
-            cc = [cf, cs]
-            lp_c, plane_c, layer_c, win_c, rot_c = scanned
+            x, *cc = carry
+            plane_c, layer_c = scanned
             cd_cyc = None
             for j in range(c):
-                lp_s = {**jax.tree.map(lambda a: a[j], lp_c), **experts}
-                g = scan_kinds[j]  # periodic: same kind for every cycle
+                g = scan_kinds[j]  # periodic: the same for every cycle
+                lid = layer_c[j]
                 x, cc[g], cd = layer_body(
-                    x, cc[g], lp_s, layer_kinds[n_dense + j], LayerCtx(
+                    x, cc[g], {**layer_leaves(lid, lid), **experts},
+                    layer_kinds[n_dense + j], LayerCtx(
                         plane=plane_c[j], table=tables[g],
                         run_phys=run_physes[g],
-                        window=win_c[j] if g else None,
-                        moe_layer=layer_c[j],
-                        rotate=None if rot_c is None else rot_c[j],
+                        window=win_static[n_dense + j] if g else None,
+                        rope=scan_ropes[j], moe_layer=lid,
                         attn_kind=kind_name(n_dense + j),
                     ), use_moe=cfg.is_moe,
                 )
                 if cd is not None:
                     cd_cyc = cd if cd_cyc is None else _census_merge(cd_cyc, cd)
-            return (x, cc[0], cc[1]), cd_cyc
+            return (x, *cc), cd_cyc
 
         (x, caches[0], caches[1]), cds = jax.lax.scan(
-            cyc, (x, caches[0], caches[1]), cyc_scanned
+            cyc, (x, caches[0], caches[1]), (resh(plane_arr), resh(layer_arr))
         )
         if census is not None and cds is not None:
             census = _census_merge(census, _reduce_census(cds))
     else:
         off = 0
         if per_kind and (cyc_n := _kind_cycles(
-            tuple(zip(layer_kinds, has_ffn))
+            tuple(zip(layer_kinds, has_ffn, rope_static))
         )) is not None:
             # A model whose BLOCKS are one mixer each (nemotron_h), as
             # layers of mixer (+ FFN where an expert block follows): the
@@ -672,8 +684,7 @@ def forward_hidden(
                         x, cc[g], _ = layer_body(
                             x, cc[g], lp_s, kind, LayerCtx(
                                 plane=plane_c[j], table=tables[g],
-                                run_phys=run_physes[g],
-                                rotate=False if no_rope else None,
+                                run_phys=run_physes[g], rope=rope_static[j],
                             ), use_moe=cfg.is_moe, ffn=False,
                         )
                     if has_ffn[j]:
@@ -705,6 +716,7 @@ def forward_hidden(
             while (
                 off + ln < n_scan and scan_kinds[off + ln] == g
                 and has_ffn[off + ln] == has_ffn[off]
+                and scan_ropes[off + ln] == scan_ropes[off]
             ):
                 ln += 1
             sl = slice(off, off + ln)
@@ -712,8 +724,7 @@ def forward_hidden(
                 x, caches[g], census, tables[g], None,
                 plane_arr[sl], layer_arr[sl],
                 win_arr[sl] if g and win_arr is not None else None,
-                layer_kinds[n_dense + off], run_physes[g],
-                None if rot_arr is None or no_rope else rot_arr[sl],
+                layer_kinds[n_dense + off], run_physes[g], scan_ropes[off],
                 kind_name(n_dense + off),
                 None if ffn_arr is None else ffn_arr[sl], has_ffn[off],
             )

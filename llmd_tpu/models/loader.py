@@ -19,7 +19,10 @@ Supported architectures: LlamaForCausalLM, Qwen2ForCausalLM,
 Qwen3ForCausalLM, MixtralForCausalLM, Qwen3MoeForCausalLM,
 DeepseekV2ForCausalLM, DeepseekV3ForCausalLM, ExaoneMoEForCausalLM
 (``model_type: exaone_moe``: the config's keys mapped; its tensor names are
-taken to follow DeepSeek-V3's, which the key names follow).
+taken to follow DeepSeek-V3's, which the key names follow),
+MellumForCausalLM (``model_type: mellum``, Mellum2: its keys are Qwen3-MoE's,
+whose QK-norm and tensor names it is taken to follow, plus ``layer_types``
+and a ``rope_parameters`` keyed by layer type).
 """
 
 from __future__ import annotations
@@ -45,7 +48,7 @@ _DENSE_ARCHS = {
 }
 _MOE_ARCHS = {
     "MixtralForCausalLM", "Qwen3MoeForCausalLM", "GptOssForCausalLM",
-    "ExaoneMoEForCausalLM",
+    "ExaoneMoEForCausalLM", "MellumForCausalLM",
 }
 _MLA_ARCHS = {"DeepseekV2ForCausalLM", "DeepseekV3ForCausalLM"}
 SUPPORTED_ARCHS = _DENSE_ARCHS | _MOE_ARCHS | _MLA_ARCHS
@@ -76,14 +79,22 @@ def config_from_hf(model_dir: str, **overrides) -> ModelConfig:
             f"rope_scaling type {rope_type(rope_scaling)!r} "
             f"not supported (have: {SUPPORTED_ROPE_TYPES})"
         )
-    if rope_type(rope_scaling) == "yarn":
-        # HF's _compute_yarn_parameters falls back to the model's
-        # max_position_embeddings when the original length is absent.
-        rope_scaling = dict(rope_scaling)
-        rope_scaling.setdefault(
-            "original_max_position_embeddings",
-            hf.get("max_position_embeddings", 8192),
-        )
+    rope_scaling = _yarn_original(rope_scaling, hf)
+    # ``rope_parameters`` flat holds the model's theta; keyed by layer type
+    # (Mellum2) it holds a table per kind of layer, and the model's own is
+    # the plain one among them (else the first).
+    rope_own = hf.get("rope_parameters") or {}
+    rope_by_type = None
+    if rope_own and all(isinstance(v, dict) for v in rope_own.values()):
+        rope_by_type = {t: _yarn_original(v, hf) for t, v in rope_own.items()}
+        for own in rope_by_type.values():
+            if rope_type(own) not in SUPPORTED_ROPE_TYPES:
+                raise ValueError(
+                    f"rope_parameters type {rope_type(own)!r} "
+                    f"not supported (have: {SUPPORTED_ROPE_TYPES})"
+                )
+        plain = [v for v in rope_by_type.values() if rope_type(v) == "default"]
+        rope_own = (plain or list(rope_by_type.values()))[0]
     kw: dict = dict(
         name=p.name or str(p),
         vocab_size=hf["vocab_size"],
@@ -94,8 +105,7 @@ def config_from_hf(model_dir: str, **overrides) -> ModelConfig:
         num_kv_heads=hf.get("num_key_value_heads", hf["num_attention_heads"]),
         head_dim=hf.get("head_dim"),
         rope_theta=float(
-            hf.get("rope_theta")
-            or (hf.get("rope_parameters") or {}).get("rope_theta", 10000.0)
+            hf.get("rope_theta") or rope_own.get("rope_theta", 10000.0)
         ),
         rope_scaling=rope_scaling,
         rms_norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
@@ -122,12 +132,14 @@ def config_from_hf(model_dir: str, **overrides) -> ModelConfig:
             # (Qwen2Config: 28) — falling through to uniform windows here
             # would silently slide layers the trained model didn't.
             kw["max_window_layers"] = int(hf.get("max_window_layers", 28))
+    if rope_by_type is not None and kw.get("layer_types"):
+        kw["rope_parameters"] = rope_by_type
     if arch == "Qwen2ForCausalLM":
         # Qwen2 uses bias on the QKV projections (no config flag).
         kw["attention_bias"] = True
     else:
         kw["attention_bias"] = bool(hf.get("attention_bias", False))
-    if arch in ("Qwen3ForCausalLM", "Qwen3MoeForCausalLM"):
+    if arch in ("Qwen3ForCausalLM", "Qwen3MoeForCausalLM", "MellumForCausalLM"):
         kw["qk_norm"] = True
     if arch == "MixtralForCausalLM":
         kw.update(
@@ -135,7 +147,7 @@ def config_from_hf(model_dir: str, **overrides) -> ModelConfig:
             num_experts_per_tok=hf["num_experts_per_tok"],
             moe_intermediate_size=hf["intermediate_size"],
         )
-    elif arch == "Qwen3MoeForCausalLM":
+    elif arch in ("Qwen3MoeForCausalLM", "MellumForCausalLM"):
         kw.update(
             num_experts=hf["num_experts"],
             num_experts_per_tok=hf["num_experts_per_tok"],
@@ -195,6 +207,19 @@ def config_from_hf(model_dir: str, **overrides) -> ModelConfig:
     return ModelConfig(**kw)
 
 
+def _yarn_original(scaling: dict | None, hf: dict) -> dict | None:
+    """HF's _compute_yarn_parameters falls back to the model's
+    max_position_embeddings when the original length is absent."""
+    from llmd_tpu.models.common import rope_type
+
+    if rope_type(scaling) != "yarn":
+        return scaling
+    return {
+        "original_max_position_embeddings": hf.get("max_position_embeddings", 8192),
+        **scaling,
+    }
+
+
 def exaone_moe_fields(hf: dict) -> dict:
     """ModelConfig fields from the keys of an ``exaone_moe`` config.json
     (K-EXAONE): sigmoid scores with a selection-only bias, top-k over the
@@ -204,7 +229,7 @@ def exaone_moe_fields(hf: dict) -> dict:
     hybrid-attention convention; config.json has no key for either)."""
     return dict(
         qk_norm=True,
-        rope_layer_types=("sliding_attention",),
+        rope_parameters={"full_attention": None},
         num_experts=hf["num_experts"],
         num_experts_per_tok=hf["num_experts_per_tok"],
         moe_intermediate_size=hf["moe_intermediate_size"],

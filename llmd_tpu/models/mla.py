@@ -189,7 +189,7 @@ def _mix(h, lp, cache, step: StepCtx, layer: LayerCtx):
             "only; the flat stream's write plan addresses K and V"
         )
     return mla_attention(
-        h, lp, cache, layer.plane, step.inp, step.cfg, step.cos, step.sin,
+        h, lp, cache, layer.plane, step.inp, step.cfg, *step.ropes[0],
         world_size=step.world_size, mesh=step.mesh,
     )
 
@@ -197,7 +197,7 @@ def _mix(h, lp, cache, step: StepCtx, layer: LayerCtx):
 def _halves(h, lp, cache, step: StepCtx, layer: LayerCtx):
     cfg, inp = step.cfg, step.inp
     cache, q_eff = mla_write(
-        h, lp, cache, layer.plane, inp, cfg, step.cos, step.sin,
+        h, lp, cache, layer.plane, inp, cfg, *step.ropes[0],
         world_size=step.world_size, mesh=step.mesh,
     )
 

@@ -75,7 +75,7 @@ def from_published(w: np.ndarray, heads: int, head_dim: int, rope_dim: int):
 
 def hoist(cfg: ModelConfig, inp):
     """The indexer's rope tables: ``indexer_rope_dim`` of its dimensions
-    rotate (the main attention's are ``step.cos`` / ``step.sin``)."""
+    rotate (the main attention's are ``step.ropes[0]``)."""
     if inp.token_rows is None:
         raise NotImplementedError(
             f"{cfg.name}: learned sparse attention runs on the flat step "
@@ -95,6 +95,7 @@ def mix(h, lp, cache, step: StepCtx, layer: LayerCtx):
     rank, Dl = cfg.kv_lora_rank, cfg.kv_cache_entry_dim
     eps = cfg.rms_norm_eps
     icos, isin = step.hoisted[KIND]
+    cos, sin = step.ropes[0]
     # ---- queries, from the normed query latent
     c_q = rms_norm(pdot(h, lp, "wq_a"), lp["q_norm"], eps)
     # The two products of c_q come out FLAT before their heads are told
@@ -105,11 +106,11 @@ def mix(h, lp, cache, step: StepCtx, layer: LayerCtx):
         (pdot(c_q, lp, "wq_b"), c_q @ lp["wi_q"])
     )
     q = q.reshape(T, 1, nh, nope + rope)
-    q_pe = apply_rope(q[..., nope:], step.cos, step.sin)
+    q_pe = apply_rope(q[..., nope:], cos, sin)
     # ---- the cached row: [RMSNorm(c), RoPE(k_r)], padded to the lane tile
     kv_a = pdot(h, lp, "wkv_a")
     c_kv = rms_norm(kv_a[..., :rank], lp["kv_norm"], eps)
-    k_pe = apply_rope(kv_a[..., None, rank:], step.cos, step.sin)[:, :, 0]
+    k_pe = apply_rope(kv_a[..., None, rank:], cos, sin)[:, :, 0]
     latent = jnp.pad(
         jnp.concatenate([c_kv, k_pe], axis=-1),
         ((0, 0), (0, 0), (0, Dl - rank - rope)),

@@ -197,7 +197,7 @@ def _k_exaone_236b_a23b() -> ModelConfig:
         head_dim=128, rope_theta=1000000.0, max_model_len=262144,
         rms_norm_eps=1e-5, qk_norm=True,
         sliding_window=128, layer_types=_LLLG * 12,
-        rope_layer_types=("sliding_attention",),
+        rope_parameters={"full_attention": None},
         num_experts=128, num_experts_per_tok=8, moe_intermediate_size=2048,
         shared_expert_intermediate_size=2048, first_dense_layers=1,
         router_scoring="sigmoid", topk_method="group_top2",
@@ -214,12 +214,67 @@ def _tiny_exaone() -> ModelConfig:
     return tiny_model_config(
         name="tiny-exaone", num_layers=8, qk_norm=True, max_model_len=512,
         sliding_window=16, layer_types=_LLLG * 2,
-        rope_layer_types=("sliding_attention",),
+        rope_parameters={"full_attention": None},
         num_experts=16, num_experts_per_tok=2, moe_intermediate_size=64,
         shared_expert_intermediate_size=64, first_dense_layers=1,
         router_scoring="sigmoid", topk_method="group_top2",
         norm_topk_prob=True, routed_scaling_factor=2.5,
         held_experts=4, held_experts_first=4,
+    )
+
+
+@register_model("mellum2-12b-a2.5b")
+def _mellum2_12b_a2_5b() -> ModelConfig:
+    """Mellum2-12B-A2.5B (HF JetBrains/Mellum2-12B-A2.5B-Instruct,
+    ``model_type: mellum``) as published: 32 q / 4 kv heads of 128 over hidden
+    2,304; three sliding layers (window 1,024) to one full layer, and a RoPE
+    table per kind of layer: the sliding layers rotate under the plain table
+    (theta 5e5), the full layers under YaRN (factor 16 over 8,192, with its
+    factor on cos and sin). Every layer's FFN is 64 experts top-8 of width
+    896, softmax over all, renormalised, no shared expert. QK-norm is the
+    Qwen3-MoE convention whose key names the config uses (config.json has no
+    key for it). The MTP head its card mentions has no key and is not served.
+    A rank of an expert-parallel deployment overrides ``held_experts`` /
+    ``held_experts_first`` (docs/architecture/wide-ep.md)."""
+    return ModelConfig(
+        name="mellum2-12b-a2.5b", vocab_size=98304, hidden_size=2304,
+        intermediate_size=7168, num_layers=28, num_heads=32, num_kv_heads=4,
+        head_dim=128, rope_theta=500000.0, max_model_len=131072,
+        rms_norm_eps=1e-6, qk_norm=True,
+        sliding_window=1024, layer_types=_LLLG * 7,
+        rope_parameters={
+            "full_attention": {
+                "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+                "original_max_position_embeddings": 8192, "beta_fast": 32,
+                "beta_slow": 1, "attention_factor": 1.2772588722239782,
+            },
+            "sliding_attention": {"rope_type": "default", "rope_theta": 500000},
+        },
+        num_experts=64, num_experts_per_tok=8, moe_intermediate_size=896,
+        norm_topk_prob=True,
+    )
+
+
+@register_model("tiny-mellum2")
+def _tiny_mellum2() -> ModelConfig:
+    """Mellum2's architecture in miniature (CPU tests and the benchmark's
+    rehearsal): ``LLLG`` x 2 with window 16, YaRN (factor 4 over 64
+    positions) on the full layers and the plain table on the sliding ones,
+    softmax top-2 of 16 renormalised, no shared expert, and a held share:
+    this rank holds experts 4-7 of the 16 the router scores."""
+    return tiny_model_config(
+        name="tiny-mellum2", num_layers=8, qk_norm=True, max_model_len=512,
+        rms_norm_eps=1e-6, sliding_window=16, layer_types=_LLLG * 2,
+        rope_parameters={
+            "full_attention": {
+                "rope_type": "yarn", "rope_theta": 10000.0, "factor": 4,
+                "original_max_position_embeddings": 64, "beta_fast": 32,
+                "beta_slow": 1, "attention_factor": 1.1386294361119891,
+            },
+            "sliding_attention": {"rope_type": "default", "rope_theta": 10000.0},
+        },
+        num_experts=16, num_experts_per_tok=2, moe_intermediate_size=64,
+        norm_topk_prob=True, held_experts=4, held_experts_first=4,
     )
 
 
@@ -385,8 +440,7 @@ def _tiny_nemotron_h() -> ModelConfig:
     types, ffn = nemotron_h_layers("MEMEM*E" * 2 + "MEM*E")
     return tiny_model_config(
         name="tiny-nemotron-h", num_layers=len(types), max_model_len=512,
-        rms_norm_eps=1e-5, layer_types=types, layer_ffn=ffn,
-        rope_layer_types=(),
+        rms_norm_eps=1e-5, layer_types=types, layer_ffn=ffn, rope_layer_types=(),
         mamba_n_heads=4, mamba_d_head=8, mamba_d_state=16,
         mamba_n_groups=2, mamba_d_conv=4,
         num_experts=8, num_experts_per_tok=2, moe_intermediate_size=24,

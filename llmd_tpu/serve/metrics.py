@@ -238,6 +238,9 @@ def render_metrics(
         counters["swa_section_hits_total"] = stats.swa_section_hits_total
         counters["swa_section_misses_total"] = stats.swa_section_misses_total
         counters["swa_section_captures_total"] = stats.swa_section_captures
+        counters["swa_ring_seeds_total"] = stats.swa_ring_seeds_total
+        counters["swa_ring_seed_pages_total"] = stats.swa_ring_seed_pages_total
+        counters["swa_ring_seed_host_ms_total"] = stats.swa_ring_seed_host_ms_total
     if stats.state_bytes_in_use_total:
         # The state pool of a model with state-space layers: the retained-
         # state cache's activity, the slots' bytes beside the pages'

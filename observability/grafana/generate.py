@@ -275,6 +275,20 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                    "nothing; evictions at the rate of captures = the "
                    "retained-state cache is too small for the sessions "
                    "it serves (every turn leaves one snapshot behind)."),
+        panel("Ring seeds: host ms and pages a seed, seeds /s",
+              [f"rate(llmd:swa_ring_seed_host_ms_total{M}[5m]) / "
+               f"rate(llmd:swa_ring_seeds_total{M}[5m])",
+               f"rate(llmd:swa_ring_seed_pages_total{M}[5m]) / "
+               f"rate(llmd:swa_ring_seeds_total{M}[5m])",
+               f"rate(llmd:swa_ring_seeds_total{M}[5m])"],
+              legends=["host ms a seed", "pages a seed", "seeds/s"],
+              desc="A hybrid prefix hit copies a retained section into a "
+                   "fresh ring at the admission that takes it (span "
+                   "llmd.ring.seed, in the schedule or in a top-up): "
+                   "window/page pages x the sliding layers, 64 x 21 at a "
+                   "1,024-token window. The step that carries the "
+                   "admission is later by the host ms; the copy itself "
+                   "runs on the device in front of that step."),
         panel("Retained-state capture: host ms, prompts hashed again, "
               "captures at a foreseen finish",
               [f"rate(llmd:retained_capture_host_ms_total{M}[5m]) / "

@@ -52,7 +52,7 @@ SUITES = {
     # option that the attention body branches on.
     "cpu": [
         "tiny-moe", "tiny-swa", "tiny-swa:swa_ring=true", "tiny-mla", "tiny-dsa",
-        "tiny-exaone:swa_ring=true", "tiny-granite-hybrid", "tiny-nemotron-h",
+        "tiny-exaone:swa_ring=true", "tiny-mellum2:swa_ring=true", "tiny-granite-hybrid", "tiny-nemotron-h",
         "tiny-qwen3-next", "tiny-mla-dsa",
         "tiny:num_lora_adapters=2",
         "tiny:attention_bias=true,attention_sinks=true",

@@ -101,7 +101,7 @@ def test_the_preset_runs_the_flat_step_over_both_pools_in_one_cycle_body():
     # the attention layers: a q projection of twice the width, a quarter rotated
     assert r.params["attn_layers"]["wq"].shape == (2, 64, 4 * 2 * 16) and MODEL.rotary_dim == 4
     assert "wq" not in r.params["layers"] and r.params["layers"]["ws_sig"].shape == (8, 64, 1)
-    assert all(MODEL.layer_rotates)
+    assert set(MODEL.layer_rope) == {0}
     # granite's slot, through the same property
     assert GRANITE.state_shapes == ((4, 8, 16), (3, 4 * 8 + 2 * 16)) and not GRANITE.delta_rule
 

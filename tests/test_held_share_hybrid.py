@@ -338,11 +338,11 @@ def test_dense_prefix_and_aperiodic_scan_equal_layer_by_layer(ring):
 
 def test_rope_only_where_the_layer_type_says():
     cfg = get_model_config("tiny-exaone")
-    assert cfg.layer_rotates == (True, True, True, False) * 2
-    assert get_model_config("tiny").layer_rotates == (True, True)
+    assert cfg.layer_rope == (0, 0, 0, None) * 2 and len(cfg.rope_specs) == 1
+    assert get_model_config("tiny").layer_rope == (0, 0)
     params = llama.init_params(cfg, jax.random.key(4))
     toks = tokens(2 * WINDOW, seed=12)
-    everywhere = dataclasses.replace(cfg, rope_layer_types=None)
+    everywhere = dataclasses.replace(cfg, rope_parameters=None)
     assert float(jnp.max(jnp.abs(_forward(cfg, params, toks, True) - _forward(everywhere, params, toks, True)))) > 1e-3
 
 
@@ -363,7 +363,7 @@ def test_the_configuration_file_reaches_the_program_as_published():
     for field in ("hidden_size", "intermediate_size", "num_heads", "num_kv_heads", "head_dim", "sliding_window",
                   "moe_intermediate_size", "shared_expert_intermediate_size", "num_experts_per_tok",
                   "first_dense_layers", "router_scoring", "routed_scaling_factor", "norm_topk_prob", "rope_theta",
-                  "rms_norm_eps", "qk_norm", "rope_layer_types", "tie_word_embeddings"):
+                  "rms_norm_eps", "qk_norm", "rope_parameters", "tie_word_embeddings"):
         assert getattr(m, field) == getattr(preset, field), field
     assert cfg.cache.swa_ring and (m.hidden_size, m.num_heads, m.num_kv_heads) == (6144, 64, 8)
     assert sorted(CONF["reduced"]) == ["num_experts", "num_hidden_layers", "vocab_size"]

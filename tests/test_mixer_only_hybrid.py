@@ -110,7 +110,7 @@ def test_the_configuration_file_reaches_the_program_as_published():
     assert (cfg.router_scoring, cfg.routed_scaling_factor, cfg.norm_topk_prob) == ("sigmoid", 2.5, True)
     assert (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state, cfg.mamba_n_groups) == (64, 64, 128, 8)
     assert (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.vocab_size) == (32, 2, 128, 16384)
-    assert not any(cfg.layer_rotates) and not cfg.tie_word_embeddings and cfg.rms_norm_eps == 1e-5
+    assert set(cfg.layer_rope) == {None} and not cfg.tie_word_embeddings and cfg.rms_norm_eps == 1e-5
     shapes = jax.eval_shape(lambda k: llama.init_params(cfg, k), jax.random.key(0))
     lp = shapes["layers"]
     assert lp["we_up"].shape == (12, 16, 2688, 1920) and lp["we_down"].shape == (12, 16, 1920, 2688)

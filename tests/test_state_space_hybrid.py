@@ -207,7 +207,7 @@ def test_the_preset_runs_the_flat_step_over_a_state_pool():
     assert r.kv_swa.ssm.dtype == jnp.float32
     assert "state_slots" in {f.name for f in r._layout(11, r.flat_rows, 16).fields}
     # no layer rotates, and the scale is the configuration's
-    assert not any(MODEL.layer_rotates) and MODEL.sm_scale == 1 / 16 != MODEL.head_dim ** -0.5
+    assert set(MODEL.layer_rope) == {None} and MODEL.sm_scale == 1 / 16 != MODEL.head_dim ** -0.5
     assert llama._scan_period(tuple(int(t == "mamba") for t in MODEL.layer_types)) is None
 
 
@@ -361,7 +361,7 @@ def test_the_configuration_file_reaches_the_program_as_published():
     assert preset.layer_types == tuple(CONF["layer_types"]) and preset.num_layers == CONF["published"]["num_hidden_layers"]
     for field in ("hidden_size", "num_heads", "num_kv_heads", "head_dim", "moe_intermediate_size",
                   "shared_expert_intermediate_size", "num_experts_per_tok", "norm_topk_prob", "rms_norm_eps",
-                  "rope_layer_types", "tie_word_embeddings", "attention_multiplier", "embedding_multiplier",
+                  "rope_parameters", "tie_word_embeddings", "attention_multiplier", "embedding_multiplier",
                   "residual_multiplier", "logits_scaling", "mamba_n_heads", "mamba_d_head", "mamba_d_state",
                   "mamba_n_groups", "mamba_d_conv", "router_scoring"):
         assert getattr(m, field) == getattr(preset, field), field
