@@ -649,6 +649,16 @@ def phase_kernels(profile: dict, seed: int, rehearse: bool) -> None:
         ref = jax.jit(paged_attention_xla)(
             q, wrote[L - 1], tok_table[:, :ctx_pages], kv_lens, pos2, scales=lscales)
         close(f"flat_attention-{tag}", got, ref, plan["live"], atol, ATTN_RTOL)
+        # ... and under a sliding window that starts inside a page: the same
+        # tiles, a shared one reading from its first token's window start
+        window = jnp.int32(ctx // 3 + 5)
+        wgot = jax.jit(flat_paged_attention_full, static_argnames="interpret")(
+            q, wrote, layer, jnp.asarray(plan["rows"]), jnp.asarray(plan["table"]),
+            kv_lens, interpret=interpret, scales=scales, window=window)
+        wref = jax.jit(paged_attention_xla)(
+            q, wrote[L - 1], tok_table[:, :ctx_pages], kv_lens, pos2, scales=lscales,
+            window=window)
+        close(f"window_attention-{tag}", wgot, wref, plan["live"], atol, ATTN_RTOL)
 
         # decode attention
         dq = normal((B, 1, H, D), jnp.bfloat16, 2.0 / spread)

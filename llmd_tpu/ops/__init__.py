@@ -501,8 +501,9 @@ def paged_attention_full_flat(
     """Flattened-token (``cu_q_lens``) layer-indexed attention: q is the
     packed ``[T, 1, H, D]`` stream, ``kv_lens`` is per TOKEN (position +
     1 — causality within a row derived from the packing), and the TPU
-    kernel iterates tokens against the compact per-row table through
-    its row-lookup prologue. XLA fallback gathers per-token table rows
+    kernel iterates 16-token tiles of the stream against the compact
+    per-row table through the scalar-prefetched token -> row map, with
+    or without a sliding window. XLA fallback gathers per-token table rows
     and reuses the bucketed reference path."""
     kv_cache_full, kv_scales = _split_cache(kv_cache_full)
     L, num_pages, K, page, D2 = kv_cache_full.shape

@@ -17,12 +17,12 @@ block's pages are in flight.
 Two grids over the same block stream (``_stream_blocks``) and the same
 online-softmax step (``_attend_block``):
 - ``_decode_kernel``, grid (B,): one program a sequence (the bucketed
-  [B, 1] decode) or a stream TOKEN (the flat stream's sliding-window
-  calls), each streaming its own live pages;
+  [B, 1] decode), each streaming its own live pages;
 - ``_flat_tile_kernel``, grid (T / 16,): one program per 16-token tile of
-  the flat stream. A tile inside ONE page-table row (a prefill chunk's
-  body) reads the row's pages once for its 16 queries; any other tile goes
-  token by token, exactly as the grid of tokens would.
+  the flat stream, with or without a sliding window. A tile inside ONE
+  page-table row (a prefill chunk's body) reads the row's pages once for
+  its 16 queries, from its first token's window start to its last token's
+  horizon; any other tile goes token by token, as a grid of tokens would.
 """
 
 from __future__ import annotations
@@ -125,14 +125,15 @@ def _reset(m_ref, l_ref, acc_ref, M):
 
 def _attend_block(
     q, kv, i, m_ref, l_ref, acc_ref, *, head_dim, sm_scale, key_end,
-    kv_len, win_start=0, ks=None, vs=None, chosen=None,
+    kv_len, key_start=0, win_start=0, ks=None, vs=None, chosen=None,
 ):
     """One online-softmax step: the M query rows ``q`` [K, M, D] against
     the S keys of compute block ``i`` in ``kv`` [K, S, 2D]; running max,
     sum and f32 accumulator in the first M rows of the scratch refs.
-    ``kv_len`` bounds each query row (a scalar, or [1, M, 1] where the
-    rows are a tile's tokens, each with its own causal horizon);
-    ``key_end`` is the furthest of them: keys past it were never fetched.
+    ``kv_len`` bounds each query row above and ``win_start`` (a sliding
+    window's first position) below: scalars, or [1, M, 1] where the rows
+    are a tile's tokens, each with its own. Keys before ``key_start``'s
+    page or past ``key_end`` (the nearest and furthest) were never fetched.
     ``ks``/``vs`` [K, S] are int8 row scales, ``chosen`` (broadcastable
     to [K, M, S]) the learned-sparse selection."""
     D = head_dim
@@ -145,7 +146,7 @@ def _attend_block(
     # window) hold uninitialized VMEM; zero them so a stray NaN
     # can't poison the (0-prob x v) accumulation.
     pos_v = i * S + jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
-    live_v = jnp.logical_and(pos_v < key_end, pos_v >= win_start)
+    live_v = jnp.logical_and(pos_v < key_end, pos_v >= key_start)
     v = jnp.where(live_v, v, 0.0)
     # K-batched (M, D) x (D, S) -> [K, M, S], f32 accumulate.
     s = jax.lax.dot_general(
@@ -207,9 +208,6 @@ def _normalized(m_ref, l_ref, acc_ref, M, sinks=None):
 def _decode_kernel(
     # scalar prefetch
     layer_ref,  # [1] i32 layer index (full-cache variant; [0] otherwise)
-    # [rows_ref [T] i32 when row_lookup: the flattened-token layout's
-    # token -> page-table-row map — the row-lookup prologue that lets
-    # the grid iterate TOKENS against a compact [R, max_pages] table]
     *refs,
     page_size: int,
     head_dim: int,
@@ -217,12 +215,10 @@ def _decode_kernel(
     pages_per_block: int,
     has_sinks: bool,
     quant: bool,
-    row_lookup: bool = False,
 ):
     # remaining scalar prefetch:
-    #   page_table_ref  [B|R, max_pages] i32
-    #   kv_lens_ref     [B] i32 (per token when row_lookup: position + 1,
-    #                   the causal mask derived from cu_q_lens)
+    #   page_table_ref  [B, max_pages] i32
+    #   kv_lens_ref     [B] i32
     #   win_starts_ref  [B] i32 first attended position (sliding; 0=full)
     # blocks: q_ref, sinks_ref, kv_hbm_full_ref, [ks_ref, vs_ref when
     # quant: [1, K, S_max] f32 per-row scales, gathered into lane-aligned
@@ -230,18 +226,12 @@ def _decode_kernel(
     # 128-aligned minor dim, which a page's [K, page, 2] scale slab (2
     # lanes) can never satisfy, so the scales cannot ride per-page DMAs
     # like the data], out_ref — see _decode_call
-    if row_lookup:
-        rows_ref, *refs = refs
     page_table_ref, kv_lens_ref, win_starts_ref, *refs = refs
     q_ref, sinks_ref, kv_hbm_full_ref, *refs = refs
     if quant:
         ks_ref, vs_ref, *refs = refs
     out_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
-    # Row-lookup prologue: program b handles TOKEN b; its pages live in
-    # the compact table's row rows_ref[b]. kv_lens/win_starts stay
-    # per-program (per token).
-    tr = rows_ref[b] if row_lookup else b
     kv_hbm_ref = (
         kv_hbm_full_ref.at[layer_ref[0]]
         if len(kv_hbm_full_ref.shape) == 5
@@ -262,13 +252,13 @@ def _decode_kernel(
             _attend_block(
                 q_ref[0], buf[slot], i, m_ref, l_ref, acc_ref,
                 head_dim=head_dim, sm_scale=sm_scale, key_end=kv_len,
-                kv_len=kv_len, win_start=win_start,
+                kv_len=kv_len, key_start=win_start, win_start=win_start,
                 ks=ks_ref[0, :, pl.ds(i * S, S)] if quant else None,
                 vs=vs_ref[0, :, pl.ds(i * S, S)] if quant else None,
             )
 
         _stream_blocks(
-            kv_hbm_ref, page_table_ref, buf, sem, tr,
+            kv_hbm_ref, page_table_ref, buf, sem, b,
             win_start // S,  # blocks fully before the window are skipped
             (kv_len + S - 1) // S, win_start // page_size,
             (kv_len + page_size - 1) // page_size, ppb, page_size, compute,
@@ -290,6 +280,8 @@ def _flat_tile_kernel(
     rows_ref,  # [T] i32 token -> page-table row
     page_table_ref,  # [R, max_pages] i32
     kv_lens_ref,  # [T] i32 per token: position + 1
+    # [win_starts_ref [T] i32 when windowed: per token its window's first
+    # position, 0 where the layer's window is <= 0]
     *refs,
     page_size: int,
     head_dim: int,
@@ -298,13 +290,15 @@ def _flat_tile_kernel(
     has_sinks: bool,
     quant: bool,
     select: bool,
+    windowed: bool,
     num_tokens: int,
 ):
     """One program per TILE consecutive stream tokens. A tile whose
     tokens all sit in one page-table row at consecutive positions (the
     body of a prefill chunk's sub-row) streams that row's live pages
-    ONCE, up to its last token's horizon, for all of its queries as one
-    [K, TILE*G, D] operand, each query row under its own causal bound.
+    ONCE, from its first token's window start (0 without a window) to its
+    last token's horizon, for all of its queries as one [K, TILE*G, D]
+    operand, each query row under its own window and causal bound.
     Any other tile (decode rows, a chunk's ragged head or tail, a
     sub-row's seam, verify rows, pad tokens, a shard's short last tile)
     goes token by token. Both ride the same block stream and the same
@@ -316,6 +310,9 @@ def _flat_tile_kernel(
     # when select: [TILE, S_max] f32, 1.0 where the token may read the
     # key], out_ref [TILE, K, G, D]; scratch m/l [K, TILE*G, 128], acc
     # [K, TILE*G, D], q16_ref [K, TILE*G, D].
+    win_starts_ref = None
+    if windowed:
+        win_starts_ref, *refs = refs
     q_ref, sinks_ref, kv_hbm_full_ref, *refs = refs
     ks_hbm_ref = vs_hbm_ref = sel_ref = None
     if quant:
@@ -353,22 +350,22 @@ def _flat_tile_kernel(
                 (vs_hbm_ref, vs_buf, ssem.at[1]),
             )
 
-        def attend(tr, q, key_end, kv_len, chosen):
-            """Row ``tr``'s keys [0, key_end) against the query rows that
-            ``q()`` loads ([K, M, D]), bounded row by row by ``kv_len``."""
+        def attend(tr, q, key_start, key_end, kv_len, win_start, chosen):
+            """Row ``tr``'s keys [key_start, key_end) against the query rows
+            that ``q()`` loads ([K, M, D]), each in [win_start, kv_len)."""
             def compute(slot, i):
                 _attend_block(
                     q(), buf[slot], i, m_ref, l_ref, acc_ref,
                     head_dim=head_dim, sm_scale=sm_scale, key_end=key_end,
-                    kv_len=kv_len,
+                    kv_len=kv_len, key_start=key_start, win_start=win_start,
                     ks=ks_buf[slot] if quant else None,
                     vs=vs_buf[slot] if quant else None,
                     chosen=chosen(i) if select else None,
                 )
 
             _stream_blocks(
-                kv_hbm_ref, page_table_ref, buf, sem, tr, 0,
-                (key_end + S - 1) // S, 0,
+                kv_hbm_ref, page_table_ref, buf, sem, tr, key_start // S,
+                (key_end + S - 1) // S, key_start // page_size,
                 (key_end + page_size - 1) // page_size, ppb, page_size,
                 compute, planes,
             )
@@ -382,9 +379,18 @@ def _flat_tile_kernel(
                 [q_ref[j] for j in range(TILE)], axis=1
             )
             tok_of = jax.lax.broadcasted_iota(jnp.int32, (1, M, 1), 1) // G
+            ws0 = win = 0
+            if windowed:
+                # Consecutive positions under one window: each start is
+                # the last token's less their distance, floored at 0.
+                ws0 = win_starts_ref[t0]
+                win = jnp.maximum(
+                    win_starts_ref[t0 + (TILE - 1)] - (TILE - 1) + tok_of, 0
+                )
             _reset(m_ref, l_ref, acc_ref, M)
             attend(
-                row0, lambda: q16_ref[...], kvl0 + (TILE - 1), kvl0 + tok_of,
+                row0, lambda: q16_ref[...], ws0, kvl0 + (TILE - 1),
+                kvl0 + tok_of, win,
                 lambda i: jnp.concatenate([
                     jnp.broadcast_to(
                         sel_ref[j:j + 1, pl.ds(i * S, S)], (G, S)
@@ -401,9 +407,10 @@ def _flat_tile_kernel(
             def token(u, _):
                 t = t0 + u
                 kv_len = kv_lens_ref[t]
+                ws = win_starts_ref[t] if windowed else 0
                 _reset(m_ref, l_ref, acc_ref, G)
                 attend(
-                    rows_ref[t], lambda: q_ref[u], kv_len, kv_len,
+                    rows_ref[t], lambda: q_ref[u], ws, kv_len, kv_len, ws,
                     lambda i: (
                         sel_ref[pl.ds(u, 1), pl.ds(i * S, S)] > 0.5
                     )[None],
@@ -451,6 +458,16 @@ def _row_scale_planes(scales, layer, page_table):
     return ksvs[:, :, 0], ksvs[:, :, 1]
 
 
+def _win_starts(kv_lens, window):
+    """Per query the first position a sliding window lets it attend: the
+    query sits at kv_len - 1. ``window`` may be a traced per-layer scalar;
+    <= 0 degrades to full attention."""
+    window = jnp.asarray(window, jnp.int32)
+    return jnp.where(
+        window > 0, jnp.maximum(kv_lens - window, 0), 0
+    ).astype(jnp.int32)
+
+
 def _decode_call(
     q, kv_cache, layer, page_table, kv_lens, sm_scale, interpret,
     pages_per_block, window=None, sinks=None, scales=None,
@@ -469,16 +486,10 @@ def _decode_call(
         page_table = jnp.pad(page_table, ((0, 0), (0, pad)))
 
     qk = q.reshape(B, K, G, D)
-    # Sliding window: the decode query sits at kv_len-1, so the first
-    # attended position is max(0, kv_len - window). window may be a traced
-    # per-layer scalar; window<=0 (or None) degrades to full attention.
-    if window is None:
-        win_starts = jnp.zeros_like(kv_lens)
-    else:
-        window = jnp.asarray(window, jnp.int32)
-        win_starts = jnp.where(
-            window > 0, jnp.maximum(kv_lens - window, 0), 0
-        ).astype(jnp.int32)
+    win_starts = (
+        jnp.zeros_like(kv_lens) if window is None
+        else _win_starts(kv_lens, window)
+    )
 
     if sinks is None:
         sinks2d = jnp.zeros((K, G), jnp.float32)
@@ -558,6 +569,13 @@ def decode_paged_attention(
     )
 
 
+# Jitted INLINE: no call boundary in the step program, but calls that agree
+# in shapes and options (a cycle body's sliding layers; one T's greedy and
+# sampled programs) share one trace and one lowering of the kernel.
+@functools.partial(
+    jax.jit, static_argnames=("sm_scale", "interpret", "pages_per_block"),
+    inline=True,
+)
 def flat_paged_attention_full(
     q: jax.Array,  # [T, 1, H, D] packed token-query stream
     kv_cache: jax.Array,  # [L, num_pages, K, page, 2D] (whole model)
@@ -587,9 +605,10 @@ def flat_paged_attention_full(
     verify rows, a chunk's ragged ends, pad tokens) goes token by token,
     one pass over the token's live pages each, so a pure decode row
     still costs one pass. The kernel decides per tile from ``rows`` and
-    ``kv_lens``. A sliding-window call (``window is not None``) keeps one
-    program per TOKEN: a window's few pages are not worth a tile, and
-    ``kernels.window_attention_roofline`` counts them per token.
+    ``kv_lens``. ``window`` (a sliding layer's; a traced per-layer scalar
+    may be <= 0: full attention) bounds each token below too, at ``kv_len
+    - window``: a shared tile then reads from its first token's start. A
+    call without one carries no such operand: its program knows no window.
 
     ``sel`` (learned sparse attention) masks every key a token's indexer
     did not select, on top of the causal mask: the pass stays dense over
@@ -606,35 +625,27 @@ def flat_paged_attention_full(
     if max_pages % pages_per_block:
         pad = pages_per_block - max_pages % pages_per_block
         page_table = jnp.pad(page_table, ((0, 0), (0, pad)))
-    mp = page_table.shape[1]
-    per_token = window is not None
-    assert not (per_token and sel is not None), "no windowed sparse layer"
-    tile = 1 if per_token else TILE  # stream tokens a program holds
+    assert window is None or sel is None, "no windowed sparse layer"
 
     qk = q.reshape(T, K, G, D)
     if sinks is None:
-        sinks2d = jnp.zeros((K, tile * G), jnp.float32)
+        sinks2d = jnp.zeros((K, TILE * G), jnp.float32)
     else:
-        sinks2d = jnp.tile(sinks.astype(jnp.float32).reshape(K, G), (1, tile))
+        sinks2d = jnp.tile(sinks.astype(jnp.float32).reshape(K, G), (1, TILE))
     prefetch = [
         jnp.asarray(layer, jnp.int32).reshape(1), rows.astype(jnp.int32),
         page_table, kv_lens,
     ]
-    if per_token:
-        window = jnp.asarray(window, jnp.int32)
-        prefetch.append(jnp.where(
-            window > 0, jnp.maximum(kv_lens - window, 0), 0
-        ).astype(jnp.int32))
+    if window is not None:
+        prefetch.append(_win_starts(kv_lens, window))
 
     def at(*index):
         """An index map over (program, *scalar prefetch refs)."""
-        return lambda b, l, r, *_: tuple(
-            r[b] if i == "row" else b if i == "b" else 0 for i in index
-        )
+        return lambda b, *_: tuple(b if i == "b" else 0 for i in index)
 
     in_specs = [
-        pl.BlockSpec((tile, K, G, D), at("b", 0, 0, 0)),
-        pl.BlockSpec((K, tile * G), at(0, 0)),
+        pl.BlockSpec((TILE, K, G, D), at("b", 0, 0, 0)),
+        pl.BlockSpec((K, TILE * G), at(0, 0)),
         pl.BlockSpec(memory_space=pl.ANY),  # stays in HBM; manual DMA
     ]
     operands = [qk, sinks2d, kv_cache]
@@ -642,48 +653,35 @@ def flat_paged_attention_full(
         # Per-ROW scale planes (_row_scale_planes): gathered ONCE per row,
         # so a prefill chunk's tokens share one plane instead of
         # duplicating it chunk-length times into a [T, max_pages, ...]
-        # intermediate. The per-token grid takes its row's whole plane
-        # through the scalar-prefetched row map in the BlockSpec; the
-        # tiles leave the planes in HBM and copy a block's slab beside
+        # intermediate. They stay in HBM; a block's slab is copied beside
         # its pages.
-        sspec = (
-            pl.BlockSpec((1, K, mp * page), at("row", 0, 0)) if per_token
-            else pl.BlockSpec(memory_space=pl.ANY)
-        )
+        sspec = pl.BlockSpec(memory_space=pl.ANY)
         in_specs.extend([sspec, sspec])
         operands.extend(_row_scale_planes(scales, layer, page_table))
     if sel is not None:
-        S_max = mp * page
+        S_max = page_table.shape[1] * page
         in_specs.append(pl.BlockSpec((TILE, S_max), at("b", 0)))
         operands.append(jnp.pad(
             sel.astype(jnp.float32), ((0, 0), (0, S_max - sel.shape[1]))
         ))
-    scratch_shapes = [
-        pltpu.VMEM((K, tile * G, 128), jnp.float32),
-        pltpu.VMEM((K, tile * G, 128), jnp.float32),
-        pltpu.VMEM((K, tile * G, D), jnp.float32),
-    ]
-    common = dict(
-        page_size=page, head_dim=D, sm_scale=sm_scale,
-        pages_per_block=pages_per_block, has_sinks=sinks is not None,
-        quant=scales is not None,
-    )
-    if per_token:
-        body = functools.partial(_decode_kernel, row_lookup=True, **common)
-    else:
-        scratch_shapes.append(pltpu.VMEM((K, TILE * G, D), q.dtype))
-        body = functools.partial(
-            _flat_tile_kernel, select=sel is not None, num_tokens=T,
-            **common,
-        )
     kernel = pl.pallas_call(
-        body,
+        functools.partial(
+            _flat_tile_kernel, page_size=page, head_dim=D, sm_scale=sm_scale,
+            pages_per_block=pages_per_block, has_sinks=sinks is not None,
+            quant=scales is not None, select=sel is not None,
+            windowed=window is not None, num_tokens=T,
+        ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
-            grid=(pl.cdiv(T, tile),),
+            grid=(pl.cdiv(T, TILE),),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((tile, K, G, D), at("b", 0, 0, 0)),
-            scratch_shapes=scratch_shapes,
+            out_specs=pl.BlockSpec((TILE, K, G, D), at("b", 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((K, TILE * G, 128), jnp.float32),
+                pltpu.VMEM((K, TILE * G, 128), jnp.float32),
+                pltpu.VMEM((K, TILE * G, D), jnp.float32),
+                pltpu.VMEM((K, TILE * G, D), q.dtype),
+            ],
         ),
         out_shape=jax.ShapeDtypeStruct((T, K, G, D), q.dtype),
         compiler_params=pltpu.CompilerParams(

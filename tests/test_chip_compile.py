@@ -39,6 +39,11 @@ LLAMA = (28, 24, 8, 128)
 QWEN3 = (48, 32, 4, 128)
 # k-exaone-236b-a23b.1chip's ring pool: its 6 sliding layers, 64 q / 8 kv heads.
 EXAONE = (6, 64, 8, 128)
+# mellum2-12b-a2.5b.1chip's ring pool: its 21 sliding layers (a window of
+# 1,024), 32 q / 4 kv heads; 24 rings of 73 pages + 40 sections of 65, seen
+# through a ring-view table of 32,768-token rows.
+MELLUM2 = (21, 32, 4, 128)
+MELLUM2_RING_PAGES, MELLUM2_TABLE = 24 * 73 + 40 * 65, (34, 2048)
 # Serving defaults: 64 sequences, a 2048-token step, 8192-token contexts:
 # 96 flat rows x 512 pages, and up to 2064 tokens a step.
 ROWS, SEQS, MAX_PAGES = 96, 64, 512
@@ -98,13 +103,21 @@ def _flat_attention(model, dtype, T):
     return flat_paged_attention_full, args
 
 
-def _window_attention(T):
+def _window_attention(T, model=EXAONE, pool_pages=PAGES, table=(ROWS, MAX_PAGES)):
     """The flat kernel with a window, as the sliding layers of a model that
-    mixes the two kinds call it (``window`` a traced per-layer scalar)."""
-    fn, args = _flat_attention(EXAONE, BF16, T)
+    mixes the two kinds call it (``window`` a traced per-layer scalar): the
+    16-token tile with each token's window start as a fifth prefetch array.
+    ``table`` is the ring VIEW's shape (logical page -> the ring's page):
+    as wide as the main table, whatever the ring holds."""
+    L, H, K, D = model
     return (
-        lambda q, kv, l, r, pt, kl, w: fn(q, kv, l, r, pt, kl, window=w),
-        args + [((), I32)],
+        lambda q, kv, l, r, pt, kl, w: flat_paged_attention_full(
+            q, kv, l, r, pt, kl, window=w
+        ),
+        [
+            ((T, 1, H, D), BF16), ((L, pool_pages, K, PAGE, 2 * D), BF16),
+            ((), I32), ((T,), I32), (table, I32), ((T,), I32), ((), I32),
+        ],
     )
 
 
@@ -367,6 +380,9 @@ CASES = {
     "latent_write-deepseek-v3.2": lambda d: _latent_write(144, d),
     "flat_attention-k-exaone-236b-a23b": lambda d: _flat_attention(EXAONE, BF16, 528),
     "window_attention-k-exaone-236b-a23b": lambda d: _window_attention(528),
+    "window_attention-mellum2-12b-a2.5b": lambda d: _window_attention(
+        128, MELLUM2, MELLUM2_RING_PAGES, MELLUM2_TABLE
+    ),
     "flat_write-k-exaone-236b-a23b": lambda d: _flat_write(EXAONE, BF16, 528),
     "flat_write-bf16": lambda d: _flat_write(LLAMA, BF16, 2064),
     "flat_write-int8": lambda d: _flat_write(LLAMA, I8, 256),
@@ -409,7 +425,7 @@ def test_kernel_compiles_for_v5e(v5e, case):
 @pytest.mark.parametrize("case", [
     "flat_attention-qwen3-30b-a3b", "sparse_attention-keye-vl-2.0-30b-a3b",
     "flat_attention-k-exaone-236b-a23b", "window_attention-k-exaone-236b-a23b",
-    "flat_attention-granite-4.0-h-small",
+    "window_attention-mellum2-12b-a2.5b", "flat_attention-granite-4.0-h-small",
 ])
 def test_the_attention_rooflines_can_read_the_tiled_call(v5e, case):
     """The benchmark's attention rooflines take a call's shapes from its HLO

@@ -677,14 +677,48 @@ def test_a_token_shard_that_ends_inside_a_tile_goes_token_by_token():
     np.testing.assert_allclose(np.asarray(out), np.asarray(oracle), atol=2e-5)
 
 
-def test_a_window_call_keeps_one_program_a_token():
-    """The tile is the full-attention call's; a sliding-window call (the
-    argument the caller already passes) stays on the grid of tokens, whose
-    rows ``kernels.window_attention_roofline`` counts per token."""
+def test_a_window_call_rides_the_grid_of_tiles():
+    """ONE grid for the flat stream: a sliding-window call (the argument the
+    caller already passes) runs T / 16 programs like the full-attention
+    call, so a chunk's window is read once a tile, not once a token."""
     import jax.numpy as jnp
 
     assert flat_attention_grid() == (4,)
-    assert flat_attention_grid(window=jnp.int32(16)) == (64,)
+    assert flat_attention_grid(window=jnp.int32(16)) == (4,)
+
+
+def test_calls_of_one_shape_share_one_trace_of_the_kernel():
+    """A step program calls the flat kernel once a layer of its cycle body
+    (three sliding layers and a full one, say) and once more in every other
+    program of the same T. The call is jitted INLINE: calls that agree in
+    shapes and options reuse one traced kernel (and one lowering of it), so
+    a T bucket's first call pays for each distinct call once, not once a
+    layer: what ``setup_s`` holds of the shape ladder."""
+    import jax
+    import jax.numpy as jnp
+
+    from llmd_tpu.ops.ragged_paged_attention import flat_paged_attention_full
+
+    T, K, G, D, page = 32, 2, 2, 128, 8
+
+    def step(q, kv, r, pt, kl):
+        for layer in range(3):
+            q = q + flat_paged_attention_full(
+                q, kv, jnp.int32(layer), r, pt, kl, interpret=True,
+                window=jnp.int32(16),
+            )
+        return q + flat_paged_attention_full(
+            q, kv, jnp.int32(3), r, pt, kl, interpret=True
+        )
+
+    jaxpr = jax.make_jaxpr(step)(
+        jnp.zeros((T, 1, K * G, D)), jnp.zeros((4, 8, K, page, 2 * D)),
+        jnp.zeros(T, jnp.int32), jnp.zeros((2, 4), jnp.int32),
+        jnp.zeros(T, jnp.int32),
+    )
+    calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 4  # inlined: no call boundary in the program
+    assert len({id(e.params["jaxpr"]) for e in calls}) == 2
 
 
 def test_shared_tile_tokens_count_what_the_stream_lays_out():

@@ -16,7 +16,13 @@ from llmd_tpu.config import (
     CacheConfig, EngineConfig, SchedulerConfig, tiny_model_config,
 )
 from llmd_tpu.ops.paged_attention import paged_attention_xla, write_kv_pages
-from llmd_tpu.ops.ragged_paged_attention import decode_paged_attention
+from llmd_tpu.ops.ragged_paged_attention import (
+    decode_paged_attention, flat_paged_attention_full,
+)
+
+from flat_streams import (
+    tiled_stream, window_attention_on_the_grid_of_tokens,
+)
 
 
 def _dense_windowed_oracle(q, k, v, positions, kv_lens, window):
@@ -224,3 +230,100 @@ def test_pallas_decode_sinks_matches_oracle():
             np.asarray(out), np.asarray(oracle(window)),
             atol=2e-4, rtol=2e-4,
         )
+
+
+def _one_row_stream(first, tokens, T):
+    """One sequence's chunk from position ``first``, pads behind it."""
+    tok_rows = np.zeros(T, np.int32)
+    positions = np.zeros(T, np.int32)
+    live = np.arange(T) < tokens
+    positions[:tokens] = first + np.arange(tokens)
+    return tok_rows, positions, live
+
+
+# window, and what differs from the mixed stream of ``tiled_stream`` (a
+# chunk with ragged ends, sub-rows and their seam, decode rows, a verify
+# row, pads) over an f32 pool, page 8, blocks of 32 keys.
+WINDOWED_TILES = {
+    "window_5_smaller_than_a_tile": (5, {}),
+    "window_16_a_tile": (16, {}),
+    "window_21_starts_mid_page_and_mid_block": (21, {}),
+    "window_32_a_block": (32, {}),
+    "window_50_larger_than_a_block": (50, {}),
+    "a_tile_at_a_rows_start_has_fewer_than_a_window_cached": (
+        24, {"stream": (0, 40, 48)}
+    ),
+    "a_traced_window_of_0_is_full_attention": (0, {"full": True}),
+    "a_traced_negative_window_is_full_attention": (-1, {"full": True}),
+    "a_ring_view_table_repeats_modulo_the_ring": (21, {"ring": 9}),
+    "sinks": (21, {"sinks": True}),
+    "int8_pool": (21, {"int8": True}),
+    "a_shards_short_last_tile": (21, {"stream": (9, 40, 40)}),
+}
+
+
+@pytest.mark.parametrize("case", list(WINDOWED_TILES))
+def test_windowed_tiles_match_xla_and_the_grid_of_tokens(case):
+    """The flat stream's sliding-window call on the 16-token tile: a tile
+    inside one row reads the row's pages ONCE, from its first token's
+    window start to its last token's horizon, each query row under its own
+    window and horizon; every other tile goes token by token. Against the
+    XLA oracle, and BIT FOR BIT against the grid of one program a token
+    that the call rode before (kept in ``flat_streams``): both visit the
+    same blocks in the same order for every token."""
+    window, what = WINDOWED_TILES[case]
+    rng = np.random.default_rng(11)
+    L, P, K, page, D, G, ppb = 2, 96, 2, 8, 128, 4, 4
+    H = K * G
+    if "stream" in what:
+        tok_rows, positions, live = _one_row_stream(*what["stream"])
+        pt = rng.permutation(P)[:16].reshape(1, 16).astype(np.int32)
+    else:
+        tok_rows, positions, live, pt = tiled_stream(rng, page, P)
+    if "ring" in what:  # logical page p -> the ring's page p % ring
+        pt = np.take_along_axis(
+            pt, np.arange(pt.shape[1])[None] % what["ring"], axis=1
+        )
+    T = len(tok_rows)
+    kv_lens = jnp.asarray(np.where(live, positions + 1, 0).astype(np.int32))
+    q = jnp.asarray(rng.normal(size=(T, 1, H, D)).astype(np.float32))
+    kw, atol, rtol = {}, 2e-5, 1e-6
+    if "int8" in what:
+        cache = jnp.asarray(
+            rng.integers(-127, 128, size=(L, P, K, page, 2 * D)).astype(np.int8)
+        )
+        kw["scales"] = jnp.asarray(
+            rng.uniform(0.01, 0.1, size=(L, P, K, page, 2))
+            .astype(np.float16).astype(np.float32)
+        )
+        atol, rtol = 2e-2, 1e-2
+    else:
+        cache = jnp.asarray(
+            rng.normal(size=(L, P, K, page, 2 * D)).astype(np.float32)
+        )
+    if "sinks" in what:
+        kw["sinks"] = jnp.asarray(rng.normal(size=(H,)).astype(np.float32))
+    rows, table = jnp.asarray(tok_rows), jnp.asarray(pt)
+
+    tiled = jax.jit(lambda w: flat_paged_attention_full(
+        q, cache, jnp.int32(1), rows, table, kv_lens, interpret=True,
+        pages_per_block=ppb, window=w, **kw,
+    ))  # the window a traced scalar, as a scan over layers hands it in
+    out = np.asarray(tiled(jnp.int32(window)))
+    oracle = np.asarray(paged_attention_xla(
+        q, cache[1], jnp.asarray(pt[tok_rows]), kv_lens,
+        jnp.asarray(positions[:, None]), window=jnp.int32(window),
+        **{k: (v[1] if k == "scales" else v) for k, v in kw.items()},
+    ))
+    np.testing.assert_allclose(out[live], oracle[live], atol=atol, rtol=rtol)
+    assert np.isfinite(out).all() and not out[~live].any()  # pads read nothing
+    by_token = np.asarray(window_attention_on_the_grid_of_tokens(
+        q, cache, jnp.int32(1), rows, table, kv_lens, window,
+        pages_per_block=ppb, **kw,
+    ))
+    np.testing.assert_array_equal(out, by_token)
+    if what.get("full"):
+        np.testing.assert_array_equal(out, np.asarray(flat_paged_attention_full(
+            q, cache, jnp.int32(1), rows, table, kv_lens, interpret=True,
+            pages_per_block=ppb, **kw,
+        )))
