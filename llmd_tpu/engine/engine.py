@@ -9,6 +9,7 @@ exposes the queue/KV metrics the EPP scrapes
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import functools
 import itertools
@@ -51,6 +52,20 @@ from llmd_tpu.engine.scheduler import (
 )
 from llmd_tpu.obs import profiling
 from llmd_tpu.parallel.mesh import MeshContext, build_mesh
+
+try:  # the engine thread's involuntary context switches (Linux)
+    import resource
+
+    _RUSAGE_THREAD = resource.RUSAGE_THREAD
+except (ImportError, AttributeError):
+    _RUSAGE_THREAD = None
+
+# EngineStats' partition of the holds: upper edges in ms, and the counters.
+_HOLD_EDGES_MS = (1.0, 4.0, 16.0, 64.0, 256.0)
+_HOLD_BUCKETS = tuple(
+    f"step_host_hold_{name}ms_total"
+    for name in ("le1", "1to4", "4to16", "16to64", "64to256", "over256")
+)
 
 
 @dataclass
@@ -441,6 +456,57 @@ class EngineStats:
     step_ready_lag_bound_ms_total: float = 0.0
     step_readback_ms_total: float = 0.0
     step_gap_admit_ms_total: float = 0.0
+    # The host's worst moments, which a mean over engine_steps_total hides
+    # (docs/architecture/observability.md's metric reference). HOLD: for a
+    # step behind which another was dispatched in the same turn, from the
+    # last is_ready() that was false (WaitTiming.ready_at less its lag
+    # bound) to the NEXT dispatch's return: an upper bound, from host
+    # clocks alone, on how long the device stood finished with nothing
+    # queued (the ready-lag bound + redispatch on a step dispatched early;
+    # + readback and commit on one that waits for the commit). A step with
+    # nothing to dispatch behind it holds nothing (that idle time is the
+    # load's), nor does the synchronous step. A bound only where a look
+    # found the device RUNNING: a step the host comes late to (a pause that
+    # fell under the device, in readback, commit, finish or staging, and
+    # outlasted the program) is held from the wait's entry, and how long
+    # the device had stood before that no host clock says; the pace's sum
+    # and gc_full_pause_ms_total hold such a pause. The six bucket counts
+    # partition the holds (ms, upper edge included); /metrics renders them
+    # as the one histogram llmd:step_host_hold_ms.
+    step_host_hold_ms_total: float = 0.0
+    step_host_holds_total: int = 0
+    step_host_hold_le1ms_total: int = 0
+    step_host_hold_1to4ms_total: int = 0
+    step_host_hold_4to16ms_total: int = 0
+    step_host_hold_16to64ms_total: int = 0
+    step_host_hold_64to256ms_total: int = 0
+    step_host_hold_over256ms_total: int = 0
+    # PACE: WaitTiming.ready_at of step N less that of N-1, under N's kind
+    # (prefill: prefill or mixed), counted only where N was dispatched while
+    # N-1 was in flight or in N-1's own turn, never across a pipeline that
+    # ran empty: what a step costs every stream, ready to ready. While the
+    # pipeline stays full the two sums add up to the wall clock.
+    step_ready_interval_ms_decode_total: float = 0.0
+    step_ready_intervals_decode_total: int = 0
+    step_ready_interval_ms_prefill_total: float = 0.0
+    step_ready_intervals_prefill_total: int = 0
+    # The cyclic collector (obs/profiling.py::gc_watch; span llmd.runner.gc):
+    # the process's collections and their pauses since this engine began to
+    # watch, folded in once a step; a pause on ANY thread counts, it holds
+    # the interpreter lock. full: generation 2, a pass over the whole heap.
+    gc_pause_ms_total: float = 0.0
+    gc_collections_total: int = 0
+    gc_full_pause_ms_total: float = 0.0
+    gc_full_collections_total: int = 0
+    # The engine thread against the machine, once a step on the thread
+    # that steps: the growth of time.thread_time() and of the thread's
+    # involuntary context switches (getrusage(RUSAGE_THREAD).ru_nivcsw; 0
+    # on a platform without it) since the step before. A host phase that
+    # reads longer with the same CPU ms was preempted or waited; with more
+    # CPU ms it ran slower (a starved core, a colder cache). A changed
+    # thread id restarts the baseline.
+    engine_thread_cpu_ms_total: float = 0.0
+    engine_thread_preemptions_total: int = 0
     # The serving loop (serve/async_engine.py; 0 for an engine stepped
     # directly). engine_idle: time the loop waited with nothing to run
     # (span llmd.serve.idle; not paused): 1 - idle / wall is the
@@ -460,6 +526,10 @@ class EngineStats:
     steps_prefill_total: int = 0
     steps_decode_total: int = 0
     steps_mixed_total: int = 0
+    # (since the step dispatches early, PR 49, step() holds the wait of the
+    # step in FRONT: these two time a call's entry to its exit under the
+    # kind of the step it landed, not what that step cost. The pace of a
+    # step, ready to ready, is step_ready_interval_ms_*_total above.)
     step_ms_decode_total: float = 0.0
     step_ms_prefill_total: float = 0.0  # prefill or mixed
     # Queue wait: at a request's FIRST scheduling, now - arrival_time
@@ -977,6 +1047,13 @@ class LLMEngine:
         # arrival during step N rides step N+1 (_top_up).
         self.intake_hook = None
         self._inflight: _InflightStep | None = None
+        # The host's tail (EngineStats): WaitTiming.ready_at of the step
+        # before while the pipeline has not run empty since; the collector's
+        # totals and the stepping thread's (id, CPU s, involuntary switches)
+        # as the last step found them.
+        self._paced_from: float | None = None
+        self._gc_seen: tuple | None = profiling.gc_watch()
+        self._thread_seen: tuple | None = None
         # time.monotonic() at the end of the newest step readback: the
         # serving loop counts an output's deliver lag from it.
         self.last_readback_at = time.monotonic()
@@ -1542,6 +1619,9 @@ class LLMEngine:
         """Release network-facing resources (KV connector, store client)
         and, in a multi-host world, release the follower processes."""
         self.runner.stop_followers()
+        if self._gc_seen is not None:  # (idempotent)
+            self._gc_seen = None
+            profiling.gc_unwatch()
         if self.kv_connector is not None:
             self.kv_connector.close()
         if self._kvstore_client is not None:
@@ -1818,6 +1898,8 @@ class LLMEngine:
         t_in = time.monotonic()
         counted = self.stats.engine_steps_total
         self._step_carried = ("empty", 0, 0)
+        if self._inflight is None:
+            self._paced_from = None  # the pipeline ran empty: no pace across it
         with profiling.span("llmd.step") as step_span:
             with profiling.span("llmd.step.admit"):
                 if self._kv_parked:
@@ -1930,7 +2012,7 @@ class LLMEngine:
             batch, (t_dispatched - t0) + finish_s,
             schedule_s=now - t0, launch_s=t_dispatched - now,
             wait_s=t_read - t_dispatched, finish_s=finish_s,
-            readback_s=waited.readback_s,
+            readback_s=waited.readback_s, ready_at=waited.ready_at,
         )
         return outputs
 
@@ -2085,6 +2167,9 @@ class LLMEngine:
             commit_s=t_reconciled - t_read,
             redispatch_s=redispatch_s,
             gap_admit_s=slot.in_gap_s,
+            ready_at=waited.ready_at,
+            # (an empty slot dispatched nothing: that idle time is the load's)
+            dispatched_at=None if slot.batch.is_empty else t_redispatched,
         )
         return outputs
 
@@ -2609,6 +2694,8 @@ class LLMEngine:
         commit_s: float = 0.0,
         redispatch_s: float = 0.0,
         gap_admit_s: float = 0.0,
+        ready_at: float = 0.0,
+        dispatched_at: float | None = None,
     ) -> None:
         """Count one step that ran ``batch``: the host gap, the phase
         sums and the step kind (``step()`` adds the whole-step sums).
@@ -2619,7 +2706,10 @@ class LLMEngine:
         ready to parsed results, inside ``wait_s``), makes it the host's
         whole turn between two programs; ``ready_lag_bound_s`` is the
         most the host can have noticed the device's end late. The
-        synchronous step's gap is its phases."""
+        synchronous step's gap is its phases. ``ready_at`` is the wait's
+        (``WaitTiming``); ``dispatched_at`` the return of the dispatch that
+        followed in the same turn, where one did: the end of the step's
+        hold on the device."""
         st = self.stats
         gap_ms = host_gap_s * 1e3
         st.engine_steps_total += 1
@@ -2637,6 +2727,22 @@ class LLMEngine:
         self._step_carried = self._carried(batch)
         by_kind = f"steps_{self._step_carried[0]}_total"
         setattr(st, by_kind, getattr(st, by_kind) + 1)
+        if dispatched_at is not None:
+            hold_ms = (dispatched_at - ready_at + ready_lag_bound_s) * 1e3
+            st.step_host_hold_ms_total += hold_ms
+            st.step_host_holds_total += 1
+            bucket = _HOLD_BUCKETS[bisect.bisect_left(_HOLD_EDGES_MS, hold_ms)]
+            setattr(st, bucket, getattr(st, bucket) + 1)
+        if self._paced_from is not None:
+            pace_ms = (ready_at - self._paced_from) * 1e3
+            if self._step_carried[0] == "decode":
+                st.step_ready_interval_ms_decode_total += pace_ms
+                st.step_ready_intervals_decode_total += 1
+            else:
+                st.step_ready_interval_ms_prefill_total += pace_ms
+                st.step_ready_intervals_prefill_total += 1
+        self._paced_from = ready_at
+        self._count_host_tail()
         st.kv_bytes_in_use_total += self._kv_bytes_in_use()
         if self._state_pool:
             w = self.swa_allocator
@@ -2648,6 +2754,30 @@ class LLMEngine:
         )
         self._moe_tick()
         self._refresh_gauges()
+
+    def _count_host_tail(self) -> None:
+        """Once a step, on the thread that steps: what the collector paused
+        the process for since the step before, and what the machine gave
+        this thread (EngineStats' gc_* and engine_thread_* counters)."""
+        st = self.stats
+        if self._gc_seen is not None:
+            was, now = self._gc_seen, profiling.gc_totals()
+            self._gc_seen = now
+            st.gc_pause_ms_total += now[0] - was[0]
+            st.gc_collections_total += now[1] - was[1]
+            st.gc_full_pause_ms_total += now[2] - was[2]
+            st.gc_full_collections_total += now[3] - was[3]
+        switches = (
+            0 if _RUSAGE_THREAD is None
+            else resource.getrusage(_RUSAGE_THREAD).ru_nivcsw
+        )
+        was = self._thread_seen
+        self._thread_seen = now = (
+            threading.get_ident(), time.thread_time(), switches
+        )
+        if was is not None and was[0] == now[0]:
+            st.engine_thread_cpu_ms_total += (now[1] - was[1]) * 1e3
+            st.engine_thread_preemptions_total += now[2] - was[2]
 
     def _kv_bytes_in_use(self) -> int:
         """Bytes of KV pages that live references hold, over both pools."""

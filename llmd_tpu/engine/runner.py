@@ -346,12 +346,12 @@ class PendingUnified:
 
 @dataclass
 class WaitTiming:
-    """When the last ``wait_step`` learnt that its outputs were ready, when
-    it began to read them back (behind ``at_ready``, where one was given)
-    and when it had them parsed (``time.monotonic()``), and the most that
-    the notice can have lagged the device: the time from the last
-    ``is_ready()`` that was false (the wait's entry where the first was
-    true) to the first that was true; 0 for a blocking wait."""
+    """When the last ``wait_step`` learnt that its outputs were ready, began
+    to read them back (behind ``at_ready``) and had them parsed (monotonic
+    s), and the most the notice can have lagged the device: from the last
+    ``is_ready()`` that was false (the wait's entry where the first was true)
+    to the first that was true; 0 for a blocking wait. ``ready_at`` less that
+    bound is the last false look: a step's HOLD on the device starts there."""
 
     ready_lag_bound_s: float = 0.0
     ready_at: float = 0.0

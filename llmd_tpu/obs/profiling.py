@@ -9,6 +9,10 @@ spans land on the ``/host:CPU`` plane of the same ``.xplane.pb`` that holds
 the device's operations. Nothing is exported or kept by us: while no
 session is open a span costs well under a microsecond and records nothing.
 
+The cyclic collector is timed here too (``gc_watch``): a pass over the
+engine's heap holds the interpreter lock on whichever thread it runs, so
+it is the one host phase that no span of the step's own can bracket.
+
 Used by ``POST /start_profile`` / ``/stop_profile`` (serve/api.py) and by
 the benchmark's ``--trace 2`` (perfbench/topologies/engine.py); the span
 names are listed in docs/architecture/observability.md.
@@ -20,7 +24,9 @@ names are listed in docs/architecture/observability.md.
 from __future__ import annotations
 
 import functools
+import gc
 import threading
+import time
 
 
 class ProfilerBusy(RuntimeError):
@@ -95,3 +101,62 @@ def spanned(name: str):
         return call
 
     return deco
+
+
+# The collector's pauses: ``gc.callbacks`` is called at the start and the
+# stop of every collection, on the thread that runs it and under the
+# interpreter lock (a collection never starts inside another), so the totals
+# need no lock of their own: [pause ms, collections, pause ms and collections
+# of generation 2, a FULL pass over every tracked object of the process].
+_gc_lock = threading.Lock()
+_gc_watchers = 0  # llmd: guarded_by(_gc_lock)
+_gc_totals = [0.0, 0, 0.0, 0]
+_gc_open: tuple | None = None  # (span, time.monotonic()) of the collection running
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_open
+    if phase == "start":
+        sp = span("llmd.runner.gc", generation=info["generation"])
+        sp.__enter__()
+        _gc_open = (sp, time.monotonic())
+    elif _gc_open is not None:  # (None: watched from inside a collection)
+        sp, began = _gc_open
+        _gc_open = None
+        ms = (time.monotonic() - began) * 1e3
+        sp.__exit__(None, None, None)
+        _gc_totals[0] += ms
+        _gc_totals[1] += 1
+        if info["generation"] == 2:
+            _gc_totals[2] += ms
+            _gc_totals[3] += 1
+
+
+def gc_watch() -> tuple:
+    """Time every collection of this process from now on: the span
+    ``llmd.runner.gc`` (attribute ``generation``) on the thread that
+    collects, a child of whatever it interrupts, and the totals that
+    ``gc_totals`` returns. One callback a process however many engines
+    watch; each takes its watch back with ``gc_unwatch``. Returns the totals
+    as they stand, the watcher's baseline."""
+    global _gc_watchers
+    with _gc_lock:
+        _gc_watchers += 1
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+    return gc_totals()
+
+
+def gc_unwatch() -> None:
+    """One watcher less; the last takes the callback out of ``gc.callbacks``."""
+    global _gc_watchers
+    with _gc_lock:
+        _gc_watchers = max(0, _gc_watchers - 1)
+        if not _gc_watchers and _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
+
+
+def gc_totals() -> tuple:
+    """(pause ms, collections, full pause ms, full collections) since the
+    callback was first installed; a pause on ANY thread counts."""
+    return tuple(_gc_totals)

@@ -145,6 +145,33 @@ def render_metrics(
         ),
         "step_readback_ms_total": round(stats.step_readback_ms_total, 3),
         "step_gap_admit_ms_total": round(stats.step_gap_admit_ms_total, 3),
+        # The host's tail: the pace of a step, ready to ready, by kind;
+        # the cyclic collector's pauses (any thread: it holds the
+        # interpreter lock), those of a full pass apart; the engine
+        # thread's CPU time and involuntary context switches. (A step's
+        # hold on the device is the histogram llmd:step_host_hold_ms.)
+        "step_ready_interval_ms_decode_total": round(
+            stats.step_ready_interval_ms_decode_total, 3
+        ),
+        "step_ready_intervals_decode_total": (
+            stats.step_ready_intervals_decode_total
+        ),
+        "step_ready_interval_ms_prefill_total": round(
+            stats.step_ready_interval_ms_prefill_total, 3
+        ),
+        "step_ready_intervals_prefill_total": (
+            stats.step_ready_intervals_prefill_total
+        ),
+        "gc_pause_ms_total": round(stats.gc_pause_ms_total, 3),
+        "gc_collections_total": stats.gc_collections_total,
+        "gc_full_pause_ms_total": round(stats.gc_full_pause_ms_total, 3),
+        "gc_full_collections_total": stats.gc_full_collections_total,
+        "engine_thread_cpu_ms_total": round(
+            stats.engine_thread_cpu_ms_total, 3
+        ),
+        "engine_thread_preemptions_total": (
+            stats.engine_thread_preemptions_total
+        ),
         # The serving loop: time waited with nothing to run (1 - idle /
         # wall is the replica's duty cycle), submit() to intake, and a
         # step's readback's end to its outputs handed to their streams.
@@ -357,27 +384,13 @@ def render_metrics(
         ):
             lines.append(f"# TYPE llmd:{name} counter")
             lines.append(f"llmd:{name}{label} {v}")
-        # Per-step accepted-draft-length histogram (Prometheus histogram
-        # text form; one bucket per accepted length 0..k).
+        # Per-step accepted-draft-length histogram (one bucket per
+        # accepted length 0..k).
         hist = stats.spec_accepted_len_hist
-        lines.append("# TYPE llmd:spec_accepted_len histogram")
-        cum = 0
-        for ln, cnt in enumerate(hist):
-            cum += cnt
-            lines.append(
-                f'llmd:spec_accepted_len_bucket{{le="{ln}",'
-                f'model_name="{model_name}"}} {cum}'
-            )
-        lines.append(
-            f'llmd:spec_accepted_len_bucket{{le="+Inf",'
-            f'model_name="{model_name}"}} {cum}'
-        )
-        total = sum(j * c for j, c in enumerate(hist))
-        lines.append(
-            f'llmd:spec_accepted_len_sum{{model_name="{model_name}"}} {total}'
-        )
-        lines.append(
-            f'llmd:spec_accepted_len_count{{model_name="{model_name}"}} {cum}'
+        lines += _histogram(
+            "llmd:spec_accepted_len", model_name,
+            [*enumerate(hist), ("+Inf", 0)],
+            sum(j * c for j, c in enumerate(hist)), sum(hist),
         )
     if stats.spec_row_depth_hist:
         # Per-row verify depth histogram (--ragged-qlens adaptive depth:
@@ -385,25 +398,27 @@ def render_metrics(
         # exactly d tokens; two buckets populated on one step means two
         # rows ran DIFFERENT verify depths in the same program).
         hist = stats.spec_row_depth_hist
-        lines.append("# TYPE llmd:spec_row_depth histogram")
-        cum = 0
-        for d, cnt in enumerate(hist):
-            cum += cnt
-            lines.append(
-                f'llmd:spec_row_depth_bucket{{le="{d}",'
-                f'model_name="{model_name}"}} {cum}'
-            )
-        lines.append(
-            f'llmd:spec_row_depth_bucket{{le="+Inf",'
-            f'model_name="{model_name}"}} {cum}'
+        lines += _histogram(
+            "llmd:spec_row_depth", model_name,
+            [*enumerate(hist), ("+Inf", 0)],
+            sum(d * c for d, c in enumerate(hist)), sum(hist),
         )
-        total = sum(d * c for d, c in enumerate(hist))
-        lines.append(
-            f'llmd:spec_row_depth_sum{{model_name="{model_name}"}} {total}'
-        )
-        lines.append(
-            f'llmd:spec_row_depth_count{{model_name="{model_name}"}} {cum}'
-        )
+    # A step's hold on the device (EngineStats.step_host_hold_*): how long,
+    # at most, the device stood finished with nothing queued before the
+    # next step was dispatched, by size in ms. A hold over 16 ms is a host
+    # stall: every stream's next token waits for it.
+    lines += _histogram(
+        "llmd:step_host_hold_ms", model_name,
+        [
+            (1, stats.step_host_hold_le1ms_total),
+            (4, stats.step_host_hold_1to4ms_total),
+            (16, stats.step_host_hold_4to16ms_total),
+            (64, stats.step_host_hold_16to64ms_total),
+            (256, stats.step_host_hold_64to256ms_total),
+            ("+Inf", stats.step_host_hold_over256ms_total),
+        ],
+        round(stats.step_host_hold_ms_total, 3), stats.step_host_holds_total,
+    )
     for family in ("vllm", "llmd"):
         for name, v in gauges.items():
             lines.append(f"# TYPE {family}:{name} gauge")
@@ -417,6 +432,22 @@ def render_metrics(
             f'num_gpu_blocks="{stats.num_pages}",model_name="{model_name}"}} 1'
         )
     return "\n".join(lines) + "\n"
+
+
+def _histogram(
+    name: str, model_name: str, buckets: list, total, count: int
+) -> list[str]:
+    """The Prometheus text form of one histogram. ``buckets``: [(upper
+    edge, observations in that bucket alone)], the last edge ``+Inf``; they
+    are made cumulative here. ``count``: every observation."""
+    out = [f"# TYPE {name} histogram"]
+    cum = 0
+    for le, n in buckets:
+        cum += n
+        out.append(f'{name}_bucket{{le="{le}",model_name="{model_name}"}} {cum}')
+    out.append(f'{name}_sum{{model_name="{model_name}"}} {total}')
+    out.append(f'{name}_count{{model_name="{model_name}"}} {count}')
+    return out
 
 
 def parse_prometheus(text: str) -> dict[str, float]:

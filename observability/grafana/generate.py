@@ -56,6 +56,14 @@ def panel(title, exprs, *, kind="timeseries", w=8, h=7, unit=None,
     return p
 
 
+def heatmap(title, expr, *, desc=None, w=8):
+    """A Prometheus histogram's buckets over time (one target, by ``le``)."""
+    p = panel(title, [expr], kind="heatmap", w=w, desc=desc,
+              legends=["{{le}}"])
+    p["targets"][0]["format"] = "heatmap"
+    return p
+
+
 def row(title):
     return {"type": "row", "title": title, "id": _id(), "_w": 24, "_h": 1}
 
@@ -628,6 +636,71 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                    "wide-ep.md). 1 where the model is served whole; "
                    "held / routed experts under balanced routing; off "
                    "that = this rank's experts run hot or cold."),
+        row("The host's tail (what a mean over steps hides)"),
+        heatmap("A step's hold on the device, by size",
+                f"sum by (le) (rate(llmd:step_host_hold_ms_bucket{M}[5m]))",
+                desc="llmd:step_host_hold_ms: for every step behind which "
+                     "another was dispatched, from the last look that found "
+                     "it running to the NEXT dispatch's return: an upper "
+                     "bound on how long the chip stood finished with "
+                     "nothing queued. Nearly every hold is under 1 ms; a "
+                     "cell lighting up above 16 ms is a host stall, several "
+                     "step times in which every stream's next token waits."),
+        panel("Hold p99.9, and stalls a second",
+              ["histogram_quantile(0.999, sum by (le) "
+               f"(rate(llmd:step_host_hold_ms_bucket{M}[5m])))",
+               f"rate(llmd:step_host_hold_ms_sum{M}[5m]) / "
+               f"rate(llmd:step_host_hold_ms_count{M}[5m])",
+               f"sum(rate(llmd:step_host_hold_ms_count{M}[5m])) - "
+               "sum(rate(llmd:step_host_hold_ms_bucket"
+               f'{{le="16",{M[1:]}[5m]))'],
+              legends=["p99.9 (ms, to the bucket's edge)", "mean (ms)",
+                       "holds over 16 ms a second"],
+              desc="The tail of the histogram beside its mean: a stall "
+                   "every ten seconds moves no mean and no 95th percentile "
+                   "of the gap between tokens, and is all a user of that "
+                   "second sees."),
+        panel("Collector pauses, ms a second by generation",
+              [f"rate(llmd:gc_full_pause_ms_total{M}[5m])",
+               f"rate(llmd:gc_pause_ms_total{M}[5m]) - "
+               f"rate(llmd:gc_full_pause_ms_total{M}[5m])",
+               f"rate(llmd:gc_full_collections_total{M}[5m])",
+               f"rate(llmd:gc_collections_total{M}[5m])"],
+              legends=["generation 2 (a full pass), ms/s",
+                       "generations 0 and 1, ms/s",
+                       "full collections a second", "collections a second"],
+              desc="Python's cyclic collector, timed where it runs (any "
+                   "thread: a collection holds the interpreter lock). A "
+                   "full pass over an engine's heap is tens of ms; beside "
+                   "the hold histogram it says whether a stall was the "
+                   "collector's."),
+        panel("The engine thread against the machine",
+              [f"rate(llmd:engine_thread_preemptions_total{M}[5m])",
+               f"rate(llmd:engine_thread_cpu_ms_total{M}[5m]) / 1000"],
+              legends=["preemptions a second (involuntary context "
+                       "switches)", "CPU share of one core"],
+              desc="How often the kernel took the processor from the "
+                   "thread that steps the engine while it wanted to run, "
+                   "and how much of a core that thread used. Host phases "
+                   "that read longer at the same CPU time: the thread was "
+                   "preempted or waited (a crowded host); at more CPU "
+                   "time: the same work ran slower. A sandboxed kernel "
+                   "that reports no context switches to getrusage reads "
+                   "0 preemptions whatever happens: trust a 0 only on a "
+                   "host where the series has been seen to move."),
+        panel("The pace of a step, ready to ready",
+              [f"rate(llmd:step_ready_interval_ms_decode_total{M}[5m]) / "
+               f"rate(llmd:step_ready_intervals_decode_total{M}[5m])",
+               f"rate(llmd:step_ready_interval_ms_prefill_total{M}[5m]) / "
+               f"rate(llmd:step_ready_intervals_prefill_total{M}[5m])"],
+              legends=["decode step", "step with a prefill chunk (prefill "
+                       "or mixed)"], unit="ms",
+              desc="From one step's outputs seen ready to the next's, "
+                   "under the kind of the later step, while the pipeline "
+                   "stays full: what a step of each kind puts between two "
+                   "tokens of every running stream, the host's turn "
+                   "included. (step_ms_decode_total times a call of "
+                   "step(), which holds the wait of the step in front.)"),
         row("Speculative decoding"),
         panel("Draft acceptance", [f"llmd:spec_acceptance_rate{M}"],
               unit="percentunit", max1=True,

@@ -34,6 +34,11 @@ NEW_COUNTERS = (
     "step_ready_lag_bound_ms_total", "step_readback_ms_total", "step_gap_admit_ms_total",
     "engine_idle_ms_total", "intake_wait_ms_total", "intake_requests_total",
     "deliver_lag_ms_total", "outputs_delivered_total",
+    # the host's tail (tests/test_host_tail.py; a step's hold is a histogram there)
+    "step_ready_interval_ms_decode_total", "step_ready_intervals_decode_total",
+    "step_ready_interval_ms_prefill_total", "step_ready_intervals_prefill_total",
+    "gc_pause_ms_total", "gc_collections_total", "gc_full_pause_ms_total", "gc_full_collections_total",
+    "engine_thread_cpu_ms_total", "engine_thread_preemptions_total",
 )
 
 
