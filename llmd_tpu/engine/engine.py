@@ -611,6 +611,14 @@ class EngineStats:
     # per token. Over live_tokens_total: the share of computed tokens
     # whose attention bytes are shared (0 for decode-only steps).
     attn_shared_tile_tokens_total: int = 0
+    # Shared-prefix runs (engine/prefix_runs.py; flat step, calls without a
+    # window): over the flat steps' plain decode tokens, the keys under
+    # their horizons, and of those the keys in a run's blocks, which the
+    # attention kernel reads once a tile for all the run's members and not
+    # once a row. Host-counted where the runs are planned; keys / keys is
+    # the share of the decode rows' context that crosses HBM shared.
+    attn_prefix_run_keys_total: int = 0
+    attn_decode_keys_total: int = 0
     # The grouped expert matmul (one-device "grouped" MoE backend; 0 for a
     # dense model and under wide-EP, which has its own census): grouped
     # MoE layer calls of the step programs (one a layer and step; gate, up
@@ -2912,6 +2920,8 @@ class LLMEngine:
             self.runner.attn_shared_tile_tokens_total
         )
         r = self.runner
+        self.stats.attn_prefix_run_keys_total = r.attn_prefix_run_keys_total
+        self.stats.attn_decode_keys_total = r.attn_decode_keys_total
         self.stats.moe_grouped_calls_total = r.moe_grouped_calls_total
         self.stats.moe_groups_with_rows_total = r.moe_groups_with_rows_total
         self.stats.moe_picks_total = r.moe_picks_total

@@ -46,6 +46,7 @@ def step_fields(
     ring: bool,
     lora: bool,
     state: bool = False,
+    runs: bool = False,
 ) -> list[tuple[str, tuple[int, ...], type]]:
     """(name, shape, dtype) of one step program's host inputs.
 
@@ -55,7 +56,9 @@ def step_fields(
     stream bucket T of a flat step. ``sample_cols`` is the unified and flat
     steps' sample width S; ``ring`` adds the sliding layers' second page
     table (and the flat step's second write plan), ``lora`` the adapter
-    slots, ``state`` (flat only) each row's slot of the state pool.
+    slots, ``state`` (flat only) each row's slot of the state pool, ``runs``
+    (flat only) the attention's shared-prefix runs, a token each
+    (``engine/prefix_runs.py``).
 
     ``tok_slot`` is each row's entry of the runner's ``last_tokens`` (the
     token the device sampled last for the row's sequence; an index past the
@@ -115,6 +118,11 @@ def step_fields(
             ]
             if ring:
                 spec.append(("wphys_swa", (rn,), np.int32))
+            if runs:
+                spec += [
+                    ("run_lead", (t,), np.int32),
+                    ("run_blocks", (t,), np.int32),
+                ]
     elif kind == "decode":
         spec = [
             ("first", (B,), np.int32),

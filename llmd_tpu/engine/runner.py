@@ -47,7 +47,7 @@ import numpy as np
 
 from llmd_tpu import ops
 from llmd_tpu.config import EngineConfig, state_slot_spec, swa_ring_spec
-from llmd_tpu.engine import payload
+from llmd_tpu.engine import payload, prefix_runs
 from llmd_tpu.engine.sampler import (
     SamplingInputs,
     sample_tokens,
@@ -58,6 +58,7 @@ from llmd_tpu.models import llama
 from llmd_tpu.models.common import StepInput
 from llmd_tpu.obs import profiling
 from llmd_tpu.ops import ssm as ssm_ops
+from llmd_tpu.ops.ragged_paged_attention import PAGES_PER_BLOCK
 from llmd_tpu.ops.ragged_paged_attention import TILE as ATTENTION_TILE
 from llmd_tpu.parallel import distributed as dist
 from llmd_tpu.parallel.mesh import MeshContext, kv_cache_spec, shard_params
@@ -383,7 +384,8 @@ class ModelRunner:
     _FILL_COUNTERS = (
         "step_h2d_transfers_total", "step_h2d_bytes_total",
         "live_tokens_total", "padded_tokens_total",
-        "attn_shared_tile_tokens_total", "sparse_bound_tokens_total",
+        "attn_shared_tile_tokens_total", "attn_prefix_run_keys_total",
+        "attn_decode_keys_total", "sparse_bound_tokens_total",
         "sparse_unbound_tokens_total", "indexer_keys_scored_total",
         "indexer_keys_written_total", "sparse_rows_selected_total",
         "latent_rows_written_total", "ssm_update_rows_total",
@@ -605,6 +607,12 @@ class ModelRunner:
         # attention reads once for all their tokens
         # (ops/ragged_paged_attention.py; EngineStats field of the name).
         self.attn_shared_tile_tokens_total = 0
+        # Of the keys the flat steps' plain decode tokens read (their
+        # horizons, summed), those in a shared-prefix run's blocks: read
+        # once a tile for all the run's members (engine/prefix_runs.py;
+        # EngineStats fields of the names). Both 0 where no call takes runs.
+        self.attn_prefix_run_keys_total = 0
+        self.attn_decode_keys_total = 0
         # Learned sparse attention (EngineStats fields of the same names),
         # from the positions each flat step already holds: computed query
         # tokens with more / no more than indexer_topk cached tokens, the
@@ -705,6 +713,11 @@ class ModelRunner:
         self._flat = None
         self.flat_rows = 0
         self.flat_t_buckets: tuple[int, ...] = ()
+        # Does the flat step plan shared-prefix runs for its attention
+        # (engine/prefix_runs.py)? Wherever the flat stream's attention is
+        # the paged kernel over ``page_table``: every flat model but the
+        # latent ones (ops/sparse_mla.py gathers rows).
+        self._plans_runs = False
         if sched.unified_step and sched.ragged_qlens and (
             not self.cfg.is_mla or self.cfg.sparse_attention
         ):
@@ -712,6 +725,7 @@ class ModelRunner:
             limit = -(-limit // 16) * 16
             self.flat_t_buckets = tuple(range(16, limit + 1, 16))
             self.flat_rows = self.unified_row_buckets[-1]
+            self._plans_runs = not self.cfg.is_mla
             self._flat = self._build_flat()
 
     def _check_page_table_fits_smem(self) -> None:
@@ -1451,6 +1465,11 @@ class ModelRunner:
                 swa_page_table=swa_table,
                 token_rows=row_of,
                 flat_runs=((wsrc, woff, wcnt), wphys, wphys_swa),
+                # The attention's shared-prefix runs, [T] each.
+                attn_runs=(
+                    (f["run_lead"], f["run_blocks"]) if "run_lead" in f
+                    else None
+                ),
                 # A segment's "starts at position 0" comes from pos0: the
                 # payload carries the slots alone.
                 state_rows=None if state_slots is None else ssm_ops.state_rows(
@@ -2050,6 +2069,7 @@ class ModelRunner:
             sample_cols=self.unified_s,
             ring=self.swa is not None and not self.state_pool,
             lora=bool(self.cfg.num_lora_adapters), state=self.state_pool,
+            runs=self._plans_runs,
         )
 
     def _layout(self, op: int, B: int, QK: int) -> payload.PayloadLayout:
@@ -3296,15 +3316,16 @@ class ModelRunner:
         row_off: list[int] = []
         row_plan: list[int] = []
         prefill_rows: list[int] = []
-        decode_rows: list[int] = []
         for s in prefills:
             for off, w in self._chunk_rows(s):
                 row_seqs.append(s)
                 row_off.append(off)
                 row_plan.append(w)
             prefill_rows.append(len(row_seqs) - 1)
-        for s in decodes:
-            decode_rows.append(len(row_seqs))
+        decode_rows = [0] * len(decodes)
+        for i in self._decode_row_order(decodes):
+            s = decodes[i]
+            decode_rows[i] = len(row_seqs)
             row_seqs.append(s)
             row_off.append(0)
             row_plan.append(s.num_tokens)
@@ -3415,6 +3436,11 @@ class ModelRunner:
         n_pre_rows = (
             staged.prefill_rows[-1] + 1 if staged.prefill_rows else 0
         )
+        if staged.decode_rows:
+            # A decode seq keeps the seeds of its place among the decodes,
+            # wherever ``_decode_row_order`` put its row.
+            rows = np.asarray(staged.decode_rows)
+            a["seeds"][rows] = a["seeds"][n_pre_rows:n_pre_rows + len(rows)].copy()
         sparse_topk = self.cfg.indexer_topk
         t = 0
         for r, (seq, off, _plan) in enumerate(
@@ -3480,6 +3506,8 @@ class ModelRunner:
             # array the device searchsorts stays monotonic.
             row_start[len(staged.row_seqs):] = t
             self._fill_flat_runs(staged, a)
+            if self._plans_runs:
+                self._fill_prefix_runs(staged, a)
             self.padded_tokens_total += staged.T - t
         else:
             self.padded_tokens_total += staged.B * staged.Q - t
@@ -3538,6 +3566,33 @@ class ModelRunner:
         if wphys_swa is not None:
             a["wphys_swa"] = wphys_swa
 
+    def _decode_row_order(self, decodes: list[ScheduledSeq]):
+        """The order a step's decode seqs take their rows in: where the
+        attention reads shared-prefix runs, seqs that start on the same
+        page side by side (a run's members are neighbours in the stream);
+        as they come everywhere else, and where none share. The collect
+        goes by ``decode_rows``, so outputs are per seq as ever."""
+        if not self._plans_runs or len(decodes) < prefix_runs.RUN_MIN_MEMBERS:
+            return range(len(decodes))
+        return prefix_runs.group_order([
+            s.request.block_ids[0] if s.request.block_ids else -1 - i
+            for i, s in enumerate(decodes)
+        ])
+
+    def _fill_prefix_runs(self, staged: StagedUnified, a: dict) -> None:
+        """Host half of the attention's shared-prefix runs: the plain
+        decode tokens of the stream, read against the step's page table."""
+        block_keys = PAGES_PER_BLOCK * self.page
+        n = len(staged.row_seqs)
+        rows = np.flatnonzero(a["kind"][:n] == _KIND_DECODE)
+        a["run_lead"], a["run_blocks"], keys = prefix_runs.plan_runs(
+            a["page_table"], rows, a["row_start"][rows].astype(np.int64),
+            a["kvlens"][rows], staged.T, block_keys, self.page,
+            shards=self.ctx.dp,  # the stream's tokens split over dp
+        )
+        self.attn_prefix_run_keys_total += keys
+        self.attn_decode_keys_total += int(a["kvlens"][rows].sum())
+
     def _chunk_rows(self, s: ScheduledSeq) -> list[tuple[int, int]]:
         """(offset, width) of a prefill chunk's sub-rows of at most
         ``unified_row_cap`` tokens."""
@@ -3570,7 +3625,6 @@ class ModelRunner:
         row_off: list[int] = []
         row_plan: list[int] = []
         prefill_rows: list[int] = []
-        decode_rows: list[int] = []
         for s in prefills:
             kept = keep_of.get(id(s))
             if kept is not None:
@@ -3583,9 +3637,11 @@ class ModelRunner:
                 row_off.append(off)
                 row_plan.append(w)
             prefill_rows.append(len(src) - 1)
-        for s in decodes:
+        decode_rows = [0] * len(decodes)
+        for i in self._decode_row_order(decodes):
+            s = decodes[i]
             kept = keep_of.get(id(s))
-            decode_rows.append(len(src))
+            decode_rows[i] = len(src)
             src.append(kept[0] if kept is not None else -1)
             row_seqs.append(s)
             row_off.append(0)

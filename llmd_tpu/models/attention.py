@@ -196,6 +196,7 @@ def mix(h, lp, cache, step: StepCtx, layer: LayerCtx):
                 inp.kv_lens, inp.positions, cfg.sm_scale,
                 world_size=step.world_size, mesh=step.mesh,
                 window=layer.window, sinks=sinks,
+                runs=inp.attn_runs if layer.window is None else None,
             )
     elif step.cp:
         attn = ring_prefill_attention_full(

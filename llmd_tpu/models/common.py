@@ -53,6 +53,11 @@ class StepInput:
     # [K, T + 2*page, 2D] token slab (src = page + t0 - off, so slab row
     # off+j holds token t0+j). phys_swa is None without a SWA ring.
     flat_runs: tuple | None = None
+    # The flat attention's shared-prefix runs over ``page_table``
+    # (engine/prefix_runs.py): (run_lead, run_blocks), [T] i32 each. A call
+    # without a window reads the main pool through that table and takes
+    # them; a sliding layer's call never does.
+    attn_runs: tuple | None = None
     # State-space layers (flattened layout only): the step's packing as
     # they read it, derived from the per-row metadata and the rows' slots
     # of the state pool (ops/ssm.py::StateRows). None for every other model.

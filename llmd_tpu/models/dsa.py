@@ -75,6 +75,7 @@ def mix(h, lp, cache, step: StepCtx, layer: LayerCtx):
         q, iq[:, 0], iw[:, 0], cache, layer.plane, inp.token_rows,
         layer.table, inp.kv_lens, inp.positions, cfg.indexer_topk,
         cfg.sm_scale, world_size=step.world_size, mesh=step.mesh,
+        runs=inp.attn_runs,
     )
     return attention.output(attn, out_gate, lp, cfg), cache
 

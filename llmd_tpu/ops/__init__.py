@@ -206,11 +206,12 @@ _SMEM_RESERVE = 4096
 def _page_table_smem_bytes(rows: int, max_pages: int, tokens: int) -> int:
     """SMEM an attention kernel needs to prefetch a [rows, max_pages] page
     table: the compiler lays a 2-D i32 table out in (8, 128) tiles, and the
-    kernels prefetch three per-token i32 vectors and a few words of their
-    own beside it. Calibrated by compiling for v5e: [120, 2048] with 2064
-    tokens fits, [124, 2048] does not."""
+    kernels prefetch up to four per-token i32 vectors (the flat call's
+    rows, horizons and its two of shared-prefix runs) and a few words of
+    their own beside it. Calibrated by compiling for v5e: [120, 2048] with
+    2064 tokens fits, [124, 2048] does not."""
     table = -(-rows // 8) * 8 * -(-max_pages // 128) * 128 * 4
-    return table + 3 * -(-tokens // 128) * 128 * 4 + _SMEM_RESERVE
+    return table + 4 * -(-tokens // 128) * 128 * 4 + _SMEM_RESERVE
 
 
 def page_table_smem(
@@ -497,14 +498,16 @@ def write_kv_pages_full_flat(
 def paged_attention_full_flat(
     q, kv_cache_full, layer, rows, page_table, kv_lens, positions,
     sm_scale=None, world_size=1, mesh=None, window=None, sinks=None,
+    runs=None,
 ):
     """Flattened-token (``cu_q_lens``) layer-indexed attention: q is the
     packed ``[T, 1, H, D]`` stream, ``kv_lens`` is per TOKEN (position +
     1 — causality within a row derived from the packing), and the TPU
     kernel iterates 16-token tiles of the stream against the compact
     per-row table through the scalar-prefetched token -> row map, with
-    or without a sliding window. XLA fallback gathers per-token table rows
-    and reuses the bucketed reference path."""
+    or without a sliding window; ``runs`` (a call without one) are the
+    step's shared-prefix runs, a token each. XLA fallback gathers per-token
+    table rows and reuses the bucketed reference path."""
     kv_cache_full, kv_scales = _split_cache(kv_cache_full)
     L, num_pages, K, page, D2 = kv_cache_full.shape
     T, Q, H, D = q.shape
@@ -517,7 +520,7 @@ def paged_attention_full_flat(
         return flat_paged_attention_full(
             q, kv_cache_full, layer, rows, page_table, kv_lens,
             sm_scale=sm_scale, interpret=_interpret(), window=window,
-            sinks=sinks, scales=kv_scales,
+            sinks=sinks, scales=kv_scales, runs=runs,
         )
     if plan == "shard":
         tp_k = _kv_head_axis(K, mesh.shape["tp"])
@@ -530,13 +533,18 @@ def paged_attention_full_flat(
             (P(None, None, tp_k, None, None),) if kv_scales is not None else ()
         )
         scale_arg = (kv_scales,) if kv_scales is not None else ()
+        # A token's run is a matter of its shard's tiles: the host plans
+        # them so (engine/prefix_runs.py), and they split with the tokens.
+        run_spec = (P("dp"), P("dp")) if runs is not None else ()
+        run_arg = tuple(runs) if runs is not None else ()
 
-        def local(q, cache, layer, rows, pt, kl, win, sk, *sc):
+        def local(q, cache, layer, rows, pt, kl, win, sk, *rest):
+            rn, sc = rest[:len(run_arg)], rest[len(run_arg):]
             return flat_paged_attention_full(
                 q, cache, layer, rows, pt, kl, sm_scale=sm_scale,
                 interpret=interpret, window=win if use_win else None,
                 sinks=sk if use_sinks else None,
-                scales=sc[0] if sc else None,
+                scales=sc[0] if sc else None, runs=rn or None,
             )
 
         # The compact table stays REPLICATED: any token shard may
@@ -546,12 +554,12 @@ def paged_attention_full_flat(
             in_specs=(
                 P("dp", None, "tp", None), P(None, None, tp_k, None, None),
                 P(), P("dp"), P(None, None), P("dp"), P(), P("tp"),
-                *scale_spec,
+                *run_spec, *scale_spec,
             ),
             out_specs=P("dp", None, "tp", None),
             check_vma=False,
         )(q, kv_cache_full, layer, rows, page_table, kv_lens, win, sk,
-          *scale_arg)
+          *run_arg, *scale_arg)
     pt_tok = page_table[rows]  # [T, max_pages]
     sl = jax.lax.dynamic_index_in_dim(kv_cache_full, layer, 0, keepdims=False)
     ssl = (

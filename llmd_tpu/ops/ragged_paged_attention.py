@@ -19,10 +19,16 @@ online-softmax step (``_attend_block``):
 - ``_decode_kernel``, grid (B,): one program a sequence (the bucketed
   [B, 1] decode), each streaming its own live pages;
 - ``_flat_tile_kernel``, grid (T / 16,): one program per 16-token tile of
-  the flat stream, with or without a sliding window. A tile inside ONE
-  page-table row (a prefill chunk's body) reads the row's pages once for
-  its 16 queries, from its first token's window start to its last token's
-  horizon; any other tile goes token by token, as a grid of tokens would.
+  the flat stream, with or without a sliding window: the blocks some of
+  its queries share, read once for all 16 as one operand, then each
+  token's rest. A tile inside ONE page-table row (a prefill chunk's body)
+  is all shared: the row's pages once, from its first token's window start
+  to its last token's horizon. Decode rows of DIFFERENT rows that hold the
+  same physical pages at their heads (sessions over one cached document)
+  share those: a SHARED-PREFIX RUN, planned by the host from the step's
+  page table (``engine/prefix_runs.py``) for calls without a window, read
+  once a tile off its leader's row; each member then streams only what is
+  its own. Any other token goes alone, as a grid of tokens would.
 """
 
 from __future__ import annotations
@@ -36,6 +42,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.0**30
 TILE = 16  # the flat stream's granule: ``flat_t_buckets`` pads T to it
+PAGES_PER_BLOCK = 16  # pages a compute block, where the caller names none
 
 
 def _stream_blocks(
@@ -282,6 +289,9 @@ def _flat_tile_kernel(
     kv_lens_ref,  # [T] i32 per token: position + 1
     # [win_starts_ref [T] i32 when windowed: per token its window's first
     # position, 0 where the layer's window is <= 0]
+    # [run_lead_ref, run_blocks_ref [T] i32 when runs: a token of a
+    # shared-prefix run carries the run's leading compute blocks and the
+    # place in its tile of the run's leader; any other token 0 blocks]
     *refs,
     page_size: int,
     head_dim: int,
@@ -291,35 +301,51 @@ def _flat_tile_kernel(
     quant: bool,
     select: bool,
     windowed: bool,
+    runs: bool,
     num_tokens: int,
 ):
-    """One program per TILE consecutive stream tokens. A tile whose
-    tokens all sit in one page-table row at consecutive positions (the
-    body of a prefill chunk's sub-row) streams that row's live pages
-    ONCE, from its first token's window start (0 without a window) to its
-    last token's horizon, for all of its queries as one [K, TILE*G, D]
-    operand, each query row under its own window and causal bound.
-    Any other tile (decode rows, a chunk's ragged head or tail, a
-    sub-row's seam, verify rows, pad tokens, a shard's short last tile)
-    goes token by token. Both ride the same block stream and the same
-    online-softmax step; the choice is read off ``rows``/``kv_lens``."""
+    """One program per TILE consecutive stream tokens: the blocks some of
+    its queries share, then each token's rest. Both ride the same block
+    stream and the same online-softmax step, and a query row meets its
+    keys in the order a pass of its own would bring them.
+
+    A SHARED pass streams one page-table row's blocks once for all of the
+    tile's queries as one [K, TILE*G, D] operand, each query row under its
+    own bounds. A tile whose tokens all sit in one row at consecutive
+    positions (the body of a prefill chunk's sub-row) is one such pass
+    and nothing else: from its first token's window start (0 without a
+    window) to its last token's horizon. A tile of a call with ``runs``
+    takes one pass per shared-prefix run (decode rows of DIFFERENT
+    page-table rows whose leading pages are the same physical ids, found
+    by the host): the run's leading blocks off its leader's row, its
+    members' query rows live and every other row dead (a masked block
+    leaves a dead row's running max, sum and accumulator exactly as they
+    were). Then every token streams what is left of its own row, from the
+    state the shared passes left it. A tile with neither (decode rows that
+    share nothing, a chunk's ragged head or tail, a sub-row's seam, verify
+    rows, pad tokens, a shard's short last tile) goes token by token from
+    nothing. One-row tiles are read off ``rows``/``kv_lens``."""
     # blocks: q_ref [TILE, K, G, D], sinks_ref [K, TILE*G] (the heads'
     # sinks, once per token of a tile), kv_hbm_full_ref, [ks_hbm_ref,
     # vs_hbm_ref when quant: [R, K, S_max] f32 per-ROW scale planes left
     # in HBM: a block's [K, S] slab rides beside its pages], [sel_ref
     # when select: [TILE, S_max] f32, 1.0 where the token may read the
     # key], out_ref [TILE, K, G, D]; scratch m/l [K, TILE*G, 128], acc
-    # [K, TILE*G, D], q16_ref [K, TILE*G, D].
-    win_starts_ref = None
+    # [K, TILE*G, D], q16_ref [K, TILE*G, D], [when runs, a token's state
+    # by its place in the tile: tm/tl [TILE, K, G, 128], tacc [TILE, K,
+    # G, D]].
+    win_starts_ref = run_lead_ref = run_blocks_ref = None
     if windowed:
         win_starts_ref, *refs = refs
+    if runs:
+        run_lead_ref, run_blocks_ref, *refs = refs
     q_ref, sinks_ref, kv_hbm_full_ref, *refs = refs
     ks_hbm_ref = vs_hbm_ref = sel_ref = None
     if quant:
         ks_hbm_ref, vs_hbm_ref, *refs = refs
     if select:
         sel_ref, *refs = refs
-    out_ref, m_ref, l_ref, acc_ref, q16_ref = refs
+    out_ref, m_ref, l_ref, acc_ref, q16_ref, *tok_state = refs
     kv_hbm_ref = kv_hbm_full_ref.at[layer_ref[0]]
     K, G = q_ref.shape[1], q_ref.shape[2]
     M = TILE * G
@@ -330,15 +356,24 @@ def _flat_tile_kernel(
     n_tok = TILE if whole else jnp.minimum(TILE, num_tokens - t0)
     row0, kvl0 = rows_ref[t0], kv_lens_ref[t0]
 
-    def same_row_next_position(j, ok):
-        t = t0 + j if whole else jnp.minimum(t0 + j, num_tokens - 1)
-        return jnp.logical_and(ok, jnp.logical_and(
+    def at(j):
+        return t0 + j if whole else jnp.minimum(t0 + j, num_tokens - 1)
+
+    def same_row_next_position(j, carry):
+        one_row, any_run = carry
+        t = at(j)
+        one_row = jnp.logical_and(one_row, jnp.logical_and(
             rows_ref[t] == row0, kv_lens_ref[t] == kvl0 + j
         ))
+        if runs:
+            any_run = jnp.logical_or(any_run, run_blocks_ref[t] > 0)
+        return one_row, any_run
 
-    shared = jax.lax.fori_loop(
-        1, TILE, same_row_next_position,
-        jnp.asarray(True) if whole else n_tok == TILE,
+    one_row, any_run = jax.lax.fori_loop(
+        1, TILE, same_row_next_position, (
+            jnp.asarray(True) if whole else n_tok == TILE,
+            run_blocks_ref[t0] > 0 if runs else jnp.asarray(False),
+        ),
     )
 
     def body(buf, sem, *scale_bufs):
@@ -350,12 +385,12 @@ def _flat_tile_kernel(
                 (vs_hbm_ref, vs_buf, ssem.at[1]),
             )
 
-        def attend(tr, q, key_start, key_end, kv_len, win_start, chosen):
+        def attend(state, tr, q, key_start, key_end, kv_len, win_start, chosen):
             """Row ``tr``'s keys [key_start, key_end) against the query rows
             that ``q()`` loads ([K, M, D]), each in [win_start, kv_len)."""
             def compute(slot, i):
                 _attend_block(
-                    q(), buf[slot], i, m_ref, l_ref, acc_ref,
+                    q(), buf[slot], i, *state,
                     head_dim=head_dim, sm_scale=sm_scale, key_end=key_end,
                     kv_len=kv_len, key_start=key_start, win_start=win_start,
                     ks=ks_buf[slot] if quant else None,
@@ -371,9 +406,10 @@ def _flat_tile_kernel(
             )
 
         sinks = sinks_ref[...] if has_sinks else None
+        tile_state = (m_ref, l_ref, acc_ref)
 
-        @pl.when(shared)
-        def _one_row():
+        @pl.when(jnp.logical_or(one_row, any_run))
+        def _shared_passes():
             # Query row r is head r % G of token r // G.
             q16_ref[...] = jnp.concatenate(
                 [q_ref[j] for j in range(TILE)], axis=1
@@ -387,37 +423,99 @@ def _flat_tile_kernel(
                 win = jnp.maximum(
                     win_starts_ref[t0 + (TILE - 1)] - (TILE - 1) + tok_of, 0
                 )
-            _reset(m_ref, l_ref, acc_ref, M)
-            attend(
-                row0, lambda: q16_ref[...], ws0, kvl0 + (TILE - 1),
-                kvl0 + tok_of, win,
-                lambda i: jnp.concatenate([
-                    jnp.broadcast_to(
-                        sel_ref[j:j + 1, pl.ds(i * S, S)], (G, S)
+            lead_of = None
+            if runs:
+                # Each query row's run, by its leader's place in the tile
+                # (-1: the row's token is in none).
+                lead_of = jnp.full((1, M, 1), -1, jnp.int32)
+                for j in range(TILE):
+                    t = at(j)
+                    lead = jnp.where(
+                        run_blocks_ref[t] > 0, run_lead_ref[t], -1
                     )
-                    for j in range(TILE)
-                ])[None] > 0.5,  # [1, M, S]
-            )
-            o = _normalized(m_ref, l_ref, acc_ref, M, sinks)
-            for j in range(TILE):
-                out_ref[j] = o[:, j * G:(j + 1) * G].astype(out_ref.dtype)
+                    if not whole:
+                        lead = jnp.where(j < n_tok, lead, -1)
+                    lead_of = jnp.where(tok_of == j, lead, lead_of)
+            _reset(*tile_state, M)
 
-        @pl.when(jnp.logical_not(shared))
-        def _token_by_token():
+            def shared_pass(u, _):
+                """The blocks the tile's queries share with token ``u``,
+                off its row: all of a one-row tile's, a run's if ``u``
+                leads one."""
+                key_end, leads = kvl0 + (TILE - 1), one_row
+                if runs:
+                    t = at(u)
+                    run_end = run_blocks_ref[t] * S
+                    leads = jnp.logical_or(one_row, jnp.logical_and(
+                        run_end > 0, run_lead_ref[t] == u
+                    ))
+                    key_end = jnp.where(one_row, key_end, run_end)
+
+                @pl.when(leads)
+                def _():
+                    kv_len = kvl0 + tok_of  # a one-row tile's horizons
+                    if runs:  # a run's: its end for its members, 0 for the dead
+                        kv_len = jnp.where(
+                            one_row, kv_len, jnp.where(lead_of == u, run_end, 0)
+                        )
+                    attend(
+                        tile_state, rows_ref[at(u)], lambda: q16_ref[...],
+                        ws0, key_end, kv_len, win,
+                        lambda i: jnp.concatenate([
+                            jnp.broadcast_to(
+                                sel_ref[j:j + 1, pl.ds(i * S, S)], (G, S)
+                            )
+                            for j in range(TILE)
+                        ])[None] > 0.5,  # [1, M, S]
+                    )
+
+                return 0
+
+            if runs:
+                jax.lax.fori_loop(
+                    0, jnp.where(one_row, 1, n_tok), shared_pass, 0
+                )
+            else:
+                shared_pass(0, 0)
+
+            @pl.when(one_row)
+            def _out():
+                o = _normalized(*tile_state, M, sinks)
+                for j in range(TILE):
+                    out_ref[j] = o[:, j * G:(j + 1) * G].astype(out_ref.dtype)
+
+            if runs:
+                @pl.when(jnp.logical_not(one_row))
+                def _to_tokens():
+                    for ref, by_token in zip(tile_state, tok_state):
+                        for j in range(TILE):
+                            by_token[j] = ref[:, j * G:(j + 1) * G]
+
+        @pl.when(jnp.logical_not(one_row))
+        def _each_tokens_rest():
             def token(u, _):
                 t = t0 + u
                 kv_len = kv_lens_ref[t]
                 ws = win_starts_ref[t] if windowed else 0
-                _reset(m_ref, l_ref, acc_ref, G)
+                if runs:
+                    state = tuple(ref.at[u] for ref in tok_state)
+                    key_start = run_blocks_ref[t] * S
+
+                    @pl.when(jnp.logical_not(any_run))
+                    def _from_nothing():
+                        _reset(*state, G)
+                else:
+                    state, key_start = tile_state, ws
+                    _reset(*state, G)
                 attend(
-                    rows_ref[t], lambda: q_ref[u], ws, kv_len, kv_len, ws,
+                    state, rows_ref[t], lambda: q_ref[u], key_start, kv_len,
+                    kv_len, ws,
                     lambda i: (
                         sel_ref[pl.ds(u, 1), pl.ds(i * S, S)] > 0.5
                     )[None],
                 )
                 out_ref[u] = _normalized(
-                    m_ref, l_ref, acc_ref, G,
-                    None if sinks is None else sinks[:, :G],
+                    *state, G, None if sinks is None else sinks[:, :G],
                 ).astype(out_ref.dtype)
                 return 0
 
@@ -557,7 +655,7 @@ def decode_paged_attention(
     kv_lens: jax.Array,  # [B] i32
     sm_scale: float | None = None,
     interpret: bool = False,
-    pages_per_block: int = 16,
+    pages_per_block: int = PAGES_PER_BLOCK,
     window: jax.Array | None = None,
     sinks: jax.Array | None = None,
     scales: jax.Array | None = None,  # [num_pages, K, page, 2]
@@ -585,11 +683,12 @@ def flat_paged_attention_full(
     kv_lens: jax.Array,  # [T] i32 per-token: position + 1 (causal-in-row)
     sm_scale: float | None = None,
     interpret: bool = False,
-    pages_per_block: int = 16,
+    pages_per_block: int = PAGES_PER_BLOCK,
     window: jax.Array | None = None,
     sinks: jax.Array | None = None,
     scales: jax.Array | None = None,  # [L, num_pages, K, page, 2]
     sel: jax.Array | None = None,  # [T, S] bool: keys each token may read
+    runs: tuple[jax.Array, jax.Array] | None = None,  # ([T], [T]) i32
 ) -> jax.Array:
     """Flattened-token (``cu_q_lens``) attention over the packed stream,
     against the compact per-row table through the scalar-prefetched
@@ -601,14 +700,25 @@ def flat_paged_attention_full(
     The grid iterates the stream in tiles of ``TILE`` tokens (the granule
     T is padded to). A tile that lies inside one row — the body of a
     prefill chunk — streams the row's live pages ONCE for its TILE
-    queries, each under its own horizon; every other tile (decode and
-    verify rows, a chunk's ragged ends, pad tokens) goes token by token,
-    one pass over the token's live pages each, so a pure decode row
-    still costs one pass. The kernel decides per tile from ``rows`` and
-    ``kv_lens``. ``window`` (a sliding layer's; a traced per-layer scalar
-    may be <= 0: full attention) bounds each token below too, at ``kv_len
-    - window``: a shared tile then reads from its first token's start. A
-    call without one carries no such operand: its program knows no window.
+    queries, each under its own horizon; in every other tile (decode and
+    verify rows, a chunk's ragged ends, pad tokens) a token streams its
+    own live pages, but for what ``runs`` let it share. The kernel finds
+    a one-row tile from ``rows`` and ``kv_lens``. ``window`` (a sliding
+    layer's; a traced per-layer scalar may be <= 0: full attention) bounds
+    each token below too, at ``kv_len - window``: a shared tile then reads
+    from its first token's start. A call without one carries no such
+    operand: its program knows no window.
+
+    ``runs`` = (run_lead, run_blocks), the host's plan of SHARED-PREFIX
+    RUNS over ``page_table`` (``engine/prefix_runs.py``): tokens of one
+    tile, of different rows, whose leading ``run_blocks[t]`` compute blocks
+    (``pages_per_block`` pages each) are the same physical pages in every
+    member's row and lie wholly under every member's horizon. A member
+    carries the run's blocks and ``run_lead[t]``, its leader's place in the
+    tile (a member too); every other token carries 0 blocks. The tile reads
+    a run's blocks once, off the leader's row, for all its members, and
+    each member then streams only the rest of its own row. Only a call
+    without a window may carry them.
 
     ``sel`` (learned sparse attention) masks every key a token's indexer
     did not select, on top of the causal mask: the pass stays dense over
@@ -626,6 +736,7 @@ def flat_paged_attention_full(
         pad = pages_per_block - max_pages % pages_per_block
         page_table = jnp.pad(page_table, ((0, 0), (0, pad)))
     assert window is None or sel is None, "no windowed sparse layer"
+    assert window is None or runs is None, "no shared-prefix run under a window"
 
     qk = q.reshape(T, K, G, D)
     if sinks is None:
@@ -638,6 +749,8 @@ def flat_paged_attention_full(
     ]
     if window is not None:
         prefetch.append(_win_starts(kv_lens, window))
+    if runs is not None:
+        prefetch.extend(r.astype(jnp.int32) for r in runs)
 
     def at(*index):
         """An index map over (program, *scalar prefetch refs)."""
@@ -669,7 +782,8 @@ def flat_paged_attention_full(
             _flat_tile_kernel, page_size=page, head_dim=D, sm_scale=sm_scale,
             pages_per_block=pages_per_block, has_sinks=sinks is not None,
             quant=scales is not None, select=sel is not None,
-            windowed=window is not None, num_tokens=T,
+            windowed=window is not None, runs=runs is not None,
+            num_tokens=T,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
@@ -681,7 +795,11 @@ def flat_paged_attention_full(
                 pltpu.VMEM((K, TILE * G, 128), jnp.float32),
                 pltpu.VMEM((K, TILE * G, D), jnp.float32),
                 pltpu.VMEM((K, TILE * G, D), q.dtype),
-            ],
+            ] + ([
+                pltpu.VMEM((TILE, K, G, 128), jnp.float32),
+                pltpu.VMEM((TILE, K, G, 128), jnp.float32),
+                pltpu.VMEM((TILE, K, G, D), jnp.float32),
+            ] if runs is not None else []),
         ),
         out_shape=jax.ShapeDtypeStruct((T, K, G, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -700,7 +818,7 @@ def decode_paged_attention_full(
     kv_lens: jax.Array,
     sm_scale: float | None = None,
     interpret: bool = False,
-    pages_per_block: int = 16,
+    pages_per_block: int = PAGES_PER_BLOCK,
     window: jax.Array | None = None,
     sinks: jax.Array | None = None,
     scales: jax.Array | None = None,  # [L, num_pages, K, page, 2]

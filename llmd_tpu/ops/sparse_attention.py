@@ -384,12 +384,14 @@ def select_topk(scores, topk: int) -> jax.Array:
 
 def sparse_attention_full_flat(
     q, iq, iw, cache: IndexedPool, layer, rows, page_table, kv_lens,
-    positions, topk: int, sm_scale=None, world_size=1, mesh=None,
+    positions, topk: int, sm_scale=None, world_size=1, mesh=None, runs=None,
 ):
     """Attention of the packed ``[T, 1, H, D]`` stream over each token's
     selected keys only (see the module docstring). ``iq`` [T, J, Di] and
     ``iw`` [T, J] are the indexer's rotated query heads and head weights;
-    ``kv_lens`` is per token (position + 1)."""
+    ``kv_lens`` is per token (position + 1); ``runs`` the step's
+    shared-prefix runs (the dense pass under the mask reads a run's blocks
+    once a tile)."""
     from llmd_tpu import ops
 
     if world_size != 1:
@@ -411,7 +413,7 @@ def sparse_attention_full_flat(
         if plan == "direct":
             return flat_paged_attention_full(
                 q, kv, layer, rows, page_table, kv_lens, sm_scale=sm_scale,
-                interpret=ops._interpret(), sel=sel,
+                interpret=ops._interpret(), sel=sel, runs=runs,
             )
         sl = jax.lax.dynamic_index_in_dim(kv, layer, 0, keepdims=False)
         return ops._attention_xla(

@@ -221,6 +221,10 @@ def render_metrics(
         # Live tokens whose 16-token stream granule holds one row only:
         # the flat attention reads their context once per tile.
         "attn_shared_tile_tokens_total": stats.attn_shared_tile_tokens_total,
+        # Keys under the flat steps' decode tokens' horizons, and those of
+        # them in a shared-prefix run: read once a tile for its members.
+        "attn_prefix_run_keys_total": stats.attn_prefix_run_keys_total,
+        "attn_decode_keys_total": stats.attn_decode_keys_total,
         # What a cached token costs, over both KV pools: bytes held by
         # live references and the scheduled sequences' tokens, each summed
         # over steps (the ratio of two rates).

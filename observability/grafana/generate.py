@@ -578,6 +578,21 @@ DASHBOARDS["llmd-engine-kv-cache"] = dashboard(
                    "rows run. A prefill-heavy engine that reads low has "
                    "chunks cut smaller than the 16-token granule or "
                    "laid off it."),
+        panel("Decode keys read through shared-prefix runs (flat step)",
+              [f"rate(llmd:attn_prefix_run_keys_total{M}[5m]) / "
+               f"rate(llmd:attn_decode_keys_total{M}[5m])"],
+              unit="percentunit", legends=["run keys / decode keys"],
+              desc="Of the keys under the horizons of the flat steps' "
+                   "decode rows, the share in a shared-prefix run: "
+                   "leading blocks of the SAME physical pages (a shared "
+                   "document or system prompt, handed out by the prefix "
+                   "cache) that the attention kernel reads once a "
+                   "16-token tile for all the rows of the run, not once "
+                   "a row. High where the router brings sessions of one "
+                   "prefix to one replica; 0 where nothing is shared. "
+                   "Low beside a high prefix-cache hit rate: the shared "
+                   "part is under one 256-key block, or fewer than "
+                   "three rows of a prefix decode in the same tile."),
         panel("Padding efficiency (ragged qlens)",
               [f"rate(llmd:padded_tokens_total{M}[5m]) / "
                f"rate(llmd:live_tokens_total{M}[5m])",

@@ -87,20 +87,27 @@ def _scales(model):
     return ((L, PAGES, K, PAGE, 2), F32)
 
 
-def _flat_attention(model, dtype, T):
+def _flat_attention(model, dtype, T, table=(ROWS, MAX_PAGES)):
+    """The flat kernel without a window, as the step programs call it: with
+    the host's shared-prefix runs as two more prefetch arrays of T."""
     _, H, _, D = model
     args = [
         ((T, 1, H, D), BF16), _pool(model, dtype), ((), I32), ((T,), I32),
-        ((ROWS, MAX_PAGES), I32), ((T,), I32),
+        (table, I32), ((T,), I32), ((T,), I32), ((T,), I32),
     ]
     if dtype == I8:
         return (
-            lambda q, kv, l, r, pt, kl, sc: flat_paged_attention_full(
-                q, kv, l, r, pt, kl, scales=sc
+            lambda q, kv, l, r, pt, kl, lead, blocks, sc: flat_paged_attention_full(
+                q, kv, l, r, pt, kl, scales=sc, runs=(lead, blocks)
             ),
             args + [_scales(model)],
         )
-    return flat_paged_attention_full, args
+    return (
+        lambda q, kv, l, r, pt, kl, lead, blocks, **kw: flat_paged_attention_full(
+            q, kv, l, r, pt, kl, runs=(lead, blocks), **kw
+        ),
+        args,
+    )
 
 
 def _window_attention(T, model=EXAONE, pool_pages=PAGES, table=(ROWS, MAX_PAGES)):
@@ -127,7 +134,9 @@ def _sink_attention(T):
     model = (24, 64, 8, 128)
     fn, args = _flat_attention(model, BF16, T)
     return (
-        lambda q, kv, l, r, pt, kl, s: fn(q, kv, l, r, pt, kl, sinks=s),
+        lambda q, kv, l, r, pt, kl, lead, blocks, s: fn(
+            q, kv, l, r, pt, kl, lead, blocks, sinks=s
+        ),
         args + [((model[1],), F32)],
     )
 
@@ -139,13 +148,13 @@ def _sparse_attention(T):
     L, H, K, D = 6, 32, 4, 128
     rows, max_pages = 34, 2048
     return (
-        lambda q, kv, l, r, pt, kl, sel: flat_paged_attention_full(
-            q, kv, l, r, pt, kl, sel=sel
+        lambda q, kv, l, r, pt, kl, lead, blocks, sel: flat_paged_attention_full(
+            q, kv, l, r, pt, kl, sel=sel, runs=(lead, blocks)
         ),
         [
             ((T, 1, H, D), BF16), ((L, 24576, K, PAGE, 2 * D), BF16), ((), I32),
-            ((T,), I32), ((rows, max_pages), I32), ((T,), I32),
-            ((T, max_pages * PAGE), jnp.bool_),
+            ((T,), I32), ((rows, max_pages), I32), ((T,), I32), ((T,), I32),
+            ((T,), I32), ((T, max_pages * PAGE), jnp.bool_),
         ],
     )
 
@@ -393,6 +402,11 @@ CASES = {
     "decode_write-int8": lambda d: _decode_write(LLAMA, I8),
     "mla_decode-deepseek-v2-lite": lambda d: _mla_decode(),
     "flat_attention-granite-4.0-h-small": lambda d: _flat_attention(GRANITE, BF16, 528),
+    # the full layers' call of mellum2-12b-a2.5b.1chip (7 of 28 layers; 34 flat
+    # rows of 32,768 tokens) and nemotron-3-nano-30b-a3b.1chip's attention
+    # blocks (2 kv heads of 16 queries: a tile's operand is 256 query rows)
+    "flat_attention-mellum2-12b-a2.5b": lambda d: _flat_attention((7, 32, 4, 128), BF16, 128, (34, 2048)),
+    "flat_attention-nemotron-3-nano-30b-a3b": lambda d: _flat_attention((6, 32, 2, 128), BF16, 144, (136, 256)),
     "flat_write-granite-4.0-h-small": lambda d: _flat_write(GRANITE, BF16, 528),
     "ssm_update-granite-4.0-h-small": lambda d: _ssm_update(40),
     "ssm_update-nemotron-3-nano-30b-a3b": lambda d: _ssm_update(136, GROUPED_STATE_POOL, GROUPS),
@@ -511,8 +525,13 @@ def test_page_table_at_the_smem_bound_compiles(v5e):
     shapes = [
         ((tokens, 1, H, D), BF16), _pool(LLAMA, BF16), ((), I32),
         ((tokens,), I32), ((rows, 2048), I32), ((tokens,), I32),
+        ((tokens,), I32), ((tokens,), I32),
     ]
-    jax.jit(flat_paged_attention_full).lower(*[
+    jax.jit(
+        lambda q, kv, l, r, pt, kl, lead, blocks: flat_paged_attention_full(
+            q, kv, l, r, pt, kl, runs=(lead, blocks)
+        )
+    ).lower(*[
         jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
         for shape, dtype in shapes
     ]).compile()
